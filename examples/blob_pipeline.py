@@ -25,7 +25,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 from ponyc_tpu import (Blob, BlobVal, I32, Ref, Runtime,  # noqa: E402
                        RuntimeOptions, actor, behaviour)
-from ponyc_tpu.platforms import auto_backend  # noqa: E402
 
 W = 16          # pool width: up to 64 UTF-8 bytes per line
 
@@ -71,7 +70,6 @@ class Reviewer:
 
 
 def main():
-    auto_backend()
     lines = ["hello pony", "actors all the way down",
              "payloads live on the device now"]
     rt = Runtime(RuntimeOptions(blob_slots=32, blob_words=W, msg_words=2,

@@ -9,7 +9,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 from ponyc_tpu import (I32, Ref, Runtime, RuntimeOptions, actor,  # noqa
                        behaviour, options_from_env)
-from ponyc_tpu.platforms import auto_backend  # noqa: E402
 
 
 @actor
@@ -41,7 +40,6 @@ class Reporter:
 
 
 def main():
-    auto_backend()      # never hang on a wedged TPU plugin
     n, incs = 8, 100
     # options_from_env so `python -m ponyc_tpu run examples/counter.py
     # --ponyanalysis=2` (or any --pony* flag) reaches this runtime —

@@ -8,7 +8,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from ponyc_tpu import I32, Runtime, RuntimeOptions, actor, behaviour
-from ponyc_tpu.platforms import auto_backend  # noqa: E402
 
 
 @actor
@@ -34,7 +33,6 @@ class Echo:
 
 
 def main():
-    auto_backend()      # never hang on a wedged TPU plugin
     port = int(sys.argv[1]) if len(sys.argv) > 1 else 0
     rt = Runtime(RuntimeOptions(msg_words=4, inject_slots=64))
     rt.declare(Echo, 1).start()

@@ -16,7 +16,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 from ponyc_tpu import (I32, Runtime, RuntimeOptions,  # noqa: E402
                        actor, behaviour)
-from ponyc_tpu.platforms import auto_backend  # noqa: E402
 from ponyc_tpu.stdlib.timers import Timers  # noqa: E402
 
 BEATS = 5
@@ -35,7 +34,6 @@ class Heart:
 
 
 def main() -> int:
-    auto_backend()
     rt = Runtime(RuntimeOptions(mailbox_cap=8, batch=2, msg_words=3,
                                 inject_slots=8))
     rt.declare(Heart, 1).start()

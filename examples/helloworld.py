@@ -7,7 +7,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from ponyc_tpu import I32, Runtime, RuntimeOptions, actor, behaviour
-from ponyc_tpu.platforms import auto_backend  # noqa: E402
 
 
 @actor
@@ -22,7 +21,6 @@ class Main:
 
 
 def main():
-    auto_backend()      # never hang on a wedged TPU plugin
     rt = Runtime(RuntimeOptions(msg_words=1)).declare(Main, 1).start()
     rt.send(rt.spawn(Main), Main.create, 0)
     sys.exit(rt.run())

@@ -8,11 +8,9 @@ import sys
 sys.path.insert(0, ".")
 
 from ponyc_tpu.models import mandelbrot  # noqa: E402
-from ponyc_tpu.platforms import auto_backend  # noqa: E402
 
 
 def main():
-    auto_backend()      # never hang on a wedged TPU plugin
     width = int(sys.argv[1]) if len(sys.argv) > 1 else 128
     out = sys.argv[2] if len(sys.argv) > 2 else "/tmp/mandelbrot.pbm"
     grid = mandelbrot.render(width, width)

@@ -12,7 +12,6 @@ import numpy as np  # noqa: E402
 
 from ponyc_tpu import (I32, Ref, Runtime, RuntimeOptions, actor,  # noqa
                        behaviour)
-from ponyc_tpu.platforms import auto_backend  # noqa: E402
 
 N_SENDERS, ITEMS = 64, 50
 
@@ -43,7 +42,6 @@ class Sender:
 
 
 def main():
-    auto_backend()      # never hang on a wedged TPU plugin
     rt = Runtime(RuntimeOptions(mailbox_cap=8, batch=2, msg_words=1,
                                 max_sends=2, spill_cap=4096,
                                 inject_slots=64))

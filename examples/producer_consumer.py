@@ -9,7 +9,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from ponyc_tpu import I32, Ref, Runtime, RuntimeOptions, actor, behaviour
-from ponyc_tpu.platforms import auto_backend  # noqa: E402
 
 
 @actor
@@ -36,7 +35,6 @@ class Consumer:
 
 
 def main():
-    auto_backend()      # never hang on a wedged TPU plugin
     n_prod, items = 8, 200
     rt = Runtime(RuntimeOptions(mailbox_cap=16, batch=8, max_sends=2,
                                 msg_words=2, spill_cap=512,

@@ -16,7 +16,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from ponyc_tpu import I32, Runtime, RuntimeOptions, actor, behaviour
-from ponyc_tpu.platforms import auto_backend  # noqa: E402
 from ponyc_tpu.stdlib.term import (ANSITerm, Readline,  # noqa: E402
                                    ReadlineNotify, attach_stdin)
 
@@ -59,7 +58,6 @@ class Shell(ReadlineNotify):
 
 
 def main():
-    auto_backend()      # never hang on a wedged TPU plugin
     rt = Runtime(RuntimeOptions(msg_words=1)).declare(Echo, 1).start()
     echo = rt.spawn(Echo)
     holder = {}

@@ -9,7 +9,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from ponyc_tpu import I32, Ref, Runtime, RuntimeOptions, actor, behaviour
-from ponyc_tpu.platforms import auto_backend  # noqa: E402
 
 
 @actor
@@ -50,7 +49,6 @@ LINT_ROOTS = (Node.grow,)
 
 
 def main():
-    auto_backend()      # never hang on a wedged TPU plugin
     depth = 6                     # 2^6 = 64 leaves, 127 nodes
     rt = Runtime(RuntimeOptions(mailbox_cap=8, batch=4, max_sends=3,
                                 msg_words=2, inject_slots=8,

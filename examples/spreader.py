@@ -18,12 +18,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 from ponyc_tpu import (I32, Ref, Runtime, RuntimeOptions,  # noqa: E402
                        actor, behaviour)
-from ponyc_tpu.platforms import auto_backend  # noqa: E402
 from ponyc_tpu.stdlib.promises import Promise  # noqa: E402
 
 
 def main(depth: int = 6) -> int:
-    auto_backend()
     expect = 1 << depth
     done = Promise()            # fulfilled by the HOST actor below
 

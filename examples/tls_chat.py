@@ -25,7 +25,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from ponyc_tpu import I32, Runtime, RuntimeOptions, actor, behaviour
-from ponyc_tpu.platforms import auto_backend  # noqa: E402
 from ponyc_tpu.net.tls import (TLSClientConfig,  # noqa: E402
                                TLSServerConfig)
 
@@ -136,7 +135,6 @@ RECEIVED = {}
 
 
 def main():
-    auto_backend()      # never hang on a wedged TPU plugin
     certfile, keyfile = selfsigned_cert()
     rt = Runtime(RuntimeOptions(mailbox_cap=16, batch=4, max_sends=1,
                                 msg_words=3, inject_slots=64))

@@ -13,7 +13,6 @@ import numpy as np  # noqa: E402
 
 from ponyc_tpu import (I32, Ref, Runtime, RuntimeOptions, actor,  # noqa
                        behaviour)
-from ponyc_tpu.platforms import auto_backend  # noqa: E402
 from ponyc_tpu.stdlib import backpressure as bp  # noqa: E402
 
 
@@ -48,7 +47,6 @@ class Send:
 
 
 def main():
-    auto_backend()      # never hang on a wedged TPU plugin
     rt = Runtime(RuntimeOptions(mailbox_cap=8, batch=1, msg_words=1,
                                 max_sends=2, spill_cap=256,
                                 inject_slots=8))

@@ -16,7 +16,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from ponyc_tpu import I32, Runtime, RuntimeOptions, actor, behaviour
-from ponyc_tpu.platforms import auto_backend  # noqa: E402
 from ponyc_tpu.stdlib.cli import (ArgSpec, CliSyntaxError, CommandHelp,
                                   CommandParser, CommandSpec, OptionSpec)
 from ponyc_tpu.stdlib.itertools import Iter
@@ -46,7 +45,6 @@ def build_spec() -> CommandSpec:
 
 
 def main(argv):
-    auto_backend()      # never hang on a wedged TPU plugin
     cmd = CommandParser(build_spec()).parse(argv)
     if isinstance(cmd, CliSyntaxError):
         print(cmd.string(), file=sys.stderr)

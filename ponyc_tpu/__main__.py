@@ -9,9 +9,9 @@ ergonomics the reference gets from its binary.
 
 Commands:
   run <script.py> [args...]   strip --pony* flags into the environment
-                              (config.strip_runtime_flags), pick a
-                              backend (platforms.auto_backend), exec the
-                              script with the remaining argv.
+                              (config.strip_runtime_flags), exec the
+                              script with the remaining argv on the
+                              backend JAX resolves (JAX_PLATFORMS).
   bench [args...]             the headline benchmark (bench.py).
   test [pytest args...]       the test suite (≙ ponytest aggregate).
   doc <module[:ATTR]> [-o D]  generate docs for actor types reachable
@@ -50,8 +50,7 @@ Commands:
                               refreshed every --interval seconds
                               (--once renders a single frame).
   doctor --postmortem FILE    render a flight-recorder postmortem
-  doctor <host:port|url>      (crash/SIGQUIT/watchdog dump, or the
-                              probe evidence in a BENCH json) or a
+  doctor <host:port|url>      (crash/SIGQUIT/watchdog dump) or a
                               live /metrics+/healthz endpoint
                               (RuntimeOptions.metrics_port) into a
                               one-line verdict + diagnosis. Exit:
@@ -101,7 +100,8 @@ Commands:
                               python -m ponyc_tpu.loadgen HOST PORT.
                               Exit: 0 drained, the error code on a
                               coded failure (supervise restarts it).
-  version                     print version + backend info.
+  version                     print the package, jax and jaxlib versions
+                              (initialises no backend).
 
 Runtime flags accepted anywhere in `run` argv, exactly like the
 reference stripping --pony* before the app sees argv (start.c:185-261):
@@ -164,8 +164,6 @@ def cmd_run(argv) -> int:
     if not os.path.exists(script):
         print(f"ponyc_tpu run: no such script: {script}", file=sys.stderr)
         return 2
-    from .platforms import auto_backend
-    auto_backend()
     sys.argv = [script] + args
     sys.path.insert(0, os.path.dirname(os.path.abspath(script)) or ".")
     runpy.run_path(script, run_name="__main__")
@@ -179,6 +177,9 @@ def cmd_bench(argv) -> int:
         print("ponyc_tpu bench: bench.py not found (installed package "
               "without the repo harness)", file=sys.stderr)
         return 2
+    # One process per chip: this parent has imported the package but
+    # never initialised a JAX backend (importing ponyc_tpu touches no
+    # device), so the child is the only process that claims the chip.
     return subprocess.call([sys.executable, bench] + list(argv))
 
 
@@ -496,12 +497,11 @@ def cmd_doctor(argv) -> int:
     """Operational diagnosis (PROFILE.md §11): read stall/crash
     evidence and lead with a one-line verdict.
 
-        ponyc_tpu doctor --postmortem <file.postmortem.json|BENCH.json>
+        ponyc_tpu doctor --postmortem <file.postmortem.json>
         ponyc_tpu doctor <host:port | http://host:port>
 
-    The first form renders a flight-recorder postmortem (also accepts
-    a BENCH json whose `postmortem`/`tpu_init` evidence rides inside);
-    the second GETs /healthz + /metrics from a live runtime
+    The first form renders a flight-recorder postmortem; the second
+    GETs /healthz + /metrics from a live runtime
     (RuntimeOptions.metrics_port). Exit codes: 0 the world looks
     healthy (ok / plain snapshot), 1 stalled/crashed/degraded, 2 usage
     error or unreadable target."""
@@ -516,18 +516,8 @@ def cmd_doctor(argv) -> int:
         try:
             pm = load_postmortem(path)
         except (OSError, ValueError) as e:
-            # A BENCH json carries the probe postmortem nested under
-            # "postmortem" — accept the wrapper file directly.
-            import json as _json
-            try:
-                with open(path) as f:
-                    obj = _json.load(f)
-                pm = obj["postmortem"]
-                if not isinstance(pm, dict) or "reason" not in pm:
-                    raise KeyError("postmortem")
-            except (OSError, ValueError, KeyError, TypeError):
-                print(f"ponyc_tpu doctor: {e}", file=sys.stderr)
-                return 2
+            print(f"ponyc_tpu doctor: {e}", file=sys.stderr)
+            return 2
         line, detail = diagnose_postmortem(pm)
         print(line)
         print(detail)
@@ -782,15 +772,14 @@ def cmd_perf(argv) -> int:
 
 
 def cmd_version(_argv) -> int:
+    # Prints strings only: no jax.devices(), so asking for the version
+    # never claims the chip from a process that is using it.
     from . import __version__
+    import jax
+    import jaxlib
     print(f"ponyc_tpu {__version__}")
-    try:
-        from .platforms import probe_accelerator
-        plat, err = probe_accelerator(10.0)
-        print(f"backend: {plat or 'cpu'}"
-              + (f" (accelerator unavailable: {err})" if err else ""))
-    except Exception as e:                     # noqa: BLE001
-        print(f"backend probe failed: {e}")
+    print(f"jax {jax.__version__}, jaxlib {jaxlib.__version__}, "
+          f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS') or '(unset)'}")
     return 0
 
 

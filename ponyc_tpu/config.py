@@ -154,15 +154,17 @@ class RuntimeOptions:
     trace_seed: int = 0            # sampling-hash seed (determinism knob)
     pallas: Union[bool, str] = False   # route the dispatch mailbox drain
     #   through the Pallas kernel (ops/mailbox_kernel.py) instead of the
-    #   XLA select-chain; interpret-mode on CPU. "auto" adds the kernel
-    #   as a calibrated variant (tuning.py) where the program's cohorts
-    #   are block-aligned; the measured winner is used.
+    #   XLA select-chain; interpret-mode on CPU. True on a cohort the
+    #   kernel cannot tile raises at start(). "auto" adds the kernel as
+    #   a calibrated variant (tuning.py) where every dispatching cohort
+    #   is block-aligned; the measured winner is used.
     pallas_fused: Union[bool, str] = False  # fuse drain + behaviour +
-    #   outbox into ONE Pallas kernel per eligible cohort
-    #   (ops/fused_dispatch.py: no sync-construction/blob pool; others
-    #   fall back to the XLA path). The north-star dispatch kernel;
-    #   "auto" = calibrate it against the XLA path at start() and keep
-    #   the winner (tuning.py).
+    #   outbox into ONE Pallas kernel per cohort
+    #   (ops/fused_dispatch.py). True on a cohort it cannot host
+    #   (sync-construction, blob pool, unaligned rows) raises at
+    #   start(). The north-star dispatch kernel; "auto" = calibrate it
+    #   against the XLA path at start() where every dispatching cohort
+    #   can host it, and keep the winner (tuning.py).
     host_fastpath: bool = True     # host-sender → host-target messages
     #   bypass the device mailbox table: they queue host-side and
     #   dispatch at host boundaries (≙ the main-thread scheduler's
@@ -171,7 +173,7 @@ class RuntimeOptions:
     #   FIFO is preserved (a host sender's messages to a host receiver
     #   ALL take this lane; device senders all take the device lane);
     #   lifts the host-plane ceiling ~the device-window cost per hop
-    #   (benchmarks.md "host-bridge ceiling"). False restores the
+    #   (profiling/_bridge_pump.py measures it). False restores the
     #   everything-through-the-device-table path.
     host_fastpath_budget: int = 100_000  # max fast-lane dispatches per
     #   host boundary; leftovers keep the loop busy (starvation guard so
@@ -190,24 +192,25 @@ class RuntimeOptions:
     #   "cosort" — one stable multi-operand lax.sort per tick that moves
     #              the payload with the key (no plan, no gathers; wins
     #              where arbitrary lane gathers lower poorly);
-    #   "pallas_mega" — the persistent fused window megakernel
+    #   "pallas_mega" — the fused window megakernel
     #              (ops/megakernel.py, PROFILE.md §14): the WHOLE gated
     #              window — delivery gather, mailbox drain, dispatch,
-    #              profiler lanes — runs as one Pallas kernel with the
-    #              in-window while as a kernel-internal loop, and ring
-    #              records cross the kernel boundary packed into int16
-    #              lanes + an int32 escape plane (the mailbox bandwidth
-    #              diet). Plan-formulation delivery semantics,
-    #              bit-equivalent by construction; ineligible programs
-    #              (mesh shards > 1, pallas/pallas_fused forced on)
-    #              fall back to the XLA spelling.
+    #              profiler lanes — as one Pallas kernel with the
+    #              in-window while as a kernel-internal loop, ring
+    #              records crossing the kernel boundary packed into
+    #              int16 lanes + an int32 escape plane. Plan-formulation
+    #              delivery semantics, bit-equivalent by construction —
+    #              in interpret mode on the CPU backend. It does not
+    #              lower on v5e (jax 0.9.0): on a TPU, and for programs
+    #              it cannot serve (mesh shards > 1, pallas/pallas_fused
+    #              forced on), start() raises the reason.
     #   "auto"   — calibrate the formulations at Runtime.start() by
     #              timing a short in-executable fused window per
     #              formulation on the program's real cohort shapes and
     #              keep the faster one (tuning.py; the decision
     #              persists in the tuning cache so steady-state starts
-    #              skip calibration; pallas_mega joins the candidates
-    #              on TPU, or under PONY_TPU_MEGA_AUTO=1 elsewhere).
+    #              skip calibration; plan and cosort are the
+    #              candidates, never pallas_mega).
     debug_checks: bool = False     # run Runtime.check_invariants() at
     #   every aux fetch (≙ the reference's debug-build queue checkers,
     #   actor.c:57-92; costly — test/debug only)
@@ -275,17 +278,19 @@ class RuntimeOptions:
     # PROFILE.md §6) ---
     tuning_cache: str = "auto"     # on-disk decision cache for "auto"
     #   option values, keyed by (platform, jax version, cohort layout,
-    #   geometry). "auto" = $PONY_TPU_TUNING_CACHE or
-    #   ~/.cache/ponyc_tpu/tuning; "off" disables (recalibrate every
+    #   geometry). "auto" = $PONY_TPU_TUNING_CACHE or the checkout's
+    #   .cache/ponyc_tpu/tuning; "off" disables (recalibrate every
     #   start); any other value = explicit directory.
-    compile_cache: str = "auto"    # jax persistent compilation cache
-    #   (attacks the measured 11.8 s warmup, PROFILE.md §4b). Same
-    #   spelling: "auto" = $PONY_TPU_COMPILE_CACHE or
-    #   ~/.cache/ponyc_tpu/xla; "off" leaves jax.config untouched.
+    compile_cache: str = "auto"    # jax persistent compilation cache:
+    #   "auto" = on (accelerator backends; the CPU backend keeps it off,
+    #   tuning.enable_compile_cache says why), at
+    #   $JAX_COMPILATION_CACHE_DIR where that is set (the code then sets
+    #   no directory) and else at the checkout's .cache/ponyc_tpu/xla;
+    #   "off" leaves jax.config untouched.
     tuning_ticks: int = 0          # in-executable ticks per calibration
-    #   window (lax.fori_loop trip count — the only methodology
-    #   PROFILE.md §4b trusts; per-call timings carry an ~11 ms launch
-    #   floor). 0 = auto-size from the synthetic workload's sustain.
+    #   window (lax.fori_loop trip count, so the per-call launch cost
+    #   divides out). 0 = auto-size from the synthetic workload's
+    #   sustain.
     tuning_repeats: int = 3        # timed windows per variant (the
     #   median is kept; the first, compile-bearing window never counts)
 
@@ -338,6 +343,10 @@ class RuntimeOptions:
             v = getattr(self, name)
             if not (v is True or v is False or v == "auto"):
                 raise ValueError(f"{name} must be True, False or 'auto'")
+        if self.compile_cache not in ("auto", "off"):
+            raise ValueError(
+                "compile_cache must be 'auto' or 'off' (place the cache "
+                "with JAX_COMPILATION_CACHE_DIR)")
         if self.tuning_repeats < 1:
             raise ValueError("tuning_repeats must be >= 1")
         if self.tuning_ticks < 0:

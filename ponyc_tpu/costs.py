@@ -249,8 +249,8 @@ def measured_block(rt, modelled: Optional[Dict[str, Any]] = None,
 # the committed BENCH_r*.json round records are ingested too (their
 # driver wrapper format: {"n", "cmd", "rc", "tail", "parsed"} with the
 # bench stdout json under "parsed"). The scoreboard compares like with
-# like — a CPU-fallback round must not read as a "regression" from the
-# last TPU round, and a 256-actor smoke must not be judged against a
+# like — an explicit CPU run must not read as a "regression" from the
+# last TPU run, and a 256-actor smoke must not be judged against a
 # 1M-actor headline — so rows group by (metric, unit, platform,
 # actors) and --check gates the newest row of each group against the
 # best earlier row of the SAME group.
@@ -286,8 +286,6 @@ def flatten_result(parsed: Dict[str, Any], source: str,
         "platform": detail.get("platform", parsed.get("platform")),
         "delivery": detail.get("delivery", parsed.get("delivery")),
         "actors": detail.get("actors", parsed.get("actors")),
-        "tpu_init_error": bool(detail.get("tpu_init_error")
-                               or parsed.get("tpu_init_error")),
         "measured_step_bytes": step.get(
             "bytes_accessed", parsed.get("measured_step_bytes")),
         "model_divergence": bool(div.get(
@@ -393,8 +391,6 @@ def render_perf(rows: list, check: Optional[Dict[str, Any]] = None,
                 f"{row.get('platform') or '?'}/"
                 f"{row.get('delivery') or '?'}",
                 f"actors={row.get('actors') or '?'}"]
-        if row.get("tpu_init_error"):
-            bits.append("TPU-FALLBACK")
         if row.get("model_divergence"):
             bits.append("MODEL-DIVERGED")
         lines.append(f"  {row['source']:<18} " + "  ".join(bits))

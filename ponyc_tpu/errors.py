@@ -98,8 +98,7 @@ class PonyStallError(RuntimeError):
     run-loop phase (backend init, a dispatched window, host work)
     exceeded its deadline with no progress stamp. Carries the tripped
     phase and the postmortem path the watchdog wrote — the structured
-    replacement for the silent forever-hang (ISSUE 7 / the
-    `jax.devices()` init hang that degraded BENCH r03–r05)."""
+    replacement for the silent forever-hang (ISSUE 7)."""
 
     code = ERROR_CODES["PonyStallError"]
 

@@ -5,8 +5,7 @@ the opt-in profiling one).
 
 The per-behaviour profiler (PR 4) and causal tracing (PR 6) made the
 runtime *introspectable*; nothing made it *operable*: a wedged window
-produced no diagnosis, and the `jax.devices()` init hang silently
-degraded three BENCH rounds to CPU before anything recorded why. Two
+or a backend init that never returned produced no diagnosis. Two
 host-side pieces fix that:
 
 - **FlightRecorder** — a bounded ring retaining the last
@@ -35,8 +34,7 @@ host-side pieces fix that:
   phases never trip (a runtime waiting on external events is healthy).
 
 ``python -m ponyc_tpu doctor --postmortem FILE`` renders a dump into a
-diagnosis (``diagnose_postmortem`` below); bench.py embeds
-``probe_postmortem`` evidence in every ``tpu_init_error`` BENCH json.
+diagnosis (``diagnose_postmortem`` below).
 """
 
 from __future__ import annotations
@@ -47,7 +45,7 @@ import os
 import sys
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 POSTMORTEM_VERSION = 1
 
@@ -63,10 +61,9 @@ ARMED_PHASES = frozenset({"backend-init", "dispatching", "in-flight",
 
 # Deadline multiplier for COLD device phases (backend init and the
 # first window before any retire): the first dispatch pays trace + XLA
-# compile — tens of seconds is legitimate there (PROFILE.md §4b's
-# 11.8 s warmup) and must not read as a stall under a deadline sized
-# for steady-state windows. The observed init hang was 90 s+, so a
-# few-second watchdog still catches it comfortably.
+# compile — tens of seconds is legitimate there (about a minute for a
+# 1M-actor window on a v5e, PERF.md) and must not read as a stall
+# under a deadline sized for steady-state windows.
 COLD_FACTOR = 10.0
 
 
@@ -74,8 +71,7 @@ def env_snapshot() -> Dict[str, Any]:
     """Probed-environment snapshot for postmortems: accelerator-related
     env vars (secret-filtered), libtpu importability, device nodes —
     the block that makes a backend-init failure diagnosable from the
-    record alone (ROADMAP item 2's first sub-task, now shared by
-    bench.py's tpu_env_details and every flight-recorder dump)."""
+    record alone (every flight-recorder dump carries it)."""
     import importlib.util
     env = {k: v for k, v in sorted(os.environ.items())
            if k.startswith(("TPU", "JAX", "LIBTPU", "PJRT", "XLA"))
@@ -343,28 +339,6 @@ def load_postmortem(path: str) -> Dict[str, Any]:
     return pm
 
 
-def probe_postmortem(timeline: List[Dict[str, Any]],
-                     env: Optional[Dict[str, Any]] = None
-                     ) -> Dict[str, Any]:
-    """A flight-recorder-shaped postmortem for a failure BEFORE any
-    runtime exists: the TPU backend-init probe (bench.py). `timeline`
-    is the probe attempts — [{attempt, t_s, timeout_s, error}] — the
-    stall evidence every CPU-fallback BENCH round must carry."""
-    last = timeline[-1]["error"] if timeline else None
-    return {
-        "version": POSTMORTEM_VERSION,
-        "reason": "tpu_init_failed",
-        "time": time.time(),
-        "pid": os.getpid(),
-        "phase": {"name": "backend-init", "epoch": 0,
-                  "age_s": round(sum(a.get("t_s", 0.0)
-                                     for a in timeline), 1)},
-        "probe_timeline": timeline,
-        "last_error": last,
-        "env": env if env is not None else env_snapshot(),
-    }
-
-
 def _fmt_flags(flags: Dict[str, Any]) -> str:
     up = [k for k, v in (flags or {}).items() if v]
     return ",".join(up) if up else "-"
@@ -456,13 +430,6 @@ def render_postmortem(pm: Dict[str, Any]) -> str:
             f"{div.get('measured_bytes')} B/msg "
             f"(ratio {div['ratio']}, tol {div.get('tolerance')}) "
             f"-> {verdict}")
-    tl = pm.get("probe_timeline")
-    if tl:
-        lines.append(f"backend probe attempts: {len(tl)}")
-        for a in tl[-4:]:
-            lines.append(f"  attempt {a.get('attempt')}: "
-                         f"timeout={a.get('timeout_s')}s "
-                         f"error={a.get('error')}")
     env = pm.get("env") or {}
     if env:
         lines.append(f"env: libtpu_importable="
@@ -474,19 +441,12 @@ def render_postmortem(pm: Dict[str, Any]) -> str:
 
 def diagnose_postmortem(pm: Dict[str, Any]) -> Tuple[str, str]:
     """(one_line_verdict, detail_text) for a postmortem — the doctor's
-    reading. The one-liner is what bench.py prints when a TPU init
-    failure downgrades a round, and what the CLI leads with."""
+    reading; the one-liner is what the CLI leads with."""
     reason = str(pm.get("reason", "?"))
     ph = pm.get("phase") or {}
     wins = pm.get("windows") or []
     last = wins[-1] if wins else None
-    if reason == "tpu_init_failed":
-        tl = pm.get("probe_timeline") or []
-        line = (f"STALLED: TPU backend init failed after "
-                f"{len(tl)} probe attempt(s) over "
-                f"{ph.get('age_s', '?')}s — last error: "
-                f"{pm.get('last_error') or '?'}")
-    elif reason.startswith("watchdog"):
+    if reason.startswith("watchdog"):
         hint = ""
         if ph.get("name") == "in-flight":
             hint = " (device never retired the window: backend hang " \
@@ -494,8 +454,8 @@ def diagnose_postmortem(pm: Dict[str, Any]) -> Tuple[str, str]:
         elif ph.get("name") == "host-work":
             hint = " (a host behaviour, poller or GC pass is stuck)"
         elif ph.get("name") == "backend-init":
-            hint = " (jax backend init hang — probe the accelerator " \
-                   "in a subprocess: platforms.probe_accelerator)"
+            hint = " (jax backend init never returned: is another " \
+                   "process holding the chip?)"
         line = (f"STALLED: {reason}{hint}")
     elif (pm.get("errors") or []):
         e = pm["errors"][-1]

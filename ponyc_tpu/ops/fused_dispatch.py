@@ -13,8 +13,8 @@ outbox planes and head advance — where the XLA path makes `batch`
 separate select-chain passes over the mailbox block plus materialised
 scan intermediates.
 
-Eligibility (checked by `eligible()` — everything else falls back to
-the XLA path, same semantics):
+Eligibility (`refusal()` — a cohort the kernel cannot host is refused
+at start(), never quietly run on the XLA path):
   - no SYNC-construction across the cohort's behaviours (its per-site
     field-value packaging is host-assembled). destroy(), error_int()
     AND device spawns ARE hosted: destroy/error flags ride out as lane
@@ -31,8 +31,10 @@ the XLA path, same semantics):
     detectable, hence contract + documentation, like vmap's own
     semantics.
 
-Gating: `RuntimeOptions.pallas_fused` (off by default until measured on
-the real chip; interpret mode exercises the kernel on CPU in the suite).
+Gating: `RuntimeOptions.pallas_fused` (off by default: compiled by
+Mosaic on the v5e and bit-identical to the XLA path there —
+chip_smoke.py phase (d) — but not yet timed against it; interpret mode
+exercises the kernel on CPU in the suite and is unreachable on a TPU).
 """
 
 from __future__ import annotations
@@ -44,18 +46,25 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import mailbox_kernel as mk
 
-LANE_BLOCK = 1024
+LANE_BLOCK = mk.LANE_BLOCK      # one lane block for both kernels
 
 
-def eligible(cohort, effects, opts) -> bool:
-    """Structural + trace-discovered preconditions for the fused path.
+def refusal(cohort, opts, sync_init: bool) -> str | None:
+    """Why the fused kernel cannot host `cohort` as asked, or None.
     destroy/error AND device spawns are hosted (reservation planes ride
     in, claim planes ride out — ≙ pony_create from a behaviour,
-    actor.c:688-734); only synchronous construction still needs the XLA
-    path (its per-site field-value packaging is host-assembled)."""
-    return (len(cohort.behaviours) >= 1
-            and not effects["sync_init"])
+    actor.c:688-734); synchronous construction is not (its per-site
+    field-value packaging is host-assembled), nor is the blob pool.
+    `sync_init` is the trace-discovered fact (verify.behaviour_effects
+    at start(), the engine's own probe at build)."""
+    name = cohort.atype.__name__
+    if opts.blob_slots > 0 and cohort.uses_blobs:
+        return f"cohort {name}: uses the device blob pool"
+    if sync_init:
+        return f"cohort {name}: a behaviour constructs actors synchronously"
+    return mk.refusal(cohort)
 
 
 def _slim_branch(bdef, field_specs, field_dtypes, msg_words, ms, lanes,

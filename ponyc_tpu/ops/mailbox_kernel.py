@@ -13,11 +13,15 @@ one grid step pulls a [cap, w1, LANE] tile of the (planar, actor-minor —
 state.py layout note) mailbox table into VMEM ONCE and emits all
 `batch` message planes and validity masks from it.
 
-Gating: `RuntimeOptions.pallas` (off by default until measured ≥ the
-XLA path on the real chip; `interpret=True` runs the same kernel on CPU
-for the test suite). No per-lane gather is used anywhere — ring-slot
-selection is a static select chain over the small `cap` axis, which is
-the TPU-legal formulation (dynamic per-lane indexing does not lower).
+Gating: `RuntimeOptions.pallas` (off by default: compiled by Mosaic on
+the v5e and bit-identical to the XLA path there — chip_smoke.py phase
+(d) — but not yet timed against it; `interpret=True` runs the same
+kernel on CPU for the test suite and is unreachable on a TPU). No
+per-lane gather is used anywhere — ring-slot selection is a static
+select chain over the small `cap` axis, which is the TPU-legal
+formulation (dynamic per-lane indexing does not lower). A cohort the
+kernel cannot tile is REFUSED (`refusal`), never quietly run on the XLA
+path.
 """
 
 from __future__ import annotations
@@ -51,8 +55,7 @@ def drain_msgs(buf, head, n_run, *, batch: int, interpret: bool = False):
 
     buf: [cap, w1, N] int32 (planar); head, n_run: [N] int32.
     Returns (msgs [batch, w1, N] int32, valids [batch, N] bool).
-    N must be a multiple of LANE_BLOCK (cohort capacities are padded by
-    the caller; engine cohorts fall back to the XLA path otherwise).
+    N must be at most LANE_BLOCK or a multiple of it (`refusal`).
     """
     cap, w1, n = buf.shape
     lb = min(LANE_BLOCK, n)
@@ -80,9 +83,16 @@ def drain_msgs(buf, head, n_run, *, batch: int, interpret: bool = False):
     return msgs, valid.astype(jnp.bool_)
 
 
-def use_pallas(opts) -> bool:
-    """Whether the engine should route dispatch through this kernel."""
-    return bool(getattr(opts, "pallas", False))
+def refusal(cohort) -> str | None:
+    """Why this kernel cannot drain `cohort` as asked, or None. The grid
+    tiles the actor-lane axis in whole LANE_BLOCKs (shared with
+    ops.fused_dispatch)."""
+    rows = cohort.local_capacity
+    if rows > LANE_BLOCK and rows % LANE_BLOCK:
+        return (f"cohort {cohort.atype.__name__}: {rows} rows per shard "
+                f"is neither <= {LANE_BLOCK} nor a multiple of it (the "
+                "kernel's lane block)")
+    return None
 
 
 def interpret_mode() -> bool:

@@ -26,11 +26,17 @@ Two ideas, one module:
    REAL window `while` condition (`engine.aux_go`) — equivalence with
    the XLA scan path is by construction, and the differential/FIFO
    corpora (tests/test_differential.py, tests/test_fifo.py) pin it
-   bit-for-bit in interpret mode. On a backend where the Mosaic
-   lowering of some contained op is unsupported, the tuner's per-
-   variant error capture (tuning.calibrate) records the failure and
-   the variant self-disqualifies — `delivery="pallas_mega"` can never
-   break a start, only lose a race.
+   bit-for-bit in interpret mode on the CPU backend.
+
+   **Does not lower on v5e (jax 0.9.0).** Mosaic refuses the kernel at
+   lowering (`MOSAIC_REFUSAL` below holds its words), and more refusals
+   sit behind that first one: the `pallas_call` has no grid and no
+   `BlockSpec`, so all of the window's state is asked into VMEM at
+   once, and the replayed jaxpr is full of sort/gather/scatter/while.
+   So on a TPU `refusal()` names that reason, `delivery="pallas_mega"`
+   raises it at start(), and `delivery="auto"` never enumerates the
+   variant. It stays as an interpret-mode vehicle for the equivalence
+   corpora only; its deletion is ROADMAP C1's.
 
 2. **The bandwidth diet** (`pack_words`/`unpack_words`): mailbox ring
    records, spill words and trace lanes are int32, but behaviour ids
@@ -46,10 +52,10 @@ Two ideas, one module:
    escape rate of every run in the BENCH json `kernel` block, and
    PROFILE.md §14 carries the bytes-moved/tick table).
 
-Single-shard only (`eligible`): under a mesh the window's psum votes
+Single-shard only (`refusal`): under a mesh the window's psum votes
 cross shards mid-tick, which a single-device kernel scope cannot
-express — sharded programs fall back to the XLA formulation (same
-semantics; delivery="pallas_mega" behaves as "plan" there).
+express — delivery="pallas_mega" on a sharded program is refused at
+start().
 """
 
 from __future__ import annotations
@@ -159,34 +165,35 @@ def modelled_bytes_per_msg(opts, esc_rate: float = 0.0) -> Dict[str, Any]:
 # eligibility
 
 
-def eligible(program, opts) -> bool:
-    """Structural preconditions of the megakernel: one shard (the
-    window's mesh psum votes cannot cross a single kernel's scope),
-    some device cohort to run, and the nested Pallas kernels OFF
-    (a pallas_call inside the megakernel's scope would nest kernels —
-    the megakernel IS the fused form of both)."""
+# Mosaic's words when the window kernel is lowered for "TPU v5 lite"
+# (jax 0.9.0 / libtpu 0.0.34; AOT against the v5e topology and on the
+# chip alike). The source is `_decode_refs` materialising a zero-size
+# bypass leaf inside the kernel.
+MOSAIC_REFUSAL = ("vector types must have positive constant sizes "
+                  "but got 3, 0")
+
+
+def refusal(program, opts) -> Optional[str]:
+    """Why delivery="pallas_mega" cannot run as asked, or None.
+    Structural preconditions: one shard (the window's mesh psum votes
+    cannot cross a single kernel's scope), some device cohort to run,
+    and the nested Pallas kernels OFF (a pallas_call inside the
+    megakernel's scope would nest kernels — the megakernel IS the fused
+    form of both). And the backend: on a TPU the kernel does not lower
+    (module docstring), so there the answer is always Mosaic's."""
+    if not interpret_mode():
+        return ("the window megakernel does not lower on TPU "
+                f"(jax {jax.__version__}); Mosaic: {MOSAIC_REFUSAL}")
     if program.shards != 1:
-        return False
+        return f"mesh_shards={program.shards} (single-shard only)"
     if getattr(opts, "pallas", False) is True:
-        return False
+        return "pallas=True would nest a pallas_call inside the kernel"
     if getattr(opts, "pallas_fused", False) is True:
-        return False
-    return any(ch.behaviours for ch in program.device_cohorts)
-
-
-def auto_enumerable(program, opts) -> bool:
-    """Whether delivery="auto" should TIME the megakernel as a variant.
-    On a real TPU: whenever eligible. On CPU the kernel only runs in
-    interpret mode — a test vehicle, never a perf winner — so auto
-    skips it unless PONY_TPU_MEGA_AUTO=1 (bench.py sets it: every
-    BENCH json's A/B table carries the variant; the unit suite's many
-    auto-starts don't pay an extra window compile)."""
-    import os
-    if not eligible(program, opts):
-        return False
-    if jax.default_backend() == "tpu":
-        return True
-    return os.environ.get("PONY_TPU_MEGA_AUTO", "0") == "1"
+        return ("pallas_fused=True would nest a pallas_call inside the "
+                "kernel")
+    if not any(ch.behaviours for ch in program.device_cohorts):
+        return "no device cohort has behaviours to run"
+    return None
 
 
 # ---------------------------------------------------------------------------

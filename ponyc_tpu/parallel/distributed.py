@@ -53,10 +53,7 @@ def initialize(coordinator: Optional[str] = None,
     # collectives implementation is selected; gloo ships with jaxlib.
     # Must land BEFORE the backend initialises — harmless for
     # accelerator backends, which ignore the knob.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):
-        pass          # the knob moved (older/newer jax): leave default
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=num_processes,
                                process_id=process_id)

@@ -415,9 +415,9 @@ def jit_gc(program: Program, opts: RuntimeOptions, mesh=None):
     sharded = P("actors")
     repl = P()
     state_spec = state_partition_specs(program, opts)
-    from ..compat import shard_map
-    mapped = shard_map(
+    mapped = jax.shard_map(           # check_vma: see engine._jit_over_mesh
         gc, mesh=mesh,
         in_specs=(state_spec, sharded, sharded),
-        out_specs=(state_spec, (repl, repl, repl, repl)))
+        out_specs=(state_spec, (repl, repl, repl, repl)),
+        check_vma=False)
     return jax.jit(mapped, donate_argnums=(0,))

@@ -310,10 +310,10 @@ class Runtime:
     def start(self) -> "Runtime":
         # ≙ pony_init, split so the operational pieces (the always-on
         # flight recorder + optional stall watchdog, PROFILE.md §11)
-        # arm BEFORE the first device-touching call: a hung backend
-        # init (the jax.devices() wedge that silently degraded BENCH
-        # r03–r05 to CPU) then trips the watchdog — postmortem on disk,
-        # int-coded PonyStallError raised — instead of hanging forever.
+        # arm BEFORE the first device-touching call: a backend init
+        # that never returns then trips the watchdog — postmortem on
+        # disk, int-coded PonyStallError raised — instead of hanging
+        # forever.
         self._apply_defaults_and_pin()
         from .. import flight as _flight
         self._flight = _flight.FlightRecorder(
@@ -377,7 +377,7 @@ class Runtime:
     def _start_world(self) -> None:
         # Persistent compile cache (tuning.enable_compile_cache): lands
         # before the first jit of this runtime so warm starts reload
-        # executables instead of re-lowering (PROFILE.md §4b's 11.8 s).
+        # executables instead of re-lowering.
         from .. import tuning
         from ..config import auto_fields
         tuning.enable_compile_cache(self.opts.compile_cache)
@@ -398,6 +398,10 @@ class Runtime:
             self.opts, self.tuning_record = tuning.resolve(
                 self.program, self.opts, self.mesh, self.state)
             self.program.opts = self.opts
+        # An explicitly requested kernel that cannot run as asked is an
+        # error HERE, naming the cohort and the reason — never a quiet
+        # XLA path under the kernel's name.
+        tuning.check_requested(self.program, self.opts)
         # Adaptive quiesce window (runtime/controller.py): resolve the
         # "auto" initial value through the tuning cache (a previous
         # run's converged window for this layout), then hand the bounds

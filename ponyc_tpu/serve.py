@@ -752,7 +752,6 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     from .config import strip_runtime_flags
     from .errors import error_code
-    from .platforms import auto_backend
     argv = list(sys.argv[1:] if argv is None else argv)
     try:
         opts_env, rest = strip_runtime_flags(["x"] + argv)
@@ -772,7 +771,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         print("ponyc_tpu serve: --tls-cert and --tls-key go together",
               file=sys.stderr)
         return 2
-    auto_backend()
     import dataclasses as _dc
     base = default_options(args.workers)
     opts = _dc.replace(base, **{

@@ -212,6 +212,9 @@ class Supervisor:
             else:
                 env.pop(RESTORE_ENV, None)
             self.restored_from = path or None
+            # One process per chip: the supervising parent only reads
+            # checkpoint files (numpy) and never initialises a JAX
+            # backend, so each child in turn is the chip's one owner.
             p = subprocess.run(self.argv, env=env)
             if p.returncode == 0:
                 return 0

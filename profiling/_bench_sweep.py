@@ -1,7 +1,6 @@
 """One-claim bench config sweep: fused-window ubench tick_ms for every
-(delivery, pings, pallas) combination, in a single TPU session. Appends
-to /tmp/p9_sweep.txt. Run detached; waits for the claim as long as it
-takes."""
+(delivery, pings, pallas) combination, in a single TPU session (one
+process). Appends to /tmp/p9_sweep.txt."""
 import sys
 import time
 

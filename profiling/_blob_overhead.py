@@ -3,7 +3,7 @@ the structural claim: a program that never touches the pool pays nothing
 — the threading is gated per cohort (engine.use_blob), and a merely
 ENABLED pool only adds the per-tick free-slot compaction when some
 cohort allocates. Run:
-    env -u PYTHONPATH JAX_PLATFORMS=cpu python profiling/_blob_overhead.py
+    JAX_PLATFORMS=cpu python profiling/_blob_overhead.py
 """
 import sys
 import time

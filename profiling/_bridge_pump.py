@@ -5,8 +5,7 @@ this measures the equivalent ceiling of THIS runtime's host plane:
 C loopback TCP connections ping-ponging M messages each through
 host-cohort actors (socket → bridge → host dispatch → socket). The
 result is the msgs/s bound a chatty-net program hits BEFORE the device
-ever matters (the host plane is single-threaded Python by design —
-VERDICT r4 weak #6); recorded in benchmarks.md.
+ever matters (the host plane is single-threaded Python by design).
 
 Usage: python profiling/_bridge_pump.py [clients] [msgs_per_client]
 """

@@ -1,7 +1,8 @@
 """Follow-up to _profile_all.py: the A/B rows it doesn't cover —
 pallas_fused (the north-star fused dispatch kernel, ops/fused_dispatch.py)
 and dispatch_gating — plus a cap sweep on the winner axis. Appends to the
-same /tmp/p9_results.txt. Run after _profile_all.py releases the claim:
+same /tmp/p9_results.txt. Run after _profile_all.py has exited (one
+process per chip):
     nohup python -u _profile_fused.py > /tmp/p9_fused.log 2>&1 &
 """
 import sys

@@ -15,7 +15,7 @@ Random world sizes and traffic per seed, rotating configurations
 route buckets). Any mismatch against the sequential oracle or failure to
 quiesce prints FAIL lines and exits nonzero. The round-3 campaign ran
 30 single-chip + 12 mesh seeds clean after fixing the mute-cycle
-deadlock this harness found (ROUND3_NOTES.md)."""
+deadlock this harness found."""
 
 import os
 import sys

@@ -1,9 +1,8 @@
 """Fast bench-wiring smoke test: the fused measurement window driven
 through delivery="auto" at toy scale, so bench.py's harness (counter
 verification + the tuning record every run publishes) can never silently
-rot between the rare on-chip campaigns (the round-3→5 lesson: the A/B
-machinery sat unmeasured for three rounds because nothing cheap
-exercised it)."""
+rot between chip runs. Everything here runs on the CPU backend the
+suite pins; the no-chip tests below pin that bench.py says so."""
 
 import argparse
 
@@ -21,7 +20,6 @@ def _args(**kw):
 @pytest.fixture()
 def bench_mod(tmp_path, monkeypatch):
     monkeypatch.setenv("PONY_TPU_TUNING_CACHE", str(tmp_path / "tuning"))
-    monkeypatch.setenv("PONY_TPU_COMPILE_CACHE", "off")
     import bench
     return bench
 
@@ -190,31 +188,35 @@ def test_bench_serve_smoke_block(bench_mod):
     assert s["batches"] >= 1 and s["submitted"] >= s["ok"]
 
 
-def test_tpu_env_details_shape(bench_mod):
-    """The tpu_init_error env snapshot: JSON-serialisable, secrets
-    filtered, libtpu presence probed."""
-    import json as _json
-    d = bench_mod.tpu_env_details()
-    _json.dumps(d)                       # must serialise
-    assert "libtpu_importable" in d
-    assert all("KEY" not in k and "TOKEN" not in k for k in d["env"])
+def test_no_accelerator_exits_nonzero_before_any_number(
+        bench_mod, monkeypatch, capsys):
+    """`python bench.py` (--platform tpu is the default) on a machine
+    where JAX resolves no TPU exits non-zero and prints NO throughput:
+    no probe child, no CPU fallback, no shrunken world."""
+    monkeypatch.delenv("PONY_TPU_BENCH_PLATFORM", raising=False)
+    monkeypatch.setattr("sys.argv", ["bench.py"])
+    monkeypatch.setattr(
+        bench_mod, "bench_ubench",
+        lambda args: pytest.fail("measured without a chip"))
+    with pytest.raises(SystemExit) as exc:
+        bench_mod.main()
+    assert exc.value.code not in (0, None)
+    out, err = capsys.readouterr()
+    assert out == ""                     # no result, no number
+    assert "no chip, no number" in err
+    for gone in ("probe_tpu", "cpu_fallback_allowed",
+                 "tpu_init_postmortem", "tpu_env_details"):
+        assert not hasattr(bench_mod, gone)
 
 
-def test_tpu_init_postmortem_embeds_and_diagnoses(bench_mod, capsys):
-    """On tpu_init_error the BENCH json carries the flight-recorder
-    postmortem (probe timeline + env snapshot) and the doctor's
-    one-line diagnosis lands on stderr — CPU-fallback rounds carry
-    their stall evidence."""
-    import json as _json
-    tl = [{"attempt": 1, "timeout_s": 180.0, "t_s": 181.0,
-           "error": "jax.devices() did not return within 180s"}]
-    pm = bench_mod.tpu_init_postmortem(tl)
-    _json.dumps(pm)                      # BENCH json embeddable
-    assert pm["reason"] == "tpu_init_failed"
-    assert pm["probe_timeline"] == tl
-    assert "libtpu_importable" in pm["env"]
-    err = capsys.readouterr().err
-    assert "doctor: STALLED: TPU backend init failed" in err
+def test_results_carry_the_device_and_an_honest_unit(bench_mod):
+    """Every result names the device it ran on (platform, device_kind,
+    count), and only a TPU run is filed as msgs/sec/chip."""
+    dev, init_s = bench_mod.resolve_device("cpu")
+    assert dev["platform"] == "cpu" and dev["device_count"] >= 1
+    assert isinstance(dev["device_kind"], str) and init_s >= 0
+    assert bench_mod.unit_for(dev) == "msgs/sec/cpu-backend"
+    assert bench_mod.unit_for({"platform": "tpu"}) == "msgs/sec/chip"
 
 
 def test_tristate_parsing(bench_mod):
@@ -225,14 +227,13 @@ def test_tristate_parsing(bench_mod):
     assert bench_mod.tristate("0") is False
 
 
-def test_bench_kernel_smoke_block(bench_mod, monkeypatch):
+def test_bench_kernel_smoke_block(bench_mod):
     """The --kernel-smoke `kernel` block (PR 11): the same seeded world
     through the XLA window and the persistent megakernel must agree
     bit-for-bit, both variants must produce a timing, and the bandwidth
     diet must hit the ISSUE acceptance bar (ratio >= 1.8) on the
     smoke's clean-payload traffic. On CPU the kernel runs interpreted
     and the block says so."""
-    monkeypatch.delenv("PONY_TPU_MEGA_AUTO", raising=False)
     k = bench_mod.bench_kernel_smoke(_args(actors=16, ticks=4, fuse=2))
     assert k["equal_ok"], k["mismatched"]
     assert k["tick_ms"]["plan"] > 0
@@ -258,11 +259,20 @@ def test_bench_ubench_records_packed_bytes(bench_mod):
     assert 0.0 <= bm["escape_rate"] <= 1.0
 
 
-def test_cpu_fallback_policy(bench_mod, monkeypatch):
-    """--no-fallback beats the legacy env kill switch; default stays
-    allow (a degraded-but-recorded run beats no record at all)."""
-    monkeypatch.delenv("PONY_TPU_BENCH_ALLOW_CPU", raising=False)
-    assert bench_mod.cpu_fallback_allowed(False) is True
-    assert bench_mod.cpu_fallback_allowed(True) is False
-    monkeypatch.setenv("PONY_TPU_BENCH_ALLOW_CPU", "0")
-    assert bench_mod.cpu_fallback_allowed(False) is False
+def test_failed_phase_is_recorded_and_fails_the_process(bench_mod,
+                                                        capsys):
+    """A secondary phase that raises still records its error in its
+    JSON block, but lands in `failed` — main() then exits non-zero
+    after printing (an error never rides out under exit code 0)."""
+    failed = []
+
+    def boom(_args, delivery, fused):
+        raise RuntimeError(f"no {delivery}")
+
+    block = bench_mod.run_phase(failed, "telemetry", boom, None,
+                                delivery="plan", fused=False)
+    assert block == {"error": "RuntimeError: no plan"}
+    assert failed == ["telemetry"]
+    assert "RuntimeError: no plan" in capsys.readouterr().err
+    ok = bench_mod.run_phase(failed, "run_loop", lambda: {"x": 1})
+    assert ok == {"x": 1} and failed == ["telemetry"]

@@ -40,9 +40,8 @@ _WORKER = textwrap.dedent("""
                            jax.local_devices()[0])
     garr = jax.make_array_from_single_device_arrays(
         (len(devs),), sharding, [local])
-    from ponyc_tpu.compat import shard_map
     total = jax.jit(
-        shard_map(lambda x: jax.lax.psum(x, "actors"),
+        jax.shard_map(lambda x: jax.lax.psum(x, "actors"),
                       mesh=mesh, in_specs=P("actors"), out_specs=P()),
     )(garr)
     assert int(total[0]) == 3, total     # 1 + 2

@@ -343,24 +343,25 @@ def test_sigquit_dumps_and_continues(tmp_path):
     assert "flight-recorder postmortem" in p.stderr
 
 
-# -------------------------------------------------- probe postmortems
+# ------------------------------------------- backend-init stall verdict
 
-def test_probe_postmortem_and_diagnosis():
-    """The backend-init evidence bench.py embeds on tpu_init_error."""
-    tl = [{"attempt": 1, "timeout_s": 180.0, "t_s": 180.2,
-           "error": "jax.devices() did not return within 180s "
-                    "(backend init hang)"},
-          {"attempt": 2, "timeout_s": 300.0, "t_s": 12.0,
-           "error": "probe exited rc=1"}]
-    pm = flight.probe_postmortem(tl, {"env": {}, "libtpu_importable": False})
+def test_backend_init_stall_diagnosis():
+    """A backend init that never returns is diagnosed from the
+    watchdog's own postmortem — in-process evidence only: there is no
+    probe child and no probe postmortem any more (a program runs on
+    what JAX resolves, and fails if that fails)."""
+    assert not hasattr(flight, "probe_postmortem")
+    pm = {"version": flight.POSTMORTEM_VERSION,
+          "reason": "watchdog: phase 'backend-init' made no progress "
+                    "for 40.0s (deadline 30.0s)",
+          "phase": {"name": "backend-init", "epoch": 0, "age_s": 40.0},
+          "steps_run": 0, "env": {"env": {}, "libtpu_importable": True}}
     json.dumps(pm)                              # must serialise
-    assert pm["reason"] == "tpu_init_failed"
-    assert pm["phase"]["name"] == "backend-init"
-    assert pm["probe_timeline"] == tl
     line, detail = flight.diagnose_postmortem(pm)
-    assert line.startswith("STALLED: TPU backend init failed after 2")
-    assert "probe exited rc=1" in line
-    assert "attempt 1" in detail
+    assert line.startswith("STALLED: watchdog: phase 'backend-init'")
+    assert "another process holding the chip" in line
+    assert "probe" not in line and "probe" not in detail
+    assert "libtpu_importable=True" in detail
 
 
 # ----------------------------------------------------------- doctor CLI
@@ -388,18 +389,18 @@ def test_doctor_cli_postmortem(tmp_path, capsys):
     assert capsys.readouterr().out.startswith("STALLED")
 
 
-def test_doctor_cli_bench_json_wrapper(tmp_path, capsys):
-    """`doctor --postmortem BENCH.json` reads the nested probe
-    evidence a CPU-fallback bench round embeds."""
+def test_doctor_cli_refuses_bench_json(tmp_path, capsys):
+    """`doctor --postmortem` reads flight-recorder postmortems only: a
+    bench result json (which no longer embeds one — bench.py exits
+    non-zero without a chip instead of recording a fallback) is a
+    usage error, not a diagnosis."""
     from ponyc_tpu.__main__ import main as cli_main
-    tl = [{"attempt": 1, "timeout_s": 60.0, "t_s": 60.0,
-           "error": "backend init hang"}]
     bench_json = {"metric": "x", "value": 1,
-                  "postmortem": flight.probe_postmortem(tl, {"env": {}})}
-    path = str(tmp_path / "BENCH_r99.json")
+                  "postmortem": {"reason": "tpu_init_failed"}}
+    path = str(tmp_path / "bench.json")
     json.dump(bench_json, open(path, "w"))
-    assert cli_main(["doctor", "--postmortem", path]) == 1
-    assert "TPU backend init failed" in capsys.readouterr().out
+    assert cli_main(["doctor", "--postmortem", path]) == 2
+    assert "not a ponyc_tpu postmortem" in capsys.readouterr().err
 
 
 def test_doctor_cli_usage_errors(tmp_path):

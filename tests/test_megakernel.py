@@ -15,7 +15,7 @@ Three layers under test, matching the tentpole:
 
 The full differential/FIFO corpora also run the kernel via their
 pallas-mega configs (test_differential.py / test_fifo.py); this file
-owns the codec edges, the forced-window spelling, and the fallbacks.
+owns the codec edges, the forced-window spelling, and the refusals.
 """
 
 import os
@@ -29,7 +29,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import jax
 import jax.numpy as jnp
 
-from ponyc_tpu import RuntimeOptions, serialise
+from ponyc_tpu import Runtime, RuntimeOptions, serialise
 from ponyc_tpu.models import ubench
 from ponyc_tpu.ops import megakernel
 from ponyc_tpu.runtime import engine
@@ -190,46 +190,53 @@ def test_run_loop_end_to_end_with_mega():
     assert totals["plan"] == totals["pallas_mega"] > 0
 
 
-# ============================================== eligibility + fallbacks
+# ================================================ refusals, out loud
 
-def test_sharded_world_falls_back_to_xla():
+def test_sharded_world_refuses_mega_at_start():
     """mesh_shards > 1 is outside the kernel's single-shard contract:
-    eligible() is False and the engine silently runs the XLA plan
-    formulation — same answers, no crash."""
-    okw = dict(mailbox_cap=4, batch=2, max_sends=1, msg_words=1,
-               spill_cap=256, inject_slots=16, mesh_shards=4,
-               quiesce_interval=2)
-    rt, ids = ubench.build(16, _opts(**okw, delivery="pallas_mega"),
-                           pings=2)
-    assert not megakernel.eligible(rt.program, rt.opts)
-    ubench.seed_all(rt, ids, hops=40, pings=2)
-    assert rt.run() == 0
-    rt2, ids2 = ubench.build(16, _opts(**okw), pings=2)
-    ubench.seed_all(rt2, ids2, hops=40, pings=2)
-    assert rt2.run() == 0
-    assert rt.counter("n_processed") == rt2.counter("n_processed") > 0
+    an explicit delivery="pallas_mega" raises at start(), with the
+    reason — it never runs the XLA plan formulation under the
+    megakernel's name."""
+    rt = Runtime(_opts(mailbox_cap=4, batch=2, max_sends=1, msg_words=1,
+                       spill_cap=256, inject_slots=16, mesh_shards=4,
+                       quiesce_interval=2, delivery="pallas_mega"))
+    rt.declare(ubench.Pinger, 16)
+    with pytest.raises(ValueError, match="pallas_mega.*mesh_shards=4"):
+        rt.start()
 
 
-def test_explicit_pallas_kernels_exclude_mega():
-    """pallas=True / pallas_fused=True force the PR-era per-pass
-    kernels; the megakernel declines rather than nesting pallas_call
-    inside its staged window."""
-    rt, _ = ubench.build(8, _opts(pallas_fused=True), pings=1)
+def test_explicit_pallas_kernels_refuse_mega():
+    """pallas=True / pallas_fused=True with delivery="pallas_mega"
+    would nest a pallas_call inside the staged window: refused at
+    start(), naming the nesting."""
+    for kernel in ("pallas", "pallas_fused"):
+        rt = Runtime(_opts(delivery="pallas_mega", **{kernel: True}))
+        rt.declare(ubench.Pinger, 8)
+        with pytest.raises(ValueError, match=f"{kernel}=True would nest"):
+            rt.start()
+
+
+def test_mega_is_refused_on_tpu_with_mosaics_words(monkeypatch):
+    """The kernel does not lower on v5e (jax 0.9.0): wherever
+    interpret mode is off, refusal() is Mosaic's recorded reason, the
+    explicit request raises it at start(), and the window builders
+    refuse too (no quiet XLA spelling behind them)."""
+    rt, _ = ubench.build(8, _opts(), pings=1)
+    assert megakernel.refusal(rt.program, rt.opts) is None   # CPU: ok
+    monkeypatch.setattr(megakernel, "interpret_mode", lambda: False)
+    reason = megakernel.refusal(rt.program, rt.opts)
+    assert "does not lower on TPU" in reason
+    assert megakernel.MOSAIC_REFUSAL in reason
+    rt2 = Runtime(_opts(delivery="pallas_mega"))
+    rt2.declare(ubench.Pinger, 8)
+    with pytest.raises(ValueError, match="vector types must have"):
+        rt2.start()
     import dataclasses
     mega_opts = dataclasses.replace(rt.opts, delivery="pallas_mega")
-    assert not megakernel.eligible(rt.program, mega_opts)
-
-
-def test_auto_enumeration_is_env_gated(monkeypatch):
-    """On CPU the megakernel joins delivery=auto candidates only under
-    PONY_TPU_MEGA_AUTO=1 (bench.py sets it; the unit suite's many
-    auto-starts stay lean without it)."""
-    rt, _ = ubench.build(8, _opts(), pings=1)
-    monkeypatch.delenv("PONY_TPU_MEGA_AUTO", raising=False)
-    if jax.default_backend() != "tpu":
-        assert not megakernel.auto_enumerable(rt.program, rt.opts)
-    monkeypatch.setenv("PONY_TPU_MEGA_AUTO", "1")
-    assert megakernel.auto_enumerable(rt.program, rt.opts)
+    for build in (engine.build_multi_step_gated,
+                  engine.build_forced_window):
+        with pytest.raises(ValueError, match="does not lower on TPU"):
+            build(rt.program, mega_opts)
 
 
 def test_delivery_option_validation():

@@ -141,7 +141,7 @@ def _deadlocked_pair(mute_age_limit):
     congested) muting ref. No release path exists except aging: each
     muter's occ stays above unmute_occ because the muter itself is
     muted and can never run to drain — the mute-cycle deadlock class
-    the round-2 differential hunt found (ROUND3_NOTES.md), which the
+    the round-2 differential hunt (tests/hunt.py) found, which the
     reference's pre-0.36 backpressure shares.
 
     Live sends can't assemble this state directly (the reference's

@@ -498,12 +498,12 @@ print("SURVIVED-SIGTERM")
 
 
 def test_window_constants_ride_optimization_barrier():
-    """Compile-time regression guard (PR 11 satellite, BENCH_r05): the
-    gated window's loop-invariant operands (injections, limit, force
-    bit) must sit behind lax.optimization_barrier in the lowered HLO.
-    Without it XLA constant-folds them INTO the while body and the
-    r05-style constant-propagation sweep re-runs per window compile —
-    the multi-minute stall BENCH_r05 recorded. The barrier's presence
+    """Compile-time regression guard (PR 11 satellite): the gated
+    window's loop-invariant operands (injections, limit, force bit)
+    must sit behind lax.optimization_barrier in the lowered HLO.
+    Without it XLA constant-folds them INTO the while body and a
+    constant-propagation sweep re-runs per window compile — the stall
+    an early bench run's tail recorded. The barrier's presence
     in the StableHLO text is the cheapest stable proxy for "the hoist
     survived lowering"."""
     import jax

@@ -97,30 +97,29 @@ def test_variants_auto_delivery_baseline_first():
     assert names == ["plan", "cosort"]
 
 
-def test_variants_auto_enumerates_megakernel_when_gated_on(monkeypatch):
-    """PR 11: with PONY_TPU_MEGA_AUTO=1 (bench.py sets it) delivery=auto
-    races the window megakernel too — as a pure-delivery variant, never
-    combined with the per-pass pallas kernels it replaces."""
-    monkeypatch.setenv("PONY_TPU_MEGA_AUTO", "1")
-    rt = Runtime(_ub_opts(delivery="auto"))
+def test_variants_auto_never_enumerates_megakernel():
+    """The window megakernel does not lower on TPU (ops/megakernel.py)
+    and on CPU only runs interpreted: delivery="auto" never races it,
+    on any backend, under any environment."""
+    rt = Runtime(_ub_opts(delivery="auto", pallas="auto",
+                          pallas_fused="auto"))
     rt.declare(ubench.Pinger, 8)
     rt.program.finalize()
     vs = tuning.variants(rt.program, rt.opts)
-    assert [n for n, _ in vs] == ["plan", "cosort", "pallas_mega"]
-    mega = dict(vs)["pallas_mega"]
-    assert mega == {"delivery": "pallas_mega", "pallas": False,
-                    "pallas_fused": False}
+    assert len(vs) == 8                       # 2 deliveries x 2 x 2
+    assert all(ov["delivery"] in ("plan", "cosort") for _n, ov in vs)
+    assert not hasattr(tuning, "mega_eligible")
 
 
-def test_tuning_key_version_pinned_v2():
+def test_tuning_key_version_pinned_v3():
     """The cache-key version must be bumped whenever the variant space
-    changes (v2: pallas_mega joined) — a stale v1 record transferring a
-    two-way decision into the three-way race would silently skip the
-    megakernel forever. Pin it so the bump is a conscious act."""
+    changes (v3: pallas_mega left it) — a stale v2 record naming the
+    megakernel the winner would be refused at start(). Pin it so the
+    bump is a conscious act."""
     rt = Runtime(_ub_opts(delivery="auto"))
     rt.declare(ubench.Pinger, 8)
     rt.program.finalize()
-    assert tuning.tuning_key(rt.program, rt.opts)["v"] == 2
+    assert tuning.tuning_key(rt.program, rt.opts)["v"] == 3
 
 
 def test_variants_fused_auto_skips_ineligible_programs():
@@ -263,3 +262,129 @@ def test_host_only_program_skips_calibration():
     rt.declare(H, 4)
     rt.start()                      # must not raise, must resolve
     assert rt.opts.delivery in ("plan", "cosort")
+
+
+# ---------------------------------------------------------------------------
+# explicit kernels never give way silently
+
+
+def test_explicit_kernel_that_cannot_run_raises_at_start():
+    """pallas=True on a cohort the drain kernel cannot tile, and
+    pallas_fused=True on a cohort the fused kernel cannot host, raise
+    at start() naming the cohort and the reason — they never run the
+    XLA path under the kernel's name. (delivery="pallas_mega":
+    tests/test_megakernel.py.) "auto" skips the same variants without
+    raising."""
+    from ponyc_tpu.ops import mailbox_kernel as mk
+    unaligned = mk.LANE_BLOCK + 8       # > one block, not a multiple
+
+    for kernel in ("pallas", "pallas_fused"):
+        rt = Runtime(_ub_opts(**{kernel: True}))
+        rt.declare(ubench.Pinger, unaligned)
+        with pytest.raises(ValueError) as exc:
+            rt.start()
+        msg = str(exc.value)
+        assert f"{kernel}=True cannot be honoured" in msg
+        assert "cohort Pinger" in msg and str(unaligned) in msg
+
+        rt = Runtime(_ub_opts(**{kernel: "auto"}))
+        rt.declare(ubench.Pinger, unaligned)
+        rt.start()                      # auto: skipped, not an error
+        assert getattr(rt.opts, kernel) is False
+
+    @actor
+    class BlobUser:
+        n: I32
+        MAX_BLOBS = 1
+
+        @behaviour
+        def grab(self, st):
+            self.blob_alloc(length=1)
+            return st
+
+    rt = Runtime(_ub_opts(pallas_fused=True, msg_words=2, blob_slots=8,
+                          blob_words=4))
+    rt.declare(BlobUser, 8)
+    with pytest.raises(ValueError, match="cohort BlobUser: uses the "
+                                         "device blob pool"):
+        rt.start()
+
+
+def test_calibration_failure_is_said_once_on_stderr(capsys, monkeypatch):
+    """A variant that fails to build is out of the race AND named on
+    stderr; if none produced a timing, the unmeasured baseline default
+    is said too — never swallowed."""
+    from ponyc_tpu.runtime import engine
+
+    def refuse(program, opts, mesh=None):
+        raise NotImplementedError(f"no lowering for {opts.delivery}")
+
+    monkeypatch.setattr(engine, "jit_forced_window", refuse)
+    rt = Runtime(_ub_opts(delivery="auto"))
+    rt.declare(ubench.Pinger, 8)
+    rt.start()
+    assert rt.opts.delivery == "plan"
+    assert rt.tuning_record["table"] == {"plan": None, "cosort": None}
+    err = capsys.readouterr().err
+    assert err.count("variant 'plan' failed") == 1
+    assert err.count("variant 'cosort' failed") == 1
+    assert "no lowering for cosort" in err
+    assert "running the baseline 'plan' unmeasured" in err
+
+
+# ---------------------------------------------------------------------------
+# a compile cache that can be placed from outside
+
+
+@pytest.fixture()
+def restore_jax_cache_config():
+    import jax
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    yield
+    for k, v in saved.items():
+        jax.config.update(k, v)
+
+
+def test_compile_cache_leaves_an_outside_directory_alone(
+        monkeypatch, tmp_path, restore_jax_cache_config):
+    """JAX_COMPILATION_CACHE_DIR set => the code sets no directory at
+    all (jax reads the variable itself; here it was set after import,
+    so jax.config must still hold whatever it held)."""
+    import jax
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("PONY_TPU_COMPILE_CACHE_FORCE", "1")
+    assert tuning.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    assert tuning.enable_compile_cache("off") is None
+
+
+def test_compile_cache_default_is_one_fixed_path_in_the_checkout(
+        monkeypatch, restore_jax_cache_config):
+    """Unset => one fixed path under the checkout (git-ignored
+    .cache/), shared with the tuning-decision cache: never ~, a temp
+    name, a pid or a time. Path-valued options are gone."""
+    import os
+    import jax
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("PONY_TPU_TUNING_CACHE", raising=False)
+    # The CPU guard (jaxlib 0.9.0 still deadlocks a reloaded meshed
+    # executable's collectives): off here unless forced.
+    monkeypatch.delenv("PONY_TPU_COMPILE_CACHE_FORCE", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    assert tuning.enable_compile_cache() is None
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.setenv("PONY_TPU_COMPILE_CACHE_FORCE", "1")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(root, ".cache", "ponyc_tpu", "xla")
+    assert tuning.enable_compile_cache() == want
+    assert tuning.enable_compile_cache() == want        # idempotent
+    assert jax.config.jax_compilation_cache_dir == want
+    assert tuning.tuning_cache_dir(RuntimeOptions()) \
+        == os.path.join(root, ".cache", "ponyc_tpu", "tuning")
+    assert not hasattr(tuning, "compile_cache_dir")
+    with pytest.raises(ValueError, match="compile_cache"):
+        RuntimeOptions(compile_cache="/tmp/somewhere")
