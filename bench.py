@@ -267,14 +267,6 @@ def bench_ubench(args):
                     1e3 * elapsed / ticks, 4)
         except Exception as e:                   # noqa: BLE001
             measured = {"error": str(e)}
-    if getattr(args, "xprof", 0):
-        # --xprof N: wrap N retired fused windows in a jax.profiler
-        # trace for op-level device wall attribution.
-        try:
-            measured["xprof_trace"] = rt.profile_device(
-                windows=args.xprof, ticks=K)
-        except Exception as e:                   # noqa: BLE001
-            measured["xprof_error"] = str(e)
     return {
         "measured": measured,
         "packed_bytes_per_msg": bytes_model["packed_bytes"],
@@ -922,11 +914,6 @@ def main():
                     "`serving` block — p50/p99 end-to-end latency of "
                     "admitted requests, shed rate, goodput, and the "
                     "rings-never-sticky-fail check")
-    ap.add_argument("--xprof", type=int, default=int(os.environ.get(
-                        "PONY_TPU_BENCH_XPROF", 0)), metavar="N",
-                    help="wrap N retired fused windows in a "
-                    "jax.profiler trace (Runtime.profile_device) and "
-                    "record the trace dir in the `measured` block")
     ap.add_argument("--skip-measured", action="store_true",
                     help="skip the measured cost capture (dev "
                     "iteration only — runs for the record keep it; "
@@ -959,7 +946,7 @@ def main():
 
     failed = []          # secondary phases that raised (run_phase)
     ub = bench_ubench(args)
-    if "error" in ub["measured"] or "xprof_error" in ub["measured"]:
+    if "error" in ub["measured"]:
         failed.append("measured")
     form = dict(delivery=ub["delivery"], fused=ub["pallas_fused"])
     lat = bench_latency(args, **form)

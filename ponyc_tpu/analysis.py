@@ -363,7 +363,11 @@ class Analysis:
                 f"shrinks={c['shrinks']} windows={rl['windows']} "
                 f"pipelined={rl['pipelined_dispatches']}"
                 f"/{rl['pipelined_dispatches'] + rl['sync_dispatches']} "
-                f"host_gap_ms={rl['host_gap_us_total'] / 1e3:.2f}")
+                f"host_gap_ms={rl['host_gap_us_total'] / 1e3:.2f} "
+                f"windows_wall_ms={rl['windows_wall_s'] * 1e3:.2f}")
+            lines.append("run_loop phase_ms " + " ".join(
+                f"{k}={v * 1e3:.2f}"
+                for k, v in rl["phase_s"].items() if v))
         if self.level >= 3 and rt.state is not None:
             lines.append(
                 f"events_pending={int(np.asarray(rt.state.ev_count).sum())} "

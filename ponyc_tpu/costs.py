@@ -27,9 +27,11 @@ cost accounting attributed per construct rather than per opaque binary:
   so (a silent model is how three rounds of A/B machinery rotted).
 
 The ``measured`` block these compose (``measured_block(rt)``) rides
-every BENCH json next to the modelled bytes/msg, and ``bench.py
---xprof`` / ``Runtime.profile_device(windows=N)`` wrap real retired
-windows in a ``jax.profiler`` trace for op-level wall attribution.
+every BENCH json next to the modelled bytes/msg. For device wall time
+by operation, wrap ``Runtime.run()`` in ``jax.profiler.trace(dir)``:
+the tick's named scopes (``pony/<phase>``) and the run loop's
+``pony:*`` spans come with it (``benchmarks/phase_trace.py`` reads
+them).
 """
 
 from __future__ import annotations

@@ -45,6 +45,7 @@ import os
 import sys
 import threading
 import time
+import weakref
 from typing import Any, Dict, Optional, Tuple
 
 POSTMORTEM_VERSION = 1
@@ -65,6 +66,18 @@ ARMED_PHASES = frozenset({"backend-init", "dispatching", "in-flight",
 # 1M-actor window on a v5e, PERF.md) and must not read as a stall
 # under a deadline sized for steady-state windows.
 COLD_FACTOR = 10.0
+
+
+_latest: Optional["weakref.ref[FlightRecorder]"] = None
+
+
+def latest() -> Optional["FlightRecorder"]:
+    """The recorder of the runtime most recently started in this
+    process, or None (none started, or that runtime is gone: the
+    reference is weak and keeps nothing alive). For a signal handler, a
+    REPL or a benchmark reader that must reach the black box without
+    holding `rt`."""
+    return _latest() if _latest is not None else None
 
 
 def env_snapshot() -> Dict[str, Any]:
@@ -103,17 +116,29 @@ class FlightRecorder:
         self.t0 = time.time()
         self.last_dump: Optional[str] = None    # newest postmortem path
         self.dumps = 0
+        global _latest
+        _latest = weakref.ref(self)
 
     # -- recording (hot-ish path: host ints only, one deque append) --
     def window(self, step: int, ticks: int, budget: int, gap_us: float,
-               pipelined: bool, aux) -> None:
+               pipelined: bool, aux, wall_ms: float = 0.0,
+               wait_ms: float = 0.0, since_prev_ms: float = 0.0) -> None:
         """One retired window's facts. `aux` is the already-fetched
         host-side StepAux (numpy scalars) — the recorder converts, the
-        run loop pays no extra device traffic."""
+        run loop pays no extra device traffic. `wall_ms`: dispatch start
+        to retire returned; `wait_ms`: the part of it the host was
+        blocked on the device (`pony:wait`); `since_prev_ms`: the
+        previous record's retire to this dispatch's start, across run()
+        calls too. A pipelined window has since_prev_ms 0 and a wall_ms
+        counted from the previous retire, so since_prev_ms + wall_ms of
+        consecutive records tile the wall clock."""
         self.windows.append({
             "t_ms": round((time.time() - self.t0) * 1e3, 3),
             "step": int(step), "ticks": int(ticks),
             "budget": int(budget), "gap_us": round(float(gap_us), 1),
+            "wall_ms": round(float(wall_ms), 4),
+            "wait_ms": round(float(wait_ms), 4),
+            "since_prev_ms": round(float(since_prev_ms), 4),
             "pipelined": bool(pipelined),
             "processed": int(aux.n_processed) & 0xFFFFFFFF,
             "delivered": int(aux.n_delivered) & 0xFFFFFFFF,
@@ -381,9 +406,13 @@ def render_postmortem(pm: Dict[str, Any]) -> str:
     if wins:
         lines.append(f"last {len(wins)} windows (newest last):")
         for w in wins[-8:]:
+            # wall/wait/since_prev: absent before ISSUE 24's records
+            clock = ("" if "wall_ms" not in w else
+                     f"wall={w['wall_ms']}ms wait={w.get('wait_ms')}ms "
+                     f"since_prev={w.get('since_prev_ms')}ms ")
             lines.append(
                 f"  step={w['step']} ticks={w['ticks']}/{w['budget']} "
-                f"gap={w['gap_us']}us occ={w['occ_sum']} "
+                f"gap={w['gap_us']}us {clock}occ={w['occ_sum']} "
                 f"qw_p99={w['qw_p99']} flags={_fmt_flags(w['flags'])}")
     srv = pm.get("serving")
     if srv:

@@ -301,6 +301,13 @@ def prometheus_text(snap: Dict[str, Any],
         fam("pony_tpu_host_gap_us_total", "counter",
             "Cumulative host-imposed device idle (us)",
             [(None, round(rl.get("host_gap_us_total", 0.0), 1))])
+        fam("pony_tpu_windows_wall_seconds_total", "counter",
+            "Cumulative wall clock of retired windows (dispatch start "
+            "to retire)", [(None, round(rl.get("windows_wall_s", 0.0), 6))])
+        fam("pony_tpu_run_phase_seconds_total", "counter",
+            "Seconds run() spent in each run-loop phase (self time)",
+            [({"phase": k}, round(v, 6))
+             for k, v in sorted((rl.get("phase_s") or {}).items())])
         ctrl = rl.get("controller")
         if ctrl:
             fam("pony_tpu_window_length", "gauge",

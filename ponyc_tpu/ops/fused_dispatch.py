@@ -298,6 +298,7 @@ def build_fused_dispatch(bdefs, *, base_gid: int, field_names: Sequence[str],
         outs = pl.pallas_call(
             kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
             out_shape=out_shape, interpret=interpret,
+            name="pony_dispatch",
         )(head[None, :], n_run[None, :], ids[None, :],
           *[f[None, :] for f in fields], buf, *resv)
         new_fields = tuple(outs[i][0] for i in range(nf))

@@ -79,6 +79,7 @@ def drain_msgs(buf, head, n_run, *, batch: int, interpret: bool = False):
             jax.ShapeDtypeStruct((batch, n), jnp.int32),
         ],
         interpret=interpret,
+        name="pony_drain",
     )(head[None, :], n_run[None, :], buf)
     return msgs, valid.astype(jnp.bool_)
 

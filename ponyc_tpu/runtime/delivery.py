@@ -54,6 +54,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.segment import compact_mask, stable_sort_by
+from .state import phase_scope
 
 
 class Entries(NamedTuple):
@@ -194,14 +195,16 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
         perm = jnp.arange(e, dtype=jnp.int32)
         bounds = jnp.zeros((n + 1,), jnp.int32)
     elif plan is None:
-        perm, bounds = _compute_plan(key)
+        with phase_scope("delivery/plan"):
+            perm, bounds = _compute_plan(key)
     else:
         plan_key, plan_perm, plan_bounds = plan
-        perm, bounds = lax.cond(
-            jnp.all(key == plan_key),
-            lambda _: (plan_perm, plan_bounds),
-            lambda _: _compute_plan(key),
-            operand=None)
+        with phase_scope("delivery/plan"):
+            perm, bounds = lax.cond(
+                jnp.all(key == plan_key),
+                lambda _: (plan_perm, plan_bounds),
+                lambda _: _compute_plan(key),
+                operand=None)
 
     def _empty_spill():
         refs, ovf = empty_mute_slots(n, mute_slots)
@@ -216,17 +219,21 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
     # it exists, README.md:8-10 — a waiting scheduler must cost ~nothing).
     def with_msgs(_):
         if cosort:
-            ops = lax.sort((key, tgt, sender) + tuple(words),
-                           num_keys=1, is_stable=True)
-            key_s, tgt_s, snd_s = ops[0], ops[1], ops[2]
-            wds = jnp.stack(ops[3:])
-            seg_bounds = _bounds(key_s)
-            kt = jnp.where(key_s < n * n_levels, tgt_s, n).astype(jnp.int32)
+            with phase_scope("delivery/plan"):
+                ops = lax.sort((key, tgt, sender) + tuple(words),
+                               num_keys=1, is_stable=True)
+                key_s, tgt_s, snd_s = ops[0], ops[1], ops[2]
+                seg_bounds = _bounds(key_s)
+            with phase_scope("delivery/permute"):
+                wds = jnp.stack(ops[3:])
+                kt = jnp.where(key_s < n * n_levels, tgt_s,
+                               n).astype(jnp.int32)
         else:
             snd_s = None
             seg_bounds = bounds
-            kt = jnp.where(valid, tgt, n).astype(jnp.int32)[perm]
-            wds = words[:, perm]                 # [w1, E] sorted
+            with phase_scope("delivery/permute"):
+                kt = jnp.where(valid, tgt, n).astype(jnp.int32)[perm]
+                wds = words[:, perm]                 # [w1, E] sorted
         ktc = jnp.minimum(kt, n - 1)
         seg_start = seg_bounds[:-1]              # [n]
         cnt = seg_bounds[1:] - seg_start         # [n] msgs per target
@@ -241,32 +248,34 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
         # [w1_c, cap*rows_c] — a narrow type's rebuild never moves the
         # widest type's words (the HBM win of per-cohort widths). Within
         # a cohort all planes' indices still concatenate into ONE gather.
-        rels = (jnp.arange(c, dtype=jnp.int32)[:, None]
-                - tail[None, :]) % c                 # [cap, n]
-        wmasks = rels < acc[None, :]
-        srcs = jnp.minimum(seg_start[None, :] + rels, e - 1)
-        buf2 = {}
-        for cname, s0, s1, w1c in cohort_layout:
-            nn = s1 - s0
-            pulled = jnp.take(wds[:w1c], srcs[:, s0:s1].reshape(c * nn),
-                              axis=1).reshape(w1c, c, nn)
-            buf2[cname] = jnp.where(wmasks[:, None, s0:s1],
-                                    pulled.transpose(1, 0, 2),
-                                    buf[cname])
-        # Trace side lanes (causal tracing on): the trailing two word
-        # rows land in trace_buf through the SAME (mask, source) pair
-        # as the payload — context and message are inseparable.
-        tbuf2 = {}
-        if trace_buf is not None:
-            w1f = wds.shape[0]
-            for cname, s0, s1, _w1c in cohort_layout:
+        with phase_scope("delivery/rebuild"):
+            rels = (jnp.arange(c, dtype=jnp.int32)[:, None]
+                    - tail[None, :]) % c                 # [cap, n]
+            wmasks = rels < acc[None, :]
+            srcs = jnp.minimum(seg_start[None, :] + rels, e - 1)
+            buf2 = {}
+            for cname, s0, s1, w1c in cohort_layout:
                 nn = s1 - s0
-                pulled = jnp.take(wds[w1f - 2:],
+                pulled = jnp.take(wds[:w1c],
                                   srcs[:, s0:s1].reshape(c * nn),
-                                  axis=1).reshape(2, c, nn)
-                tbuf2[cname] = jnp.where(wmasks[:, None, s0:s1],
-                                         pulled.transpose(1, 0, 2),
-                                         trace_buf[cname])
+                                  axis=1).reshape(w1c, c, nn)
+                buf2[cname] = jnp.where(wmasks[:, None, s0:s1],
+                                        pulled.transpose(1, 0, 2),
+                                        buf[cname])
+            # Trace side lanes (causal tracing on): the trailing two word
+            # rows land in trace_buf through the SAME (mask, source) pair
+            # as the payload — context and message are inseparable.
+            tbuf2 = {}
+            if trace_buf is not None:
+                w1f = wds.shape[0]
+                for cname, s0, s1, _w1c in cohort_layout:
+                    nn = s1 - s0
+                    pulled = jnp.take(wds[w1f - 2:],
+                                      srcs[:, s0:s1].reshape(c * nn),
+                                      axis=1).reshape(2, c, nn)
+                    tbuf2[cname] = jnp.where(wmasks[:, None, s0:s1],
+                                             pulled.transpose(1, 0, 2),
+                                             trace_buf[cname])
 
         n_delivered = jnp.sum(acc)
         nrej = jnp.sum(cnt - acc)
@@ -312,14 +321,16 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
                                        n=n, k=mute_slots)
             return spill, newly_muted, refs, ovf
 
-        any_pressure = (nrej > 0) | jnp.any(occ_after > overload_occ)
-        if pressured is not None:
-            # Only when a send actually TARGETS a pressured receiver —
-            # an unrelated actor's long-lived pressure (a stalled socket)
-            # must not make every tick pay the pressure branch.
-            any_pressure = any_pressure | jnp.any(pressured[ktc] & (kt < n))
-        spill, newly_muted, new_refs, new_ovf = lax.cond(
-            any_pressure, pressure, lambda _: _empty_spill(), operand=None)
+        with phase_scope("delivery/pressure"):
+            any_pressure = (nrej > 0) | jnp.any(occ_after > overload_occ)
+            if pressured is not None:
+                # Only when a send actually TARGETS a pressured receiver —
+                # an unrelated actor's long-lived pressure (a stalled socket)
+                # must not make every tick pay the pressure branch.
+                any_pressure = any_pressure | jnp.any(
+                    pressured[ktc] & (kt < n))
+            spill, newly_muted, new_refs, new_ovf = lax.cond(
+                any_pressure, pressure, lambda _: _empty_spill(), operand=None)
         return (buf2, tbuf2, new_tail, spill, newly_muted, new_refs,
                 new_ovf, n_delivered, nrej)
 

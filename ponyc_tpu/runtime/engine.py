@@ -45,7 +45,8 @@ from ..ops import pack
 from ..ops.segment import compact_mask, counts_by_key, stable_sort_by
 from ..program import Cohort, Program
 from .delivery import (Entries, deliver, empty_mute_slots, mute_ref_slots)
-from .state import PHASE_NAMES, QW_BUCKETS, RtState, layout_sizes
+from .state import (PHASE_NAMES, QW_BUCKETS, PhaseCursor, RtState,
+                    layout_sizes, phase_scope)
 
 
 class StepAux(NamedTuple):
@@ -914,15 +915,17 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
             if opts.pallas:          # gate BEFORE importing pallas/mosaic
                 from ..ops import mailbox_kernel as mk
                 _honour("pallas=True", mk.refusal(cohort))
-                msgs, valids = mk.drain_msgs(
-                    buf_rows, head_rows, n_run, batch=batch,
-                    interpret=mk.interpret_mode())
+                with phase_scope("drain"):
+                    msgs, valids = mk.drain_msgs(
+                        buf_rows, head_rows, n_run, batch=batch,
+                        interpret=mk.interpret_mode())
             else:
-                msgs = jnp.stack(
-                    [_ring_take(buf_rows, (head_rows + k) % cap)
-                     for k in range(batch)])            # [batch, w1, rows]
-                valids = (jnp.arange(batch, dtype=jnp.int32)[:, None]
-                          < n_run[None, :])             # [batch, rows]
+                with phase_scope("drain"):
+                    msgs = jnp.stack(
+                        [_ring_take(buf_rows, (head_rows + k) % cap)
+                         for k in range(batch)])        # [batch, w1, rows]
+                    valids = (jnp.arange(batch, dtype=jnp.int32)[:, None]
+                              < n_run[None, :])         # [batch, rows]
             z = lambda d: jnp.zeros((rows,), d)         # noqa: E731
             if use_blob:
                 blb0 = (blob["data"], blob["used"], blob["len"],
@@ -1259,6 +1262,14 @@ def build_step(program: Program, opts: RuntimeOptions):
 
     def local_step(st: RtState, inject_tgt, inject_words
                    ) -> Tuple[RtState, StepAux]:
+        with PhaseCursor() as phase:
+            return tick(st, inject_tgt, inject_words, phase)
+
+    def tick(st: RtState, inject_tgt, inject_words, phase: PhaseCursor
+             ) -> Tuple[RtState, StepAux]:
+        # `phase(name)` opens the named scope `pony/<name>` for what is
+        # traced from there to the next call (state.PhaseCursor).
+        phase("unmute")
         if p > 1:
             shard = lax.axis_index("actors").astype(jnp.int32)
         else:
@@ -1442,6 +1453,7 @@ def build_step(program: Program, opts: RuntimeOptions):
             jnp.any(st.muted), unmute_pass,
             lambda _: (st.muted, st.mute_refs, st.mute_ovf), operand=None)
 
+        phase("spawn")
         # --- 1b. spawn reservations (≙ pony_create's slot allocation,
         # actor.c:688-734, done ahead of dispatch): per spawn-target
         # cohort, compact this shard's free rows (dead, drained, no stale
@@ -1563,6 +1575,7 @@ def build_step(program: Program, opts: RuntimeOptions):
                    + jnp.arange(sites, dtype=jnp.int32)[None, :, None])
             handles = jnp.take(free_blob, idx, mode="fill", fill_value=-1)
             return jnp.where(run_c[None, None, :], handles, jnp.int32(-1))
+        phase("dispatch")
         new_type_state: Dict[str, Dict[str, Any]] = dict(st.type_state)
         head_segments: List[jnp.ndarray] = []
         out_entries: List[Entries] = []
@@ -1582,17 +1595,19 @@ def build_step(program: Program, opts: RuntimeOptions):
         for run_cohort, ch in dispatchers:
             s0, s1 = ch.local_start, ch.local_stop
             ids = base + s0 + jnp.arange(ch.local_capacity, dtype=jnp.int32)
-            if blob_en and ch.uses_blobs:
-                blobd = {"data": blob_cur[0], "used": blob_cur[1],
-                         "len": blob_cur[2], "gen": blob_cur[3],
-                         "base": bbase, "resv": cohort_blob_resv(ch)}
-            else:
-                blobd = None
+            with phase_scope("spawn"):
+                resv = cohort_resv(ch)
+                if blob_en and ch.uses_blobs:
+                    blobd = {"data": blob_cur[0], "used": blob_cur[1],
+                             "len": blob_cur[2], "gen": blob_cur[3],
+                             "base": bbase, "resv": cohort_blob_resv(ch)}
+                else:
+                    blobd = None
             (stf, out, new_head_rows, ef, ec, nproc, nbad, claims, inits,
              sfail, dstr, errs, blob_out) = run_cohort(
                 st.type_state[ch.atype.__name__],
                 st.buf[ch.atype.__name__], st.head[s0:s1], occ0[s0:s1],
-                runnable[s0:s1], ids, cohort_resv(ch), blob=blobd)
+                runnable[s0:s1], ids, resv, blob=blobd)
             if blob_out is not None:
                 blob_cur = blob_out[:4]
                 blob_fail = blob_fail | blob_out[4]
@@ -1621,6 +1636,7 @@ def build_step(program: Program, opts: RuntimeOptions):
         new_head = (jnp.concatenate(head_segments) if head_segments
                     else st.head)
 
+        phase("spawn")
         # --- 2b. apply spawn claims (before delivery, so constructor
         # messages and same-step sends to the newborn land): claimed rows
         # become alive with a fresh empty mailbox and zeroed state fields
@@ -1676,6 +1692,7 @@ def build_step(program: Program, opts: RuntimeOptions):
         # dispatch that emitted each entry; spills, routing and
         # delivery move them with the payload from here on.
         if tracing:
+            phase("analysis")
             (span_data2, span_count2, span_dropped2, span_next2,
              tr_rows) = trace_span_lanes(program, opts, st, drain_facts,
                                          base, shard)
@@ -1684,6 +1701,7 @@ def build_step(program: Program, opts: RuntimeOptions):
                 for o, t in zip(out_entries, tr_rows)]
 
         # --- 3. route (mesh) or pass through (single chip).
+        phase("route")
         rspill_e = Entries(st.rspill_tgt, st.rspill_sender, st.rspill_words)
         out_cat = Entries(
             tgt=jnp.concatenate([rspill_e.tgt] +
@@ -1754,6 +1772,7 @@ def build_step(program: Program, opts: RuntimeOptions):
             jnp.zeros_like(dspill_e.tgt),
             jnp.ones_like(inj_local),
             lvl_in])
+        phase("delivery")
         res = deliver(st.buf, new_head, tail0, alive, all_e,
                       n_local=nl, mailbox_cap=c, spill_cap=s_cap,
                       overload_occ=opts.overload_occ, shard_base=base,
@@ -1765,6 +1784,7 @@ def build_step(program: Program, opts: RuntimeOptions):
                       cosort=(opts.delivery == "cosort"),
                       trace_buf=st.trace_buf if tracing else None)
 
+        phase("gc_mark")
         # --- 4b. apply destroys (≙ ponyint_actor_setpendingdestroy +
         # ponyint_actor_destroy, actor.c:570-664): the slot dies at end of
         # step; its remaining queue is discarded (head := tail), flags
@@ -1805,6 +1825,7 @@ def build_step(program: Program, opts: RuntimeOptions):
             pressured = pressured.at[rows].set(False, mode="drop")
             n_destroyed = n_destroyed + jnp.sum(dstr.astype(jnp.int32))
 
+        phase("mute")
         # --- 5. mute bookkeeping (≙ ponyint_mute_actor + mutemap insert,
         # actor.c:1171-1207, mutemap.c): this tick's muting refs from
         # delivery and routing MERGE into each sender's slot table (a
@@ -1850,6 +1871,8 @@ def build_step(program: Program, opts: RuntimeOptions):
         occ_after = new_tail - new_head
         ev_data, ev_count, ev_dropped = (st.ev_data, st.ev_count[0],
                                          st.ev_dropped[0])
+        if opts.analysis >= 1:
+            phase("analysis")
         if opts.analysis >= 3:
             released_ev = st.muted & ~muted & alive
             over_ev = (occ_after > opts.overload_occ) \
@@ -1913,6 +1936,9 @@ def build_step(program: Program, opts: RuntimeOptions):
             qw_enq2 = dict(st.qwait_enq)
             phase_cost2 = st.phase_cost
 
+        # --- 6. the vote: the tick's facts reduced to the aux the window's
+        # continue test (aux_go) and the host read.
+        phase("vote")
         nrej_new = st.n_rejected[0] + res.n_rejected
         nbad_new = st.n_badmsg[0] + nbad_total
         ndl_new = st.n_deadletter[0] + res.n_deadletter
@@ -2165,8 +2191,9 @@ def build_multi_step_gated(program: Program, opts: RuntimeOptions):
         def cond(carry):
             _st, aux, i = carry
             first = i == 0
-            return (first & (force | aux_go(aux))) | \
-                (~first & (i < limit) & aux_go(aux))
+            with phase_scope("vote"):
+                return (first & (force | aux_go(aux))) | \
+                    (~first & (i < limit) & aux_go(aux))
 
         def body(carry):
             s, _aux, i = carry

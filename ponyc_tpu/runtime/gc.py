@@ -68,7 +68,7 @@ from jax import lax
 
 from ..config import RuntimeOptions
 from ..program import Program
-from .state import RtState
+from .state import RtState, phase_scope
 
 
 def build_ref_arg_mask(program: Program, msg_words: int) -> np.ndarray:
@@ -147,6 +147,10 @@ def build_gc(program: Program, opts: RuntimeOptions):
                                       for c in program.cohorts))
 
     def local_gc(st: RtState, extra_roots, blob_roots):
+        with phase_scope("gc_mark"):
+            return mark_and_sweep(st, extra_roots, blob_roots)
+
+    def mark_and_sweep(st: RtState, extra_roots, blob_roots):
         if p > 1:
             shard = lax.axis_index("actors").astype(jnp.int32)
         else:

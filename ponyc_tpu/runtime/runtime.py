@@ -30,6 +30,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..api import ActorTypeMeta, BehaviourDef
 from ..config import RuntimeOptions
@@ -43,6 +44,60 @@ from .state import RtState, init_state
 # Window-length histogram buckets (power-of-two, like state.QW_BUCKETS):
 # bucket k counts retired windows that ran [2^k, 2^(k+1)) ticks.
 WIN_BUCKETS = 16
+
+
+# The run loop's phases (ISSUE 24). One mechanism does three things at a
+# phase boundary: the watchdog stamp (flight.py), a span on the
+# profiler's clock (`pony:<phase>`, in the same .xplane.pb as the device
+# operations) and the seconds the phase took (run_loop_stats()
+# ["phase_s"], self time: a child's seconds are not its parent's too).
+# The top-level phases cover the whole of run(): enter, dispatching,
+# wait, host-work, quiescent, exit. The children of host-work — outbox
+# (host-cohort mail), pollers, gc, checkpoint, analysis — leave the
+# watchdog's stamp as it is: the watchdog's vocabulary does not change.
+PHASE_STAMPS = {"dispatching": "dispatching", "wait": "in-flight",
+                "host-work": "host-work", "quiescent": "quiescent"}
+RUN_PHASES = ("enter", "dispatching", "wait", "host-work", "outbox",
+              "pollers", "gc", "checkpoint", "analysis", "quiescent",
+              "exit")
+
+
+class _PhaseSpan:
+    """One run-loop phase as a context (`Runtime._phase`). With no
+    profiler session it costs two clock reads, a list push and pop and a
+    dict add; the annotation is built only while one is recording."""
+
+    __slots__ = ("rt", "name", "meta", "note", "t0", "child_s")
+
+    def __init__(self, rt, name, meta):
+        self.rt, self.name, self.meta = rt, name, meta
+        self.note = None
+        self.child_s = 0.0
+
+    def __enter__(self):
+        rt = self.rt
+        stamp = PHASE_STAMPS.get(self.name)
+        if stamp is not None:
+            rt._stamp(stamp)
+        if TraceAnnotation.is_enabled():
+            self.note = TraceAnnotation("pony:" + self.name, **self.meta)
+            self.note.__enter__()
+        rt._phase_stack.append(self)
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self.t0
+        rt = self.rt
+        stack = rt._phase_stack
+        while stack and stack.pop() is not self:
+            pass        # an interrupt may have left a child behind
+        rt._phase_s[self.name] += dt - self.child_s
+        if stack:
+            stack[-1].child_s += dt
+        if self.note is not None:
+            self.note.__exit__(*exc)
+        return False
 
 
 class SpillOverflowError(RuntimeError):
@@ -242,6 +297,16 @@ class Runtime:
         #   write landed since that window's dispatch (a write after
         #   dispatch is invisible to the window's aux)
         self._last_retire_t: Optional[float] = None
+        self._rl_tile_t: Optional[float] = None   # the newest flight
+        #   window record's retire: where the next record's time starts.
+        #   NOT reset at run() entry, so the time between two run()
+        #   calls has an owner (since_prev_ms)
+        self._rl_seq = 0              # windows dispatched: the `window`
+        #   the spans of one window share
+        self._rl_retired_seq = 0      # ... of the newest retired one
+        self._rl_wall_ns = 0          # sum of the windows' wall_ms
+        self._phase_s = dict.fromkeys(RUN_PHASES, 0.0)
+        self._phase_stack: List[_PhaseSpan] = []
         # Run-loop telemetry (run_loop_stats()): windows retired, how
         #   many dispatches rode behind an in-flight window, cumulative
         #   host-imposed device-idle gap, re-queued gated-out injects,
@@ -1265,6 +1330,15 @@ class Runtime:
         self._wd_epoch += 1
         self._wd_stamp = (phase, self._wd_epoch, time.monotonic())
 
+    def _phase(self, name: str, **meta) -> _PhaseSpan:
+        """`with self._phase("dispatching", window=n): ...` — the
+        phase as a nested context (see RUN_PHASES above): stamps the
+        watchdog where the phase is one of its own, is the profiler span
+        `pony:<name>` carrying `meta` (window=<sequence number>,
+        ticks=<k> where known), and adds its self seconds to
+        run_loop_stats()["phase_s"]."""
+        return _PhaseSpan(self, name, meta)
+
     def _fatal(self, exc):
         """Record a coded runtime error (metrics label + postmortem
         evidence) on its way out; returns `exc` so raise sites stay
@@ -1329,17 +1403,19 @@ class Runtime:
             self._rl_synced += 1
             gap_ns = 0 if self._last_retire_t is None else \
                 max(0, int((now - self._last_retire_t) * 1e9))
-        inj_t, inj_w, consumed = self._drain_inject_tracked()
-        self._stamp("dispatching")
-        mask = self._defer_signals()
-        try:
-            st2, aux, kdev = self._multi_g(
-                self.state, inj_t, inj_w, jnp.int32(max(1, budget)),
-                np.bool_(force), prev_aux)
-            self.state = st2
-            epoch = self._state_epoch
-        finally:
-            self._restore_signals(mask)
+        self._rl_seq += 1
+        seq = self._rl_seq
+        with self._phase("dispatching", window=seq):
+            inj_t, inj_w, consumed = self._drain_inject_tracked()
+            mask = self._defer_signals()
+            try:
+                st2, aux, kdev = self._multi_g(
+                    self.state, inj_t, inj_w, jnp.int32(max(1, budget)),
+                    np.bool_(force), prev_aux)
+                self.state = st2
+                epoch = self._state_epoch
+            finally:
+                self._restore_signals(mask)
         # From here the window is the device's: the watchdog deadline
         # now covers device completion, not host dispatch latency.
         self._stamp("in-flight")
@@ -1353,7 +1429,7 @@ class Runtime:
                 pass
         return {"aux": aux, "k": kdev, "budget": int(budget),
                 "consumed": consumed, "gap_ns": gap_ns, "epoch": epoch,
-                "pipelined": pipelined}
+                "pipelined": pipelined, "seq": seq, "t_dispatch": now}
 
     def _retire_window(self, win: Dict[str, Any]):
         """Fetch an in-flight window's (ticks_run, aux) and fold it into
@@ -1361,17 +1437,28 @@ class Runtime:
         its injections go back to the FRONT of the queue in order, and
         no counters/controller/analysis state moves. Returns (k, aux as
         host scalars)."""
-        k, a = jax.device_get((win["k"], win["aux"]))
-        self._last_retire_t = time.perf_counter()
+        seq = win["seq"]
+        t_wait = time.perf_counter()
+        with self._phase("wait", window=seq):
+            k, a = jax.device_get((win["k"], win["aux"]))
+        now = self._last_retire_t = time.perf_counter()
+        self._rl_retired_seq = seq
+        k = int(k)
         # The fetch returned: the device answered, the host boundary
         # work for this window starts now (watchdog phase evidence).
-        self._stamp("host-work")
-        k = int(k)
-        if k == 0:
-            if win["consumed"]:
-                self._inject_q.extendleft(reversed(win["consumed"]))
-                self._rl_requeued += len(win["consumed"])
-            return 0, a
+        with self._phase("host-work", window=seq, ticks=k):
+            if k == 0:
+                if win["consumed"]:
+                    self._inject_q.extendleft(reversed(win["consumed"]))
+                    self._rl_requeued += len(win["consumed"])
+                return 0, a
+            self._account_window(win, k, a, t_wait, now)
+        return k, a
+
+    def _account_window(self, win: Dict[str, Any], k: int, a,
+                        t_wait: float, now: float) -> None:
+        """_retire_window's host accounting for a window that ran k > 0
+        ticks and whose fetch began at t_wait and returned at now."""
         # The window just observed (and advanced) true device state;
         # its aux is authoritative for the quiescence-skip decision
         # UNLESS a host-side write landed after its dispatch (the
@@ -1392,6 +1479,21 @@ class Runtime:
             self._last_counters[key] = cur
         self._rl_windows += 1
         self._rl_gap_ns += win["gap_ns"]
+        # Where this window's wall clock went. The records of
+        # consecutive windows tile the clock: a sync-point window owns
+        # [previous retire, its dispatch) as since_prev and [its
+        # dispatch, its retire) as wall; a pipelined one was dispatched
+        # behind the previous window, so since_prev is 0 and its wall
+        # counts from the previous retire.
+        tile = self._rl_tile_t
+        if win["pipelined"] and tile is not None:
+            since_prev, t_from = 0.0, tile
+        else:
+            t_from = win["t_dispatch"]
+            since_prev = 0.0 if tile is None else max(0.0, t_from - tile)
+        wall = max(0.0, now - t_from)
+        self._rl_tile_t = now
+        self._rl_wall_ns += int(wall * 1e9)
         self._win_hist[min(WIN_BUCKETS - 1,
                            max(0, k.bit_length() - 1))] += 1
         # Controller: a full-budget exit with no host attention grows
@@ -1407,14 +1509,16 @@ class Runtime:
         # one bounded-deque append; no extra device traffic.
         if self._flight is not None:
             self._flight.window(self.steps_run, k, win["budget"],
-                                win["gap_ns"] / 1e3,
-                                win.get("pipelined", False), a)
+                                win["gap_ns"] / 1e3, win["pipelined"], a,
+                                wall_ms=wall * 1e3,
+                                wait_ms=min(wall, now - t_wait) * 1e3,
+                                since_prev_ms=since_prev * 1e3)
         if getattr(self, "_analysis", None) is not None:
-            self._analysis.window(a, ticks=k,
-                                  gap_us=win["gap_ns"] / 1e3)
+            with self._phase("analysis", window=win["seq"]):
+                self._analysis.window(a, ticks=k,
+                                      gap_us=win["gap_ns"] / 1e3)
         if self._metrics is not None:
             self._metrics.maybe_update(self)
-        return k, a
 
     def _fatal_checks(self, a) -> None:
         if bool(a.spill_overflow):
@@ -1451,34 +1555,39 @@ class Runtime:
     def run(self, max_steps: Optional[int] = None) -> int:
         if self.state is None:
             raise RuntimeError("call start() first")
-        if self.opts.analysis >= 1 and getattr(self, "_analysis",
-                                               None) is None:
-            from .. import analysis as _analysis_mod
-            _analysis_mod.attach(self)
-        # A request_exit() fired BEFORE run() (signal handler, input
-        # callback between runs) must be honoured, not discarded — the
-        # flag is consumed at the break below, never cleared on entry.
-        max_steps = max_steps or self.opts.max_steps
-        ctrl = self._controller
-        pipelining = bool(self.opts.pipeline)
-        idle_polls = 0
-        steps_this_run = 0
-        skipped_boundaries = 0
-        a = None          # newest RETIRED aux; None forces a first window
-        win = None        # the one in-flight (unretired) window
-        self._last_retire_t = None
-        self._last_run_crashed = False
-        # SIGQUIT = dump the flight recorder and keep running (the
-        # operator's "what is it doing RIGHT NOW" key, ^\ on a tty;
-        # SIGTERM/SIGUSR1 stay the analysis dump's, PROFILE.md §8).
-        prev_quit = None
-        if self._flight is not None and hasattr(signal, "SIGQUIT"):
-            def _quit_dump(_signum, _frame):
-                self._flight.dump(reason="SIGQUIT")
-            try:
-                prev_quit = signal.signal(signal.SIGQUIT, _quit_dump)
-            except ValueError:      # not the main thread: skip
-                prev_quit = None
+        # pony:enter / pony:exit: what run() does before its first
+        # dispatch and after its last retire, so that the phases cover
+        # the whole of run().
+        self._phase_stack.clear()
+        with self._phase("enter"):
+            if self.opts.analysis >= 1 and getattr(self, "_analysis",
+                                                   None) is None:
+                from .. import analysis as _analysis_mod
+                _analysis_mod.attach(self)
+            # A request_exit() fired BEFORE run() (signal handler, input
+            # callback between runs) must be honoured, not discarded — the
+            # flag is consumed at the break below, never cleared on entry.
+            max_steps = max_steps or self.opts.max_steps
+            ctrl = self._controller
+            pipelining = bool(self.opts.pipeline)
+            idle_polls = 0
+            steps_this_run = 0
+            skipped_boundaries = 0
+            a = None          # newest RETIRED aux; None forces a first window
+            win = None        # the one in-flight (unretired) window
+            self._last_retire_t = None   # host_gap_us: inside one run()
+            self._last_run_crashed = False
+            # SIGQUIT = dump the flight recorder and keep running (the
+            # operator's "what is it doing RIGHT NOW" key, ^\ on a tty;
+            # SIGTERM/SIGUSR1 stay the analysis dump's, PROFILE.md §8).
+            prev_quit = None
+            if self._flight is not None and hasattr(signal, "SIGQUIT"):
+                def _quit_dump(_signum, _frame):
+                    self._flight.dump(reason="SIGQUIT")
+                try:
+                    prev_quit = signal.signal(signal.SIGQUIT, _quit_dump)
+                except ValueError:      # not the main thread: skip
+                    prev_quit = None
         try:
             while True:
                 if win is None:
@@ -1545,64 +1654,75 @@ class Runtime:
                     win = spec
                 # ---- host boundary for `a` (overlaps `win`'s device
                 # execution when the pipeline kept one in flight) ----
-                self._stamp("host-work")
-                self._fatal_checks(a)
-                if bool(a.exit_flag):
-                    self._exit_code = int(a.exit_code)
-                    break
-                if bool(a.host_pending):
-                    self._drain_host()
-                for p in self._bridge_pollers:
-                    p.poll(self)
-                # Fast lane: host→host messages (including any the drains
-                # and pollers just produced) dispatch NOW, without waiting
-                # a device window per hop (≙ inject_main staying on the
-                # main-thread scheduler).
-                self._drain_host_fast(self.opts.host_fastpath_budget)
-                # Periodic collection (≙ the cycle detector triggered off
-                # the scheduler-0 idle path every --ponycdinterval,
-                # scheduler.c:976-989) — only when something can actually
-                # be garbage: a host ref was released or actors spawn on
-                # device. Host-heap allocation pressure schedules a
-                # collection EARLY (≙ the per-actor heap's
-                # growth-triggered GC, heap.c next_gc with
-                # --ponygcinitial/--ponygcfactor, start.c:204-209).
-                heap = getattr(self, "_heap", None)
-                heap_pressure = (heap is not None
-                                 and heap.bytes_since_gc > self._next_gc)
-                # Cadence counts device steps + skipped host-only
-                # boundaries (steps_run freezes while boundaries are
-                # skipped; host-heavy phases must still collect
-                # periodically).
-                eff_step = self.steps_run + self._idle_boundaries
-                if (not self.opts.noblock
-                        and (self._ever_released
-                             or self.program.has_device_spawns)
-                        and (heap_pressure
-                             or (self.opts.cd_interval > 0
-                                 and eff_step - self._last_gc_step
-                                 >= self.opts.cd_interval))):
-                    self._last_gc_step = eff_step
-                    self.gc()
-                # Periodic crash-safe checkpoint (PROFILE.md §12): the
-                # world is quiescent-consistent here whenever no window
-                # is in flight (retired state + host queues = exactly
-                # what serialise captures); the device→host copy runs
-                # now, the file write rides the background writer
-                # behind the next window. Never lets a checkpointing
-                # failure take down the run it exists to protect.
-                if self._ckpt is not None and win is None:
-                    try:
-                        self._ckpt.tick(self, in_flight=False)
-                    except Exception as e:          # noqa: BLE001
-                        self.totals["checkpoint_errors"] += 1
-                        if self._flight is not None:
-                            self._flight.event(
-                                "checkpoint_failed",
-                                error=f"{type(e).__name__}: {e}")
-                if self._exit_requested:
-                    self._exit_requested = False    # consume the request
-                    break
+                seq = self._rl_retired_seq
+                with self._phase("host-work", window=seq):
+                    self._fatal_checks(a)
+                    if bool(a.exit_flag):
+                        self._exit_code = int(a.exit_code)
+                        break
+                    if bool(a.host_pending):
+                        with self._phase("outbox", window=seq):
+                            self._drain_host()
+                    if self._bridge_pollers:
+                        with self._phase("pollers", window=seq):
+                            for p in self._bridge_pollers:
+                                p.poll(self)
+                    # Fast lane: host→host messages (including any the
+                    # drains and pollers just produced) dispatch NOW,
+                    # without waiting a device window per hop (≙
+                    # inject_main staying on the main-thread scheduler).
+                    if self._host_fast_q:
+                        with self._phase("outbox", window=seq):
+                            self._drain_host_fast(
+                                self.opts.host_fastpath_budget)
+                    # Periodic collection (≙ the cycle detector triggered
+                    # off the scheduler-0 idle path every
+                    # --ponycdinterval, scheduler.c:976-989) — only when
+                    # something can actually be garbage: a host ref was
+                    # released or actors spawn on device. Host-heap
+                    # allocation pressure schedules a collection EARLY
+                    # (≙ the per-actor heap's growth-triggered GC, heap.c
+                    # next_gc with --ponygcinitial/--ponygcfactor,
+                    # start.c:204-209).
+                    heap = getattr(self, "_heap", None)
+                    heap_pressure = (heap is not None and
+                                     heap.bytes_since_gc > self._next_gc)
+                    # Cadence counts device steps + skipped host-only
+                    # boundaries (steps_run freezes while boundaries are
+                    # skipped; host-heavy phases must still collect
+                    # periodically).
+                    eff_step = self.steps_run + self._idle_boundaries
+                    if (not self.opts.noblock
+                            and (self._ever_released
+                                 or self.program.has_device_spawns)
+                            and (heap_pressure
+                                 or (self.opts.cd_interval > 0
+                                     and eff_step - self._last_gc_step
+                                     >= self.opts.cd_interval))):
+                        self._last_gc_step = eff_step
+                        with self._phase("gc", window=seq):
+                            self.gc()
+                    # Periodic crash-safe checkpoint (PROFILE.md §12):
+                    # the world is quiescent-consistent here whenever no
+                    # window is in flight (retired state + host queues =
+                    # exactly what serialise captures); the device→host
+                    # copy runs now, the file write rides the background
+                    # writer behind the next window. Never lets a
+                    # checkpointing failure take down the run it exists
+                    # to protect.
+                    if self._ckpt is not None and win is None:
+                        with self._phase("checkpoint", window=seq):
+                            try:
+                                self._ckpt.tick(self, in_flight=False)
+                            except Exception as e:      # noqa: BLE001
+                                self.totals["checkpoint_errors"] += 1
+                                if self._flight is not None:
+                                    self._flight.event(
+                                        "checkpoint_failed",
+                                        error=f"{type(e).__name__}: {e}")
+                    if self._exit_requested:
+                        self._exit_requested = False    # consume it
+                        break
                 # A dirty device (host-side state write since the last
                 # window — e.g. bulk_send's direct mailbox writes from a
                 # host behaviour) is not provably quiet: stay busy so the
@@ -1665,12 +1785,12 @@ class Runtime:
                     # Waiting on the outside world is a HEALTHY steady
                     # state: the watchdog disarms on this phase (a
                     # quiet timer-driven service is not a stall).
-                    self._stamp("quiescent")
-                    if waiter is not None:
-                        waiter.wait(0.02)
-                    else:
-                        time.sleep(min(0.002,
-                                       2e-5 * (1 << min(idle_polls, 7))))
+                    with self._phase("quiescent"):
+                        if waiter is not None:
+                            waiter.wait(0.02)
+                        else:
+                            time.sleep(min(0.002,
+                                           2e-5 * (1 << min(idle_polls, 7))))
                 else:
                     idle_polls = 0
                 if max_steps is not None \
@@ -1692,51 +1812,52 @@ class Runtime:
             # run loses no host-outbox messages and the runtime stays
             # consistent for a restart (no donated-buffer reuse).
             import sys as _sys
-            # A tripped watchdog means the device (or a host phase) is
-            # WEDGED: retiring the in-flight window or refreshing the
-            # metrics snapshot would block on the very hang we are
-            # converting to an error — skip device-touching teardown
-            # and let the PonyStallError out (the runtime is not
-            # restartable after a stall; the postmortem is the value).
-            stalled = (self._watchdog is not None
-                       and self._watchdog.tripped is not None)
-            if win is not None and not stalled:
-                k2, a2 = self._retire_window(win)
-                steps_this_run += k2
-                if bool(a2.host_pending):
-                    self._drain_host()
-            if _sys.exc_info()[0] is not None \
-                    and not isinstance(_sys.exc_info()[1], PonyStallError):
-                # Interrupted between boundaries: host→host messages
-                # already queued on the fast lane would otherwise be
-                # stranded until the next run() — deliver them now
-                # (bounded by the normal per-boundary budget). Normal
-                # exits skip this: quiescent termination proves the
-                # lane empty, and an exit() break stops the world as
-                # the synchronous loop always has. A watchdog STALL
-                # also skips it: the wedged behaviour may be ON this
-                # lane, and re-dispatching it would hang the unwind.
-                self._drain_host_fast(self.opts.host_fastpath_budget)
-            if prev_quit is not None:
-                try:
-                    signal.signal(signal.SIGQUIT, prev_quit)
-                except ValueError:
-                    pass
-            self._stamp("idle")
-            # Crash postmortem (PROFILE.md §11): any exceptional exit
-            # dumps the black box. Stall trips already dumped (the
-            # watchdog thread wrote it before interrupting us).
-            exc = _sys.exc_info()[1]
-            self._last_run_crashed = (exc is not None
-                                      and not isinstance(exc, SystemExit))
-            if (exc is not None and self._flight is not None
-                    and not isinstance(exc, (SystemExit,
-                                             PonyStallError))):
-                self._flight.dump(
-                    reason=f"crash: {type(exc).__name__}: {exc}",
-                    error_code=error_code(exc))
-            if self._metrics is not None and not stalled:
-                self._metrics.update_now(self)
+            with self._phase("exit"):
+                # A tripped watchdog means the device (or a host phase) is
+                # WEDGED: retiring the in-flight window or refreshing the
+                # metrics snapshot would block on the very hang we are
+                # converting to an error — skip device-touching teardown
+                # and let the PonyStallError out (the runtime is not
+                # restartable after a stall; the postmortem is the value).
+                stalled = (self._watchdog is not None
+                           and self._watchdog.tripped is not None)
+                if win is not None and not stalled:
+                    k2, a2 = self._retire_window(win)
+                    steps_this_run += k2
+                    if bool(a2.host_pending):
+                        self._drain_host()
+                if _sys.exc_info()[0] is not None \
+                        and not isinstance(_sys.exc_info()[1], PonyStallError):
+                    # Interrupted between boundaries: host→host messages
+                    # already queued on the fast lane would otherwise be
+                    # stranded until the next run() — deliver them now
+                    # (bounded by the normal per-boundary budget). Normal
+                    # exits skip this: quiescent termination proves the
+                    # lane empty, and an exit() break stops the world as
+                    # the synchronous loop always has. A watchdog STALL
+                    # also skips it: the wedged behaviour may be ON this
+                    # lane, and re-dispatching it would hang the unwind.
+                    self._drain_host_fast(self.opts.host_fastpath_budget)
+                if prev_quit is not None:
+                    try:
+                        signal.signal(signal.SIGQUIT, prev_quit)
+                    except ValueError:
+                        pass
+                self._stamp("idle")
+                # Crash postmortem (PROFILE.md §11): any exceptional exit
+                # dumps the black box. Stall trips already dumped (the
+                # watchdog thread wrote it before interrupting us).
+                exc = _sys.exc_info()[1]
+                self._last_run_crashed = (exc is not None
+                                          and not isinstance(exc, SystemExit))
+                if (exc is not None and self._flight is not None
+                        and not isinstance(exc, (SystemExit,
+                                                 PonyStallError))):
+                    self._flight.dump(
+                        reason=f"crash: {type(exc).__name__}: {exc}",
+                        error_code=error_code(exc))
+                if self._metrics is not None and not stalled:
+                    self._metrics.update_now(self)
         # Persist a converged adaptive window for warm starts (PR 1
         # tuning-cache machinery): only a steady controller with real
         # evidence writes, and only when the value actually moved.
@@ -1744,17 +1865,21 @@ class Runtime:
                 and self._rl_windows >= 8
                 and ctrl.window != self._qi_loaded):
             from .. import tuning
-            tuning.store_quiesce_interval(self.program, self.opts,
-                                          ctrl.window)
+            with self._phase("exit"):
+                tuning.store_quiesce_interval(self.program, self.opts,
+                                              ctrl.window)
             self._qi_loaded = ctrl.window
         return self._exit_code
 
     def run_loop_stats(self) -> Dict[str, Any]:
         """Observable run-loop telemetry (dump(), `top`, bench.py):
         windows retired, pipelined vs sync-point dispatches, the
-        cumulative host-imposed device-idle gap, re-queued gated-out
-        injections, the window-length histogram (power-of-two buckets)
-        and the controller snapshot."""
+        cumulative host-imposed device-idle gap, the windows' summed
+        wall clock (dispatch start to retire; a pipelined window's
+        counts from the retire before it), the seconds run() spent in
+        each phase (RUN_PHASES, self time, cumulative), re-queued
+        gated-out injections, the window-length histogram
+        (power-of-two buckets) and the controller snapshot."""
         n = max(1, self._rl_windows)
         return {
             "windows": self._rl_windows,
@@ -1762,6 +1887,8 @@ class Runtime:
             "sync_dispatches": self._rl_synced,
             "host_gap_us_total": self._rl_gap_ns / 1e3,
             "host_gap_us_mean": self._rl_gap_ns / 1e3 / n,
+            "windows_wall_s": self._rl_wall_ns / 1e9,
+            "phase_s": dict(self._phase_s),
             "injects_requeued": self._rl_requeued,
             "window_hist": [int(x) for x in self._win_hist],
             "controller": (self._controller.snapshot()
@@ -2035,42 +2162,6 @@ class Runtime:
         backend doesn't report degrade to None."""
         from .. import costs as _costs
         return _costs.capture(self, force=force)
-
-    def profile_device(self, windows: int = 1, path: str | None = None,
-                       ticks: int | None = None) -> str:
-        """Wrap N real retired fused windows in a ``jax.profiler``
-        trace (xprof / tensorboard / perfetto-compatible, ISSUE 19) for
-        op-level device wall attribution — the measurement the modelled
-        bytes/msg numbers are judged against on silicon. Drives
-        ``windows`` forced fused windows of ``ticks`` ticks each (the
-        controller's current window by default) through the runtime's
-        own executable — the world genuinely advances and the retired
-        steps count in ``steps_run``. The first window runs OUTSIDE the
-        trace to absorb compilation. Returns the trace directory
-        (default ``<analysis_path or ponyc_xprof>.xprof``)."""
-        if self.state is None:
-            raise RuntimeError("call start() first")
-        import jax
-        from jax import profiler as _prof
-        if path is None:
-            base = self.opts.analysis_path or "ponyc_xprof"
-            path = base + ".xprof"
-        n = int(ticks if ticks is not None else self._controller.window)
-        limit = jnp.int32(max(1, n))
-        inj_t, inj_w = self._empty_inject
-        # Warm-up window outside the trace: compilation (or cache
-        # lookup) must not pollute the device timeline.
-        st, _aux, k = self._multi(self.state, inj_t, inj_w, limit)
-        self.state = st
-        self.steps_run += int(k)
-        with _prof.trace(path):
-            for _ in range(max(1, int(windows))):
-                st, _aux, k = self._multi(self.state, inj_t, inj_w,
-                                          limit)
-                jax.block_until_ready(st)
-                self.state = st
-                self.steps_run += int(k)
-        return path
 
     def traces(self) -> Dict[int, Dict[str, Any]]:
         """Reassembled causal traces (PROFILE.md §10): drains the

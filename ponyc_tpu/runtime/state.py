@@ -70,6 +70,56 @@ QW_BUCKETS = 16
 PHASE_NAMES = ("delivery", "drain", "dispatch", "gc_mark")
 N_PHASES = len(PHASE_NAMES)
 
+# Named scopes on the tick's phases (ISSUE 24): every operation of the
+# step carries `pony/<phase>` in its HLO op_name, so a profiler trace
+# names device time by phase instead of by fusion number. One
+# vocabulary: where a phase coincides with PHASE_NAMES the scope carries
+# that name. A scope is written ABSOLUTE (`pony/delivery/rebuild`, not
+# `rebuild` inside `pony/delivery`): lax.cond / while put their own
+# segments between nested scopes, so a reader takes what follows the
+# LAST `pony` segment of an op_name (benchmarks/phase_trace.py). Scopes
+# are metadata only — the optimised HLO with `_named_scope` stubbed out
+# is the same program (tests/test_profiler.py).
+SCOPE_PREFIX = "pony"
+STEP_SCOPES = ("unmute", "spawn", "drain", "dispatch", "route",
+               "delivery", "delivery/plan", "delivery/permute",
+               "delivery/rebuild", "delivery/pressure", "gc_mark",
+               "mute", "vote")
+_named_scope = jax.named_scope      # the one seam the tests stub
+
+
+def phase_scope(path: str):
+    """Context manager: the traced operations inside belong to phase
+    `path` (one of STEP_SCOPES, or `analysis` for the opt-in lanes)."""
+    return _named_scope(f"{SCOPE_PREFIX}/{path}")
+
+
+class PhaseCursor:
+    """The step is a straight line of phases: `phase("route")` closes
+    the scope that was open and opens the next, so the step's body is
+    not re-indented under a dozen `with` blocks. Itself a context
+    manager that closes the last scope, whatever ends the trace."""
+
+    def __init__(self):
+        self._open = None
+
+    def __call__(self, path: str) -> None:
+        self._close()
+        self._open = phase_scope(path)
+        self._open.__enter__()
+
+    def _close(self) -> None:
+        if self._open is not None:
+            scope, self._open = self._open, None
+            scope.__exit__(None, None, None)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *_exc):
+        self._close()
+        return False
+
 # Span-ring record rows (causal tracing, PROFILE.md §10): the layout is
 # owned by tracing.py so the host reassembler and the device writer can
 # never drift. (trace_id, span_id, parent_span, behaviour_gid,

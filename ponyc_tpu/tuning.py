@@ -127,6 +127,14 @@ def enable_compile_cache(setting: str = "auto") -> Optional[str]:
     # small scatter/gather programs besides the step and the window).
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # The key covers the HLO's metadata too. jax leaves it out by
+    # default, and an executable reloaded from an entry that another
+    # build wrote then carries THAT build's op_names: a profile would
+    # show the tick's phases (`pony/<phase>`, state.STEP_SCOPES) under
+    # stale names, or under none (seen on the v5e, PR 24: an entry
+    # written by a build without the scopes served a nameless window).
+    # The price is a recompile when only line numbers moved.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path
 
 

@@ -88,7 +88,7 @@ def test_bench_ubench_emits_measured_block(bench_mod):
     cost/memory analysis of the run's real executables, the record
     probe, and the model_divergence verdict against the modelled
     bytes/msg."""
-    ub = bench_mod.bench_ubench(_args(xprof=0))
+    ub = bench_mod.bench_ubench(_args())
     m = ub["measured"]
     assert "error" not in m
     assert m["executables"]["step"]["bytes_accessed"] > 0
@@ -105,7 +105,7 @@ def test_bench_perf_smoke_scoreboard_row(bench_mod, tmp_path, capsys,
     import json
     hist = tmp_path / "BENCH_HISTORY.jsonl"
     monkeypatch.setattr(bench_mod, "HISTORY_PATH", str(hist))
-    rc = bench_mod.bench_perf_smoke(_args(xprof=0, platform="cpu"))
+    rc = bench_mod.bench_perf_smoke(_args(platform="cpu"))
     assert rc == 0
     result = json.loads(capsys.readouterr().out.splitlines()[-1])
     assert result["detail"]["perf_smoke"] is True

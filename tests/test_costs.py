@@ -93,17 +93,6 @@ def test_capture_requires_started_runtime():
         costs.capture(rt)
 
 
-def test_profile_device_writes_trace(plain_rt, tmp_path):
-    rt, ids = plain_rt
-    rt.send(int(ids[0]), ring.RingNode.token, 500)
-    path = rt.profile_device(windows=2, path=str(tmp_path / "xp"),
-                             ticks=8)
-    assert path == str(tmp_path / "xp")
-    assert os.path.isdir(path)
-    # the traced windows really advanced the world
-    assert rt.steps_run > 0
-
-
 # -------------------------------------------------- modelled vs measured
 
 def test_record_probe_agrees_with_model_on_cpu():

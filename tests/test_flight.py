@@ -419,3 +419,90 @@ def test_watchdog_option_validation():
         RuntimeOptions(watchdog_s=0.0)
     with pytest.raises(ValueError, match="flight_windows"):
         RuntimeOptions(flight_windows=0)
+
+
+# ------------------------------------ the window's clock (ISSUE 24)
+
+def test_window_records_tile_the_wall_clock(tmp_path):
+    """Every window record says where its wall clock went: wall_ms >=
+    wait_ms >= 0, and since_prev_ms + wall_ms of consecutive records
+    tile the time from the first retire to the last — across two run()
+    calls too (since_prev_ms is not reset at run() entry)."""
+    rt, ids = ring.build(8, _opts(flight_windows=256, tuning_cache="off",
+                                  quiesce_interval=16,
+                                  analysis_path=str(tmp_path / "an.csv")))
+    rt.send(int(ids[0]), ring.RingNode.token, 10_000)
+    rt.run(max_steps=200)
+    t_mid = time.perf_counter()
+    time.sleep(0.05)                       # between two run() calls
+    rt.run(max_steps=200)
+    t_end = time.perf_counter()
+    recs = list(rt._flight.windows)
+    rt.stop()
+    assert len(recs) >= 8
+    for w in recs:
+        assert w["wall_ms"] >= w["wait_ms"] >= 0
+        assert w["since_prev_ms"] >= 0
+        assert not (w["pipelined"] and w["since_prev_ms"])
+    tiled = sum(w["since_prev_ms"] + w["wall_ms"] for w in recs[1:])
+    # the ring's own clock (t_ms is stamped at the retire's accounting)
+    assert tiled == pytest.approx(recs[-1]["t_ms"] - recs[0]["t_ms"],
+                                  rel=0.05, abs=2.0)
+    # the sleep between the calls has an owner
+    second = [w for w in recs if w["since_prev_ms"] >= 50.0]
+    assert len(second) == 1
+    assert t_end - t_mid >= second[0]["since_prev_ms"] / 1e3
+    rl = rt.run_loop_stats()
+    assert rl["windows_wall_s"] == pytest.approx(
+        sum(w["wall_ms"] for w in recs) / 1e3, rel=1e-3)
+    assert set(rl["phase_s"]) >= {"dispatching", "wait", "host-work"}
+
+
+def test_old_postmortem_without_the_clock_still_renders(tmp_path, capsys):
+    """A postmortem written before the window records had wall_ms /
+    wait_ms / since_prev_ms renders as it did; a new one shows them."""
+    from ponyc_tpu.__main__ import main as cli_main
+    path = str(tmp_path / "an.csv")
+    rt, ids = ring.build(8, _opts(analysis_path=path))
+    rt.send(int(ids[0]), ring.RingNode.token, 20)
+    rt.run()
+    rt.stop(postmortem=True)
+    capsys.readouterr()
+    pm = flight.load_postmortem(path + ".postmortem.json")
+    assert "wall=" in flight.render_postmortem(pm)
+    for w in pm["windows"]:
+        for key in ("wall_ms", "wait_ms", "since_prev_ms"):
+            del w[key]
+    old = str(tmp_path / "old.json")
+    json.dump(pm, open(old, "w"))
+    assert cli_main(["doctor", "--postmortem", old]) == 0
+    out = capsys.readouterr().out
+    assert "last " in out and "wall=" not in out and "gap=" in out
+
+
+def test_latest_follows_the_newest_runtime_and_keeps_none_alive():
+    import gc
+    import weakref
+    first, _ = ring.build(8, _opts())
+    assert flight.latest() is first._flight
+    second, _ = ring.build(8, _opts())
+    assert flight.latest() is second._flight
+    first.stop()
+    second.stop()
+    gone = weakref.ref(second)
+    del second
+    gc.collect()
+    assert gone() is None               # latest() held no strong reference
+    assert flight.latest() is None      # ... and does not fall back
+    del first
+
+
+def test_metrics_expose_the_phase_seconds(tmp_path):
+    from ponyc_tpu import metrics
+    rt, ids = ring.build(8, _opts(analysis_path=str(tmp_path / "an.csv")))
+    rt.send(int(ids[0]), ring.RingNode.token, 20)
+    rt.run()
+    text = metrics.prometheus_text(metrics.snapshot(rt))
+    rt.stop()
+    assert 'pony_tpu_run_phase_seconds_total{phase="wait"}' in text
+    assert "pony_tpu_windows_wall_seconds_total" in text

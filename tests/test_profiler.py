@@ -462,3 +462,119 @@ def test_example_smoke_analysis2(tmp_path):
     assert sum(int(r["run:Counter.report"]) for r in rows) == 8
     # the dump summary (level >= 1) ran on exit too
     assert "analysis dump" in p.stderr
+
+
+# ------------------------------------- named scopes on the tick (ISSUE 24)
+
+def _lowered_window(delivery):
+    import jax
+    import jax.numpy as jnp
+
+    from ponyc_tpu.models import ubench
+    from ponyc_tpu.runtime import engine
+    opts = _opts(mailbox_cap=4, batch=2, delivery=delivery,
+                 tuning_cache="off", compile_cache="off")
+    rt, _ids = ubench.build(64, opts, pings=2)
+    gated = engine.build_multi_step_gated(rt.program, rt.opts)
+    lowered = jax.jit(gated).lower(
+        rt.state, *rt._empty_inject, jnp.int32(4), jnp.bool_(True),
+        engine.zero_aux())
+    rt.stop()
+    return rt, lowered
+
+
+@pytest.mark.parametrize("delivery", ["plan", "cosort"])
+def test_phase_scopes_name_the_lowered_window(delivery):
+    """Every scope of the vocabulary (state.STEP_SCOPES) appears in the
+    lowered window's op_name metadata, under both delivery formulations;
+    `pony/gc_mark` also heads the collection pass's own program."""
+    import jax
+
+    from ponyc_tpu.runtime import gc as gc_mod
+    from ponyc_tpu.runtime.state import SCOPE_PREFIX, STEP_SCOPES
+    rt, lowered = _lowered_window(delivery)
+    text = lowered.as_text(debug_info=True)
+    missing = [s for s in STEP_SCOPES if s != "gc_mark"
+               and f"{SCOPE_PREFIX}/{s}/" not in text]
+    assert not missing, missing
+    import numpy as np
+    nl = rt.program.n_local
+    gc_text = jax.jit(gc_mod.build_gc(rt.program, rt.opts)).lower(
+        rt.state, np.zeros((nl,), bool),
+        np.zeros((max(1, rt.opts.blob_slots),), bool)
+    ).as_text(debug_info=True)
+    assert f"{SCOPE_PREFIX}/gc_mark/" in gc_text
+
+
+def _bare_hlo(text):
+    """Optimised HLO without what only describes it: per-instruction
+    metadata={...} and the module's file / function / location / stack
+    frame tables that the metadata indexes."""
+    import re
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    out, skipping = [], False
+    for line in text.splitlines():
+        if line in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            skipping = True
+        elif skipping and not line.strip():
+            skipping = False
+        elif not skipping:
+            out.append(line)
+    return "\n".join(out)
+
+
+@pytest.mark.parametrize("delivery", ["plan", "cosort"])
+def test_phase_scopes_are_metadata_only(delivery, monkeypatch):
+    """The optimised HLO of the window is the same program with the
+    scopes and with the one scope helper stubbed out, once metadata is
+    stripped: the names cost the compiled program nothing."""
+    import contextlib
+
+    from ponyc_tpu.runtime import state
+    _rt, lowered = _lowered_window(delivery)
+    scoped = lowered.compile().as_text()
+    assert 'op_name="jit(multi)/while/body/pony/delivery' in scoped
+    monkeypatch.setattr(state, "_named_scope",
+                        lambda _name: contextlib.nullcontext())
+    _rt, lowered = _lowered_window(delivery)
+    bare = lowered.compile().as_text()
+    assert "pony/" not in bare
+    assert _bare_hlo(scoped) == _bare_hlo(bare)
+
+
+def test_profiler_trace_holds_the_run_phases(tmp_path):
+    """A jax.profiler trace of run() on the CPU backend contains the
+    run loop's `pony:*` spans, on the profiler's clock, carrying the
+    window they belong to."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    rt, ids = ring.build(8, _opts(tuning_cache="off"))
+    rt.send(int(ids[0]), ring.RingNode.token, 50)
+    rt.run()                                  # compile outside the trace
+    rt._exit_code = 0
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        rt.send(int(ids[0]), ring.RingNode.token, 50)
+        rt.run()
+    finally:
+        jax.profiler.stop_trace()
+    rt.stop()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    spans = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("pony:"):
+                    spans.setdefault(e.name, []).append(dict(e.stats))
+    assert {"pony:enter", "pony:dispatching", "pony:wait",
+            "pony:host-work", "pony:exit"} <= set(spans)
+    assert all("window" in s for s in spans["pony:dispatching"])
+    assert any(s.get("ticks") for s in spans["pony:host-work"])

@@ -192,7 +192,8 @@ def test_gated_out_window_requeues_injections():
                              np.bool_(False), quiet)
     rt.state = st
     win = {"aux": aux, "k": k, "budget": 4, "consumed": consumed,
-           "gap_ns": 0, "epoch": rt._state_epoch}
+           "gap_ns": 0, "epoch": rt._state_epoch, "pipelined": True,
+           "seq": rt._rl_seq + 1, "t_dispatch": 0.0}
     k2, _a = rt._retire_window(win)
     assert k2 == 0
     assert [t for t, _w in rt._inject_q] == [int(ids[0]), int(ids[1])]
@@ -482,7 +483,22 @@ ids = rt.spawn_many(Pinger, 16)
 rt.set_fields(Pinger, ids, nxt=np.roll(ids, -1))
 for i in ids:
     rt.send(int(i), Pinger.ping, 1)
-threading.Timer(1.0, lambda: os.kill(os.getpid(), signal.SIGTERM)).start()
+
+class Killer:
+    # Not after a fixed time (on a loaded machine that lands before
+    # run() has compiled and installed the dump handler): once the run
+    # loop has retired a window and holds another in flight behind it.
+    fired = False
+    def poll(self, rt):
+        s = rt.run_loop_stats()
+        in_flight = s["sync_dispatches"] + s["pipelined_dispatches"] \
+            - s["windows"]
+        if (not self.fired and s["windows"] >= 1 and in_flight >= 1
+                and s["pipelined_dispatches"] >= 1):
+            self.fired = True
+            os.kill(os.getpid(), signal.SIGTERM)
+
+rt.register_poller(Killer())
 rt.run()
 print("SURVIVED-SIGTERM")
 """
@@ -518,3 +534,94 @@ def test_window_constants_ride_optimization_barrier():
         rt.state, *rt._empty_inject, jnp.int32(4), jnp.bool_(True),
         engine.zero_aux()).as_text()
     assert "optimization_barrier" in text
+
+
+# ------------------------------------------- run-loop phases (ISSUE 24)
+
+def test_run_phases_nest_carry_window_and_cover_run(monkeypatch):
+    """The pony:* spans of one run() are properly nested, the window's
+    spans carry its sequence number, and the per-phase seconds (self
+    time) add up to run()'s wall clock."""
+    import time
+
+    from ponyc_tpu.runtime import runtime as rtmod
+
+    log = []
+
+    class Recording:
+        depth = 0
+
+        def __init__(self, name, **meta):
+            self.name, self.meta = name, meta
+
+        @staticmethod
+        def is_enabled():
+            return True
+
+        def __enter__(self):
+            log.append(("enter", self.name, Recording.depth, self.meta))
+            Recording.depth += 1
+
+        def __exit__(self, *_exc):
+            Recording.depth -= 1
+            log.append(("exit", self.name, Recording.depth, self.meta))
+
+    rt, _ids = _ring(hops=3000, quiesce_interval=64)
+    rt.run(max_steps=64)                        # compile outside the clock
+    before = rt.run_loop_stats()
+    monkeypatch.setattr(rtmod, "TraceAnnotation", Recording)
+    t0 = time.perf_counter()
+    assert rt.run(max_steps=100_000) == 0
+    wall = time.perf_counter() - t0
+    monkeypatch.undo()
+    after = rt.run_loop_stats()
+    rt.stop()
+
+    # properly nested: every exit closes the newest open span
+    stack = []
+    for kind, name, depth, _meta in log:
+        if kind == "enter":
+            assert depth == len(stack)
+            stack.append(name)
+        else:
+            assert stack.pop() == name and depth == len(stack)
+    assert not stack
+    names = {name for _k, name, _d, _m in log}
+    assert {"pony:enter", "pony:dispatching", "pony:wait",
+            "pony:host-work", "pony:exit"} <= names
+    assert names <= {"pony:" + p for p in rtmod.RUN_PHASES}
+    # top level covers run() from its first line to its last
+    assert log[0][1] == "pony:enter" and log[-1][1] == "pony:exit"
+    # a window's spans share its sequence number; host-work knows ticks
+    windows = after["windows"] - before["windows"]
+    assert windows > 3
+    for phase in ("pony:dispatching", "pony:wait", "pony:host-work"):
+        seqs = {m["window"] for k, n, _d, m in log
+                if k == "enter" and n == phase}
+        assert len(seqs) >= windows, (phase, seqs)
+    assert any(m.get("ticks") for _k, n, _d, m in log
+               if n == "pony:host-work")
+    spent = sum(after["phase_s"][p] - before["phase_s"][p]
+                for p in rtmod.RUN_PHASES)
+    assert spent == pytest.approx(wall, rel=0.05)
+    assert after["windows_wall_s"] > before["windows_wall_s"]
+    assert after["windows_wall_s"] - before["windows_wall_s"] <= wall
+
+
+def test_run_phases_cost_little_with_the_profiler_off():
+    """Budget: about a microsecond a span with no profiler session
+    (generous bound: CI machines are slow and shared)."""
+    import time
+
+    from ponyc_tpu.runtime import runtime as rtmod
+    rt, _ids = _ring(hops=10)
+    n = 20_000
+    t0 = time.perf_counter()
+    for i in range(n):
+        with rt._phase("host-work", window=i):
+            pass
+    per_span = (time.perf_counter() - t0) / n
+    rt.stop()
+    assert per_span < 20e-6, per_span
+    assert not rt._phase_stack
+    assert rtmod.PHASE_STAMPS["wait"] == "in-flight"   # the watchdog's word

@@ -333,6 +333,7 @@ def test_slow_consumer_does_not_stall_neighbours():
 
     def client():
         slow_done = threading.Event()
+        fast_done = threading.Event()
 
         def slow():
             try:
@@ -342,7 +343,11 @@ def test_slow_consumer_does_not_stall_neighbours():
                 s.connect(("127.0.0.1", port))
                 for i in range(800):
                     s.sendall(encode_request(i + 1, 0, [i]))
-                time.sleep(2.0)            # never reads its replies
+                # Never reads its replies, and hangs up only once the
+                # fast lane is through: a fixed 2 s nap ended before a
+                # loaded machine had compiled the first window, and the
+                # server's first write then met a reset connection.
+                fast_done.wait(timeout=20.0)
                 s.close()
             except OSError:
                 pass                       # server may kill the conn
@@ -353,6 +358,7 @@ def test_slow_consumer_does_not_stall_neighbours():
         ts.start()
         fast = loadgen.run_load("127.0.0.1", port, conns=1, depth=2,
                                 requests=60, busy_backoff_s=0.002)
+        fast_done.set()
         slow_done.wait(timeout=30.0)
         return fast
 

@@ -342,7 +342,8 @@ def restore_jax_cache_config():
     saved = {k: getattr(jax.config, k) for k in (
         "jax_compilation_cache_dir",
         "jax_persistent_cache_min_compile_time_secs",
-        "jax_persistent_cache_min_entry_size_bytes")}
+        "jax_persistent_cache_min_entry_size_bytes",
+        "jax_compilation_cache_include_metadata_in_key")}
     yield
     for k, v in saved.items():
         jax.config.update(k, v)
@@ -383,6 +384,9 @@ def test_compile_cache_default_is_one_fixed_path_in_the_checkout(
     assert tuning.enable_compile_cache() == want
     assert tuning.enable_compile_cache() == want        # idempotent
     assert jax.config.jax_compilation_cache_dir == want
+    # op_names are what a profile names the tick's phases by: an entry
+    # written by another build must not serve its own (ISSUE 24)
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
     assert tuning.tuning_cache_dir(RuntimeOptions()) \
         == os.path.join(root, ".cache", "ponyc_tpu", "tuning")
     assert not hasattr(tuning, "compile_cache_dir")
