@@ -244,8 +244,8 @@ def prometheus_text(snap: Dict[str, Any],
     phases = snap.get("phases") or {}
     if phases:
         fam("pony_tpu_phase_work_total", "counter",
-            "Per-phase work units (delivery/drain/dispatch/gc_mark "
-            "tick-cost lanes, state.PHASE_NAMES)",
+            "Per-phase work units (delivery/drain/dispatch/gc_mark/"
+            "rebuild tick-cost lanes, state.PHASE_NAMES)",
             [({"phase": k}, v) for k, v in sorted(phases.items())])
     measured = snap.get("measured") or {}
     if measured:

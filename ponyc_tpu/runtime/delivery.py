@@ -17,16 +17,31 @@ within one shard of the actor world:
   3. per-target segment bounds come from a vectorised binary search over
      the sorted keys; each target accepts min(count, free-space), so
      rejections are always the newest suffix per target, keeping FIFO safe;
-  4. the mailbox table is rebuilt slot-plane by slot-plane: ring slot c of
-     every actor at once takes sorted entry seg_start + (c - tail) % cap.
+  4. the mailbox table is rebuilt by ARRIVAL RANK, in blocks: block k
+     pulls, for every actor at once, sorted entries seg_start + r for
+     the REBUILD_BLOCK ranks r = k*B .. k*B+B-1, and rank r lands in
+     ring slot (tail + r) % cap where r < accepted. Only as many blocks
+     run as the fullest mailbox of the tick needs (`max(acc)`, a
+     `lax.while_loop`): the gather — the expensive part, ~6-9 ns an
+     index on a v5e whatever it fetches — costs B*N indices a block
+     instead of cap*N a tick, of which a steady world uses one block.
      TPU-first design notes: (a) XLA lowers large scatters to serial
      loops on TPU, so the one scatter the CPU-obvious design would use
      was the whole step's bottleneck — the gather form is fully
      vectorised; (b) the mailbox table is laid out [cap, words, N] with
      the actor axis minor-most, so each plane op is a full-width
-     128-lane vector op and the per-plane pull from the sorted entries
+     128-lane vector op and the per-block pull from the sorted entries
      is a plain 1-D lane gather (see state.py's layout note — the
      actor-major form ran ~30× slower on real TPU from tile padding);
+     (c) a pulled rank is PLACED with a select chain over the table
+     (`where(slot's rank == r, pulled[r], table)`, B selects XLA fuses
+     into one pass at memory speed), never with `take_along_axis` over
+     the slot axis: that lowers to the same per-index gather over all
+     cap*N slots the blocks exist to avoid; (d) a ring no deeper than
+     one block (`mailbox_cap <= REBUILD_BLOCK`) is rebuilt slot-plane by
+     slot-plane in one gather with no depth reduction and no loop — the
+     same indices, and the idle tick of a small world is counted in
+     operations;
   5. rejections compact into the next spill buffer and their locally
      resident senders mute (≙ ponyint_maybe_mute: mute on sending to an
      overloaded/muted receiver, actor.c:898-921). Both are *pressure
@@ -88,6 +103,10 @@ class DeliveryResult(NamedTuple):
     plan_key: jnp.ndarray      # [E] the key vector this plan sorts
     plan_perm: jnp.ndarray     # [E] cached stable-sort permutation
     plan_bounds: jnp.ndarray   # [n_local+1] cached segment bounds
+    rebuild_blocks: jnp.ndarray  # [] int32 rank blocks the mailbox
+    #                               rebuild ran this tick (each gathers
+    #                               rebuild_block_ranks(cap) * n_local
+    #                               slots; 0 on a tick with no message)
 
 
 def mute_ref_slots(trig, mute_row, refs, *, n: int, k: int):
@@ -108,6 +127,81 @@ def mute_ref_slots(trig, mute_row, refs, *, n: int, k: int):
 
 def empty_mute_slots(n: int, k: int):
     return jnp.full((k, n), -1, jnp.int32), jnp.zeros((n,), jnp.bool_)
+
+
+# Arrival ranks one rebuild block gathers for every actor: a vreg's
+# sublanes, and RuntimeOptions' default `batch` — an actor that keeps
+# taking in more than it drains is under pressure, not in steady state.
+REBUILD_BLOCK = 8
+
+
+def rebuild_block_ranks(mailbox_cap: int) -> int:
+    """Ranks (ring slots per actor) one block of the rebuild gathers."""
+    return min(REBUILD_BLOCK, mailbox_cap)
+
+
+def rebuild_tables(tables, wds, tail, acc, seg_start, *, mailbox_cap: int):
+    """Write this tick's accepted messages into the ring tables.
+
+    `tables` = [(table [cap, rows, nn], s0, s1, r0, r1)]: the table of
+    actors [s0, s1) takes word rows [r0, r1) of the sorted entries `wds`
+    — every cohort's mailbox at its own width and, with causal tracing
+    on, its trace side lanes through the SAME (mask, source) pairs, so
+    context and message cannot land in different slots. Arrival rank r
+    of actor i (r < acc[i]) is entry seg_start[i] + r and lands in ring
+    slot (tail[i] + r) % cap. Returns (new tables, blocks run)."""
+    c = mailbox_cap
+    e = wds.shape[1]
+    rels = (jnp.arange(c, dtype=jnp.int32)[:, None]
+            - tail[None, :]) % c                  # [cap, n] rank of a slot
+
+    if c <= REBUILD_BLOCK:
+        # One block covers the ring: plane c (ring slot c of every
+        # actor) pulls sorted entry seg_start + (c - tail) % cap, all
+        # planes' indices in ONE gather per table. No depth, no loop.
+        wmasks = rels < acc[None, :]
+        srcs = jnp.minimum(seg_start[None, :] + rels, e - 1)
+        out = []
+        for table, s0, s1, r0, r1 in tables:
+            nn = s1 - s0
+            pulled = jnp.take(wds[r0:r1],
+                              srcs[:, s0:s1].reshape(c * nn),
+                              axis=1).reshape(r1 - r0, c, nn)
+            out.append(jnp.where(wmasks[:, None, s0:s1],
+                                 pulled.transpose(1, 0, 2), table))
+        return out, None
+
+    b = REBUILD_BLOCK
+    depth = jnp.max(acc)
+
+    def block(carry):
+        k, tabs = carry
+        # Absolute, like every scope (state.py): the loop body is its
+        # own computation and would otherwise carry no phase.
+        with phase_scope("delivery/rebuild"):
+            ranks = k * b + jnp.arange(b, dtype=jnp.int32)        # [B]
+            srcs = jnp.minimum(seg_start[None, :] + ranks[:, None],
+                               e - 1)                             # [B, n]
+            live = ranks[:, None] < acc[None, :]                  # [B, n]
+            out = []
+            for tab, (_t, s0, s1, r0, r1) in zip(tabs, tables):
+                nn = s1 - s0
+                pulled = jnp.take(wds[r0:r1],
+                                  srcs[:, s0:s1].reshape(b * nn),
+                                  axis=1).reshape(r1 - r0, b, nn)
+                # Placement is a select chain, not a gather over the
+                # slot axis (module docstring, 4c).
+                for j in range(b):
+                    here = (rels[:, s0:s1] == ranks[j]) & live[j, s0:s1]
+                    tab = jnp.where(here[:, None, :], pulled[:, j][None],
+                                    tab)
+                out.append(tab)
+        return k + 1, tuple(out)
+
+    blocks, out = lax.while_loop(
+        lambda carry: carry[0] * b < depth, block,
+        (jnp.int32(0), tuple(t[0] for t in tables)))
+    return list(out), blocks
 
 
 def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
@@ -206,6 +300,8 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
                 lambda _: _compute_plan(key),
                 operand=None)
 
+    one_block = c <= REBUILD_BLOCK     # rebuild_tables' static guard
+
     def _empty_spill():
         refs, ovf = empty_mute_slots(n, mute_slots)
         return (Entries(tgt=jnp.full((spill_cap,), -1, jnp.int32),
@@ -242,40 +338,24 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
         acc = jnp.minimum(cnt, space)            # accepted per target
         new_tail = tail + acc
 
-        # Slot-plane ring rebuild: plane c (ring slot c of every actor)
-        # pulls sorted entry seg_start + (c - tail) % cap. Per COHORT,
-        # at the cohort's own word width: each table's gather touches
-        # [w1_c, cap*rows_c] — a narrow type's rebuild never moves the
-        # widest type's words (the HBM win of per-cohort widths). Within
-        # a cohort all planes' indices still concatenate into ONE gather.
+        # The ring rebuild, by arrival rank and only as deep as this
+        # tick's fullest mailbox (rebuild_tables). Per COHORT, at the
+        # cohort's own word width: a narrow type's rebuild never moves
+        # the widest type's words (the HBM win of per-cohort widths).
         with phase_scope("delivery/rebuild"):
-            rels = (jnp.arange(c, dtype=jnp.int32)[:, None]
-                    - tail[None, :]) % c                 # [cap, n]
-            wmasks = rels < acc[None, :]
-            srcs = jnp.minimum(seg_start[None, :] + rels, e - 1)
-            buf2 = {}
-            for cname, s0, s1, w1c in cohort_layout:
-                nn = s1 - s0
-                pulled = jnp.take(wds[:w1c],
-                                  srcs[:, s0:s1].reshape(c * nn),
-                                  axis=1).reshape(w1c, c, nn)
-                buf2[cname] = jnp.where(wmasks[:, None, s0:s1],
-                                        pulled.transpose(1, 0, 2),
-                                        buf[cname])
-            # Trace side lanes (causal tracing on): the trailing two word
-            # rows land in trace_buf through the SAME (mask, source) pair
-            # as the payload — context and message are inseparable.
-            tbuf2 = {}
+            tables = [(buf[cname], s0, s1, 0, w1c)
+                      for cname, s0, s1, w1c in cohort_layout]
             if trace_buf is not None:
+                # Trace side lanes (causal tracing on): the trailing two
+                # word rows, through the same call as the payload.
                 w1f = wds.shape[0]
-                for cname, s0, s1, _w1c in cohort_layout:
-                    nn = s1 - s0
-                    pulled = jnp.take(wds[w1f - 2:],
-                                      srcs[:, s0:s1].reshape(c * nn),
-                                      axis=1).reshape(2, c, nn)
-                    tbuf2[cname] = jnp.where(wmasks[:, None, s0:s1],
-                                             pulled.transpose(1, 0, 2),
-                                             trace_buf[cname])
+                tables += [(trace_buf[cname], s0, s1, w1f - 2, w1f)
+                           for cname, s0, s1, _w1c in cohort_layout]
+            rebuilt, blocks = rebuild_tables(tables, wds, tail, acc,
+                                             seg_start, mailbox_cap=c)
+            names = [cname for cname, *_ in cohort_layout]
+            buf2 = dict(zip(names, rebuilt))
+            tbuf2 = dict(zip(names, rebuilt[len(names):]))
 
         n_delivered = jnp.sum(acc)
         nrej = jnp.sum(cnt - acc)
@@ -332,17 +412,25 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
             spill, newly_muted, new_refs, new_ovf = lax.cond(
                 any_pressure, pressure, lambda _: _empty_spill(), operand=None)
         return (buf2, tbuf2, new_tail, spill, newly_muted, new_refs,
-                new_ovf, n_delivered, nrej)
+                new_ovf, n_delivered, nrej) + (() if one_block
+                                               else (blocks,))
 
     def no_msgs(_):
         spill, newly_muted, new_refs, new_ovf = _empty_spill()
         return (buf, dict(trace_buf) if trace_buf is not None else {},
                 tail, spill, newly_muted, new_refs, new_ovf,
-                jnp.int32(0), jnp.int32(0))
+                jnp.int32(0), jnp.int32(0)) + (
+                    () if one_block else (jnp.int32(0),))
 
+    any_valid = jnp.any(valid)
     (buf_out, tbuf_out, new_tail, spill, newly_muted, new_refs, new_ovf,
-     n_delivered, nrej) = lax.cond(jnp.any(valid), with_msgs, no_msgs,
-                                   operand=None)
+     n_delivered, nrej, *blocks) = lax.cond(any_valid, with_msgs, no_msgs,
+                                            operand=None)
+    # A ring of one block carries no count out of the cond (its window
+    # stays the program it was): it ran its block iff the tick had a
+    # message.
+    rebuild_blocks = (any_valid.astype(jnp.int32) if one_block
+                      else blocks[0])
 
     n_deadletter = jnp.sum(to_dead.astype(jnp.int32))
     return DeliveryResult(
@@ -355,4 +443,5 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
         n_rejected=nrej,
         n_deadletter=n_deadletter,
         plan_key=key, plan_perm=perm, plan_bounds=bounds,
+        rebuild_blocks=rebuild_blocks,
     )

@@ -44,7 +44,8 @@ from ..config import RuntimeOptions
 from ..ops import pack
 from ..ops.segment import compact_mask, counts_by_key, stable_sort_by
 from ..program import Cohort, Program
-from .delivery import (Entries, deliver, empty_mute_slots, mute_ref_slots)
+from .delivery import (Entries, deliver, empty_mute_slots, mute_ref_slots,
+                       rebuild_block_ranks)
 from .state import (PHASE_NAMES, QW_BUCKETS, PhaseCursor, RtState,
                     layout_sizes, phase_scope)
 
@@ -467,7 +468,7 @@ def profile_lanes(program: Program, opts: RuntimeOptions, st: RtState,
 
 
 def phase_cost_lanes(st: RtState, all_e, drain_facts, nproc_total,
-                     n_spawned, n_destroyed):
+                     n_spawned, n_destroyed, rebuild_slots):
     """Per-phase window telemetry (the device-cost observatory, ISSUE
     19): accumulate one deterministic work-unit tally per scheduler-tick
     phase into st.phase_cost (state.PHASE_NAMES order). ONLY traced when
@@ -488,7 +489,11 @@ def phase_cost_lanes(st: RtState, all_e, drain_facts, nproc_total,
       - dispatch += behaviours actually run (the n_processed increment);
       - gc_mark  += spawn/destroy bookkeeping rows touched (claimed
                     spawns + completed destroys — the slot-lifecycle
-                    work the GC pass marks from).
+                    work the GC pass marks from);
+      - rebuild  += mailbox slots the delivery rebuild gathered: rank
+                    blocks run x ranks a block x local rows
+                    (delivery.rebuild_tables; how deep the fullest
+                    mailbox of each tick made it go).
 
     Work units, not wall time: wall/bytes attribution is the measured
     layer's job (costs.py)."""
@@ -501,6 +506,7 @@ def phase_cost_lanes(st: RtState, all_e, drain_facts, nproc_total,
     pc = pc.at[PHASE_NAMES.index("drain")].add(drained)
     pc = pc.at[PHASE_NAMES.index("dispatch")].add(nproc_total)
     pc = pc.at[PHASE_NAMES.index("gc_mark")].add(n_spawned + n_destroyed)
+    pc = pc.at[PHASE_NAMES.index("rebuild")].add(rebuild_slots)
     return pc
 
 
@@ -1925,9 +1931,10 @@ def build_step(program: Program, opts: RuntimeOptions):
             (beh_runs2, beh_del2, beh_rej2, coh_mt2, qw_hist2,
              qw_enq2) = profile_lanes(program, opts, st, tail0, res,
                                       drain_facts, muted2)
-            phase_cost2 = phase_cost_lanes(st, all_e, drain_facts,
-                                           nproc_total, n_spawned,
-                                           n_destroyed)
+            phase_cost2 = phase_cost_lanes(
+                st, all_e, drain_facts, nproc_total, n_spawned,
+                n_destroyed,
+                res.rebuild_blocks * (rebuild_block_ranks(c) * nl))
         else:
             beh_runs2, beh_del2, beh_rej2 = (st.beh_runs,
                                              st.beh_delivered,

@@ -2078,7 +2078,8 @@ class Runtime:
                                   "queue_wait_p99": int,   #  bucket lo)
                                   "mute_ticks": int}},
              "phases": {"delivery": int, "drain": int, "dispatch": int,
-                        "gc_mark": int},      # cumulative work units
+                        "gc_mark": int,       # cumulative work units
+                        "rebuild": int},      # mailbox slots gathered
              "totals": {"processed", "delivered", "rejected", "badmsg",
                         "deadletter", "mutes", "host_processed"},
              "gc": {"passes", "collected", "blob_slots_reclaimed",

@@ -63,11 +63,14 @@ QW_BUCKETS = 16
 # one work-unit counter per scheduler-tick phase, accumulated on device
 # in engine.phase_cost_lanes. Work units are DETERMINISTIC per-phase
 # tallies (delivery-list entries gathered, ring slots drained,
-# behaviours dispatched, GC bookkeeping rows touched) — not wall time —
+# behaviours dispatched, GC bookkeeping rows touched, mailbox slots the
+# rebuild gathered) — not wall time —
 # so the XLA scan window and the megakernel's jaxpr replay produce
 # bit-identical lanes by construction; wall/bytes attribution is the
 # measured layer's job (costs.py).
-PHASE_NAMES = ("delivery", "drain", "dispatch", "gc_mark")
+# New lanes go at the END: a snapshot written with fewer restores with
+# the missing ones at zero (serialise.py).
+PHASE_NAMES = ("delivery", "drain", "dispatch", "gc_mark", "rebuild")
 N_PHASES = len(PHASE_NAMES)
 
 # Named scopes on the tick's phases (ISSUE 24): every operation of the

@@ -229,12 +229,28 @@ def _unpack_snapshot_arrays(arrays: Dict[str, np.ndarray],
 _ZERO_IF_ABSENT = frozenset({"st.phase_cost"})
 
 
+def _pad_phase_lanes(arr, n_shards: int) -> np.ndarray:
+    """`st.phase_cost` of a snapshot written when state.PHASE_NAMES was
+    shorter: lanes are only ever appended, so each shard's row is
+    padded with zeros for the ones it never counted."""
+    from .runtime.state import N_PHASES
+    arr = np.asarray(arr)
+    if not (n_shards and arr.size and arr.size % n_shards == 0
+            and arr.size // n_shards < N_PHASES):
+        return arr
+    rows = arr.reshape(n_shards, -1)
+    return np.pad(rows, ((0, 0), (0, N_PHASES - rows.shape[1]))).ravel()
+
+
 def _take(arrays, name, like):
     arr = arrays.get(name)
     if arr is None and name in _ZERO_IF_ABSENT:
         return jnp.zeros(like.shape, like.dtype)
     if arr is None:
         raise FingerprintMismatch(f"snapshot is missing array {name!r}")
+    if name == "st.phase_cost":
+        from .runtime.state import N_PHASES
+        arr = _pad_phase_lanes(arr, like.size // N_PHASES)
     if tuple(arr.shape) != tuple(like.shape):
         raise FingerprintMismatch(
             f"array {name!r} shape {tuple(arr.shape)} != "
@@ -876,6 +892,8 @@ def _restore_relayout(rt, header, Z: Dict[str, np.ndarray]) -> None:
                        ("qwait_hist", nd * QW_BUCKETS),
                        ("phase_cost", N_PHASES)):
         src = Z.get(f"st.{name}")
+        if name == "phase_cost" and src is not None:
+            src = _pad_phase_lanes(src, p_old)
         if st[name].size and src is not None and src.size:
             dst = st[name].copy()
             dst[:] = 0

@@ -23,11 +23,15 @@ def test_ubench_sustained(mode):
     assert not bool(aux.spill_overflow)
 
 
+# deep-cap: the ring is deeper than one rebuild block, and the 24
+# producers' first items reach the empty aggregator in one tick — three
+# rank blocks in that tick, one or none in the others (delivery.py, 4).
+@pytest.mark.parametrize("cap", [8, 32], ids=["cap8", "deep-cap"])
 @pytest.mark.parametrize("mode", ["plan", "cosort"])
-def test_fanin_pressure(mode):
+def test_fanin_pressure(mode, cap):
     from ponyc_tpu.models import fanin
     rt = fanin.run(n_producers=24, items_each=30, opts=RuntimeOptions(
-        mailbox_cap=8, batch=2, msg_words=1, max_sends=2, spill_cap=512,
+        mailbox_cap=cap, batch=2, msg_words=1, max_sends=2, spill_cap=512,
         inject_slots=16, delivery=mode))
     assert int(rt.cohort_state(fanin.Aggregator)["total"].sum()) == 24 * 30
 

@@ -148,6 +148,19 @@ def _case(seed, n_w=24, n_s=8, n_seeds=10, vmax=14):
     return w_nxt, s_w, s_s, seeds
 
 
+def _fanin_case(seed, n_w=24, n_s=8, vmax=14):
+    """Every Walker forwards to one of two hubs and every Walker starts
+    with a token, so a hub takes a dozen or more messages in one tick:
+    at `mailbox_cap=32` the rebuild runs more than one rank block."""
+    rng = np.random.default_rng(seed)
+    w_nxt = rng.integers(0, 2, n_w)
+    s_w = rng.integers(0, 2, n_s)
+    s_s = rng.integers(0, n_s, n_s)
+    seeds = ([("w", i, int(rng.integers(3, vmax))) for i in range(n_w)]
+             + [("s", i, int(rng.integers(2, vmax))) for i in range(n_s)])
+    return w_nxt, s_w, s_s, seeds
+
+
 CONFIGS = [
     ("tiny-cap-forces-spill", dict(mailbox_cap=2, batch=1, msg_words=1,
                                    max_sends=2, spill_cap=512,
@@ -170,6 +183,16 @@ CONFIGS = [
     ("pallas-mega", dict(mailbox_cap=2, batch=1, msg_words=1,
                          max_sends=2, spill_cap=512, inject_slots=16,
                          delivery="pallas_mega")),
+    # PR 25: rings deeper than one rebuild block (delivery.REBUILD_BLOCK)
+    # under a fan-in (_fanin_case) that fills more than one block a tick.
+    ("deep-cap", dict(mailbox_cap=32, batch=2, msg_words=1, max_sends=2,
+                      spill_cap=512, inject_slots=32)),
+    ("deep-cap-cosort", dict(mailbox_cap=32, batch=2, msg_words=1,
+                             max_sends=2, spill_cap=512, inject_slots=32,
+                             delivery="cosort")),
+    ("deep-cap-mesh4", dict(mailbox_cap=32, batch=2, msg_words=1,
+                            max_sends=2, spill_cap=1024, inject_slots=32,
+                            mesh_shards=4, quiesce_interval=2)),
 ]
 
 
@@ -232,7 +255,8 @@ def test_uneven_cohorts_on_mesh_match_oracle():
 @pytest.mark.parametrize("seed", [7, 23])
 def test_device_matches_oracle(name, okw, seed):
     n_w, n_s = 24, 8
-    w_nxt, s_w, s_s, seeds = _case(seed, n_w, n_s)
+    case = _fanin_case if name.startswith("deep-cap") else _case
+    w_nxt, s_w, s_s, seeds = case(seed, n_w, n_s)
     want = oracle(n_w, n_s, w_nxt, s_w, s_s, seeds)
     got = run_device(n_w, n_s, w_nxt, s_w, s_s, seeds,
                      RuntimeOptions(**okw))

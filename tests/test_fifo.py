@@ -86,7 +86,10 @@ def _wire(seed, n_cons):
     return n_prod, pairs[:n_prod], pairs[n_prod:]
 
 
-def run_fifo(seed, okw, n_cons=6, items=60):
+def run_fifo(seed, okw, n_cons=6, items=60, chains=1):
+    """`chains` self-send chains a producer: with `batch >= chains` it
+    stamps that many messages an edge a tick, so a consumer's four
+    in-edges bring it 4 * chains arrivals in one tick."""
     n_prod, e1, e2 = _wire(seed, n_cons)
     opts = RuntimeOptions(msg_words=2, **okw)
     rt = Runtime(opts)
@@ -102,7 +105,9 @@ def run_fifo(seed, okw, n_cons=6, items=60):
                          c2=cids[np.asarray([c for c, _ in e2])],
                          slot1=np.asarray([s for _, s in e1], np.int32),
                          slot2=np.asarray([s for _, s in e2], np.int32))
-    rt.bulk_send(pids, Prod.produce, np.full(n_prod, items, np.int32))
+    for _ in range(chains):
+        rt.bulk_send(pids, Prod.produce, np.full(n_prod, items, np.int32))
+    items *= chains
     assert rt.run(max_steps=500_000) == 0, "must quiesce"
     st = rt.cohort_state(Cons)
     bad = st["bad"][:n_cons]
@@ -141,12 +146,19 @@ CONFIGS = [
     ("pallas-mega", dict(mailbox_cap=2, batch=1, max_sends=3,
                          spill_cap=2048, inject_slots=16,
                          delivery="pallas_mega")),
+    # PR 25: rings deeper than one rebuild block (delivery.REBUILD_BLOCK),
+    # four chains a producer at batch 4: a consumer takes 16 stamped
+    # messages in a tick, four an edge, so two rank blocks run and one
+    # edge's messages span both.
+    ("deep-cap", dict(mailbox_cap=32, batch=4, max_sends=3, spill_cap=2048,
+                      inject_slots=32, chains=4)),
 ]
 
 
 @pytest.mark.parametrize("name,okw", CONFIGS, ids=[c[0] for c in CONFIGS])
 def test_per_edge_fifo(name, okw):
-    run_fifo(seed=101, okw=okw)
+    okw = dict(okw)
+    run_fifo(seed=101, chains=okw.pop("chains", 1), okw=okw)
 
 
 def test_per_edge_fifo_more_seeds_tiny():

@@ -259,6 +259,51 @@ def test_phase_lanes_count_ring_work():
     rt.stop()
 
 
+@actor
+class Leaf:
+    hub: Ref[Worker]
+
+    @behaviour
+    def poke(self, st, v: I32):
+        self.send(st["hub"], Worker.work, v)
+        return st
+
+
+@pytest.mark.parametrize("shards", [1, 4], ids=["one-shard", "mesh4"])
+def test_rebuild_lane_counts_slots_gathered(shards):
+    """The `rebuild` lane (PR 25) is rank blocks run x ranks a block x
+    local rows, summed over ticks and shards: 20 leaves poke one hub in
+    one tick (acc = 20 at mailbox_cap 32: three blocks of 8 on the hub's
+    shard, none elsewhere), later 5 do (one block); ticks that deliver
+    nothing gather nothing."""
+    from ponyc_tpu.runtime.delivery import REBUILD_BLOCK
+    rt = Runtime(_opts(mailbox_cap=32, batch=2, analysis=1,
+                       inject_slots=32, mesh_shards=shards))
+    rt.declare(Worker, 1).declare(Leaf, 20).start()
+    hub = rt.spawn(Worker)
+    leaves = rt.spawn_many(Leaf, 20, hub=hub)
+    rt.bulk_send(leaves, Leaf.poke, np.ones(20, np.int32))
+    assert rt.run() == 0
+    rows = rt.program.n_local
+    assert rt.profile()["phases"]["rebuild"] == 3 * REBUILD_BLOCK * rows
+    rt.bulk_send(leaves[:5], Leaf.poke, np.ones(5, np.int32))
+    assert rt.run() == 0
+    assert rt.profile()["phases"]["rebuild"] == 4 * REBUILD_BLOCK * rows
+    assert rt.state_of(hub)["done"] == 25
+    rt.stop()
+
+
+def test_one_block_ring_counts_its_whole_ring():
+    """`mailbox_cap <= REBUILD_BLOCK`: one block of `cap` ranks on every
+    tick that delivers (the 50 hops of the ring's token)."""
+    rt, ids = ring.build(8, _opts(mailbox_cap=4, analysis=1))
+    rt.send(int(ids[0]), ring.RingNode.token, 50)
+    rt.run()
+    ph = rt.profile()["phases"]
+    assert ph["rebuild"] == ph["delivery"] * 4 * rt.program.n_local
+    rt.stop()
+
+
 # ------------------------------------------------------- GC window stats
 
 def test_gc_window_stats_thread_into_profile_and_csv(tmp_path):
@@ -466,13 +511,19 @@ def test_example_smoke_analysis2(tmp_path):
 
 # ------------------------------------- named scopes on the tick (ISSUE 24)
 
-def _lowered_window(delivery):
+# plan / cosort at a one-block ring, and a ring deeper than one rebuild
+# block, whose rebuild is a loop (delivery.rebuild_tables).
+WINDOWS = [("plan", 4), ("cosort", 4), ("plan", 16)]
+WINDOW_IDS = ["plan", "cosort", "plan-deep-cap"]
+
+
+def _lowered_window(delivery, cap):
     import jax
     import jax.numpy as jnp
 
     from ponyc_tpu.models import ubench
     from ponyc_tpu.runtime import engine
-    opts = _opts(mailbox_cap=4, batch=2, delivery=delivery,
+    opts = _opts(mailbox_cap=cap, batch=2, delivery=delivery,
                  tuning_cache="off", compile_cache="off")
     rt, _ids = ubench.build(64, opts, pings=2)
     gated = engine.build_multi_step_gated(rt.program, rt.opts)
@@ -483,20 +534,26 @@ def _lowered_window(delivery):
     return rt, lowered
 
 
-@pytest.mark.parametrize("delivery", ["plan", "cosort"])
-def test_phase_scopes_name_the_lowered_window(delivery):
+@pytest.mark.parametrize("delivery,cap", WINDOWS, ids=WINDOW_IDS)
+def test_phase_scopes_name_the_lowered_window(delivery, cap):
     """Every scope of the vocabulary (state.STEP_SCOPES) appears in the
     lowered window's op_name metadata, under both delivery formulations;
-    `pony/gc_mark` also heads the collection pass's own program."""
+    `pony/gc_mark` also heads the collection pass's own program. The
+    rebuild's loop body is a computation of its own: its operations
+    carry `pony/delivery/rebuild` themselves."""
     import jax
 
     from ponyc_tpu.runtime import gc as gc_mod
     from ponyc_tpu.runtime.state import SCOPE_PREFIX, STEP_SCOPES
-    rt, lowered = _lowered_window(delivery)
+    rt, lowered = _lowered_window(delivery, cap)
     text = lowered.as_text(debug_info=True)
     missing = [s for s in STEP_SCOPES if s != "gc_mark"
                and f"{SCOPE_PREFIX}/{s}/" not in text]
     assert not missing, missing
+    in_body = "rebuild/while/body/pony/delivery/rebuild/"
+    assert (in_body in text) == (cap > 8)
+    if cap > 8:
+        assert in_body + "jit(_take)" in text, "the body's gather"
     import numpy as np
     nl = rt.program.n_local
     gc_text = jax.jit(gc_mod.build_gc(rt.program, rt.opts)).lower(
@@ -524,20 +581,20 @@ def _bare_hlo(text):
     return "\n".join(out)
 
 
-@pytest.mark.parametrize("delivery", ["plan", "cosort"])
-def test_phase_scopes_are_metadata_only(delivery, monkeypatch):
+@pytest.mark.parametrize("delivery,cap", WINDOWS, ids=WINDOW_IDS)
+def test_phase_scopes_are_metadata_only(delivery, cap, monkeypatch):
     """The optimised HLO of the window is the same program with the
     scopes and with the one scope helper stubbed out, once metadata is
     stripped: the names cost the compiled program nothing."""
     import contextlib
 
     from ponyc_tpu.runtime import state
-    _rt, lowered = _lowered_window(delivery)
+    _rt, lowered = _lowered_window(delivery, cap)
     scoped = lowered.compile().as_text()
     assert 'op_name="jit(multi)/while/body/pony/delivery' in scoped
     monkeypatch.setattr(state, "_named_scope",
                         lambda _name: contextlib.nullcontext())
-    _rt, lowered = _lowered_window(delivery)
+    _rt, lowered = _lowered_window(delivery, cap)
     bare = lowered.compile().as_text()
     assert "pony/" not in bare
     assert _bare_hlo(scoped) == _bare_hlo(bare)

@@ -1,0 +1,156 @@
+"""The mailbox rebuild (delivery.rebuild_tables): arrival ranks gathered
+in blocks, as deep as the fullest mailbox of the tick.
+
+Most of tier-1 runs rings of 2-8 slots, which take the one-block form;
+these tests drive `deliver()` itself at `mailbox_cap` 16 and 64 against
+a NumPy oracle that pushes every arrival into its ring one by one, and
+pin the one-block form to a program with no loop and no reduction."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ponyc_tpu.runtime import delivery
+from ponyc_tpu.runtime.delivery import Entries, deliver
+
+N, E = 24, 320
+LAYOUT = [("Narrow", 0, 16, 2), ("Wide", 16, 24, 4)]   # (type, s0, s1, 1+W)
+W1 = 4 + 2                                 # widest payload + trace context
+
+
+def _world(cap, seed):
+    """Tables with recognisable old contents, wrapped monotonic
+    counters, and arrivals that make every acceptance from 0 to `cap`:
+    actor 0 is empty and is sent `cap` (every block runs), actor 1 is
+    sent more than its free space (space-limited), actor 2 nothing."""
+    rng = np.random.default_rng(seed)
+    occ = rng.integers(0, cap + 1, N)
+    occ[0], occ[1], occ[2] = 0, cap - 3, 5
+    head = rng.integers(10 * cap, 1000 * cap, N)
+    tail = head + occ
+    cnt = rng.integers(0, 12, N)
+    cnt[0], cnt[1], cnt[2] = cap, 7, 0
+    cnt[3] = 0 if seed % 2 else 9
+    tgt = np.repeat(np.arange(N), cnt)
+    assert tgt.size <= E - 8
+    # A few dead-lettered and empty entries between the live ones.
+    tgt = np.concatenate([tgt, [-1, N + 3, 5, -1]])
+    tgt = np.concatenate([tgt, np.full(E - tgt.size, -1)])
+    rng.shuffle(tgt)
+    alive = np.ones(N, bool)
+    alive[5] = False
+    words = rng.integers(1, 1 << 30, (W1, E))
+    buf = {name: rng.integers(-99, -1, (cap, w1c, s1 - s0))
+           for name, s0, s1, w1c in LAYOUT}
+    tbuf = {name: rng.integers(-99, -1, (cap, 2, s1 - s0))
+            for name, s0, s1, _w in LAYOUT}
+    return buf, tbuf, head, tail, alive, tgt, words
+
+
+def _oracle(cap, buf, tbuf, head, tail, alive, tgt, words):
+    """Push arrivals one by one, in list order, while there is room."""
+    buf = {k: v.copy() for k, v in buf.items()}
+    tbuf = {k: v.copy() for k, v in tbuf.items()}
+    tail = tail.copy()
+    acc = np.zeros(N, int)
+    for j, t in enumerate(tgt):
+        if not (0 <= t < N) or not alive[t] or tail[t] - head[t] >= cap:
+            continue
+        name, s0, _s1, w1c = next(c for c in LAYOUT if c[1] <= t < c[2])
+        buf[name][tail[t] % cap, :, t - s0] = words[:w1c, j]
+        tbuf[name][tail[t] % cap, :, t - s0] = words[W1 - 2:, j]
+        tail[t] += 1
+        acc[t] += 1
+    return buf, tbuf, tail, acc
+
+
+def _deliver(cap, world, *, cosort, tracing):
+    buf, tbuf, head, tail, alive, tgt, words = world
+    i32 = lambda a: jnp.asarray(a, jnp.int32)
+    return deliver(
+        {k: i32(v) for k, v in buf.items()}, i32(head), i32(tail),
+        jnp.asarray(alive),
+        Entries(i32(tgt), jnp.full((E,), -1, jnp.int32), i32(words)),
+        n_local=N, mailbox_cap=cap, spill_cap=E, overload_occ=cap,
+        shard_base=jnp.int32(0), cohort_layout=LAYOUT, cosort=cosort,
+        trace_buf={k: i32(v) for k, v in tbuf.items()} if tracing else None)
+
+
+@pytest.mark.parametrize("tracing", [False, True], ids=["plain", "traced"])
+@pytest.mark.parametrize("mode", ["plan", "cosort"])
+@pytest.mark.parametrize("cap", [16, 64])
+def test_rebuild_equals_one_by_one_pushes(cap, mode, tracing):
+    for seed in (0, 1):
+        world = _world(cap, seed)
+        want_buf, want_tbuf, want_tail, acc = _oracle(cap, *world)
+        assert acc.max() == cap and acc.min() == 0      # every block ran
+        assert acc[1] == 3                              # space-limited
+        res = _deliver(cap, world, cosort=(mode == "cosort"),
+                       tracing=tracing)
+        np.testing.assert_array_equal(res.tail, want_tail)
+        for name in want_buf:
+            np.testing.assert_array_equal(res.buf[name], want_buf[name])
+        if tracing:
+            for name in want_tbuf:
+                np.testing.assert_array_equal(res.trace_buf[name],
+                                              want_tbuf[name])
+        else:
+            assert res.trace_buf == {}
+        assert int(res.n_delivered) == acc.sum()
+        assert int(res.rebuild_blocks) == cap // delivery.REBUILD_BLOCK
+
+
+def test_rebuild_runs_as_many_blocks_as_the_fullest_mailbox():
+    """Depth follows the input: 1 message -> 1 block, 9 to one actor ->
+    2 blocks, none -> no block, whatever the ring's capacity."""
+    cap = 64
+    buf, tbuf, head, tail, alive, _tgt, words = _world(cap, 0)
+    for sent, blocks in ((0, 0), (1, 1), (8, 1), (9, 2), (17, 3)):
+        tgt = np.full(E, -1)
+        tgt[:sent] = 0
+        tgt[100] = 2 if sent else -1
+        res = _deliver(cap, (buf, tbuf, head, tail, alive, tgt, words),
+                       cosort=False, tracing=False)
+        assert int(res.rebuild_blocks) == blocks, sent
+        assert int(res.n_delivered) == sent + (sent > 0)
+
+
+def _rebuild_eqns(cap):
+    """Primitive names (a jitted helper's own name for `jit`) of every
+    equation under pony/delivery/rebuild in `deliver`'s jaxpr,
+    sub-jaxprs included."""
+    world = _world(cap, 0)
+    jaxpr = jax.make_jaxpr(
+        lambda: _deliver(cap, world, cosort=False, tracing=True))()
+    names = []
+
+    def walk(jp, inherited):
+        # A sub-jaxpr's name stacks are relative to its equation's.
+        for eqn in jp.eqns:
+            under = inherited or ("pony/delivery/rebuild"
+                                  in str(eqn.source_info.name_stack))
+            if under:
+                names.append(eqn.params["name"]
+                             if eqn.primitive.name == "jit"
+                             else eqn.primitive.name)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, under)
+    walk(jaxpr.jaxpr, False)
+    return names
+
+
+def test_one_block_ring_has_no_loop_and_no_reduction():
+    """`mailbox_cap <= REBUILD_BLOCK`: the slot-plane program, in which
+    two more operations are the ring cell's whole 1% bound."""
+    tables = 2 * len(LAYOUT)                        # buf + trace_buf
+    small = _rebuild_eqns(8)
+    assert "while" not in small and "reduce_max" not in small
+    assert small.count("_take") == tables
+    # One select a table; `_take` and the `%` of `rels` hold one each.
+    assert small.count("_where") == tables + tables + 1
+    deep = _rebuild_eqns(16)
+    assert deep.count("while") == 1 and deep.count("reduce_max") == 1
+    assert deep.count("_take") == tables            # one gather a block
+    assert deep.count("_where") == (tables * delivery.REBUILD_BLOCK
+                                    + tables + 1)

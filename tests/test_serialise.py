@@ -97,6 +97,35 @@ def test_geometry_change_relayouts_since_v3(tmp_path):
         rt2.cohort_state(ring.RingNode)["passes"], want)
 
 
+@pytest.mark.parametrize("target", ["same-layout", "relayout",
+                                    "relayout-mesh2"])
+def test_older_phase_lanes_restore_with_new_lane_at_zero(tmp_path, target):
+    """A snapshot written at analysis>=1 when state.PHASE_NAMES had only
+    its first four lanes still restores — same layout and re-laid-out —
+    with the counted lanes kept and the `rebuild` lane at zero."""
+    from ponyc_tpu.runtime.state import PHASE_NAMES
+    rt, ids = _build_ring(8, _opts(analysis=1))
+    rt.send(int(ids[0]), ring.RingNode.token, 300)
+    rt.run(max_steps=40)
+    assert rt.profile()["phases"]["rebuild"] > 0
+    header, arrays = serialise.capture(rt)
+    old = arrays["st.phase_cost"].reshape(1, len(PHASE_NAMES))[:, :4]
+    assert PHASE_NAMES[4:] == ("rebuild",)
+    arrays["st.phase_cost"] = np.ascontiguousarray(old.ravel())
+    path = str(tmp_path / "four-lanes.npz")
+    serialise.write_snapshot(header, arrays, path)
+
+    okw = {"same-layout": {}, "relayout": dict(mailbox_cap=16),
+           "relayout-mesh2": dict(mesh_shards=2)}[target]
+    rt2, _ = _build_ring(8, _opts(analysis=1, **okw))
+    serialise.restore(rt2, path)
+    want = dict(rt.profile()["phases"], rebuild=0)
+    assert rt2.profile()["phases"] == want
+    rt2.run()
+    assert rt2.profile()["phases"]["delivery"] == 300
+    assert rt2.profile()["phases"]["rebuild"] > 0
+
+
 def test_host_actor_state_round_trips(tmp_path):
     @actor
     class Keeper:
