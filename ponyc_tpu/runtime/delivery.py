@@ -364,16 +364,17 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
         # --- pressure paths, traced under a nested cond so the quiet
         # busy state pays nothing (≙ mute bookkeeping only on overload).
         def pressure(_):
-            rank = jnp.arange(e, dtype=jnp.int32) - seg_start[ktc]
-            ok = kt < n
-            rej = ok & (rank >= acc[ktc])
-            perm2, vspill, _ = compact_mask(rej, spill_cap)
-            snd = snd_s if cosort else sender[perm]
-            spill = Entries(
-                tgt=jnp.where(vspill, kt[perm2], -1),
-                sender=jnp.where(vspill, snd[perm2], -1),
-                words=jnp.where(vspill[None, :], wds[:, perm2], 0),
-            )
+            with phase_scope("delivery/pressure/spill"):
+                rank = jnp.arange(e, dtype=jnp.int32) - seg_start[ktc]
+                ok = kt < n
+                rej = ok & (rank >= acc[ktc])
+                perm2, vspill, _ = compact_mask(rej, spill_cap)
+                snd = snd_s if cosort else sender[perm]
+                spill = Entries(
+                    tgt=jnp.where(vspill, kt[perm2], -1),
+                    sender=jnp.where(vspill, snd[perm2], -1),
+                    words=jnp.where(vspill[None, :], wds[:, perm2], 0),
+                )
             # Mute triggers (≙ actor.c:898-921 + mute rules
             # actor.c:1171-1235): a valid send whose receiver rejected it,
             # is now over the overload threshold, or has DECLARED pressure
@@ -382,23 +383,24 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
             # reference's !OVERLOADED/UNDER_PRESSURE guard, which prevents
             # mute deadlocks among hot actors). Only senders resident on
             # this shard can be muted here.
-            recv_hot = occ_after[ktc] > overload_occ
-            if pressured is not None:
-                recv_hot = recv_hot | pressured[ktc]
-            lsnd = snd - shard_base
-            sender_local = (lsnd >= 0) & (lsnd < n)
-            sc = jnp.minimum(jnp.maximum(lsnd, 0), n - 1)
-            sender_hot = occ_after[sc] > overload_occ
-            if pressured is not None:
-                # ≙ the UNDER_PRESSURE half of the sender exemption: a
-                # sender that itself declared pressure never mutes.
-                sender_hot = sender_hot | pressured[sc]
-            trig = ok & sender_local & (rej | recv_hot) & ~sender_hot
-            mute_row = jnp.where(trig, sc, n)
-            newly_muted = jnp.zeros((n,), jnp.bool_).at[mute_row].max(
-                trig, mode="drop")
-            refs, ovf = mute_ref_slots(trig, mute_row, kt + shard_base,
-                                       n=n, k=mute_slots)
+            with phase_scope("delivery/pressure/mute"):
+                recv_hot = occ_after[ktc] > overload_occ
+                if pressured is not None:
+                    recv_hot = recv_hot | pressured[ktc]
+                lsnd = snd - shard_base
+                sender_local = (lsnd >= 0) & (lsnd < n)
+                sc = jnp.minimum(jnp.maximum(lsnd, 0), n - 1)
+                sender_hot = occ_after[sc] > overload_occ
+                if pressured is not None:
+                    # ≙ the UNDER_PRESSURE half of the sender exemption: a
+                    # sender that itself declared pressure never mutes.
+                    sender_hot = sender_hot | pressured[sc]
+                trig = ok & sender_local & (rej | recv_hot) & ~sender_hot
+                mute_row = jnp.where(trig, sc, n)
+                newly_muted = jnp.zeros((n,), jnp.bool_).at[mute_row].max(
+                    trig, mode="drop")
+                refs, ovf = mute_ref_slots(trig, mute_row, kt + shard_base,
+                                           n=n, k=mute_slots)
             return spill, newly_muted, refs, ovf
 
         with phase_scope("delivery/pressure"):

@@ -86,8 +86,9 @@ N_PHASES = len(PHASE_NAMES)
 SCOPE_PREFIX = "pony"
 STEP_SCOPES = ("unmute", "spawn", "drain", "dispatch", "route",
                "delivery", "delivery/plan", "delivery/permute",
-               "delivery/rebuild", "delivery/pressure", "gc_mark",
-               "mute", "vote")
+               "delivery/rebuild", "delivery/pressure",
+               "delivery/pressure/spill", "delivery/pressure/mute",
+               "gc_mark", "mute", "vote")
 _named_scope = jax.named_scope      # the one seam the tests stub
 
 

@@ -603,7 +603,10 @@ def test_run_phases_nest_carry_window_and_cover_run(monkeypatch):
                if n == "pony:host-work")
     spent = sum(after["phase_s"][p] - before["phase_s"][p]
                 for p in rtmod.RUN_PHASES)
-    assert spent == pytest.approx(wall, rel=0.05)
+    # two host clocks round one short run: under busy test workers the
+    # interpreter can be descheduled between `t0` and run()'s first
+    # stamp, so the relative bound has an absolute slack beside it
+    assert spent == pytest.approx(wall, rel=0.05, abs=0.02)
     assert after["windows_wall_s"] > before["windows_wall_s"]
     assert after["windows_wall_s"] - before["windows_wall_s"] <= wall
 
