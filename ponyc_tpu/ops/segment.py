@@ -13,9 +13,46 @@ import jax
 import jax.numpy as jnp
 
 
+def stable_sort_with_keys(keys: jnp.ndarray):
+    """(sorted keys, permutation) of a stable ascending sort of int32
+    keys: what `argsort` computes and then drops. Reading the sorted
+    keys back as `keys[perm]` is a gather, ~6-9 ns an index on a v5e;
+    the sort that carries them costs ~2 ns a key."""
+    return jax.lax.sort((keys, jax.lax.iota(jnp.int32, keys.shape[0])),
+                        num_keys=1, is_stable=True)
+
+
 def stable_sort_by(keys: jnp.ndarray):
     """Return the permutation that stably sorts int32 keys ascending."""
-    return jnp.argsort(keys, stable=True)
+    return stable_sort_with_keys(keys)[1]
+
+
+def segment_bounds(sorted_keys: jnp.ndarray, num_segments: int,
+                   stride: int = 1) -> jnp.ndarray:
+    """bounds[t] = how many of the ascending int32 `sorted_keys` (each
+    in [0, num_segments * stride]) are below t * stride, t = 0 ..
+    num_segments: `searchsorted(sorted_keys, arange(n + 1) * stride,
+    side="left")`, computed as a MERGE of the two sorted sequences.
+
+    A binary search is ~log2(E) dependent indexed reads a query, and an
+    indexed read is the costliest thing a v5e does (24M of them for 1M
+    queries into 8.4M keys: 167 ms). A sort is its cheapest data-
+    dependent move, so the merge is two one-operand sorts and a prefix
+    sum (~18 ms, same sizes): sort queries and keys together, the flag
+    "is a key" in the low bit so that a query lands before the keys
+    equal to it; a query's bound is then the number of keys before it;
+    and since those counts rise with the query, sorting them (every
+    key's entry pushed to the maximum) brings them to the front in
+    query order. uint32 holds 2 * key + 1 for every int32 key."""
+    n = num_segments
+    queries = jnp.arange(n + 1, dtype=jnp.uint32) * jnp.uint32(2 * stride)
+    merged = jax.lax.sort(jnp.concatenate(
+        [queries, sorted_keys.astype(jnp.uint32) * 2 + 1]), is_stable=False)
+    is_key = (merged & 1).astype(jnp.int32)
+    before = jnp.cumsum(is_key) - is_key
+    return jax.lax.sort(
+        jnp.where(is_key == 1, jnp.iinfo(jnp.int32).max, before),
+        is_stable=False)[:n + 1]
 
 
 def segment_ranks(sorted_keys: jnp.ndarray) -> jnp.ndarray:

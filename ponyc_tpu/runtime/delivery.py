@@ -14,9 +14,11 @@ within one shard of the actor world:
      send order; SURVEY.md §7 hard part (c)) because a sender whose message
      was rejected is muted until its spill drains, so it can never emit a
      *newer* message that would overtake an older spilled one;
-  3. per-target segment bounds come from a vectorised binary search over
-     the sorted keys; each target accepts min(count, free-space), so
-     rejections are always the newest suffix per target, keeping FIFO safe;
+  3. per-target segment bounds come from merging the target boundaries
+     into the sorted keys (ops/segment.py `segment_bounds`: sorts and a
+     prefix sum, no indexed read); each target accepts min(count,
+     free-space), so rejections are always the newest suffix per target,
+     keeping FIFO safe;
   4. the mailbox table is rebuilt by ARRIVAL RANK, in blocks: block k
      pulls, for every actor at once, sorted entries seg_start + r for
      the REBUILD_BLOCK ranks r = k*B .. k*B+B-1, and rank r lands in
@@ -68,7 +70,8 @@ from typing import NamedTuple
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.segment import compact_mask, stable_sort_by
+from ..ops.segment import (compact_mask, segment_bounds,
+                           stable_sort_with_keys)
 from .state import phase_scope
 
 
@@ -250,9 +253,10 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
                     n * n_levels).astype(jnp.int32)
 
     # --- the delivery plan: stable-sort permutation + per-target segment
-    # bounds (one vectorised binary search replaces the scatter-add
-    # histogram — see module docstring, point 4; queries at target
-    # boundaries of the composite key span all priority levels).
+    # bounds (a merge of the target boundaries into the sorted keys
+    # replaces the scatter-add histogram — see module docstring, point 3;
+    # queries at target boundaries of the composite key span all priority
+    # levels).
     #
     # Topology-stable traffic (every sustained benchmark's steady state:
     # ubench's in-flight cycle, fan-in's hot edges) produces the *same*
@@ -266,14 +270,15 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
     def _bounds(sorted_key):
         """Per-target segment bounds over an already-sorted key vector
         (shared by both delivery formulations so the key/level encoding
-        lives once)."""
-        return jnp.searchsorted(
-            sorted_key, jnp.arange(n + 1, dtype=jnp.int32) * n_levels,
-            side="left").astype(jnp.int32)
+        lives once). In a scope of its own below the plan's: the plan's
+        sort stays the one sort directly under `delivery/plan`, which
+        is how a trace counts the misses."""
+        with phase_scope("delivery/plan/bounds"):
+            return segment_bounds(sorted_key, n, n_levels)
 
     def _compute_plan(k):
-        p_ = stable_sort_by(k)
-        return p_, _bounds(k[p_])
+        sorted_key, p_ = stable_sort_with_keys(k)
+        return p_, _bounds(sorted_key)
 
     w1 = words.shape[0]
     if cosort:

@@ -85,8 +85,8 @@ N_PHASES = len(PHASE_NAMES)
 # is the same program (tests/test_profiler.py).
 SCOPE_PREFIX = "pony"
 STEP_SCOPES = ("unmute", "spawn", "drain", "dispatch", "route",
-               "delivery", "delivery/plan", "delivery/permute",
-               "delivery/rebuild", "delivery/pressure",
+               "delivery", "delivery/plan", "delivery/plan/bounds",
+               "delivery/permute", "delivery/rebuild", "delivery/pressure",
                "delivery/pressure/spill", "delivery/pressure/mute",
                "gc_mark", "mute", "vote")
 _named_scope = jax.named_scope      # the one seam the tests stub

@@ -603,12 +603,18 @@ def test_run_phases_nest_carry_window_and_cover_run(monkeypatch):
                if n == "pony:host-work")
     spent = sum(after["phase_s"][p] - before["phase_s"][p]
                 for p in rtmod.RUN_PHASES)
-    # two host clocks round one short run: under busy test workers the
-    # interpreter can be descheduled between `t0` and run()'s first
-    # stamp, so the relative bound has an absolute slack beside it
-    assert spent == pytest.approx(wall, rel=0.05, abs=0.02)
-    assert after["windows_wall_s"] > before["windows_wall_s"]
-    assert after["windows_wall_s"] - before["windows_wall_s"] <= wall
+    # Three readings of one clock: the spans lie inside run(), and the
+    # windows' walls tile [first dispatch, last retire], inside run()
+    # too. What run() does BETWEEN two spans (a few lines of the loop
+    # and the spans' own book-keeping, ~0.2 ms a window: 3% of this run
+    # on an idle machine) is in the windows' walls and in `wall` but in
+    # no phase, and it stretches when busy test workers deschedule the
+    # interpreter there. So `spent` is held from above exactly, and
+    # from below to most of the windows' walls, not to a few percent of
+    # a second clock.
+    windows_wall = after["windows_wall_s"] - before["windows_wall_s"]
+    assert 0 < windows_wall <= wall
+    assert 0.8 * windows_wall <= spent <= wall
 
 
 def test_run_phases_cost_little_with_the_profiler_off():
