@@ -28,12 +28,10 @@ explicitly (tests, smoke runs); every result carries `platform`,
 `device_kind` and the device count, so a CPU number can never be read
 as a chip number.
 
-Delivery/dispatch formulation defaults to "auto": Runtime.start()
-calibrates every eligible variant in-executable (ponyc_tpu/tuning.py),
-the JSON gains a `tuning` block with the per-variant tick_ms table, and
-the decision persists in the on-disk tuning cache (steady-state runs
-skip calibration). The jax persistent compile cache is enabled too
-(at $JAX_COMPILATION_CACHE_DIR where set, else the checkout's
+The formulation is what the flags say (`--delivery plan|cosort`,
+`--pallas`, `--fused`; default plan, kernels off): one the program
+cannot serve is refused at start(). The jax persistent compile cache is
+enabled (at $JAX_COMPILATION_CACHE_DIR where set, else the checkout's
 .cache/ponyc_tpu/xla), so a second identical run's warmup_s drops to
 executable-reload time.
 
@@ -44,11 +42,10 @@ attribute the ticks, so the BENCH trajectory records where the time
 went, not just totals. The timed headline pass itself stays level 0.
 
 Usage: python bench.py  [--actors N] [--ticks K] [--platform tpu|cpu]
-                        [--delivery auto|plan|cosort|pallas_mega]
-                        [--fused auto|on|off]
+                        [--delivery plan|cosort]
+                        [--pallas on|off] [--fused on|off]
                         [--trace-smoke] [--metrics-smoke]
                         [--checkpoint-smoke] [--serve-smoke]
-                        [--kernel-smoke]
 
 --trace-smoke adds a `tracing` block: one sampled causal-tracing pass
 (analysis=3, trace_sample=1, PROFILE.md §10) reassembled and checked
@@ -63,16 +60,11 @@ front door (serve.py) driven by loadgen.py at ~2x measured capacity —
 p50/p99 end-to-end latency of admitted requests, shed rate at the
 edge, goodput, and the rings-never-sticky-fail check (PROFILE.md
 §13). A secondary phase that fails still records its error in the
-JSON, but the process then exits non-zero. Every run embeds a
-`kernel` block with the packed bytes/msg model (ops/megakernel.py) at
-the measured escape rate; --kernel-smoke extends it with a bit-for-bit
-plan-vs-pallas_mega A/B on a small world (PROFILE.md §14; CPU interpret
-mode only — the megakernel does not lower on TPU and is refused there).
+JSON, but the process then exits non-zero.
 Env:   PONY_TPU_BENCH_ACTORS / PONY_TPU_BENCH_TICKS /
        PONY_TPU_BENCH_PLATFORM / PONY_TPU_BENCH_DELIVERY /
-       PONY_TPU_BENCH_FUSED / PONY_TPU_BENCH_KERNEL_SMOKE override;
-       PONY_TPU_TUNING_CACHE relocates ("off" disables) the tuning
-       cache; JAX_COMPILATION_CACHE_DIR places the compile cache.
+       PONY_TPU_BENCH_FUSED / PONY_TPU_BENCH_PALLAS override;
+       JAX_COMPILATION_CACHE_DIR places the compile cache.
 """
 
 import argparse
@@ -100,7 +92,6 @@ def history_entry(result):
     delivery, world size), and the measured numbers the scoreboard
     tracks alongside the modelled ones."""
     detail = result.get("detail") or {}
-    kernel = result.get("kernel") or {}
     measured = result.get("measured") or {}
     step = (measured.get("executables") or {}).get("step") or {}
     div = measured.get("model_divergence") or {}
@@ -113,8 +104,6 @@ def history_entry(result):
         "platform": detail.get("platform"),
         "delivery": detail.get("delivery"),
         "actors": detail.get("actors"),
-        "packed_bytes_per_msg": detail.get("packed_bytes_per_msg"),
-        "kernel_ratio": (kernel.get("bytes_per_msg") or {}).get("ratio"),
         "measured_step_bytes": step.get("bytes_accessed"),
         "measured_step_flops": step.get("flops"),
         "measured_step_peak_bytes": step.get("peak_bytes"),
@@ -182,12 +171,13 @@ def run_phase(failed, name, fn, *a, **kw):
         return {"error": f"{type(e).__name__}: {e}"}
 
 
-def tristate(v):
-    """CLI/env spelling of a bool-or-"auto" runtime option."""
+def switch(v):
+    """CLI/env spelling of an on/off runtime option (the environment's
+    default does not pass through argparse's `choices`)."""
     v = str(v).lower()
-    if v == "auto":
-        return "auto"
-    return v in ("1", "true", "yes", "on")
+    if v not in ("1", "on", "0", "off"):
+        raise ValueError(f"expected on/off/1/0, not {v!r}")
+    return v in ("1", "on")
 
 
 def bench_ubench(args):
@@ -204,8 +194,8 @@ def bench_ubench(args):
     opts = RuntimeOptions(mailbox_cap=cap, batch=pings, max_sends=1,
                           msg_words=1, spill_cap=1024, inject_slots=8,
                           delivery=args.delivery,
-                          pallas=tristate(args.pallas),
-                          pallas_fused=tristate(args.fused))
+                          pallas=switch(args.pallas),
+                          pallas_fused=switch(args.fused))
     t0 = time.time()
     rt, ids = ubench.build(args.actors, opts, pings=pings)
     ubench.seed_all(rt, ids, hops=1 << 30, pings=pings)  # ~infinite
@@ -238,17 +228,10 @@ def bench_ubench(args):
 
     processed = rt.counter("n_processed") & 0xFFFFFFFF
     expect = (warm_windows * K + ticks) * args.actors * pings
-    # The bandwidth-diet model at this run's MEASURED escape rate
-    # (ops/megakernel.py): packed bytes per ring record on the hot
-    # path — recorded in every run so the standing telemetry shows
-    # whether real payloads stay inside the int16 lanes.
-    from ponyc_tpu.ops import megakernel as _mk
-    bytes_model = _mk.modelled_bytes_per_msg(
-        rt.opts, _mk.escape_rate_state(rt.state))
     # Measured, not modelled (ISSUE 19): XLA's own cost/memory analysis
     # of THIS run's compiled executables plus the record-move probe,
-    # judged against bytes_model — the `measured` block every BENCH
-    # json carries next to the modelled number. Never sinks a run.
+    # judged against the modelled bytes/msg — the `measured` block
+    # every BENCH json carries. Never sinks a run.
     from ponyc_tpu import costs as _costs
     if getattr(args, "skip_measured", False):
         # --skip-measured: dev-iteration knob only — runs for the
@@ -256,7 +239,7 @@ def bench_ubench(args):
         measured = {"skipped": True}
     else:
         try:
-            measured = _costs.measured_block(rt, modelled=bytes_model)
+            measured = _costs.measured_block(rt)
             # Per-executable wall from the headline timing itself: the
             # measured windows above ARE this executable.
             win_rec = (measured.get("executables") or {}).get("window")
@@ -269,8 +252,6 @@ def bench_ubench(args):
             measured = {"error": str(e)}
     return {
         "measured": measured,
-        "packed_bytes_per_msg": bytes_model["packed_bytes"],
-        "bytes_model": bytes_model,
         "msgs_per_sec": args.actors * pings * ticks / elapsed,
         "pings": pings,
         "elapsed_s": elapsed,
@@ -280,76 +261,9 @@ def bench_ubench(args):
         "processed_counter_ok": bool(processed == expect % (1 << 32)),
         "build_s": build_s,
         "warmup_s": warm_s,
-        # The A/B record: what "auto" measured and picked (tuning.py);
-        # None when every formulation flag was forced.
-        "tuning": rt.tuning_record,
         "delivery": rt.opts.delivery,
         "pallas": rt.opts.pallas,
         "pallas_fused": rt.opts.pallas_fused,
-    }
-
-
-def bench_kernel_smoke(args):
-    """The --kernel-smoke `kernel` A/B block (PROFILE.md §14): the same
-    seeded ubench world advanced through the XLA window
-    (delivery="plan") and through the persistent fused window
-    megakernel (delivery="pallas_mega"), compared BIT-FOR-BIT over
-    every state leaf, with per-variant in-executable tick timings and
-    the bandwidth-diet model at the measured escape rate. On CPU the
-    megakernel runs interpreted — there the timing is a wiring check,
-    not a perf claim (`interpret: true` in the block says so)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from ponyc_tpu import RuntimeOptions, serialise
-    from ponyc_tpu.models import ubench
-    from ponyc_tpu.ops import megakernel as mk
-
-    actors = max(4, min(args.actors, 64))    # interpret-mode friendly
-    pings = args.pings
-    cap = ubench.cap_for_pings(pings, floor=args.cap)
-    ticks = max(2, min(args.ticks, 16))
-    K = max(1, min(args.fuse, ticks))
-    windows = max(1, ticks // K)
-    tick_ms = {}
-    named = {}
-    esc_rate = 0.0
-    for delivery in ("plan", "pallas_mega"):
-        opts = RuntimeOptions(mailbox_cap=cap, batch=pings, max_sends=1,
-                              msg_words=1, spill_cap=64, inject_slots=8,
-                              delivery=delivery)
-        rt, ids = ubench.build(actors, opts, pings=pings)
-        # Representative small-payload traffic: hops fits the int16
-        # lane (and outlives the smoke's few ticks), so the diet model
-        # here shows the packed ratio on clean payloads. The headline
-        # run keeps its ~2^30 hops counter and records the honest
-        # (escape-heavy) rate for THAT traffic in detail/bytes_model.
-        ubench.seed_all(rt, ids, hops=1 << 12, pings=pings)
-        st, inj = rt.state, rt._empty_inject
-        limit = jnp.int32(K)
-        st, aux, _k = rt._multi(st, *inj, limit)      # pays the jit
-        jax.block_until_ready(aux)
-        t0 = time.time()
-        for _ in range(windows):
-            st, aux, _k = rt._multi(st, *inj, limit)
-        jax.block_until_ready(aux)
-        rt.state = st
-        tick_ms[delivery] = round(
-            1e3 * (time.time() - t0) / (windows * K), 4)
-        named[delivery] = serialise._named_state_arrays(rt.state)
-        esc_rate = mk.escape_rate_state(rt.state)
-        model_opts = rt.opts
-    a, b = named["plan"], named["pallas_mega"]
-    mismatched = [k for k in a if not np.array_equal(np.asarray(a[k]),
-                                                     np.asarray(b[k]))]
-    return {
-        "equal_ok": not mismatched,
-        "mismatched": mismatched[:4],
-        "tick_ms": tick_ms,
-        "interpret": mk.interpret_mode(),
-        "actors": actors,
-        "ticks": (windows + 1) * K,
-        "bytes_per_msg": mk.modelled_bytes_per_msg(model_opts, esc_rate),
     }
 
 
@@ -674,9 +588,7 @@ def bench_latency(args, delivery="plan", fused=False):
     from ponyc_tpu import RuntimeOptions
     from ponyc_tpu.models import ring
 
-    # The latency ring reuses the headline run's RESOLVED formulation
-    # (auto calibrating again on the tiny ring layout would measure the
-    # wrong program and pay a second calibration).
+    # The latency ring runs the headline run's formulation.
     opts = RuntimeOptions(mailbox_cap=8, batch=1, max_sends=1, msg_words=1,
                           spill_cap=64, inject_slots=8,
                           delivery=delivery, pallas_fused=fused)
@@ -822,9 +734,7 @@ def bench_perf_smoke(args):
             "ticks": ub["ticks"],
             "delivery": ub["delivery"],
             **dev,
-            "packed_bytes_per_msg": ub["packed_bytes_per_msg"],
         },
-        "kernel": {"bytes_per_msg": ub["bytes_model"]},
         "measured": ub["measured"],
     }
     result["history_path"] = append_history(result)
@@ -848,22 +758,17 @@ def main():
                     default=int(os.environ.get("PONY_TPU_BENCH_PINGS", 4)))
     ap.add_argument("--delivery",
                     default=os.environ.get("PONY_TPU_BENCH_DELIVERY",
-                                           "auto"),
-                    choices=["plan", "cosort", "pallas_mega", "auto"],
-                    help="delivery formulation; 'auto' (default) "
-                    "calibrates plan vs cosort in-executable at start "
-                    "and records the table in the JSON (tuning.py); "
-                    "pallas_mega is interpret-mode only and refused "
-                    "on TPU")
+                                           "plan"),
+                    choices=["plan", "cosort"],
+                    help="delivery formulation (RuntimeOptions.delivery)")
     ap.add_argument("--fused", nargs="?", const="on",
                     default=os.environ.get("PONY_TPU_BENCH_FUSED", "0"),
-                    choices=["on", "off", "auto", "0", "1"],
-                    help="fused Pallas dispatch: on/off/auto "
-                    "(auto adds it to the calibrated variants)")
+                    choices=["on", "off", "0", "1"],
+                    help="fused Pallas dispatch: on/off")
     ap.add_argument("--pallas", nargs="?", const="on",
                     default=os.environ.get("PONY_TPU_BENCH_PALLAS", "0"),
-                    choices=["on", "off", "auto", "0", "1"],
-                    help="Pallas drain kernel: on/off/auto")
+                    choices=["on", "off", "0", "1"],
+                    help="Pallas drain kernel: on/off")
     ap.add_argument("--lat-actors", type=int, default=1024)
     ap.add_argument("--lat-ticks", type=int, default=200)
     ap.add_argument("--platform",
@@ -895,16 +800,6 @@ def main():
                     "(ckpt_cost_us_per_window), per-checkpoint capture/"
                     "write costs, and restore-fast-start time — "
                     "embedded as a `checkpoint` block (PROFILE.md §12)")
-    ap.add_argument("--kernel-smoke", action="store_true",
-                    default=os.environ.get(
-                        "PONY_TPU_BENCH_KERNEL_SMOKE", "0") == "1",
-                    help="megakernel A/B smoke: the same seeded world "
-                    "through delivery=plan and delivery=pallas_mega, "
-                    "compared bit-for-bit, with per-variant tick "
-                    "timings and the packed bytes/msg model — "
-                    "embedded as the `kernel` block (PROFILE.md §14); "
-                    "CPU interpret mode only, the megakernel is "
-                    "refused on TPU")
     ap.add_argument("--serve-smoke", action="store_true",
                     default=os.environ.get(
                         "PONY_TPU_BENCH_SERVE_SMOKE", "0") == "1",
@@ -975,13 +870,6 @@ def main():
     if args.serve_smoke:
         serving_block = run_phase(failed, "serving", bench_serve_smoke,
                                   args, **form)
-    # Megakernel block (PROFILE.md §14): the bandwidth-diet model at
-    # the headline run's measured escape rate rides EVERY json;
-    # --kernel-smoke adds the bit-for-bit plan-vs-pallas_mega A/B.
-    kernel_block = {"bytes_per_msg": ub["bytes_model"]}
-    if args.kernel_smoke:
-        kernel_block.update(run_phase(failed, "kernel",
-                                      bench_kernel_smoke, args))
     msgs_per_sec = ub["msgs_per_sec"]
 
     result = {
@@ -994,14 +882,12 @@ def main():
             "ticks": ub["ticks"],
             "pings": ub["pings"],
             "delivery": ub["delivery"],
-            "delivery_requested": args.delivery,
             "pallas": ub["pallas"],
             "pallas_fused": ub["pallas_fused"],
             "fused_ticks_per_dispatch": ub["fuse"],
             "elapsed_s": round(ub["elapsed_s"], 4),
             "tick_ms": round(ub["tick_ms"], 3),
             "processed_counter_ok": ub["processed_counter_ok"],
-            "packed_bytes_per_msg": ub["packed_bytes_per_msg"],
             "build_s": round(ub["build_s"], 1),
             "warmup_s": round(ub["warmup_s"], 1),
             **dev,
@@ -1013,9 +899,6 @@ def main():
             "latency_hops_ok": lat["hops_ok"],
             "compile_cache": compile_cache,
         },
-        # In-executable tick_ms per eligible variant + the decision —
-        # every bench run IS the A/B record (PROFILE.md §6).
-        "tuning": ub["tuning"],
         # Per-behaviour attribution of a headline-shaped pass at
         # analysis=1 (Runtime.profile(), PROFILE.md §8): the perf
         # trajectory records WHERE the ticks went, not just totals.
@@ -1024,10 +907,6 @@ def main():
         # synchronous loop through the real Runtime.run() (PROFILE.md
         # §9) — the standing record of this PR's win.
         "run_loop": run_loop,
-        # Persistent megakernel + mailbox bandwidth diet (PROFILE.md
-        # §14): packed bytes/msg model at the measured escape rate,
-        # plus the --kernel-smoke bit-for-bit A/B when requested.
-        "kernel": kernel_block,
         # Measured device costs (costs.py, ISSUE 19): XLA's own
         # cost/memory analysis of the headline run's compiled
         # executables, the record-move probe, and the loud

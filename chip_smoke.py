@@ -27,8 +27,7 @@ functions and runs them tiny on the CPU backend):
   (c) serve       serve.build(256) behind a real socket, loadgen traffic
   (d) formulations  the same seeded ubench world under plan / cosort /
                   pallas / pallas_fused, every state leaf bit-for-bit
-                  against plan (interpret=False on a TPU); the window
-                  megakernel must be refused out loud there
+                  against plan (interpret=False on a TPU)
   (e) mesh        (a) and (b) at mesh_shards=4 when >= 4 devices are
                   visible; with fewer it prints "mesh: not run (N
                   device)" — the only permitted non-run
@@ -281,9 +280,8 @@ def phase_formulations(n: int, window: int, windows: int) -> dict:
     bit-for-bit with plan's."""
     import jax
     import numpy as np
-    from ponyc_tpu import Runtime, RuntimeOptions
+    from ponyc_tpu import RuntimeOptions
     from ponyc_tpu.models import ubench
-    from ponyc_tpu.ops import mailbox_kernel
 
     def advance(overrides):
         rt, ids = ubench.build(
@@ -320,21 +318,6 @@ def phase_formulations(n: int, window: int, windows: int) -> dict:
         check(f"{name} == plan bit-for-bit", not bad and
               set(leaves) == set(plan),
               f"{len(compared)} leaves compared, mismatched {bad[:4]}")
-
-    # The fifth formulation has two states and no third: compiled by
-    # Mosaic and equal, or refused out loud. On a TPU the window
-    # megakernel is the second (ops/megakernel.py).
-    if not mailbox_kernel.interpret_mode():
-        rt = Runtime(RuntimeOptions(**UBENCH_GEOMETRY,
-                                    delivery="pallas_mega"))
-        rt.declare(ubench.Pinger, n)
-        try:
-            rt.start()
-        except ValueError as e:
-            print(f"  pallas_mega: refused at start() — {e}", flush=True)
-        else:
-            check("pallas_mega refused at start()", False,
-                  "start() accepted a kernel that does not lower")
     return out
 
 

@@ -56,11 +56,9 @@ CSV_COLUMNS = [
     # windows dispatched behind an in-flight one), and the controller's
     # next window length + state (grow/shrink/steady).
     "window_ticks", "host_gap_us", "ctrl_window", "ctrl_state",
-    # Mailbox bandwidth diet (ops/megakernel.py): bytes per ring record
-    # at this run's delivery formulation — 2 bytes/word inside the
-    # pallas_mega packed kernel boundary, 4 bytes/word on the int32 XLA
-    # paths. Static per run; rides every row so downstream tooling can
-    # turn msgs/s into bytes/s without re-deriving the layout.
+    # Bytes per ring record (4 a word × state.record_words). Static per
+    # run; rides every row so downstream tooling can turn msgs/s into
+    # bytes/s without re-deriving the layout.
     "bytes_msg",
 ]
 
@@ -128,7 +126,7 @@ class Analysis:
                           for b in rt.program.behaviour_table]
         self.dev_names = [c.atype.__name__
                           for c in rt.program.device_cohorts]
-        from .runtime.state import PHASE_NAMES, QW_BUCKETS
+        from .runtime.state import PHASE_NAMES, QW_BUCKETS, record_words
         self.columns = (CSV_COLUMNS
                         + [f"run:{n}" for n in self.beh_names]
                         + [c for n in self.dev_names
@@ -139,12 +137,7 @@ class Analysis:
                         + [f"ph:{n}" for n in PHASE_NAMES])
         self._prev_hist = np.zeros((len(self.dev_names), QW_BUCKETS),
                                    np.int64)
-        # Packed-record width for the bytes_msg column (see
-        # CSV_COLUMNS): int16 lanes inside the megakernel boundary,
-        # int32 words everywhere else.
-        from .ops.megakernel import record_words
-        self.bytes_msg = record_words(rt.opts) * (
-            2 if rt.opts.delivery == "pallas_mega" else 4)
+        self.bytes_msg = 4 * record_words(rt.opts)
         if self.level >= 2:
             self._writer = threading.Thread(target=self._write_loop,
                                             daemon=True)

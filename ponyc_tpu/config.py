@@ -152,19 +152,14 @@ class RuntimeOptions:
     #   overflow between two drains drops spans and counts them
     #   (state.span_dropped) — raise for deep fan-outs
     trace_seed: int = 0            # sampling-hash seed (determinism knob)
-    pallas: Union[bool, str] = False   # route the dispatch mailbox drain
+    pallas: bool = False           # route the dispatch mailbox drain
     #   through the Pallas kernel (ops/mailbox_kernel.py) instead of the
     #   XLA select-chain; interpret-mode on CPU. True on a cohort the
-    #   kernel cannot tile raises at start(). "auto" adds the kernel as
-    #   a calibrated variant (tuning.py) where every dispatching cohort
-    #   is block-aligned; the measured winner is used.
-    pallas_fused: Union[bool, str] = False  # fuse drain + behaviour +
-    #   outbox into ONE Pallas kernel per cohort
-    #   (ops/fused_dispatch.py). True on a cohort it cannot host
-    #   (sync-construction, blob pool, unaligned rows) raises at
-    #   start(). The north-star dispatch kernel; "auto" = calibrate it
-    #   against the XLA path at start() where every dispatching cohort
-    #   can host it, and keep the winner (tuning.py).
+    #   kernel cannot tile raises at start().
+    pallas_fused: bool = False     # fuse drain + behaviour + outbox
+    #   into ONE Pallas kernel per cohort (ops/fused_dispatch.py). True
+    #   on a cohort it cannot host (sync-construction, blob pool,
+    #   unaligned rows) raises at start().
     host_fastpath: bool = True     # host-sender → host-target messages
     #   bypass the device mailbox table: they queue host-side and
     #   dispatch at host boundaries (≙ the main-thread scheduler's
@@ -172,45 +167,16 @@ class RuntimeOptions:
     #   message each other without crossing schedulers). Per-sender-pair
     #   FIFO is preserved (a host sender's messages to a host receiver
     #   ALL take this lane; device senders all take the device lane);
-    #   lifts the host-plane ceiling ~the device-window cost per hop
-    #   (profiling/_bridge_pump.py measures it). False restores the
-    #   everything-through-the-device-table path.
+    #   lifts the host-plane ceiling ~the device-window cost per hop.
+    #   False restores the everything-through-the-device-table path.
     host_fastpath_budget: int = 100_000  # max fast-lane dispatches per
     #   host boundary; leftovers keep the loop busy (starvation guard so
     #   a host ping-pong cannot lock out device progress)
-    dispatch_gating: bool = False  # skip a behaviour's planar evaluation
-    #   under a scalar lax.cond when no lane's current batch slot selects
-    #   it (engine scan_body). Semantics-identical (behaviours are
-    #   lane-local by contract); pays one any-reduction + branch per
-    #   (slot, behaviour) to avoid evaluating cold behaviours — the
-    #   countermeasure to the planar-dispatch heterogeneity cliff
-    #   (profiling/_hetero.py measures; the reference's switch is O(1),
-    #   genfun.c). Off by default until measured on the real chip.
     delivery: str = "plan"         # delivery formulation (delivery.py):
     #   "plan"   — cached stable-sort plan + permutation gathers (skips
     #              the sort when traffic shape repeats);
     #   "cosort" — one stable multi-operand lax.sort per tick that moves
-    #              the payload with the key (no plan, no gathers; wins
-    #              where arbitrary lane gathers lower poorly);
-    #   "pallas_mega" — the fused window megakernel
-    #              (ops/megakernel.py, PROFILE.md §14): the WHOLE gated
-    #              window — delivery gather, mailbox drain, dispatch,
-    #              profiler lanes — as one Pallas kernel with the
-    #              in-window while as a kernel-internal loop, ring
-    #              records crossing the kernel boundary packed into
-    #              int16 lanes + an int32 escape plane. Plan-formulation
-    #              delivery semantics, bit-equivalent by construction —
-    #              in interpret mode on the CPU backend. It does not
-    #              lower on v5e (jax 0.9.0): on a TPU, and for programs
-    #              it cannot serve (mesh shards > 1, pallas/pallas_fused
-    #              forced on), start() raises the reason.
-    #   "auto"   — calibrate the formulations at Runtime.start() by
-    #              timing a short in-executable fused window per
-    #              formulation on the program's real cohort shapes and
-    #              keep the faster one (tuning.py; the decision
-    #              persists in the tuning cache so steady-state starts
-    #              skip calibration; plan and cosort are the
-    #              candidates, never pallas_mega).
+    #              the payload with the key (no plan, no gathers).
     debug_checks: bool = False     # run Runtime.check_invariants() at
     #   every aux fetch (≙ the reference's debug-build queue checkers,
     #   actor.c:57-92; costly — test/debug only)
@@ -271,28 +237,19 @@ class RuntimeOptions:
     #   (the supervisor falls back past corrupt ones, so > 1 is the
     #   crash-safety margin; old files beyond K are deleted)
 
-    # --- autotuning / caches (tuning.py; ≙ nothing in the reference —
-    # its dispatch is one fixed O(1) switch, genfun.c; ours has
-    # formulation choices whose winner is hardware- and shape-dependent,
-    # so the runtime measures instead of a human with a scratch script:
-    # PROFILE.md §6) ---
-    tuning_cache: str = "auto"     # on-disk decision cache for "auto"
-    #   option values, keyed by (platform, jax version, cohort layout,
-    #   geometry). "auto" = $PONY_TPU_TUNING_CACHE or the checkout's
-    #   .cache/ponyc_tpu/tuning; "off" disables (recalibrate every
-    #   start); any other value = explicit directory.
+    # --- caches (tuning.py) ---
+    tuning_cache: str = "auto"     # on-disk memory of the window length
+    #   quiesce_interval="auto" converged to, keyed by (platform, jax
+    #   version, cohort layout, geometry). "auto" =
+    #   $PONY_TPU_TUNING_CACHE or the checkout's .cache/ponyc_tpu/tuning;
+    #   "off" disables (every start begins at the default window); any
+    #   other value = explicit directory.
     compile_cache: str = "auto"    # jax persistent compilation cache:
     #   "auto" = on (accelerator backends; the CPU backend keeps it off,
     #   tuning.enable_compile_cache says why), at
     #   $JAX_COMPILATION_CACHE_DIR where that is set (the code then sets
     #   no directory) and else at the checkout's .cache/ponyc_tpu/xla;
     #   "off" leaves jax.config untouched.
-    tuning_ticks: int = 0          # in-executable ticks per calibration
-    #   window (lax.fori_loop trip count, so the per-call launch cost
-    #   divides out). 0 = auto-size from the synthetic workload's
-    #   sustain.
-    tuning_repeats: int = 3        # timed windows per variant (the
-    #   median is kept; the first, compile-bearing window never counts)
 
     # --- device blob pool (≙ rich message payloads: pony_alloc_msg +
     # actor-heap objects riding messages, pony.h:332-360 / genfun.c.
@@ -325,10 +282,8 @@ class RuntimeOptions:
             raise ValueError("msg_words must be >= 1")
         if self.batch < 1:
             raise ValueError("batch must be >= 1")
-        if self.delivery not in ("plan", "cosort", "pallas_mega",
-                                 "auto"):
-            raise ValueError("delivery must be 'plan', 'cosort', "
-                             "'pallas_mega' or 'auto'")
+        if self.delivery not in ("plan", "cosort"):
+            raise ValueError("delivery must be 'plan' or 'cosort'")
         if isinstance(self.quiesce_interval, str):
             if self.quiesce_interval != "auto":
                 raise ValueError(
@@ -340,17 +295,12 @@ class RuntimeOptions:
             raise ValueError(
                 "need 1 <= quiesce_interval_min <= quiesce_interval_max")
         for name in ("pallas", "pallas_fused"):
-            v = getattr(self, name)
-            if not (v is True or v is False or v == "auto"):
-                raise ValueError(f"{name} must be True, False or 'auto'")
+            if not isinstance(getattr(self, name), bool):
+                raise ValueError(f"{name} must be True or False")
         if self.compile_cache not in ("auto", "off"):
             raise ValueError(
                 "compile_cache must be 'auto' or 'off' (place the cache "
                 "with JAX_COMPILATION_CACHE_DIR)")
-        if self.tuning_repeats < 1:
-            raise ValueError("tuning_repeats must be >= 1")
-        if self.tuning_ticks < 0:
-            raise ValueError("tuning_ticks must be >= 0 (0 = auto)")
         if self.analysis_flush_ms < 0:
             raise ValueError("analysis_flush_ms must be >= 0")
         if self.trace_sample < 0:
@@ -409,27 +359,24 @@ class RuntimeOptions:
 
 _FLAG_TYPES = {f.name: f.type for f in dataclasses.fields(RuntimeOptions)}
 
-# bool-or-"auto" tri-state flags: bare flag spells True, "auto" survives
-# coercion (everything else parses like a bool).
-_TRISTATE = ("pallas", "pallas_fused")
-
 # int-or-"auto" flags ("auto" survives coercion, anything else is int).
 _INT_OR_AUTO = ("quiesce_interval",)
 
 
 def _is_boolish(name: str) -> bool:
-    return name in _TRISTATE or _FLAG_TYPES[name] in ("bool", bool)
+    return _FLAG_TYPES[name] in ("bool", bool)
 
 
 def _coerce(name: str, raw: str):
     ty = _FLAG_TYPES[name]
-    if name in _TRISTATE:
-        return "auto" if raw.lower() == "auto" else (
-            raw.lower() in ("1", "true", "yes", "on", ""))
     if name in _INT_OR_AUTO:
         return "auto" if raw.lower() == "auto" else int(raw)
     if ty in ("bool", bool):
-        return raw.lower() in ("1", "true", "yes", "on", "")
+        if raw.lower() in ("1", "true", "yes", "on", ""):
+            return True
+        if raw.lower() in ("0", "false", "no", "off"):
+            return False
+        raise ValueError(f"{name} must be true or false, not {raw!r}")
     if ty in ("int", int, "Optional[int]", Optional[int]):
         return int(raw)
     if ty in ("float", float, "Optional[float]", Optional[float]):
@@ -485,11 +432,3 @@ def strip_runtime_flags(argv: Optional[List[str]] = None,
         i += 1
     base = options_from_env(base)
     return dataclasses.replace(base, **overrides), rest
-
-
-def auto_fields(opts: RuntimeOptions) -> List[str]:
-    """Option fields whose value is the "auto" sentinel — the set the
-    tuner (tuning.py) must resolve to concrete values before the engine
-    traces (the engine only ever sees concrete formulations)."""
-    return [n for n in ("delivery", "pallas", "pallas_fused")
-            if getattr(opts, n) == "auto"]

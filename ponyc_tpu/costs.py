@@ -2,10 +2,9 @@
 observability substrate ROADMAP item 2's real-silicon speed run
 dispatches on).
 
-Every megakernel claim so far (window fusion, the 2.0x bytes/msg diet)
-is interpret-mode or *modelled*: ops/megakernel.modelled_bytes_per_msg
-prices a ring record from the layout alone. This module pulls the
-numbers XLA itself reports for the REAL executables — the Halide
+``modelled_bytes_per_msg`` prices a ring record from the layout alone.
+This module pulls the numbers XLA itself reports for the REAL
+executables — the Halide
 push-memory paper's discipline (PAPERS.md): HBM traffic is measured
 before/after staging a pipeline, never assumed — and the
 resource-consumption-preserving actors→Haskell translation's posture of
@@ -165,6 +164,14 @@ def capture(rt, force: bool = False) -> Dict[str, Any]:
 _PROBE_CACHE: Dict[tuple, Dict[str, Any]] = {}
 
 
+def modelled_bytes_per_msg(opts) -> Dict[str, Any]:
+    """The model the probe is judged against: a ring record moves as
+    int32 words, 4 bytes each."""
+    from .runtime.state import record_words
+    w1 = record_words(opts)
+    return {"record_words": w1, "unpacked_bytes": 4.0 * w1}
+
+
 def record_move_probe(opts, n: int = 4096) -> Dict[str, Any]:
     """Measure what XLA actually charges to move one mailbox ring
     record per actor: compile ``record + 1`` over a [record_words, n]
@@ -177,7 +184,7 @@ def record_move_probe(opts, n: int = 4096) -> Dict[str, Any]:
     import jax
     import jax.numpy as jnp
 
-    from .ops.megakernel import record_words
+    from .runtime.state import record_words
     w1 = record_words(opts)
     # The probe depends only on (record_words, n, backend) — memoize
     # per process so repeated measured_block calls pay one compile.
@@ -222,11 +229,9 @@ def measured_block(rt, modelled: Optional[Dict[str, Any]] = None,
     real executables' cost/memory analysis, the record-move probe, the
     modelled bytes/msg it is judged against, and the loud
     ``model_divergence`` verdict."""
-    from .ops.megakernel import escape_rate_state, modelled_bytes_per_msg
     cap = dict(capture(rt))
     if modelled is None:
-        esc = escape_rate_state(rt.state) if rt.state is not None else 0.0
-        modelled = modelled_bytes_per_msg(rt.opts, esc)
+        modelled = modelled_bytes_per_msg(rt.opts)
     probe = record_move_probe(rt.opts)
     div = divergence(modelled["unpacked_bytes"], probe["bytes_per_msg"],
                      tolerance)

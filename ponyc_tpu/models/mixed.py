@@ -6,15 +6,13 @@ that matters on TPU: BEHAVIOUR COUNT per type. The generated dispatch
 switch costs one indirect jump regardless of how many behaviours a type
 has (src/libponyc/codegen/genfun.c); the planar dispatch evaluates
 every behaviour of a cohort per batch slot (engine.py scan_body), so a
-B-behaviour type pays ~B× — this model measures that cliff
-(profiling/_hetero.py) and A/Bs the branch-gating countermeasure
-(RuntimeOptions.dispatch_gating).
+B-behaviour type pays ~B× — this model is the world that shows that
+cliff.
 
 One cohort of N workers; behaviour k bumps a counter and forwards to
 the next worker's behaviour (k+1) % B, so sustained traffic exercises
 every behaviour every tick (the all-hot worst case). `hot=1` builds the
-other extreme: traffic stays on behaviour 0 (one-hot — the case branch
-gating rescues).
+other extreme: traffic stays on behaviour 0 (one-hot).
 """
 
 from __future__ import annotations
@@ -93,9 +91,8 @@ def seed_all(rt: Runtime, ids, wt, hops: int, pings: int = 1,
              mix: bool = False):
     """Default seeding puts every token on step0 → the round-robin wave
     stays PHASE-SYNCHRONIZED (each tick all lanes carry one behaviour
-    id — the case dispatch gating collapses to O(1)). mix=True spreads
-    lanes across all B behaviours → every tick carries every id (the
-    gating worst case: nothing can be skipped)."""
+    id). mix=True spreads lanes across all B behaviours → every tick
+    carries every id."""
     steps = [getattr(wt, f"step{k}")
              for k in range(len(wt.behaviour_defs))]
     for _ in range(pings):

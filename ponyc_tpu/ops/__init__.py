@@ -1,5 +1,3 @@
 """Low-level ops: payload packing, segment primitives, and the Pallas
-kernels for the dispatch/delivery hot path — mailbox_kernel (drain),
-fused_dispatch (drain+behaviour+outbox), megakernel (the whole gated
-window in one persistent kernel + the int16/escape-plane record
-codec, PROFILE.md §14)."""
+kernels for the dispatch hot path — mailbox_kernel (drain) and
+fused_dispatch (drain+behaviour+outbox)."""

@@ -51,16 +51,6 @@ within one shard of the actor world:
      state where nothing rejects and nobody is overloaded (≙ the
      reference only walking mute maps when senders actually muted,
      scheduler.c:1478-1494).
-
-Megakernel boundary (PR 11, ops/megakernel.py): under
-delivery="pallas_mega" this module still formulates every pass above —
-the megakernel stages the whole window (this gather-form delivery
-included) to a jaxpr and replays it inside one persistent Pallas
-kernel, so the in-window while no longer round-trips through XLA
-between ticks. The int32 plan/cosort formulations here stay the oracle
-the kernel is differentially tested against; the int16+escape record
-packing (the bandwidth diet) happens only at the kernel operand
-boundary, never in these tables.
 """
 
 from __future__ import annotations

@@ -477,9 +477,8 @@ def phase_cost_lanes(st: RtState, all_e, drain_facts, nproc_total,
     function exactly like profile_lanes).
 
     The tallies are recomputed from facts every dispatch formulation
-    already produces (the profile_lanes recomputation trick), so the XLA
-    scan window and the megakernel's jaxpr replay yield bit-identical
-    lanes by construction:
+    already produces (the profile_lanes recomputation trick), so the
+    lanes are bit-identical whichever formulation ran:
 
       - delivery += valid delivery-list entries gathered this tick
                     (spill retries + host injections + routed sends);
@@ -705,8 +704,6 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
                     jax.ShapeDtypeStruct((rows,), jnp.int32),
                     {t: jax.ShapeDtypeStruct((n, rows), jnp.int32)
                      for t, n in spawn_sites})
-        _honour("pallas_fused=True",
-                fd.refusal(cohort, opts, effects["sync_init"]))
         fnames = tuple(cohort.atype.field_specs.keys())
         fused = (fd.build_fused_dispatch(
             cohort.behaviours, base_gid=base,
@@ -848,18 +845,7 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
                    erf_n, erc_n, erl_n, clm_n, ini_n, blb_acc)
             for j, br in enumerate(branches):
                 take = (do & in_range & (local == j))
-                if opts.dispatch_gating:
-                    # Skip a cold behaviour's whole planar evaluation
-                    # under a scalar cond (≙ the generated dispatch
-                    # switch running only the selected case, genfun.c).
-                    # Behaviour bodies are lane-local by contract, so a
-                    # shard-divergent predicate is safe.
-                    acc = lax.cond(
-                        jnp.any(take),
-                        lambda a, _br=br, _t=take: _merge(_br, _t, a),
-                        lambda a: a, acc)
-                else:
-                    acc = _merge(br, take, acc)
+                acc = _merge(br, take, acc)
             (st_n, tgt_n, wrd_n, ef_n, ec_n, yf_n, sf_n, ds_n,
              erf_n, erc_n, erl_n, clm_n, ini_n, blb_acc) = acc
             if blb_acc is not None:
@@ -920,7 +906,6 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
                         erf_l, erc_l, erl_l, None)
             if opts.pallas:          # gate BEFORE importing pallas/mosaic
                 from ..ops import mailbox_kernel as mk
-                _honour("pallas=True", mk.refusal(cohort))
                 with phase_scope("drain"):
                     msgs, valids = mk.drain_msgs(
                         buf_rows, head_rows, n_run, batch=batch,
@@ -1229,6 +1214,7 @@ def build_step(program: Program, opts: RuntimeOptions):
     *per-shard* coordinates. Wrap with jit (P=1) or shard_map (P>1) via
     jit_step()."""
     assert program.frozen
+    check_kernels(program, opts)
     p = program.shards
     nl = program.n_local
     c = opts.mailbox_cap
@@ -2170,19 +2156,8 @@ def build_multi_step_gated(program: Program, opts: RuntimeOptions):
     window); a gated-out window consumes none (ticks_run == 0 tells the
     host to re-queue them).
     Returns (state, last_aux, ticks_run).
-
-    delivery="pallas_mega" (PROFILE.md §14): the whole window body runs
-    as ONE persistent Pallas kernel (ops/megakernel.py) instead of the
-    XLA while-loop below — same step closure, same gate, bit-equivalent
-    by construction. Interpret mode on the CPU backend only: a program
-    or backend the kernel cannot serve (mesh shards, nested Pallas
-    kernels on, any TPU) is refused (_mega_or_refuse), never run as
-    "plan" under the megakernel's name.
     """
     step = build_step(program, opts)
-    if opts.delivery == "pallas_mega":
-        return _mega_or_refuse(program, opts).build_mega_window(
-            program, opts, step, aux_go)
 
     def multi(st: RtState, inject_tgt, inject_words, limit, force,
               prev_aux: StepAux):
@@ -2217,26 +2192,42 @@ def build_multi_step_gated(program: Program, opts: RuntimeOptions):
     return multi
 
 
-def _honour(what: str, reason) -> None:
-    """An explicitly requested kernel with a refusal (ops.*.refusal) is
-    an error, never a quiet XLA path under the kernel's name.
-    Runtime.start() refuses such a program first
-    (tuning.check_requested); the engine's own calls are the backstop
-    for callers that build a step or window directly."""
-    if reason:
-        raise ValueError(f"{what} cannot be honoured — {reason}")
+def check_kernels(program: Program, opts: RuntimeOptions) -> None:
+    """The one gate on the Pallas switches. `pallas=True` /
+    `pallas_fused=True` on a program with a dispatching cohort the
+    kernel cannot serve (ops.mailbox_kernel.refusal /
+    ops.fused_dispatch.refusal, synchronous construction found by the
+    verify pass's probe tracing) raises, naming the cohort and the
+    reason: never a quiet XLA path under the kernel's name.
+    Runtime.start() calls it before the first trace, build_step for
+    callers that build a step or window themselves."""
+    if not (opts.pallas or opts.pallas_fused):
+        return
+    from .. import verify
+    from ..ops import fused_dispatch as fd
+    from ..ops import mailbox_kernel as mk
+    dispatching = [ch for ch in program.device_cohorts if ch.behaviours]
 
+    def honour(what, reason):
+        if reason:
+            raise ValueError(f"{what} cannot be honoured — {reason}")
 
-def _mega_or_refuse(program: Program, opts: RuntimeOptions):
-    from ..ops import megakernel
-    _honour('delivery="pallas_mega"', megakernel.refusal(program, opts))
-    return megakernel
+    if opts.pallas:
+        for ch in dispatching:
+            honour("pallas=True", mk.refusal(ch))
+    if opts.pallas_fused:
+        for ch in dispatching:
+            sync_init = any(verify.behaviour_effects(
+                b, ch.atype, msg_words=opts.msg_words,
+                default_max_sends=opts.max_sends).sync_spawns
+                for b in ch.behaviours)
+            honour("pallas_fused=True", fd.refusal(ch, opts, sync_init))
 
 
 def build_multi_step(program: Program, opts: RuntimeOptions):
     """The ungated window: `build_multi_step_gated` with tick 0 forced
-    (the pre-pipelining signature — bench.py and the profiling harnesses
-    drive it directly; zero_aux as prev keeps the carry well-typed)."""
+    (the pre-pipelining signature — bench.py drives it directly;
+    zero_aux as prev keeps the carry well-typed)."""
     gated = build_multi_step_gated(program, opts)
 
     def multi(st: RtState, inject_tgt, inject_words, limit):
@@ -2261,53 +2252,6 @@ def zero_aux() -> StepAux:
         n_muted_now=i32(0), n_overloaded_now=i32(0),
         n_rejected=i32(0), n_badmsg=i32(0),
         n_deadletter=i32(0), n_mutes=i32(0), qw_p99=i32(0))
-
-
-def build_forced_window(program: Program, opts: RuntimeOptions):
-    """`limit` ticks of the real step in ONE executable, unconditionally.
-
-    The calibration harness (tuning.py): a `lax.fori_loop` over
-    build_step that — unlike build_multi_step's while — ignores every
-    early-exit fact (host_pending, exit, sticky failure flags), so a
-    synthetic workload's odd corners (spawn-capable cohorts finding no
-    free slot, behaviours exiting on zero-filled state) cannot shorten
-    the trip count. Wall time / `limit` is then a trustworthy per-tick
-    cost: the per-call launch cost divides out.
-    Injections are applied every tick (the tuner passes the empty
-    inject). Same signature family as build_multi_step so
-    _jit_over_mesh wraps it identically.
-
-    delivery="pallas_mega" delegates to the megakernel's forced
-    spelling (ops/megakernel.py) so calibration times the kernel on
-    exactly the trip count every other variant runs."""
-    step = build_step(program, opts)
-    if opts.delivery == "pallas_mega":
-        mega = _mega_or_refuse(program, opts).build_mega_window(
-            program, opts, step, aux_go, forced=True)
-
-        def forced_mega(st: RtState, inject_tgt, inject_words, limit):
-            return mega(st, inject_tgt, inject_words, limit,
-                        jnp.bool_(True), zero_aux())
-
-        return forced_mega
-
-    def forced(st: RtState, inject_tgt, inject_words, limit):
-        def body(_i, carry):
-            s, _aux = carry
-            return step(s, inject_tgt, inject_words)
-
-        stf, auxf = lax.fori_loop(0, limit, body, (st, zero_aux()))
-        return stf, auxf, limit
-
-    return forced
-
-
-def jit_forced_window(program: Program, opts: RuntimeOptions, mesh=None):
-    """Jit the calibration window (extra replicated input: trip count;
-    extra replicated output: the same count, for signature symmetry
-    with jit_multi_step)."""
-    return _jit_over_mesh(build_forced_window(program, opts), program,
-                          opts, mesh, n_extra=1)
 
 
 def _jit_over_mesh(fn, program: Program, opts: RuntimeOptions, mesh,

@@ -45,16 +45,6 @@ Marking is shard-local by design — after migration (engine._route moves
 a blob WITH its routed message) every reachable handle is local to its
 pool's shard; the rare off-shard handle (host injection without
 near=, or a migration drop) is undereferenceable and is collected.
-
-Megakernel cadence note (PR 11, ops/megakernel.py): GC keeps its own
-host-cadence dispatch (Runtime.run fires jit_gc between windows, gated
-by gc_interval) rather than fusing into the persistent window kernel —
-the mark loop's fixpoint trip count is data-dependent and its masked
-scatters want XLA's full scatter lowering, and the windows the kernel
-fuses never spawn or collect mid-window. The megakernel therefore
-reads/writes the same alive/pin/spill tables this pass does, in int32;
-the int16 bandwidth-diet packing exists only at the kernel operand
-boundary and is invisible here.
 """
 
 from __future__ import annotations

@@ -283,9 +283,9 @@ class Runtime:
         self._tracer = None      # tracing.Tracer, set by start() when
         #   opts.tracing (analysis >= 3 and trace_sample > 0)
         self.tuning_record: Optional[Dict[str, Any]] = None   # set by
-        #   start() when any option is "auto" (tuning.resolve): source
-        #   (cache/calibrated/default), per-variant tick_ms table,
-        #   winner — bench.py publishes it as the A/B record
+        #   start() when quiesce_interval is "auto":
+        #   {"quiesce_interval": {source (cache/default), initial,
+        #   bounds}} (tuning.resolve_quiesce_interval)
         # ---- adaptive run loop (PROFILE.md §9) ----
         self._controller: Optional[WindowController] = None  # window
         #   sizer, created at start() (fixed lo==hi when
@@ -444,7 +444,6 @@ class Runtime:
         # before the first jit of this runtime so warm starts reload
         # executables instead of re-lowering.
         from .. import tuning
-        from ..config import auto_fields
         tuning.enable_compile_cache(self.opts.compile_cache)
         self.program.finalize()
         self.state = init_state(self.program, self.opts)
@@ -454,19 +453,9 @@ class Runtime:
             self.state = shard_state(self.state, self.mesh)
         else:
             self.mesh = None
-        if auto_fields(self.opts):
-            # Resolve "auto" formulation choices to measured winners
-            # BEFORE the engine traces (it only ever sees concrete
-            # opts). Calibration runs on throwaway copies of the fresh
-            # state; only delivery/pallas/pallas_fused may change, none
-            # of which affect Program layout or state shapes.
-            self.opts, self.tuning_record = tuning.resolve(
-                self.program, self.opts, self.mesh, self.state)
-            self.program.opts = self.opts
-        # An explicitly requested kernel that cannot run as asked is an
-        # error HERE, naming the cohort and the reason — never a quiet
-        # XLA path under the kernel's name.
-        tuning.check_requested(self.program, self.opts)
+        # A requested kernel that cannot serve this program is an error
+        # HERE, before the first trace.
+        engine.check_kernels(self.program, self.opts)
         # Adaptive quiesce window (runtime/controller.py): resolve the
         # "auto" initial value through the tuning cache (a previous
         # run's converged window for this layout), then hand the bounds
@@ -479,8 +468,7 @@ class Runtime:
                 self.program, self.opts)
             lo = self.opts.quiesce_interval_min
             hi = self.opts.quiesce_interval_max
-            self.tuning_record = {**(self.tuning_record or {}),
-                                  "quiesce_interval": qi_rec}
+            self.tuning_record = {"quiesce_interval": qi_rec}
         else:
             qi = max(1, int(qi))
             lo = hi = qi
@@ -495,7 +483,7 @@ class Runtime:
         # The PIPELINED window (tick 0 gated on-device by the previous
         # window's aux) — only the executable the run loop actually
         # calls gets compiled (jit is lazy), so drivers that use
-        # self._multi directly (bench.py, profiling/) pay nothing here.
+        # self._multi directly (bench.py) pay nothing here.
         self._multi_g = engine.jit_multi_step_gated(
             self.program, self.opts, self.mesh)
         self._zero_aux = engine.zero_aux()

@@ -64,10 +64,9 @@ QW_BUCKETS = 16
 # in engine.phase_cost_lanes. Work units are DETERMINISTIC per-phase
 # tallies (delivery-list entries gathered, ring slots drained,
 # behaviours dispatched, GC bookkeeping rows touched, mailbox slots the
-# rebuild gathered) — not wall time —
-# so the XLA scan window and the megakernel's jaxpr replay produce
-# bit-identical lanes by construction; wall/bytes attribution is the
-# measured layer's job (costs.py).
+# rebuild gathered) — not wall time — so every dispatch formulation
+# produces bit-identical lanes; wall/bytes attribution is the measured
+# layer's job (costs.py).
 # New lanes go at the END: a snapshot written with fewer restores with
 # the missing ones at zero (serialise.py).
 PHASE_NAMES = ("delivery", "drain", "dispatch", "gc_mark", "rebuild")
@@ -157,6 +156,12 @@ def layout_sizes(program: Program, opts: RuntimeOptions):
         bucket = 0
         incoming = s + e_out          # route-spill passthrough + outbox
     return e_out, bucket, s + opts.inject_slots + incoming
+
+
+def record_words(opts: RuntimeOptions) -> int:
+    """Words in one mailbox ring record: behaviour id + payload +
+    trace lanes (w1 below)."""
+    return 1 + opts.msg_words + opts.trace_lanes
 
 
 @jax.tree_util.register_dataclass
@@ -366,14 +371,11 @@ class RtState:
     type_state: Dict[str, Dict[str, jnp.ndarray]]
 
 
-# The int32 word tables eligible for the narrow-dtype "bandwidth diet"
-# (ops/megakernel.py): mailbox ring records, both spill word tables and
-# the per-message trace lanes. These are the hot-path bytes-per-message
-# — behaviour ids and small payload words travel as int16 lanes with an
-# int32 escape plane at the megakernel boundary, and serialise.py can
-# store snapshots in the same packed form (save(packed=True)). Listed
-# here, next to the layout they describe, so the kernel boundary and the
-# snapshot codec can never disagree about WHICH tables pack.
+# The int32 word tables that serialise.save(packed=True) stores as an
+# int16 lane plane + an int32 escape plane: mailbox ring records, both
+# spill word tables and the per-message trace lanes (behaviour ids and
+# most payload words are small). Listed here, next to the layout they
+# describe.
 PACKED_WORD_FIELDS = ("buf", "dspill_words", "rspill_words", "trace_buf")
 
 

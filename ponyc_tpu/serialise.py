@@ -177,17 +177,40 @@ def _is_word_table(name: str) -> bool:
                for f in PACKED_WORD_FIELDS)
 
 
+# The escaped sentinel of the packed codec: int16 min. A lane equal to
+# ESC means "read the escape plane". -32768 itself fits int16 but
+# collides with the sentinel, so it is escaped too — the codec is total
+# on int32.
+ESC = -32768
+
+
+def pack_words_np(w: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """int32 words → (int16 lane plane, int32 escape plane). Lossless:
+    `unpack_words_np(*pack_words_np(w)) == w` for every int32 value."""
+    w = np.asarray(w, np.int32)
+    lo = w.astype(np.int16)
+    fits = (lo.astype(np.int32) == w) & (lo != np.int16(ESC))
+    lo16 = np.where(fits, lo, np.int16(ESC)).astype(np.int16)
+    esc32 = np.where(fits, np.int32(0), w).astype(np.int32)
+    return lo16, esc32
+
+
+def unpack_words_np(lo16: np.ndarray, esc32: np.ndarray) -> np.ndarray:
+    return np.where(lo16 == np.int16(ESC), esc32,
+                    lo16.astype(np.int32)).astype(np.int32)
+
+
 def pack_snapshot_arrays(arrays: Dict[str, np.ndarray],
                          ) -> Dict[str, np.ndarray]:
-    """The snapshot spelling of the mailbox bandwidth diet
-    (ops/megakernel.py): every int32 word table (mailbox rings, spill
+    """save(packed=True): every int32 word table (mailbox rings, spill
     words, trace lanes — state.PACKED_WORD_FIELDS) is stored as an
     int16 lane plane (`<name>.lo16`) plus an int32 escape plane
-    (`<name>.esc32`). The codec is lossless, so a packed snapshot
-    restores bit-identically; the escape plane compresses to almost
-    nothing when payloads are narrow (savez_compressed). `_load_raw`
-    decodes transparently — readers never see the planes."""
-    from .ops.megakernel import pack_words_np
+    (`<name>.esc32`): a word that fits int16 travels in 2 bytes, the
+    rare wide word via the escape plane. The codec is lossless, so a
+    packed snapshot restores bit-identically; the escape plane
+    compresses to almost nothing when payloads are narrow
+    (savez_compressed). `_load_raw` decodes transparently — readers
+    never see the planes."""
     out: Dict[str, np.ndarray] = {}
     for name, a in arrays.items():
         if _is_word_table(name) and a.dtype == np.int32:
@@ -204,7 +227,6 @@ def _unpack_snapshot_arrays(arrays: Dict[str, np.ndarray],
     """Decode `pack_snapshot_arrays` planes back into int32 tables
     (no-op for unpacked snapshots — v3 stays one format, packing is an
     encoding choice per save)."""
-    from .ops.megakernel import unpack_words_np
     out: Dict[str, np.ndarray] = {}
     for name, a in arrays.items():
         if name.endswith(".lo16"):
@@ -484,8 +506,9 @@ def restore(rt, path: str, opts=None) -> None:
     if rt.state is None:
         raise RuntimeError("call start() before restore()")
     if opts is not None:
-        # start() rewrites the "auto" fields (tuning.resolve /
-        # resolve_quiesce_interval) — compare everything else.
+        # start() rewrites quiesce_interval="auto"
+        # (tuning.resolve_quiesce_interval), and the formulation
+        # switches change no layout — compare everything else.
         auto = {"quiesce_interval", "delivery", "pallas", "pallas_fused"}
         a = {k: v for k, v in _opts_dict(opts).items() if k not in auto}
         b = {k: v for k, v in _opts_dict(rt.opts).items()

@@ -41,8 +41,6 @@ CONFIGS = {
                    spill_cap=2048, inject_slots=16, delivery="cosort"),
     "fused": dict(mailbox_cap=4, batch=2, msg_words=1, max_sends=2,
                   spill_cap=2048, inject_slots=16, pallas_fused=True),
-    "gated": dict(mailbox_cap=4, batch=2, msg_words=1, max_sends=2,
-                  spill_cap=2048, inject_slots=16, dispatch_gating=True),
     "mesh4": dict(mailbox_cap=2, batch=1, msg_words=1, max_sends=2,
                   spill_cap=4096, inject_slots=64, mesh_shards=4,
                   quiesce_interval=2),
@@ -111,8 +109,6 @@ FIFO_CONFIGS = {
                  inject_slots=16, mute_age_limit=2),
     "fused": dict(mailbox_cap=4, batch=2, max_sends=3, spill_cap=4096,
                   inject_slots=16, pallas_fused=True),
-    "gated": dict(mailbox_cap=4, batch=2, max_sends=3, spill_cap=4096,
-                  inject_slots=16, dispatch_gating=True),
     "mesh4-bucket": dict(mailbox_cap=2, batch=1, max_sends=3,
                          spill_cap=8192, inject_slots=32, mesh_shards=4,
                          route_bucket=8, quiesce_interval=2),

@@ -1,8 +1,8 @@
-"""Fast bench-wiring smoke test: the fused measurement window driven
-through delivery="auto" at toy scale, so bench.py's harness (counter
-verification + the tuning record every run publishes) can never silently
-rot between chip runs. Everything here runs on the CPU backend the
-suite pins; the no-chip tests below pin that bench.py says so."""
+"""Fast bench-wiring smoke test: the fused measurement window driven at
+toy scale, so bench.py's harness (counter verification, the blocks
+every run publishes) can never silently rot between chip runs.
+Everything here runs on the CPU backend the suite pins; the no-chip
+tests below pin that bench.py says so."""
 
 import argparse
 
@@ -11,7 +11,7 @@ import pytest
 
 def _args(**kw):
     base = dict(actors=64, ticks=8, fuse=4, warmup=1, cap=4, pings=2,
-                delivery="auto", fused="off", pallas="off",
+                delivery="plan", fused="off", pallas="off",
                 lat_actors=64, lat_ticks=40)
     base.update(kw)
     return argparse.Namespace(**base)
@@ -24,9 +24,9 @@ def bench_mod(tmp_path, monkeypatch):
     return bench
 
 
-def test_bench_ubench_auto_smoke(bench_mod):
-    # --skip-measured: this test is about tuning, not the observatory
-    # (covered below) — skip the capture to keep the smoke fast.
+def test_bench_ubench_smoke(bench_mod):
+    # --skip-measured: the observatory is covered below — skip the
+    # capture to keep the smoke fast.
     ub = bench_mod.bench_ubench(_args(skip_measured=True))
     assert ub["measured"] == {"skipped": True}
     # The fused window really advanced the world: every tick dispatched
@@ -34,40 +34,24 @@ def test_bench_ubench_auto_smoke(bench_mod):
     assert ub["processed_counter_ok"]
     assert ub["msgs_per_sec"] > 0
     assert ub["ticks"] == 8 and ub["fuse"] == 4
-    # auto resolved to a concrete formulation...
-    assert ub["delivery"] in ("plan", "cosort")
-    # ...and published a well-formed tuning record: every eligible
-    # variant measured in-executable, the minimum selected.
-    rec = ub["tuning"]
-    assert rec["source"] in ("calibrated", "cache")
-    assert set(rec["table"]) == {"plan", "cosort"}
-    timed = {k: v for k, v in rec["table"].items() if v is not None}
-    assert timed, "no variant produced a timing"
-    assert all(v > 0 for v in timed.values())
-    assert rec["winner"] in timed
-    assert rec["table"][rec["winner"]] == min(timed.values())
-    assert rec["chosen"]["delivery"] == ub["delivery"]
+    # the formulation is the one the flags gave, and nothing was raced
+    assert (ub["delivery"], ub["pallas"], ub["pallas_fused"]) \
+        == ("plan", False, False)
+    assert "tuning" not in ub
 
 
-def test_bench_forced_delivery_skips_tuning(bench_mod):
-    ub = bench_mod.bench_ubench(_args(delivery="plan",
-                                      skip_measured=True))
-    assert ub["processed_counter_ok"]
-    assert ub["delivery"] == "plan"
-    # No formulation was "auto" → no calibration record. (The default
-    # quiesce_interval="auto" still resolves its initial window through
-    # the cache machinery — a lookup, not a calibration — and is the
-    # only key allowed to appear.)
-    rec = ub["tuning"]
-    assert rec is None or set(rec) == {"quiesce_interval"}, rec
-    if rec is not None:
-        assert rec["quiesce_interval"]["source"] in ("default", "cache")
-
-
-def test_bench_latency_uses_resolved_formulation(bench_mod):
+def test_bench_latency_uses_the_delivery_given(bench_mod, monkeypatch):
+    from ponyc_tpu.models import ring
+    seen = []
+    build = ring.build
+    monkeypatch.setattr(
+        ring, "build",
+        lambda n, opts: seen.append(opts) or build(n, opts))
     lat = bench_mod.bench_latency(_args(), delivery="cosort", fused=False)
     assert lat["hops_ok"]
     assert lat["p50_us"] > 0
+    assert [(o.delivery, o.pallas_fused) for o in seen] \
+        == [("cosort", False)]
 
 
 def test_bench_telemetry_block(bench_mod):
@@ -93,7 +77,7 @@ def test_bench_ubench_emits_measured_block(bench_mod):
     assert "error" not in m
     assert m["executables"]["step"]["bytes_accessed"] > 0
     assert m["executables"]["window"]["bytes_accessed"] > 0
-    assert m["modelled"] == ub["bytes_model"]
+    assert m["modelled"] == {"record_words": 2, "unpacked_bytes": 8.0}
     assert m["model_divergence"]["diverged"] is False
 
 
@@ -219,44 +203,13 @@ def test_results_carry_the_device_and_an_honest_unit(bench_mod):
     assert bench_mod.unit_for({"platform": "tpu"}) == "msgs/sec/chip"
 
 
-def test_tristate_parsing(bench_mod):
-    assert bench_mod.tristate("auto") == "auto"
-    assert bench_mod.tristate("on") is True
-    assert bench_mod.tristate("1") is True
-    assert bench_mod.tristate("off") is False
-    assert bench_mod.tristate("0") is False
-
-
-def test_bench_kernel_smoke_block(bench_mod):
-    """The --kernel-smoke `kernel` block (PR 11): the same seeded world
-    through the XLA window and the persistent megakernel must agree
-    bit-for-bit, both variants must produce a timing, and the bandwidth
-    diet must hit the ISSUE acceptance bar (ratio >= 1.8) on the
-    smoke's clean-payload traffic. On CPU the kernel runs interpreted
-    and the block says so."""
-    k = bench_mod.bench_kernel_smoke(_args(actors=16, ticks=4, fuse=2))
-    assert k["equal_ok"], k["mismatched"]
-    assert k["tick_ms"]["plan"] > 0
-    assert k["tick_ms"]["pallas_mega"] > 0
-    bm = k["bytes_per_msg"]
-    assert bm["ratio"] >= 1.8
-    assert bm["packed_bytes"] < bm["unpacked_bytes"]
-    import jax
-    if jax.default_backend() != "tpu":
-        assert k["interpret"] is True
-
-
-def test_bench_ubench_records_packed_bytes(bench_mod):
-    """Every run — not just --kernel-smoke ones — carries the packed
-    record width so the standing telemetry can price msgs/s in bytes."""
-    ub = bench_mod.bench_ubench(_args(ticks=4, fuse=2,
-                                      skip_measured=True))
-    bm = ub["bytes_model"]
-    assert ub["packed_bytes_per_msg"] == bm["packed_bytes"] > 0
-    assert bm["record_words"] == 2          # 1 target + msg_words=1
-    # ubench's ~2^30 hops counters escape the int16 lanes: the model
-    # must report the honest measured rate, not assume clean traffic.
-    assert 0.0 <= bm["escape_rate"] <= 1.0
+def test_switch_parsing(bench_mod):
+    assert bench_mod.switch("on") is True
+    assert bench_mod.switch("1") is True
+    assert bench_mod.switch("off") is False
+    assert bench_mod.switch("0") is False
+    with pytest.raises(ValueError, match="on/off"):
+        bench_mod.switch("auto")
 
 
 def test_failed_phase_is_recorded_and_fails_the_process(bench_mod,

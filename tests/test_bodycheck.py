@@ -513,9 +513,6 @@ def test_shipped_trees_lint_clean_pure_ast():
          # types (Egress/FrontDoor/ServeWorker) and the load generator
          os.path.join(ROOT, "ponyc_tpu", "serve.py"),
          os.path.join(ROOT, "ponyc_tpu", "loadgen.py"),
-         # window megakernel + record codec (PR 11): pure ops module,
-         # no behaviours, but the sweep keeps its AST clean as it grows
-         os.path.join(ROOT, "ponyc_tpu", "ops", "megakernel.py"),
          # device-cost observatory + perf scoreboard (ISSUE 19)
          os.path.join(ROOT, "ponyc_tpu", "costs.py")])
     dt = time.perf_counter() - t0

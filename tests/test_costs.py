@@ -5,7 +5,7 @@ Four layers under test, matching the tentpole:
    compiled executables (capture / Runtime.measured_costs /
    opts.cost_capture), memoized, never advancing the world;
 2. modelled vs measured — on CPU the record-move probe's bytes/msg must
-   agree with megakernel.modelled_bytes_per_msg's unpacked bytes within
+   agree with costs.modelled_bytes_per_msg's unpacked bytes within
    the divergence tolerance, and a seeded mismatch must trip the loud
    model_divergence flag;
 3. the scoreboard — BENCH_HISTORY.jsonl + BENCH_r*.json ingestion,
@@ -100,10 +100,10 @@ def test_record_probe_agrees_with_model_on_cpu():
     lands on the model's unpacked bytes within tolerance on CPU."""
     opts = _opts()
     probe = costs.record_move_probe(opts)
-    from ponyc_tpu.ops.megakernel import (modelled_bytes_per_msg,
-                                          record_words)
+    from ponyc_tpu.runtime.state import record_words
     assert probe["record_words"] == record_words(opts)
-    modelled = modelled_bytes_per_msg(opts, 0.0)["unpacked_bytes"]
+    modelled = costs.modelled_bytes_per_msg(opts)["unpacked_bytes"]
+    assert modelled == 4.0 * record_words(opts)
     assert probe["bytes_per_msg"] is not None
     assert (abs(probe["bytes_per_msg"] - modelled) / modelled
             <= costs.DIVERGENCE_TOLERANCE)
@@ -125,8 +125,7 @@ def test_seeded_divergence_trips_the_flag(plain_rt, capsys):
     """A model that prices the record at 10x reality must be called
     out — loudly (stderr) and in the block itself."""
     rt, _ = plain_rt
-    fake = {"record_words": 2, "unpacked_bytes": 80.0,
-            "packed_bytes": 40.0, "ratio": 2.0, "escape_rate": 0.0}
+    fake = {"record_words": 2, "unpacked_bytes": 80.0}
     blk = costs.measured_block(rt, modelled=fake)
     assert blk["model_divergence"]["diverged"] is True
     assert "MODEL DIVERGENCE" in capsys.readouterr().err

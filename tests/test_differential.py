@@ -177,12 +177,11 @@ CONFIGS = [
     ("fused-kernel", dict(mailbox_cap=4, batch=2, msg_words=1,
                           max_sends=2, spill_cap=512, inject_slots=16,
                           pallas_fused=True)),
-    # PR 11: the whole gated window as ONE persistent Pallas kernel
-    # (ops/megakernel.py, interpret mode on CPU) — must match the
-    # sequential oracle exactly, like every XLA formulation above.
-    ("pallas-mega", dict(mailbox_cap=2, batch=1, msg_words=1,
-                         max_sends=2, spill_cap=512, inject_slots=16,
-                         delivery="pallas_mega")),
+    # The Pallas drain kernel alone (ops/mailbox_kernel.py, interpret
+    # mode on CPU) under XLA dispatch.
+    ("pallas-drain", dict(mailbox_cap=2, batch=1, msg_words=1,
+                          max_sends=2, spill_cap=512, inject_slots=16,
+                          pallas=True)),
     # PR 25: rings deeper than one rebuild block (delivery.REBUILD_BLOCK)
     # under a fan-in (_fanin_case) that fills more than one block a tick.
     ("deep-cap", dict(mailbox_cap=32, batch=2, msg_words=1, max_sends=2,

@@ -253,8 +253,29 @@ def test_v3_restore_keeps_telemetry(tmp_path):
     rt2.stop()
 
 
+def test_pack_roundtrip_boundary_values_np():
+    """The packed snapshot's codec (serialise.pack_words_np) is
+    lossless on every int32, the int16 edges and the sentinel's own
+    value among them."""
+    boundary = np.array(
+        [0, 1, -1, 32767, -32767, -32768, 32768, -32769, 65535, -65536,
+         2**31 - 1, -(2**31), 12345, -12345], np.int32)
+    lo16, esc32 = serialise.pack_words_np(boundary)
+    assert lo16.dtype == np.int16 and esc32.dtype == np.int32
+    np.testing.assert_array_equal(
+        serialise.unpack_words_np(lo16, esc32), boundary)
+    # -32768 collides with the sentinel: it MUST ride the escape plane
+    # even though it fits int16 (the one value the naive range check
+    # gets wrong).
+    i = int(np.where(boundary == -32768)[0][0])
+    assert lo16[i] == serialise.ESC and esc32[i] == -32768
+    # In-range values leave the escape plane zero (what compresses).
+    j = int(np.where(boundary == 12345)[0][0])
+    assert lo16[j] == 12345 and esc32[j] == 0
+
+
 def test_packed_snapshot_cross_dtype_restore(tmp_path):
-    """PR 11 bandwidth diet, snapshot spelling: save(packed=True)
+    """save(packed=True)
     stores the word tables as int16 lanes + an int32 escape plane; a
     mid-flight world whose payloads do NOT fit int16 must restore
     bit-identically to the plain-int32 snapshot of the same instant,

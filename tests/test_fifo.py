@@ -140,12 +140,11 @@ CONFIGS = [
     ("fused-kernel", dict(mailbox_cap=4, batch=2, max_sends=3,
                           spill_cap=2048, inject_slots=16,
                           pallas_fused=True)),
-    # PR 11: persistent fused-window megakernel (ops/megakernel.py);
-    # the per-edge FIFO guarantee must survive the kernel boundary's
-    # int16+escape record packing bit-for-bit.
-    ("pallas-mega", dict(mailbox_cap=2, batch=1, max_sends=3,
-                         spill_cap=2048, inject_slots=16,
-                         delivery="pallas_mega")),
+    # The Pallas drain kernel alone (ops/mailbox_kernel.py): the ring
+    # slots it reads must come out in arrival order.
+    ("pallas-drain", dict(mailbox_cap=2, batch=1, max_sends=3,
+                          spill_cap=2048, inject_slots=16,
+                          pallas=True)),
     # PR 25: rings deeper than one rebuild block (delivery.REBUILD_BLOCK),
     # four chains a producer at batch 4: a consumer takes 16 stamped
     # messages in a tick, four an edge, so two rank blocks run and one
