@@ -18,7 +18,10 @@ within one shard of the actor world:
      into the sorted keys (ops/segment.py `segment_bounds`: sorts and a
      prefix sum, no indexed read); each target accepts min(count,
      free-space), so rejections are always the newest suffix per target,
-     keeping FIFO safe;
+     keeping FIFO safe. What is a property of the target ROW is decided
+     here, over the rows, never per entry of the list: a dead row
+     accepts nothing and its segment is the tick's dead letters; "does
+     anything target a pressured actor" is asked of the counts;
   4. the mailbox table is rebuilt by ARRIVAL RANK, in blocks: block k
      pulls, for every actor at once, sorted entries seg_start + r for
      the REBUILD_BLOCK ranks r = k*B .. k*B+B-1, and rank r lands in
@@ -229,17 +232,15 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
     tgt, sender, words = entries
     e = tgt.shape[0]
 
+    # Liveness is the target ROW's, so no entry asks for it: a send to
+    # a dead slot sorts into that row's segment like any other and is
+    # taken out where the per-row counts are (`cnt_live` below).
     in_range = (tgt >= 0) & (tgt < n)
-    tgt_c = jnp.minimum(jnp.maximum(tgt, 0), n - 1)
-    # Sends to dead slots drop with a counter (the reference's type system
-    # makes this unrepresentable — ORCA keeps receivers alive).
-    to_dead = in_range & ~alive[tgt_c]
-    valid = in_range & ~to_dead
 
     if level is None:
         level = jnp.zeros((e,), jnp.int32)
         n_levels = 1
-    key = jnp.where(valid, tgt * n_levels + level,
+    key = jnp.where(in_range, tgt * n_levels + level,
                     n * n_levels).astype(jnp.int32)
 
     # --- the delivery plan: stable-sort permutation + per-target segment
@@ -323,14 +324,18 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
             snd_s = None
             seg_bounds = bounds
             with phase_scope("delivery/permute"):
-                kt = jnp.where(valid, tgt, n).astype(jnp.int32)[perm]
+                kt = jnp.where(in_range, tgt, n).astype(jnp.int32)[perm]
                 wds = words[:, perm]                 # [w1, E] sorted
         ktc = jnp.minimum(kt, n - 1)
         seg_start = seg_bounds[:-1]              # [n]
-        cnt = seg_bounds[1:] - seg_start         # [n] msgs per target
+        cnt = seg_bounds[1:] - seg_start         # [n] sends per target
+        # Sends to dead slots drop with a counter (the reference's type
+        # system makes this unrepresentable — ORCA keeps receivers alive).
+        cnt_live = jnp.where(alive, cnt, 0)
+        n_deadletter = jnp.sum(cnt - cnt_live)
         occ = tail - head
         space = jnp.maximum(c - occ, 0)
-        acc = jnp.minimum(cnt, space)            # accepted per target
+        acc = jnp.minimum(cnt_live, space)       # accepted per target
         new_tail = tail + acc
 
         # The ring rebuild, by arrival rank and only as deep as this
@@ -353,16 +358,22 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
             tbuf2 = dict(zip(names, rebuilt[len(names):]))
 
         n_delivered = jnp.sum(acc)
-        nrej = jnp.sum(cnt - acc)
+        nrej = jnp.sum(cnt_live - acc)
         occ_after = new_tail - head
 
         # --- pressure paths, traced under a nested cond so the quiet
         # busy state pays nothing (≙ mute bookkeeping only on overload).
         def pressure(_):
+            # An entry to a dead row rides in that row's segment: it must
+            # be neither spilled nor a mute trigger. Liveness is folded
+            # into the tables the [ktc] reads gather anyway (a dead row
+            # "accepts" all of its segment, is never hot, never
+            # pressured); the sender's [sc] reads see the tables as
+            # they are.
             with phase_scope("delivery/pressure/spill"):
                 rank = jnp.arange(e, dtype=jnp.int32) - seg_start[ktc]
                 ok = kt < n
-                rej = ok & (rank >= acc[ktc])
+                rej = ok & (rank >= jnp.where(alive, acc, cnt)[ktc])
                 perm2, vspill, _ = compact_mask(rej, spill_cap)
                 snd = snd_s if cosort else sender[perm]
                 spill = Entries(
@@ -379,9 +390,9 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
             # mute deadlocks among hot actors). Only senders resident on
             # this shard can be muted here.
             with phase_scope("delivery/pressure/mute"):
-                recv_hot = occ_after[ktc] > overload_occ
+                recv_hot = jnp.where(alive, occ_after, 0)[ktc] > overload_occ
                 if pressured is not None:
-                    recv_hot = recv_hot | pressured[ktc]
+                    recv_hot = recv_hot | (pressured & alive)[ktc]
                 lsnd = snd - shard_base
                 sender_local = (lsnd >= 0) & (lsnd < n)
                 sc = jnp.minimum(jnp.maximum(lsnd, 0), n - 1)
@@ -403,33 +414,35 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
             if pressured is not None:
                 # Only when a send actually TARGETS a pressured receiver —
                 # an unrelated actor's long-lived pressure (a stalled socket)
-                # must not make every tick pay the pressure branch.
+                # must not make every tick pay the pressure branch. Asked
+                # of the rows, which have their counts already.
                 any_pressure = any_pressure | jnp.any(
-                    pressured[ktc] & (kt < n))
+                    pressured & (cnt_live > 0))
             spill, newly_muted, new_refs, new_ovf = lax.cond(
                 any_pressure, pressure, lambda _: _empty_spill(), operand=None)
         return (buf2, tbuf2, new_tail, spill, newly_muted, new_refs,
-                new_ovf, n_delivered, nrej) + (() if one_block
-                                               else (blocks,))
+                new_ovf, n_delivered, nrej, n_deadletter) + (
+                    () if one_block else (blocks,))
 
     def no_msgs(_):
         spill, newly_muted, new_refs, new_ovf = _empty_spill()
         return (buf, dict(trace_buf) if trace_buf is not None else {},
                 tail, spill, newly_muted, new_refs, new_ovf,
-                jnp.int32(0), jnp.int32(0)) + (
+                jnp.int32(0), jnp.int32(0), jnp.int32(0)) + (
                     () if one_block else (jnp.int32(0),))
 
-    any_valid = jnp.any(valid)
+    # A tick whose only sends go to dead rows has messages too: it
+    # counts them in with_msgs and delivers nothing.
+    any_valid = jnp.any(in_range)
     (buf_out, tbuf_out, new_tail, spill, newly_muted, new_refs, new_ovf,
-     n_delivered, nrej, *blocks) = lax.cond(any_valid, with_msgs, no_msgs,
-                                            operand=None)
+     n_delivered, nrej, n_deadletter, *blocks) = lax.cond(
+         any_valid, with_msgs, no_msgs, operand=None)
     # A ring of one block carries no count out of the cond (its window
     # stays the program it was): it ran its block iff the tick had a
     # message.
     rebuild_blocks = (any_valid.astype(jnp.int32) if one_block
                       else blocks[0])
 
-    n_deadletter = jnp.sum(to_dead.astype(jnp.int32))
     return DeliveryResult(
         buf=buf_out, trace_buf=tbuf_out, tail=new_tail,
         spill=spill, spill_count=jnp.minimum(nrej, spill_cap),

@@ -1243,9 +1243,6 @@ def build_step(program: Program, opts: RuntimeOptions):
     pri_sorted = sorted({ch.priority for ch in dev_cohorts}, reverse=True)
     pri_rank = {pv: i for i, pv in enumerate(pri_sorted)}
     n_levels = 2 + max(1, len(pri_sorted))
-    prio_row_np = _np.zeros((nl,), _np.int32)
-    for ch in dev_cohorts:
-        prio_row_np[ch.local_start:ch.local_stop] = pri_rank[ch.priority]
     # Per-cohort mailbox widths tiling the local row space (ALL cohorts,
     # device + host) — delivery rebuilds each table at its own width.
     cohort_layout = tuple(
@@ -1755,11 +1752,27 @@ def build_step(program: Program, opts: RuntimeOptions):
                                    incoming.words], axis=1),
         )
 
-        prio_row = jnp.asarray(prio_row_np)
-        snd_in = incoming.sender
-        srow = jnp.where(snd_in >= 0, snd_in, 0) % nl
-        lvl_in = jnp.where(snd_in >= 0, 2 + prio_row[srow],
-                           jnp.int32(2)).astype(jnp.int32)
+        # The level of an incoming entry is its sender's cohort's: a
+        # constant of the program when it has one priority, and on one
+        # chip a constant of each segment of `incoming` (the route
+        # spill, empty there, then one outbox a cohort). Only a mesh
+        # with several priorities has to ask each entry for its sender.
+        if len(pri_sorted) <= 1:
+            lvl_in = jnp.full_like(incoming.tgt, 2)
+        elif p == 1:
+            lvl_in = jnp.concatenate(
+                [jnp.full_like(rspill_e.tgt, 2)]
+                + [jnp.full_like(o.tgt, 2 + pri_rank[ch.priority])
+                   for ch, o in zip(dev_cohorts, out_entries)])
+        else:
+            prio_row = _np.zeros((nl,), _np.int32)
+            for ch in dev_cohorts:
+                prio_row[ch.local_start:ch.local_stop] = pri_rank[ch.priority]
+            snd_in = incoming.sender
+            srow = jnp.where(snd_in >= 0, snd_in, 0) % nl
+            lvl_in = jnp.where(snd_in >= 0,
+                               2 + jnp.asarray(prio_row)[srow],
+                               jnp.int32(2)).astype(jnp.int32)
         lvl_all = jnp.concatenate([
             jnp.zeros_like(dspill_e.tgt),
             jnp.ones_like(inj_local),

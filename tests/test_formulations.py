@@ -137,9 +137,56 @@ def _spawning(**form):
     return rt
 
 
+@actor
+class Mortal:
+    seen: I32
+
+    @behaviour
+    def poke(self, st, v: I32):
+        self.destroy(when=st["seen"] >= 2)        # dies on its third poke
+        return {**st, "seen": st["seen"] + 1}
+
+
+@actor
+class Poker:
+    target: Ref
+    MAX_SENDS = 2
+
+    @behaviour
+    def go(self, st, left: I32):
+        self.send(st["target"], Mortal.poke, left)
+        self.send(self.actor_id, Poker.go, left - 1, when=left > 1)
+        return st
+
+
+def _dying(**form):
+    """Six pokers poke two Mortals for eight ticks; a Mortal takes three
+    pokes a tick and runs one, so it is overloaded (its pokers mute) by
+    the tick it dies on its third poke, with six more queued and the
+    next tick's in flight. Every send to the dead row is a dead letter:
+    counted, never delivered, never spilled, never a mute
+    (tests/test_guards.py has the semantics tick by tick). The
+    formulations of ONE tree agree on every leaf; `plan_key`,
+    `plan_perm` and `plan_bounds` of such a tick are not the parent
+    commit's (PR 30: a dead-lettered entry sorts inside its row's
+    segment, not after the last row)."""
+    rt = Runtime(_opts(mailbox_cap=8, batch=1, max_sends=2, **form))
+    rt.declare(Mortal, 2).declare(Poker, 6).start()
+    mortals = rt.spawn_many(Mortal, 2)
+    pokers = rt.spawn_many(Poker, 6, target=np.repeat(mortals, 3))
+    rt.bulk_send(pokers, Poker.go, [8] * 6)
+    assert rt.run() == 0
+    assert rt.counter("n_destroyed") == 2
+    # 48 pokes: each Mortal ran 3, had 6 more queued when it died
+    # (discarded with the slot), and the other 15 found it dead
+    assert rt.counter("n_deadletter") == 30
+    assert rt.counter("n_rejected") == 0 and rt.counter("n_mutes") == 6
+    return rt
+
+
 WORLDS = {"ubench": _ubench, "fanin-pressure": _fanin_pressure,
           "fanin-deep": _fanin_deep, "ring": _ring, "mixed": _mixed,
-          "spawning": _spawning}
+          "spawning": _spawning, "dying": _dying}
 
 # (world, formulation) -> the refusal the gate must raise
 REFUSED = {("spawning", "pallas_fused"):
