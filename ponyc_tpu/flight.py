@@ -271,7 +271,7 @@ class Watchdog(threading.Thread):
         self.rt = rt
         self.deadline_s = float(deadline_s)
         self.tripped: Optional[Dict[str, Any]] = None
-        self._stop = threading.Event()
+        self._closed = threading.Event()   # not `_stop`: Thread.join calls that
         self._main_ident = threading.main_thread().ident
 
     def effective_deadline(self, phase: Optional[str] = None) -> float:
@@ -315,7 +315,7 @@ class Watchdog(threading.Thread):
 
     def run(self) -> None:
         poll = max(0.01, min(0.25, self.deadline_s / 4.0))
-        while not self._stop.wait(poll):
+        while not self._closed.wait(poll):
             trip = self.check()
             if trip is not None:
                 self.trip(trip)
@@ -350,7 +350,7 @@ class Watchdog(threading.Thread):
             _thread.interrupt_main()
 
     def close(self) -> None:
-        self._stop.set()
+        self._closed.set()
 
 
 # ---- postmortem rendering / diagnosis -------------------------------------

@@ -15,9 +15,36 @@ import os
 import re
 
 
+def compile_cache_forced() -> bool:
+    """PONY_TPU_COMPILE_CACHE_FORCE=1: the hook that re-tests jax's
+    persistent compile cache on the CPU backend (ROADMAP C8)."""
+    return os.environ.get("PONY_TPU_COMPILE_CACHE_FORCE", "0") == "1"
+
+
+def compile_cache_off() -> None:
+    """Switch jax's persistent compile cache off in this process,
+    whatever the machine exports: jax reads JAX_COMPILATION_CACHE_DIR
+    by itself at import and a set directory IS the cache switched on.
+    On the CPU backend a reloaded meshed executable deadlocks its own
+    collectives and aborts the process (tuning.enable_compile_cache has
+    the evidence), so the CPU substrate neither reads nor writes one."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    if not (jax.config.jax_compilation_cache_dir
+            or jax.config.jax_enable_compilation_cache):
+        return                                   # already off
+    jax.config.update("jax_compilation_cache_dir", None)
+    jax.config.update("jax_enable_compilation_cache", False)
+    # jax decides "is the cache used" once, at the first compile; forget
+    # a decision taken before this call.
+    compilation_cache.reset_cache()
+
+
 def force_cpu(n_devices: int | None = None) -> None:
     """Pin this process to the CPU backend, optionally as `n_devices`
-    virtual devices (a stand-in mesh for the sharded engine).
+    virtual devices (a stand-in mesh for the sharded engine), with the
+    persistent compile cache off (`compile_cache_off`) unless the
+    re-test hook forces it.
 
     For tests (tests/conftest.py: 8 virtual devices) and
     ``__graft_entry__.dryrun_multichip``; bench.py's ``--platform cpu``
@@ -38,3 +65,5 @@ def force_cpu(n_devices: int | None = None) -> None:
     os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
     jax.config.update("jax_platforms", "cpu")
+    if not compile_cache_forced():
+        compile_cache_off()

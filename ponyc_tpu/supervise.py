@@ -39,6 +39,7 @@ Two modes share one class:
 from __future__ import annotations
 
 import os
+import signal
 import subprocess
 import sys
 import time
@@ -48,6 +49,10 @@ from . import serialise
 from .errors import ERROR_CODES, error_code
 
 RESTORE_ENV = "PONY_TPU_RESTORE"
+# The failure code of a child that outlived `child_timeout_s` and was
+# killed by the supervisor: timeout(1)'s, outside ERROR_CODES and never
+# a signal's (those are negative).
+CHILD_TIMEOUT_CODE = 124
 
 
 class PoisonError(RuntimeError):
@@ -91,6 +96,13 @@ class Supervisor:
         (no intact checkpoint); the workload-injection site.
     retries: restart budget (total restarts, not attempts).
     backoff_s / backoff_max_s: exponential backoff between restarts.
+    child_timeout_s: subprocess mode — the longest one child may run
+        (None: no bound). A child still running then is wedged where
+        its own watchdog cannot see (the watchdog thread starved, a
+        hang in ``stop()`` or in the checkpoint writer): it is killed
+        with its process group and recorded as a failure of code
+        CHILD_TIMEOUT_CODE, which counts against `retries` and which
+        the poison rule sees like any other.
     """
 
     def __init__(self, build: Optional[Callable[[], Any]] = None, *,
@@ -100,6 +112,7 @@ class Supervisor:
                  retries: int = 5,
                  backoff_s: float = 0.25,
                  backoff_max_s: float = 30.0,
+                 child_timeout_s: Optional[float] = None,
                  sleep: Callable[[float], None] = time.sleep):
         if (build is None) == (argv is None):
             raise ValueError("exactly one of build= (in-process) or "
@@ -111,6 +124,7 @@ class Supervisor:
         self.retries = int(retries)
         self.backoff_s = float(backoff_s)
         self.backoff_max_s = float(backoff_max_s)
+        self.child_timeout_s = child_timeout_s
         self._sleep = sleep
         self.failures: List[Dict[str, Any]] = []   # evidence trail
         self.restarts = 0
@@ -200,6 +214,30 @@ class Supervisor:
         ckpts = serialise.list_checkpoints(self.prefix)
         return ckpts[-1][0] if ckpts else -1
 
+    def _run_child(self, env: Dict[str, str]) -> int:
+        """One child to its end, or to `child_timeout_s`. A bounded
+        child leads a process group of its own, so that the kill
+        reaches what it started; whoever interrupts the wait (^C, a
+        test's deadline) takes the child down too."""
+        bounded = self.child_timeout_s is not None
+        p = subprocess.Popen(self.argv, env=env, start_new_session=bounded)
+        try:
+            return p.wait(timeout=self.child_timeout_s)
+        except BaseException as e:
+            if bounded:
+                try:
+                    os.killpg(p.pid, signal.SIGKILL)
+                except ProcessLookupError:
+                    pass
+            else:
+                p.kill()
+            p.wait()
+            if not isinstance(e, subprocess.TimeoutExpired):
+                raise
+            print(f"supervise: child still ran after {self.child_timeout_s:g}"
+                  " s; killed with its process group", file=sys.stderr)
+            return CHILD_TIMEOUT_CODE
+
     def _run_subprocess(self) -> int:
         attempt = 0
         while True:
@@ -215,24 +253,24 @@ class Supervisor:
             # One process per chip: the supervising parent only reads
             # checkpoint files (numpy) and never initialises a JAX
             # backend, so each child in turn is the chip's one owner.
-            p = subprocess.run(self.argv, env=env)
-            if p.returncode == 0:
+            returncode = self._run_child(env)
+            if returncode == 0:
                 return 0
             # Position for the poison rule: the ring's newest sequence
             # number — a child that wrote new checkpoints made forward
             # progress, so an identical exit code is NOT the same
             # failure (the fault moved).
-            self._record(code=p.returncode, cls="subprocess",
+            self._record(code=returncode, cls="subprocess",
                          position=self._ring_seq(), restored=path or None)
             self._poison_check()
             attempt += 1
             if attempt > self.retries:
-                return p.returncode
+                return returncode
             self.restarts += 1
-            how = ("killed by signal " + str(-p.returncode)
-                   if p.returncode < 0 else "coded exit")
+            how = ("killed by signal " + str(-returncode)
+                   if returncode < 0 else "coded exit")
             print(f"supervise: attempt {attempt}/{self.retries} — child "
-                  f"exited {p.returncode} ({how}); restarting after "
+                  f"exited {returncode} ({how}); restarting after "
                   f"{self._backoff(attempt):.2f}s from the newest "
                   "intact checkpoint", file=sys.stderr)
             self._sleep(self._backoff(attempt))

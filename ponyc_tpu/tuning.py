@@ -23,6 +23,7 @@ import time
 from typing import Any, Dict, Optional, Tuple
 
 from .config import RuntimeOptions
+from .platforms import compile_cache_forced, compile_cache_off
 
 
 # ---------------------------------------------------------------------------
@@ -55,13 +56,15 @@ def enable_compile_cache(setting: str = "auto") -> Optional[str]:
     """Turn on jax's persistent compilation cache ("off" leaves jax
     alone). Returns the directory in use, or None.
 
-    Where ``JAX_COMPILATION_CACHE_DIR`` is set the cache can be placed
-    from outside: jax reads the variable itself and this function sets
-    no directory at all. Otherwise the directory is the fixed
-    CACHE_ROOT/xla. Idempotent; call before the first compile that
-    should be cached (Runtime.start() does).
+    On an accelerator, where ``JAX_COMPILATION_CACHE_DIR`` is set the
+    cache can be placed from outside: jax reads the variable itself and
+    this function sets no directory at all. Otherwise the directory is
+    the fixed CACHE_ROOT/xla. Idempotent; call before the first compile
+    that should be cached (Runtime.start() does).
 
-    CPU guard: on the CPU backend the cache stays off unless
+    CPU guard: on the CPU backend the cache is switched OFF
+    (platforms.compile_cache_off), also where the machine exports a
+    directory and jax has by then switched it on by itself, unless
     PONY_TPU_COMPILE_CACHE_FORCE=1 (the re-test hook). Re-tested on
     jaxlib 0.9.0 (PR 21): single-device worlds reload soundly (fuzz,
     ring, gc, run-loop and differential suites pass cold and warm — the
@@ -69,14 +72,15 @@ def enable_compile_cache(setting: str = "auto") -> Optional[str]:
     executable deadlocks its own collectives — on a warm cache
     tests/test_mesh_pressure.py::test_programmatic_backpressure_on_mesh
     dies in rendezvous.cc ("Expected 4 threads to join the rendezvous,
-    but only 2 of them arrived") — and every reload logs a 2 KB
-    cpu_aot_loader feature-mismatch line. The start-up this cache
-    attacks is the accelerator's anyway."""
+    but only 2 of them arrived"; rc 134 with only the variable exported,
+    PR 31) — and every reload logs a 2 KB cpu_aot_loader
+    feature-mismatch line. The start-up this cache attacks is the
+    accelerator's anyway."""
     if setting == "off":
         return None
     import jax
-    if jax.default_backend() == "cpu" and os.environ.get(
-            "PONY_TPU_COMPILE_CACHE_FORCE", "0") != "1":
+    if jax.default_backend() == "cpu" and not compile_cache_forced():
+        compile_cache_off()
         return None
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:

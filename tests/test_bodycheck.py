@@ -8,12 +8,12 @@ and ponyc_tpu/models/ (zero findings — tier-1)."""
 import importlib
 import json
 import os
-import subprocess
 import sys
 import time
 
 import pytest
 
+import _child
 from ponyc_tpu.lint import (check_path, check_paths, check_source,
                             lint_module, lint_types)
 from ponyc_tpu.lint.bodycheck import check_types, parse_module
@@ -415,39 +415,30 @@ def test_behaviour_level_ignore_on_live_types(tmp_path):
 
 # ---- CLI: paths, directories, output formats ----------------------------
 
-def _run_cli(args, cwd=ROOT):
-    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = ROOT
-    return subprocess.run([sys.executable, "-m", "ponyc_tpu"] + args,
-                          cwd=str(cwd), env=env, capture_output=True,
-                          text=True, timeout=240)
-
-
 def test_cli_lint_accepts_files_dirs_and_formats(tmp_path):
     rel = os.path.relpath(BROKEN, ROOT)
     # A single broken file: findings, exit 1, file:line in the text.
-    r = _run_cli(["lint", rel])
+    r = _child.cli(["lint", rel])
     assert r.returncode == 1, r.stderr[-500:]
     assert f"{rel}:" in r.stdout and "R6" in r.stdout
     assert "lint:" in r.stdout          # summary line
     # JSON: stable keys incl. file/line.
-    r = _run_cli(["lint", rel, "--json"])
+    r = _child.cli(["lint", rel, "--json"])
     objs = [json.loads(line) for line in r.stdout.splitlines()]
     assert all(o["file"] == rel for o in objs)
     assert any(o["rule"] == "R6" and o["line"] for o in objs)
     # GitHub annotations.
-    r = _run_cli(["lint", rel, "--format", "github"])
+    r = _child.cli(["lint", rel, "--format", "github"])
     assert r.returncode == 1
     assert any(line.startswith(f"::error file={rel},line=")
                for line in r.stdout.splitlines()), r.stdout[:400]
     # A directory target sweeps the tree (suppressed fixture rides
     # along clean; the broken one keeps the exit code at 1).
-    r = _run_cli(["lint", os.path.relpath(FIXDIR, ROOT)])
+    r = _child.cli(["lint", os.path.relpath(FIXDIR, ROOT)])
     assert r.returncode == 1 and "type(s)" in r.stdout
     # No actor types anywhere: exit 3.
     (tmp_path / "plain.py").write_text("x = 1\n")
-    r = _run_cli(["lint", str(tmp_path)])
+    r = _child.cli(["lint", str(tmp_path)])
     assert r.returncode == 3, (r.returncode, r.stderr)
     # Clean actor file: exit 0.
     (tmp_path / "cleanmod.py").write_text(
@@ -458,7 +449,7 @@ def test_cli_lint_accepts_files_dirs_and_formats(tmp_path):
         "    @behaviour\n"
         "    def go(self, st, v: I32):\n"
         "        return {**st, 'n': v}\n")
-    r = _run_cli(["lint", str(tmp_path / "cleanmod.py")])
+    r = _child.cli(["lint", str(tmp_path / "cleanmod.py")])
     assert r.returncode == 0, (r.returncode, r.stdout, r.stderr)
     assert "clean" in r.stdout
 
@@ -481,7 +472,7 @@ def test_cli_verify_json_carries_locations(tmp_path):
         "        self.send(st['out'], S.put, v)\n"
         "        self.send(st['out'], S.put, v + 1)\n"
         "        return st\n")
-    r = _run_cli(["verify", "vloc", "--json"], cwd=tmp_path)
+    r = _child.cli(["verify", "vloc", "--json"], cwd=tmp_path)
     assert r.returncode == 1, r.stderr[-500:]
     obj = json.loads(r.stdout.splitlines()[0])
     assert obj["file"].endswith("vloc.py") and obj["line"] == 12

@@ -2,10 +2,10 @@
 of FFI (≙ genheader.c:256): the emitted header must compile under g++
 and agree with the program's actual ids and layouts."""
 
-import subprocess
 import tempfile
 import os
 
+import _child
 from ponyc_tpu import (F32, I32, Iso, Ref, Runtime, RuntimeOptions,  # noqa
                        VecF32, actor, behaviour)
 from ponyc_tpu.translate import export_header, write_header
@@ -88,11 +88,10 @@ int main() {{
 }}
 ''')
         exe = os.path.join(d, "a.out")
-        r = subprocess.run(["g++", "-std=c++17", "-Wall", "-Werror",
-                            main, "-o", exe],
-                           capture_output=True, text=True)
+        r = _child.run(["g++", "-std=c++17", "-Wall", "-Werror",
+                        main, "-o", exe], timeout=60)
         assert r.returncode == 0, r.stderr
-        out = subprocess.run([exe], capture_output=True, text=True)
+        out = _child.run([exe], timeout=10)
         assert out.stdout.split() == [str(gid["Sensor.sample"]), "7"]
 
 

@@ -8,13 +8,18 @@ process 0 is the coordinator, both call initialize(), see the global
 device count, and agree on a psum across processes.
 """
 
+import contextlib
 import os
 import socket
-import subprocess
 import sys
 import textwrap
 
 import pytest
+
+import _child
+
+# One CPU device per process: none of conftest's eight virtual ones.
+_ONE_DEVICE = {"PYTHONPATH": None, "XLA_FLAGS": None}
 
 _WORKER = textwrap.dedent("""
     import os, sys
@@ -82,16 +87,15 @@ def test_engine_across_two_processes():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     worker = os.path.join(root, "tests", "_dist_worker.py")
     coord = f"127.0.0.1:{_free_port()}"
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("PYTHONPATH", "XLA_FLAGS")}
-    env["JAX_PLATFORMS"] = "cpu"
-    procs = [subprocess.Popen(
-        [sys.executable, "-u", worker, coord, str(r), "2"], env=env,
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
-        for r in range(2)]
-    try:
+    with contextlib.ExitStack() as stack:
+        procs = [stack.enter_context(_child.popen(
+            [sys.executable, "-u", worker, coord, str(r), "2"],
+            env=_ONE_DEVICE, stdout=_child.PIPE, stderr=_child.STDOUT,
+            text=True)) for r in range(2)]
         for rank, p in enumerate(procs):
-            out, _ = p.communicate(timeout=420)
+            # Forced runs only (skipped above): 3 of 3 on jaxlib 0.9.0
+            # ended in under a minute; conftest's deadline is 120 s.
+            out = _child.finish(p, timeout=100).stdout
             assert p.returncode == 0, f"rank {rank} failed:\n{out[-3000:]}"
             assert f"RANK{rank}_UBENCH_OK" in out
             assert f"RANK{rank}_RING_OK" in out
@@ -101,34 +105,20 @@ def test_engine_across_two_processes():
             assert (f"RANK{rank}_PRESSURE_OK" in out
                     or f"RANK{rank}_PRESSURE_SKIPPED" in out)
             assert f"RANK{rank}_ALL_OK" in out
-    finally:
-        for p in procs:
-            if p.poll() is None:
-                p.kill()
 
 
 def test_two_process_distributed_psum(tmp_path):
-    # (bounded by the communicate(timeout=150) below — workers that
-    # never rendezvous are killed and fail the assert)
+    # Workers that never rendezvous are killed with their groups at the
+    # bound and named (the two take ~5 s together, ISSUE 31).
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     coord = f"127.0.0.1:{_free_port()}"
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("PYTHONPATH", "XLA_FLAGS")}   # 1 CPU dev per proc
-    env["JAX_PLATFORMS"] = "cpu"
-    procs = []
-    for rank in range(2):
-        src = _WORKER.format(root=root, coord=coord, rank=rank)
-        procs.append(subprocess.Popen(
-            [sys.executable, "-c", src], env=env,
-            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True))
-    outs = []
-    try:
+    with contextlib.ExitStack() as stack:
+        procs = [stack.enter_context(_child.popen(
+            [sys.executable, "-c",
+             _WORKER.format(root=root, coord=coord, rank=rank)],
+            env=_ONE_DEVICE, stdout=_child.PIPE, stderr=_child.STDOUT,
+            text=True)) for rank in range(2)]
         for rank, p in enumerate(procs):
-            out, _ = p.communicate(timeout=150)
-            outs.append(out)
+            out = _child.finish(p, timeout=60).stdout
             assert p.returncode == 0, f"rank {rank} failed:\n{out}"
             assert f"RANK{rank}_OK" in out
-    finally:
-        for p in procs:
-            if p.poll() is None:
-                p.kill()

@@ -18,7 +18,6 @@ Four layers under test, matching the tentpole:
 
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -699,6 +698,12 @@ sys.exit(code)
 
 
 ACCEPT_HOPS = 3000
+# One supervised child may run this long (a clean one takes ~5 s). The
+# wedge sleeps 600 s and stays so: what ends the wedged child is its own
+# watchdog (0.6 s), and where that cannot (the watchdog thread starved,
+# a hang in stop() or in the checkpoint writer) the supervisor's kill
+# at this bound does, as a failure that counts against `retries`.
+ACCEPT_CHILD_S = 60.0
 
 
 def _accept_script(tmp_path, mode):
@@ -774,7 +779,7 @@ def test_acceptance_wedged_run_supervised_to_completion(
     script, prefix, out = _accept_script(tmp_path, "wedge")
     sup = supervise.Supervisor(
         argv=[sys.executable, script], prefix=prefix, retries=3,
-        backoff_s=0.05)
+        backoff_s=0.05, child_timeout_s=ACCEPT_CHILD_S)
     t0 = time.monotonic()
     code = sup.run()
     elapsed = time.monotonic() - t0
@@ -803,7 +808,7 @@ def test_acceptance_sigkill_mid_flush_supervised_to_completion(
     try:
         sup = supervise.Supervisor(
             argv=[sys.executable, script], prefix=prefix, retries=5,
-            backoff_s=0.05)
+            backoff_s=0.05, child_timeout_s=ACCEPT_CHILD_S)
         code = sup.run()
     finally:
         if env_before is None:

@@ -6,12 +6,11 @@ postmortem + int-coded PonyStallError, stable error codes, and the
 
 import json
 import os
-import subprocess
-import sys
 import time
 
 import pytest
 
+import _child
 from ponyc_tpu import I32, Runtime, RuntimeOptions, actor, behaviour
 from ponyc_tpu import flight
 from ponyc_tpu.errors import ERROR_CODES, PonyError, PonyStallError, \
@@ -174,11 +173,22 @@ def test_fatal_errors_count_for_metrics(tmp_path):
 
 # ------------------------------------------------------------- watchdog
 
+def _stopped(wd):
+    """check() is pure; the live monitor polls the stamp a test fakes
+    every 0.25 s, would trip on it too, and its trip() SIGINTs the main
+    thread — under xdist that ends the worker and the session (PR 31,
+    seen in tests/test_metrics.py). So it is stopped first."""
+    wd.close()
+    wd.join(5.0)
+    assert not wd.is_alive()
+    return wd
+
+
 def test_watchdog_check_pure():
     """Deadline evaluation against synthetic phase stamps: armed phases
     trip past the (scaled) deadline, healthy phases never do."""
     rt, _ids = ring.build(8, _opts(watchdog_s=1.0))
-    wd = rt._watchdog
+    wd = _stopped(rt._watchdog)
     try:
         now = time.monotonic()
         # warm runtime: flush the cold-phase grace
@@ -207,7 +217,7 @@ def test_watchdog_cold_phase_grace():
     """The first window's trace+compile must not read as a stall: cold
     device phases get COLD_FACTOR x deadline."""
     rt, _ids = ring.build(8, _opts(watchdog_s=1.0))
-    wd = rt._watchdog
+    wd = _stopped(rt._watchdog)
     try:
         now = time.monotonic()
         assert rt._rl_windows == 0              # nothing retired yet
@@ -275,9 +285,7 @@ def test_watchdog_trips_wedged_run_subprocess(tmp_path):
     within the deadline — instead of the silent forever-hang."""
     apath = str(tmp_path / "stall.csv")
     code = STALL_SCRIPT.format(root=ROOT, apath=apath)
-    p = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
-        timeout=300, env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    p = _child.script(code)
     assert p.returncode == 42, (p.returncode, p.stdout, p.stderr)
     out = json.loads(p.stdout.strip().splitlines()[-1])
     assert out["code"] == ERROR_CODES["PonyStallError"] == 7
@@ -332,9 +340,7 @@ def test_sigquit_dumps_and_continues(tmp_path):
     to its normal exit (dump-and-continue, unlike SIGTERM)."""
     apath = str(tmp_path / "sq.csv")
     code = SIGQUIT_SCRIPT.format(root=ROOT, apath=apath)
-    p = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
-        timeout=300, env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    p = _child.script(code)
     assert p.returncode == 0, (p.returncode, p.stdout, p.stderr)
     assert "EXIT 0" in p.stdout
     assert "DUMPS 3" in p.stdout               # one per SIGQUIT

@@ -12,6 +12,7 @@ import time
 
 import pytest
 
+import _child
 from ponyc_tpu import (Blob, BlobVal, I32, Iso, Program, Ref, Runtime,
                        RuntimeOptions, actor, behaviour)
 from ponyc_tpu.lint import (Finding, findings_to_json, format_findings,
@@ -537,17 +538,6 @@ def test_spawn_tree_declares_its_root():
 
 # ---- CLI -----------------------------------------------------------------
 
-def _run_cli(args, cwd):
-    import subprocess
-    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))
-    return subprocess.run([sys.executable, "-m", "ponyc_tpu"] + args,
-                          cwd=str(cwd), env=env, capture_output=True,
-                          text=True, timeout=240)
-
-
 def test_cli_lint_json_findings_and_exit_codes(tmp_path):
     (tmp_path / "away_mod.py").write_text(
         "from ponyc_tpu import I32, Ref, actor, behaviour\n"
@@ -568,13 +558,13 @@ def test_cli_lint_json_findings_and_exit_codes(tmp_path):
     # Linting a module that only re-exports Alone: Away is outside the
     # analysed world, so Alone.go's send is a guaranteed dead letter.
     (tmp_path / "lmod.py").write_text("from away_mod import Alone\n")
-    r = _run_cli(["lint", "lmod", "--json"], tmp_path)
+    r = _child.cli(["lint", "lmod", "--json"], cwd=tmp_path)
     assert r.returncode == 1, r.stderr[-500:]
     objs = [json.loads(line) for line in r.stdout.splitlines()]
     assert any(o["rule"] == "R2" and o["severity"] == "error"
                and o["type"] == "Alone" for o in objs)
     # Human mode prints the summary line and the same exit code.
-    r2 = _run_cli(["lint", "lmod"], tmp_path)
+    r2 = _child.cli(["lint", "lmod"], cwd=tmp_path)
     assert r2.returncode == 1 and "lint:" in r2.stdout
     assert "R2" in r2.stdout
 
@@ -598,10 +588,10 @@ def test_cli_verify_distinct_exit_codes_and_json(tmp_path):
         "        self.send(st['out'], S.put, v)\n"
         "        self.send(st['out'], S.put, v + 1)\n"
         "        return st\n")
-    r = _run_cli(["verify", "empty_mod"], tmp_path)
+    r = _child.cli(["verify", "empty_mod"], cwd=tmp_path)
     assert r.returncode == 3, (r.returncode, r.stderr[-300:])
     assert "no concrete actor types" in r.stderr
-    r = _run_cli(["verify", "over_mod", "--json"], tmp_path)
+    r = _child.cli(["verify", "over_mod", "--json"], cwd=tmp_path)
     assert r.returncode == 1, r.stderr[-500:]
     objs = [json.loads(line) for line in r.stdout.splitlines()]
     assert len(objs) == 1 and objs[0]["rule"] == "VERIFY"

@@ -118,7 +118,13 @@ def test_healthz_flips_ok_to_stalled(tmp_path):
     hz = json.loads(_get(port, "/healthz")[0])
     assert hz["status"] == "ok" and hz["watchdog"] is not None
     # Simulate the trip the monitor thread would record for a wedged
-    # phase (trip() itself also interrupts the main thread — us).
+    # phase (trip() itself also interrupts the main thread — us: so the
+    # live monitor, which polls the same stamp every 0.25 s and would
+    # trip on the faked one too, is stopped first. Seen in PR 31: its
+    # SIGINT took the xdist worker down "by keyboard-interrupt", and the
+    # session with it, rc 2 with 190 tests not run).
+    rt._watchdog.close()
+    rt._watchdog.join(5.0)
     rt._wd_stamp = ("in-flight", 99, time.monotonic() - 120.0)
     trip = rt._watchdog.check()
     assert trip is not None

@@ -3,11 +3,10 @@ safe-packages / allow_ffi) and the unified CLI driver (__main__.py ≙
 src/ponyc/main.c)."""
 
 import os
-import subprocess
-import sys
 
 import pytest
 
+import _child
 from ponyc_tpu.stdlib import pkg
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -63,26 +62,18 @@ def test_env_var_activates_restriction():
         os.environ.pop("PONY_TPU_SAFE")
 
 
-def _cli(*args, timeout=120):
-    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
-    env["JAX_PLATFORMS"] = "cpu"
-    return subprocess.run(
-        [sys.executable, "-m", "ponyc_tpu", *args], cwd=ROOT, env=env,
-        capture_output=True, text=True, timeout=timeout)
-
-
 def test_cli_version():
-    r = _cli("version")
+    r = _child.cli(["version"])
     assert r.returncode == 0 and "ponyc_tpu" in r.stdout
 
 
 def test_cli_unknown_command():
-    r = _cli("frobnicate")
+    r = _child.cli(["frobnicate"])
     assert r.returncode == 2 and "unknown command" in r.stderr
 
 
 def test_cli_run_strips_runtime_flags():
-    r = _cli("run", "examples/helloworld.py", "--ponybatch=4")
+    r = _child.cli(["run", "examples/helloworld.py", "--ponybatch=4"])
     assert r.returncode == 0, r.stderr[-800:]
     assert "Hello, world!" in r.stdout
     assert "--ponybatch" not in r.stdout
@@ -98,7 +89,7 @@ def test_cli_run_safe_flag_reaches_program(tmp_path):
         "    print('NET_ALLOWED')\n"
         "except PermissionError:\n"
         "    print('NET_BLOCKED')\n")
-    r = _cli("run", "--safe", "files", str(script))
+    r = _child.cli(["run", "--safe", "files", str(script)])
     assert r.returncode == 0, r.stderr[-800:]
     assert "NET_BLOCKED" in r.stdout
 
@@ -111,23 +102,23 @@ def test_cli_run_safe_equals_form(tmp_path):
         "    pkg.use('net'); print('NET_ALLOWED')\n"
         "except PermissionError:\n"
         "    print('NET_BLOCKED')\n")
-    r = _cli("run", f"--safe=files", str(script))
+    r = _child.cli(["run", f"--safe=files", str(script)])
     assert r.returncode == 0, r.stderr[-500:]
     assert "NET_BLOCKED" in r.stdout
 
 
 def test_cli_run_safe_missing_value_is_usage_error():
-    r = _cli("run", "x.py", "--safe")
+    r = _child.cli(["run", "x.py", "--safe"])
     assert r.returncode == 2 and "--safe needs a value" in r.stderr
 
 
 def test_cli_run_flags_only_is_usage_error():
-    r = _cli("run", "--ponybatch", "4")
+    r = _child.cli(["run", "--ponybatch", "4"])
     assert r.returncode == 2 and "missing script path" in r.stderr
 
 
 def test_cli_doc_generates_markdown(tmp_path):
-    r = _cli("doc", "ponyc_tpu.models.ring", "-o", str(tmp_path))
+    r = _child.cli(["doc", "ponyc_tpu.models.ring", "-o", str(tmp_path)])
     assert r.returncode == 0, r.stderr[-500:]
     out = r.stdout.strip()
     assert os.path.exists(out)

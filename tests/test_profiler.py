@@ -8,12 +8,11 @@ guarantee."""
 import json
 import os
 import signal
-import subprocess
-import sys
 
 import numpy as np
 import pytest
 
+import _child
 from ponyc_tpu import (I32, Ref, Runtime, RuntimeOptions, actor,
                        analysis, behaviour)
 from ponyc_tpu.models import ring
@@ -469,9 +468,7 @@ a = analysis.attach(rt)
 os.kill(os.getpid(), signal.SIGTERM)
 print("SURVIVED-SIGTERM")
 """
-    p = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
-        timeout=300, env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    p = _child.script(code)
     assert p.returncode == -signal.SIGTERM, (p.returncode, p.stderr)
     assert "ponyc_tpu analysis dump" in p.stderr
     assert "SURVIVED-SIGTERM" not in p.stdout
@@ -488,12 +485,8 @@ def test_example_smoke_analysis2(tmp_path):
     analysis=2 and validate the window CSV schema end to end,
     including the per-behaviour columns (satellite)."""
     path = str(tmp_path / "counter.csv")
-    p = subprocess.run(
-        [sys.executable, "-m", "ponyc_tpu", "run",
-         os.path.join(ROOT, "examples", "counter.py"),
-         "--ponyanalysis=2", f"--ponyanalysis_path={path}"],
-        capture_output=True, text=True, timeout=300, cwd=ROOT,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    p = _child.cli(["run", os.path.join(ROOT, "examples", "counter.py"),
+                    "--ponyanalysis=2", f"--ponyanalysis_path={path}"])
     assert p.returncode == 0, (p.stdout, p.stderr)
     lines = open(path).read().strip().split("\n")
     header = lines[0].split(",")

@@ -8,12 +8,11 @@ pipelined window (SIGINT/SIGTERM subprocess tests)."""
 
 import os
 import signal
-import subprocess
-import sys
 
 import numpy as np
 import pytest
 
+import _child
 from ponyc_tpu import (I32, Ref, Runtime, RuntimeOptions, actor,
                        behaviour)
 from ponyc_tpu.runtime import engine
@@ -443,10 +442,7 @@ except KeyboardInterrupt:
     print("INTERRUPT-CLEAN got", rt.state_of(sink)["got"],
           "steps", rt.steps_run)
 """
-    p = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
-        timeout=300, env={**os.environ, "JAX_PLATFORMS": "cpu",
-                          "PONY_TPU_TUNING_CACHE": "off"})
+    p = _child.script(code, env={"PONY_TPU_TUNING_CACHE": "off"})
     assert p.returncode == 0, (p.returncode, p.stdout, p.stderr)
     assert "INTERRUPT-CLEAN got 1" in p.stdout, (p.stdout, p.stderr)
     assert "NO-INTERRUPT" not in p.stdout
@@ -502,10 +498,7 @@ rt.register_poller(Killer())
 rt.run()
 print("SURVIVED-SIGTERM")
 """
-    p = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
-        timeout=300, env={**os.environ, "JAX_PLATFORMS": "cpu",
-                          "PONY_TPU_TUNING_CACHE": "off"})
+    p = _child.script(code, env={"PONY_TPU_TUNING_CACHE": "off"})
     assert p.returncode == -signal.SIGTERM, (p.returncode, p.stderr)
     assert "ponyc_tpu analysis dump" in p.stderr, p.stderr
     assert "run_loop window=" in p.stderr, p.stderr

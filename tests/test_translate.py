@@ -4,9 +4,9 @@ these by compiling packages containing .h/.schema.json/.md resources)."""
 import ctypes
 import ctypes.util
 import os
-import subprocess
 import sys
 
+import _child
 from ponyc_tpu.translate import (translate_c_header, translate_dir,
                                  translate_json_schema,
                                  translate_text_resource)
@@ -61,8 +61,9 @@ void reset(void) {}
 unsigned mask_bits(unsigned x, unsigned s) { return x >> s; }
 """)
     so = tmp_path / "libdemo.so"
-    subprocess.run(["gcc", "-shared", "-fPIC", "-o", str(so), str(c)],
-                   check=True)
+    r = _child.run(["gcc", "-shared", "-fPIC", "-o", str(so), str(c)],
+                   timeout=60)
+    assert r.returncode == 0, r.stderr
     mod.bind(str(so))
     assert mod.add_numbers(2, 40) == 42
     assert abs(mod.scale_value(2.0) - 5.0) < 1e-9

@@ -153,6 +153,7 @@ def restore_jax_cache_config():
     import jax
     saved = {k: getattr(jax.config, k) for k in (
         "jax_compilation_cache_dir",
+        "jax_enable_compilation_cache",
         "jax_persistent_cache_min_compile_time_secs",
         "jax_persistent_cache_min_entry_size_bytes",
         "jax_compilation_cache_include_metadata_in_key")}
@@ -173,6 +174,23 @@ def test_compile_cache_leaves_an_outside_directory_alone(
     assert tuning.enable_compile_cache() == str(tmp_path)
     assert jax.config.jax_compilation_cache_dir == before
     assert tuning.enable_compile_cache("off") is None
+    # ... also a directory jax switched on by itself (the variable was
+    # exported when jax was imported): with the hook it is left alone,
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_enable_compilation_cache", True)
+    assert tuning.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+    assert jax.config.jax_enable_compilation_cache
+    # "off" leaves jax alone,
+    assert tuning.enable_compile_cache("off") is None
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+    # and without the hook the CPU backend switches it off (ISSUE 31:
+    # returning None over a cache jax had on is how a warm
+    # test_mesh_pressure aborted).
+    monkeypatch.delenv("PONY_TPU_COMPILE_CACHE_FORCE")
+    assert tuning.enable_compile_cache() is None
+    assert not jax.config.jax_compilation_cache_dir
+    assert not jax.config.jax_enable_compilation_cache
 
 
 def test_compile_cache_default_is_one_fixed_path_in_the_checkout(
@@ -186,9 +204,13 @@ def test_compile_cache_default_is_one_fixed_path_in_the_checkout(
     # The CPU guard (jaxlib 0.9.0 still deadlocks a reloaded meshed
     # executable's collectives): off here unless forced.
     monkeypatch.delenv("PONY_TPU_COMPILE_CACHE_FORCE", raising=False)
-    before = jax.config.jax_compilation_cache_dir
+    # conftest's force_cpu has switched it off already; a directory set
+    # since (by jax itself, from the machine's variable) goes too.
+    assert not jax.config.jax_compilation_cache_dir
+    assert not jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_compilation_cache_dir", "/tmp/set-before")
     assert tuning.enable_compile_cache() is None
-    assert jax.config.jax_compilation_cache_dir == before
+    assert not jax.config.jax_compilation_cache_dir
     monkeypatch.setenv("PONY_TPU_COMPILE_CACHE_FORCE", "1")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     want = os.path.join(root, ".cache", "ponyc_tpu", "xla")

@@ -4,6 +4,7 @@ and the CLI command."""
 
 import pytest
 
+import _child
 from ponyc_tpu import (I32, Ref, Runtime, RuntimeOptions, actor,
                        behaviour)
 from ponyc_tpu.verify import (VerifyError, behaviour_effects,
@@ -155,9 +156,6 @@ def test_string_spawns_target_probes_clean():
 
 
 def test_cli_verify_reports_fail_lines(tmp_path):
-    import os
-    import subprocess
-    import sys
     mod = tmp_path / "vmod.py"
     mod.write_text(
         "from ponyc_tpu import I32, Ref, actor, behaviour\n"
@@ -176,12 +174,6 @@ def test_cli_verify_reports_fail_lines(tmp_path):
         "        self.send(st['out'], Sink.put, v)\n"
         "        self.send(st['out'], Sink.put, v)\n"
         "        return st\n")
-    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
-    env["JAX_PLATFORMS"] = "cpu"
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = root
-    r = subprocess.run([sys.executable, "-m", "ponyc_tpu", "verify",
-                        "vmod"], cwd=str(tmp_path), env=env,
-                       capture_output=True, text=True, timeout=240)
+    r = _child.cli(["verify", "vmod"], cwd=tmp_path)
     assert r.returncode == 1, r.stderr[-500:]
     assert "FAIL Bad.go" in r.stdout and "ok   Sink.put" in r.stdout
