@@ -53,7 +53,26 @@ within one shard of the actor world:
      paths*: they run under `lax.cond` and cost nothing in the steady
      state where nothing rejects and nobody is overloaded (≙ the
      reference only walking mute maps when senders actually muted,
-     scheduler.c:1478-1494).
+     scheduler.c:1478-1494). Inside the branch the list asks each ROW
+     one thing at a time and reads one word for it: an index vector of
+     E entries costs the same whatever it fetches, so four questions of
+     one index are four gathers, and what can be answered over the N
+     rows first is one. A TARGET answers `bound` = seg_start + accepted,
+     the sorted position where its rejected suffix begins (entry i is
+     rejected iff i >= bound — no rank, no second table), and `hot_t` =
+     over the overload threshold after this tick or declared pressure;
+     a dead row accepts its whole segment and is never hot. A SENDER
+     answers the one bit `hot_s` (over the threshold or declared
+     pressure: the exemption from muting). `bound` and `hot_t` also
+     pack into one word, `(bound << 1) | hot_t`, read once: equal on
+     every leaf and its gather as fast as either, but the executable
+     the v5e's compiler built round it put the rebuild's gathers in
+     slower memory and the tick read slower (PERF.md §6, PR 32), so two
+     reads by the target shipped. Who was muted is read off the ref
+     table the triggers were scattered into, not scattered a second
+     time. The unmute pass (engine.py `muter_bits`) reads its muting
+     receivers the same way: one status word a row, gathered once by
+     the mute refs.
 """
 
 from __future__ import annotations
@@ -364,16 +383,25 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
         # --- pressure paths, traced under a nested cond so the quiet
         # busy state pays nothing (≙ mute bookkeeping only on overload).
         def pressure(_):
-            # An entry to a dead row rides in that row's segment: it must
-            # be neither spilled nor a mute trigger. Liveness is folded
-            # into the tables the [ktc] reads gather anyway (a dead row
-            # "accepts" all of its segment, is never hot, never
-            # pressured); the sender's [sc] reads see the tables as
-            # they are.
+            # What the protocol asks of a ROW is decided over the n rows
+            # and read once per index vector (a gather is paid per
+            # index, whatever it fetches). A target answers two things:
+            # `bound`, the sorted position where its rejected suffix
+            # begins (seg_start + accepted: entry i is rejected iff
+            # i >= bound — no rank, no second table), and `hot_t`: over
+            # the overload threshold after this tick, or declared
+            # pressure. An entry to a dead row rides in that row's
+            # segment and must be neither spilled nor a mute trigger:
+            # liveness is folded into both answers (a dead row "accepts"
+            # all of its segment, is never hot, never pressured); the
+            # sender's bit sees its tables as they are.
             with phase_scope("delivery/pressure/spill"):
-                rank = jnp.arange(e, dtype=jnp.int32) - seg_start[ktc]
+                bound = seg_start + jnp.where(alive, acc, cnt)
+                hot_t = jnp.where(alive, occ_after, 0) > overload_occ
+                if pressured is not None:
+                    hot_t = hot_t | (pressured & alive)
                 ok = kt < n
-                rej = ok & (rank >= jnp.where(alive, acc, cnt)[ktc])
+                rej = ok & (jnp.arange(e, dtype=jnp.int32) >= bound[ktc])
                 perm2, vspill, _ = compact_mask(rej, spill_cap)
                 snd = snd_s if cosort else sender[perm]
                 spill = Entries(
@@ -385,28 +413,26 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
             # actor.c:1171-1235): a valid send whose receiver rejected it,
             # is now over the overload threshold, or has DECLARED pressure
             # (pony_apply_backpressure, actor.c:1137-1162) mutes the
-            # sender — unless the sender is itself overloaded (the
-            # reference's !OVERLOADED/UNDER_PRESSURE guard, which prevents
-            # mute deadlocks among hot actors). Only senders resident on
-            # this shard can be muted here.
+            # sender — unless the sender is itself overloaded or itself
+            # declared pressure (the reference's !OVERLOADED /
+            # UNDER_PRESSURE guard, which prevents mute deadlocks among
+            # hot actors): one bit a row, read once by the sender index.
+            # Only senders resident on this shard can be muted here.
             with phase_scope("delivery/pressure/mute"):
-                recv_hot = jnp.where(alive, occ_after, 0)[ktc] > overload_occ
+                recv_hot = hot_t[ktc]
+                hot_s = occ_after > overload_occ
                 if pressured is not None:
-                    recv_hot = recv_hot | (pressured & alive)[ktc]
+                    hot_s = hot_s | pressured
                 lsnd = snd - shard_base
                 sender_local = (lsnd >= 0) & (lsnd < n)
                 sc = jnp.minimum(jnp.maximum(lsnd, 0), n - 1)
-                sender_hot = occ_after[sc] > overload_occ
-                if pressured is not None:
-                    # ≙ the UNDER_PRESSURE half of the sender exemption: a
-                    # sender that itself declared pressure never mutes.
-                    sender_hot = sender_hot | pressured[sc]
-                trig = ok & sender_local & (rej | recv_hot) & ~sender_hot
+                trig = ok & sender_local & (rej | recv_hot) & ~hot_s[sc]
                 mute_row = jnp.where(trig, sc, n)
-                newly_muted = jnp.zeros((n,), jnp.bool_).at[mute_row].max(
-                    trig, mode="drop")
                 refs, ovf = mute_ref_slots(trig, mute_row, kt + shard_base,
                                            n=n, k=mute_slots)
+                # Every trigger wrote its ref (>= 0) into slot ref % K of
+                # its sender's column: the table says who was muted.
+                newly_muted = jnp.any(refs >= 0, axis=0)
             return spill, newly_muted, refs, ovf
 
         with phase_scope("delivery/pressure"):

@@ -1208,6 +1208,10 @@ def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
             nrej > rspill_cap, newly_muted, new_refs, new_ovf, blob_out)
 
 
+# A row's status word for the unmute pass (`muter_bits` in the tick).
+LIVE_CONG, CAN_RECOVER, RECOVERED, PRESSURED = 1, 2, 4, 8
+
+
 def build_step(program: Program, opts: RuntimeOptions):
     """Trace one whole-world scheduler tick; returns a function
     local_step(state, inject_tgt, inject_words) → (state, StepAux) in
@@ -1305,17 +1309,28 @@ def build_step(program: Program, opts: RuntimeOptions):
                 jnp.minimum(jnp.maximum(st.dspill_tgt, 0), nl - 1),
                 (st.dspill_tgt >= 0).astype(jnp.int32), nl),
             lambda _: jnp.zeros((nl,), jnp.int32), operand=None)
-        # Mesh-wide muter-status bits for the aging veto below, packed
-        # into one gather (bit 0: live-congested — shows congestion
+        # One status word a row: everything the unmute pass asks of a
+        # muting RECEIVER is decided here, over the rows, and the pass
+        # reads it once by the mute refs (a gather is paid per index,
+        # whatever it fetches). Bit 0: live-congested — shows congestion
         # evidence AND can run to drain it; bit 1: can-recover — alive
-        # and unmuted, i.e. not itself deadlocked). Gathered OUTSIDE the
-        # unmute cond (collectives must run collectively; jnp.any(
-        # st.muted) is shard-local).
+        # and unmuted, i.e. not itself deadlocked; bit 2: recovered —
+        # drained to the unmute threshold, nothing parked for it in the
+        # device spill, no declared pressure: what releases a sender it
+        # muted; bit 3: declares pressure. The word is also the mesh's
+        # one all-gather for the pass, so it is built OUTSIDE the unmute
+        # cond (collectives must run collectively; jnp.any(st.muted) is
+        # shard-local).
         can_recover = st.alive & ~st.muted
         live_cong = (((occ0 > opts.unmute_occ) | (dspill_pending > 0))
                      & can_recover)
-        muter_bits = (live_cong.astype(jnp.int32)
-                      | (can_recover.astype(jnp.int32) << 1))
+        recovered = ((occ0 <= opts.unmute_occ) & (dspill_pending == 0)
+                     & ~st.pressured)
+        muter_bits = (jnp.where(live_cong, LIVE_CONG, 0)
+                      | jnp.where(can_recover, CAN_RECOVER, 0)
+                      | jnp.where(recovered, RECOVERED, 0)
+                      | jnp.where(st.pressured, PRESSURED, 0)
+                      ).astype(jnp.int32)
         # Gated like the pressured gather: the bits feed only the unmute
         # pass, which has work only when someone (anywhere) is muted —
         # exactly what world bit1 reports from the previous tick's vote.
@@ -1328,8 +1343,7 @@ def build_step(program: Program, opts: RuntimeOptions):
                 operand=None)
         else:
             muter_bits_global = muter_bits
-        live_cong_global = (muter_bits_global & 1) > 0
-        can_recover_global = (muter_bits_global & 2) > 0
+
         def unmute_pass(_):
             # ≙ ponyint_sched_unmute_senders walking the mutemap
             # receiver-set (scheduler.c:1552-1635): a sender releases only
@@ -1338,21 +1352,32 @@ def build_step(program: Program, opts: RuntimeOptions):
             has = refs >= 0
             lref = refs - base
             ref_local = (lref >= 0) & (lref < nl)
-            mr = jnp.minimum(jnp.maximum(lref, 0), nl - 1)
-            local_ok = (has & ref_local & (occ0[mr] <= opts.unmute_occ)
-                        & (dspill_pending[mr] == 0)
-                        & ~st.pressured[mr])
+            status = muter_bits_global
+            if p > 1:
+                # Each bit is believed from where it was believed before
+                # the word: live-congested and can-recover as gathered
+                # under world bit1, pressure from its own all-gather
+                # (world bit0), and `recovered` from this shard's rows
+                # alone — a remote ref's is never read.
+                status = ((status & (LIVE_CONG | CAN_RECOVER))
+                          | jnp.where(pressured_global, PRESSURED, 0)
+                          | lax.dynamic_update_slice(
+                              jnp.zeros((p * nl,), jnp.int32),
+                              muter_bits & RECOVERED, (base,)))
+            got = jnp.take(status, jnp.maximum(refs, 0), mode="clip")
+
+            def says(bit):       # [K, nl]: the ref's muter has `bit` set
+                return has & ((got & bit) > 0)
+            ref_pressured = says(PRESSURED)
+            local_ok = ref_local & says(RECOVERED)
             # Remote muting ref: release once this shard's route-spill
             # drained (the local evidence of congestion is gone;
             # receiver-side pressure will re-mute via routing if it
             # persists) — unless the remote receiver still DECLARES
             # pressure (the all-gathered bits above), which holds the
             # sender muted exactly as a local pressured ref would.
-            remote_pr = jnp.take(pressured_global,
-                                 jnp.maximum(refs, 0),
-                                 mode="clip") & has & ~ref_local
             remote_ok = (has & ~ref_local & (st.rspill_count[0] == 0)
-                         & ~remote_pr)
+                         & ~ref_pressured)
             slot_ok = ~has | local_ok | remote_ok
             all_ok = jnp.all(slot_ok, axis=0)
             # Overflowed ref sets (more distinct muters than slots) defer
@@ -1383,15 +1408,12 @@ def build_step(program: Program, opts: RuntimeOptions):
                 lim = opts.mute_age_limit
                 threshold = lim + jnp.arange(nl, dtype=jnp.int32) % lim
                 aged = st.mute_age >= threshold
-                held_by_pressure = jnp.any(
-                    (refs >= 0) & jnp.take(
-                        pressured_global, jnp.maximum(refs, 0),
-                        mode="clip"),
-                    axis=0)
-                # A tracked muter (on ANY shard — live_cong_global) that
-                # still shows LIVE congestion evidence (occ above the
-                # unmute threshold, or messages parked in its shard's
-                # device spill) and that can still run to drain it
+                held_by_pressure = jnp.any(ref_pressured, axis=0)
+                # A tracked muter (on ANY shard — the word is the
+                # mesh's all-gather) that still shows LIVE congestion
+                # evidence (occ above the unmute threshold, or messages
+                # parked in its shard's device spill) and that can still
+                # run to drain it
                 # (alive, not itself muted) vetoes aging: releasing a
                 # sender into a receiver that is actively being worked
                 # just grows the bounded spill until overflow — the
@@ -1408,16 +1430,10 @@ def build_step(program: Program, opts: RuntimeOptions):
                 # route-spill backlog can never drain (muted receivers
                 # don't run), and holding on it would re-create the
                 # cross-shard mute-cycle deadlock aging exists to break.
-                held_by_live = jnp.any(
-                    has & jnp.take(live_cong_global,
-                                   jnp.maximum(refs, 0), mode="clip"),
-                    axis=0)
+                held_by_live = jnp.any(says(LIVE_CONG), axis=0)
                 if p > 1:
                     remote_recover = jnp.any(
-                        has & ~ref_local
-                        & jnp.take(can_recover_global,
-                                   jnp.maximum(refs, 0), mode="clip"),
-                        axis=0)
+                        ~ref_local & says(CAN_RECOVER), axis=0)
                     held_by_live = held_by_live | (
                         remote_recover & (st.rspill_count[0] > 0))
                 # Overflowed ref sets may have EVICTED a pressured ref, so

@@ -8,8 +8,12 @@ collisions; the unmute pass releases a sender only when all tracked refs
 have recovered (overflowed senders wait for a shard-quiet tick).
 """
 
+import dataclasses
+
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from ponyc_tpu import I32, Ref, Runtime, RuntimeOptions, actor, behaviour
 from ponyc_tpu.runtime.delivery import empty_mute_slots, mute_ref_slots
@@ -169,7 +173,6 @@ def _deadlocked_pair(mute_age_limit):
     refs = np.full_like(np.asarray(state.mute_refs), -1)
     refs[b % rt.opts.mute_slots, a] = b       # a muted by b
     refs[a % rt.opts.mute_slots, b] = a       # b muted by a
-    import dataclasses
     rt.state = dataclasses.replace(
         state,
         muted=jnp.ones_like(state.muted),
@@ -443,3 +446,148 @@ def test_release_only_after_all_refs_recover():
     rt.state = state
     assert releases_checked > 0, "scenario never exercised a release"
     assert rt.state_of(slow)["total"] == 8 * 30
+
+# ------------------------------------------- the unmute pass, bit by bit
+
+@actor
+class Quiet:
+    """Takes items and sends nothing: a tick of a world of these mutes
+    nobody, so what the unmute pass released is what the tick changed."""
+
+    n: I32
+
+    BATCH = 1
+
+    @behaviour
+    def take(self, st, v: I32):
+        return {**st, "n": st["n"] + 1}
+
+
+AGE_LIMIT = 4
+# Global ids. On the mesh of two shards (four rows each) FAR_SENDER
+# lives on the other shard than MUTER: its ref is a remote ref.
+MUTER, BYSTANDER, SENDER, FAR_SENDER = 0, 3, 2, 5
+_quiet_worlds = {}
+
+
+def _quiet(shards):
+    if shards not in _quiet_worlds:
+        rt = Runtime(RuntimeOptions(mailbox_cap=8, batch=1, msg_words=1,
+                                    max_sends=1, spill_cap=16,
+                                    inject_slots=4, mesh_shards=shards,
+                                    mute_age_limit=AGE_LIMIT))
+        rt.declare(Quiet, 8).start()
+        rt.spawn_many(Quiet, 8)
+        # the step donates its state: every case starts from this copy
+        _quiet_worlds[shards] = rt, jax.tree.map(np.asarray, rt.state)
+    return _quiet_worlds[shards]
+
+
+MUTER_STATES = [dict(zip(("over", "parked", "declared", "dead", "muted"),
+                         map(bool, map(int, f"{i:05b}"))))
+                for i in range(32)]
+SENDERS = ["local-ref", "overflowed-set", "aged", "aged-overflowed",
+           "two-refs"]
+# behind a route-spill backlog a remote ref releases nobody, and ages
+# nobody away from a muter that can still recover
+WORLDS = [(1, s) for s in SENDERS] + [(2, s) for s in SENDERS] + [
+    (2, "backlog"), (2, "aged-backlog")]
+
+
+@pytest.mark.parametrize("shards,sender", WORLDS,
+                         ids=lambda v: {1: "one-shard", 2: "mesh"}.get(v, v))
+@pytest.mark.parametrize("muter", MUTER_STATES, ids=lambda m: "+".join(
+    k for k, v in m.items() if v) or "recovered")
+def test_unmute_pass_against_its_predicates_one_by_one(muter, shards,
+                                                       sender):
+    """The pass gathers one status word by the mute refs (recovered /
+    pressured / live-congested / can-recover, engine `muter_bits`).
+    Here every state a muting receiver can be in — queued over the
+    unmute line, an item parked for it in the device spill, declared
+    pressure, dead, itself muted — meets every kind of muted sender, on
+    its own shard and on another, and the tick's `muted`, `mute_refs`
+    and `mute_ovf` are held to the protocol's predicates, each read from
+    its own table."""
+    rt, st0 = _quiet(shards)
+    opts, k = rt.opts, rt.opts.mute_slots
+    n, nl = rt.program.total, rt.program.n_local
+    shard = np.arange(n) // nl
+    senders = [SENDER, FAR_SENDER]
+    occ = np.zeros(n, np.int32)
+    occ[MUTER] = opts.unmute_occ + (2 if muter["over"] else 0)
+    pressured = np.zeros(n, bool)
+    pressured[MUTER] = muter["declared"]
+    alive = np.ones(n, bool)
+    alive[MUTER] = not muter["dead"]
+    muted = np.zeros(n, bool)
+    muted[MUTER], muted[senders] = muter["muted"], True
+    refs = np.full((k, n), -1, np.int32)
+    refs[MUTER % k, senders] = MUTER
+    if sender == "two-refs":              # the other muter has recovered
+        refs[BYSTANDER % k, senders] = BYSTANDER
+    ovf = np.zeros(n, bool)
+    ovf[senders] = "overflowed" in sender
+    age = np.zeros(n, np.int32)
+    age[senders] = 2 * AGE_LIMIT if "aged" in sender else 0
+    cap = st0.dspill_tgt.size // shards
+    spill_tgt = np.full_like(st0.dspill_tgt, -1)     # local rows
+    spill_tgt[shard[MUTER] * cap] = MUTER % nl if muter["parked"] else -1
+    parked = np.zeros(n, np.int32)
+    parked[MUTER] = muter["parked"]
+    in_spill = np.bincount(shard, parked, shards)
+    # one item for BYSTANDER waits in the route spill of FAR_SENDER's shard
+    rspill_tgt = np.full_like(st0.rspill_tgt, -1)    # global ids
+    in_route = np.zeros(shards, np.int32)
+    if "backlog" in sender:
+        rspill_tgt[shard[FAR_SENDER] * cap] = BYSTANDER
+        in_route[shard[FAR_SENDER]] = 1
+
+    # --- the protocol, predicate by predicate
+    has = refs >= 0
+    at = np.maximum(refs, 0)
+    ref_local = shard[at] == shard[None, :]
+    local_ok = (has & ref_local & (occ[at] <= opts.unmute_occ)
+                & (parked[at] == 0) & ~pressured[at])
+    remote_pressured = has & ~ref_local & pressured[at]
+    remote_ok = (has & ~ref_local & (in_route[shard] == 0)[None, :]
+                 & ~remote_pressured)
+    can_recover = alive & ~muted
+    live_congested = ((occ > opts.unmute_occ) | (parked > 0)) & can_recover
+    held_by_pressure = (has & pressured[at]).any(axis=0)
+    held_by_live = (has & live_congested[at]).any(axis=0)
+    remote_recover = (has & ~ref_local & can_recover[at]).any(axis=0)
+    held_by_live |= remote_recover & (in_route[shard] > 0)
+    all_ok = (~has | local_ok | remote_ok).all(axis=0)
+    occ_max = np.asarray([occ[shard == s].max() for s in range(shards)])
+    shard_quiet = ((occ_max <= opts.unmute_occ) & (in_spill == 0)
+                   & (in_route == 0) & ~pressured.any())[shard]
+    aged = age >= AGE_LIMIT + np.arange(n) % nl % AGE_LIMIT
+    aged_ok = aged & ~held_by_pressure & ~held_by_live \
+        & (~ovf | (not pressured.any()))
+    release = muted & ((all_ok & (~ovf | shard_quiet)) | aged_ok)
+    # the case is the one its name says: the plain sender waits for a
+    # muter of its shard while any of the three holds it, for a remote
+    # one only while that declares pressure — or behind a backlog
+    if sender == "local-ref":
+        assert release[SENDER] == (not (muter["over"] or muter["parked"]
+                                        or muter["declared"]))
+        if shards > 1:
+            assert release[FAR_SENDER] == (not muter["declared"])
+    if sender == "backlog":
+        assert not release[FAR_SENDER]
+
+    bits = pressured.any() | muted.any() << 1 | in_route.any() << 2
+    state = dataclasses.replace(
+        st0, head=np.zeros(n, np.int32), tail=occ, alive=alive,
+        pressured=pressured, muted=muted, mute_refs=refs, mute_ovf=ovf,
+        mute_age=age, dspill_tgt=spill_tgt,
+        dspill_sender=np.full_like(st0.dspill_sender, -1),
+        dspill_count=in_spill.astype(np.int32),
+        rspill_tgt=rspill_tgt, rspill_count=in_route,
+        world_bits=np.full_like(st0.world_bits, bits))
+    after, _aux = rt._step(jax.tree.map(jnp.asarray, state),
+                           *rt._empty_inject)
+    np.testing.assert_array_equal(after.muted, muted & ~release)
+    np.testing.assert_array_equal(after.mute_refs,
+                                  np.where(release[None, :], -1, refs))
+    np.testing.assert_array_equal(after.mute_ovf, ovf & ~release)
