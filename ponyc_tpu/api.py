@@ -35,6 +35,7 @@ requires (`lax.cond` under vmap selects, it does not branch).
 
 from __future__ import annotations
 
+import functools
 import inspect
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -284,6 +285,19 @@ def actor(cls):
     return ActorTypeMeta(cls.__name__, (Actor,), ns)
 
 
+def _heap_scoped(method):
+    """One blob op of a behaviour: its handle checks, gathers and
+    scatters on the pool carry the device scope `pony/dispatch/heap`
+    (runtime.state.STEP_SCOPES; metadata only), so a trace names the
+    heap's share of the dispatch."""
+    @functools.wraps(method)
+    def scoped(self, *args, **kwargs):
+        from .runtime.state import phase_scope
+        with phase_scope("dispatch/heap"):
+            return method(self, *args, **kwargs)
+    return scoped
+
+
 class BlobPoolView:
     """Trace-time working view of the device blob pool for ONE behaviour
     evaluation (see ops.pack.Blob; pool arrays live in runtime.state).
@@ -300,18 +314,20 @@ class BlobPoolView:
     ≙ the reference's actor heap + pony_alloc_msg payloads
     (pony.h:332-360): alloc on the owning actor, move by message."""
 
-    __slots__ = ("data", "used", "len_", "gen", "base", "nslots", "take",
+    __slots__ = ("data", "used", "len_", "gen", "base", "nslots", "words",
+                 "take",
                  "resv", "claims", "fail", "budget_fail", "n_alloc",
                  "n_free", "n_remote", "alloced", "budget_over")
 
     def __init__(self, data, used, len_, gen, base, take, resv,
                  budget_over=None):
-        self.data = data            # [W, B] i32 (working copy)
+        self.data = data            # [W*B] i32, word-major (working copy)
         self.used = used            # [B] bool
         self.len_ = len_            # [B] i32
         self.gen = gen              # [B] i32 slot generations (ABA guard)
         self.base = base            # traced i32: this shard's first handle
         self.nslots = used.shape[0]
+        self.words = data.shape[0] // max(1, self.nslots)   # W
         self.take = take            # [lanes] bool
         self.resv = resv            # [sites, lanes] i32 handles, or None
         self.claims = 0             # trace-time alloc-site counter
@@ -343,6 +359,13 @@ class BlobPoolView:
         ok = ok & (jnp.take(self.gen, hs, mode="fill", fill_value=-1)
                    == pack.blob_gen_of(h))
         return jnp.where(ok, hl, self.nslots), ok
+
+    def at(self, word, slot, ok):
+        """Flat index of (word, local slot) where `ok`, else one past
+        the end — what mode="fill" fills and mode="drop" drops."""
+        from .runtime.state import pool_index
+        return jnp.where(ok, pool_index(self.nslots, word, slot),
+                         self.data.shape[0])
 
 
 class Context:
@@ -699,6 +722,7 @@ class Context:
                 f"capability: use-after-move — blob handle already moved "
                 f"by {prev} is passed to {what}")
 
+    @_heap_scoped
     def blob_alloc(self, length=None, when=True):
         """Claim a fresh device blob; returns its handle ([lanes] i32,
         -1 where `when` is false or the pool had no free slot — the
@@ -739,18 +763,21 @@ class Context:
         b.gen = b.gen.at[idx].set(newgen, mode="drop")
         h = pack.blob_handle(slot, newgen)
         b.used = b.used.at[idx].set(True, mode="drop")
-        wpool = b.data.shape[0]
+        wpool = b.words
         ln = (jnp.int32(wpool) if length is None
               else jnp.clip(jnp.asarray(length, jnp.int32), 0, wpool))
         b.len_ = b.len_.at[idx].set(
             jnp.broadcast_to(ln, idx.shape), mode="drop")
-        b.data = b.data.at[:, idx].set(0, mode="drop")
+        b.data = b.data.at[b.at(
+            jnp.arange(wpool, dtype=jnp.int32)[:, None], idx[None],
+            ok[None])].set(0, mode="drop")
         b.n_alloc = b.n_alloc + jnp.sum(ok.astype(jnp.int32))
         b.alloced = b.alloced | ok
         h2 = jnp.where(ok, h, jnp.int32(-1))
         self.cap_types.tag(h2, "iso")
         return h2
 
+    @_heap_scoped
     def blob_get(self, h, i):
         """Read word `i` of blob `h` ([lanes] i32; 0 for null/-1 handles,
         out-of-range words, or handles owned by another shard). Floats:
@@ -763,13 +790,10 @@ class Context:
         # another blob's leftover words — the same used-gate writes have.
         ok = ok & jnp.take(b.used, hl, mode="fill", fill_value=False)
         i = jnp.asarray(i, jnp.int32)
-        nflat = b.data.shape[0] * b.nslots
-        flat = jnp.where(ok & (i >= 0) & (i < b.data.shape[0]),
-                         jnp.minimum(i, b.data.shape[0] - 1) * b.nslots
-                         + jnp.minimum(hl, b.nslots - 1), nflat)
-        return jnp.take(b.data.reshape(-1), flat, mode="fill",
-                        fill_value=0)
+        ok = ok & (i >= 0) & (i < b.words)
+        return jnp.take(b.data, b.at(i, hl, ok), mode="fill", fill_value=0)
 
+    @_heap_scoped
     def blob_length(self, h):
         """Logical word count recorded at blob_alloc ([lanes] i32; 0 for
         null/remote handles)."""
@@ -795,6 +819,7 @@ class Context:
         self.cap_types.tag(h, "val")
         return h
 
+    @_heap_scoped
     def blob_set(self, h, i, v, when=True):
         """Write word `i` of blob `h` (i32; masked by `when`). Only the
         owner holds the handle (iso), so lanes never collide; writes are
@@ -812,15 +837,12 @@ class Context:
         hl, okh = b.local(h)
         i = jnp.asarray(i, jnp.int32)
         ok = (jnp.asarray(when, jnp.bool_) & b.take & okh
-              & (i >= 0) & (i < b.data.shape[0])
+              & (i >= 0) & (i < b.words)
               & jnp.take(b.used, hl, mode="fill", fill_value=False))
-        flat = jnp.where(ok, jnp.minimum(i, b.data.shape[0] - 1)
-                         * b.nslots + jnp.minimum(hl, b.nslots - 1),
-                         b.data.shape[0] * b.nslots)   # OOB-high → dropped
-        v = jnp.broadcast_to(jnp.asarray(v, jnp.int32), flat.shape)
-        b.data = b.data.reshape(-1).at[flat].set(
-            v, mode="drop").reshape(b.data.shape)
+        v = jnp.broadcast_to(jnp.asarray(v, jnp.int32), ok.shape)
+        b.data = b.data.at[b.at(i, hl, ok)].set(v, mode="drop")
 
+    @_heap_scoped
     def blob_free(self, h, when=True):
         """Release blob `h` back to the pool. Explicit free is the fast
         path; blobs whose owner died (or whose handle moved off-shard)

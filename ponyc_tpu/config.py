@@ -254,8 +254,8 @@ class RuntimeOptions:
     # --- device blob pool (≙ rich message payloads: pony_alloc_msg +
     # actor-heap objects riding messages, pony.h:332-360 / genfun.c.
     # Messages carry a blob HANDLE (i32, mode iso — moved-unique); the
-    # words live device-resident in a [blob_words, shards*blob_slots]
-    # pool, so payloads larger than msg_words never round-trip the
+    # words live device-resident in a flat pool of shards * blob_words *
+    # blob_slots words, so payloads larger than msg_words never round-trip the
     # host. 0 = disabled (all blob plumbing compiles away). ---
     blob_slots: int = 0            # pool slots PER SHARD; handles carry
     #   (generation, global slot id) — ops/pack.py encoding. On a mesh a

@@ -50,7 +50,8 @@ class Cohort:
         # behaviours is discovered at trace time; the declared bound here is
         # the engine's outbox width. Behaviours exceeding it fail loudly at
         # trace, not silently at run.
-        self.max_sends = getattr(atype, "MAX_SENDS", None) or opts.max_sends
+        ms = getattr(atype, "MAX_SENDS", None)
+        self.max_sends = opts.max_sends if ms is None else int(ms)
         self.behaviours = list(atype.behaviour_defs)
         # Per-cohort mailbox word width (≙ per-type pony_msg_t sizes —
         # genfun.c packs exactly each behaviour's params; the reference

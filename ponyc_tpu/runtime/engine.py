@@ -47,7 +47,7 @@ from ..program import Cohort, Program
 from .delivery import (Entries, deliver, empty_mute_slots, mute_ref_slots,
                        rebuild_block_ranks)
 from .state import (PHASE_NAMES, QW_BUCKETS, PhaseCursor, RtState,
-                    layout_sizes, phase_scope)
+                    layout_sizes, phase_scope, pool_index)
 
 
 class StepAux(NamedTuple):
@@ -1059,7 +1059,15 @@ def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
         mask_np = blob["mask"]                   # STATIC numpy masks
         mask = jnp.asarray(mask_np)
         mask_iso = jnp.asarray(blob["mask_iso"])
-        wb = bdata.shape[0]
+        wb = bdata.shape[0] // bsl       # flat pool: state.pool_index
+        word_i = jnp.arange(wb, dtype=jnp.int32)[:, None]
+
+        def whole(slots, ok):
+            """Flat indices of whole blobs, [wb, len(slots)]; one past
+            the end (filled / dropped) where not `ok`."""
+            return jnp.where(ok[None, :],
+                             pool_index(bsl, word_i, slots[None, :]),
+                             bdata.shape[0])
         n_gids = mask.shape[0]
         sb = shards * bucket
         gid = bw[0]
@@ -1086,10 +1094,9 @@ def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
             extra_rows.append(jnp.where(
                 okh, jnp.take(blen, hx, mode="fill", fill_value=0),
                 jnp.int32(-1))[None, :])             # -1 = no payload
-            extra_rows.append(jnp.where(
-                okh[None, :],
-                jnp.take(bdata, hx, axis=1, mode="fill", fill_value=0),
-                0))                                  # [wb, sb]
+            extra_rows.append(jnp.take(
+                bdata, whole(hx, okh), mode="fill",
+                fill_value=0))                       # [wb, sb]
             # Iso handles MOVE (source freed); val handles COPY — the
             # receiver gets a replica, other readers keep the original.
             freed = freed.at[jnp.where(okh & mask_iso[g, wpos],
@@ -1137,10 +1144,8 @@ def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
             bgen = bgen.at[sx].set(newgen, mode="drop")
             bused = bused.at[sx].set(True, mode="drop")
             blen = blen.at[sx].set(jnp.where(ok, lenr, 0), mode="drop")
-            bdata = bdata.at[:, sx].set(
-                jnp.where(ok[None, :], rw[base_row + 1:base_row + 1 + wb],
-                          jnp.take(bdata, sx, axis=1, mode="fill",
-                                   fill_value=0)), mode="drop")
+            bdata = bdata.at[whole(sx, ok)].set(
+                rw[base_row + 1:base_row + 1 + wb], mode="drop")
             newh = pack.blob_handle(bbase + slot_l, newgen)
             # has & ok → fresh local handle; has & ~ok → dropped (null);
             # ~has → original word untouched (not a blob for this gid,

@@ -22,6 +22,7 @@ one tick, as if steps were dispatched singly.
 from __future__ import annotations
 
 import collections
+import functools
 import os
 import signal
 import time
@@ -39,7 +40,7 @@ from ..ops import pack
 from ..program import Program
 from . import engine
 from .controller import WindowController
-from .state import RtState, init_state
+from .state import RtState, init_state, pool_index
 
 # Window-length histogram buckets (power-of-two, like state.QW_BUCKETS):
 # bucket k counts retired windows that ran [2^k, 2^(k+1)) ticks.
@@ -59,7 +60,25 @@ PHASE_STAMPS = {"dispatching": "dispatching", "wait": "in-flight",
                 "host-work": "host-work", "quiescent": "quiescent"}
 RUN_PHASES = ("enter", "dispatching", "wait", "host-work", "outbox",
               "pollers", "gc", "checkpoint", "analysis", "quiescent",
-              "exit")
+              "exit", "blob-store")
+
+
+@functools.partial(jax.jit, static_argnames=("bw", "bsl", "run"))
+def _fetch_blobs(data, slots, *, bw: int, bsl: int, run: bool):
+    """Every word of the pool's global `slots`, [len(slots), bw], from
+    the flat blob_data. `run`: the slots are adjacent and of one shard,
+    so each word is one slice. Compiled once a shape (a whole-table read
+    in blocks calls it block after block)."""
+    shard, local = slots // bsl, slots % bsl
+    words = jnp.arange(bw, dtype=jnp.int32)
+    if run:
+        first = shard[0] * (bw * bsl) + pool_index(bsl, words, local[0])
+        got = jax.vmap(lambda a: jax.lax.dynamic_slice(
+            data, (a,), slots.shape))(first)
+    else:
+        got = data[shard[None, :] * (bw * bsl) + pool_index(
+            bsl, words[:, None], local[None, :])]
+    return got.T
 
 
 class _PhaseSpan:
@@ -526,6 +545,7 @@ class Runtime:
         if unknown:
             raise TypeError(f"{atype.__name__} has no fields {unknown}")
         self._check_ref_fields(atype, fields)
+        self._move_blob_fields(atype, fields)
         if not cohort.host and (self.program.has_device_spawns
                                 or self.steps_run):
             # Device-side spawn/destroy/GC may have claimed or freed slots
@@ -730,6 +750,7 @@ class Runtime:
         e.g. wiring refs once ids are known). ids are global actor ids."""
         cohort = self.program.by_type[atype]
         self._check_ref_fields(atype, fields)
+        self._move_blob_fields(atype, fields)
         if cohort.host:
             for i, aid in enumerate(np.asarray(ids).reshape(-1)):
                 st = self._host_state.setdefault(int(aid), {})
@@ -2201,13 +2222,55 @@ class Runtime:
                            "its slot was recycled (generation mismatch)")
         return slot
 
+    def _blob_flat(self, slots) -> np.ndarray:
+        """Where the words of the pool's global `slots` lie in the flat
+        blob_data, [blob_words, len(slots)] (state.pool_index inside a
+        shard's block, the blocks shard-major)."""
+        bw, bsl = self.opts.blob_words, self.opts.blob_slots
+        shard, local = np.divmod(np.asarray(slots, np.int64), bsl)
+        return (shard * (bw * bsl) + pool_index(
+            bsl, np.arange(bw, dtype=np.int64)[:, None], local))
+
     def blob_fetch(self, handle: int) -> np.ndarray:
         """Host-side read of a device blob's logical words (≙ receiving
         a message payload on the main-thread scheduler). Raises on null/
         unallocated/stale handles."""
         slot = self._blob_slot_of(handle, "blob_fetch")
         ln = int(self._fetch(self.state.blob_len)[slot])
-        return self._fetch(self.state.blob_data)[:ln, slot]
+        at = self._blob_flat([slot])[:ln, 0]
+        data = self.state.blob_data
+        # Addressable pools: gather on device (the blob crosses the
+        # wire, not the pool).
+        return (np.asarray(data[at])
+                if getattr(data, "is_fully_addressable", True)
+                else self._fetch(data)[at])
+
+    def blob_fetch_many(self, handles) -> np.ndarray:
+        """blob_fetch's bulk twin: every word of every handle's blob,
+        [count, blob_words] (logical lengths are not applied), in ONE
+        device gather — the blobs cross the wire, not the pool. Raises
+        on a null, unallocated or stale handle, as blob_fetch does."""
+        h = np.asarray(handles, np.int64).reshape(-1)
+        bw, bsl = self.opts.blob_words, self.opts.blob_slots
+        slots = pack.blob_slot(h)
+        if (h < 0).any() or (slots >= self.program.shards * bsl).any():
+            raise IndexError("blob_fetch_many: a handle out of range")
+        if not self._fetch(self.state.blob_used)[slots].all():
+            raise KeyError("blob_fetch_many: a handle is not allocated")
+        if ((self._fetch(self.state.blob_gen)[slots] & pack.BLOB_GEN_MASK)
+                != pack.blob_gen_of(h)).any():
+            raise KeyError("blob_fetch_many: a STALE handle — its slot "
+                           "was recycled (generation mismatch)")
+        # adjacent slots of one shard (a fresh pool's) are `blob_words`
+        # strided runs, read as slices; anything else word by word
+        run = (h.size > 0 and slots[0] // bsl == slots[-1] // bsl
+               and np.array_equal(slots, slots[0] + np.arange(h.size)))
+
+        data = self.state.blob_data
+        if not getattr(data, "is_fully_addressable", True):
+            data = self._fetch(data)
+        return np.asarray(_fetch_blobs(data, slots.astype(np.int32),
+                                       bw=bw, bsl=bsl, run=bool(run)))
 
     def blob_store(self, words, length: Optional[int] = None,
                    near: Optional[int] = None) -> int:
@@ -2255,7 +2318,8 @@ class Runtime:
         gen = (int(self._fetch(st.blob_gen)[slot]) + 1) \
             & pack.BLOB_GEN_MASK
         self.state = self._replace(
-            blob_data=st.blob_data.at[:, slot].set(jnp.asarray(full)),
+            blob_data=st.blob_data.at[self._blob_flat([slot])[:, 0]].set(
+                jnp.asarray(full)),
             blob_used=st.blob_used.at[slot].set(True),
             blob_len=st.blob_len.at[slot].set(jnp.int32(ln)),
             blob_gen=st.blob_gen.at[slot].set(jnp.int32(gen)),
@@ -2263,6 +2327,107 @@ class Runtime:
         handle = pack.blob_handle(slot, gen)
         self._host_blobs.add(handle)    # GC root until sent/freed
         return handle
+
+    def blob_store_many(self, count: int, words=None, *, fill=None,
+                        length: Optional[int] = None,
+                        near: Optional[int] = None) -> np.ndarray:
+        """blob_store's bulk twin (≙ spawn_many for the actor heap):
+        claims the `count` lowest free pool slots and writes them in ONE
+        device operation. Returns the [count] handles, host-owned like
+        blob_store's until a send, spawn_many or set_fields moves them
+        into an actor (`spawn_many(T, count, table=handles)`).
+
+        The words come from `words` ([count, w] i32, w <= blob_words,
+        shipped from the host) or from `fill(k, w)`, evaluated on the
+        device: a jnp function of the blob's index in this call, k
+        [1, count], and the word's index, w [blob_words, 1] (a table too
+        large to ship — `fill=lambda k, w: k * 2048 + w`). Neither:
+        zeroed blobs. `length` and `near` are blob_store's."""
+        if self.opts.blob_slots <= 0:
+            raise RuntimeError("blob pool disabled: set "
+                               "RuntimeOptions.blob_slots/blob_words")
+        if words is not None and fill is not None:
+            raise ValueError("give words or fill, not both")
+        count, bw, bsl = int(count), self.opts.blob_words, \
+            self.opts.blob_slots
+        if words is not None:
+            words = np.asarray(words, np.int32).reshape(count, -1)
+            if words.shape[1] > bw:
+                raise ValueError(
+                    f"{words.shape[1]} words > blob_words={bw}")
+        ln = ((bw if words is None else words.shape[1])
+              if length is None else int(length))
+        if not 0 <= ln <= bw:
+            raise ValueError(
+                f"length={ln} outside [0, blob_words={bw}]")
+        used = self._fetch(self.state.blob_used)
+        off = 0
+        if near is not None:
+            off = (int(near) // self.program.n_local) * bsl
+            used = used[off:off + bsl]
+        free = np.flatnonzero(~used)
+        if free.size < count:
+            raise BlobCapacityError(
+                f"host blob_store_many: {count} blobs wanted, "
+                f"{free.size} pool slots free"
+                + (f" on shard {near // self.program.n_local}"
+                   if near is not None else ""))
+        slots = (off + free[:count]).astype(np.int32)
+        gens = ((self._fetch(self.state.blob_gen)[slots] + 1)
+                & pack.BLOB_GEN_MASK).astype(np.int32)
+        allocs = np.bincount(slots // bsl, minlength=self.program.shards)
+        # A whole shard's pool (a fresh one's, word-major: exactly the
+        # [blob_words, count] block) is one slice update in place;
+        # anything else is one scatter of single words.
+        whole = count == bsl and int(slots[0]) % bsl == 0
+        at = None if whole else self._blob_flat(slots)
+
+        def store(data, used, len_, gen, n_alloc, slots, gens, vals, at):
+            if fill is not None:
+                vals = jnp.broadcast_to(jnp.asarray(fill(
+                    jnp.arange(count, dtype=jnp.int32)[None, :],
+                    jnp.arange(bw, dtype=jnp.int32)[:, None]),
+                    jnp.int32), (bw, count))
+            if whole:
+                data = jax.lax.dynamic_update_slice(
+                    data, vals.reshape(-1), (slots[0] * bw,))
+            else:
+                data = data.at[at].set(vals)
+            return (data, used.at[slots].set(True),
+                    len_.at[slots].set(jnp.int32(ln)),
+                    gen.at[slots].set(gens),
+                    n_alloc + jnp.asarray(allocs, jnp.int32))
+
+        vals = None
+        if words is not None:
+            vals = np.zeros((bw, count), np.int32)
+            vals[:words.shape[1]] = words.T
+        elif fill is None:
+            fill = lambda k, w: 0           # noqa: E731 — zeroed blobs
+        st = self.state
+        with self._phase("blob-store", blobs=count):
+            data, used_d, len_d, gen_d, n_alloc = jax.jit(
+                store, donate_argnums=(0, 1, 2, 3, 4))(
+                st.blob_data, st.blob_used, st.blob_len, st.blob_gen,
+                st.n_blob_alloc, slots, gens, vals, at)
+            self.state = self._replace(
+                blob_data=data, blob_used=used_d, blob_len=len_d,
+                blob_gen=gen_d, n_blob_alloc=n_alloc)
+        handles = np.asarray(pack.blob_handle(slots, gens), np.int32)
+        self._host_blobs.update(handles.tolist())   # roots until moved
+        return handles
+
+    def _move_blob_fields(self, atype: ActorTypeMeta, fields) -> None:
+        """An iso Blob handle the host stores into an actor's field has
+        MOVED there (≙ send()'s iso args): the field is its GC root from
+        now on, the host's goes."""
+        if not self._host_blobs:
+            return
+        for fname, v in fields.items():
+            spec = atype.field_specs.get(fname)
+            if pack.is_blob(spec) and not pack.is_blob_val(spec):
+                self._host_blobs.difference_update(
+                    np.asarray(v).reshape(-1).tolist())
 
     def blob_free_host(self, handle: int) -> None:
         """Host-side release of a blob the host owns (e.g. fetched and

@@ -83,7 +83,8 @@ N_PHASES = len(PHASE_NAMES)
 # are metadata only — the optimised HLO with `_named_scope` stubbed out
 # is the same program (tests/test_profiler.py).
 SCOPE_PREFIX = "pony"
-STEP_SCOPES = ("unmute", "spawn", "drain", "dispatch", "route",
+STEP_SCOPES = ("unmute", "spawn", "drain", "dispatch", "dispatch/heap",
+               "route",
                "delivery", "delivery/plan", "delivery/plan/bounds",
                "delivery/permute", "delivery/rebuild", "delivery/pressure",
                "delivery/pressure/spill", "delivery/pressure/mute",
@@ -95,6 +96,13 @@ def phase_scope(path: str):
     """Context manager: the traced operations inside belong to phase
     `path` (one of STEP_SCOPES, or `analysis` for the opt-in lanes)."""
     return _named_scope(f"{SCOPE_PREFIX}/{path}")
+
+
+def pool_index(nslots: int, word, slot):
+    """Where word `word` of slot `slot` lies in ONE shard's flat blob
+    pool of `nslots` slots (RtState.blob_data): word-major, slots on the
+    lanes. Broadcasts; jnp or numpy."""
+    return word * nslots + slot
 
 
 class PhaseCursor:
@@ -327,10 +335,16 @@ class RtState:
     # and per-type object graphs, pony.h:332-360; see ops.pack.Blob and
     # api.Context.blob_*): message payloads wider than msg_words live
     # here and ride messages as moved-unique HANDLES (global id =
-    # shard * blob_slots + slot; -1 null). Planar layout like every hot
-    # array: word index major, blob slot minor (lanes). Zero-size when
+    # shard * blob_slots + slot; -1 null). Planar like every hot array,
+    # word index major, blob slot minor (lanes) — and FLAT: word w of a
+    # shard's slot s lies at w*BS + s of the shard's block (shard-major,
+    # so the one axis shards). A behaviour reads and writes single words
+    # (api.Context.blob_get / blob_set), which the chip does in place on
+    # a 1-D array only: of a [W, BS] table it first makes a flat copy,
+    # the whole pool a batch slot (PERF.md, PR 33). `pool_index` below
+    # is the one place that knows the order. Zero-size when
     # RuntimeOptions.blob_slots == 0 — all plumbing compiles away.
-    blob_data: jnp.ndarray    # [blob_words, P*BS] int32 payload words
+    blob_data: jnp.ndarray    # [P*blob_words*BS] int32 payload words
     blob_used: jnp.ndarray    # [P*BS] bool — slot allocated
     blob_len: jnp.ndarray     # [P*BS] int32 — logical word count
     blob_gen: jnp.ndarray     # [P*BS] int32 — slot generation, bumped on
@@ -475,7 +489,7 @@ def init_state(program: Program, opts: RuntimeOptions) -> RtState:
         plan_perm=jnp.zeros((p * n_entries,), i32),
         plan_bounds=jnp.zeros((p * (program.n_local + 1),), i32),
         world_bits=jnp.zeros((p,), i32),
-        blob_data=jnp.zeros((opts.blob_words, p * opts.blob_slots), i32),
+        blob_data=jnp.zeros((p * opts.blob_words * opts.blob_slots,), i32),
         blob_used=jnp.zeros((p * opts.blob_slots,), jnp.bool_),
         blob_len=jnp.zeros((p * opts.blob_slots,), i32),
         blob_gen=jnp.zeros((p * opts.blob_slots,), i32),

@@ -167,7 +167,8 @@ def _state_from_named(template, arrays: Dict[str, np.ndarray]):
             kw[f.name] = {tname: _take(arrays, f"st.{f.name}.{tname}", arr)
                           for tname, arr in v.items()}
         else:
-            kw[f.name] = _take(arrays, f"st.{f.name}", v)
+            kw[f.name] = _take(arrays, f"st.{f.name}", v,
+                               template.world_bits.shape[0])
     return dataclasses.replace(template, **kw)
 
 
@@ -264,7 +265,19 @@ def _pad_phase_lanes(arr, n_shards: int) -> np.ndarray:
     return np.pad(rows, ((0, 0), (0, N_PHASES - rows.shape[1]))).ravel()
 
 
-def _take(arrays, name, like):
+def _flat_pool(arr, n_shards: int) -> np.ndarray:
+    """`st.blob_data` of a snapshot written when the pool was a
+    [words, shards * slots] table: the flat pool holds a shard's block
+    word-major (state.pool_index), the blocks shard-major."""
+    arr = np.asarray(arr)
+    if arr.ndim != 2:
+        return arr
+    words, slots = arr.shape
+    return arr.reshape(words, n_shards, slots // n_shards) \
+        .transpose(1, 0, 2).reshape(-1)
+
+
+def _take(arrays, name, like, n_shards: int = 1):
     arr = arrays.get(name)
     if arr is None and name in _ZERO_IF_ABSENT:
         return jnp.zeros(like.shape, like.dtype)
@@ -273,6 +286,8 @@ def _take(arrays, name, like):
     if name == "st.phase_cost":
         from .runtime.state import N_PHASES
         arr = _pad_phase_lanes(arr, like.size // N_PHASES)
+    if name == "st.blob_data":
+        arr = _flat_pool(arr, n_shards)
     if tuple(arr.shape) != tuple(like.shape):
         raise FingerprintMismatch(
             f"array {name!r} shape {tuple(arr.shape)} != "
@@ -875,12 +890,16 @@ def _restore_relayout(rt, header, Z: Dict[str, np.ndarray]) -> None:
 
     # ---- blob pool scatter ----
     if bs_old and bs_new:
-        data_o = Z["st.blob_data"]
+        # flat pools, a shard's block word-major (state.pool_index)
+        data_o = _flat_pool(Z["st.blob_data"], p_old) \
+            .reshape(p_old, bw_old, bs_old)
+        data_n = st["blob_data"].reshape(p_new, bw_new, bs_new)
         len_o = Z["st.blob_len"]
         for g in np.flatnonzero(used_o):
             ns = int(blob_slot_map[g])
             w = min(bw_old, bw_new)
-            st["blob_data"][:w, ns] = data_o[:w, g]
+            data_n[ns // bs_new, :w, ns % bs_new] = \
+                data_o[g // bs_old, :w, g % bs_old]
             st["blob_used"][ns] = True
             st["blob_len"][ns] = len_o[g]
             st["blob_gen"][ns] = gen_o[g]

@@ -238,7 +238,7 @@ def probe_behaviour(bdef: BehaviourDef,
         from .api import BlobPoolView
         mb = int(getattr(atype, "MAX_BLOBS", 0) or 0)
         bv = BlobPoolView(
-            jnp.zeros((1, 1), jnp.int32), jnp.zeros((1,), jnp.bool_),
+            jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.bool_),
             jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
             jnp.int32(0), jnp.bool_(True),
             jnp.full((mb,), -1, jnp.int32) if mb else None)
@@ -287,8 +287,9 @@ def behaviour_effects(bdef: BehaviourDef,
     (`MAX_SENDS or opts.max_sends`, program.py) so verify enforces the
     budget the engine actually uses."""
     atype = atype or bdef.actor_type
-    max_sends = (getattr(atype, "MAX_SENDS", None)
-                 or int(default_max_sends))
+    max_sends = getattr(atype, "MAX_SENDS", None)
+    if max_sends is None:
+        max_sends = int(default_max_sends)
     if getattr(atype, "HOST", False):
         return Effects(sends=0, max_sends=0, can_error=False,
                        can_destroy=False, can_exit=False,

@@ -779,3 +779,60 @@ def test_snapshot_resumes_midflight_blob_pipeline():
     assert st["n"] == want_n
     assert np.int32(st["total"]) == np.int32(want_total)
     assert rt2.blobs_in_use == 0
+
+
+@pytest.mark.parametrize("target", ["same-layout", "relayout"])
+def test_snapshot_of_the_pool_as_a_table_restores_into_the_flat_pool(
+        tmp_path, target):
+    """A snapshot written when `st.blob_data` was a [words, slots] table
+    (before the pool was flat) restores mid-flight, same layout and
+    re-laid-out, and finishes exact; so does a blob-free one's empty
+    [0, 0] table."""
+    from ponyc_tpu import serialise
+    from ponyc_tpu.models import records, ring
+
+    def opts(**kw):
+        return RuntimeOptions(**{**dict(
+            mailbox_cap=8, batch=2, max_sends=2, msg_words=2,
+            inject_slots=8, blob_slots=128, blob_words=records.W), **kw})
+    rt, _, sources = records.build(8, 6, opts())
+    for s in sources:
+        rt.send(int(s), records.RecSource.emit, 0)
+    rt.run(max_steps=3)
+    assert rt.blobs_in_use > 0
+    header, arrays = serialise.capture(rt)
+    arrays["st.blob_data"] = np.ascontiguousarray(
+        arrays["st.blob_data"].reshape(records.W, 128))
+    path = str(tmp_path / "table-pool.npz")
+    serialise.write_snapshot(header, arrays, path)
+
+    okw = {"same-layout": {}, "relayout": dict(blob_slots=64)}[target]
+    rt2, sink2, _ = records.build(8, 6, opts(**okw))
+    serialise.restore(rt2, path)
+    rt2.run()
+    want_n, want_total = records.oracle(8, 6)
+    st = rt2.state_of(int(sink2))
+    assert st["n"] == want_n
+    assert np.int32(st["total"]) == np.int32(want_total)
+    assert rt2.blobs_in_use == 0
+
+    if target == "same-layout":
+        # two shards' table: each shard's block word-major, shard-major
+        from ponyc_tpu.runtime.state import pool_index
+        table = np.arange(3 * 8).reshape(3, 8)         # [words, 2 x 4 slots]
+        flat = serialise._flat_pool(table, 2)
+        for shard, word, slot in ((0, 0, 0), (0, 2, 3), (1, 0, 0), (1, 1, 2)):
+            assert flat[shard * 12 + pool_index(4, word, slot)] \
+                == table[word, shard * 4 + slot]
+        free = RuntimeOptions(mailbox_cap=8, batch=2, max_sends=1,
+                              msg_words=1, inject_slots=8)
+        rt3, ids = ring.build(8, free)
+        rt3.send(int(ids[0]), ring.RingNode.token, 20)
+        rt3.run(max_steps=4)
+        header, arrays = serialise.capture(rt3)
+        arrays["st.blob_data"] = np.zeros((0, 0), np.int32)
+        serialise.write_snapshot(header, arrays, path)
+        rt4, _ = ring.build(8, free)
+        serialise.restore(rt4, path)
+        assert rt4.run() == 0
+        assert rt4.cohort_state(ring.RingNode)["passes"].sum() == 20
