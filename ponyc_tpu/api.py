@@ -40,6 +40,7 @@ import inspect
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
+from jax import lax
 
 from .ops import pack
 from .ops.pack import (Blob, BlobVal, Bool, Box, F32, I8, I16, I32,  # noqa
@@ -298,6 +299,13 @@ def _heap_scoped(method):
     return scoped
 
 
+# One word an index into the flat pool (lax.scatter, not `.at[]`, which
+# casts its indices to int32).
+_WORD_SCATTER = lax.ScatterDimensionNumbers(
+    update_window_dims=(), inserted_window_dims=(0,),
+    scatter_dims_to_operand_dims=(0,))
+
+
 class BlobPoolView:
     """Trace-time working view of the device blob pool for ONE behaviour
     evaluation (see ops.pack.Blob; pool arrays live in runtime.state).
@@ -366,6 +374,43 @@ class BlobPoolView:
         from .runtime.state import pool_index
         return jnp.where(ok, pool_index(self.nslots, word, slot),
                          self.data.shape[0])
+
+    def ordered(self, word, slot, ok, value):
+        """The writes of one blob_set as (keys u32, values i32), flat and
+        STRICTLY ascending by key — what a scatter flagged sorted and
+        unique may be handed. A lane that writes is keyed by its flat
+        index; one that does not by `len(data) + lane`, its own and past
+        the end, so the dropped lanes sort behind every write and
+        FILL_OR_DROP drops them. u32 holds a pool of 2^31 - 1 words plus
+        the lanes.
+
+        Writing lanes are distinct by iso ownership. A handle forged or
+        copied through an untyped int breaks that, and shows after the
+        sort as two equal neighbours: the lowest lane keeps the word,
+        the others are re-keyed past the end and the vector is sorted
+        once more — behind a cond, so an honest program pays one compare
+        over the lanes."""
+        from .runtime.state import pool_index
+        size, n = self.data.shape[0], ok.size
+        if size + n > 1 << 32:
+            raise ValueError(
+                f"blob_set: {n} lanes past a pool of {size} words do not "
+                "fit an unsigned 32-bit key")
+        past = jnp.uint32(size) + lax.iota(jnp.uint32, n)
+        flat = pool_index(self.nslots, word, slot).astype(jnp.uint32)
+        key, value = lax.sort(
+            (jnp.where(ok, flat, past.reshape(ok.shape)).reshape(-1),
+             value.reshape(-1)), num_keys=1, is_stable=True)
+        dup = jnp.concatenate(
+            [jnp.zeros((1,), jnp.bool_), key[1:] == key[:-1]])
+
+        def one_write_a_word():
+            return tuple(lax.sort(
+                (jnp.where(dup | (key >= size), past, key), value),
+                num_keys=1, is_stable=True))
+
+        return lax.cond(jnp.any(dup), one_write_a_word,
+                        lambda: (key, value))
 
 
 class Context:
@@ -825,7 +870,12 @@ class Context:
         owner holds the handle (iso), so lanes never collide; writes are
         visible to this dispatch's later blob_get calls and to the
         handle's next owner after a send. Floats: pass
-        ``value.view(jnp.int32)``."""
+        ``value.view(jnp.int32)``.
+
+        The lanes are scattered in the order of their flat pool index
+        and XLA is told so: its TPU scatter of single words is one
+        update after another unless the indices are declared sorted AND
+        unique (BlobPoolView.ordered makes both true)."""
         b = self._require_blob("blob_set")
         self._blob_guard(h, "blob_set")
         if self.cap_types.lookup(h) == "val":
@@ -840,7 +890,10 @@ class Context:
               & (i >= 0) & (i < b.words)
               & jnp.take(b.used, hl, mode="fill", fill_value=False))
         v = jnp.broadcast_to(jnp.asarray(v, jnp.int32), ok.shape)
-        b.data = b.data.at[b.at(i, hl, ok)].set(v, mode="drop")
+        key, v = b.ordered(i, hl, ok, v)
+        b.data = lax.scatter(
+            b.data, key[:, None], v, _WORD_SCATTER, indices_are_sorted=True,
+            unique_indices=True, mode=lax.GatherScatterMode.FILL_OR_DROP)
 
     @_heap_scoped
     def blob_free(self, h, when=True):

@@ -719,8 +719,9 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
     def run_cohort(type_state_rows, buf_rows, head_rows, occ_rows,
                    runnable_rows, ids, resv, blob=None):
         # buf_rows: [cap, w1, rows]; resv: {target: [sd, sites, rows]};
-        # blob (pool-using cohorts only): dict(data [W,B], used [B],
-        # len [B], base i32, resv [batch, sites, rows] global handles).
+        # blob (pool-using cohorts only): dict(data [W*B] flat,
+        # word-major (state.pool_index), used [B], len [B], gen [B],
+        # base i32, resv [blob_dispatches, sites, rows] global handles).
         e = rows * batch * ms
         if use_blob and blob is None:
             raise RuntimeError(

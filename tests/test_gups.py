@@ -159,17 +159,20 @@ def test_field_held_blobs_are_the_actors_own_and_survive_gc():
     rt.stop()
 
 
-def _lowered_window(delivery):
+def _lowered(rt):
+    """The window of a started runtime, lowered; the runtime stopped."""
     import jax.numpy as jnp
 
     from ponyc_tpu.runtime import engine
-    world = _world(0, delivery=delivery)
-    rt = world.rt
     lowered = jax.jit(engine.build_multi_step_gated(rt.program, rt.opts)) \
         .lower(rt.state, *rt._empty_inject, jnp.int32(4), jnp.bool_(True),
                engine.zero_aux())
     rt.stop()
     return lowered
+
+
+def _lowered_window(delivery):
+    return _lowered(_world(0, delivery=delivery).rt)
 
 
 @pytest.mark.parametrize("delivery", DELIVERIES)
@@ -191,3 +194,39 @@ def test_heap_scope_is_named_and_is_metadata_only(delivery, monkeypatch):
     bare = _lowered_window(delivery).compile().as_text()
     assert "pony/" not in bare
     assert _bare_hlo(scoped) == _bare_hlo(bare)
+
+
+def _scatters(text):
+    """(attributes, operand types) of every scatter in a lowered
+    module's text."""
+    return re.findall(r'"stablehlo\.scatter"\([^)]*\) <\{([^}]*)\}>.*?'
+                      r'\}\) : \(([^)]*)\) ->', text, flags=re.S)
+
+
+@pytest.mark.parametrize("world", DELIVERIES + ["blob-free"])
+def test_the_heaps_write_is_a_sorted_unique_scatter(world):
+    """Every scatter on the pool in this world's window is declared
+    sorted and unique and takes the unsigned keys of
+    `BlobPoolView.ordered`, whose sort lies under `pony/dispatch/heap`;
+    the window of a world without a blob cohort holds neither."""
+    if world == "blob-free":
+        from ponyc_tpu.models import ring
+        rt, _ids = ring.build(8, RuntimeOptions(
+            mailbox_cap=4, batch=1, max_sends=1, msg_words=1,
+            inject_slots=8, compile_cache="off", tuning_cache="off"))
+        text = _lowered(rt).as_text(debug_info=True)
+        assert "pony/dispatch/heap" not in text
+        assert _scatters(text) and not [
+            t for _, t in _scatters(text) if "ui32" in t]
+        return
+    text = _lowered_window(world).as_text(debug_info=True)
+    pool = f"tensor<{ACTORS // 2 * SLICE}xi32>"
+    on_pool = [(a, t) for a, t in _scatters(text) if t.startswith(pool)]
+    assert len(on_pool) >= 1
+    for attrs, types in on_pool:
+        assert "indices_are_sorted = true" in attrs \
+            and "unique_indices = true" in attrs, attrs
+        assert types == f"{pool}, tensor<64x1xui32>, tensor<64xi32>"
+    assert "pony/dispatch/heap/sort" in text
+    assert "pony/dispatch/heap/cond" in text      # the breach of iso
+
