@@ -359,8 +359,10 @@ class Analysis:
                 f"host_gap_ms={rl['host_gap_us_total'] / 1e3:.2f} "
                 f"windows_wall_ms={rl['windows_wall_s'] * 1e3:.2f}")
             lines.append("run_loop phase_ms " + " ".join(
-                f"{k}={v * 1e3:.2f}"
-                for k, v in rl["phase_s"].items() if v))
+                f"{k}={v * 1e3:.2f}/{rl['phase_n'][k]}"
+                for k, v in rl["phase_s"].items() if v)
+                + f" cold_dispatch_ms={rl['cold_dispatch_s'] * 1e3:.2f}"
+                f"/{rl['cold_dispatches']}")
         if self.level >= 3 and rt.state is not None:
             lines.append(
                 f"events_pending={int(np.asarray(rt.state.ev_count).sum())} "

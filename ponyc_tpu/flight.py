@@ -122,7 +122,9 @@ class FlightRecorder:
     # -- recording (hot-ish path: host ints only, one deque append) --
     def window(self, step: int, ticks: int, budget: int, gap_us: float,
                pipelined: bool, aux, wall_ms: float = 0.0,
-               wait_ms: float = 0.0, since_prev_ms: float = 0.0) -> None:
+               wait_ms: float = 0.0, since_prev_ms: float = 0.0,
+               dispatch_ms: float = 0.0,
+               outside_ms: Optional[Dict[str, float]] = None) -> None:
         """One retired window's facts. `aux` is the already-fetched
         host-side StepAux (numpy scalars) — the recorder converts, the
         run loop pays no extra device traffic. `wall_ms`: dispatch start
@@ -131,7 +133,14 @@ class FlightRecorder:
         previous record's retire to this dispatch's start, across run()
         calls too. A pipelined window has since_prev_ms 0 and a wall_ms
         counted from the previous retire, so since_prev_ms + wall_ms of
-        consecutive records tile the wall clock."""
+        consecutive records tile the wall clock. `dispatch_ms`: the
+        window's `pony:dispatching`, start of the dispatch to the
+        launch's return — the first stretch of a sync-point window's
+        wall_ms; a pipelined window's ran inside the record before.
+        `outside_ms`: {API phase: ms} (`runtime.API_PHASES`: a
+        counter() read, a cohort_state()) for the calls that ran in
+        since_prev_ms — its itemisation; what is left of it is run()'s
+        own exit and entry and the caller's."""
         self.windows.append({
             "t_ms": round((time.time() - self.t0) * 1e3, 3),
             "step": int(step), "ticks": int(ticks),
@@ -139,6 +148,9 @@ class FlightRecorder:
             "wall_ms": round(float(wall_ms), 4),
             "wait_ms": round(float(wait_ms), 4),
             "since_prev_ms": round(float(since_prev_ms), 4),
+            "dispatch_ms": round(float(dispatch_ms), 4),
+            "outside_ms": {p: round(float(ms), 4)
+                           for p, ms in (outside_ms or {}).items()},
             "pipelined": bool(pipelined),
             "processed": int(aux.n_processed) & 0xFFFFFFFF,
             "delivered": int(aux.n_delivered) & 0xFFFFFFFF,
@@ -410,6 +422,12 @@ def render_postmortem(pm: Dict[str, Any]) -> str:
             clock = ("" if "wall_ms" not in w else
                      f"wall={w['wall_ms']}ms wait={w.get('wait_ms')}ms "
                      f"since_prev={w.get('since_prev_ms')}ms ")
+            # dispatch/outside: absent before ISSUE 35's
+            if "dispatch_ms" in w:
+                clock += f"dispatch={w['dispatch_ms']}ms "
+            if w.get("outside_ms"):
+                clock += "outside=" + ",".join(
+                    f"{p}:{ms}ms" for p, ms in w["outside_ms"].items()) + " "
             lines.append(
                 f"  step={w['step']} ticks={w['ticks']}/{w['budget']} "
                 f"gap={w['gap_us']}us {clock}occ={w['occ_sum']} "

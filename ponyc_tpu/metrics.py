@@ -305,9 +305,18 @@ def prometheus_text(snap: Dict[str, Any],
             "Cumulative wall clock of retired windows (dispatch start "
             "to retire)", [(None, round(rl.get("windows_wall_s", 0.0), 6))])
         fam("pony_tpu_run_phase_seconds_total", "counter",
-            "Seconds run() spent in each run-loop phase (self time)",
+            "Seconds spent in each phase of run() and in each public "
+            "call outside it (self time)",
             [({"phase": k}, round(v, 6))
              for k, v in sorted((rl.get("phase_s") or {}).items())])
+        fam("pony_tpu_run_phase_calls_total", "counter",
+            "Times each phase ran (seconds / calls: its mean)",
+            [({"phase": k}, v)
+             for k, v in sorted((rl.get("phase_n") or {}).items())])
+        fam("pony_tpu_cold_dispatch_seconds_total", "counter",
+            "Seconds of the window's first launches since start(): "
+            "trace, lower, compile or cache reload",
+            [(None, round(rl.get("cold_dispatch_s", 0.0), 6))])
         ctrl = rl.get("controller")
         if ctrl:
             fam("pony_tpu_window_length", "gauge",

@@ -58,9 +58,21 @@ WIN_BUCKETS = 16
 # watchdog's stamp as it is: the watchdog's vocabulary does not change.
 PHASE_STAMPS = {"dispatching": "dispatching", "wait": "in-flight",
                 "host-work": "host-work", "quiescent": "quiescent"}
+# Outside run() (ISSUE 35) every public call that touches the device is
+# a phase of the same mechanism (`_api_phase`): start, spawn, set-fields,
+# bulk-send, blob-store, blob-fetch, read (state columns and the other
+# array reads), counter (one scalar), stop. They stamp nothing, so the
+# watchdog reads what it read; called from a host behaviour inside run()
+# they nest under outbox like any child. send() only appends to a host
+# deque and stays bare. dispatching and the host-work family stay
+# LEAVES of the run loop's own making: benchmarks/phase_trace.py sums a
+# window's host cost from their self times by name.
+API_PHASES = ("start", "spawn", "set-fields", "bulk-send", "blob-store",
+              "blob-fetch", "read", "counter", "stop")
 RUN_PHASES = ("enter", "dispatching", "wait", "host-work", "outbox",
               "pollers", "gc", "checkpoint", "analysis", "quiescent",
-              "exit", "blob-store")
+              "exit") + API_PHASES
+_API_PHASES = frozenset(API_PHASES)
 
 
 @functools.partial(jax.jit, static_argnames=("bw", "bsl", "run"))
@@ -82,9 +94,10 @@ def _fetch_blobs(data, slots, *, bw: int, bsl: int, run: bool):
 
 
 class _PhaseSpan:
-    """One run-loop phase as a context (`Runtime._phase`). With no
-    profiler session it costs two clock reads, a list push and pop and a
-    dict add; the annotation is built only while one is recording."""
+    """One phase as a context (`Runtime._phase`). With no profiler
+    session it costs two clock reads, a list push and pop and two dict
+    adds (seconds and calls; a third for a phase of API_PHASES); the
+    annotation is built only while one is recording."""
 
     __slots__ = ("rt", "name", "meta", "note", "t0", "child_s")
 
@@ -111,12 +124,35 @@ class _PhaseSpan:
         stack = rt._phase_stack
         while stack and stack.pop() is not self:
             pass        # an interrupt may have left a child behind
-        rt._phase_s[self.name] += dt - self.child_s
+        own = dt - self.child_s
+        rt._phase_s[self.name] += own
+        rt._phase_n[self.name] += 1
+        if self.name in _API_PHASES:
+            # itemised, in ms, for the next window record's outside_ms
+            out = rt._rl_outside
+            out[self.name] = out.get(self.name, 0.0) + own * 1e3
         if stack:
             stack[-1].child_s += dt
         if self.note is not None:
             self.note.__exit__(*exc)
         return False
+
+
+def _api_phase(name: str, meta=None):
+    """Make a public Runtime method the phase `name` (API_PHASES): the
+    span `pony:<name>` while a profiler session records, its self
+    seconds and calls in run_loop_stats()["phase_s"] / ["phase_n"]
+    always. `meta(self, *args, **kw)` gives the span's meta (count=,
+    blobs=, words=) and is evaluated only while a session records."""
+    def decorate(fn):
+        @functools.wraps(fn)
+        def call(self, *args, **kw):
+            m = meta(self, *args, **kw) \
+                if meta is not None and TraceAnnotation.is_enabled() else {}
+            with _PhaseSpan(self, name, m):
+                return fn(self, *args, **kw)
+        return call
+    return decorate
 
 
 class SpillOverflowError(RuntimeError):
@@ -325,7 +361,16 @@ class Runtime:
         self._rl_retired_seq = 0      # ... of the newest retired one
         self._rl_wall_ns = 0          # sum of the windows' wall_ms
         self._phase_s = dict.fromkeys(RUN_PHASES, 0.0)
+        self._phase_n = dict.fromkeys(RUN_PHASES, 0)
         self._phase_stack: List[_PhaseSpan] = []
+        self._rl_outside: Dict[str, float] = {}   # ms by API phase
+        #   since the newest window record's retire: the next sync-point
+        #   window's outside_ms (the itemisation of its since_prev_ms)
+        self._cold_window = True      # _multi_g has not run since
+        #   start(): its next launch traces, lowers and compiles or
+        #   reloads (the run loop calls no other executable)
+        self._cold_s = 0.0            # ... what those launches took,
+        self._cold_n = 0              #   and how many there were
         # Run-loop telemetry (run_loop_stats()): windows retired, how
         #   many dispatches rode behind an in-flight window, cumulative
         #   host-imposed device-idle gap, re-queued gated-out injects,
@@ -391,6 +436,7 @@ class Runtime:
         self.program.declare(atype, capacity)
         return self
 
+    @_api_phase("start")
     def start(self) -> "Runtime":
         # ≙ pony_init, split so the operational pieces (the always-on
         # flight recorder + optional stall watchdog, PROFILE.md §11)
@@ -505,6 +551,7 @@ class Runtime:
         # self._multi directly (bench.py) pay nothing here.
         self._multi_g = engine.jit_multi_step_gated(
             self.program, self.opts, self.mesh)
+        self._cold_window = True
         self._zero_aux = engine.zero_aux()
         # Inject buffers carry the trace side lanes when causal tracing
         # is on (two trailing rows: trace_id, parent_span — PROFILE §10).
@@ -529,6 +576,8 @@ class Runtime:
         return int(self.spawn_many(atype, 1, **{
             k: np.asarray([v]) for k, v in fields.items()})[0])
 
+    @_api_phase("spawn", lambda self, atype, count, **_f:
+                {"count": int(count)})
     def spawn_many(self, atype: ActorTypeMeta, count: int,
                    **fields) -> np.ndarray:
         """Allocate `count` slots of a cohort and set initial state columns.
@@ -745,6 +794,8 @@ class Runtime:
         import dataclasses as _dc
         return _dc.replace(self.state, **kw)
 
+    @_api_phase("set-fields", lambda self, atype, ids, **_f:
+                {"count": int(np.size(ids))})
     def set_fields(self, atype: ActorTypeMeta, ids, **fields):
         """Overwrite state columns for existing actors (host-side poke,
         e.g. wiring refs once ids are known). ids are global actor ids."""
@@ -916,6 +967,8 @@ class Runtime:
         else:
             self._inject_q.append((int(target), words))
 
+    @_api_phase("bulk-send", lambda self, targets, *_a, **_k:
+                {"count": int(np.size(targets))})
     def bulk_send(self, targets, behaviour_def: BehaviourDef, *arg_cols,
                   trace=None):
         """Mass-enqueue one message per (distinct) target directly into the
@@ -1344,8 +1397,9 @@ class Runtime:
         phase as a nested context (see RUN_PHASES above): stamps the
         watchdog where the phase is one of its own, is the profiler span
         `pony:<name>` carrying `meta` (window=<sequence number>,
-        ticks=<k> where known), and adds its self seconds to
-        run_loop_stats()["phase_s"]."""
+        ticks=<k> where known, cold=1 on a first launch), and adds its
+        self seconds to run_loop_stats()["phase_s"] and one call to
+        ["phase_n"]."""
         return _PhaseSpan(self, name, meta)
 
     def _fatal(self, exc):
@@ -1402,19 +1456,29 @@ class Runtime:
         from the previous retire to this dispatch's START (from then on
         the window is the device's; the call itself may run the compute
         inline on XLA:CPU's synchronous path, which must not read as
-        host-imposed idle), the quantity bench.py's host_gap_us
-        records."""
+        host-imposed idle), the quantity
+        benchmarks/layer_metrics/host_gap_pct.py reads.
+
+        The first launch since start() traces, lowers and compiles or
+        reloads the window: its span carries cold=1 and its seconds go
+        to run_loop_stats()["cold_dispatch_s"]. The span stays a LEAF
+        (see API_PHASES above)."""
         now = time.perf_counter()
         if pipelined:
             self._rl_pipelined += 1
             gap_ns = 0      # dispatched while the previous window ran
+            outside: Dict[str, float] = {}
         else:
             self._rl_synced += 1
             gap_ns = 0 if self._last_retire_t is None else \
                 max(0, int((now - self._last_retire_t) * 1e9))
+            # what the API phases took since the newest record's retire
+            outside, self._rl_outside = self._rl_outside, {}
         self._rl_seq += 1
         seq = self._rl_seq
-        with self._phase("dispatching", window=seq):
+        cold = self._cold_window
+        with _PhaseSpan(self, "dispatching", {"window": seq, "cold": 1}
+                        if cold else {"window": seq}):
             inj_t, inj_w, consumed = self._drain_inject_tracked()
             mask = self._defer_signals()
             try:
@@ -1425,6 +1489,11 @@ class Runtime:
                 epoch = self._state_epoch
             finally:
                 self._restore_signals(mask)
+        dispatch_s = time.perf_counter() - now
+        if cold:
+            self._cold_window = False
+            self._cold_s += dispatch_s
+            self._cold_n += 1
         # From here the window is the device's: the watchdog deadline
         # now covers device completion, not host dispatch latency.
         self._stamp("in-flight")
@@ -1438,7 +1507,8 @@ class Runtime:
                 pass
         return {"aux": aux, "k": kdev, "budget": int(budget),
                 "consumed": consumed, "gap_ns": gap_ns, "epoch": epoch,
-                "pipelined": pipelined, "seq": seq, "t_dispatch": now}
+                "pipelined": pipelined, "seq": seq, "t_dispatch": now,
+                "dispatch_s": dispatch_s, "outside": outside}
 
     def _retire_window(self, win: Dict[str, Any]):
         """Fetch an in-flight window's (ticks_run, aux) and fold it into
@@ -1494,7 +1564,11 @@ class Runtime:
         # dispatch, its retire) as wall; a pipelined one was dispatched
         # behind the previous window, so since_prev is 0 and its wall
         # counts from the previous retire.
+        # outside_ms itemises since_prev: the API phases (a counter()
+        # read, a cohort_state()) that ran in it. The first record has no
+        # record before it, so set-up's calls are in phase_s alone.
         tile = self._rl_tile_t
+        outside = win["outside"] if tile is not None else {}
         if win["pipelined"] and tile is not None:
             since_prev, t_from = 0.0, tile
         else:
@@ -1502,6 +1576,7 @@ class Runtime:
             since_prev = 0.0 if tile is None else max(0.0, t_from - tile)
         wall = max(0.0, now - t_from)
         self._rl_tile_t = now
+        self._rl_outside = {}
         self._rl_wall_ns += int(wall * 1e9)
         self._win_hist[min(WIN_BUCKETS - 1,
                            max(0, k.bit_length() - 1))] += 1
@@ -1521,7 +1596,9 @@ class Runtime:
                                 win["gap_ns"] / 1e3, win["pipelined"], a,
                                 wall_ms=wall * 1e3,
                                 wait_ms=min(wall, now - t_wait) * 1e3,
-                                since_prev_ms=since_prev * 1e3)
+                                since_prev_ms=since_prev * 1e3,
+                                dispatch_ms=win["dispatch_s"] * 1e3,
+                                outside_ms=outside)
         if getattr(self, "_analysis", None) is not None:
             with self._phase("analysis", window=win["seq"]):
                 self._analysis.window(a, ticks=k,
@@ -1881,14 +1958,18 @@ class Runtime:
         return self._exit_code
 
     def run_loop_stats(self) -> Dict[str, Any]:
-        """Observable run-loop telemetry (dump(), `top`, bench.py):
-        windows retired, pipelined vs sync-point dispatches, the
-        cumulative host-imposed device-idle gap, the windows' summed
-        wall clock (dispatch start to retire; a pipelined window's
-        counts from the retire before it), the seconds run() spent in
-        each phase (RUN_PHASES, self time, cumulative), re-queued
-        gated-out injections, the window-length histogram
-        (power-of-two buckets) and the controller snapshot."""
+        """Observable run-loop telemetry (dump(), `top`, the benchmark's
+        layer metrics — host_gap_pct.py reads host_gap_us_total): windows
+        retired, pipelined vs sync-point dispatches, the cumulative
+        host-imposed device-idle gap, the windows' summed wall clock
+        (dispatch start to retire; a pipelined window's counts from the
+        retire before it), the seconds spent in each phase and the
+        calls of each (RUN_PHASES: run()'s and the API calls' outside
+        it; self time, cumulative), the cold launches (the first of the
+        window's executable since start(): trace + lower + compile or
+        reload) and their seconds, re-queued gated-out injections, the
+        window-length histogram (power-of-two buckets) and the
+        controller snapshot."""
         n = max(1, self._rl_windows)
         return {
             "windows": self._rl_windows,
@@ -1898,6 +1979,9 @@ class Runtime:
             "host_gap_us_mean": self._rl_gap_ns / 1e3 / n,
             "windows_wall_s": self._rl_wall_ns / 1e9,
             "phase_s": dict(self._phase_s),
+            "phase_n": dict(self._phase_n),
+            "cold_dispatches": self._cold_n,
+            "cold_dispatch_s": self._cold_s,
             "injects_requeued": self._rl_requeued,
             "window_hist": [int(x) for x in self._win_hist],
             "controller": (self._controller.snapshot()
@@ -1934,6 +2018,7 @@ class Runtime:
         self._exit_code = int(code)
         self._exit_requested = True
 
+    @_api_phase("stop")
     def stop(self, postmortem: bool = False) -> int:
         """Tear down auxiliaries (≙ pony_stop, start.c:332-351): emit the
         analysis summary, stop the writer thread, close the bridge, and
@@ -2064,11 +2149,13 @@ class Runtime:
             arr = multihost_utils.process_allgather(arr, tiled=True)
         return np.asarray(arr)
 
+    @_api_phase("counter")
     def counter(self, name: str) -> int:
         """Sum a per-shard runtime counter (n_processed, n_delivered,
         n_rejected, n_badmsg, n_deadletter, n_mutes) over the mesh."""
         return int(self._fetch(getattr(self.state, name)).sum())
 
+    @_api_phase("read")
     def profile(self) -> Dict[str, Any]:
         """Structured per-behaviour/per-cohort telemetry report — the
         host face of the on-device profiler matrix (engine.profile_lanes;
@@ -2192,6 +2279,8 @@ class Runtime:
         self._tracer.drain(self)
         return reassemble(self._tracer.spans)
 
+    @_api_phase("read", lambda self, actor_id: {"words": len(
+        self.program.cohort_of(actor_id).atype.field_specs)})
     def state_of(self, actor_id: int) -> Dict[str, Any]:
         cohort = self.program.cohort_of(actor_id)
         if cohort.host:
@@ -2231,6 +2320,7 @@ class Runtime:
         return (shard * (bw * bsl) + pool_index(
             bsl, np.arange(bw, dtype=np.int64)[:, None], local))
 
+    @_api_phase("blob-fetch", lambda self, handle: {"blobs": 1})
     def blob_fetch(self, handle: int) -> np.ndarray:
         """Host-side read of a device blob's logical words (≙ receiving
         a message payload on the main-thread scheduler). Raises on null/
@@ -2245,6 +2335,8 @@ class Runtime:
                 if getattr(data, "is_fully_addressable", True)
                 else self._fetch(data)[at])
 
+    @_api_phase("blob-fetch", lambda self, handles:
+                {"blobs": int(np.size(handles))})
     def blob_fetch_many(self, handles) -> np.ndarray:
         """blob_fetch's bulk twin: every word of every handle's blob,
         [count, blob_words] (logical lengths are not applied), in ONE
@@ -2272,6 +2364,7 @@ class Runtime:
         return np.asarray(_fetch_blobs(data, slots.astype(np.int32),
                                        bw=bw, bsl=bsl, run=bool(run)))
 
+    @_api_phase("blob-store", lambda self, *_a, **_k: {"blobs": 1})
     def blob_store(self, words, length: Optional[int] = None,
                    near: Optional[int] = None) -> int:
         """Host-side blob allocation between steps (≙ the embedder
@@ -2328,6 +2421,8 @@ class Runtime:
         self._host_blobs.add(handle)    # GC root until sent/freed
         return handle
 
+    @_api_phase("blob-store", lambda self, count, *_a, **_k:
+                {"blobs": int(count)})
     def blob_store_many(self, count: int, words=None, *, fill=None,
                         length: Optional[int] = None,
                         near: Optional[int] = None) -> np.ndarray:
@@ -2405,14 +2500,13 @@ class Runtime:
         elif fill is None:
             fill = lambda k, w: 0           # noqa: E731 — zeroed blobs
         st = self.state
-        with self._phase("blob-store", blobs=count):
-            data, used_d, len_d, gen_d, n_alloc = jax.jit(
-                store, donate_argnums=(0, 1, 2, 3, 4))(
-                st.blob_data, st.blob_used, st.blob_len, st.blob_gen,
-                st.n_blob_alloc, slots, gens, vals, at)
-            self.state = self._replace(
-                blob_data=data, blob_used=used_d, blob_len=len_d,
-                blob_gen=gen_d, n_blob_alloc=n_alloc)
+        data, used_d, len_d, gen_d, n_alloc = jax.jit(
+            store, donate_argnums=(0, 1, 2, 3, 4))(
+            st.blob_data, st.blob_used, st.blob_len, st.blob_gen,
+            st.n_blob_alloc, slots, gens, vals, at)
+        self.state = self._replace(
+            blob_data=data, blob_used=used_d, blob_len=len_d,
+            blob_gen=gen_d, n_blob_alloc=n_alloc)
         handles = np.asarray(pack.blob_handle(slots, gens), np.int32)
         self._host_blobs.update(handles.tolist())   # roots until moved
         return handles
@@ -2478,12 +2572,17 @@ class Runtime:
         self._host_blobs.discard(int(handle))
 
     @property
+    @_api_phase("read", lambda self: {"words": int(
+        self.state.blob_used.size)})
     def blobs_in_use(self) -> int:
         """Currently allocated pool slots (leak diagnostic: orphaned
         blobs — owner died, or handle moved off-shard — persist only
         until the next rt.gc(), whose mark pass sweeps them)."""
         return int(self._fetch(self.state.blob_used).sum())
 
+    @_api_phase("read", lambda self, atype: {"words": sum(
+        int(v.size) for v in
+        self.state.type_state[atype.__name__].values())})
     def cohort_state(self, atype: ActorTypeMeta) -> Dict[str, np.ndarray]:
         """State columns in *slot order* (spawn order), whatever the shard
         layout."""

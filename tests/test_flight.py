@@ -464,6 +464,83 @@ def test_window_records_tile_the_wall_clock(tmp_path):
     assert set(rl["phase_s"]) >= {"dispatching", "wait", "host-work"}
 
 
+# a perf_counter difference and its rounding to 1e-4 ms, generously
+GRAIN_MS = 0.01
+
+
+def _two_runs_with_reads_between(tmp_path):
+    """A ring run twice; between the calls the caller polls a counter
+    and reads a cohort, as a benchmark's segment loop does."""
+    rt, ids = ring.build(8, _opts(flight_windows=256, tuning_cache="off",
+                                  quiesce_interval=16,
+                                  analysis_path=str(tmp_path / "an.csv")))
+    rt.send(int(ids[0]), ring.RingNode.token, 10_000)
+    rt.run(max_steps=120)
+    first = len(rt._flight.windows)
+    assert rt.counter("n_processed") > 0
+    rt.cohort_state(ring.RingNode)
+    rt.run(max_steps=120)
+    return rt, list(rt._flight.windows), first
+
+
+def test_window_records_itemise_the_dispatch(tmp_path):
+    """dispatch_ms is the window's `pony:dispatching`: the first stretch
+    of a sync-point window's wall_ms (a pipelined window's launch ran
+    inside the record before it), and the windows' sum is the phase's
+    seconds."""
+    rt, recs, _first = _two_runs_with_reads_between(tmp_path)
+    rl = rt.run_loop_stats()
+    rt.stop()
+    assert len(recs) >= 8 and any(w["pipelined"] for w in recs)
+    for w in recs:
+        assert w["dispatch_ms"] > 0
+        if not w["pipelined"]:
+            assert w["dispatch_ms"] <= w["wall_ms"] + GRAIN_MS
+    # the first launch compiled: it is the cold one, to the clock's grain
+    assert recs[0]["dispatch_ms"] == pytest.approx(
+        rl["cold_dispatch_s"] * 1e3, abs=GRAIN_MS)
+    assert recs[0]["dispatch_ms"] > max(w["dispatch_ms"] for w in recs[1:])
+    # dispatch_ms starts a few lines before the span does
+    assert sum(w["dispatch_ms"] for w in recs) == pytest.approx(
+        rl["phase_s"]["dispatching"] * 1e3, rel=0.05, abs=1.0)
+
+
+def test_window_records_itemise_since_prev(tmp_path):
+    """outside_ms is the itemisation of since_prev_ms: its sum lies
+    inside it, a pipelined window has none, the first record has no
+    record before it (set-up's calls are in phase_s alone), and a
+    counter() and a cohort_state() between two run() calls land in the
+    next record."""
+    rt, recs, first = _two_runs_with_reads_between(tmp_path)
+    rl = rt.run_loop_stats()
+    rt.stop()
+    for w in recs:
+        assert sum(w["outside_ms"].values()) <= w["since_prev_ms"] + GRAIN_MS
+        assert not (w["pipelined"] and w["outside_ms"])
+    assert recs[0]["outside_ms"] == {} and rl["phase_s"]["start"] > 0
+    second = recs[first]
+    assert set(second["outside_ms"]) == {"counter", "read"}
+    assert all(ms > 0 for ms in second["outside_ms"].values())
+    assert all(w["outside_ms"] == {} for i, w in enumerate(recs)
+               if i != first)
+    # what the records hold is what the phases took, in ms
+    assert second["outside_ms"]["counter"] == pytest.approx(
+        rl["phase_s"]["counter"] * 1e3, abs=GRAIN_MS)
+    assert second["outside_ms"]["read"] == pytest.approx(
+        rl["phase_s"]["read"] * 1e3, abs=GRAIN_MS)
+
+
+def test_postmortem_renders_the_dispatch_and_the_outside(tmp_path):
+    rt, _recs, _first = _two_runs_with_reads_between(tmp_path)
+    rt.stop(postmortem=True)
+    pm = flight.load_postmortem(str(tmp_path / "an.csv") + ".postmortem.json")
+    text = flight.render_postmortem(pm)
+    assert "dispatch=" in text
+    assert any(w["outside_ms"] for w in pm["windows"])
+    pm["windows"] = [w for w in pm["windows"] if w["outside_ms"]]
+    assert "outside=counter:" in flight.render_postmortem(pm)
+
+
 def test_old_postmortem_without_the_clock_still_renders(tmp_path, capsys):
     """A postmortem written before the window records had wall_ms /
     wait_ms / since_prev_ms renders as it did; a new one shows them."""
@@ -477,7 +554,8 @@ def test_old_postmortem_without_the_clock_still_renders(tmp_path, capsys):
     pm = flight.load_postmortem(path + ".postmortem.json")
     assert "wall=" in flight.render_postmortem(pm)
     for w in pm["windows"]:
-        for key in ("wall_ms", "wait_ms", "since_prev_ms"):
+        for key in ("wall_ms", "wait_ms", "since_prev_ms", "dispatch_ms",
+                    "outside_ms"):
             del w[key]
     old = str(tmp_path / "old.json")
     json.dump(pm, open(old, "w"))
@@ -511,4 +589,7 @@ def test_metrics_expose_the_phase_seconds(tmp_path):
     text = metrics.prometheus_text(metrics.snapshot(rt))
     rt.stop()
     assert 'pony_tpu_run_phase_seconds_total{phase="wait"}' in text
+    assert 'pony_tpu_run_phase_seconds_total{phase="spawn"}' in text
+    assert 'pony_tpu_run_phase_calls_total{phase="start"} 1' in text
+    assert "pony_tpu_cold_dispatch_seconds_total" in text
     assert "pony_tpu_windows_wall_seconds_total" in text

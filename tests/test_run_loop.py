@@ -531,6 +531,38 @@ def test_window_constants_ride_optimization_barrier():
 
 # ------------------------------------------- run-loop phases (ISSUE 24)
 
+class _Recording:
+    """Stands in for jax.profiler.TraceAnnotation: a profiler session
+    that keeps (kind, name, depth, meta) in `log`."""
+    depth = 0
+    log: list = []
+
+    def __init__(self, name, **meta):
+        self.name, self.meta = name, meta
+
+    @staticmethod
+    def is_enabled():
+        return True
+
+    def __enter__(self):
+        _Recording.log.append(("enter", self.name, _Recording.depth,
+                               self.meta))
+        _Recording.depth += 1
+
+    def __exit__(self, *_exc):
+        _Recording.depth -= 1
+        _Recording.log.append(("exit", self.name, _Recording.depth,
+                               self.meta))
+
+
+@pytest.fixture
+def recording(monkeypatch):
+    from ponyc_tpu.runtime import runtime as rtmod
+    _Recording.depth, _Recording.log = 0, []
+    monkeypatch.setattr(rtmod, "TraceAnnotation", _Recording)
+    return _Recording.log
+
+
 def test_run_phases_nest_carry_window_and_cover_run(monkeypatch):
     """The pony:* spans of one run() are properly nested, the window's
     spans carry its sequence number, and the per-phase seconds (self
@@ -539,30 +571,13 @@ def test_run_phases_nest_carry_window_and_cover_run(monkeypatch):
 
     from ponyc_tpu.runtime import runtime as rtmod
 
-    log = []
-
-    class Recording:
-        depth = 0
-
-        def __init__(self, name, **meta):
-            self.name, self.meta = name, meta
-
-        @staticmethod
-        def is_enabled():
-            return True
-
-        def __enter__(self):
-            log.append(("enter", self.name, Recording.depth, self.meta))
-            Recording.depth += 1
-
-        def __exit__(self, *_exc):
-            Recording.depth -= 1
-            log.append(("exit", self.name, Recording.depth, self.meta))
+    log = _Recording.log = []
+    _Recording.depth = 0
 
     rt, _ids = _ring(hops=3000, quiesce_interval=64)
     rt.run(max_steps=64)                        # compile outside the clock
     before = rt.run_loop_stats()
-    monkeypatch.setattr(rtmod, "TraceAnnotation", Recording)
+    monkeypatch.setattr(rtmod, "TraceAnnotation", _Recording)
     t0 = time.perf_counter()
     assert rt.run(max_steps=100_000) == 0
     wall = time.perf_counter() - t0
@@ -612,7 +627,9 @@ def test_run_phases_nest_carry_window_and_cover_run(monkeypatch):
 
 def test_run_phases_cost_little_with_the_profiler_off():
     """Budget: about a microsecond a span with no profiler session
-    (generous bound: CI machines are slow and shared)."""
+    (generous bound: CI machines are slow and shared), for a phase of
+    the run loop and for a public call made a phase by `_api_phase`
+    (its meta is not even evaluated without a session)."""
     import time
 
     from ponyc_tpu.runtime import runtime as rtmod
@@ -623,7 +640,174 @@ def test_run_phases_cost_little_with_the_profiler_off():
         with rt._phase("host-work", window=i):
             pass
     per_span = (time.perf_counter() - t0) / n
-    rt.stop()
     assert per_span < 20e-6, per_span
     assert not rt._phase_stack
     assert rtmod.PHASE_STAMPS["wait"] == "in-flight"   # the watchdog's word
+
+    def meta(_self, _i):
+        raise AssertionError("meta evaluated with no profiler session")
+    call = rtmod._api_phase("counter", meta)(lambda _self, i: i)
+    before = rt.run_loop_stats()
+    t0 = time.perf_counter()
+    for i in range(n):
+        call(rt, i)
+    per_call = (time.perf_counter() - t0) / n
+    after = rt.run_loop_stats()
+    rt.stop()
+    assert per_call < 20e-6, per_call
+    assert after["phase_n"]["counter"] - before["phase_n"]["counter"] == n
+    assert after["phase_s"]["counter"] > before["phase_s"]["counter"]
+    assert not rt._phase_stack
+
+
+# ------------------------- the host's side outside run() (ISSUE 35)
+
+def test_every_phase_the_program_emits_is_declared():
+    """RUN_PHASES is the vocabulary: every name handed to `_phase`,
+    `_api_phase` or a `_PhaseSpan` anywhere in the package is in it, and
+    every API phase is used."""
+    import re
+
+    from ponyc_tpu.runtime import runtime as rtmod
+    emitted = set()
+    pkg = os.path.join(ROOT, "ponyc_tpu")
+    for d, _dirs, files in os.walk(pkg):
+        for f in files:
+            if f.endswith(".py"):
+                with open(os.path.join(d, f)) as fh:
+                    emitted |= set(re.findall(
+                        r"(?:_phase|_PhaseSpan)\(\s*(?:self,\s*)?"
+                        r"\"([a-z\-]+)\"", fh.read()))
+    assert emitted, "the scan found no phase"
+    assert emitted <= set(rtmod.RUN_PHASES), emitted - set(rtmod.RUN_PHASES)
+    assert set(rtmod.API_PHASES) <= emitted
+    assert len(set(rtmod.RUN_PHASES)) == len(rtmod.RUN_PHASES)
+    # the leaves benchmarks/phase_trace.py sums by name are no API phase
+    assert not set(rtmod.API_PHASES) & {
+        "dispatching", "host-work", "outbox", "pollers", "gc",
+        "checkpoint", "analysis"}
+
+
+def _api_tour(rt_opts):
+    """Every public call that is an API phase, once each but `read`
+    (twice); returns the runtime, stopped."""
+    rt = Runtime(rt_opts)
+    rt.declare(Node, 8)
+    rt.start()
+    ids = rt.spawn_many(Node, 8)
+    rt.set_fields(Node, ids, nxt=np.roll(ids, -1))
+    rt.bulk_send(ids[:3], Node.step, np.array([5, 5, 5]))
+    handles = rt.blob_store_many(4, fill=lambda k, w: k + w)
+    assert rt.run() == 0
+    assert rt.blob_fetch_many(handles[:2]).shape == (2, 16)
+    assert rt.cohort_state(Node)["nxt"].shape == (8,)
+    assert rt.state_of(int(ids[0]))["nxt"] == int(ids[1])
+    assert rt.counter("n_processed") == 18
+    rt.stop()
+    return rt
+
+
+API_CALLS = {"start": 1, "spawn": 1, "set-fields": 1, "bulk-send": 1,
+             "blob-store": 1, "blob-fetch": 1, "read": 2, "counter": 1,
+             "stop": 1}
+
+
+def test_api_phases_are_spans_with_their_meta_under_a_session(recording):
+    """Outside run() every public call that touches the device is a
+    `pony:` span, at depth 0, with what it moved as meta."""
+    from ponyc_tpu.runtime import runtime as rtmod
+    assert set(API_CALLS) == set(rtmod.API_PHASES)
+    rt = _api_tour(_opts(blob_slots=8, blob_words=16))
+    api = [(name, meta) for kind, name, depth, meta in recording
+           if kind == "enter" and name[5:] in API_CALLS]
+    assert all(depth == 0 for kind, name, depth, _m in recording
+               if kind == "enter" and name[5:] in API_CALLS)
+    assert [name for name, _m in api] == [
+        "pony:start", "pony:spawn", "pony:set-fields", "pony:bulk-send",
+        "pony:blob-store", "pony:blob-fetch", "pony:read", "pony:read",
+        "pony:counter", "pony:stop"]
+    meta = dict((name, m) for name, m in api if name != "pony:read")
+    assert meta["pony:spawn"] == {"count": 8}
+    assert meta["pony:set-fields"] == {"count": 8}
+    assert meta["pony:bulk-send"] == {"count": 3}
+    assert meta["pony:blob-store"] == {"blobs": 4}
+    assert meta["pony:blob-fetch"] == {"blobs": 2}
+    assert meta["pony:start"] == meta["pony:counter"] == \
+        meta["pony:stop"] == {}
+    reads = [m for name, m in api if name == "pony:read"]
+    assert reads == [{"words": 16}, {"words": 2}]    # 8 x (nxt, seen); one row
+    assert rt.run_loop_stats()["phase_n"]["read"] == 2
+
+
+def test_api_phases_are_counted_with_no_session():
+    """Always on: seconds and calls in run_loop_stats(), no profiler."""
+    rt = _api_tour(_opts(blob_slots=8, blob_words=16))
+    rl = rt.run_loop_stats()
+    for phase, calls in API_CALLS.items():
+        assert rl["phase_n"][phase] == calls, phase
+        assert rl["phase_s"][phase] > 0, phase
+    assert rl["phase_n"]["dispatching"] == rl["phase_n"]["wait"] \
+        == rl["sync_dispatches"] + rl["pipelined_dispatches"]
+    assert set(rl["phase_n"]) == set(rl["phase_s"])
+
+
+def test_dispatching_is_a_leaf_and_only_the_first_launch_is_cold(recording):
+    """`pony:dispatching` has no child (benchmarks/phase_trace.py sums
+    it by name), the first launch after start() carries cold=1 and no
+    later one does — in a second run() either — and its seconds are
+    counted apart."""
+    rt, ids = _ring(hops=300, quiesce_interval=16)
+    assert rt.run() == 0
+    rt.send(int(ids[0]), Node.step, 40)
+    assert rt.run() == 0
+    rl = rt.run_loop_stats()
+    rt.stop()
+    launches = []
+    for i, (kind, name, _depth, meta) in enumerate(recording):
+        if kind == "enter" and name == "pony:dispatching":
+            assert recording[i + 1][:2] == ("exit", "pony:dispatching")
+            launches.append(meta)
+    assert len(launches) > 4
+    assert launches[0] == {"window": 1, "cold": 1}
+    assert all(set(m) == {"window"} for m in launches[1:])
+    assert rl["cold_dispatches"] == 1
+    assert 0 < rl["cold_dispatch_s"] <= rl["phase_s"]["dispatching"]
+    # a second world's first launch is cold again: the flag is the
+    # runtime's own, set by start()
+    other, _ids = _ring(hops=5)
+    assert other._cold_window
+    assert other.run() == 0
+    assert other.run_loop_stats()["cold_dispatches"] == 1
+    other.stop()
+
+
+def test_an_api_call_from_a_host_behaviour_nests_under_outbox(recording):
+    """Inside run() such a span is a child like any other: under
+    `pony:outbox`, never under `pony:dispatching`."""
+    @actor
+    class Poller:
+        HOST = True
+        seen: I32
+
+        @behaviour
+        def poll(self, st, _v: I32):
+            return {**st, "seen": self.rt.counter("n_processed")}
+
+    rt = Runtime(_opts())
+    rt.declare(Node, 4).declare(Poller, 1).start()
+    ids = rt.spawn_many(Node, 4)
+    rt.set_fields(Node, ids, nxt=np.roll(ids, -1))
+    p = rt.spawn(Poller)
+    rt.send(int(ids[0]), Node.step, 6)
+    rt.send(p, Poller.poll, 0)
+    assert rt.run() == 0
+    rt.stop()
+    stack, parents = [], []
+    for kind, name, _depth, _meta in recording:
+        if kind == "enter":
+            if name == "pony:counter":
+                parents.append(tuple(stack))
+            stack.append(name)
+        else:
+            stack.pop()
+    assert parents and all(p[-1] == "pony:outbox" for p in parents), parents
