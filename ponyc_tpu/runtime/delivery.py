@@ -25,11 +25,14 @@ within one shard of the actor world:
   4. the mailbox table is rebuilt by ARRIVAL RANK, in blocks: block k
      pulls, for every actor at once, sorted entries seg_start + r for
      the REBUILD_BLOCK ranks r = k*B .. k*B+B-1, and rank r lands in
-     ring slot (tail + r) % cap where r < accepted. Only as many blocks
-     run as the fullest mailbox of the tick needs (`max(acc)`, a
-     `lax.while_loop`): the gather — the expensive part, ~6-9 ns an
-     index on a v5e whatever it fetches — costs B*N indices a block
-     instead of cap*N a tick, of which a steady world uses one block.
+     ring slot (tail + r) % cap where r < accepted. A COHORT's tables
+     run only as many blocks as that cohort's fullest mailbox of the
+     tick needs (`max(acc)` over its rows, one `lax.while_loop` a
+     cohort; none where it received nothing): the gather — the
+     expensive part, ~6-9 ns an index on a v5e whatever it fetches —
+     costs B*rows indices a block instead of cap*N a tick, of which a
+     steady world uses one block, and a million shallow senders do not
+     pay for the one deep receiver they share a world with.
      TPU-first design notes: (a) XLA lowers large scatters to serial
      loops on TPU, so the one scatter the CPU-obvious design would use
      was the whole step's bottleneck — the gather form is fully
@@ -118,10 +121,11 @@ class DeliveryResult(NamedTuple):
     plan_key: jnp.ndarray      # [E] the key vector this plan sorts
     plan_perm: jnp.ndarray     # [E] cached stable-sort permutation
     plan_bounds: jnp.ndarray   # [n_local+1] cached segment bounds
-    rebuild_blocks: jnp.ndarray  # [] int32 rank blocks the mailbox
-    #                               rebuild ran this tick (each gathers
-    #                               rebuild_block_ranks(cap) * n_local
-    #                               slots; 0 on a tick with no message)
+    rebuild_slots: jnp.ndarray  # [] int32 mailbox slots the rebuild
+    #                               gathered this tick: over the cohorts,
+    #                               the rank blocks ITS fullest mailbox
+    #                               made it run x min(REBUILD_BLOCK, cap)
+    #                               ranks x its rows (0 with no message)
 
 
 def mute_ref_slots(trig, mute_row, refs, *, n: int, k: int):
@@ -150,11 +154,6 @@ def empty_mute_slots(n: int, k: int):
 REBUILD_BLOCK = 8
 
 
-def rebuild_block_ranks(mailbox_cap: int) -> int:
-    """Ranks (ring slots per actor) one block of the rebuild gathers."""
-    return min(REBUILD_BLOCK, mailbox_cap)
-
-
 def rebuild_tables(tables, wds, tail, acc, seg_start, *, mailbox_cap: int):
     """Write this tick's accepted messages into the ring tables.
 
@@ -164,7 +163,9 @@ def rebuild_tables(tables, wds, tail, acc, seg_start, *, mailbox_cap: int):
     on, its trace side lanes through the SAME (mask, source) pairs, so
     context and message cannot land in different slots. Arrival rank r
     of actor i (r < acc[i]) is entry seg_start[i] + r and lands in ring
-    slot (tail[i] + r) % cap. Returns (new tables, blocks run)."""
+    slot (tail[i] + r) % cap. Returns (new tables, slots gathered: blocks
+    run x ranks a block x rows, summed over the cohorts; None for a
+    ring of one block, whose count the caller knows)."""
     c = mailbox_cap
     e = wds.shape[1]
     rels = (jnp.arange(c, dtype=jnp.int32)[:, None]
@@ -186,7 +187,33 @@ def rebuild_tables(tables, wds, tail, acc, seg_start, *, mailbox_cap: int):
                                  pulled.transpose(1, 0, 2), table))
         return out, None
 
+    # A cohort's tables (its mailbox and, traced, its side lanes) share
+    # their rows, so they share a loop and its depth; no two cohorts do.
+    cohorts = {}
+    for i, (_t, s0, s1, _r0, _r1) in enumerate(tables):
+        cohorts.setdefault((s0, s1), []).append(i)
+    out = [None] * len(tables)
+    slots = jnp.int32(0)
+    for (s0, s1), members in cohorts.items():
+        tabs, blocks = _rebuild_cohort(
+            [tables[i][0] for i in members],
+            [tables[i][3:] for i in members], wds, rels[:, s0:s1],
+            acc[s0:s1], seg_start[s0:s1])
+        for i, tab in zip(members, tabs):
+            out[i] = tab
+        slots = slots + blocks * (REBUILD_BLOCK * (s1 - s0))
+    return out, slots
+
+
+def _rebuild_cohort(tabs, word_rows, wds, rels, acc, seg_start):
+    """rebuild_tables' loop for one cohort: its tables `tabs`, each
+    taking word rows `word_rows[i]` = (r0, r1) of `wds`; `rels`, `acc`
+    and `seg_start` are the cohort's rows only. Runs ceil(max(acc) /
+    REBUILD_BLOCK) blocks — none for a cohort that received nothing —
+    and returns (new tables, blocks run)."""
     b = REBUILD_BLOCK
+    e = wds.shape[1]
+    nn = acc.shape[0]
     depth = jnp.max(acc)
 
     def block(carry):
@@ -196,18 +223,16 @@ def rebuild_tables(tables, wds, tail, acc, seg_start, *, mailbox_cap: int):
         with phase_scope("delivery/rebuild"):
             ranks = k * b + jnp.arange(b, dtype=jnp.int32)        # [B]
             srcs = jnp.minimum(seg_start[None, :] + ranks[:, None],
-                               e - 1)                             # [B, n]
-            live = ranks[:, None] < acc[None, :]                  # [B, n]
+                               e - 1).reshape(b * nn)
+            live = ranks[:, None] < acc[None, :]                  # [B, nn]
             out = []
-            for tab, (_t, s0, s1, r0, r1) in zip(tabs, tables):
-                nn = s1 - s0
-                pulled = jnp.take(wds[r0:r1],
-                                  srcs[:, s0:s1].reshape(b * nn),
+            for tab, (r0, r1) in zip(tabs, word_rows):
+                pulled = jnp.take(wds[r0:r1], srcs,
                                   axis=1).reshape(r1 - r0, b, nn)
                 # Placement is a select chain, not a gather over the
                 # slot axis (module docstring, 4c).
                 for j in range(b):
-                    here = (rels[:, s0:s1] == ranks[j]) & live[j, s0:s1]
+                    here = (rels == ranks[j]) & live[j]
                     tab = jnp.where(here[:, None, :], pulled[:, j][None],
                                     tab)
                 out.append(tab)
@@ -215,8 +240,8 @@ def rebuild_tables(tables, wds, tail, acc, seg_start, *, mailbox_cap: int):
 
     blocks, out = lax.while_loop(
         lambda carry: carry[0] * b < depth, block,
-        (jnp.int32(0), tuple(t[0] for t in tables)))
-    return list(out), blocks
+        (jnp.int32(0), tuple(tabs)))
+    return out, blocks
 
 
 def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
@@ -357,10 +382,12 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
         acc = jnp.minimum(cnt_live, space)       # accepted per target
         new_tail = tail + acc
 
-        # The ring rebuild, by arrival rank and only as deep as this
-        # tick's fullest mailbox (rebuild_tables). Per COHORT, at the
-        # cohort's own word width: a narrow type's rebuild never moves
-        # the widest type's words (the HBM win of per-cohort widths).
+        # The ring rebuild, by arrival rank (rebuild_tables). Per
+        # COHORT, at the cohort's own word width and only as deep as
+        # the cohort's own fullest mailbox of this tick: a narrow
+        # type's rebuild never moves the widest type's words (the HBM
+        # win of per-cohort widths), a shallow type's never gathers the
+        # blocks a deep one asked for.
         with phase_scope("delivery/rebuild"):
             tables = [(buf[cname], s0, s1, 0, w1c)
                       for cname, s0, s1, w1c in cohort_layout]
@@ -370,8 +397,8 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
                 w1f = wds.shape[0]
                 tables += [(trace_buf[cname], s0, s1, w1f - 2, w1f)
                            for cname, s0, s1, _w1c in cohort_layout]
-            rebuilt, blocks = rebuild_tables(tables, wds, tail, acc,
-                                             seg_start, mailbox_cap=c)
+            rebuilt, slots = rebuild_tables(tables, wds, tail, acc,
+                                            seg_start, mailbox_cap=c)
             names = [cname for cname, *_ in cohort_layout]
             buf2 = dict(zip(names, rebuilt))
             tbuf2 = dict(zip(names, rebuilt[len(names):]))
@@ -448,7 +475,7 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
                 any_pressure, pressure, lambda _: _empty_spill(), operand=None)
         return (buf2, tbuf2, new_tail, spill, newly_muted, new_refs,
                 new_ovf, n_delivered, nrej, n_deadletter) + (
-                    () if one_block else (blocks,))
+                    () if one_block else (slots,))
 
     def no_msgs(_):
         spill, newly_muted, new_refs, new_ovf = _empty_spill()
@@ -461,13 +488,13 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
     # counts them in with_msgs and delivers nothing.
     any_valid = jnp.any(in_range)
     (buf_out, tbuf_out, new_tail, spill, newly_muted, new_refs, new_ovf,
-     n_delivered, nrej, n_deadletter, *blocks) = lax.cond(
+     n_delivered, nrej, n_deadletter, *slots) = lax.cond(
          any_valid, with_msgs, no_msgs, operand=None)
     # A ring of one block carries no count out of the cond (its window
-    # stays the program it was): it ran its block iff the tick had a
-    # message.
-    rebuild_blocks = (any_valid.astype(jnp.int32) if one_block
-                      else blocks[0])
+    # stays the program it was): it gathered its whole ring iff the
+    # tick had a message.
+    rebuild_slots = (any_valid.astype(jnp.int32) * (c * n) if one_block
+                     else slots[0])
 
     return DeliveryResult(
         buf=buf_out, trace_buf=tbuf_out, tail=new_tail,
@@ -479,5 +506,5 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
         n_rejected=nrej,
         n_deadletter=n_deadletter,
         plan_key=key, plan_perm=perm, plan_bounds=bounds,
-        rebuild_blocks=rebuild_blocks,
+        rebuild_slots=rebuild_slots,
     )

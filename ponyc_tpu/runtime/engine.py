@@ -44,8 +44,7 @@ from ..config import RuntimeOptions
 from ..ops import pack
 from ..ops.segment import compact_mask, counts_by_key, stable_sort_by
 from ..program import Cohort, Program
-from .delivery import (Entries, deliver, empty_mute_slots, mute_ref_slots,
-                       rebuild_block_ranks)
+from .delivery import Entries, deliver, empty_mute_slots, mute_ref_slots
 from .state import (PHASE_NAMES, QW_BUCKETS, PhaseCursor, RtState,
                     layout_sizes, phase_scope, pool_index)
 
@@ -489,10 +488,11 @@ def phase_cost_lanes(st: RtState, all_e, drain_facts, nproc_total,
       - gc_mark  += spawn/destroy bookkeeping rows touched (claimed
                     spawns + completed destroys — the slot-lifecycle
                     work the GC pass marks from);
-      - rebuild  += mailbox slots the delivery rebuild gathered: rank
-                    blocks run x ranks a block x local rows
-                    (delivery.rebuild_tables; how deep the fullest
-                    mailbox of each tick made it go).
+      - rebuild  += mailbox slots the delivery rebuild gathered: over
+                    the cohorts, rank blocks run x ranks a block x the
+                    cohort's rows (delivery.rebuild_tables; how deep
+                    each cohort's own fullest mailbox of each tick made
+                    its tables go).
 
     Work units, not wall time: wall/bytes attribution is the measured
     layer's job (costs.py)."""
@@ -1954,8 +1954,7 @@ def build_step(program: Program, opts: RuntimeOptions):
                                       drain_facts, muted2)
             phase_cost2 = phase_cost_lanes(
                 st, all_e, drain_facts, nproc_total, n_spawned,
-                n_destroyed,
-                res.rebuild_blocks * (rebuild_block_ranks(c) * nl))
+                n_destroyed, res.rebuild_slots)
         else:
             beh_runs2, beh_del2, beh_rej2 = (st.beh_runs,
                                              st.beh_delivered,

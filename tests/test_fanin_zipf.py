@@ -100,18 +100,26 @@ def test_one_aggregator_under_every_producer(delivery):
                         out=world.agg_ids[world.out])
     world._ticks = ref.Ticks(world.out, world.a, **world.protocol)
     cap = world.rt.opts.mailbox_cap
-    bursts = 0
+    bursts = producer_blocks = 0
+    seen = world.observed()
     for tick in range(1, TICKS + 1):
-        before = world.observed()["queued"][0]
+        before, sent = seen["queued"][0], seen["sent"].sum()
         assert world.rt.run(max_steps=1) == 0
         seen = world.observed()
         _same(seen, world.reference(tick), tick)
         drained = min(before, world.rt.opts.batch)
         bursts += seen["queued"][0] - (before - drained) >= cap - 16
+        # a producer that ran sent itself its next `produce`: the one
+        # block Producer's rows ever need, whatever the aggregator took
+        producer_blocks += seen["sent"].sum() > sent
         if tick % 16 == 0:
             _conserved(world)
     assert bursts >= 2
-    blocks = world.rt.profile()["phases"]["rebuild"] // (8 * world.n)
+    # the lane sums blocks x 8 ranks x the COHORT's rows (ISSUE 36)
+    slots = world.rt.profile()["phases"]["rebuild"]
+    blocks, rest = divmod(
+        slots - 8 * producer_blocks * world.p, 8 * world.a)
+    assert rest == 0 and producer_blocks <= TICKS
     assert blocks >= 6 * bursts               # 48 accepted: 6 blocks of 8
     world.rt.stop()
 

@@ -270,24 +270,38 @@ class Leaf:
 
 @pytest.mark.parametrize("shards", [1, 4], ids=["one-shard", "mesh4"])
 def test_rebuild_lane_counts_slots_gathered(shards):
-    """The `rebuild` lane (PR 25) is rank blocks run x ranks a block x
-    local rows, summed over ticks and shards: 20 leaves poke one hub in
-    one tick (acc = 20 at mailbox_cap 32: three blocks of 8 on the hub's
-    shard, none elsewhere), later 5 do (one block); ticks that deliver
-    nothing gather nothing."""
+    """The `rebuild` lane is, over the cohorts, rank blocks run x ranks a
+    block x THAT cohort's local rows, summed over ticks and shards: 20
+    leaves (poked in bulk, straight into their rings) send to one hub in
+    one tick — acc = 20 at mailbox_cap 32, three blocks of 8 over
+    Worker's rows on the hub's shard, none over Leaf's and none
+    elsewhere; later 5 leaves are poked through the delivery list (one
+    block of Leaf's rows on each shard that holds one of them) and send
+    (one block of Worker's); ticks that deliver nothing gather nothing."""
     from ponyc_tpu.runtime.delivery import REBUILD_BLOCK
     rt = Runtime(_opts(mailbox_cap=32, batch=2, analysis=1,
                        inject_slots=32, mesh_shards=shards))
     rt.declare(Worker, 1).declare(Leaf, 20).start()
     hub = rt.spawn(Worker)
     leaves = rt.spawn_many(Leaf, 20, hub=hub)
+    rows = {ch.atype.__name__: ch.local_stop - ch.local_start
+            for ch in rt.program.cohorts}
+    assert rows["Leaf"] * shards == 20
+    assert sum(rows.values()) == rt.program.n_local
     rt.bulk_send(leaves, Leaf.poke, np.ones(20, np.int32))
     assert rt.run() == 0
-    rows = rt.program.n_local
-    assert rt.profile()["phases"]["rebuild"] == 3 * REBUILD_BLOCK * rows
-    rt.bulk_send(leaves[:5], Leaf.poke, np.ones(5, np.int32))
+    hub_blocks = 3
+    assert rt.profile()["phases"]["rebuild"] == (
+        hub_blocks * REBUILD_BLOCK * rows["Worker"])
+    for leaf in leaves[:5]:
+        rt.send(int(leaf), Leaf.poke, 1)
     assert rt.run() == 0
-    assert rt.profile()["phases"]["rebuild"] == 4 * REBUILD_BLOCK * rows
+    hub_blocks += 1
+    leaf_shards = len({int(leaf) // rt.program.n_local
+                       for leaf in leaves[:5]})
+    assert leaf_shards == min(shards, 5)
+    assert rt.profile()["phases"]["rebuild"] == REBUILD_BLOCK * (
+        hub_blocks * rows["Worker"] + leaf_shards * rows["Leaf"])
     assert rt.state_of(hub)["done"] == 25
     rt.stop()
 

@@ -1,5 +1,6 @@
 """The mailbox rebuild (delivery.rebuild_tables): arrival ranks gathered
-in blocks, as deep as the fullest mailbox of the tick.
+in blocks, a cohort's tables as deep as that cohort's fullest mailbox of
+the tick.
 
 Most of tier-1 runs rings of 2-8 slots, which take the one-block form;
 these tests drive `deliver()` itself at `mailbox_cap` 16 and 64 against
@@ -16,10 +17,19 @@ from ponyc_tpu.runtime.delivery import Entries, deliver
 
 N, E = 24, 320
 LAYOUT = [("Narrow", 0, 16, 2), ("Wide", 16, 24, 4)]   # (type, s0, s1, 1+W)
+ONE = [("Wide", 0, N, 4)]                  # a world of one cohort
 W1 = 4 + 2                                 # widest payload + trace context
 
 
-def _world(cap, seed):
+def _slots(acc, layout=LAYOUT):
+    """What `rebuild_slots` must read: a cohort gathers ceil(its fullest
+    acceptance / 8) blocks of 8 ranks for each of ITS rows."""
+    b = delivery.REBUILD_BLOCK
+    return sum(-(-int(acc[s0:s1].max()) // b) * b * (s1 - s0)
+               for _n, s0, s1, _w in layout)
+
+
+def _world(cap, seed, cnt=None, layout=LAYOUT):
     """Tables with recognisable old contents, wrapped monotonic
     counters, and arrivals that make every acceptance from 0 to `cap`:
     actor 0 is empty and is sent `cap` (every block runs), actor 1 is
@@ -29,9 +39,10 @@ def _world(cap, seed):
     occ[0], occ[1], occ[2] = 0, cap - 3, 5
     head = rng.integers(10 * cap, 1000 * cap, N)
     tail = head + occ
-    cnt = rng.integers(0, 12, N)
-    cnt[0], cnt[1], cnt[2] = cap, 7, 0
-    cnt[3] = 0 if seed % 2 else 9
+    if cnt is None:
+        cnt = rng.integers(0, 12, N)
+        cnt[0], cnt[1], cnt[2] = cap, 7, 0
+        cnt[3] = 0 if seed % 2 else 9
     tgt = np.repeat(np.arange(N), cnt)
     assert tgt.size <= E - 8
     # A few dead-lettered and empty entries between the live ones.
@@ -42,9 +53,9 @@ def _world(cap, seed):
     alive[5] = False
     words = rng.integers(1, 1 << 30, (W1, E))
     buf = {name: rng.integers(-99, -1, (cap, w1c, s1 - s0))
-           for name, s0, s1, w1c in LAYOUT}
+           for name, s0, s1, w1c in layout}
     tbuf = {name: rng.integers(-99, -1, (cap, 2, s1 - s0))
-            for name, s0, s1, _w in LAYOUT}
+            for name, s0, s1, _w in layout}
     return buf, tbuf, head, tail, alive, tgt, words
 
 
@@ -65,7 +76,7 @@ def _oracle(cap, buf, tbuf, head, tail, alive, tgt, words):
     return buf, tbuf, tail, acc
 
 
-def _deliver(cap, world, *, cosort, tracing):
+def _deliver(cap, world, *, cosort, tracing, layout=LAYOUT):
     buf, tbuf, head, tail, alive, tgt, words = world
     i32 = lambda a: jnp.asarray(a, jnp.int32)
     return deliver(
@@ -73,7 +84,7 @@ def _deliver(cap, world, *, cosort, tracing):
         jnp.asarray(alive),
         Entries(i32(tgt), jnp.full((E,), -1, jnp.int32), i32(words)),
         n_local=N, mailbox_cap=cap, spill_cap=E, overload_occ=cap,
-        shard_base=jnp.int32(0), cohort_layout=LAYOUT, cosort=cosort,
+        shard_base=jnp.int32(0), cohort_layout=layout, cosort=cosort,
         trace_buf={k: i32(v) for k, v in tbuf.items()} if tracing else None)
 
 
@@ -98,12 +109,47 @@ def test_rebuild_equals_one_by_one_pushes(cap, mode, tracing):
         else:
             assert res.trace_buf == {}
         assert int(res.n_delivered) == acc.sum()
-        assert int(res.rebuild_blocks) == cap // delivery.REBUILD_BLOCK
+        # Narrow (actor 0) ran every block, Wide what its own took.
+        assert int(res.rebuild_slots) == _slots(acc)
+
+
+# Fullest arrival (Narrow, Wide): what one cohort takes in must not set
+# how deep the other's tables are gathered.
+DEPTHS = {"narrow-deep": (20, 0), "wide-deep": (1, 17),
+          "both-deep": (20, 9), "neither-deep": (3, 2)}
+
+
+@pytest.mark.parametrize("tracing", [False, True], ids=["plain", "traced"])
+@pytest.mark.parametrize("mode", ["plan", "cosort"])
+@pytest.mark.parametrize("deep", list(DEPTHS))
+def test_rebuild_depth_is_the_cohorts(deep, mode, tracing):
+    """A cohort's tables run ceil(its own fullest acceptance / 8) blocks
+    — none where it received nothing — and every table still equals the
+    one-by-one oracle: a block no longer run carried no message."""
+    cap = 64
+    cnt = np.zeros(N, int)
+    for (_name, s0, s1, _w), most in zip(LAYOUT, DEPTHS[deep]):
+        cnt[s0:s1] = np.arange(s1 - s0) % (min(most, 4) + 1)
+        cnt[s1 - 2] = most
+    buf, tbuf, head, _tail, alive, tgt, words = _world(cap, 2, cnt=cnt)
+    world = (buf, tbuf, head, head + 1, alive, tgt, words)   # room for all
+    want_buf, want_tbuf, want_tail, acc = _oracle(cap, *world)
+    assert (acc[:16].max(), acc[16:].max()) == DEPTHS[deep]
+    res = _deliver(cap, world, cosort=(mode == "cosort"), tracing=tracing)
+    np.testing.assert_array_equal(res.tail, want_tail)
+    for name in want_buf:
+        np.testing.assert_array_equal(res.buf[name], want_buf[name])
+        if tracing:
+            np.testing.assert_array_equal(res.trace_buf[name],
+                                          want_tbuf[name])
+    assert int(res.rebuild_slots) == _slots(acc)
 
 
 def test_rebuild_runs_as_many_blocks_as_the_fullest_mailbox():
     """Depth follows the input: 1 message -> 1 block, 9 to one actor ->
-    2 blocks, none -> no block, whatever the ring's capacity."""
+    2 blocks, none -> no block, whatever the ring's capacity — of the
+    cohort that took them (Narrow, 16 rows); Wide, sent nothing, runs
+    none."""
     cap = 64
     buf, tbuf, head, tail, alive, _tgt, words = _world(cap, 0)
     for sent, blocks in ((0, 0), (1, 1), (8, 1), (9, 2), (17, 3)):
@@ -112,17 +158,20 @@ def test_rebuild_runs_as_many_blocks_as_the_fullest_mailbox():
         tgt[100] = 2 if sent else -1
         res = _deliver(cap, (buf, tbuf, head, tail, alive, tgt, words),
                        cosort=False, tracing=False)
-        assert int(res.rebuild_blocks) == blocks, sent
+        assert int(res.rebuild_slots) == (
+            blocks * delivery.REBUILD_BLOCK * 16), sent
         assert int(res.n_delivered) == sent + (sent > 0)
 
 
-def _rebuild_eqns(cap):
+def _rebuild_eqns(cap, layout=LAYOUT, inherit=True):
     """Primitive names (a jitted helper's own name for `jit`) of every
     equation under pony/delivery/rebuild in `deliver`'s jaxpr,
-    sub-jaxprs included."""
-    world = _world(cap, 0)
+    sub-jaxprs included; with `inherit` off, only of those whose OWN
+    name stack holds the scope."""
+    world = _world(cap, 0, layout=layout)
     jaxpr = jax.make_jaxpr(
-        lambda: _deliver(cap, world, cosort=False, tracing=True))()
+        lambda: _deliver(cap, world, cosort=False, tracing=True,
+                         layout=layout))()
     names = []
 
     def walk(jp, inherited):
@@ -135,7 +184,7 @@ def _rebuild_eqns(cap):
                              if eqn.primitive.name == "jit"
                              else eqn.primitive.name)
             for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub, under)
+                walk(sub, under and inherit)
     walk(jaxpr.jaxpr, False)
     return names
 
@@ -149,8 +198,22 @@ def test_one_block_ring_has_no_loop_and_no_reduction():
     assert small.count("_take") == tables
     # One select a table; `_take` and the `%` of `rels` hold one each.
     assert small.count("_where") == tables + tables + 1
-    deep = _rebuild_eqns(16)
-    assert deep.count("while") == 1 and deep.count("reduce_max") == 1
-    assert deep.count("_take") == tables            # one gather a block
+    assert "while" not in _rebuild_eqns(8, ONE)
+
+
+@pytest.mark.parametrize("layout", [ONE, LAYOUT], ids=["one", "two"])
+def test_deep_ring_has_one_loop_a_cohort(layout):
+    """A ring deeper than a block: one loop and one depth (`max(acc)`
+    over the cohort's rows) a COHORT — a world of one cohort has the one
+    loop it had — and one gather a block a table, the cohort's trace
+    side lanes inside its loop."""
+    tables = 2 * len(layout)                        # buf + trace_buf
+    deep = _rebuild_eqns(16, layout)
+    assert deep.count("while") == len(layout)
+    assert deep.count("reduce_max") == len(layout)
+    assert deep.count("_take") == tables
     assert deep.count("_where") == (tables * delivery.REBUILD_BLOCK
                                     + tables + 1)
+    # Every loop's body writes the scope itself (a body is a computation
+    # of its own): its gathers are named without their loop's help.
+    assert _rebuild_eqns(16, layout, inherit=False).count("_take") == tables
