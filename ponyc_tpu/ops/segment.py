@@ -55,6 +55,25 @@ def segment_bounds(sorted_keys: jnp.ndarray, num_segments: int,
         is_stable=False)[:n + 1]
 
 
+# A list of at most 1/SCATTER_BELOW of the id space is marked by a
+# scatter (its serial updates cost less than sorting the id space).
+SCATTER_BELOW = 16
+
+
+def marks_of(ids, n: int):
+    """[n] bool: which of 0..n-1 occur in `ids` (any shape; anything
+    outside [0, n) is no id). Long lists by sort and merge, no scatter
+    (runtime/gc.py's docstring has the rates)."""
+    ids = ids.reshape(-1)
+    if ids.shape[0] * SCATTER_BELOW <= n:
+        return jnp.zeros((n,), jnp.bool_).at[
+            jnp.where(ids >= 0, ids, n)].max(True, mode="drop")
+    key = jnp.where((ids >= 0) & (ids < n), ids, n).astype(jnp.int32)
+    # the merge sorts ids and keys together: it asks no order of the keys
+    below = segment_bounds(key, n)                        # [n + 1]
+    return below[1:] > below[:-1]
+
+
 def segment_ranks(sorted_keys: jnp.ndarray) -> jnp.ndarray:
     """Given keys already sorted ascending, return each element's index
     within its run of equal keys. [3,3,5,5,5,9] → [0,1,0,1,2,0]."""

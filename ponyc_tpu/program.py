@@ -244,10 +244,15 @@ class Program:
 
         ≙ pony_create's allocation (actor.c:688) done ahead of time: each
         (spawner, target) pair owns a window of the target's compacted
-        free rows; within the window, each *runnable* actor gets
-        spawn_dispatches × sites disjoint slots (ranked by a cumsum over
-        the runnable mask at step time), so concurrent vmapped spawns can
-        never collide while idle actors reserve nothing. The static
+        free rows; within the window, each actor that can DISPATCH this
+        tick (runnable and holding a message: only a dispatch can spawn)
+        gets spawn_dispatches × sites disjoint slots (ranked by a cumsum
+        over that mask at step time, engine.py cohort_resv), so
+        concurrent vmapped spawns can never collide while idle actors —
+        a parent waiting for its children, garbage the collector has not
+        reached — reserve nothing. What the NEXT tick's windows will
+        reach to, against the free rows, is the row pressure the run
+        loop collects on (engine.StepAux.spawn). The static
         partition *between* spawner cohorts is worst-case
         (capacity × spawn_dispatches × sites) — the TPU-static price: a
         second spawner cohort can exhaust its window while the first's

@@ -33,7 +33,7 @@ jit) and meshed execution (shard_map over an 'actors' axis); per-shard
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, NamedTuple, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,7 +42,8 @@ from jax import lax
 from ..api import Context
 from ..config import RuntimeOptions
 from ..ops import pack
-from ..ops.segment import compact_mask, counts_by_key, stable_sort_by
+from ..ops.segment import (compact_mask, counts_by_key, marks_of,
+                           stable_sort_by)
 from ..program import Cohort, Program
 from .delivery import Entries, deliver, empty_mute_slots, mute_ref_slots
 from .state import (PHASE_NAMES, QW_BUCKETS, PhaseCursor, RtState,
@@ -94,6 +95,15 @@ class StepAux(NamedTuple):
     #   the quiesce window when this climbs past the window length —
     #   long windows trade host-event latency for dispatch amortisation,
     #   and this lane is the device's vote that the trade went bad.
+    spawn: dict = {}             # row pressure, a program with device
+    #   spawns only ({} elsewhere: no leaf, the window's HLO unchanged).
+    #   "room" int32 — over the spawn targets, the least (free rows -
+    #   rows the NEXT tick's reservations will ask for), read off this
+    #   tick's final state: below 0 the window ends (aux_go) and the
+    #   host collects before the next tick (runtime.py run()); "low"
+    #   int32 — the least room >= 0 any tick of the run has left: how
+    #   near the world came to a refused spawn; "spawned" int32 —
+    #   *cumulative* device spawns (the state's n_spawned, mesh-wide).
 
 
 def _ring_take(buf_rows, slot):
@@ -1259,6 +1269,18 @@ def build_step(program: Program, opts: RuntimeOptions):
         (ch.atype.__name__, ch.local_start, ch.local_stop,
          1 + ch.msg_words) for ch in program.cohorts)
 
+    def spawning(ch):
+        """Which behaviours of a spawner cohort hold a spawn site, by
+        local behaviour index (verify's probe trace, at the window's
+        trace like every other check of a behaviour's body): only a row
+        about to dispatch one of them can be refused a row
+        (StepAux.spawn)."""
+        from .. import verify
+        return _np.array([bool(verify.behaviour_effects(
+            b, ch.atype, msg_words=opts.msg_words,
+            default_max_sends=opts.max_sends).spawns)
+            for b in ch.behaviours])
+
     def local_step(st: RtState, inject_tgt, inject_words
                    ) -> Tuple[RtState, StepAux]:
         with PhaseCursor() as phase:
@@ -1471,64 +1493,83 @@ def build_step(program: Program, opts: RuntimeOptions):
         # spill) and hand each spawner cohort its statically-partitioned
         # window, reshaped to per-(actor, batch-slot, site) refs.
         free_rows: Dict[str, jnp.ndarray] = {}
-        if program.spawn_target_names and p > 1:
-            # A message parked in *another shard's* route-spill may still
-            # be addressed to a locally dead row; reclaiming that row would
-            # deliver the stale message to the newborn. Make every shard's
-            # rspill targets globally visible (one psum over the mesh) —
-            # the cross-shard twin of the dspill_pending guard below.
-            # Gated on world bit2: with every shard's route-spill empty
-            # (the steady state) the psum is skipped and zeros are exact.
+
+        def rspill_hits(rspill_tgt, any_rspill):
+            """[nl] bool: rows some shard's route-spill still addresses.
+            A message parked in *another shard's* route-spill may be
+            addressed to a locally dead row; reclaiming that row would
+            deliver the stale message to the newborn. One psum over the
+            mesh makes every shard's rspill targets globally visible —
+            the cross-shard twin of the dspill_pending guard. Gated on
+            world bit2: with every shard's route-spill empty (the
+            steady state) the psum is skipped and zeros are exact."""
+            if not (program.spawn_target_names and p > 1):
+                return jnp.zeros((nl,), jnp.bool_)
+
             def _rhit(_):
                 rhit = jnp.zeros((p * nl,), jnp.int32).at[
-                    jnp.maximum(st.rspill_tgt, 0)].max(
-                    (st.rspill_tgt >= 0).astype(jnp.int32), mode="drop")
+                    jnp.maximum(rspill_tgt, 0)].max(
+                    (rspill_tgt >= 0).astype(jnp.int32), mode="drop")
                 rhit = lax.psum(rhit, "actors")
                 return lax.dynamic_slice(rhit, (base,), (nl,)) > 0
-            rspill_hit = lax.cond(
-                world_rspill, _rhit,
+            return lax.cond(
+                any_rspill, _rhit,
                 lambda _: jnp.zeros((nl,), jnp.bool_), operand=None)
-        else:
-            rspill_hit = jnp.zeros((nl,), jnp.bool_)
+
+        def free_mask(tc, alive_, occ_, pending_, rhit_):
+            """A target cohort's claimable rows: dead, drained, nothing
+            parked for the last tenant in any spill."""
+            s0, s1 = tc.local_start, tc.local_stop
+            return (~alive_[s0:s1] & (occ_[s0:s1] == 0)
+                    & (pending_[s0:s1] == 0) & ~rhit_[s0:s1])
+
+        rspill_hit = rspill_hits(st.rspill_tgt, world_rspill)
         for tname in program.spawn_target_names:
             tc = program.by_type_name(tname)
-            s0, s1 = tc.local_start, tc.local_stop
-            free_ok = (~st.alive[s0:s1] & (occ0[s0:s1] == 0)
-                       & (dspill_pending[s0:s1] == 0)
-                       & ~rspill_hit[s0:s1])
-            perm, vfree, _ = compact_mask(free_ok, tc.local_capacity)
-            free_rows[tname] = jnp.where(vfree, s0 + perm.astype(jnp.int32),
-                                         jnp.int32(-1))
+            with phase_scope("spawn/free"):
+                perm, vfree, _ = compact_mask(
+                    free_mask(tc, st.alive, occ0, dspill_pending,
+                              rspill_hit), tc.local_capacity)
+                free_rows[tname] = jnp.where(
+                    vfree, tc.local_start + perm.astype(jnp.int32),
+                    jnp.int32(-1))
 
         # --- 2. drain + dispatch per cohort (≙ actor run loop).
         runnable = st.alive & ~muted
 
         def cohort_resv(ch):
-            """Per-actor spawn reservations: runnable actors get disjoint
-            spawn_dispatches × sites windows into the target's free rows,
-            ranked by a cumsum over the runnable mask (idle actors
-            reserve nothing — see Program._resolve_spawns)."""
+            """Per-actor spawn reservations: the rows that can DISPATCH
+            this tick (runnable and holding a message — only a dispatch
+            can spawn) get disjoint spawn_dispatches × sites windows
+            into the target's free rows, ranked by a cumsum over that
+            mask. An idle row reserves nothing, whether it waits for a
+            reply or is garbage the collector has not reached yet — see
+            Program._resolve_spawns."""
             resv = {}
             if not ch.spawns:
                 return resv
-            run_c = runnable[ch.local_start:ch.local_stop]
-            rank = jnp.cumsum(run_c.astype(jnp.int32)) - 1
-            sd = ch.spawn_dispatches
-            for tname, sites in sorted(ch.spawns.items()):
-                per = sd * sites
-                off = ch.spawn_offsets[tname]
-                widx = jnp.where(run_c, rank * per, 0)
-                # Planar [sd, sites, rows]: the per-(dispatch, site)
-                # offsets are the small major axes, actor lanes minor.
-                idx = (off + widx[None, None, :]
-                       + (jnp.arange(sd, dtype=jnp.int32)
-                          * sites)[:, None, None]
-                       + jnp.arange(sites, dtype=jnp.int32)[None, :, None])
-                rows = jnp.take(free_rows[tname], idx, mode="fill",
-                                fill_value=-1)
-                refs = jnp.where((rows >= 0) & run_c[None, None, :],
-                                 base + rows, jnp.int32(-1))
-                resv[tname] = refs
+            s0, s1 = ch.local_start, ch.local_stop
+            with phase_scope("spawn/reserve"):
+                run_c = runnable[s0:s1] & (occ0[s0:s1] > 0)
+                rank = jnp.cumsum(run_c.astype(jnp.int32)) - 1
+                sd = ch.spawn_dispatches
+                for tname, sites in sorted(ch.spawns.items()):
+                    per = sd * sites
+                    off = ch.spawn_offsets[tname]
+                    widx = jnp.where(run_c, rank * per, 0)
+                    # Planar [sd, sites, rows]: the per-(dispatch, site)
+                    # offsets are the small major axes, actor lanes
+                    # minor.
+                    idx = (off + widx[None, None, :]
+                           + (jnp.arange(sd, dtype=jnp.int32)
+                              * sites)[:, None, None]
+                           + jnp.arange(sites,
+                                        dtype=jnp.int32)[None, :, None])
+                    rows = jnp.take(free_rows[tname], idx, mode="fill",
+                                    fill_value=-1)
+                    resv[tname] = jnp.where(
+                        (rows >= 0) & run_c[None, None, :],
+                        base + rows, jnp.int32(-1))
             return resv
 
         # --- 2a'. device blob pool reservations (the spawn-reservation
@@ -1656,44 +1697,52 @@ def build_step(program: Program, opts: RuntimeOptions):
         alive = st.alive
         tail0 = st.tail
         n_spawned = jnp.int32(0)
-        for tname, clist in claim_lists.items():
-            if not clist:
-                continue
-            refs = jnp.concatenate(clist)
-            any_sync = any(e is not None for e in init_lists[tname])
-            rows = jnp.where(refs >= 0, refs - base, nl)  # row nl → dropped
-            alive = alive.at[rows].set(True, mode="drop")
-            new_head = new_head.at[rows].set(0, mode="drop")
-            tail0 = tail0.at[rows].set(0, mode="drop")
-            n_spawned = n_spawned + jnp.sum((refs >= 0).astype(jnp.int32))
-            tc = program.by_type_name(tname)
-            cols = jnp.where(refs >= 0, rows - tc.local_start,
-                             tc.local_capacity)
-            if any_sync:
-                # Cohorts that never spawn_sync contribute constant-False
-                # has-masks (the lanes cost only exists when some
-                # behaviour of the program actually sync-constructs).
-                has_init = jnp.concatenate(
-                    [e[0] if e is not None
-                     else jnp.zeros((cl.shape[0],), jnp.bool_)
-                     for e, cl in zip(init_lists[tname], clist)])
-            ts = dict(new_type_state[tname])
-            for fname in ts:
-                default = pack.null_word(tc.atype.field_specs[fname])
+        with phase_scope("spawn/claim"):
+            for tname, clist in claim_lists.items():
+                if not clist:
+                    continue
+                refs = jnp.concatenate(clist)
+                any_sync = any(e is not None for e in init_lists[tname])
+                # Every claimed row is claimed once (the windows are
+                # disjoint), so "which rows were claimed" is membership:
+                # one mask (ops.segment.marks_of — a sort and a merge,
+                # where a scatter of the claim list runs one update after
+                # another), then selects over the rows.
+                claimed = marks_of(jnp.where(refs >= 0, refs - base, -1), nl)
+                alive = alive | claimed
+                new_head = jnp.where(claimed, 0, new_head)
+                tail0 = jnp.where(claimed, 0, tail0)
+                n_spawned = n_spawned + jnp.sum(
+                    (refs >= 0).astype(jnp.int32))
+                tc = program.by_type_name(tname)
+                born = claimed[tc.local_start:tc.local_stop]
+                ts = dict(new_type_state[tname])
                 if any_sync:
                     # Sync-constructed spawns (spawn_sync) land their
-                    # constructor's field values; async spawns zero and
-                    # let the constructor message initialise.
-                    vals = jnp.concatenate(
-                        [e[1][fname] if e is not None
-                         else jnp.zeros((cl.shape[0],), ts[fname].dtype)
+                    # constructor's field values, claim by claim; cohorts
+                    # that never spawn_sync contribute constant-False
+                    # has-masks (the lanes cost only exists when some
+                    # behaviour of the program actually sync-constructs).
+                    cols = jnp.where(refs >= 0, refs - base - tc.local_start,
+                                     tc.local_capacity)
+                    has_init = jnp.concatenate(
+                        [e[0] if e is not None
+                         else jnp.zeros((cl.shape[0],), jnp.bool_)
                          for e, cl in zip(init_lists[tname], clist)])
-                    val = jnp.where(has_init,
-                                    vals.astype(ts[fname].dtype), default)
-                else:
-                    val = default
-                ts[fname] = ts[fname].at[cols].set(val, mode="drop")
-            new_type_state[tname] = ts
+                for fname in ts:
+                    # async spawns zero and let the constructor message
+                    # initialise
+                    default = pack.null_word(tc.atype.field_specs[fname])
+                    ts[fname] = jnp.where(born, default, ts[fname])
+                    if any_sync:
+                        vals = jnp.concatenate(
+                            [e[1][fname] if e is not None
+                             else jnp.zeros((cl.shape[0],), ts[fname].dtype)
+                             for e, cl in zip(init_lists[tname], clist)])
+                        ts[fname] = ts[fname].at[
+                            jnp.where(has_init, cols, tc.local_capacity)
+                        ].set(vals.astype(ts[fname].dtype), mode="drop")
+                new_type_state[tname] = ts
 
         # --- 2c. causal-trace spans + context propagation (tracing on
         # only; the Python-level gate keeps the jaxpr bit-identical to
@@ -2068,6 +2117,67 @@ def build_step(program: Program, opts: RuntimeOptions):
             ndel_all = st.n_delivered[0] + res.n_delivered
             blob_fail_any = blob_fail
             blob_budget_any = blob_budget
+        # Row pressure (a program with device spawns only): what the
+        # NEXT tick's reservations will find, read off this tick's final
+        # state with the next tick's own predicates: the free rows by
+        # free_mask; the rows that will reserve, those that hold a
+        # message (muted or not: an unmute may release them first). A
+        # row can be refused only when it dispatches a behaviour that
+        # spawns, so a spawner cohort needs its window up to the LAST
+        # such row: spawn_offset + (that row's rank among the reserving
+        # rows + 1) × spawn_dispatches × sites.
+        spawn_aux = {}
+        if program.has_device_spawns:
+            with phase_scope("spawn/reserve"):
+                pending2 = lax.cond(
+                    res.spill_count > 0,
+                    lambda _: counts_by_key(
+                        jnp.minimum(jnp.maximum(res.spill.tgt, 0), nl - 1),
+                        (res.spill.tgt >= 0).astype(jnp.int32), nl),
+                    lambda _: jnp.zeros((nl,), jnp.int32), operand=None)
+                rhit2 = rspill_hits(new_rspill.tgt, any_rspill_all)
+                n_free = {
+                    t: jnp.sum(free_mask(
+                        program.by_type_name(t), alive, occ_after, pending2,
+                        rhit2).astype(jnp.int32))
+                    for t in program.spawn_target_names}
+                room = jnp.int32(2**31 - 1)
+                for ch in dev_cohorts:
+                    if not ch.spawns:
+                        continue
+                    s0, s1 = ch.local_start, ch.local_stop
+                    holds = alive[s0:s1] & (occ_after[s0:s1] > 0)
+                    may = spawning(ch)
+                    wants = holds
+                    if not may.all():
+                        # some behaviour never spawns: ask the messages
+                        # the next dispatch will take which they are
+                        gid0 = ch.behaviours[0].global_id
+                        gids = res.buf[ch.atype.__name__][:, :1, :]
+                        wants = jnp.zeros_like(holds)
+                        for k in range(ch.batch):
+                            beh = _ring_take(
+                                gids, (new_head[s0:s1] + k) % c)[0] - gid0
+                            wants = wants | (
+                                (k < occ_after[s0:s1])
+                                & (beh >= 0) & (beh < len(may))
+                                & jnp.asarray(may)[
+                                    jnp.clip(beh, 0, len(may) - 1)])
+                        wants = wants & holds
+                    last = jnp.max(jnp.where(
+                        wants, jnp.cumsum(holds.astype(jnp.int32)), 0))
+                    for tname, sites in ch.spawns.items():
+                        room = jnp.minimum(room, n_free[tname] - (
+                            ch.spawn_offsets[tname] * (last > 0)
+                            + last * ch.spawn_dispatches * sites))
+                born = st.n_spawned[0] + n_spawned
+                if p > 1:
+                    room = lax.pmin(room, "actors")
+                    born = lax.psum(born, "actors")
+            spawn_aux = {"room": room,
+                         "low": jnp.where(room >= 0, room,
+                                          jnp.int32(2**31 - 1)),
+                         "spawned": born}
         wb_new =(any_pressured_all.astype(jnp.int32)
                   | (any_muted_all.astype(jnp.int32) << 1)
                   | (any_rspill_all.astype(jnp.int32) << 2))
@@ -2142,6 +2252,7 @@ def build_step(program: Program, opts: RuntimeOptions):
             n_rejected=nrej_all, n_badmsg=nbad_all,
             n_deadletter=ndl_all, n_mutes=nmut_all,
             qw_p99=qw_p99,
+            spawn=spawn_aux,
         )
         return st2, aux
 
@@ -2154,9 +2265,14 @@ def aux_go(aux: StepAux):
     Shared by the in-window while condition and the tick-0 gate of the
     pipelined dispatch (build_multi_step_gated) so the two can never
     disagree about what "host attention" means."""
-    return (aux.device_pending & ~aux.host_pending & ~aux.exit_flag
-            & ~aux.spill_overflow & ~aux.spawn_fail
-            & ~aux.blob_fail & ~aux.blob_budget_fail)
+    go = (aux.device_pending & ~aux.host_pending & ~aux.exit_flag
+          & ~aux.spill_overflow & ~aux.spawn_fail
+          & ~aux.blob_fail & ~aux.blob_budget_fail)
+    if aux.spawn:
+        # the next tick's reservations would outrun the free rows: the
+        # host collects first (StepAux.spawn)
+        go = go & (aux.spawn["room"] >= 0)
+    return go
 
 
 def build_multi_step_gated(program: Program, opts: RuntimeOptions):
@@ -2217,6 +2333,9 @@ def build_multi_step_gated(program: Program, opts: RuntimeOptions):
             it = jnp.where(first, inject_tgt, jnp.int32(-1))
             iw = jnp.where(first, inject_words, jnp.int32(0))
             s2, aux2 = step(s, it, iw)
+            if aux2.spawn:
+                aux2 = aux2._replace(spawn={**aux2.spawn, "low": jnp.minimum(
+                    _aux.spawn["low"], aux2.spawn["low"])})
             return (s2, aux2, i + 1)
 
         stf, auxf, k = lax.while_loop(cond, body,
@@ -2266,16 +2385,21 @@ def build_multi_step(program: Program, opts: RuntimeOptions):
 
     def multi(st: RtState, inject_tgt, inject_words, limit):
         return gated(st, inject_tgt, inject_words, limit,
-                     jnp.bool_(True), zero_aux())
+                     jnp.bool_(True), zero_aux(program))
 
     return multi
 
 
-def zero_aux() -> StepAux:
+def zero_aux(program: Optional[Program] = None) -> StepAux:
     """The pre-first-tick aux template (device_pending=True so a window's
-    while condition admits tick 0; everything else zero/false)."""
+    while condition admits tick 0; everything else zero/false; for a
+    `program` with device spawns, all the room there is)."""
     i32, b = jnp.int32, jnp.bool_
+    most = i32(2**31 - 1)
     return StepAux(
+        spawn=({"room": most, "low": most, "spawned": i32(0)}
+               if program is not None and program.has_device_spawns
+               else {}),
         device_pending=b(True), host_pending=b(False),
         any_muted=b(False),
         exit_flag=b(False), exit_code=i32(0),
@@ -2310,7 +2434,7 @@ def _jit_over_mesh(fn, program: Program, opts: RuntimeOptions, mesh,
     assert mesh is not None, "sharded program needs a mesh"
     repl = P()
     state_spec = state_partition_specs(program, opts)
-    aux_spec = StepAux(*([repl] * len(StepAux._fields)))
+    aux_spec = jax.tree.map(lambda _: repl, zero_aux(program))
     if extra_in is None:
         extra_in = ("repl",) * n_extra
     in_extra = tuple(aux_spec if kind == "aux" else repl

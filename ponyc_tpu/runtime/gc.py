@@ -28,6 +28,25 @@ Edges: Ref-typed state fields of live actors, and Ref-typed arguments of
 every queued/spilled message (the behaviour signature's Ref annotations
 are the trace functions ≙ the compiler-generated gentrace.c ones).
 
+Formulation (one shard and a mesh alike): no scatter in the trace. A
+TPU runs a scatter of single words one update after another (~90 ns an
+update: a hop over a million rows was 0.1 s a Ref field, and the parent's
+mailbox planes — every ring slot of every row, again on every hop — 4
+to 12 s); a sort is its cheapest data-dependent move and a gather is
+paid per index. So "which ids occur in this list" is a sort and a merge
+(`marks_of`: ops.segment.segment_bounds counts, for every id, the
+entries below it); the Ref-field edges are sorted by target ONCE a pass
+(`sorted_edges`), and a hop is then: gather `live` by the sorted
+sources, one prefix sum, gather the sums at every target's bounds —
+target t is referenced by a live source iff the sum moves across its
+segment. A row that holds a message is a root, so a mailbox edge's
+source is live before the first hop: the Ref arguments of queued
+messages are marked once, into the initial marks, over the OCCUPIED ring
+slots only — rank k of every mailbox at a time, as deep as the fullest
+mailbox, not `mailbox_cap` planes. A list a sixteenth of the id space or
+shorter (the spills) is still scattered: the merge would sort the whole
+id space for it.
+
 Termination: each iteration extends reachability by one hop, so the loop
 runs at most graph-diameter times; `gc_max_iters` (0 = unbounded) caps
 pathological chains — if the cap is hit before fixpoint, *nothing* is
@@ -57,8 +76,28 @@ import numpy as np
 from jax import lax
 
 from ..config import RuntimeOptions
+from ..ops.segment import marks_of, segment_bounds
 from ..program import Program
-from .state import RtState, phase_scope
+from .state import PhaseCursor, RtState, phase_scope
+
+def sorted_edges(src, src_ok, tgt, n: int):
+    """The edges (row src[i] -> tgt[i]) with `src_ok`, sorted by target:
+    (`src_sorted`, the source row of each sorted edge; `below` [n + 1],
+    how many sorted edges point below each id). Edges that are not ok or
+    point outside [0, n) sort past every id and are never counted."""
+    key = jnp.where(src_ok & (tgt >= 0) & (tgt < n), tgt, n)
+    key_s, src_sorted = lax.sort((key.astype(jnp.int32), src), num_keys=1)
+    return src_sorted, segment_bounds(key_s, n)
+
+
+def hop_marks(live, src_sorted, below):
+    """[n] bool: ids some edge of `sorted_edges` reaches from a live
+    source (`src_sorted`: the source row of each sorted edge) — two
+    gathers and a prefix sum."""
+    hit = jnp.take(live, src_sorted).astype(jnp.int32)
+    upto = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(hit)])
+    at = jnp.take(upto, below)
+    return at[1:] > at[:-1]
 
 
 def build_ref_arg_mask(program: Program, msg_words: int) -> np.ndarray:
@@ -75,6 +114,20 @@ def build_ref_arg_mask(program: Program, msg_words: int) -> np.ndarray:
                 mask[gid, off] = True
             off += spec_width(spec)
     return mask
+
+
+def _carries(mask: np.ndarray, w: int, gid, among=None):
+    """Lanes whose message (behaviour id `gid`) carries in payload word
+    `w` what the static `mask` [n_gids, words] marks: a compare a marked
+    behaviour (`among`: only these ids can occur, a cohort's own), never
+    an indexed read of the mask by a million ids. None where no
+    behaviour can: the word is skipped at trace time."""
+    hits = [k for k in (range(mask.shape[0]) if among is None else among)
+            if w < mask.shape[1] and mask[k, w]]
+    out = None
+    for k in hits:
+        out = (gid == k) if out is None else out | (gid == k)
+    return out
 
 
 def _ref_fields(cohort):
@@ -110,8 +163,9 @@ def _blob_fields(cohort):
 
 
 def build_gc(program: Program, opts: RuntimeOptions):
-    """Trace the collection pass; returns local_gc(state, extra_roots)
-    → (state, (n_collected_total, converged, iters)) in per-shard
+    """Trace the collection pass; returns local_gc(state, extra_roots,
+    blob_roots) → (state, (n_collected_total, converged, iters,
+    n_blobs_swept, free_rows_before, n_spawned_so_far)) in per-shard
     coordinates (wrap like the step: jit for P=1, shard_map for P>1)."""
     assert program.frozen
     p = program.shards
@@ -121,7 +175,6 @@ def build_gc(program: Program, opts: RuntimeOptions):
     cap = opts.mailbox_cap
     ref_mask_np = build_ref_arg_mask(program, opts.msg_words)
     any_ref_args = bool(ref_mask_np.any())
-    n_gids = ref_mask_np.shape[0]
     max_iters = opts.gc_max_iters
     bsl = opts.blob_slots
     blob_mask_np = build_blob_arg_mask(program, opts.msg_words)
@@ -137,10 +190,13 @@ def build_gc(program: Program, opts: RuntimeOptions):
                                       for c in program.cohorts))
 
     def local_gc(st: RtState, extra_roots, blob_roots):
-        with phase_scope("gc_mark"):
-            return mark_and_sweep(st, extra_roots, blob_roots)
+        with phase_scope("gc_mark"), PhaseCursor() as phase:
+            return mark_and_sweep(st, extra_roots, blob_roots, phase)
 
-    def mark_and_sweep(st: RtState, extra_roots, blob_roots):
+    def mark_and_sweep(st: RtState, extra_roots, blob_roots,
+                       phase: PhaseCursor):
+        # `phase(name)` opens the scope `pony/<name>` for what is traced
+        # from there to the next call (state.PhaseCursor).
         if p > 1:
             shard = lax.axis_index("actors").astype(jnp.int32)
         else:
@@ -149,98 +205,109 @@ def build_gc(program: Program, opts: RuntimeOptions):
         occ = st.tail - st.head
         rows = jnp.arange(nl, dtype=jnp.int32)
 
-        # --- roots ---
+        from ..ops import pack as _pk
+
+        def bmark(marks, handles, ok):
+            """Mark gen-MATCHING local handles only: a stale handle
+            to a recycled slot is dead and must not keep the new
+            occupant alive (ops.pack handle encoding)."""
+            hl = _pk.blob_slot(handles) - shard * bsl
+            good = ok & (handles >= 0) & (hl >= 0) & (hl < bsl)
+            hs = jnp.where(good, hl, bsl)
+            good = good & (jnp.take(st.blob_gen, hs, mode="fill",
+                                    fill_value=-1)
+                           == _pk.blob_gen_of(handles))
+            return marks.at[jnp.where(good, hl, bsl)].max(
+                True, mode="drop")
+
+        # --- roots, and everything a root's own mail names ---
+        phase("gc_mark/roots")
         roots = (st.pinned | extra_roots | (occ > 0) | st.muted
                  | (rows >= fh))
-
-        # Initial global marks: local roots + in-flight spill traffic.
-        marks0 = jnp.zeros((ntot,), jnp.bool_).at[
-            jnp.where(roots, base + rows, ntot)].max(True, mode="drop")
+        # Initial global marks: the local roots (on one shard the
+        # marks ARE the roots) + in-flight spill traffic.
+        marks0 = roots if p == 1 else lax.dynamic_update_slice(
+            jnp.zeros((ntot,), jnp.bool_), roots, (base,))
         for tgt_arr, words_arr in (
-                (jnp.where(st.dspill_tgt >= 0, base + st.dspill_tgt, -1),
-                 st.dspill_words),                 # words planar [w1, S]
+                (jnp.where(st.dspill_tgt >= 0, base + st.dspill_tgt,
+                           -1), st.dspill_words),  # words planar [w1, S]
                 (st.rspill_tgt, st.rspill_words)):
-            marks0 = marks0.at[jnp.where(tgt_arr >= 0, tgt_arr, ntot)].max(
-                True, mode="drop")
-            if any_ref_args:
-                gid = words_arr[0]
-                g = jnp.clip(gid, 0, n_gids - 1)
-                inr = (gid >= 0) & (gid < n_gids) & (tgt_arr >= 0)
-                # Payload words only: with tracing on the spill tables
-                # carry two trailing (trace_id, parent_span) rows that
-                # are never refs.
-                for w in range(min(words_arr.shape[0] - 1,
-                                   opts.msg_words)):
-                    rm = jnp.asarray(ref_mask_np)[g, w] & inr
-                    refs = jnp.where(rm, words_arr[1 + w], -1)
-                    marks0 = marks0.at[
-                        jnp.where(refs >= 0, refs, ntot)].max(
-                        True, mode="drop")
+            named = [tgt_arr]
+            # Payload words only: with tracing on the spill tables carry
+            # two trailing (trace_id, parent_span) rows that are never
+            # refs.
+            for w in range(min(words_arr.shape[0] - 1, opts.msg_words)):
+                rm = _carries(ref_mask_np, w, words_arr[0])
+                if rm is not None:
+                    named.append(jnp.where(rm & (tgt_arr >= 0),
+                                           words_arr[1 + w], -1))
+            marks0 = marks0 | marks_of(jnp.concatenate(named), ntot)
 
-        # Pre-extract edges (targets are global ids; sources are local).
-        # State-field edges, one [local_cap] target column per Ref field.
-        field_edges = []   # (src_slice_start, src_slice_stop, targets)
+        # Mailbox edges: Ref (and Blob) arguments of queued
+        # messages. A row that holds a message is a root, so these
+        # are marked ONCE, here; and only the occupied slots are
+        # read: rank k of every mailbox at a time (ring slot
+        # (head + k) % cap where k < occupancy; engine._ring_take, a
+        # select chain over the planar [cap, w1_c, rows] table), as
+        # deep as the fullest mailbox. ONE walk serves both masks
+        # (ref args feed the actor trace, Blob args the blob sweep).
+        walk_blobs = sweep_blobs and any_blob_args
+        walked = [c for c in program.cohorts
+                  if st.buf[c.atype.__name__].shape[1] > 1] \
+            if any_ref_args or walk_blobs else []
+        mb_blobs = jnp.zeros((bsl if walk_blobs else 0,), jnp.bool_)
+        if walked:
+            from .engine import _ring_take
+            def rank_k(carry):
+                k, marks, bmarks = carry
+                refs = []
+                for cohort in walked:
+                    cbuf = st.buf[cohort.atype.__name__]
+                    s0, s1 = cohort.local_start, cohort.local_stop
+                    msg = _ring_take(cbuf, (st.head[s0:s1] + k) % cap)
+                    held = k < occ[s0:s1]
+                    own = [b.global_id for b in cohort.behaviours]
+                    for w in range(cbuf.shape[1] - 1):
+                        rm = _carries(ref_mask_np, w, msg[0], own)
+                        if rm is not None:
+                            refs.append(jnp.where(rm & held, msg[1 + w], -1))
+                        bm = _carries(blob_mask_np, w, msg[0], own) \
+                            if walk_blobs else None
+                        if bm is not None:
+                            bmarks = bmark(bmarks, msg[1 + w], bm & held)
+                if refs:
+                    marks = marks | marks_of(jnp.concatenate(refs),
+                                             ntot)
+                return k + 1, marks, bmarks
+
+            deepest = jnp.max(occ)
+            _, marks0, mb_blobs = lax.while_loop(
+                lambda c: c[0] < deepest, rank_k,
+                (jnp.int32(0), marks0, mb_blobs))
+
+        # State-field edges (targets are global ids; sources local
+        # rows), every Ref field of every device cohort in one list,
+        # sorted by target once.
+        srcs, oks, tgts = [], [], []
         for cohort in program.device_cohorts:
+            s0, s1 = cohort.local_start, cohort.local_stop
             for fname in _ref_fields(cohort):
                 col = st.type_state[cohort.atype.__name__][fname]
-                field_edges.append((cohort.local_start, cohort.local_stop,
-                                    col.astype(jnp.int32)))
-        # Mailbox edges: ref args of queued messages. Planar over each
-        # cohort's [cap, w1_c, rows] table (per-cohort widths): ring slot
-        # ci holds a live message iff (ci - head) mod cap < occupancy;
-        # each payload word that the static ref mask marks contributes a
-        # [rows_c]-wide plane padded into an [nl] lane (targets are -1
-        # outside the cohort's rows).
-        # ONE walk serves both masks (ref args feed the actor trace,
-        # Blob args feed the blob sweep) — the ring-validity and gid
-        # computations are shared per (cohort, slot).
-        mb_planes = []                                    # [nl] each
-        mbb_planes = []                                   # blob handles
-        if any_ref_args or (sweep_blobs and any_blob_args):
-            rmask = jnp.asarray(ref_mask_np)
-            bmask = jnp.asarray(blob_mask_np)
-            for cohort in program.cohorts:
-                cbuf = st.buf[cohort.atype.__name__]
-                s0, s1 = cohort.local_start, cohort.local_stop
-                if cbuf.shape[1] <= 1:
-                    continue                   # gid-only mailboxes: no refs
-                for ci in range(cap):
-                    valid = ((ci - st.head[s0:s1]) % cap) < occ[s0:s1]
-                    gid = cbuf[ci, 0]
-                    g = jnp.clip(gid, 0, n_gids - 1)
-                    inr = valid & (gid >= 0) & (gid < n_gids)
-                    for w in range(cbuf.shape[1] - 1):
-                        if any_ref_args:
-                            rm = rmask[g, w] & inr
-                            plane = jnp.full((nl,), -1, jnp.int32).at[
-                                s0 + jnp.arange(s1 - s0)].set(
-                                jnp.where(rm, cbuf[ci, 1 + w], -1))
-                            mb_planes.append(plane)
-                        if sweep_blobs and any_blob_args:
-                            bmm = bmask[g, w] & inr
-                            mbb_planes.append(
-                                jnp.where(bmm, cbuf[ci, 1 + w], -1))
-        mb_tgt = jnp.stack(mb_planes) if mb_planes else None
+                srcs.append(jnp.arange(s0, s1, dtype=jnp.int32))
+                oks.append(st.alive[s0:s1])
+                tgts.append(col.astype(jnp.int32))
+        if srcs:
+            src_sorted, below = sorted_edges(
+                jnp.concatenate(srcs), jnp.concatenate(oks),
+                jnp.concatenate(tgts), ntot)
 
-        def propagate(live):
-            """One hop: mark every target referenced by a live source."""
-            marks = jnp.zeros((ntot,), jnp.bool_).at[
-                jnp.where(live, base + rows, ntot)].max(True, mode="drop")
-            for s0, s1, tgt in field_edges:
-                src_ok = live[s0:s1] & st.alive[s0:s1] & (tgt >= 0)
-                marks = marks.at[jnp.where(src_ok, tgt, ntot)].max(
-                    True, mode="drop")
-            if mb_tgt is not None:
-                src_ok = live[None, :] & (mb_tgt >= 0)
-                marks = marks.at[
-                    jnp.where(src_ok, mb_tgt, ntot).reshape(-1)].max(
-                    True, mode="drop")
-            return marks
+        phase("gc_mark/hop")
 
         def glob(marks):
             if p > 1:
                 marks = lax.psum(marks.astype(jnp.int32), "actors") > 0
-            return lax.dynamic_slice(marks, (base,), (nl,))
+                return lax.dynamic_slice(marks, (base,), (nl,))
+            return marks
 
         live0 = glob(marks0)
 
@@ -252,18 +319,25 @@ def build_gc(program: Program, opts: RuntimeOptions):
             return going
 
         def body(carry):
+            """One hop: mark every target a live source's field names."""
             live, _, it = carry
-            new_live = live | glob(propagate(live))
-            ch = jnp.any(new_live != live)
-            if p > 1:
-                ch = lax.psum(ch.astype(jnp.int32), "actors") > 0
+            with phase_scope("gc_mark/hop"):
+                new_live = live
+                if srcs:
+                    new_live = live | glob(
+                        hop_marks(live, src_sorted, below))
+                ch = jnp.any(new_live != live)
+                if p > 1:
+                    ch = lax.psum(ch.astype(jnp.int32), "actors") > 0
             return new_live, ch, it + 1
 
         live, changed, iters = lax.while_loop(
             cond, body, (live0, jnp.bool_(True), jnp.int32(0)))
         converged = ~changed
 
+        phase("gc_mark/sweep")
         # --- collect (only on a converged trace; ≙ cycle.c `collect`) ---
+        free_before = jnp.sum((~st.alive & (rows < fh)).astype(jnp.int32))
         dead = st.alive & ~live & (rows < fh) & converged
         n_dead = jnp.sum(dead.astype(jnp.int32))
 
@@ -280,23 +354,7 @@ def build_gc(program: Program, opts: RuntimeOptions):
         blob_used2, blob_len2 = st.blob_used, st.blob_len
         nbf2 = st.n_blob_free
         if sweep_blobs:
-            bbase = shard * bsl
             alive2 = st.alive & ~dead
-
-            from ..ops import pack as _pk
-
-            def bmark(marks, handles, ok):
-                """Mark gen-MATCHING local handles only: a stale handle
-                to a recycled slot is dead and must not keep the new
-                occupant alive (ops.pack handle encoding)."""
-                hl = _pk.blob_slot(handles) - bbase
-                good = ok & (handles >= 0) & (hl >= 0) & (hl < bsl)
-                hs = jnp.where(good, hl, bsl)
-                good = good & (jnp.take(st.blob_gen, hs, mode="fill",
-                                        fill_value=-1)
-                               == _pk.blob_gen_of(handles))
-                return marks.at[jnp.where(good, hl, bsl)].max(
-                    True, mode="drop")
 
             bm = blob_roots
             for cohort in program.device_cohorts:
@@ -305,21 +363,18 @@ def build_gc(program: Program, opts: RuntimeOptions):
                     col = st.type_state[cohort.atype.__name__][fname]
                     bm = bmark(bm, col.astype(jnp.int32), alive2[s0:s1])
             if any_blob_args:
-                bmask2 = jnp.asarray(blob_mask_np)
                 for tgt_arr, words_arr in (
                         (st.dspill_tgt, st.dspill_words),
                         (st.rspill_tgt, st.rspill_words)):
-                    gid = words_arr[0]
-                    g = jnp.clip(gid, 0, n_gids - 1)
-                    inr = (gid >= 0) & (gid < n_gids) & (tgt_arr >= 0)
                     for w in range(min(words_arr.shape[0] - 1,
                                        opts.msg_words)):
-                        bm = bmark(bm, words_arr[1 + w],
-                                   bmask2[g, w] & inr)
-                # Queued-message handles: planes collected by the shared
-                # mailbox walk above (-1 where not a valid Blob arg).
-                for bplane in mbb_planes:
-                    bm = bmark(bm, bplane, bplane >= 0)
+                        carried = _carries(blob_mask_np, w, words_arr[0])
+                        if carried is not None:
+                            bm = bmark(bm, words_arr[1 + w],
+                                       carried & (tgt_arr >= 0))
+                # Queued-message handles: marked by the shared mailbox
+                # walk above.
+                bm = bm | mb_blobs
             swept = st.blob_used & ~bm
             n_swept = jnp.sum(swept.astype(jnp.int32))
             blob_used2 = st.blob_used & bm
@@ -391,10 +446,12 @@ def build_gc(program: Program, opts: RuntimeOptions):
             n_blob_moved=st.n_blob_moved,
             type_state=st.type_state,
         )
+        born = st.n_spawned[0]
         if p > 1:
-            n_dead = lax.psum(n_dead, "actors")
-            n_swept = lax.psum(n_swept, "actors")
-        return st2, (n_dead, converged, iters, n_swept)
+            n_dead, n_swept, free_before, born = lax.psum(
+                (n_dead, n_swept, free_before, born), "actors")
+        return st2, (n_dead, converged, iters, n_swept, free_before,
+                     born)
 
     return local_gc
 
@@ -412,6 +469,6 @@ def jit_gc(program: Program, opts: RuntimeOptions, mesh=None):
     mapped = jax.shard_map(           # check_vma: see engine._jit_over_mesh
         gc, mesh=mesh,
         in_specs=(state_spec, sharded, sharded),
-        out_specs=(state_spec, (repl, repl, repl, repl)),
+        out_specs=(state_spec, (repl,) * 6),
         check_vma=False)
     return jax.jit(mapped, donate_argnums=(0,))

@@ -333,6 +333,14 @@ class Runtime:
         self._ever_released = False
         self._last_gc_step = 0
         self._next_gc = self.opts.gc_initial   # ≙ heap.c next_gc
+        # Row pressure (programs with device spawns; StepAux.spawn): the
+        # least room any tick has left, and the step of the last pass it
+        # asked for (one pass an aux: a gated-out window hands the same
+        # aux back).
+        self._free_rows_low = 2**31 - 1
+        self._row_gc_step = -1
+        self._spawned_at_gc = 0     # device spawns when the last pass ran
+        self._row_bytes = None      # see _spawned_bytes
         self._host_errors: Dict[int, int] = {}
         self._host_error_locs: Dict[int, str] = {}
         self._tracer = None      # tracing.Tracer, set by start() when
@@ -552,7 +560,7 @@ class Runtime:
         self._multi_g = engine.jit_multi_step_gated(
             self.program, self.opts, self.mesh)
         self._cold_window = True
-        self._zero_aux = engine.zero_aux()
+        self._zero_aux = engine.zero_aux(self.program)
         # Inject buffers carry the trace side lanes when causal tracing
         # is on (two trailing rows: trace_id, parent_span — PROFILE §10).
         w1 = 1 + self.opts.msg_words + self.opts.trace_lanes
@@ -765,21 +773,31 @@ class Runtime:
                     slot = pack.blob_slot(v)
                     if v >= 0 and 0 <= slot < n_blob_total:
                         blob_roots[slot] = True
-        before = self.counter("n_collected")
-        self.state, (n, converged, iters, n_swept) = self._gc_fn(
+        self.state, facts = self._gc_fn(
             self.state, jnp.asarray(extra), jnp.asarray(blob_roots))
+        # the pass's answer, waited for here: its seconds are this
+        # phase's (`gc`), not the next counter read's
+        n, converged, iters, n_swept, free_before, born = map(
+            int, jax.device_get(facts))
         self.totals["gc_runs"] += 1
+        self._spawned_at_gc = born & 0xFFFFFFFF
+        if TraceAnnotation.is_enabled():
+            # the pass's outcome, known only now: a zero-length child
+            # that closes the `pony:gc` span it ran under
+            with TraceAnnotation("pony:gc", collected=n, hops=iters,
+                                 free_before=free_before):
+                pass
         # GC window stats for the profiler (analysis.window / profile()):
         # passes run, trace iterations, blob slots reclaimed; actors
         # collected ride the device n_collected counter.
-        self.totals["gc_iters"] += int(iters)
-        self.totals["gc_swept_blobs"] += int(n_swept)
-        if not bool(converged):
+        self.totals["gc_iters"] += iters
+        self.totals["gc_swept_blobs"] += n_swept
+        if not converged:
             self.totals["gc_aborted"] += 1
         if self._flight is not None:
-            self._flight.event("gc", collected=int(n), iters=int(iters),
-                               swept=int(n_swept),
-                               converged=bool(converged))
+            self._flight.event("gc", collected=n, iters=iters,
+                               swept=n_swept, converged=bool(converged),
+                               free_before=free_before)
         # Growth-triggered accounting reset (≙ heap.c's next_gc update
         # after a collection) — here so every collection path, manual
         # included, clears the allocation-pressure signal consistently.
@@ -788,7 +806,7 @@ class Runtime:
             heap.bytes_since_gc = 0
             self._next_gc = max(self.opts.gc_initial,
                                 int(heap.bytes_live * self.opts.gc_factor))
-        return self.counter("n_collected") - before
+        return n
 
     def _replace(self, **kw) -> RtState:
         import dataclasses as _dc
@@ -1523,6 +1541,9 @@ class Runtime:
         now = self._last_retire_t = time.perf_counter()
         self._rl_retired_seq = seq
         k = int(k)
+        if a.spawn:
+            self._free_rows_low = min(self._free_rows_low,
+                                      int(a.spawn["low"]))
         # The fetch returned: the device answered, the host boundary
         # work for this window starts now (watchdog phase evidence).
         with self._phase("host-work", window=seq, ticks=k):
@@ -1606,6 +1627,19 @@ class Runtime:
         if self._metrics is not None:
             self._metrics.maybe_update(self)
 
+    def _spawned_bytes(self, a) -> int:
+        """Bytes of actor memory (mailbox ring + fields, the leanest
+        spawn target's) the device has spawned since the last pass: what
+        a pass could at most find to free."""
+        if self._row_bytes is None:
+            self._row_bytes = min(
+                4 * (self.opts.mailbox_cap * (1 + tc.msg_words)
+                     + len(tc.atype.field_specs))
+                for tc in map(self.program.by_type_name,
+                              self.program.spawn_target_names))
+        born = (int(a.spawn["spawned"]) - self._spawned_at_gc) & 0xFFFFFFFF
+        return born * self._row_bytes
+
     def _fatal_checks(self, a) -> None:
         if bool(a.spill_overflow):
             raise self._fatal(SpillOverflowError(
@@ -1636,7 +1670,8 @@ class Runtime:
         return (bool(a.device_pending) and not bool(a.host_pending)
                 and not bool(a.exit_flag) and not bool(a.spill_overflow)
                 and not bool(a.spawn_fail) and not bool(a.blob_fail)
-                and not bool(a.blob_budget_fail))
+                and not bool(a.blob_budget_fail)
+                and not (a.spawn and int(a.spawn["room"]) < 0))
 
     def run(self, max_steps: Optional[int] = None) -> int:
         if self.state is None:
@@ -1773,6 +1808,22 @@ class Runtime:
                     heap = getattr(self, "_heap", None)
                     heap_pressure = (heap is not None and
                                      heap.bytes_since_gc > self._next_gc)
+                    # Rows are this runtime's actor memory, and a world
+                    # that creates actors outgrows them long before the
+                    # cadence: the device ended the window because the
+                    # NEXT tick's spawn reservations would outrun the
+                    # free rows (StepAux.spawn "room" < 0, engine.aux_go)
+                    # — collect now, before that tick is launched (≙
+                    # next_gc, in rows; derived, no option: as a heap
+                    # is not collected before gc_initial bytes have been
+                    # allocated, rows are not before the actors spawned
+                    # since the last pass hold that much). If the pass
+                    # frees too little, the tick's spawn is refused and
+                    # SpawnCapacityError says so.
+                    row_pressure = bool(
+                        a.spawn and int(a.spawn["room"]) < 0
+                        and self.steps_run > self._row_gc_step
+                        and self._spawned_bytes(a) >= self.opts.gc_initial)
                     # Cadence counts device steps + skipped host-only
                     # boundaries (steps_run freezes while boundaries are
                     # skipped; host-heavy phases must still collect
@@ -1781,11 +1832,12 @@ class Runtime:
                     if (not self.opts.noblock
                             and (self._ever_released
                                  or self.program.has_device_spawns)
-                            and (heap_pressure
+                            and (heap_pressure or row_pressure
                                  or (self.opts.cd_interval > 0
                                      and eff_step - self._last_gc_step
                                      >= self.opts.cd_interval))):
                         self._last_gc_step = eff_step
+                        self._row_gc_step = self.steps_run
                         with self._phase("gc", window=seq):
                             self.gc()
                     # Periodic crash-safe checkpoint (PROFILE.md §12):
@@ -1968,7 +2020,11 @@ class Runtime:
         it; self time, cumulative), the cold launches (the first of the
         window's executable since start(): trace + lower + compile or
         reload) and their seconds, re-queued gated-out injections, the
-        window-length histogram (power-of-two buckets) and the
+        collector's passes and trace hops (`gc_runs`, `gc_iters`; the
+        actors are the device's `n_spawned` / `n_collected`, counter()),
+        `free_rows_low` (a program with device spawns: the least free
+        rows, net of the next tick's reservations, any tick has left;
+        None before the first window), the window-length histogram (power-of-two buckets) and the
         controller snapshot."""
         n = max(1, self._rl_windows)
         return {
@@ -1983,6 +2039,10 @@ class Runtime:
             "cold_dispatches": self._cold_n,
             "cold_dispatch_s": self._cold_s,
             "injects_requeued": self._rl_requeued,
+            "gc_runs": self.totals["gc_runs"],
+            "gc_iters": self.totals["gc_iters"],
+            "free_rows_low": (self._free_rows_low
+                              if self._free_rows_low < 2**31 - 1 else None),
             "window_hist": [int(x) for x in self._win_hist],
             "controller": (self._controller.snapshot()
                            if self._controller is not None else None),

@@ -88,7 +88,10 @@ STEP_SCOPES = ("unmute", "spawn", "drain", "dispatch", "dispatch/heap",
                "delivery", "delivery/plan", "delivery/plan/bounds",
                "delivery/permute", "delivery/rebuild", "delivery/pressure",
                "delivery/pressure/spill", "delivery/pressure/mute",
-               "gc_mark", "mute", "vote")
+               "gc_mark", "mute", "vote",
+               # a program with device spawns; the collector's own program
+               "spawn/free", "spawn/reserve", "spawn/claim",
+               "gc_mark/roots", "gc_mark/hop", "gc_mark/sweep")
 _named_scope = jax.named_scope      # the one seam the tests stub
 
 
