@@ -23,16 +23,29 @@ within one shard of the actor world:
      accepts nothing and its segment is the tick's dead letters; "does
      anything target a pressured actor" is asked of the counts;
   4. the mailbox table is rebuilt by ARRIVAL RANK, in blocks: block k
-     pulls, for every actor at once, sorted entries seg_start + r for
-     the REBUILD_BLOCK ranks r = k*B .. k*B+B-1, and rank r lands in
-     ring slot (tail + r) % cap where r < accepted. A COHORT's tables
-     run only as many blocks as that cohort's fullest mailbox of the
-     tick needs (`max(acc)` over its rows, one `lax.while_loop` a
-     cohort; none where it received nothing): the gather — the
-     expensive part, ~6-9 ns an index on a v5e whatever it fetches —
-     costs B*rows indices a block instead of cap*N a tick, of which a
-     steady world uses one block, and a million shallow senders do not
-     pay for the one deep receiver they share a world with.
+     pulls sorted entries seg_start + r for the REBUILD_BLOCK ranks
+     r = k*B .. k*B+B-1, and rank r lands in ring slot (tail + r) % cap
+     where r < accepted. A COHORT's tables run only as many blocks as
+     that cohort's fullest mailbox of the tick needs (`max(acc)` over
+     its rows; none where it received nothing), and a block pulls only
+     for the rows that have a message in it, those with acc > k*B.
+     While more than M = ceil(rows / B) of the cohort's rows are that
+     deep the block runs FULL WIDTH: one gather of B*rows indices, every
+     row's B ranks. From the first block whose rows fit in M on — their
+     count only falls with k, so a cohort is two `lax.while_loop`s in
+     turn and no `cond` — a block runs COMPACTED: the deep rows'
+     indices ascending, each with its segment's start (one sort of the
+     rows), B*M indices into the list, then one scatter of the M
+     windows back to their rows' lanes, sorted and unique and told so:
+     B*M = rows indices for B*rows. The gather is the expensive part —
+     ~6 ns an index on a v5e whatever it fetches; a scatter told sorted
+     and unique ~40 ns a window, untold one update after another (note
+     a) — so a steady world pays B*rows a tick instead of cap*N, a
+     million shallow senders do not pay for the one deep receiver they
+     share a world with, the few rows past rank 8 of a Poisson tail do
+     not make every row gather another block, and a world few of whose
+     rows receive anything compacts its first block too (k = 0 is no
+     special case).
      TPU-first design notes: (a) XLA lowers large scatters to serial
      loops on TPU, so the one scatter the CPU-obvious design would use
      was the whole step's bottleneck — the gather form is fully
@@ -80,6 +93,7 @@ within one shard of the actor world:
 
 from __future__ import annotations
 
+from itertools import accumulate
 from typing import NamedTuple
 
 import jax.numpy as jnp
@@ -121,11 +135,14 @@ class DeliveryResult(NamedTuple):
     plan_key: jnp.ndarray      # [E] the key vector this plan sorts
     plan_perm: jnp.ndarray     # [E] cached stable-sort permutation
     plan_bounds: jnp.ndarray   # [n_local+1] cached segment bounds
-    rebuild_slots: jnp.ndarray  # [] int32 mailbox slots the rebuild
-    #                               gathered this tick: over the cohorts,
+    rebuild_slots: jnp.ndarray  # [] int32 indices the rebuild's gathers
+    #                               read this tick: over the cohorts and
     #                               the rank blocks ITS fullest mailbox
-    #                               made it run x min(REBUILD_BLOCK, cap)
-    #                               ranks x its rows (0 with no message)
+    #                               made it run, B ranks x its rows a
+    #                               full-width block, B ranks x M a
+    #                               compacted one (rebuild_tables); cap x
+    #                               N for a ring of one block; 0 with no
+    #                               message
 
 
 def mute_ref_slots(trig, mute_row, refs, *, n: int, k: int):
@@ -163,9 +180,10 @@ def rebuild_tables(tables, wds, tail, acc, seg_start, *, mailbox_cap: int):
     on, its trace side lanes through the SAME (mask, source) pairs, so
     context and message cannot land in different slots. Arrival rank r
     of actor i (r < acc[i]) is entry seg_start[i] + r and lands in ring
-    slot (tail[i] + r) % cap. Returns (new tables, slots gathered: blocks
-    run x ranks a block x rows, summed over the cohorts; None for a
-    ring of one block, whose count the caller knows)."""
+    slot (tail[i] + r) % cap. Returns (new tables, indices gathered: B
+    ranks x rows a full-width block, B ranks x M a compacted one,
+    summed over the blocks each cohort ran; None for a ring of one
+    block, whose count the caller knows)."""
     c = mailbox_cap
     e = wds.shape[1]
     rels = (jnp.arange(c, dtype=jnp.int32)[:, None]
@@ -195,53 +213,99 @@ def rebuild_tables(tables, wds, tail, acc, seg_start, *, mailbox_cap: int):
     out = [None] * len(tables)
     slots = jnp.int32(0)
     for (s0, s1), members in cohorts.items():
-        tabs, blocks = _rebuild_cohort(
+        tabs, gathered = _rebuild_cohort(
             [tables[i][0] for i in members],
             [tables[i][3:] for i in members], wds, rels[:, s0:s1],
             acc[s0:s1], seg_start[s0:s1])
         for i, tab in zip(members, tabs):
             out[i] = tab
-        slots = slots + blocks * (REBUILD_BLOCK * (s1 - s0))
+        slots = slots + gathered
     return out, slots
 
 
 def _rebuild_cohort(tabs, word_rows, wds, rels, acc, seg_start):
-    """rebuild_tables' loop for one cohort: its tables `tabs`, each
+    """rebuild_tables' loops for one cohort: its tables `tabs`, each
     taking word rows `word_rows[i]` = (r0, r1) of `wds`; `rels`, `acc`
     and `seg_start` are the cohort's rows only. Runs ceil(max(acc) /
-    REBUILD_BLOCK) blocks — none for a cohort that received nothing —
-    and returns (new tables, blocks run)."""
+    REBUILD_BLOCK) blocks — none for a cohort that received nothing.
+    Block k has work only for the rows with acc > k*B; their count falls
+    with k, so the blocks come as two loops in turn: full-width ones
+    while more than M = ceil(rows / B) rows are that deep, then
+    compacted ones, which pull for those rows alone. Returns (new
+    tables, indices gathered)."""
     b = REBUILD_BLOCK
     e = wds.shape[1]
     nn = acc.shape[0]
+    m = -(-nn // b)
     depth = jnp.max(acc)
 
-    def block(carry):
+    def place(k, tabs, pulled):
+        """Rank k*B + j of every row, `pulled[i]` = [words, B, nn], into
+        ring slot (tail + rank) % cap where rank < acc: a select chain,
+        not a gather over the slot axis (module docstring, 4c)."""
+        for j in range(b):
+            here = ((rels == k * b + j) & (k * b + j < acc))[:, None, :]
+            tabs = [jnp.where(here, pull[:, j][None], tab)
+                    for tab, pull in zip(tabs, pulled)]
+        return tuple(tabs)
+
+    def full(carry):
         k, tabs = carry
-        # Absolute, like every scope (state.py): the loop body is its
-        # own computation and would otherwise carry no phase.
+        # Absolute, like every scope (state.py): a loop's body and its
+        # test are computations of their own and would carry no phase.
         with phase_scope("delivery/rebuild"):
             ranks = k * b + jnp.arange(b, dtype=jnp.int32)        # [B]
             srcs = jnp.minimum(seg_start[None, :] + ranks[:, None],
                                e - 1).reshape(b * nn)
-            live = ranks[:, None] < acc[None, :]                  # [B, nn]
-            out = []
-            for tab, (r0, r1) in zip(tabs, word_rows):
-                pulled = jnp.take(wds[r0:r1], srcs,
-                                  axis=1).reshape(r1 - r0, b, nn)
-                # Placement is a select chain, not a gather over the
-                # slot axis (module docstring, 4c).
-                for j in range(b):
-                    here = (rels == ranks[j]) & live[j]
-                    tab = jnp.where(here[:, None, :], pulled[:, j][None],
-                                    tab)
-                out.append(tab)
-        return k + 1, tuple(out)
+            pulled = [jnp.take(wds[r0:r1], srcs,
+                               axis=1).reshape(r1 - r0, b, nn)
+                      for r0, r1 in word_rows]
+            return k + 1, place(k, tabs, pulled)
 
-    blocks, out = lax.while_loop(
-        lambda carry: carry[0] * b < depth, block,
-        (jnp.int32(0), tuple(tabs)))
-    return out, blocks
+    def compact(carry):
+        k, tabs = carry
+        with phase_scope("delivery/rebuild/compact"):
+            ranks = k * b + jnp.arange(b, dtype=jnp.int32)        # [B]
+            # The rows with a message in this block, at most M of them,
+            # ascending, each with its segment's start: one sort. A row
+            # that is not deep sorts behind them under a key past nn, so
+            # the keys stay unique and the scatter below drops it.
+            iota = jnp.arange(nn, dtype=jnp.int32)
+            rows, seg_deep = lax.sort(
+                (jnp.where(acc > k * b, iota, nn + iota), seg_start),
+                num_keys=1)
+            srcs = jnp.minimum(seg_deep[None, :m] + ranks[:, None],
+                               e - 1).reshape(b * m)
+            # B*M indices into the list, then the M windows of words x B
+            # back to their rows' lanes for all the cohort's tables in
+            # ONE scatter, sorted and unique and told so (XLA's TPU
+            # scatter is one update after another otherwise). A row that
+            # is not deep reads zeros and places none of them: no rank
+            # of this block is below its acc.
+            small = jnp.concatenate(
+                [jnp.take(wds[r0:r1], srcs, axis=1).reshape((r1 - r0) * b, m)
+                 for r0, r1 in word_rows])
+            wide = jnp.zeros((small.shape[0], nn), jnp.int32).at[
+                :, rows[:m]].set(small, mode="drop", indices_are_sorted=True,
+                                 unique_indices=True)
+            pulled = [part.reshape(-1, b, nn) for part in jnp.split(
+                wide, list(accumulate((r1 - r0) * b
+                                      for r0, r1 in word_rows))[:-1])]
+            return k + 1, place(k, tabs, pulled)
+
+    def more(k):
+        return k * b < depth
+
+    def wide_block(carry):
+        with phase_scope("delivery/rebuild"):
+            k = carry[0]
+            return more(k) & (jnp.sum(acc > k * b) > m)
+
+    n_full, tabs = lax.while_loop(wide_block, full,
+                                  (jnp.int32(0), tuple(tabs)))
+    blocks, tabs = lax.while_loop(lambda carry: more(carry[0]), compact,
+                                  (n_full, tabs))
+    return tabs, (n_full * nn + (blocks - n_full) * m) * b
 
 
 def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,

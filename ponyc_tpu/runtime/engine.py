@@ -498,11 +498,13 @@ def phase_cost_lanes(st: RtState, all_e, drain_facts, nproc_total,
       - gc_mark  += spawn/destroy bookkeeping rows touched (claimed
                     spawns + completed destroys — the slot-lifecycle
                     work the GC pass marks from);
-      - rebuild  += mailbox slots the delivery rebuild gathered: over
-                    the cohorts, rank blocks run x ranks a block x the
-                    cohort's rows (delivery.rebuild_tables; how deep
-                    each cohort's own fullest mailbox of each tick made
-                    its tables go).
+      - rebuild  += indices the delivery rebuild's gathers read: over
+                    the cohorts and the rank blocks each ran (as deep as
+                    its own fullest mailbox of the tick), 8 ranks x the
+                    cohort's rows a full-width block; 8 ranks x M a
+                    compacted one, M = ceil(rows / 8), which a block is
+                    from the first whose rows with a message in it fit
+                    in M (delivery.rebuild_tables).
 
     Work units, not wall time: wall/bytes attribution is the measured
     layer's job (costs.py)."""

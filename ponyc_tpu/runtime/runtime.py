@@ -2235,7 +2235,7 @@ class Runtime:
                                   "mute_ticks": int}},
              "phases": {"delivery": int, "drain": int, "dispatch": int,
                         "gc_mark": int,       # cumulative work units
-                        "rebuild": int},      # mailbox slots gathered
+                        "rebuild": int},      # indices the rebuild read
              "totals": {"processed", "delivered", "rejected", "badmsg",
                         "deadletter", "mutes", "host_processed"},
              "gc": {"passes", "collected", "blob_slots_reclaimed",

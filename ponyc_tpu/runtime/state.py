@@ -86,7 +86,8 @@ SCOPE_PREFIX = "pony"
 STEP_SCOPES = ("unmute", "spawn", "drain", "dispatch", "dispatch/heap",
                "route",
                "delivery", "delivery/plan", "delivery/plan/bounds",
-               "delivery/permute", "delivery/rebuild", "delivery/pressure",
+               "delivery/permute", "delivery/rebuild",
+               "delivery/rebuild/compact", "delivery/pressure",
                "delivery/pressure/spill", "delivery/pressure/mute",
                "gc_mark", "mute", "vote",
                # a program with device spawns; the collector's own program

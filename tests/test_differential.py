@@ -148,15 +148,17 @@ def _case(seed, n_w=24, n_s=8, n_seeds=10, vmax=14):
     return w_nxt, s_w, s_s, seeds
 
 
-def _fanin_case(seed, n_w=24, n_s=8, vmax=14):
-    """Every Walker forwards to one of two hubs and every Walker starts
-    with a token, so a hub takes a dozen or more messages in one tick:
-    at `mailbox_cap=32` the rebuild runs more than one rank block."""
+def _fanin_case(seed, n_w=24, n_s=8, vmax=14, hubs=2, tokens=1):
+    """Every Walker forwards to one of `hubs` hubs and every Walker
+    starts with `tokens` tokens, so a hub takes a dozen or more messages
+    in one tick: at `mailbox_cap=32` the rebuild runs more than one rank
+    block."""
     rng = np.random.default_rng(seed)
-    w_nxt = rng.integers(0, 2, n_w)
-    s_w = rng.integers(0, 2, n_s)
+    w_nxt = rng.integers(0, hubs, n_w)
+    s_w = rng.integers(0, hubs, n_s)
     s_s = rng.integers(0, n_s, n_s)
-    seeds = ([("w", i, int(rng.integers(3, vmax))) for i in range(n_w)]
+    seeds = ([("w", i, int(rng.integers(3, vmax))) for i in range(n_w)
+              for _ in range(tokens)]
              + [("s", i, int(rng.integers(2, vmax))) for i in range(n_s)])
     return w_nxt, s_w, s_s, seeds
 
@@ -192,6 +194,16 @@ CONFIGS = [
     ("deep-cap-mesh4", dict(mailbox_cap=32, batch=2, msg_words=1,
                             max_sends=2, spill_cap=1024, inject_slots=32,
                             mesh_shards=4, quiesce_interval=2)),
+    # PR 39: a block is as wide as the rows that have a message in it.
+    # Four hubs, two tokens a Walker, all injected in one tick: the 20
+    # other Walkers then send 40 at once, about 10 a hub. A compacted
+    # block holds M = 3 of the 24 Walkers, and the hubs past the first
+    # block are 4 at seed 7 (two full blocks, then a compacted one) and
+    # 3 or fewer at seed 23 (one full, two compacted); every other tick
+    # of either seed has few receivers and compacts its first block.
+    ("deep-cap-straddle", dict(mailbox_cap=32, batch=2, msg_words=1,
+                               max_sends=2, spill_cap=512, inject_slots=64,
+                               fanin=dict(hubs=4, tokens=2))),
 ]
 
 
@@ -254,8 +266,10 @@ def test_uneven_cohorts_on_mesh_match_oracle():
 @pytest.mark.parametrize("seed", [7, 23])
 def test_device_matches_oracle(name, okw, seed):
     n_w, n_s = 24, 8
+    okw = dict(okw)
+    fanin = okw.pop("fanin", {})
     case = _fanin_case if name.startswith("deep-cap") else _case
-    w_nxt, s_w, s_s, seeds = case(seed, n_w, n_s)
+    w_nxt, s_w, s_s, seeds = case(seed, n_w, n_s, **fanin)
     want = oracle(n_w, n_s, w_nxt, s_w, s_s, seeds)
     got = run_device(n_w, n_s, w_nxt, s_w, s_s, seeds,
                      RuntimeOptions(**okw))

@@ -20,6 +20,7 @@ import pytest
 
 from benchmarks import reference_fanin as ref
 from benchmarks.worlds import fanin
+from _rebuild import block_indices
 from test_profiler import _bare_hlo
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -100,26 +101,29 @@ def test_one_aggregator_under_every_producer(delivery):
                         out=world.agg_ids[world.out])
     world._ticks = ref.Ticks(world.out, world.a, **world.protocol)
     cap = world.rt.opts.mailbox_cap
-    bursts = producer_blocks = 0
+    bursts = producer_slots = 0
     seen = world.observed()
     for tick in range(1, TICKS + 1):
-        before, sent = seen["queued"][0], seen["sent"].sum()
+        before, sent = seen["queued"][0], seen["sent"]
         assert world.rt.run(max_steps=1) == 0
         seen = world.observed()
         _same(seen, world.reference(tick), tick)
         drained = min(before, world.rt.opts.batch)
         bursts += seen["queued"][0] - (before - drained) >= cap - 16
         # a producer that ran sent itself its next `produce`: the one
-        # block Producer's rows ever need, whatever the aggregator took
-        producer_blocks += seen["sent"].sum() > sent
+        # block Producer's rows ever need, whatever the aggregator took,
+        # as wide as the producers that ran (tests/_rebuild.py)
+        ran = int((seen["sent"] > sent).sum())
+        producer_slots += block_indices(world.p, ran) if ran else 0
         if tick % 16 == 0:
             _conserved(world)
     assert bursts >= 2
-    # the lane sums blocks x 8 ranks x the COHORT's rows (ISSUE 36)
+    # the lane sums, block by block, what the block read over the
+    # COHORT's rows (ISSUE 36, 39): one aggregator row ever receives, so
+    # every block of Aggregator's is a compacted one
     slots = world.rt.profile()["phases"]["rebuild"]
-    blocks, rest = divmod(
-        slots - 8 * producer_blocks * world.p, 8 * world.a)
-    assert rest == 0 and producer_blocks <= TICKS
+    blocks, rest = divmod(slots - producer_slots, block_indices(world.a, 1))
+    assert rest == 0
     assert blocks >= 6 * bursts               # 48 accepted: 6 blocks of 8
     world.rt.stop()
 

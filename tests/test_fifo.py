@@ -86,11 +86,16 @@ def _wire(seed, n_cons):
     return n_prod, pairs[:n_prod], pairs[n_prod:]
 
 
-def run_fifo(seed, okw, n_cons=6, items=60, chains=1):
+def run_fifo(seed, okw, n_cons=6, items=60, chains=1, hot=None):
     """`chains` self-send chains a producer: with `batch >= chains` it
     stamps that many messages an edge a tick, so a consumer's four
-    in-edges bring it 4 * chains arrivals in one tick."""
+    in-edges bring it 4 * chains arrivals in one tick. With `hot`, only
+    the first `hot` producers run `chains` chains and the others one: a
+    consumer then takes 4 to 4 * chains a tick by its in-edges."""
     n_prod, e1, e2 = _wire(seed, n_cons)
+    per = np.full(n_prod, chains)
+    if hot is not None:
+        per[hot:] = 1
     opts = RuntimeOptions(msg_words=2, **okw)
     rt = Runtime(opts)
     rt.declare(Prod, n_prod).declare(Cons, n_cons)
@@ -105,23 +110,28 @@ def run_fifo(seed, okw, n_cons=6, items=60, chains=1):
                          c2=cids[np.asarray([c for c, _ in e2])],
                          slot1=np.asarray([s for _, s in e1], np.int32),
                          slot2=np.asarray([s for _, s in e2], np.int32))
-    for _ in range(chains):
-        rt.bulk_send(pids, Prod.produce, np.full(n_prod, items, np.int32))
-    items *= chains
+    for k in range(chains):
+        rt.bulk_send(pids[per > k], Prod.produce,
+                     np.full(int((per > k).sum()), items, np.int32))
     assert rt.run(max_steps=500_000) == 0, "must quiesce"
     st = rt.cohort_state(Cons)
     bad = st["bad"][:n_cons]
     assert not bad.any(), f"FIFO violations: {np.asarray(bad)}"
     # Completeness: every edge delivered its full stream (the per-slot
-    # last stamp is exactly items-1, matching the oracle sequence).
+    # last stamp is exactly its producer's items-1, matching the oracle
+    # sequence).
+    want = np.zeros((IN_SLOTS, n_cons), int)
+    for edges in (e1, e2):
+        for (c, s), stamps in zip(edges, items * per):
+            want[s, c] = stamps
     for s in range(IN_SLOTS):
         last = np.asarray(st[f"last{s}"][:n_cons])
-        assert (last == items - 1).all(), (s, last)
+        assert (last == want[s] - 1).all(), (s, last)
     got = np.asarray(st["got"][:n_cons])
-    assert (got == IN_SLOTS * items).all(), got
+    assert (got == want.sum(0)).all(), got
     # Producer self-chains all ran to exhaustion.
     pst = rt.cohort_state(Prod)
-    assert (np.asarray(pst["seq"][:n_prod]) == items).all()
+    assert (np.asarray(pst["seq"][:n_prod]) == items * per).all()
     return rt
 
 
@@ -151,13 +161,24 @@ CONFIGS = [
     # edge's messages span both.
     ("deep-cap", dict(mailbox_cap=32, batch=4, max_sends=3, spill_cap=2048,
                       inject_slots=32, chains=4)),
+    # PR 39: a block is as wide as the rows that have a message in it.
+    # 16 consumers (a compacted block holds M = 2 of them), 10 of the 32
+    # producers on four chains and the rest on one: a consumer takes 4
+    # to 16 a tick by its in-edges and by who is muted, so how many go
+    # past the first block straddles M from tick to tick — every form
+    # runs (first block compacted or full, second none, compacted or
+    # full), and an edge's messages span blocks of both forms.
+    ("deep-cap-straddle", dict(mailbox_cap=32, batch=4, max_sends=3,
+                               spill_cap=2048, inject_slots=32, chains=4,
+                               hot=10, n_cons=16)),
 ]
 
 
 @pytest.mark.parametrize("name,okw", CONFIGS, ids=[c[0] for c in CONFIGS])
 def test_per_edge_fifo(name, okw):
     okw = dict(okw)
-    run_fifo(seed=101, chains=okw.pop("chains", 1), okw=okw)
+    run_fifo(seed=101, chains=okw.pop("chains", 1), hot=okw.pop("hot", None),
+             n_cons=okw.pop("n_cons", 6), okw=okw)
 
 
 def test_per_edge_fifo_more_seeds_tiny():

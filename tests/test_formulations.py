@@ -21,6 +21,7 @@ from jax import monitoring
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
+from _rebuild import block_indices  # noqa: E402
 from chip_smoke import PLAN_CACHE_LEAVES  # noqa: E402
 from ponyc_tpu import (I32, Ref, Runtime, RuntimeOptions, actor,  # noqa: E402
                        behaviour)
@@ -245,12 +246,13 @@ def test_profiler_lanes_equal_plans_at_analysis_1(formulation):
         assert got["." + lane].sum() > 0, lane
     cost = dict(zip(PHASE_NAMES, got[".phase_cost"]))
     assert cost["delivery"] > 0 and cost["drain"] == cost["dispatch"] > 0
-    # 8 ranks a block over a COHORT's rows: the first delivery alone took
-    # three blocks of the aggregator's one row and one of the producers'
-    # 20 — and the whole run less than that delivery cost when all 21
-    # rows went as deep as the aggregator (ISSUE 36)
-    assert cost["rebuild"] % 8 == 0
-    assert 8 * (3 * 1 + 1 * 20) <= cost["rebuild"] < 8 * 3 * 21
+    # A block reads over a COHORT's rows, as wide as those with a message
+    # in it (tests/_rebuild.py): the first delivery alone took three
+    # blocks of the aggregator's one row and one full-width block of the
+    # producers' 20 — and the whole run less than that delivery cost
+    # when all 21 rows went as deep as the aggregator (ISSUE 36)
+    assert (3 * block_indices(1, 1) + block_indices(20, 20)
+            <= cost["rebuild"] < 8 * 3 * 21)
 
 
 # ------------------------------------------- values that no longer exist

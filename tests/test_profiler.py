@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 import _child
+from _rebuild import block_indices
 from ponyc_tpu import (I32, Ref, Runtime, RuntimeOptions, actor,
                        analysis, behaviour)
 from ponyc_tpu.models import ring
@@ -270,15 +271,15 @@ class Leaf:
 
 @pytest.mark.parametrize("shards", [1, 4], ids=["one-shard", "mesh4"])
 def test_rebuild_lane_counts_slots_gathered(shards):
-    """The `rebuild` lane is, over the cohorts, rank blocks run x ranks a
-    block x THAT cohort's local rows, summed over ticks and shards: 20
-    leaves (poked in bulk, straight into their rings) send to one hub in
-    one tick — acc = 20 at mailbox_cap 32, three blocks of 8 over
-    Worker's rows on the hub's shard, none over Leaf's and none
-    elsewhere; later 5 leaves are poked through the delivery list (one
-    block of Leaf's rows on each shard that holds one of them) and send
-    (one block of Worker's); ticks that deliver nothing gather nothing."""
-    from ponyc_tpu.runtime.delivery import REBUILD_BLOCK
+    """The `rebuild` lane is, over the cohorts, the indices the blocks
+    THAT cohort ran read over its local rows (`block_indices`), summed over
+    ticks and shards: 20 leaves (poked in bulk, straight into their
+    rings) send to one hub in one tick — acc = 20 at mailbox_cap 32,
+    three blocks for the one row of Worker's on the hub's shard, none
+    over Leaf's and none elsewhere; later 5 leaves are poked through the
+    delivery list (one block of Leaf's rows on each shard that holds one
+    of them, as wide as the leaves it holds there) and send (one block
+    of Worker's); ticks that deliver nothing gather nothing."""
     rt = Runtime(_opts(mailbox_cap=32, batch=2, analysis=1,
                        inject_slots=32, mesh_shards=shards))
     rt.declare(Worker, 1).declare(Leaf, 20).start()
@@ -292,17 +293,38 @@ def test_rebuild_lane_counts_slots_gathered(shards):
     assert rt.run() == 0
     hub_blocks = 3
     assert rt.profile()["phases"]["rebuild"] == (
-        hub_blocks * REBUILD_BLOCK * rows["Worker"])
+        hub_blocks * block_indices(rows["Worker"], 1))
     for leaf in leaves[:5]:
         rt.send(int(leaf), Leaf.poke, 1)
     assert rt.run() == 0
     hub_blocks += 1
-    leaf_shards = len({int(leaf) // rt.program.n_local
-                       for leaf in leaves[:5]})
-    assert leaf_shards == min(shards, 5)
-    assert rt.profile()["phases"]["rebuild"] == REBUILD_BLOCK * (
-        hub_blocks * rows["Worker"] + leaf_shards * rows["Leaf"])
+    poked = np.bincount([int(leaf) // rt.program.n_local
+                         for leaf in leaves[:5]], minlength=shards)
+    assert (poked > 0).sum() == min(shards, 5)
+    assert rt.profile()["phases"]["rebuild"] == (
+        hub_blocks * block_indices(rows["Worker"], 1)
+        + sum(block_indices(rows["Leaf"], int(k)) for k in poked if k))
     assert rt.state_of(hub)["done"] == 25
+    rt.stop()
+
+
+def test_rebuild_lane_falls_where_few_rows_are_deep():
+    """A later block reads for the rows that have a message in it: 64
+    workers all take a message in one tick and one of them takes 20, so
+    the first block is full width (8 x 64) and the two later ones, with
+    one deep row against M = 8, are compacted: 8 x 8 each, where a full
+    block reads 8 x 64."""
+    rt = Runtime(_opts(mailbox_cap=32, batch=2, analysis=1))
+    rt.declare(Worker, 64).declare(Leaf, 83).start()
+    workers = rt.spawn_many(Worker, 64)
+    hubs = np.concatenate([np.full(20, workers[0]), workers[1:]])
+    leaves = rt.spawn_many(Leaf, 83, hub=hubs)
+    rt.bulk_send(leaves, Leaf.poke, np.ones(83, np.int32))
+    assert rt.run() == 0
+    got = rt.profile()["phases"]["rebuild"]
+    assert got == block_indices(64, 64) + 2 * block_indices(64, 1) == 512 + 2 * 64
+    assert got < 3 * 8 * 64
+    assert rt.state_of(int(workers[0]))["done"] == 20
     rt.stop()
 
 
@@ -547,7 +569,9 @@ def test_phase_scopes_name_the_lowered_window(delivery, cap):
     lowered window's op_name metadata, under both delivery formulations;
     `pony/gc_mark` also heads the collection pass's own program. The
     rebuild's loop body is a computation of its own: its operations
-    carry `pony/delivery/rebuild` themselves."""
+    carry `pony/delivery/rebuild` themselves, and the compacted blocks'
+    (a second loop, a ring deeper than one block only) the sub-scope
+    `pony/delivery/rebuild/compact`."""
     import jax
 
     from ponyc_tpu.runtime import gc as gc_mod
@@ -560,15 +584,19 @@ def test_phase_scopes_name_the_lowered_window(delivery, cap):
     # collector's own program (tests/test_spreader.py holds both)
     elsewhere = ("gc_mark", "dispatch/heap", "spawn/free", "spawn/reserve",
                  "spawn/claim", "gc_mark/roots", "gc_mark/hop",
-                 "gc_mark/sweep")
+                 "gc_mark/sweep") + (
+                     () if cap > 8 else ("delivery/rebuild/compact",))
     missing = [s for s in STEP_SCOPES if s not in elsewhere
                and f"{SCOPE_PREFIX}/{s}/" not in text]
     assert not missing, missing
     assert f"{SCOPE_PREFIX}/dispatch/heap" not in text
     in_body = "rebuild/while/body/pony/delivery/rebuild/"
     assert (in_body in text) == (cap > 8)
+    compact_body = "rebuild/while/body/pony/delivery/rebuild/compact/"
+    assert (compact_body in text) == (cap > 8)
     if cap > 8:
         assert in_body + "jit(_take)" in text, "the body's gather"
+        assert compact_body + "jit(_take)" in text, "the compacted body's"
     import numpy as np
     nl = rt.program.n_local
     gc_text = jax.jit(gc_mod.build_gc(rt.program, rt.opts)).lower(

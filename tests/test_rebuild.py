@@ -1,6 +1,8 @@
 """The mailbox rebuild (delivery.rebuild_tables): arrival ranks gathered
 in blocks, a cohort's tables as deep as that cohort's fullest mailbox of
-the tick.
+the tick, a block as wide as the rows that have a message in it: full
+width while more than an eighth of the cohort's rows are that deep,
+compacted to those rows from then on.
 
 Most of tier-1 runs rings of 2-8 slots, which take the one-block form;
 these tests drive `deliver()` itself at `mailbox_cap` 16 and 64 against
@@ -15,18 +17,39 @@ import pytest
 from ponyc_tpu.runtime import delivery
 from ponyc_tpu.runtime.delivery import Entries, deliver
 
+from _rebuild import block_indices
+
 N, E = 24, 320
 LAYOUT = [("Narrow", 0, 16, 2), ("Wide", 16, 24, 4)]   # (type, s0, s1, 1+W)
 ONE = [("Wide", 0, N, 4)]                  # a world of one cohort
 W1 = 4 + 2                                 # widest payload + trace context
 
 
-def _slots(acc, layout=LAYOUT):
-    """What `rebuild_slots` must read: a cohort gathers ceil(its fullest
-    acceptance / 8) blocks of 8 ranks for each of ITS rows."""
+def _forms(acc, layout=LAYOUT):
+    """The blocks each cohort must run, 'F' full width or 'C' compacted:
+    ceil(its fullest acceptance / 8) of them, block k compacted once the
+    rows with acc > 8k fit in M = ceil(rows / 8) — and every later one
+    with it, since their count only falls."""
     b = delivery.REBUILD_BLOCK
-    return sum(-(-int(acc[s0:s1].max()) // b) * b * (s1 - s0)
-               for _n, s0, s1, _w in layout)
+    out = []
+    for _n, s0, s1, _w in layout:
+        a, m = acc[s0:s1], -(-(s1 - s0) // b)
+        out.append("".join(
+            "F" if (a > k * b).sum() > m else "C"
+            for k in range(-(-int(a.max()) // b))))
+        assert "CF" not in out[-1]
+    return out
+
+
+def _slots(acc, layout=LAYOUT):
+    """What `rebuild_slots` must read, the indices the rebuild's gathers
+    read: over the blocks each cohort runs, `block_indices` of its rows
+    and of those with a message in the block."""
+    b = delivery.REBUILD_BLOCK
+    return sum(
+        block_indices(s1 - s0, int((acc[s0:s1] > k * b).sum()))
+        for _n, s0, s1, _w in layout
+        for k in range(-(-int(acc[s0:s1].max()) // b)))
 
 
 def _world(cap, seed, cnt=None, layout=LAYOUT):
@@ -59,7 +82,7 @@ def _world(cap, seed, cnt=None, layout=LAYOUT):
     return buf, tbuf, head, tail, alive, tgt, words
 
 
-def _oracle(cap, buf, tbuf, head, tail, alive, tgt, words):
+def _oracle(cap, buf, tbuf, head, tail, alive, tgt, words, layout=LAYOUT):
     """Push arrivals one by one, in list order, while there is room."""
     buf = {k: v.copy() for k, v in buf.items()}
     tbuf = {k: v.copy() for k, v in tbuf.items()}
@@ -68,7 +91,7 @@ def _oracle(cap, buf, tbuf, head, tail, alive, tgt, words):
     for j, t in enumerate(tgt):
         if not (0 <= t < N) or not alive[t] or tail[t] - head[t] >= cap:
             continue
-        name, s0, _s1, w1c = next(c for c in LAYOUT if c[1] <= t < c[2])
+        name, s0, _s1, w1c = next(c for c in layout if c[1] <= t < c[2])
         buf[name][tail[t] % cap, :, t - s0] = words[:w1c, j]
         tbuf[name][tail[t] % cap, :, t - s0] = words[W1 - 2:, j]
         tail[t] += 1
@@ -145,6 +168,98 @@ def test_rebuild_depth_is_the_cohorts(deep, mode, tracing):
     assert int(res.rebuild_slots) == _slots(acc)
 
 
+# A cohort of fewer than 8 rows (M = 1) beside one of 21 (M = 3).
+TINY = [("Narrow", 0, 3, 2), ("Wide", 3, 24, 4)]
+
+
+def _form_cnt(form, cap):
+    """Arrivals a row for one form of a tick's blocks, and what each
+    cohort of the layout must then run (`_forms`). Narrow has 16 rows
+    (M = 2), Wide 8 (M = 1); row 5 is dead and takes nothing."""
+    cnt = np.zeros(N, int)
+    layout = LAYOUT
+    if form == "later-compacted":
+        cnt[:16] = np.arange(16) % 5 + 1
+        cnt[3] = 12
+        cnt[16:] = 2
+        want = ["FC", "F"]
+    elif form == "later-full":
+        cnt[:16] = np.arange(16) % 3
+        cnt[[0, 7, 9, 12]] = 10
+        cnt[16:] = 9
+        want = ["FF", "FF"]
+    elif form == "first-compacted":
+        cnt[7] = 3
+        cnt[11] = 11 if cap > 16 else 8
+        want = ["CC" if cap > 16 else "C", ""]
+    elif form == "full-then-compacted":
+        cnt[:16] = 4
+        cnt[[1, 2, 3] if cap > 16 else [2, 3]] = 9
+        cnt[3] = cap
+        cnt[20] = 1
+        want = ["FC" if cap == 16 else "FF" + "C" * (cap // 8 - 2), "C"]
+    elif form == "exactly-m":
+        cnt[:] = 3
+        cnt[[4, 10]] = 9             # Narrow: 2 rows past 8 = M
+        cnt[18] = 16                 # Wide: 1 = M
+        want = ["FC", "FC"]
+    elif form == "m-plus-one":
+        cnt[:] = 3
+        cnt[[4, 10, 15]] = 9         # Narrow: 3 rows past 8 = M + 1
+        cnt[[18, 19]] = 16           # Wide: 2 = M + 1
+        want = ["FF", "FF"]
+    elif form == "few-rows":
+        layout = TINY
+        cnt[[0, 2]] = 9              # Narrow, 3 rows, M = 1: 2 past 8
+        cnt[1] = 1
+        cnt[3:] = 2
+        cnt[[8, 9, 23]] = 12         # Wide, 21 rows, M = 3: 3 past 8
+        want = ["FF", "FC"]
+    elif form == "deepest-few":      # cap 64: a 57-deep acceptance
+        cnt[:16] = 2
+        cnt[6] = cap - 7
+        cnt[16:] = 1
+        cnt[22] = cap
+        want = ["F" + "C" * (cap // 8 - 1), "F" + "C" * (cap // 8 - 1)]
+    elif form == "deepest-many":     # ... by more rows than M: 8 full
+        cnt[[0, 1, 2]] = cap - 7
+        cnt[8] = 1
+        want = ["F" * (cap // 8), ""]
+    return cnt, layout, want
+
+
+FORMS = ["later-compacted", "later-full", "first-compacted",
+         "full-then-compacted", "exactly-m", "m-plus-one", "few-rows",
+         "deepest-few", "deepest-many"]
+
+
+@pytest.mark.parametrize("tracing", [False, True], ids=["plain", "traced"])
+@pytest.mark.parametrize("mode", ["plan", "cosort"])
+@pytest.mark.parametrize("cap", [16, 64])
+@pytest.mark.parametrize("form", FORMS)
+def test_rebuild_block_is_as_wide_as_its_rows(form, cap, mode, tracing):
+    """Block k of a cohort runs full width while more than M of its rows
+    have acc > 8k and compacted from then on (k = 0 is no special case);
+    whichever it ran, the tables are the one-by-one oracle's and
+    `rebuild_slots` the indices those blocks read."""
+    cnt, layout, want = _form_cnt(form, cap)
+    buf, tbuf, head, _tail, alive, tgt, words = _world(
+        cap, 3, cnt=cnt, layout=layout)
+    world = (buf, tbuf, head, head.copy(), alive, tgt, words)  # all empty
+    want_buf, want_tbuf, want_tail, acc = _oracle(cap, *world, layout=layout)
+    assert _forms(acc, layout) == want
+    res = _deliver(cap, world, cosort=(mode == "cosort"), tracing=tracing,
+                   layout=layout)
+    np.testing.assert_array_equal(res.tail, want_tail)
+    for name in want_buf:
+        np.testing.assert_array_equal(res.buf[name], want_buf[name])
+        if tracing:
+            np.testing.assert_array_equal(res.trace_buf[name],
+                                          want_tbuf[name])
+    assert int(res.n_delivered) == acc.sum()
+    assert int(res.rebuild_slots) == _slots(acc, layout)
+
+
 def test_rebuild_runs_as_many_blocks_as_the_fullest_mailbox():
     """Depth follows the input: 1 message -> 1 block, 9 to one actor ->
     2 blocks, none -> no block, whatever the ring's capacity — of the
@@ -158,16 +273,19 @@ def test_rebuild_runs_as_many_blocks_as_the_fullest_mailbox():
         tgt[100] = 2 if sent else -1
         res = _deliver(cap, (buf, tbuf, head, tail, alive, tgt, words),
                        cosort=False, tracing=False)
+        # Two rows at most receive, M = 2 of Narrow's 16: every block
+        # is a compacted one, 8 ranks x M.
         assert int(res.rebuild_slots) == (
-            blocks * delivery.REBUILD_BLOCK * 16), sent
+            blocks * delivery.REBUILD_BLOCK * 2), sent
         assert int(res.n_delivered) == sent + (sent > 0)
 
 
-def _rebuild_eqns(cap, layout=LAYOUT, inherit=True):
+def _rebuild_eqns(cap, layout=LAYOUT, inherit=True,
+                  scope="pony/delivery/rebuild"):
     """Primitive names (a jitted helper's own name for `jit`) of every
-    equation under pony/delivery/rebuild in `deliver`'s jaxpr,
-    sub-jaxprs included; with `inherit` off, only of those whose OWN
-    name stack holds the scope."""
+    equation under `scope` in `deliver`'s jaxpr, sub-jaxprs included;
+    with `inherit` off, only of those whose OWN name stack holds the
+    scope."""
     world = _world(cap, 0, layout=layout)
     jaxpr = jax.make_jaxpr(
         lambda: _deliver(cap, world, cosort=False, tracing=True,
@@ -177,7 +295,7 @@ def _rebuild_eqns(cap, layout=LAYOUT, inherit=True):
     def walk(jp, inherited):
         # A sub-jaxpr's name stacks are relative to its equation's.
         for eqn in jp.eqns:
-            under = inherited or ("pony/delivery/rebuild"
+            under = inherited or (scope
                                   in str(eqn.source_info.name_stack))
             if under:
                 names.append(eqn.params["name"]
@@ -202,18 +320,33 @@ def test_one_block_ring_has_no_loop_and_no_reduction():
 
 
 @pytest.mark.parametrize("layout", [ONE, LAYOUT], ids=["one", "two"])
-def test_deep_ring_has_one_loop_a_cohort(layout):
-    """A ring deeper than a block: one loop and one depth (`max(acc)`
-    over the cohort's rows) a COHORT — a world of one cohort has the one
-    loop it had — and one gather a block a table, the cohort's trace
-    side lanes inside its loop."""
-    tables = 2 * len(layout)                        # buf + trace_buf
+def test_deep_ring_has_two_loops_a_cohort(layout):
+    """A ring deeper than a block: one depth (`max(acc)` over the
+    cohort's rows) and two loops in turn a COHORT, the full-width
+    blocks' and the compacted blocks' — no `cond` round the tables. A
+    full block is one gather a table; a compacted one a sort of the deep
+    rows, one (short) gather a table and ONE scatter back to the table's
+    lanes for the cohort, its trace side lanes inside the same loops."""
+    cohorts, tables = len(layout), 2 * len(layout)  # buf + trace_buf
+    b = delivery.REBUILD_BLOCK
     deep = _rebuild_eqns(16, layout)
-    assert deep.count("while") == len(layout)
-    assert deep.count("reduce_max") == len(layout)
-    assert deep.count("_take") == tables
-    assert deep.count("_where") == (tables * delivery.REBUILD_BLOCK
-                                    + tables + 1)
-    # Every loop's body writes the scope itself (a body is a computation
-    # of its own): its gathers are named without their loop's help.
-    assert _rebuild_eqns(16, layout, inherit=False).count("_take") == tables
+    assert deep.count("while") == 2 * cohorts
+    assert "cond" not in deep
+    assert deep.count("reduce_max") == cohorts
+    # The full loop's test counts the rows that deep; nothing else sums.
+    assert deep.count("reduce_sum") == cohorts
+    assert deep.count("_take") == 2 * tables
+    # The selects of both loops and the deep rows' `where`; every
+    # `_take` and the `%` of `rels` hold one each.
+    assert deep.count("_where") == (2 * tables * b + cohorts
+                                    + 2 * tables + 1)
+    # Every loop's body writes its scope itself (a body is a computation
+    # of its own): its gathers are named without their loop's help, the
+    # compacted blocks' one scope down.
+    own = _rebuild_eqns(16, layout, inherit=False)
+    assert own.count("_take") == 2 * tables
+    compact = _rebuild_eqns(16, layout, inherit=False,
+                            scope="pony/delivery/rebuild/compact")
+    assert compact.count("_take") == tables
+    assert compact.count("sort") == compact.count("scatter") == cohorts
+    assert "while" not in compact
