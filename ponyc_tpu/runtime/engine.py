@@ -46,8 +46,8 @@ from ..ops.segment import (compact_mask, counts_by_key, marks_of,
                            stable_sort_by)
 from ..program import Cohort, Program
 from .delivery import Entries, deliver, empty_mute_slots, mute_ref_slots
-from .state import (PHASE_NAMES, QW_BUCKETS, PhaseCursor, RtState,
-                    layout_sizes, phase_scope, pool_index)
+from .state import (PHASE_NAMES, QW_BUCKETS, ROUTE_COUNTERS, PhaseCursor,
+                    RtState, layout_sizes, phase_scope, pool_index)
 
 
 class StepAux(NamedTuple):
@@ -1014,7 +1014,10 @@ def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
     exchange them with one all_to_all over the actor axis (ICI).
 
     Returns (received Entries [shards*bucket], new route-spill, spill count,
-    overflow flag, newly muted [n_local], their refs[, blob results]).
+    overflow flag, newly muted [n_local], their refs, ref overflow, blob
+    results or None, (entries shipped, those of them off-shard)). Its
+    parts carry the scopes `pony/route/sort`, `/bucket`, `/exchange` and
+    `/spill` (state.STEP_SCOPES).
     Bucket overflow keeps messages on the source shard (route-spill,
     retried first next step) and mutes the sender — backpressure across
     the mesh without any receiver-side state (≙ the intent of
@@ -1038,28 +1041,36 @@ def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
     """
     tgt, sender, words = entries
     e = tgt.shape[0]
-    valid = tgt >= 0
-    dest = jnp.where(valid, tgt // n_local, shards).astype(jnp.int32)
-    perm = stable_sort_by(dest)
-    dt = dest[perm]
-    ts = tgt[perm]
-    ss = sender[perm]
-    ws = words[:, perm]                              # [w1, E] planar
-    # Per-destination segment bounds via binary search; the bucket table
-    # is then a dense gather [shards, bucket] from the sorted entries —
-    # same scatter-free design as delivery.py (TPU scatters serialise).
-    bounds = jnp.searchsorted(dt, jnp.arange(shards + 1, dtype=jnp.int32),
-                              side="left").astype(jnp.int32)
-    seg_start = bounds[:-1]
-    cnt = bounds[1:] - seg_start                     # [shards]
-    acc = jnp.minimum(cnt, bucket)
-    j = jnp.arange(bucket, dtype=jnp.int32)[None, :]
-    fill = j < acc[:, None]                          # [shards, bucket]
-    src = jnp.minimum(seg_start[:, None] + j, e - 1)
-    bt = jnp.where(fill, ts[src], -1).reshape(shards * bucket)
-    bs = jnp.where(fill, ss[src], -1).reshape(shards * bucket)
-    fill_f = fill.reshape(shards * bucket)
-    bw = jnp.where(fill_f[None, :], ws[:, src.reshape(-1)], 0)
+    with phase_scope("route/sort"):
+        valid = tgt >= 0
+        dest = jnp.where(valid, tgt // n_local, shards).astype(jnp.int32)
+        perm = stable_sort_by(dest)
+        dt = dest[perm]
+        ts = tgt[perm]
+        ss = sender[perm]
+        ws = words[:, perm]                          # [w1, E] planar
+    with phase_scope("route/bucket"):
+        # Per-destination segment bounds via binary search; the bucket
+        # table is then a dense gather [shards, bucket] from the sorted
+        # entries — same scatter-free design as delivery.py (TPU
+        # scatters serialise).
+        bounds = jnp.searchsorted(
+            dt, jnp.arange(shards + 1, dtype=jnp.int32),
+            side="left").astype(jnp.int32)
+        seg_start = bounds[:-1]
+        cnt = bounds[1:] - seg_start                 # [shards]
+        acc = jnp.minimum(cnt, bucket)
+        j = jnp.arange(bucket, dtype=jnp.int32)[None, :]
+        fill = j < acc[:, None]                      # [shards, bucket]
+        src = jnp.minimum(seg_start[:, None] + j, e - 1)
+        bt = jnp.where(fill, ts[src], -1).reshape(shards * bucket)
+        bs = jnp.where(fill, ss[src], -1).reshape(shards * bucket)
+        fill_f = fill.reshape(shards * bucket)
+        bw = jnp.where(fill_f[None, :], ws[:, src.reshape(-1)], 0)
+        # What ships this tick, and how much of it leaves the shard
+        # (RtState.route_counts): read off the [shards] bucket fills.
+        n_routed = jnp.sum(acc)
+        n_remote = n_routed - jnp.take(acc, shard_base // n_local)
 
     blob_out = None
     if blob is not None:
@@ -1119,12 +1130,13 @@ def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
         n_shipped = jnp.sum(freed.astype(jnp.int32))
         bw = jnp.concatenate([bw] + extra_rows, axis=0)
 
-    rt = lax.all_to_all(bt, "actors", split_axis=0, concat_axis=0,
-                        tiled=True)
-    rs = lax.all_to_all(bs, "actors", split_axis=0, concat_axis=0,
-                        tiled=True)
-    rw = lax.all_to_all(bw, "actors", split_axis=1, concat_axis=1,
-                        tiled=True)
+    with phase_scope("route/exchange"):
+        rt = lax.all_to_all(bt, "actors", split_axis=0, concat_axis=0,
+                            tiled=True)
+        rs = lax.all_to_all(bs, "actors", split_axis=0, concat_axis=0,
+                            tiled=True)
+        rw = lax.all_to_all(bw, "actors", split_axis=1, concat_axis=1,
+                            tiled=True)
 
     if blob is not None:
         # --- migration, receive side: allocate a local slot per arrived
@@ -1171,8 +1183,29 @@ def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
         blob_out = ((bdata, bused, blen, bgen),
                     n_shipped, n_received, n_dropped)
 
-    nrej = jnp.sum(cnt - acc)
-    w1 = words.shape[0]
+    with phase_scope("route/spill"):
+        spilled = _route_spill(
+            ts, ss, ws, dt, seg_start, cnt - acc, shards=shards,
+            n_local=n_local, bucket=bucket, rspill_cap=rspill_cap,
+            overload_occ=overload_occ, head=head, tail=tail,
+            shard_base=shard_base, mute_slots=mute_slots,
+            pressured_global=pressured_global,
+            pressured_local=pressured_local)
+    received = Entries(tgt=rt, sender=rs, words=rw)
+    return (received, *spilled, blob_out, (n_routed, n_remote))
+
+
+def _route_spill(ts, ss, ws, dt, seg_start, over, *, shards: int,
+                 n_local: int, bucket: int, rspill_cap: int, overload_occ,
+                 head, tail, shard_base, mute_slots: int, pressured_global,
+                 pressured_local):
+    """What did not fit its bucket, and who mutes for it: the sorted
+    entries (`ts`, `ss`, `ws` by destination `dt`), each destination's
+    `seg_start` and overflow `over` → (new route-spill, spill count,
+    overflow flag, newly muted [n_local], their refs, ref overflow)."""
+    e = ts.shape[0]
+    nrej = jnp.sum(over)
+    w1 = ws.shape[0]
     # Sends whose (possibly remote) target DECLARED pressure: the
     # cross-shard face of pony_apply_backpressure — every shard sees the
     # all-gathered pressured bits, so senders mute at routing time, not
@@ -1220,10 +1253,8 @@ def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
 
     new_rspill, newly_muted, new_refs, new_ovf = lax.cond(
         (nrej > 0) | jnp.any(pr_t), pressure, quiet, operand=None)
-
-    received = Entries(tgt=rt, sender=rs, words=rw)
-    return (received, new_rspill, jnp.minimum(nrej, rspill_cap),
-            nrej > rspill_cap, newly_muted, new_refs, new_ovf, blob_out)
+    return (new_rspill, jnp.minimum(nrej, rspill_cap), nrej > rspill_cap,
+            newly_muted, new_refs, new_ovf)
 
 
 # A row's status word for the unmute pass (`muter_bits` in the tick).
@@ -1784,7 +1815,7 @@ def build_step(program: Program, opts: RuntimeOptions):
                          "mask": _blob_route_mask,
                          "mask_iso": _blob_route_mask_iso}
             (incoming, new_rspill, rsp_count, rsp_over, route_muted,
-             route_refs, route_ovf, route_blob_out) = _route(
+             route_refs, route_ovf, route_blob_out, routed) = _route(
                 out_cat, shards=p, n_local=nl, bucket=bucket,
                 rspill_cap=s_cap, overload_occ=opts.overload_occ,
                 head=new_head, tail=tail0, shard_base=base,
@@ -2198,6 +2229,9 @@ def build_step(program: Program, opts: RuntimeOptions):
             rspill_tgt=new_rspill.tgt, rspill_sender=new_rspill.sender,
             rspill_words=new_rspill.words,
             rspill_count=vec(rsp_count),
+            route_counts=({name: vec(st.route_counts[name][0] + n)
+                           for name, n in zip(ROUTE_COUNTERS, routed)}
+                          if p > 1 else st.route_counts),
             spill_overflow=vec(overflow, jnp.bool_),
             exit_flag=vec(exit_f, jnp.bool_), exit_code=vec(exit_c),
             step_no=vec(st.step_no[0] + 1),

@@ -396,6 +396,7 @@ def build_gc(program: Program, opts: RuntimeOptions):
             dspill_words=st.dspill_words, dspill_count=st.dspill_count,
             rspill_tgt=st.rspill_tgt, rspill_sender=st.rspill_sender,
             rspill_words=st.rspill_words, rspill_count=st.rspill_count,
+            route_counts=st.route_counts,
             spill_overflow=st.spill_overflow,
             exit_flag=st.exit_flag, exit_code=st.exit_code,
             step_no=st.step_no,

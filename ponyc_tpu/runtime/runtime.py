@@ -40,7 +40,7 @@ from ..ops import pack
 from ..program import Program
 from . import engine
 from .controller import WindowController
-from .state import RtState, init_state, pool_index
+from .state import ROUTE_COUNTERS, RtState, init_state, pool_index
 
 # Window-length histogram buckets (power-of-two, like state.QW_BUCKETS):
 # bucket k counts retired windows that ran [2^k, 2^(k+1)) ticks.
@@ -444,7 +444,7 @@ class Runtime:
         self.program.declare(atype, capacity)
         return self
 
-    @_api_phase("start")
+    @_api_phase("start", lambda self: {"shards": self.program.shards})
     def start(self) -> "Runtime":
         # ≙ pony_init, split so the operational pieces (the always-on
         # flight recorder + optional stall watchdog, PROFILE.md §11)
@@ -1495,8 +1495,10 @@ class Runtime:
         self._rl_seq += 1
         seq = self._rl_seq
         cold = self._cold_window
-        with _PhaseSpan(self, "dispatching", {"window": seq, "cold": 1}
-                        if cold else {"window": seq}):
+        meta = {"window": seq, "shards": self.program.shards}
+        if cold:
+            meta["cold"] = 1
+        with _PhaseSpan(self, "dispatching", meta):
             inj_t, inj_w, consumed = self._drain_inject_tracked()
             mask = self._defer_signals()
             try:
@@ -2212,7 +2214,12 @@ class Runtime:
     @_api_phase("counter")
     def counter(self, name: str) -> int:
         """Sum a per-shard runtime counter (n_processed, n_delivered,
-        n_rejected, n_badmsg, n_deadletter, n_mutes) over the mesh."""
+        n_rejected, n_badmsg, n_deadletter, n_mutes; the route's
+        n_routed, n_routed_remote — 0 on one chip, where nothing is
+        routed and the state holds no such leaf) over the mesh."""
+        if name in ROUTE_COUNTERS:
+            leaf = self.state.route_counts.get(name)
+            return 0 if leaf is None else int(self._fetch(leaf).sum())
         return int(self._fetch(getattr(self.state, name)).sum())
 
     @_api_phase("read")

@@ -85,6 +85,11 @@ N_PHASES = len(PHASE_NAMES)
 SCOPE_PREFIX = "pony"
 STEP_SCOPES = ("unmute", "spawn", "drain", "dispatch", "dispatch/heap",
                "route",
+               # a mesh only (engine._route): the destination sort and
+               # the reads by its permutation, the dense [shards,
+               # bucket] gathers, the all_to_alls, overflow + link mutes
+               "route/sort", "route/bucket", "route/exchange",
+               "route/spill",
                "delivery", "delivery/plan", "delivery/plan/bounds",
                "delivery/permute", "delivery/rebuild",
                "delivery/rebuild/compact", "delivery/pressure",
@@ -233,6 +238,15 @@ class RtState:
     rspill_sender: jnp.ndarray  # [P*S] int32 sender global id
     rspill_words: jnp.ndarray  # [1+W, P*S] int32
     rspill_count: jnp.ndarray  # [P] int32
+    # What the route moved, cumulative per shard, a mesh only ({} where
+    # P == 1: no leaf, the one-chip window's HLO unchanged — nothing is
+    # routed there). "n_routed" [P] int32 — entries this shard placed
+    # in an all_to_all bucket (a message counts once, in the tick it
+    # ships: one parked in the route spill counts when its retry does);
+    # "n_routed_remote" [P] int32 — those of them whose bucket went to
+    # ANOTHER shard. Read through Runtime.counter(), which sums them
+    # over the mesh like n_processed.
+    route_counts: Dict[str, jnp.ndarray]
 
     spill_overflow: jnp.ndarray  # [P] bool — a spill overflowed (fatal)
 
@@ -389,6 +403,9 @@ class RtState:
     type_state: Dict[str, Dict[str, jnp.ndarray]]
 
 
+# The route's counters (RtState.route_counts), a mesh only.
+ROUTE_COUNTERS = ("n_routed", "n_routed_remote")
+
 # The int32 word tables that serialise.save(packed=True) stores as an
 # int16 lane plane + an int32 escape plane: mailbox ring records, both
 # spill word tables and the per-message trace lanes (behaviour ids and
@@ -447,6 +464,8 @@ def init_state(program: Program, opts: RuntimeOptions) -> RtState:
         rspill_sender=jnp.full((s,), -1, i32),
         rspill_words=jnp.zeros((w1, s), i32),
         rspill_count=jnp.zeros((p,), i32),
+        route_counts=({name: jnp.zeros((p,), i32)
+                       for name in ROUTE_COUNTERS} if p > 1 else {}),
         spill_overflow=jnp.zeros((p,), jnp.bool_),
         exit_flag=jnp.zeros((p,), jnp.bool_),
         exit_code=jnp.zeros((p,), i32),

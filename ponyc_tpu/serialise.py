@@ -249,7 +249,8 @@ def _unpack_snapshot_arrays(arrays: Dict[str, np.ndarray],
 # State fields added after a snapshot format was already in the wild:
 # a same-layout restore treats a missing named twin as zeros (cumulative
 # telemetry starts over) instead of rejecting the whole snapshot.
-_ZERO_IF_ABSENT = frozenset({"st.phase_cost"})
+_ZERO_IF_ABSENT = frozenset({"st.phase_cost", "st.route_counts.n_routed",
+                             "st.route_counts.n_routed_remote"})
 
 
 def _pad_phase_lanes(arr, n_shards: int) -> np.ndarray:
@@ -915,6 +916,12 @@ def _restore_relayout(rt, header, Z: Dict[str, np.ndarray]) -> None:
         dst[:] = 0
         dst[0] = int(Z[f"st.{name}"].astype(np.int64).sum())
         st[name] = dst
+    # the route's counters exist on a mesh only: carried where both
+    # sides have them, dropped or started at zero otherwise
+    for name, dst in st["route_counts"].items():
+        old_n = Z.get(f"st.route_counts.{name}")
+        if old_n is not None:
+            dst[0] = int(old_n.astype(np.int64).sum())
     for name in ("spill_overflow", "spawn_fail", "blob_fail",
                  "blob_budget_fail", "exit_flag"):
         st[name] = np.full_like(st[name], bool(Z[f"st.{name}"].any()))

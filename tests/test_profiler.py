@@ -581,15 +581,20 @@ def test_phase_scopes_name_the_lowered_window(delivery, cap):
     # `dispatch/heap` names the blob pool's operations: a blob-free
     # world has none (tests/test_gups.py holds the world that has);
     # `spawn/*` a world whose behaviours create actors, `gc_mark/*` the
-    # collector's own program (tests/test_spreader.py holds both)
+    # collector's own program (tests/test_spreader.py holds both),
+    # `route/*` a mesh's window (tests/test_mesh_ubench.py holds it)
     elsewhere = ("gc_mark", "dispatch/heap", "spawn/free", "spawn/reserve",
                  "spawn/claim", "gc_mark/roots", "gc_mark/hop",
-                 "gc_mark/sweep") + (
+                 "gc_mark/sweep", "route/sort", "route/bucket",
+                 "route/exchange", "route/spill") + (
                      () if cap > 8 else ("delivery/rebuild/compact",))
     missing = [s for s in STEP_SCOPES if s not in elsewhere
                and f"{SCOPE_PREFIX}/{s}/" not in text]
     assert not missing, missing
     assert f"{SCOPE_PREFIX}/dispatch/heap" not in text
+    assert f"{SCOPE_PREFIX}/route/" in text
+    for sub in ("sort", "bucket", "exchange", "spill"):
+        assert f"{SCOPE_PREFIX}/route/{sub}" not in text, sub
     in_body = "rebuild/while/body/pony/delivery/rebuild/"
     assert (in_body in text) == (cap > 8)
     compact_body = "rebuild/while/body/pony/delivery/rebuild/compact/"

@@ -732,8 +732,8 @@ def test_api_phases_are_spans_with_their_meta_under_a_session(recording):
     assert meta["pony:bulk-send"] == {"count": 3}
     assert meta["pony:blob-store"] == {"blobs": 4}
     assert meta["pony:blob-fetch"] == {"blobs": 2}
-    assert meta["pony:start"] == meta["pony:counter"] == \
-        meta["pony:stop"] == {}
+    assert meta["pony:start"] == {"shards": 1}
+    assert meta["pony:counter"] == meta["pony:stop"] == {}
     reads = [m for name, m in api if name == "pony:read"]
     assert reads == [{"words": 16}, {"words": 2}]    # 8 x (nxt, seen); one row
     assert rt.run_loop_stats()["phase_n"]["read"] == 2
@@ -755,7 +755,7 @@ def test_dispatching_is_a_leaf_and_only_the_first_launch_is_cold(recording):
     """`pony:dispatching` has no child (benchmarks/phase_trace.py sums
     it by name), the first launch after start() carries cold=1 and no
     later one does — in a second run() either — and its seconds are
-    counted apart."""
+    counted apart; every launch says over how many shards."""
     rt, ids = _ring(hops=300, quiesce_interval=16)
     assert rt.run() == 0
     rt.send(int(ids[0]), Node.step, 40)
@@ -768,8 +768,8 @@ def test_dispatching_is_a_leaf_and_only_the_first_launch_is_cold(recording):
             assert recording[i + 1][:2] == ("exit", "pony:dispatching")
             launches.append(meta)
     assert len(launches) > 4
-    assert launches[0] == {"window": 1, "cold": 1}
-    assert all(set(m) == {"window"} for m in launches[1:])
+    assert launches[0] == {"window": 1, "shards": 1, "cold": 1}
+    assert all(set(m) == {"window", "shards"} for m in launches[1:])
     assert rl["cold_dispatches"] == 1
     assert 0 < rl["cold_dispatch_s"] <= rl["phase_s"]["dispatching"]
     # a second world's first launch is cold again: the flag is the
