@@ -13,18 +13,19 @@ import jax
 import jax.numpy as jnp
 
 
+def stable_sort_carrying(keys: jnp.ndarray, *payloads: jnp.ndarray):
+    """(sorted keys, *payloads in the keys' order) of ONE stable
+    ascending sort of int32 keys. Reading a payload back as
+    `payload[perm]` is a gather, ~6-20 ns an index on a v5e; the sort
+    that carries it costs ~1-2 ns a key and operand."""
+    return jax.lax.sort((keys, *payloads), num_keys=1, is_stable=True)
+
+
 def stable_sort_with_keys(keys: jnp.ndarray):
     """(sorted keys, permutation) of a stable ascending sort of int32
-    keys: what `argsort` computes and then drops. Reading the sorted
-    keys back as `keys[perm]` is a gather, ~6-9 ns an index on a v5e;
-    the sort that carries them costs ~2 ns a key."""
-    return jax.lax.sort((keys, jax.lax.iota(jnp.int32, keys.shape[0])),
-                        num_keys=1, is_stable=True)
-
-
-def stable_sort_by(keys: jnp.ndarray):
-    """Return the permutation that stably sorts int32 keys ascending."""
-    return stable_sort_with_keys(keys)[1]
+    keys: what `argsort` computes and then drops."""
+    return stable_sort_carrying(
+        keys, jax.lax.iota(jnp.int32, keys.shape[0]))
 
 
 def segment_bounds(sorted_keys: jnp.ndarray, num_segments: int,

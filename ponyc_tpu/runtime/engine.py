@@ -43,7 +43,7 @@ from ..api import Context
 from ..config import RuntimeOptions
 from ..ops import pack
 from ..ops.segment import (compact_mask, counts_by_key, marks_of,
-                           stable_sort_by)
+                           stable_sort_carrying)
 from ..program import Cohort, Program
 from .delivery import Entries, deliver, empty_mute_slots, mute_ref_slots
 from .state import (PHASE_NAMES, QW_BUCKETS, ROUTE_COUNTERS, PhaseCursor,
@@ -1006,12 +1006,64 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
     return run_cohort
 
 
+def _route_pack(tgt, sender, words, *, shards: int, n_local: int,
+                bucket: int):
+    """One shard's entries `[route spill, outbox]` → its all-to-all
+    buckets, with no read by index:
+
+      sorted   (dt, ts, ss, ws): destination shard, target, sender and
+               the `[w1, e]` words in ONE stable sort by destination
+               (`dt` = `shards` for the invalid tail), so equal
+               destinations keep their order: FIFO
+      segments (seg_start, cnt, acc), `[shards]`: where a destination's
+               run starts in the sorted entries, how long it is, and
+               how much of it fits the bucket
+      buckets  (bt, bs, bw, fill_f), `[shards * bucket]` / `[w1, ...]`:
+               block d holds entries `seg_start[d] + j`, `j < acc[d]`,
+               then -1 / -1 / 0: a contiguous slice of the sorted
+               entries, masked
+    """
+    with phase_scope("route/sort"):
+        valid = tgt >= 0
+        dest = jnp.where(valid, tgt // n_local, shards).astype(jnp.int32)
+        dt, ts, ss, *rows = stable_sort_carrying(
+            dest, tgt, sender, *(words[i] for i in range(words.shape[0])))
+        ws = jnp.stack(rows)                         # [w1, E] planar
+    with phase_scope("route/bucket"):
+        # Per-destination segment bounds via binary search; a
+        # destination's block is then `bucket` consecutive sorted
+        # entries from its segment's start. `dynamic_slice` clamps its
+        # start so that the slice fits, so the sorted entries are padded
+        # by a bucket: entry `seg_start[d] + j` stays at slot j. What
+        # lies past `acc[d]` (the next segments, the pad) is masked.
+        bounds = jnp.searchsorted(
+            dt, jnp.arange(shards + 1, dtype=jnp.int32),
+            side="left").astype(jnp.int32)
+        seg_start = bounds[:-1]
+        cnt = bounds[1:] - seg_start                 # [shards]
+        acc = jnp.minimum(cnt, bucket)
+        j = jnp.arange(bucket, dtype=jnp.int32)
+        fill = j[None, :] < acc[:, None]             # [shards, bucket]
+        fill_f = fill.reshape(shards * bucket)
+
+        def blocks(x, empty):
+            xp = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, bucket)])
+            return jnp.concatenate([
+                jnp.where(fill[d], lax.dynamic_slice_in_dim(
+                    xp, seg_start[d], bucket, axis=-1), empty)
+                for d in range(shards)], axis=-1)
+        bt, bs, bw = blocks(ts, -1), blocks(ss, -1), blocks(ws, 0)
+    return (dt, ts, ss, ws), (seg_start, cnt, acc), (bt, bs, bw, fill_f)
+
+
 def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
            rspill_cap: int, overload_occ, head, tail, shard_base,
            mute_slots: int, pressured_global, pressured_local,
            blob=None):
-    """Mesh routing: pack entries into per-destination-shard buckets and
-    exchange them with one all_to_all over the actor axis (ICI).
+    """Mesh routing: pack entries into per-destination-shard buckets
+    (`_route_pack`: one payload-carrying sort, then a contiguous slice a
+    destination) and exchange them with three all_to_all over the actor
+    axis (ICI): targets, senders, words.
 
     Returns (received Entries [shards*bucket], new route-spill, spill count,
     overflow flag, newly muted [n_local], their refs, ref overflow, blob
@@ -1040,33 +1092,10 @@ def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
     of pony_alloc_msg payload movement.
     """
     tgt, sender, words = entries
-    e = tgt.shape[0]
-    with phase_scope("route/sort"):
-        valid = tgt >= 0
-        dest = jnp.where(valid, tgt // n_local, shards).astype(jnp.int32)
-        perm = stable_sort_by(dest)
-        dt = dest[perm]
-        ts = tgt[perm]
-        ss = sender[perm]
-        ws = words[:, perm]                          # [w1, E] planar
+    ((dt, ts, ss, ws), (seg_start, cnt, acc),
+     (bt, bs, bw, fill_f)) = _route_pack(
+        tgt, sender, words, shards=shards, n_local=n_local, bucket=bucket)
     with phase_scope("route/bucket"):
-        # Per-destination segment bounds via binary search; the bucket
-        # table is then a dense gather [shards, bucket] from the sorted
-        # entries — same scatter-free design as delivery.py (TPU
-        # scatters serialise).
-        bounds = jnp.searchsorted(
-            dt, jnp.arange(shards + 1, dtype=jnp.int32),
-            side="left").astype(jnp.int32)
-        seg_start = bounds[:-1]
-        cnt = bounds[1:] - seg_start                 # [shards]
-        acc = jnp.minimum(cnt, bucket)
-        j = jnp.arange(bucket, dtype=jnp.int32)[None, :]
-        fill = j < acc[:, None]                      # [shards, bucket]
-        src = jnp.minimum(seg_start[:, None] + j, e - 1)
-        bt = jnp.where(fill, ts[src], -1).reshape(shards * bucket)
-        bs = jnp.where(fill, ss[src], -1).reshape(shards * bucket)
-        fill_f = fill.reshape(shards * bucket)
-        bw = jnp.where(fill_f[None, :], ws[:, src.reshape(-1)], 0)
         # What ships this tick, and how much of it leaves the shard
         # (RtState.route_counts): read off the [shards] bucket fills.
         n_routed = jnp.sum(acc)
@@ -1184,6 +1213,11 @@ def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
                     n_shipped, n_received, n_dropped)
 
     with phase_scope("route/spill"):
+        # The spill reads the sorted entries only behind this barrier:
+        # without it the compiler fuses `maximum(ts, 0)` into the
+        # bucket's slices and `pressured_global[ts]` loses its fast
+        # memory, 114 ms for 68 at 8.4M entries (PERF.md §6, PR 41).
+        ts, ss, ws, dt = lax.optimization_barrier((ts, ss, ws, dt))
         spilled = _route_spill(
             ts, ss, ws, dt, seg_start, cnt - acc, shards=shards,
             n_local=n_local, bucket=bucket, rspill_cap=rspill_cap,

@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from ponyc_tpu import RuntimeOptions
-from ponyc_tpu.ops.segment import (segment_bounds, stable_sort_by,
+from ponyc_tpu.ops.segment import (segment_bounds, stable_sort_carrying,
                                    stable_sort_with_keys)
 from ponyc_tpu.runtime import state
 from ponyc_tpu.runtime.delivery import Entries, deliver
@@ -69,7 +69,11 @@ def test_plan_equals_argsort_and_searchsorted(shape, n_levels, invalid):
         sorted_key, n, n_levels)
     np.testing.assert_array_equal(perm, want_perm)
     np.testing.assert_array_equal(sorted_key, key[want_perm])
-    np.testing.assert_array_equal(stable_sort_by(key), want_perm)
+    # a payload rides the same sort (the mesh route's, engine._route_pack)
+    tag = key * 3 + 1
+    np.testing.assert_array_equal(
+        jax.jit(stable_sort_carrying)(key, tag, perm)[1:],
+        (tag[want_perm], perm[want_perm]))
     np.testing.assert_array_equal(bounds, want_bounds)
     assert bounds.dtype == jnp.int32 and bounds.shape == (n + 1,)
     if INVALID[invalid] < 1.0:

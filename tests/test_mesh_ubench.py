@@ -181,6 +181,31 @@ def test_route_scopes_are_metadata_only(monkeypatch):
     assert _bare_hlo(scoped) == _bare_hlo(bare)
 
 
+def _route_gathers(text, scope):
+    """The gather instructions of a compiled window whose op_name lies
+    under `pony/<scope>` (the bounds' binary search over `shards + 1`
+    queries aside: `jnp.searchsorted` reads five words its own way)."""
+    return [line for line in text.splitlines()
+            if " gather(" in line and f"{SCOPE_PREFIX}/{scope}/" in line
+            and "jit(searchsorted)" not in line]
+
+
+@pytest.mark.parametrize("msg_words", [1, 8])
+def test_the_route_packs_without_reading_by_index(msg_words):
+    """The regression PR 41 took out: `_route` read `dest`, `tgt`,
+    `sender` and the words back through the sort's permutation and
+    padded every destination's block by a dense `[shards, bucket]`
+    gather. Now no gather runs under `pony/route/sort` (the sort carries
+    what was read back, the words of a wide message too) and none under
+    `pony/route/bucket` (a block is a slice)."""
+    world = _world(4, "random", actors=256, msg_words=msg_words)
+    text = _window_text(world.rt, compiled=True)
+    world.rt.stop()
+    assert f"{SCOPE_PREFIX}/route/bucket/dynamic_slice" in text
+    assert _route_gathers(text, "route/bucket") == []
+    assert _route_gathers(text, "route/sort") == []
+
+
 def test_a_mesh_says_its_shards_on_start_and_on_every_launch(recording):
     """`shards=` on `pony:start` and on a window's `pony:dispatching`."""
     world = _world(4, "cycle", actors=256)
