@@ -178,6 +178,11 @@ def phase_ubench(n: int, steps: int, mesh_shards: int = 1) -> dict:
     verify(steps + warm_steps)
     if mesh_shards > 1:
         spread_check(rt, mesh_shards)
+        # The cycle's arrivals fit one shard's outbox on every tick: each
+        # shard delivers over the received buckets joined front to front.
+        unpacked = rt.counter("n_unpacked")
+        check("every shard of every tick took the short delivery list",
+              unpacked == mesh_shards * rt.steps_run, f"{unpacked}")
     rt.stop()
     return {"setup_s": setup_s, "first_call_s": first_s, "rest_s": rest_s,
             "rest_steps": warm_steps}

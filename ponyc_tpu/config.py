@@ -273,7 +273,10 @@ class RuntimeOptions:
     #   entries. 0 = auto-size (state.layout_sizes): covers the worst
     #   case one-shard emission up to 4 shards; beyond that (or with an
     #   explicit smaller value) a saturated link parks messages in the
-    #   route spill and mutes senders — backpressure, not loss
+    #   route spill and mutes senders — backpressure, not loss. What a
+    #   tick pays for the buckets' padding is the pack, the exchange and
+    #   one count: delivery runs over what arrived whenever that fits
+    #   one shard's outbox (engine._route_unpack)
 
     def __post_init__(self):
         if self.mailbox_cap & (self.mailbox_cap - 1):

@@ -1056,6 +1056,70 @@ def _route_pack(tgt, sender, words, *, shards: int, n_local: int,
     return (dt, ts, ss, ws), (seg_start, cnt, acc), (bt, bs, bw, fill_f)
 
 
+def _unpack_fits(shards: int, bucket: int, l_in: int) -> bool:
+    """Whether a meshed shard's window holds the SHORT delivery list
+    beside the long one (build_step): only where the received buckets
+    are longer than what `_route_unpack` joins them into. One chip and
+    a small explicit `route_bucket` keep the one list they had. Static;
+    the seam the tests patch to get the long list alone."""
+    return shards > 1 and shards * bucket > l_in
+
+
+def _route_unpack(received: Entries, fill, *, shards: int, bucket: int,
+                  l_in: int) -> Entries:
+    """The received buckets joined front to front: `_route_pack` run
+    backwards. Block d of `received` (`[shards * bucket]`, words
+    `[w1, ...]`) holds `fill[d]` entries at its front, then -1 / -1 / 0,
+    so writing the blocks in order, block d at `sum(fill[:d])` of a
+    buffer of `l_in + bucket`, lets each block cover the pad of the one
+    before it: `shards - 1` contiguous copies, no read by index, and
+    block order is arrival order (FIFO). Returns the first `l_in`
+    entries — all of them where `sum(fill) <= l_in`, which is the
+    caller's to check (build_step's `fits`)."""
+    with phase_scope("route/unpack"):
+        start = jnp.cumsum(fill) - fill              # [shards]
+
+        def join(x, empty):
+            def block(d):
+                return lax.slice_in_dim(x, d * bucket, (d + 1) * bucket,
+                                        axis=-1)
+            out = jnp.pad(block(0), [(0, 0)] * (x.ndim - 1) + [(0, l_in)],
+                          constant_values=empty)
+            for d in range(1, shards):
+                out = lax.dynamic_update_slice_in_dim(
+                    out, block(d), start[d], axis=-1)
+            return lax.slice_in_dim(out, 0, l_in, axis=-1)
+        return Entries(tgt=join(received.tgt, -1),
+                       sender=join(received.sender, -1),
+                       words=join(received.words, 0))
+
+
+# The cached delivery plan keeps the LONG list's shape (RtState.plan_key
+# / plan_perm, state.layout_sizes' n_delivery_entries) and belongs to
+# one list length at a time. A tick over the short list compares and
+# stores its key and permutation in the first `e_short` entries and
+# marks the entry after them -1; no key is negative, so a long tick
+# never validates what a short one stored, and a short tick asks the
+# mark before it looks: the plan of one length never validates, and
+# never permutes, the other's list.
+
+def _short_plan(plan, e_short: int):
+    """(key, perm, bounds) for `deliver` over the short list: the
+    stored plan's front if a short tick stored it, else a key that
+    matches nothing."""
+    key, perm, bounds = plan
+    mine = key[e_short] < 0
+    return jnp.where(mine, key[:e_short], -1), perm[:e_short], bounds
+
+
+def _store_short_plan(plan, key_s, perm_s):
+    """The plan arrays after a tick over the short list."""
+    key, perm, _bounds = plan
+    marked = jnp.concatenate([key_s, jnp.full((1,), -1, key_s.dtype)])
+    return (lax.dynamic_update_slice(key, marked, (0,)),
+            lax.dynamic_update_slice(perm, perm_s, (0,)))
+
+
 def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
            rspill_cap: int, overload_occ, head, tail, shard_base,
            mute_slots: int, pressured_global, pressured_local,
@@ -1323,6 +1387,12 @@ def build_step(program: Program, opts: RuntimeOptions):
             program, opts.msg_words, mode="iso")
         route_blobs = bool(_blob_route_mask.any())
     e_out, bucket, _n_entries = layout_sizes(program, opts)
+    # What one shard can emit a tick (its route spill and its outbox) is
+    # what a balanced world hands it back: the length of the short
+    # delivery list's routed part (step 4 of the tick).
+    l_in = s_cap + e_out
+    short_list = _unpack_fits(p, bucket, l_in)
+    e_short = s_cap + opts.inject_slots + l_in
     # Delivery priority levels (see delivery.deliver): 0 = receiver
     # spill, 1 = host inject, 2+k = sender cohort with k-th highest
     # PRIORITY (≙ the fork's actor priority hint ordering contenders).
@@ -1363,6 +1433,11 @@ def build_step(program: Program, opts: RuntimeOptions):
         else:
             shard = jnp.int32(0)
         base = shard * nl
+
+        def local_rows(entries):
+            """Global target ids -> this shard's rows."""
+            return entries._replace(tgt=jnp.where(
+                entries.tgt >= 0, entries.tgt - base, -1))
         occ0 = st.tail - st.head
         # World bits (previous tick's mesh-wide vote, stored replicated
         # per shard): bit0 = any actor pressured anywhere, bit1 = any
@@ -1864,11 +1939,10 @@ def build_step(program: Program, opts: RuntimeOptions):
                 nb_remote = nb_remote + n_drop
             else:
                 nb_moved = jnp.int32(0)
-            incoming = incoming._replace(
-                tgt=jnp.where(incoming.tgt >= 0, incoming.tgt - base, -1))
+            if not short_list:
+                incoming = local_rows(incoming)
         else:
-            incoming = out_cat._replace(
-                tgt=jnp.where(out_cat.tgt >= 0, out_cat.tgt - base, -1))
+            incoming = local_rows(out_cat)
             new_rspill = Entries(st.rspill_tgt, st.rspill_sender,
                                  st.rspill_words)   # unused, stays empty
             rsp_count = st.rspill_count[0]
@@ -1878,54 +1952,117 @@ def build_step(program: Program, opts: RuntimeOptions):
         # --- 4. delivery list: receiver spill first (oldest), then host
         # injections, then routed messages. Injections are replicated to
         # all shards; each shard keeps only rows it owns.
-        inj_l = inject_tgt - base
-        inj_local = jnp.where((inj_l >= 0) & (inj_l < nl), inj_l, -1)
-        dspill_e = Entries(st.dspill_tgt, st.dspill_sender, st.dspill_words)
-        all_e = Entries(
-            tgt=jnp.concatenate([dspill_e.tgt, inj_local, incoming.tgt]),
-            sender=jnp.concatenate([dspill_e.sender,
-                                    jnp.full_like(inj_local, -1),
-                                    incoming.sender]),
-            words=jnp.concatenate([dspill_e.words, inject_words,
-                                   incoming.words], axis=1),
-        )
+        def delivery_list(incoming):
+            """`incoming` (local rows) behind the receiver spill and the
+            injections, and every entry's level."""
+            inj_l = inject_tgt - base
+            inj_local = jnp.where((inj_l >= 0) & (inj_l < nl), inj_l, -1)
+            dspill_e = Entries(st.dspill_tgt, st.dspill_sender,
+                               st.dspill_words)
+            all_e = Entries(
+                tgt=jnp.concatenate([dspill_e.tgt, inj_local,
+                                     incoming.tgt]),
+                sender=jnp.concatenate([dspill_e.sender,
+                                        jnp.full_like(inj_local, -1),
+                                        incoming.sender]),
+                words=jnp.concatenate([dspill_e.words, inject_words,
+                                       incoming.words], axis=1),
+            )
 
-        # The level of an incoming entry is its sender's cohort's: a
-        # constant of the program when it has one priority, and on one
-        # chip a constant of each segment of `incoming` (the route
-        # spill, empty there, then one outbox a cohort). Only a mesh
-        # with several priorities has to ask each entry for its sender.
-        if len(pri_sorted) <= 1:
-            lvl_in = jnp.full_like(incoming.tgt, 2)
-        elif p == 1:
-            lvl_in = jnp.concatenate(
-                [jnp.full_like(rspill_e.tgt, 2)]
-                + [jnp.full_like(o.tgt, 2 + pri_rank[ch.priority])
-                   for ch, o in zip(dev_cohorts, out_entries)])
+            # The level of an incoming entry is its sender's cohort's: a
+            # constant of the program when it has one priority, and on
+            # one chip a constant of each segment of `incoming` (the
+            # route spill, empty there, then one outbox a cohort). Only
+            # a mesh with several priorities has to ask each entry for
+            # its sender.
+            if len(pri_sorted) <= 1:
+                lvl_in = jnp.full_like(incoming.tgt, 2)
+            elif p == 1:
+                lvl_in = jnp.concatenate(
+                    [jnp.full_like(rspill_e.tgt, 2)]
+                    + [jnp.full_like(o.tgt, 2 + pri_rank[ch.priority])
+                       for ch, o in zip(dev_cohorts, out_entries)])
+            else:
+                prio_row = _np.zeros((nl,), _np.int32)
+                for ch in dev_cohorts:
+                    prio_row[ch.local_start:ch.local_stop] = \
+                        pri_rank[ch.priority]
+                snd_in = incoming.sender
+                srow = jnp.where(snd_in >= 0, snd_in, 0) % nl
+                lvl_in = jnp.where(snd_in >= 0,
+                                   2 + jnp.asarray(prio_row)[srow],
+                                   jnp.int32(2)).astype(jnp.int32)
+            lvl_all = jnp.concatenate([
+                jnp.zeros_like(dspill_e.tgt),
+                jnp.ones_like(inj_local),
+                lvl_in])
+            return all_e, lvl_all
+
+        def delivered(all_e, lvl_all, plan):
+            return deliver(st.buf, new_head, tail0, alive, all_e,
+                           n_local=nl, mailbox_cap=c, spill_cap=s_cap,
+                           overload_occ=opts.overload_occ, shard_base=base,
+                           cohort_layout=cohort_layout,
+                           mute_slots=opts.mute_slots,
+                           level=lvl_all, n_levels=n_levels, plan=plan,
+                           pressured=st.pressured,
+                           cosort=(opts.delivery == "cosort"),
+                           trace_buf=st.trace_buf if tracing else None)
+
+        plan = (st.plan_key, st.plan_perm, st.plan_bounds)
+        n_unpacked = jnp.int32(0)
+        if not short_list:
+            all_e, lvl_all = delivery_list(incoming)
+            phase("delivery")
+            res = delivered(all_e, lvl_all, plan)
         else:
-            prio_row = _np.zeros((nl,), _np.int32)
-            for ch in dev_cohorts:
-                prio_row[ch.local_start:ch.local_stop] = pri_rank[ch.priority]
-            snd_in = incoming.sender
-            srow = jnp.where(snd_in >= 0, snd_in, 0) % nl
-            lvl_in = jnp.where(snd_in >= 0,
-                               2 + jnp.asarray(prio_row)[srow],
-                               jnp.int32(2)).astype(jnp.int32)
-        lvl_all = jnp.concatenate([
-            jnp.zeros_like(dspill_e.tgt),
-            jnp.ones_like(inj_local),
-            lvl_in])
-        phase("delivery")
-        res = deliver(st.buf, new_head, tail0, alive, all_e,
-                      n_local=nl, mailbox_cap=c, spill_cap=s_cap,
-                      overload_occ=opts.overload_occ, shard_base=base,
-                      cohort_layout=cohort_layout,
-                      mute_slots=opts.mute_slots,
-                      level=lvl_all, n_levels=n_levels,
-                      plan=(st.plan_key, st.plan_perm, st.plan_bounds),
-                      pressured=st.pressured,
-                      cosort=(opts.delivery == "cosort"),
-                      trace_buf=st.trace_buf if tracing else None)
+            # A meshed shard delivers over what ARRIVED. The received
+            # buckets are `p * bucket` entries whatever came (at the
+            # default bucket four outboxes' worth for one outbox's worth
+            # of messages), and every list phase of delivery is paid by
+            # the entry. So the window holds delivery at two static
+            # lengths and the tick's arrivals choose: where they fit one
+            # shard's outbox (`l_in`: what a shard can emit is what a
+            # balanced world hands it back) the buckets are joined front
+            # to front (`_route_unpack`) and delivery runs over
+            # `e_short` entries; a tick that does not fit — a skewed
+            # one, a fan-in onto this shard — runs the list it always
+            # ran. Same mailboxes, tails, spill and mutes either way:
+            # delivery is stable in arrival order and sorts the invalid
+            # last. `deliver` holds no collective, so each shard takes
+            # its own branch.
+            with phase_scope("route/unpack"):
+                fill = jnp.sum(
+                    (incoming.tgt >= 0).reshape(p, bucket).astype(jnp.int32),
+                    axis=1)
+                fits = jnp.sum(fill) <= l_in
+            n_unpacked = fits.astype(jnp.int32)
+
+            def over(incoming, plan):
+                with phase_scope("route"):
+                    all_e, lvl_all = delivery_list(local_rows(incoming))
+                with phase_scope("delivery"):
+                    return delivered(all_e, lvl_all, plan)
+
+            def short(_):
+                joined = _route_unpack(incoming, fill, shards=p,
+                                       bucket=bucket, l_in=l_in)
+                with phase_scope("delivery/plan"):
+                    cached = _short_plan(plan, e_short)
+                res = over(joined, cached)
+                with phase_scope("delivery/plan"):
+                    key, perm = _store_short_plan(plan, res.plan_key,
+                                                  res.plan_perm)
+                return res._replace(plan_key=key, plan_perm=perm)
+
+            phase("delivery")
+            res = lax.cond(fits, short, lambda _: over(incoming, plan),
+                           operand=None)
+            if opts.analysis >= 1:
+                # phase_cost_lanes counts the list's valid entries, and
+                # the long list holds the same ones: built here for its
+                # targets alone, the rest of it is dead code.
+                all_e, _ = delivery_list(local_rows(incoming))
 
         phase("gc_mark")
         # --- 4b. apply destroys (≙ ponyint_actor_setpendingdestroy +
@@ -2264,7 +2401,8 @@ def build_step(program: Program, opts: RuntimeOptions):
             rspill_words=new_rspill.words,
             rspill_count=vec(rsp_count),
             route_counts=({name: vec(st.route_counts[name][0] + n)
-                           for name, n in zip(ROUTE_COUNTERS, routed)}
+                           for name, n in zip(ROUTE_COUNTERS,
+                                              (*routed, n_unpacked))}
                           if p > 1 else st.route_counts),
             spill_overflow=vec(overflow, jnp.bool_),
             exit_flag=vec(exit_f, jnp.bool_), exit_code=vec(exit_c),

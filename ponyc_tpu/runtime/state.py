@@ -85,11 +85,14 @@ N_PHASES = len(PHASE_NAMES)
 SCOPE_PREFIX = "pony"
 STEP_SCOPES = ("unmute", "spawn", "drain", "dispatch", "dispatch/heap",
                "route",
-               # a mesh only (engine._route): the destination sort and
-               # the reads by its permutation, the dense [shards,
-               # bucket] gathers, the all_to_alls, overflow + link mutes
+               # a mesh only (engine._route): the one sort by
+               # destination shard that carries the entries, a
+               # contiguous masked slice of them a destination, the
+               # all_to_alls, overflow + link mutes; then (build_step)
+               # the received buckets' fills and their joining front to
+               # front for the short delivery list
                "route/sort", "route/bucket", "route/exchange",
-               "route/spill",
+               "route/spill", "route/unpack",
                "delivery", "delivery/plan", "delivery/plan/bounds",
                "delivery/permute", "delivery/rebuild",
                "delivery/rebuild/compact", "delivery/pressure",
@@ -155,7 +158,12 @@ def layout_sizes(program: Program, opts: RuntimeOptions):
     bucket — per-destination all_to_all bucket (mesh only);
     n_delivery_entries — rows in one shard's delivery list
     (receiver-spill + host inject + incoming), which is also the length
-    of the cached delivery plan (see delivery.py)."""
+    of the cached delivery plan (see delivery.py). On a mesh that is
+    the LONG list, the received buckets as they come (`shards * bucket`
+    incoming), and the plan's length; where that is longer than one
+    shard's outbox a tick whose arrivals fit delivers over the short
+    list, `s + inject + e_out + s` entries (engine.build_step), and
+    keeps its plan in the front of the same arrays."""
     e_out = sum(ch.local_capacity * ch.batch * ch.max_sends
                 for ch in program.device_cohorts)
     s = opts.spill_cap
@@ -244,8 +252,10 @@ class RtState:
     # in an all_to_all bucket (a message counts once, in the tick it
     # ships: one parked in the route spill counts when its retry does);
     # "n_routed_remote" [P] int32 — those of them whose bucket went to
-    # ANOTHER shard. Read through Runtime.counter(), which sums them
-    # over the mesh like n_processed.
+    # ANOTHER shard; "n_unpacked" [P] int32 — the ticks on which this
+    # shard delivered over the short list (engine._route_unpack: what
+    # arrived fitted one shard's outbox). Read through
+    # Runtime.counter(), which sums them over the mesh like n_processed.
     route_counts: Dict[str, jnp.ndarray]
 
     spill_overflow: jnp.ndarray  # [P] bool — a spill overflowed (fatal)
@@ -404,7 +414,7 @@ class RtState:
 
 
 # The route's counters (RtState.route_counts), a mesh only.
-ROUTE_COUNTERS = ("n_routed", "n_routed_remote")
+ROUTE_COUNTERS = ("n_routed", "n_routed_remote", "n_unpacked")
 
 # The int32 word tables that serialise.save(packed=True) stores as an
 # int16 lane plane + an int32 escape plane: mailbox ring records, both

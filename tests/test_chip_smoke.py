@@ -46,6 +46,14 @@ def test_phase_mesh_spreads_state_over_four_devices():
     chip_smoke.phase_ring(16, 40, mesh_shards=4)
 
 
+def test_phase_mesh_ubench_takes_the_short_delivery_list(capsys):
+    """Phase (e)'s ubench: cycle traffic at the program's own bucket.
+    The phase itself checks that every shard of every tick delivered
+    over the short list (`n_unpacked`)."""
+    chip_smoke.phase_ubench(256, 8, mesh_shards=4)
+    assert "took the short delivery list: ok" in capsys.readouterr().out
+
+
 def test_a_failed_check_raises_and_entry_point_refuses_cpu(capsys):
     """Any failed check ends the run (no phase is wrapped in a try that
     continues to exit 0), and on anything but a TPU the entry point

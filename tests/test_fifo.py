@@ -147,6 +147,13 @@ CONFIGS = [
                                spill_cap=4096, inject_slots=32,
                                mesh_shards=4, route_bucket=8,
                                quiesce_interval=2)),
+    # PR 42: the program's own bucket. Every shard of every tick
+    # delivers over the SHORT list (the received buckets joined front
+    # to front, engine._route_unpack), with the receiver spill's retry
+    # and the mutes at work on it.
+    ("mesh4-default-bucket", dict(mailbox_cap=2, batch=1, max_sends=3,
+                                  spill_cap=4096, inject_slots=32,
+                                  mesh_shards=4, quiesce_interval=2)),
     ("fused-kernel", dict(mailbox_cap=4, batch=2, max_sends=3,
                           spill_cap=2048, inject_slots=16,
                           pallas_fused=True)),
@@ -177,8 +184,14 @@ CONFIGS = [
 @pytest.mark.parametrize("name,okw", CONFIGS, ids=[c[0] for c in CONFIGS])
 def test_per_edge_fifo(name, okw):
     okw = dict(okw)
-    run_fifo(seed=101, chains=okw.pop("chains", 1), hot=okw.pop("hot", None),
-             n_cons=okw.pop("n_cons", 6), okw=okw)
+    rt = run_fifo(seed=101, chains=okw.pop("chains", 1),
+                  hot=okw.pop("hot", None), n_cons=okw.pop("n_cons", 6),
+                  okw=okw)
+    if name == "mesh4-default-bucket":
+        ticks = np.asarray(rt.state.step_no)
+        np.testing.assert_array_equal(
+            np.asarray(rt.state.route_counts["n_unpacked"]), ticks)
+        assert rt.counter("n_rejected") > 0 and rt.counter("n_mutes") > 0
 
 
 def test_per_edge_fifo_more_seeds_tiny():

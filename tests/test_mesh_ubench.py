@@ -31,7 +31,7 @@ from test_run_loop import recording  # noqa: F401  (a fixture)
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ACTORS, TICKS = 1024, 12
 ROUTE_SCOPES = ("route/sort", "route/bucket", "route/exchange",
-                "route/spill")
+                "route/spill", "route/unpack")
 
 
 def _world(shards, recipients, seed=7, actors=ACTORS, **options):
@@ -80,8 +80,10 @@ def test_every_actor_follows_the_reference_on_any_layout(shards, recipients):
         assert off.size == 0, (tick, off[:8], counts[off[:8]], ref[off[:8]])
         # the route's counters are the reference's sends and crossings
         # (nothing is routed, and nothing counted, on one shard)
-        expect = (int(sent[:tick + 1].sum()), int(remote[:tick + 1].sum())) \
-            if shards > 1 else (0, 0)
+        # and at the program's own bucket every shard of every tick
+        # delivers over what arrived, the short list (`n_unpacked`)
+        expect = (int(sent[:tick + 1].sum()), int(remote[:tick + 1].sum()),
+                  shards * (tick + 1)) if shards > 1 else (0, 0, 0)
         assert routed == expect, (tick, routed, expect)
         assert spilled == 0, tick
     assert not any(errors.values()), errors
@@ -143,8 +145,8 @@ def _window_text(rt, compiled=False):
 
 
 def test_route_scopes_and_counters_exist_on_a_mesh_only():
-    """A mesh's window names the route's four parts and its state holds
-    the two counters; a one-shard window has no operation under
+    """A mesh's window names the route's five parts and its state holds
+    the three counters; a one-shard window has no operation under
     `pony/route/*` and no such leaf: its inputs are the parent's."""
     world = _world(4, "random", actors=256)
     text = _window_text(world.rt)
@@ -161,6 +163,7 @@ def test_route_scopes_and_counters_exist_on_a_mesh_only():
     assert world.rt.state.route_counts == {}
     assert world.rt.counter("n_routed") == 0
     assert world.rt.counter("n_routed_remote") == 0
+    assert world.rt.counter("n_unpacked") == 0
     world.rt.stop()
 
 

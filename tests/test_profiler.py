@@ -586,14 +586,14 @@ def test_phase_scopes_name_the_lowered_window(delivery, cap):
     elsewhere = ("gc_mark", "dispatch/heap", "spawn/free", "spawn/reserve",
                  "spawn/claim", "gc_mark/roots", "gc_mark/hop",
                  "gc_mark/sweep", "route/sort", "route/bucket",
-                 "route/exchange", "route/spill") + (
+                 "route/exchange", "route/spill", "route/unpack") + (
                      () if cap > 8 else ("delivery/rebuild/compact",))
     missing = [s for s in STEP_SCOPES if s not in elsewhere
                and f"{SCOPE_PREFIX}/{s}/" not in text]
     assert not missing, missing
     assert f"{SCOPE_PREFIX}/dispatch/heap" not in text
     assert f"{SCOPE_PREFIX}/route/" in text
-    for sub in ("sort", "bucket", "exchange", "spill"):
+    for sub in ("sort", "bucket", "exchange", "spill", "unpack"):
         assert f"{SCOPE_PREFIX}/route/{sub}" not in text, sub
     in_body = "rebuild/while/body/pony/delivery/rebuild/"
     assert (in_body in text) == (cap > 8)
