@@ -269,7 +269,7 @@ def bench_ubench(args):
 
 def bench_telemetry(args, delivery="plan", fused=False):
     """One headline-shaped pass at analysis=1: the per-behaviour
-    profiler (engine.profile_lanes / Runtime.profile()) attributes the
+    profiler (lanes.profile_lanes / Runtime.profile()) attributes the
     run so the BENCH json records WHERE the ticks went, not just
     totals — per-behaviour runs, queue-wait percentiles, gc passes.
     Runs after the timed pass on its own runtime (analysis is a

@@ -18,7 +18,7 @@ step when analysis >= 1) cost nothing observable.
             runs/deliveries/rejects per behaviour, queue-wait latency
             histograms and mute-ticks per cohort, GC window stats —
             ≙ the fork's per-actor records, computed in the jitted step
-            by engine.profile_lanes and fetched only at boundaries)
+            by lanes.profile_lanes and fetched only at boundaries)
   level 2 — level 1 + one CSV row per quiesce window to
             RuntimeOptions.analysis_path via a writer thread
             (≙ analysis.c:41-167 thread + CSV format); the window CSV
@@ -100,7 +100,7 @@ def hist_percentile(hist, q: float) -> int:
 
 
 # Level-3 per-event lane (≙ analysis.h:16-31 event enum; the device
-# records transition events in a bounded ring, engine.py §5b).
+# records transition events in a bounded ring, lanes.event_ring, step 5b).
 EVENT_NAMES = {1: "MUTE", 2: "UNMUTE", 3: "OVERLOAD", 4: "SPAWN",
                5: "DESTROY", 6: "ERROR"}
 EVENT_COLUMNS = ["time_ms", "step", "event", "actor"]
@@ -133,7 +133,7 @@ class Analysis:
                            for c in (f"qw50:{n}", f"qw99:{n}")]
                         # Per-phase window telemetry (ISSUE 19): one
                         # work-unit delta column per scheduler phase
-                        # (engine.phase_cost_lanes).
+                        # (lanes.phase_cost_lanes).
                         + [f"ph:{n}" for n in PHASE_NAMES])
         self._prev_hist = np.zeros((len(self.dev_names), QW_BUCKETS),
                                    np.int64)

@@ -259,7 +259,7 @@ class RuntimeOptions:
     # host. 0 = disabled (all blob plumbing compiles away). ---
     blob_slots: int = 0            # pool slots PER SHARD; handles carry
     #   (generation, global slot id) — ops/pack.py encoding. On a mesh a
-    #   blob MIGRATES with its routed message (engine._route); host
+    #   blob MIGRATES with its routed message (route._route); host
     #   injections bypass routing, so host payloads should allocate on
     #   the receiver's shard (Runtime.blob_store(near=...)) — an
     #   undereferenceable arrival reads null and counts in
@@ -276,7 +276,7 @@ class RuntimeOptions:
     #   route spill and mutes senders — backpressure, not loss. What a
     #   tick pays for the buckets' padding is the pack, the exchange and
     #   one count: delivery runs over what arrived whenever that fits
-    #   one shard's outbox (engine._route_unpack)
+    #   one shard's outbox (route._route_unpack)
 
     def __post_init__(self):
         if self.mailbox_cap & (self.mailbox_cap - 1):

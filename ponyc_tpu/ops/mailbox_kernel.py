@@ -5,7 +5,7 @@
 BASELINE.json's north star names ("behaviour dispatch ... as a
 vmapped/Pallas kernel").
 
-The XLA path (engine._ring_take) drains `batch` ring slots per actor
+The XLA path (state.ring_take) drains `batch` ring slots per actor
 with a static select chain per slot: `batch` separate fusions over the
 [cap, w1, N] mailbox block, each re-reading the block from HBM when the
 fusion boundary falls badly. This kernel makes the blocking explicit:

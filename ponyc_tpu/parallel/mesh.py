@@ -5,7 +5,7 @@ The reference scales by adding scheduler threads over cores
 this framework scales by sharding the actor-row axis of every runtime
 array over a 1-D `jax.sharding.Mesh` axis named 'actors'. Messages whose
 target lives on another shard ride one `lax.all_to_all` per tick
-(engine._route) — ICI between chips of a slice, DCN between hosts, with
+(route._route) — ICI between chips of a slice, DCN between hosts, with
 XLA choosing the transport (the reference's lock-free queues have no
 cross-process analog; this is the distributed communication backend built
 in its place).

@@ -247,7 +247,7 @@ class Program:
         free rows; within the window, each actor that can DISPATCH this
         tick (runnable and holding a message: only a dispatch can spawn)
         gets spawn_dispatches × sites disjoint slots (ranked by a cumsum
-        over that mask at step time, engine.py cohort_resv), so
+        over that mask at step time, spawn.py cohort_resv), so
         concurrent vmapped spawns can never collide while idle actors —
         a parent waiting for its children, garbage the collector has not
         reached — reserve nothing. What the NEXT tick's windows will

@@ -86,7 +86,7 @@ within one shard of the actor world:
      slower memory and the tick read slower (PERF.md §6, PR 32), so two
      reads by the target shipped. Who was muted is read off the ref
      table the triggers were scattered into, not scattered a second
-     time. The unmute pass (engine.py `muter_bits`) reads its muting
+     time. The unmute pass (mute.py `muter_bits`) reads its muting
      receivers the same way: one status word a row, gathered once by
      the mute refs.
 """
@@ -106,7 +106,7 @@ from .state import phase_scope
 
 class Entries(NamedTuple):
     """A flat batch of in-flight messages (targets in *local rows* here;
-    the routing layer in engine.py deals in global ids)."""
+    the routing layer in route.py deals in global ids)."""
     tgt: jnp.ndarray      # [E] int32 target row; -1 = empty slot
     sender: jnp.ndarray   # [E] int32 sender *global* id; -1 = host/no sender
     words: jnp.ndarray    # [1+W, E] int32 (word0 = behaviour gid)

@@ -33,6 +33,7 @@ jit) and meshed execution (shard_map over an 'actors' axis); per-shard
 
 from __future__ import annotations
 
+from collections import namedtuple
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
@@ -42,12 +43,12 @@ from jax import lax
 from ..api import Context
 from ..config import RuntimeOptions
 from ..ops import pack
-from ..ops.segment import (compact_mask, counts_by_key, marks_of,
-                           stable_sort_carrying)
 from ..program import Cohort, Program
-from .delivery import Entries, deliver, empty_mute_slots, mute_ref_slots
-from .state import (PHASE_NAMES, QW_BUCKETS, ROUTE_COUNTERS, PhaseCursor,
-                    RtState, layout_sizes, phase_scope, pool_index)
+from . import lanes, mute, route, spawn
+from .delivery import Entries
+from .gc import build_blob_arg_mask
+from .state import (ROUTE_COUNTERS, PhaseCursor, RtState, TickStatic,
+                    phase_scope, ring_take)
 
 
 class StepAux(NamedTuple):
@@ -104,19 +105,6 @@ class StepAux(NamedTuple):
     #   int32 — the least room >= 0 any tick of the run has left: how
     #   near the world came to a refused spawn; "spawned" int32 —
     #   *cumulative* device spawns (the state's n_spawned, mesh-wide).
-
-
-def _ring_take(buf_rows, slot):
-    """Pull ring-slot `slot[r]` of every actor r: [cap, w1, R] × [R] →
-    [w1, R]. The per-lane index varies only over the small static `cap`
-    axis, so a static select chain keeps every op a full-width vector op
-    (a gather along a tiny major axis would defeat the lane layout —
-    see state.py's layout note)."""
-    cap = buf_rows.shape[0]
-    out = buf_rows[0]
-    for c in range(1, cap):
-        out = jnp.where((slot == c)[None, :], buf_rows[c], out)
-    return out
 
 
 def _bcast_lanes(v, dtype, lanes: int):
@@ -371,279 +359,6 @@ def _make_branch(bdef, msg_words: int, max_sends: int, field_dtypes,
 
     return branch
 
-
-def _qwait_bucket(delta):
-    """Power-of-two bucket index of a queue-wait delta (in ticks):
-    bucket k ↔ [2^k, 2^(k+1)) with deltas clipped to >= 1 and the last
-    bucket open-ended — floor(log2) spelled as QW_BUCKETS-1 vector
-    compares, which XLA fuses into the surrounding reductions."""
-    d = jnp.maximum(delta, 1)
-    b = jnp.zeros(d.shape, jnp.int32)
-    for k in range(1, QW_BUCKETS):
-        b = b + (d >= (1 << k)).astype(jnp.int32)
-    return b
-
-
-def profile_lanes(program: Program, opts: RuntimeOptions, st: RtState,
-                  tail0, res, drain_facts, muted2):
-    """The per-behaviour profiler lanes (≙ the fork's per-actor
-    --ponyanalysis records, analysis.h:16-31, re-based on the cohort —
-    the TPU unit of attribution). ONLY traced when opts.analysis >= 1:
-    the caller gates the call itself, so at level 0 none of this exists
-    in the jaxpr (the zero-cost test traps this function to prove it).
-
-    All facts are recomputed from the ring head/tail advances rather
-    than threaded out of the dispatch kernels, so ONE implementation
-    covers both dispatch formulations (the XLA scan and the fused
-    Pallas kernel) and their semantics cannot drift:
-
-      - beh_runs[g]       += messages of behaviour g dispatched this
-                             tick (ring slots [head0, head1) — the
-                             drained prefix, yield-shortened included);
-      - qwait_hist[c*QW+k] += dispatched messages of device cohort c
-                             whose delivery→dispatch wait fell in
-                             bucket k (deltas against the qwait_enq
-                             stamps written at delivery);
-      - coh_mute_ticks[c] += actors of device cohort c muted at end of
-                             tick (actor-ticks: the integral of
-                             muted_now);
-      - beh_delivered[g]  += messages of behaviour g accepted into
-                             mailboxes this tick (tail advance over the
-                             post-delivery tables; host cohorts count —
-                             the host drains those rows);
-      - beh_rejected[g]   += this tick's capacity rejections by target
-                             behaviour (the compacted spill's gid
-                             words — per-tick semantics match
-                             n_rejected: a parked message re-rejected
-                             next tick counts again);
-      - qwait_enq[type]    = enqueue-step stamps for freshly delivered
-                             ring slots (read back by the next ticks'
-                             deltas above).
-
-    `drain_facts` = [(cohort, head_before, head_after)] in
-    device-cohort order. Returns the six updated state fields."""
-    cap = opts.mailbox_cap
-    s_now = st.step_no[0]
-    beh_runs = st.beh_runs
-    beh_del = st.beh_delivered
-    beh_rej = st.beh_rejected
-    coh_mt = st.coh_mute_ticks
-    qw_hist = st.qwait_hist
-    qw_enq = dict(st.qwait_enq)
-    ci = jnp.arange(cap, dtype=jnp.int32)[:, None]   # ring-slot planes
-
-    def _count(mask):
-        return jnp.sum(mask.astype(jnp.int32))
-
-    # --- dispatch side: runs per behaviour + queue-wait histogram.
-    for di, (ch, head0, head1) in enumerate(drain_facts):
-        cname = ch.atype.__name__
-        n_con = head1 - head0
-        # Ring slot ci held a message drained this tick iff its
-        # monotonic count fell in [head0, head0 + n_con).
-        drained = ((ci - head0[None, :]) % cap) < n_con[None, :]
-        gid = st.buf[cname][:, 0, :]                 # [cap, rows]
-        for b in ch.behaviours:
-            beh_runs = beh_runs.at[b.global_id].add(
-                _count(drained & (gid == b.global_id)))
-        bidx = _qwait_bucket(s_now - qw_enq[cname])
-        for k in range(QW_BUCKETS):
-            qw_hist = qw_hist.at[di * QW_BUCKETS + k].add(
-                _count(drained & (bidx == k)))
-        coh_mt = coh_mt.at[di].add(
-            _count(muted2[ch.local_start:ch.local_stop]))
-
-    # --- delivery side: acceptances per behaviour + enqueue stamps.
-    for ch in program.cohorts:
-        cname = ch.atype.__name__
-        s0, s1 = ch.local_start, ch.local_stop
-        n_new = res.tail[s0:s1] - tail0[s0:s1]
-        fresh = ((ci - tail0[None, s0:s1]) % cap) < n_new[None, :]
-        gid = res.buf[cname][:, 0, :]
-        for b in ch.behaviours:
-            beh_del = beh_del.at[b.global_id].add(
-                _count(fresh & (gid == b.global_id)))
-        if cname in qw_enq:                          # device cohorts
-            qw_enq[cname] = jnp.where(fresh, s_now, qw_enq[cname])
-
-    # --- rejects by target behaviour (the compacted spill is exactly
-    # this tick's rejections, re-rejections of parked entries included).
-    sp_gid = res.spill.words[0]
-    sp_ok = res.spill.tgt >= 0
-    for g in range(len(program.behaviour_table)):
-        beh_rej = beh_rej.at[g].add(_count(sp_ok & (sp_gid == g)))
-
-    return beh_runs, beh_del, beh_rej, coh_mt, qw_hist, qw_enq
-
-
-def phase_cost_lanes(st: RtState, all_e, drain_facts, nproc_total,
-                     n_spawned, n_destroyed, rebuild_slots):
-    """Per-phase window telemetry (the device-cost observatory, ISSUE
-    19): accumulate one deterministic work-unit tally per scheduler-tick
-    phase into st.phase_cost (state.PHASE_NAMES order). ONLY traced when
-    opts.analysis >= 1 — the caller gates the call itself, so at level 0
-    none of this exists in the jaxpr (the zero-cost test traps this
-    function exactly like profile_lanes).
-
-    The tallies are recomputed from facts every dispatch formulation
-    already produces (the profile_lanes recomputation trick), so the
-    lanes are bit-identical whichever formulation ran:
-
-      - delivery += valid delivery-list entries gathered this tick
-                    (spill retries + host injections + routed sends);
-      - drain    += mailbox ring slots consumed (head advances, the
-                    yield-shortened prefix included — >= dispatch:
-                    drained-but-dropped badmsg rows count here only);
-      - dispatch += behaviours actually run (the n_processed increment);
-      - gc_mark  += spawn/destroy bookkeeping rows touched (claimed
-                    spawns + completed destroys — the slot-lifecycle
-                    work the GC pass marks from);
-      - rebuild  += indices the delivery rebuild's gathers read: over
-                    the cohorts and the rank blocks each ran (as deep as
-                    its own fullest mailbox of the tick), 8 ranks x the
-                    cohort's rows a full-width block; 8 ranks x M a
-                    compacted one, M = ceil(rows / 8), which a block is
-                    from the first whose rows with a message in it fit
-                    in M (delivery.rebuild_tables).
-
-    Work units, not wall time: wall/bytes attribution is the measured
-    layer's job (costs.py)."""
-    pc = st.phase_cost
-    delivery = jnp.sum((all_e.tgt >= 0).astype(jnp.int32))
-    drained = jnp.int32(0)
-    for _ch, head0, head1 in drain_facts:
-        drained = drained + jnp.sum(head1 - head0)
-    pc = pc.at[PHASE_NAMES.index("delivery")].add(delivery)
-    pc = pc.at[PHASE_NAMES.index("drain")].add(drained)
-    pc = pc.at[PHASE_NAMES.index("dispatch")].add(nproc_total)
-    pc = pc.at[PHASE_NAMES.index("gc_mark")].add(n_spawned + n_destroyed)
-    pc = pc.at[PHASE_NAMES.index("rebuild")].add(rebuild_slots)
-    return pc
-
-
-def trace_span_lanes(program: Program, opts: RuntimeOptions, st: RtState,
-                     drain_facts, base, shard):
-    """Causal-tracing lanes (PROFILE.md §10; ≙ the fork's per-event
-    analysis rows following one message send→dispatch,
-    analysis.c:587-692 — per MESSAGE here, where profile_lanes is per
-    aggregate). ONLY traced when opts.tracing: the caller gates the
-    call itself, so with tracing off none of this exists in the jaxpr
-    (tests/test_tracing.py traps this function to prove it).
-
-    Works entirely from the ring-advance facts (profile_lanes'
-    recomputation trick), so ONE implementation covers both dispatch
-    formulations (the XLA scan and the fused Pallas kernel) and both
-    delivery formulations (plan and cosort):
-
-      - every drained ring slot whose trace_id side lane is >= 0
-        becomes a SPAN: a fresh even span id from the per-shard
-        monotonic counter (host spans are odd — tracing.py owns the
-        scheme), recorded in the bounded span ring as (trace_id,
-        span_id, parent_span, behaviour_gid, actor_gid, enqueue_tick
-        [the qwait_enq delivery stamp], dispatch_tick, retire_tick);
-        overflow between two host drains drops and counts;
-      - outbox PROPAGATION rows: entry (b, m, r) of the cohort's
-        outbox inherits (trace_id, span_id) of the message batch slot
-        b dispatched on lane r — sends AND spawns (constructor
-        messages ride the same outbox) continue the causal chain; the
-        rows-minor [batch, ms, rows] flatten matches both the scan's
-        stack and the fused kernel's layout, so neither dispatch path
-        needs to know tracing exists.
-
-    `drain_facts` = [(cohort, head_before, head_after)] in
-    device-cohort order. Returns (span_data, span_count, span_dropped,
-    span_next, [per-cohort [2, e_c] propagation rows])."""
-    cap = opts.mailbox_cap
-    p = program.shards
-    ts_cap = opts.trace_slots
-    s_now = st.step_no[0]
-    span_data = st.span_data
-    span_count = st.span_count[0]
-    span_dropped = st.span_dropped[0]
-    span_next = st.span_next[0]
-    ci = jnp.arange(cap, dtype=jnp.int32)[:, None]
-    tr_out = []
-    for (ch, head0, head1) in drain_facts:
-        cname = ch.atype.__name__
-        rows = ch.local_capacity
-        batch, ms = ch.batch, ch.max_sends
-        n_con = head1 - head0
-        drained = ((ci - head0[None, :]) % cap) < n_con[None, :]
-        tid = st.trace_buf[cname][:, 0, :]            # [cap, rows]
-        tparent = st.trace_buf[cname][:, 1, :]
-        traced = drained & (tid >= 0)
-        e = rows * batch * ms
-
-        def busy(_):
-            """Span allocation + ring write + propagation — runs under
-            a cond so ticks where this COHORT dispatched no traced
-            message skip the compaction sort and scatters entirely
-            (the ev-ring discipline, §5b: the structural cost of
-            tracing scales with traced traffic, not with enabling the
-            knob)."""
-            sd = span_data
-            flat = traced.reshape(-1)                 # cap-major order
-            rank = jnp.cumsum(flat.astype(jnp.int32)) - 1
-            total = jnp.sum(flat.astype(jnp.int32))
-            sid_flat = jnp.where(
-                flat, ((span_next + rank) * p + shard) * 2 + 2,
-                jnp.int32(0))
-            k_sp = min(ts_cap, cap * rows)
-            perm, valid2, _tot = compact_mask(flat, k_sp)
-            pos = span_count + jnp.arange(k_sp, dtype=jnp.int32)
-            ok = valid2 & (pos < ts_cap)
-            posc = jnp.where(ok, pos, ts_cap)
-            actor = jnp.broadcast_to(
-                (base + ch.local_start
-                 + jnp.arange(rows, dtype=jnp.int32))[None, :],
-                (cap, rows)).reshape(-1)
-            vals = (tid.reshape(-1), sid_flat, tparent.reshape(-1),
-                    st.buf[cname][:, 0, :].reshape(-1), actor,
-                    st.qwait_enq[cname].reshape(-1),
-                    jnp.broadcast_to(s_now, (cap * rows,)),
-                    jnp.broadcast_to(s_now + 1, (cap * rows,)))
-            for ri, v in enumerate(vals):
-                sd = sd.at[ri, posc].set(
-                    jnp.where(ok, v[perm], 0), mode="drop")
-            # --- propagation rows for this cohort's outbox.
-            sid = sid_flat.reshape(cap, rows)
-            tid_b, sid_b = [], []
-            for b in range(batch):
-                slot = (head0 + b) % cap
-                tb, sb = tid[0], sid[0]
-                for cslot in range(1, cap):   # static select chain,
-                    sel = slot == cslot       # like _ring_take
-                    tb = jnp.where(sel, tid[cslot], tb)
-                    sb = jnp.where(sel, sid[cslot], sb)
-                okb = (b < n_con) & (tb >= 0)
-                tid_b.append(jnp.where(okb, tb, jnp.int32(-1)))
-                sid_b.append(jnp.where(okb, sb, jnp.int32(0)))
-            if ms:
-                tid_e = jnp.broadcast_to(
-                    jnp.stack(tid_b)[:, None, :],
-                    (batch, ms, rows)).reshape(e)
-                sid_e = jnp.broadcast_to(
-                    jnp.stack(sid_b)[:, None, :],
-                    (batch, ms, rows)).reshape(e)
-            else:
-                tid_e = jnp.full((0,), -1, jnp.int32)
-                sid_e = jnp.zeros((0,), jnp.int32)
-            return (sd,
-                    jnp.minimum(span_count + total, ts_cap),
-                    span_dropped + jnp.maximum(
-                        0, span_count + total - ts_cap),
-                    span_next + total,
-                    jnp.stack([tid_e, sid_e]))
-
-        def quiet(_):
-            return (span_data, span_count, span_dropped, span_next,
-                    jnp.stack([jnp.full((e,), -1, jnp.int32),
-                               jnp.zeros((e,), jnp.int32)]))
-
-        (span_data, span_count, span_dropped, span_next,
-         tr_pair) = lax.cond(jnp.any(traced), busy, quiet, operand=None)
-        tr_out.append(tr_pair)
-    return span_data, span_count, span_dropped, span_next, tr_out
 
 
 def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
@@ -926,7 +641,7 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
             else:
                 with phase_scope("drain"):
                     msgs = jnp.stack(
-                        [_ring_take(buf_rows, (head_rows + k) % cap)
+                        [ring_take(buf_rows, (head_rows + k) % cap)
                          for k in range(batch)])        # [batch, w1, rows]
                     valids = (jnp.arange(batch, dtype=jnp.int32)[:, None]
                               < n_run[None, :])         # [batch, rows]
@@ -1006,357 +721,371 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
     return run_cohort
 
 
-def _route_pack(tgt, sender, words, *, shards: int, n_local: int,
-                bucket: int):
-    """One shard's entries `[route spill, outbox]` → its all-to-all
-    buckets, with no read by index:
-
-      sorted   (dt, ts, ss, ws): destination shard, target, sender and
-               the `[w1, e]` words in ONE stable sort by destination
-               (`dt` = `shards` for the invalid tail), so equal
-               destinations keep their order: FIFO
-      segments (seg_start, cnt, acc), `[shards]`: where a destination's
-               run starts in the sorted entries, how long it is, and
-               how much of it fits the bucket
-      buckets  (bt, bs, bw, fill_f), `[shards * bucket]` / `[w1, ...]`:
-               block d holds entries `seg_start[d] + j`, `j < acc[d]`,
-               then -1 / -1 / 0: a contiguous slice of the sorted
-               entries, masked
-    """
-    with phase_scope("route/sort"):
-        valid = tgt >= 0
-        dest = jnp.where(valid, tgt // n_local, shards).astype(jnp.int32)
-        dt, ts, ss, *rows = stable_sort_carrying(
-            dest, tgt, sender, *(words[i] for i in range(words.shape[0])))
-        ws = jnp.stack(rows)                         # [w1, E] planar
-    with phase_scope("route/bucket"):
-        # Per-destination segment bounds via binary search; a
-        # destination's block is then `bucket` consecutive sorted
-        # entries from its segment's start. `dynamic_slice` clamps its
-        # start so that the slice fits, so the sorted entries are padded
-        # by a bucket: entry `seg_start[d] + j` stays at slot j. What
-        # lies past `acc[d]` (the next segments, the pad) is masked.
-        bounds = jnp.searchsorted(
-            dt, jnp.arange(shards + 1, dtype=jnp.int32),
-            side="left").astype(jnp.int32)
-        seg_start = bounds[:-1]
-        cnt = bounds[1:] - seg_start                 # [shards]
-        acc = jnp.minimum(cnt, bucket)
-        j = jnp.arange(bucket, dtype=jnp.int32)
-        fill = j[None, :] < acc[:, None]             # [shards, bucket]
-        fill_f = fill.reshape(shards * bucket)
-
-        def blocks(x, empty):
-            xp = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, bucket)])
-            return jnp.concatenate([
-                jnp.where(fill[d], lax.dynamic_slice_in_dim(
-                    xp, seg_start[d], bucket, axis=-1), empty)
-                for d in range(shards)], axis=-1)
-        bt, bs, bw = blocks(ts, -1), blocks(ss, -1), blocks(ws, 0)
-    return (dt, ts, ss, ws), (seg_start, cnt, acc), (bt, bs, bw, fill_f)
+def tick_static(program: Program, opts: RuntimeOptions) -> TickStatic:
+    """Everything a tick knows before it is traced, worked out once."""
+    dev_cohorts = program.device_cohorts
+    blob_route = None
+    if opts.blob_slots > 0 and program.shards > 1:
+        mask = build_blob_arg_mask(program, opts.msg_words)
+        if mask.any():
+            blob_route = (mask, build_blob_arg_mask(
+                program, opts.msg_words, mode="iso"))
+    pri_sorted = sorted({ch.priority for ch in dev_cohorts}, reverse=True)
+    return TickStatic(
+        program=program, opts=opts, p=program.shards, nl=program.n_local,
+        c=opts.mailbox_cap, fh=program.first_host_row,
+        s_cap=opts.spill_cap, lists=route.list_sizes(program, opts),
+        pri_rank={pv: i for i, pv in enumerate(pri_sorted)},
+        n_levels=2 + max(1, len(pri_sorted)),
+        cohort_layout=tuple(
+            (ch.atype.__name__, ch.local_start, ch.local_stop,
+             1 + ch.msg_words) for ch in program.cohorts),
+        blob_route=blob_route,
+        dispatchers=tuple(
+            (_cohort_dispatch(ch, opts, opts.noyield, program), ch)
+            for ch in dev_cohorts))
 
 
-def _unpack_fits(shards: int, bucket: int, l_in: int) -> bool:
-    """Whether a meshed shard's window holds the SHORT delivery list
-    beside the long one (build_step): only where the received buckets
-    are longer than what `_route_unpack` joins them into. One chip and
-    a small explicit `route_bucket` keep the one list they had. Static;
-    the seam the tests patch to get the long list alone."""
-    return shards > 1 and shards * bucket > l_in
+# What step 2 leaves. `head`: [nl] heads after the drains; `out_entries`:
+# one outbox a device cohort; `claim_lists` / `init_lists`: target type ->
+# the refs each spawner cohort claimed and their sync-init values;
+# `destroy_rows` / `error_rows`: (s0, [rows] bool) and (s0, ([rows] bool,
+# codes)) a cohort; `drain_facts`: (cohort, head before, head after),
+# for the profiler lanes (lanes.profile_lanes) when analysis >= 1.
+Dispatched = namedtuple(
+    "Dispatched", "type_state head out_entries claim_lists init_lists "
+    "destroy_rows error_rows exit_f exit_c spawn_fail nproc nbad drain_facts "
+    "pool")
+# The rows after the tick's destroys and errors (step 4b).
+Lifecycle = namedtuple(
+    "Lifecycle", "alive head muted mute_refs mute_ovf pinned pressured "
+    "last_error last_error_loc n_errors n_destroyed")
 
 
-def _route_unpack(received: Entries, fill, *, shards: int, bucket: int,
-                  l_in: int) -> Entries:
-    """The received buckets joined front to front: `_route_pack` run
-    backwards. Block d of `received` (`[shards * bucket]`, words
-    `[w1, ...]`) holds `fill[d]` entries at its front, then -1 / -1 / 0,
-    so writing the blocks in order, block d at `sum(fill[:d])` of a
-    buffer of `l_in + bucket`, lets each block cover the pad of the one
-    before it: `shards - 1` contiguous copies, no read by index, and
-    block order is arrival order (FIFO). Returns the first `l_in`
-    entries — all of them where `sum(fill) <= l_in`, which is the
-    caller's to check (build_step's `fits`)."""
-    with phase_scope("route/unpack"):
-        start = jnp.cumsum(fill) - fill              # [shards]
-
-        def join(x, empty):
-            def block(d):
-                return lax.slice_in_dim(x, d * bucket, (d + 1) * bucket,
-                                        axis=-1)
-            out = jnp.pad(block(0), [(0, 0)] * (x.ndim - 1) + [(0, l_in)],
-                          constant_values=empty)
-            for d in range(1, shards):
-                out = lax.dynamic_update_slice_in_dim(
-                    out, block(d), start[d], axis=-1)
-            return lax.slice_in_dim(out, 0, l_in, axis=-1)
-        return Entries(tgt=join(received.tgt, -1),
-                       sender=join(received.sender, -1),
-                       words=join(received.words, 0))
-
-
-# The cached delivery plan keeps the LONG list's shape (RtState.plan_key
-# / plan_perm, state.layout_sizes' n_delivery_entries) and belongs to
-# one list length at a time. A tick over the short list compares and
-# stores its key and permutation in the first `e_short` entries and
-# marks the entry after them -1; no key is negative, so a long tick
-# never validates what a short one stored, and a short tick asks the
-# mark before it looks: the plan of one length never validates, and
-# never permutes, the other's list.
-
-def _short_plan(plan, e_short: int):
-    """(key, perm, bounds) for `deliver` over the short list: the
-    stored plan's front if a short tick stored it, else a key that
-    matches nothing."""
-    key, perm, bounds = plan
-    mine = key[e_short] < 0
-    return jnp.where(mine, key[:e_short], -1), perm[:e_short], bounds
-
-
-def _store_short_plan(plan, key_s, perm_s):
-    """The plan arrays after a tick over the short list."""
-    key, perm, _bounds = plan
-    marked = jnp.concatenate([key_s, jnp.full((1,), -1, key_s.dtype)])
-    return (lax.dynamic_update_slice(key, marked, (0,)),
-            lax.dynamic_update_slice(perm, perm_s, (0,)))
-
-
-def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
-           rspill_cap: int, overload_occ, head, tail, shard_base,
-           mute_slots: int, pressured_global, pressured_local,
-           blob=None):
-    """Mesh routing: pack entries into per-destination-shard buckets
-    (`_route_pack`: one payload-carrying sort, then a contiguous slice a
-    destination) and exchange them with three all_to_all over the actor
-    axis (ICI): targets, senders, words.
-
-    Returns (received Entries [shards*bucket], new route-spill, spill count,
-    overflow flag, newly muted [n_local], their refs, ref overflow, blob
-    results or None, (entries shipped, those of them off-shard)). Its
-    parts carry the scopes `pony/route/sort`, `/bucket`, `/exchange` and
-    `/spill` (state.STEP_SCOPES).
-    Bucket overflow keeps messages on the source shard (route-spill,
-    retried first next step) and mutes the sender — backpressure across
-    the mesh without any receiver-side state (≙ the intent of
-    ponyint_maybe_mute; the occupancy signal here is "the link to that
-    shard is saturated").
-
-    Blob MIGRATION (`blob` = dict(data, used, len, gen, bbase, bsl,
-    shard, mask) when the program routes Blob args on a mesh): a blob
-    rides its message across the ICI — per blob-arg word position, a
-    length row + the payload words concatenate onto the exchanged
-    words; the source shard frees the shipped slot, the receiving shard
-    allocates a fresh local slot (new generation) and rewrites the
-    handle word before delivery. Same-shard bucket blocks skip
-    migration (the handle is already dereferenceable). A receive-side
-    pool-full drop delivers the message with a null handle and counts
-    in n_blob_remote — backpressure-safe data loss made visible, never
-    corruption. Route-spilled entries keep their (still-local) blobs
-    and migrate when the retry actually ships. ≙ nothing in the
-    reference — libponyrt is single-node; this is the distributed half
-    of pony_alloc_msg payload movement.
-    """
-    tgt, sender, words = entries
-    ((dt, ts, ss, ws), (seg_start, cnt, acc),
-     (bt, bs, bw, fill_f)) = _route_pack(
-        tgt, sender, words, shards=shards, n_local=n_local, bucket=bucket)
-    with phase_scope("route/bucket"):
-        # What ships this tick, and how much of it leaves the shard
-        # (RtState.route_counts): read off the [shards] bucket fills.
-        n_routed = jnp.sum(acc)
-        n_remote = n_routed - jnp.take(acc, shard_base // n_local)
-
-    blob_out = None
-    if blob is not None:
-        # --- migration, source side: for every blob-carrying bucketed
-        # entry bound OFF-shard, append (len, payload...) rows and free
-        # the local slot. Positions are static (the Blob-arg mask).
-        bdata, bused, blen, bgen = (blob["data"], blob["used"],
-                                    blob["len"], blob["gen"])
-        bbase, bsl = blob["bbase"], blob["bsl"]
-        mask_np = blob["mask"]                   # STATIC numpy masks
-        mask = jnp.asarray(mask_np)
-        mask_iso = jnp.asarray(blob["mask_iso"])
-        wb = bdata.shape[0] // bsl       # flat pool: state.pool_index
-        word_i = jnp.arange(wb, dtype=jnp.int32)[:, None]
-
-        def whole(slots, ok):
-            """Flat indices of whole blobs, [wb, len(slots)]; one past
-            the end (filled / dropped) where not `ok`."""
-            return jnp.where(ok[None, :],
-                             pool_index(bsl, word_i, slots[None, :]),
-                             bdata.shape[0])
-        n_gids = mask.shape[0]
-        sb = shards * bucket
-        gid = bw[0]
-        g = jnp.clip(gid, 0, n_gids - 1)
-        gid_ok = fill_f & (gid >= 0) & (gid < n_gids)
-        # Off-shard only: bucket block s goes to shard s.
-        off_shard = jnp.broadcast_to(
-            (jnp.arange(shards, dtype=jnp.int32)[:, None]
-             != blob["shard"]), (shards, bucket)).reshape(sb)
-        extra_rows = []
-        freed = jnp.zeros((bsl,), jnp.bool_)
-        positions = [w for w in range(mask_np.shape[1])
-                     if bool(mask_np[:, w].any())]
-        for wpos in positions:
-            h = bw[1 + wpos]
-            hl = pack.blob_slot(h) - bbase
-            hs = jnp.where((hl >= 0) & (hl < bsl), hl, bsl)
-            okh = (gid_ok & off_shard & mask[g, wpos] & (h >= 0)
-                   & (hs < bsl)
-                   & (jnp.take(bgen, hs, mode="fill", fill_value=-1)
-                      == pack.blob_gen_of(h))
-                   & jnp.take(bused, hs, mode="fill", fill_value=False))
-            hx = jnp.where(okh, hl, bsl)
-            extra_rows.append(jnp.where(
-                okh, jnp.take(blen, hx, mode="fill", fill_value=0),
-                jnp.int32(-1))[None, :])             # -1 = no payload
-            extra_rows.append(jnp.take(
-                bdata, whole(hx, okh), mode="fill",
-                fill_value=0))                       # [wb, sb]
-            # Iso handles MOVE (source freed); val handles COPY — the
-            # receiver gets a replica, other readers keep the original.
-            freed = freed.at[jnp.where(okh & mask_iso[g, wpos],
-                                       hl, bsl)].set(True, mode="drop")
-        bused = bused & ~freed
-        blen = jnp.where(freed, 0, blen)
-        n_shipped = jnp.sum(freed.astype(jnp.int32))
-        bw = jnp.concatenate([bw] + extra_rows, axis=0)
-
-    with phase_scope("route/exchange"):
-        rt = lax.all_to_all(bt, "actors", split_axis=0, concat_axis=0,
-                            tiled=True)
-        rs = lax.all_to_all(bs, "actors", split_axis=0, concat_axis=0,
-                            tiled=True)
-        rw = lax.all_to_all(bw, "actors", split_axis=1, concat_axis=1,
-                            tiled=True)
-
-    if blob is not None:
-        # --- migration, receive side: allocate a local slot per arrived
-        # payload (disjoint ranks over the compacted free list), write
-        # len+words, bump the slot generation, rewrite the handle word.
-        w1b = words.shape[0]
-        rw_main = rw[:w1b]
-        sb = shards * bucket
-        n_pos = len(positions)
-        permf, vfree, _ = compact_mask(~bused, bsl)
-        free_slots = jnp.where(vfree, permf.astype(jnp.int32), -1)
-        has_all = jnp.stack(
-            [(rw[w1b + k * (1 + wb)] >= 0).astype(jnp.int32)
-             for k in range(n_pos)])
-        rank = (jnp.cumsum(has_all.reshape(-1)) - 1).reshape(n_pos, sb)
-        n_dropped = jnp.int32(0)
-        new_words = [rw_main[i] for i in range(w1b)]
-        for k, wpos in enumerate(positions):
-            base_row = w1b + k * (1 + wb)
-            lenr = rw[base_row]
-            has = lenr >= 0
-            slot_l = jnp.take(free_slots, jnp.where(has, rank[k], bsl),
-                              mode="fill", fill_value=-1)
-            ok = has & (slot_l >= 0)
-            n_dropped = n_dropped + jnp.sum(
-                (has & ~ok).astype(jnp.int32))
-            sx = jnp.where(ok, slot_l, bsl)
-            newgen = (jnp.take(bgen, sx, mode="fill", fill_value=0)
-                      + 1) & pack.BLOB_GEN_MASK
-            bgen = bgen.at[sx].set(newgen, mode="drop")
-            bused = bused.at[sx].set(True, mode="drop")
-            blen = blen.at[sx].set(jnp.where(ok, lenr, 0), mode="drop")
-            bdata = bdata.at[whole(sx, ok)].set(
-                rw[base_row + 1:base_row + 1 + wb], mode="drop")
-            newh = pack.blob_handle(bbase + slot_l, newgen)
-            # has & ok → fresh local handle; has & ~ok → dropped (null);
-            # ~has → original word untouched (not a blob for this gid,
-            # or a same-shard handle that skipped migration).
-            new_words[1 + wpos] = jnp.where(
-                ok, newh, jnp.where(has, jnp.int32(-1),
-                                    new_words[1 + wpos]))
-        rw = jnp.stack(new_words)
-        n_received = jnp.sum(has_all) - n_dropped
-        blob_out = ((bdata, bused, blen, bgen),
-                    n_shipped, n_received, n_dropped)
-
-    with phase_scope("route/spill"):
-        # The spill reads the sorted entries only behind this barrier:
-        # without it the compiler fuses `maximum(ts, 0)` into the
-        # bucket's slices and `pressured_global[ts]` loses its fast
-        # memory, 114 ms for 68 at 8.4M entries (PERF.md §6, PR 41).
-        ts, ss, ws, dt = lax.optimization_barrier((ts, ss, ws, dt))
-        spilled = _route_spill(
-            ts, ss, ws, dt, seg_start, cnt - acc, shards=shards,
-            n_local=n_local, bucket=bucket, rspill_cap=rspill_cap,
-            overload_occ=overload_occ, head=head, tail=tail,
-            shard_base=shard_base, mute_slots=mute_slots,
-            pressured_global=pressured_global,
-            pressured_local=pressured_local)
-    received = Entries(tgt=rt, sender=rs, words=rw)
-    return (received, *spilled, blob_out, (n_routed, n_remote))
+def dispatch(k: TickStatic, st: RtState, w, rs) -> Dispatched:
+    """--- 2. drain + dispatch per cohort (≙ actor run loop)."""
+    nl, fh, program, pool = k.nl, k.fh, k.program, rs.pool
+    new_type_state: Dict[str, Dict[str, Any]] = dict(st.type_state)
+    head_segments: List[jnp.ndarray] = []
+    out_entries: List[Entries] = []
+    claim_lists: Dict[str, List[jnp.ndarray]] = {
+        t: [] for t in program.spawn_target_names}
+    init_lists: Dict[str, List[Any]] = {
+        t: [] for t in program.spawn_target_names}
+    destroy_rows: List[Tuple[int, jnp.ndarray]] = []
+    error_rows: List[Tuple[int, Any]] = []
+    exit_f = st.exit_flag[0]
+    exit_c = st.exit_code[0]
+    spawn_fail = st.spawn_fail[0]
+    nproc_total = jnp.int32(0)
+    nbad_total = jnp.int32(0)
+    drain_facts = []
+    for run_cohort, ch in k.dispatchers:
+        s0, s1 = ch.local_start, ch.local_stop
+        ids = w.base + s0 + jnp.arange(ch.local_capacity, dtype=jnp.int32)
+        with phase_scope("spawn"):
+            resv = spawn.cohort_resv(ch, rs, w)
+            if k.opts.blob_slots > 0 and ch.uses_blobs:
+                blobd = {**dict(zip(("data", "used", "len", "gen"), pool.cur)),
+                         "base": pool.base,
+                         "resv": spawn.cohort_blob_resv(ch, rs)}
+            else:
+                blobd = None
+        (stf, out, new_head_rows, ef, ec, nproc, nbad, claims, inits,
+         sfail, dstr, errs, blob_out) = run_cohort(
+            st.type_state[ch.atype.__name__],
+            st.buf[ch.atype.__name__], st.head[s0:s1], w.occ0[s0:s1],
+            rs.runnable[s0:s1], ids, resv, blob=blobd)
+        if blob_out is not None:
+            pool = pool._replace(
+                cur=blob_out[:4], fail=pool.fail | blob_out[4],
+                budget=pool.budget | blob_out[5],
+                n_alloc=pool.n_alloc + blob_out[6],
+                n_free=pool.n_free + blob_out[7],
+                n_remote=pool.n_remote + blob_out[8])
+        new_type_state[ch.atype.__name__] = stf
+        head_segments.append(new_head_rows)
+        if k.opts.analysis >= 1:
+            drain_facts.append((ch, st.head[s0:s1], new_head_rows))
+        out_entries.append(out)
+        for t, cl in claims.items():
+            claim_lists[t].append(cl)
+            init_lists[t].append(None if inits is None else inits[t])
+        if ch.spawns:
+            spawn_fail = spawn_fail | sfail
+        destroy_rows.append((s0, dstr))
+        error_rows.append((s0, errs))
+        exit_c = jnp.where(ef & ~exit_f, ec, exit_c)
+        exit_f = exit_f | ef
+        nproc_total = nproc_total + nproc
+        nbad_total = nbad_total + nbad
+    if fh < nl:  # host-cohort heads unchanged by device dispatch
+        head_segments.append(st.head[fh:nl])
+    new_head = (jnp.concatenate(head_segments) if head_segments
+                else st.head)
+    return Dispatched(new_type_state, new_head, out_entries, claim_lists,
+                      init_lists, destroy_rows, error_rows, exit_f, exit_c,
+                      spawn_fail, nproc_total, nbad_total, drain_facts, pool)
 
 
-def _route_spill(ts, ss, ws, dt, seg_start, over, *, shards: int,
-                 n_local: int, bucket: int, rspill_cap: int, overload_occ,
-                 head, tail, shard_base, mute_slots: int, pressured_global,
-                 pressured_local):
-    """What did not fit its bucket, and who mutes for it: the sorted
-    entries (`ts`, `ss`, `ws` by destination `dt`), each destination's
-    `seg_start` and overflow `over` → (new route-spill, spill count,
-    overflow flag, newly muted [n_local], their refs, ref overflow)."""
-    e = ts.shape[0]
-    nrej = jnp.sum(over)
-    w1 = ws.shape[0]
-    # Sends whose (possibly remote) target DECLARED pressure: the
-    # cross-shard face of pony_apply_backpressure — every shard sees the
-    # all-gathered pressured bits, so senders mute at routing time, not
-    # only on the receiver's shard (≙ the reference muting any scheduler
-    # that sends to an under-pressure actor).
-    pr_t = (ts >= 0) & jnp.take(
-        pressured_global, jnp.maximum(ts, 0), mode="clip")
-
-    def pressure(_):
-        # Bucket overflow → route spill (stays on this shard, ordered)
-        # + mute the (always local) senders of parked or
-        # pressured-targeted messages.
-        rank = jnp.arange(e, dtype=jnp.int32) - seg_start[
-            jnp.minimum(dt, shards - 1)]
-        rej = (dt < shards) & (rank >= bucket)
-        perm2, vsp, _ = compact_mask(rej, rspill_cap)
-        spill = Entries(
-            tgt=jnp.where(vsp, ts[perm2], -1),
-            sender=jnp.where(vsp, ss[perm2], -1),
-            words=jnp.where(vsp[None, :], ws[:, perm2], 0),
-        )
-        lsnd = ss - shard_base
-        s_ok = (rej | pr_t) & (lsnd >= 0) & (lsnd < n_local)
-        sc = jnp.minimum(jnp.maximum(lsnd, 0), n_local - 1)
-        s_hot = (tail[sc] - head[sc]) > overload_occ
-        # ≙ the reference's !OVERLOADED/UNDER_PRESSURE sender exemption
-        # (actor.c mute rules): a sender that is itself hot or has
-        # itself declared pressure never mutes — prevents two
-        # host-pressured actors that message each other from
-        # mutually muting into a stall.
-        trig = s_ok & ~s_hot & ~pressured_local[sc]
-        mute_row = jnp.where(trig, sc, n_local)
-        newly_muted = jnp.zeros((n_local,), jnp.bool_).at[mute_row].max(
-            trig, mode="drop")
-        refs, ovf = mute_ref_slots(trig, mute_row, ts, n=n_local,
-                                   k=mute_slots)
-        return spill, newly_muted, refs, ovf
-
-    def quiet(_):
-        refs, ovf = empty_mute_slots(n_local, mute_slots)
-        return (Entries(tgt=jnp.full((rspill_cap,), -1, jnp.int32),
-                        sender=jnp.full((rspill_cap,), -1, jnp.int32),
-                        words=jnp.zeros((w1, rspill_cap), jnp.int32)),
-                jnp.zeros((n_local,), jnp.bool_), refs, ovf)
-
-    new_rspill, newly_muted, new_refs, new_ovf = lax.cond(
-        (nrej > 0) | jnp.any(pr_t), pressure, quiet, operand=None)
-    return (new_rspill, jnp.minimum(nrej, rspill_cap), nrej > rspill_cap,
-            newly_muted, new_refs, new_ovf)
+def lifecycle(k: TickStatic, st: RtState, d, new_tail, cl, um) -> Lifecycle:
+    """--- 4b. apply destroys (≙ ponyint_actor_setpendingdestroy +
+    ponyint_actor_destroy, actor.c:570-664): the slot dies at end of
+    step; its remaining queue is discarded (head := tail), flags
+    clear, and the row becomes reclaimable by a later spawn. `cl`: the
+    rows after the claims, `new_tail` after delivery."""
+    nl, alive, new_head = k.nl, cl.alive, cl.head
+    muted, mute_refs, mute_ovf = um
+    pinned = st.pinned
+    pressured = st.pressured
+    # Int-coded error residue (≙ pony_error_int/code, fork): latest
+    # nonzero code per actor + a counter; zero-cost for cohorts whose
+    # behaviours never call ctx.error_int (gated at trace).
+    last_error = st.last_error
+    last_error_loc = st.last_error_loc
+    n_errors = jnp.int32(0)
+    for s0, errs in d.error_rows:
+        if errs is None:
+            continue
+        errf, errc, errl = errs
+        rows = jnp.where(errf, s0 + jnp.arange(errf.shape[0],
+                                               dtype=jnp.int32), nl)
+        last_error = last_error.at[rows].set(
+            jnp.where(errf, errc, 0), mode="drop")
+        last_error_loc = last_error_loc.at[rows].set(
+            jnp.where(errf, errl, 0), mode="drop")
+        n_errors = n_errors + jnp.sum(errf.astype(jnp.int32))
+    n_destroyed = jnp.int32(0)
+    for s0, dstr in d.destroy_rows:
+        if dstr is None:
+            continue
+        rows = jnp.where(dstr, s0 + jnp.arange(dstr.shape[0],
+                                               dtype=jnp.int32), nl)
+        alive = alive.at[rows].set(False, mode="drop")
+        new_head = new_head.at[rows].set(
+            jnp.take(new_tail, jnp.minimum(rows, nl - 1)), mode="drop")
+        muted = muted.at[rows].set(False, mode="drop")
+        mute_refs = mute_refs.at[:, rows].set(-1, mode="drop")
+        mute_ovf = mute_ovf.at[rows].set(False, mode="drop")
+        pinned = pinned.at[rows].set(False, mode="drop")
+        pressured = pressured.at[rows].set(False, mode="drop")
+        n_destroyed = n_destroyed + jnp.sum(dstr.astype(jnp.int32))
+    return Lifecycle(alive, new_head, muted, mute_refs, mute_ovf, pinned,
+                     pressured, last_error, last_error_loc, n_errors,
+                     n_destroyed)
 
 
-# A row's status word for the unmute pass (`muter_bits` in the tick).
-LIVE_CONG, CAN_RECOVER, RECOVERED, PRESSURED = 1, 2, 4, 8
+def vote(k: TickStatic, st: RtState, w, d, n_spawned, r, life, m, qw_hist2,
+         occ_after):
+    """--- 6. the vote: the tick's facts reduced to the aux the window's
+    continue test (aux_go) and the host read. Returns (aux, this shard's
+    cumulative (rejected, badmsg, deadletter, mutes), its sticky spill
+    overflow, the next tick's world bits)."""
+    p, nl, fh = k.p, k.nl, k.fh
+    res, pool, rsp_count = r.res, r.pool, r.rspill_count
+    exit_f, exit_c, spawn_fail = d.exit_f, d.exit_c, d.spawn_fail
+    nrej_new = st.n_rejected[0] + res.n_rejected
+    nbad_new = st.n_badmsg[0] + d.nbad
+    ndl_new = st.n_deadletter[0] + res.n_deadletter
+    nmut_new = st.n_mutes[0] + jnp.sum(m.became.astype(jnp.int32))
+    counts = (nrej_new, nbad_new, ndl_new, nmut_new)
+    (occ_sum, occ_max, n_muted_now, n_over_now, nrej_all, nbad_all,
+     ndl_all, nmut_all, qw_p99) = lanes.vote_lanes(
+        k, occ_after, m.muted, counts, qw_hist2)
+    local_pending = (jnp.any(occ_after[:fh] > 0)
+                     | (res.spill_count > 0) | (rsp_count > 0))
+    any_muted_local = jnp.any(m.muted)
+    host_pending = (jnp.any(occ_after[fh:] > 0) if fh < nl
+                    else jnp.bool_(False))
+    # Sticky: once any step overflowed, every later aux reports it, so
+    # the host catches it whatever its fetch cadence (quiesce_interval).
+    overflow = st.spill_overflow[0] | res.spill_overflow | r.rspill_over
+    # End-of-tick facts feeding the next tick's gather gates (exact,
+    # not conservative: `pressured`/`muted2` are post-destroy finals,
+    # `rsp_count` is the post-route spill count).
+    any_pressured_local = jnp.any(life.pressured)
+    any_rspill_local = rsp_count > 0
+    facts = (spawn_fail, local_pending, any_muted_local, host_pending,
+             exit_f, overflow, any_pressured_local, any_rspill_local)
+    if p > 1:
+        # ONE packed psum + ONE packed pmax replace the former ~17
+        # separate collectives (≙ the CNF/ACK token protocol being a
+        # single token, not one message per fact, scheduler.c:303-480).
+        # Booleans ride as 0/1 counts ("any" = sum > 0); cumulative
+        # counters wrap mod 2^32 exactly as the per-shard counters do.
+        i32c = lambda x: jnp.asarray(x, jnp.int32)  # noqa: E731
+        summed = lax.psum(jnp.stack([
+            *(i32c(f) for f in facts),
+            st.n_processed[0] + d.nproc,
+            st.n_delivered[0] + res.n_delivered,
+            occ_sum, n_muted_now, n_over_now,
+            nrej_all, nbad_all, ndl_all, nmut_all,
+            i32c(pool.fail), i32c(pool.budget)]), "actors")
+        facts = tuple(summed[i] > 0 for i in range(len(facts)))
+        nproc_all, ndel_all = summed[8], summed[9]
+        blob_fail_any, blob_budget_any = summed[17] > 0, summed[18] > 0
+        if k.opts.analysis >= 1:
+            occ_sum, n_muted_now, n_over_now = (summed[10], summed[11],
+                                                summed[12])
+            nrej_all, nbad_all, ndl_all, nmut_all = (
+                summed[13], summed[14], summed[15], summed[16])
+        maxed = lax.pmax(jnp.stack([
+            jnp.where(exit_f, exit_c, jnp.int32(-2**31)), occ_max,
+            qw_p99]), "actors")
+        exit_code_all = jnp.where(facts[4], maxed[0], exit_c)
+        if k.opts.analysis >= 1:
+            occ_max = maxed[1]
+            qw_p99 = maxed[2]
+    else:
+        exit_code_all = exit_c
+        nproc_all = st.n_processed[0] + d.nproc
+        ndel_all = st.n_delivered[0] + res.n_delivered
+        blob_fail_any, blob_budget_any = pool.fail, pool.budget
+    (spawn_fail_any, device_pending, any_muted_all, host_pending, exit_any,
+     overflow_any, any_pressured_all, any_rspill_all) = facts
+    spawn_aux = spawn.row_pressure(k, st, w, r, any_rspill_all, life.alive,
+                                   life.head, occ_after, n_spawned)
+    wb_new =(any_pressured_all.astype(jnp.int32)
+              | (any_muted_all.astype(jnp.int32) << 1)
+              | (any_rspill_all.astype(jnp.int32) << 2))
+    aux = StepAux(
+        device_pending=device_pending,
+        host_pending=host_pending,
+        any_muted=any_muted_all,
+        exit_flag=exit_any, exit_code=exit_code_all,
+        spill_overflow=overflow_any,
+        spawn_fail=spawn_fail_any,
+        blob_fail=blob_fail_any,
+        blob_budget_fail=blob_budget_any,
+        n_processed=nproc_all,
+        n_delivered=ndel_all,
+        occ_sum=occ_sum, occ_max=occ_max,
+        n_muted_now=n_muted_now, n_overloaded_now=n_over_now,
+        n_rejected=nrej_all, n_badmsg=nbad_all,
+        n_deadletter=ndl_all, n_mutes=nmut_all,
+        qw_p99=qw_p99,
+        spawn=spawn_aux,
+    )
+    return aux, counts, overflow, wb_new
+
+
+def _vec(x, dtype=None):   # per-shard "scalar" → [1]
+    return jnp.asarray(x, dtype).reshape(1)
+
+
+def tick(k: TickStatic, st: RtState, inject_tgt, inject_words,
+         phase: PhaseCursor) -> Tuple[RtState, StepAux]:
+    """One scheduler tick: its phases in order, each given the static
+    record, the state and the earlier phases' results. `phase(name)`
+    opens the named scope `pony/<name>` for what is traced from there to
+    the next call (state.PhaseCursor)."""
+    opts, tracing = k.opts, k.opts.tracing
+    phase("unmute")
+    w = mute.world(k, st)
+    um = mute.unmute_pass(k, st, w)                         # 1
+    phase("spawn")
+    rs = spawn.reserve(k, st, w, um.muted)                  # 1b, 2a'
+    phase("dispatch")
+    d = dispatch(k, st, w, rs)                              # 2
+    phase("spawn")
+    cl = spawn.claim(k, st, w, d)                           # 2b
+    # --- 2c. causal-trace spans + context propagation (tracing on
+    # only; the Python-level gate keeps the jaxpr bit-identical to
+    # a tracer-free build otherwise — tests/test_tracing.py traps
+    # trace_span_lanes to prove it). Every cohort's outbox gains
+    # two trailing word rows carrying (trace_id, span_id) of the
+    # dispatch that emitted each entry; spills, routing and
+    # delivery move them with the payload from here on.
+    out_entries = d.out_entries
+    if tracing:
+        phase("analysis")
+        (span_data2, span_count2, span_dropped2, span_next2,
+         tr_rows) = lanes.trace_span_lanes(k.program, opts, st,
+                                           d.drain_facts, w.base, w.shard)
+        out_entries = [
+            o._replace(words=jnp.concatenate([o.words, t], axis=0))
+            for o, t in zip(out_entries, tr_rows)]
+    r = route.deliver_routed(k, st, w, inject_tgt, inject_words,
+                             out_entries, cl, d.pool, phase)    # 3, 4
+    res, pool = r.res, r.pool
+    phase("gc_mark")
+    life = lifecycle(k, st, d, res.tail, cl, um)            # 4b
+    phase("mute")
+    m = mute.bookkeeping(st, life, res, r)                  # 5
+    occ_after = res.tail - life.head
+    ring = (st.ev_data, st.ev_count[0], st.ev_dropped[0])
+    if opts.analysis >= 1:
+        phase("analysis")
+    if opts.analysis >= 3:
+        ring = lanes.event_ring(k, st, w, ring, d.error_rows, life,
+                                m.became, occ_after)        # 5b
+    # --- 5c. per-behaviour profiler lanes (analysis level >= 1 only;
+    # the gate is PYTHON-level, so level 0 traces none of this —
+    # tests trap profile_lanes to assert exactly that).
+    prof = {f: getattr(st, f) for f in lanes.PROFILE_FIELDS}
+    if opts.analysis >= 1:
+        prof = dict(zip(lanes.PROFILE_FIELDS, (
+            *lanes.profile_lanes(k.program, opts, st, cl.tail0, res,
+                                 d.drain_facts, m.muted),
+            lanes.phase_cost_lanes(
+                st, r.listed_tgt, d.drain_facts, d.nproc, cl.n_spawned,
+                life.n_destroyed, res.rebuild_slots))))
+    phase("vote")
+    aux, (nrej_new, nbad_new, ndl_new, nmut_new), overflow, wb_new = vote(
+        k, st, w, d, cl.n_spawned, r, life, m, prof["qwait_hist"],
+        occ_after)                                          # 6
+    st2 = RtState(
+        buf=res.buf, head=life.head, tail=res.tail,
+        alive=life.alive, muted=m.muted, mute_refs=m.refs,
+        mute_age=m.age,
+        mute_ovf=m.ovf, pinned=life.pinned, pressured=life.pressured,
+        dspill_tgt=res.spill.tgt, dspill_sender=res.spill.sender,
+        dspill_words=res.spill.words,
+        dspill_count=_vec(res.spill_count),
+        rspill_tgt=r.rspill.tgt, rspill_sender=r.rspill.sender,
+        rspill_words=r.rspill.words,
+        rspill_count=_vec(r.rspill_count),
+        route_counts=({name: _vec(st.route_counts[name][0] + n)
+                       for name, n in zip(ROUTE_COUNTERS, r.counts)}
+                      if k.p > 1 else st.route_counts),
+        spill_overflow=_vec(overflow, jnp.bool_),
+        exit_flag=_vec(d.exit_f, jnp.bool_), exit_code=_vec(d.exit_c),
+        step_no=_vec(st.step_no[0] + 1),
+        n_processed=_vec(st.n_processed[0] + d.nproc),
+        n_delivered=_vec(st.n_delivered[0] + res.n_delivered),
+        n_rejected=_vec(nrej_new),
+        n_badmsg=_vec(nbad_new),
+        n_deadletter=_vec(ndl_new),
+        n_mutes=_vec(nmut_new),
+        n_spawned=_vec(st.n_spawned[0] + cl.n_spawned),
+        n_destroyed=_vec(st.n_destroyed[0] + life.n_destroyed),
+        spawn_fail=_vec(d.spawn_fail, jnp.bool_),
+        n_collected=st.n_collected,
+        last_error=life.last_error, last_error_loc=life.last_error_loc,
+        n_errors=_vec(st.n_errors[0] + life.n_errors),
+        ev_data=ring[0], ev_count=_vec(ring[1]),
+        ev_dropped=_vec(ring[2]),
+        **prof,
+        trace_buf=res.trace_buf,
+        span_data=span_data2 if tracing else st.span_data,
+        span_count=(_vec(span_count2) if tracing else st.span_count),
+        span_dropped=(_vec(span_dropped2) if tracing
+                      else st.span_dropped),
+        span_next=(_vec(span_next2) if tracing else st.span_next),
+        plan_key=res.plan_key, plan_perm=res.plan_perm,
+        plan_bounds=res.plan_bounds,
+        world_bits=_vec(wb_new),
+        blob_data=pool.cur[0], blob_used=pool.cur[1],
+        blob_len=pool.cur[2], blob_gen=pool.cur[3],
+        blob_fail=_vec(pool.fail, jnp.bool_),
+        blob_budget_fail=_vec(pool.budget, jnp.bool_),
+        n_blob_alloc=_vec(st.n_blob_alloc[0] + pool.n_alloc),
+        n_blob_free=_vec(st.n_blob_free[0] + pool.n_free),
+        n_blob_remote=_vec(st.n_blob_remote[0] + pool.n_remote),
+        n_blob_moved=_vec(st.n_blob_moved[0] + r.nb_moved),
+        type_state=cl.type_state,
+    )
+    return st2, aux
 
 
 def build_step(program: Program, opts: RuntimeOptions):
@@ -1366,1103 +1095,12 @@ def build_step(program: Program, opts: RuntimeOptions):
     jit_step()."""
     assert program.frozen
     check_kernels(program, opts)
-    p = program.shards
-    nl = program.n_local
-    c = opts.mailbox_cap
-    fh = program.first_host_row
-    s_cap = opts.spill_cap
-    tracing = opts.tracing   # static: causal trace lanes (PROFILE §10)
-    dev_cohorts = program.device_cohorts
-    dispatchers = [(_cohort_dispatch(ch, opts, opts.noyield, program), ch)
-                   for ch in dev_cohorts]
-    # Blob migration over the mesh: active iff some behaviour ROUTES a
-    # Blob argument (static mask) and the pool is live (see _route).
-    route_blobs = False
-    if opts.blob_slots > 0 and p > 1:
-        from .gc import build_blob_arg_mask
-        _blob_route_mask = build_blob_arg_mask(program, opts.msg_words)
-        # Iso-mode positions MOVE (source slot freed); val-mode (frozen,
-        # shared) positions COPY — other readers keep the source.
-        _blob_route_mask_iso = build_blob_arg_mask(
-            program, opts.msg_words, mode="iso")
-        route_blobs = bool(_blob_route_mask.any())
-    e_out, bucket, _n_entries = layout_sizes(program, opts)
-    # What one shard can emit a tick (its route spill and its outbox) is
-    # what a balanced world hands it back: the length of the short
-    # delivery list's routed part (step 4 of the tick).
-    l_in = s_cap + e_out
-    short_list = _unpack_fits(p, bucket, l_in)
-    e_short = s_cap + opts.inject_slots + l_in
-    # Delivery priority levels (see delivery.deliver): 0 = receiver
-    # spill, 1 = host inject, 2+k = sender cohort with k-th highest
-    # PRIORITY (≙ the fork's actor priority hint ordering contenders).
-    import numpy as _np
-    pri_sorted = sorted({ch.priority for ch in dev_cohorts}, reverse=True)
-    pri_rank = {pv: i for i, pv in enumerate(pri_sorted)}
-    n_levels = 2 + max(1, len(pri_sorted))
-    # Per-cohort mailbox widths tiling the local row space (ALL cohorts,
-    # device + host) — delivery rebuilds each table at its own width.
-    cohort_layout = tuple(
-        (ch.atype.__name__, ch.local_start, ch.local_stop,
-         1 + ch.msg_words) for ch in program.cohorts)
-
-    def spawning(ch):
-        """Which behaviours of a spawner cohort hold a spawn site, by
-        local behaviour index (verify's probe trace, at the window's
-        trace like every other check of a behaviour's body): only a row
-        about to dispatch one of them can be refused a row
-        (StepAux.spawn)."""
-        from .. import verify
-        return _np.array([bool(verify.behaviour_effects(
-            b, ch.atype, msg_words=opts.msg_words,
-            default_max_sends=opts.max_sends).spawns)
-            for b in ch.behaviours])
+    k = tick_static(program, opts)
 
     def local_step(st: RtState, inject_tgt, inject_words
                    ) -> Tuple[RtState, StepAux]:
         with PhaseCursor() as phase:
-            return tick(st, inject_tgt, inject_words, phase)
-
-    def tick(st: RtState, inject_tgt, inject_words, phase: PhaseCursor
-             ) -> Tuple[RtState, StepAux]:
-        # `phase(name)` opens the named scope `pony/<name>` for what is
-        # traced from there to the next call (state.PhaseCursor).
-        phase("unmute")
-        if p > 1:
-            shard = lax.axis_index("actors").astype(jnp.int32)
-        else:
-            shard = jnp.int32(0)
-        base = shard * nl
-
-        def local_rows(entries):
-            """Global target ids -> this shard's rows."""
-            return entries._replace(tgt=jnp.where(
-                entries.tgt >= 0, entries.tgt - base, -1))
-        occ0 = st.tail - st.head
-        # World bits (previous tick's mesh-wide vote, stored replicated
-        # per shard): bit0 = any actor pressured anywhere, bit1 = any
-        # muted anywhere, bit2 = any route-spill entries anywhere. They
-        # are shard-uniform by construction (computed from the packed
-        # psum vote below; host writes set every shard's entry), so they
-        # can gate collectives — every shard takes the same cond branch,
-        # the same uniformity argument as the fused window's while cond.
-        # This is the fork's whole thesis applied to the mesh
-        # (README.md:8-10): a quiet world must not pay per-tick gather
-        # latency for backpressure machinery it isn't using.
-        wb0 = st.world_bits[0]
-        world_pressured = (wb0 & 1) > 0
-        world_muted = (wb0 & 2) > 0
-        world_rspill = (wb0 & 4) > 0
-        # Mesh-wide pressured bits (≙ pony_apply_backpressure being
-        # visible to every scheduler): one all_gather of the [nl] bool
-        # column — it lets BOTH the routing mute and the remote unmute
-        # guard see off-shard pressure. Gated: ticks on a mesh with no
-        # declared pressure anywhere skip the gather (zeros are exact).
-        if p > 1:
-            pressured_global = lax.cond(
-                world_pressured,
-                lambda _: lax.all_gather(st.pressured, "actors",
-                                         tiled=True),
-                lambda _: jnp.zeros((p * nl,), jnp.bool_),
-                operand=None)
-        else:
-            pressured_global = st.pressured
-
-        # --- 1. unmute pass (≙ ponyint_sched_unmute_senders,
-        # scheduler.c:1552-1635: receiver recovered → senders released).
-        # The per-row pending histogram (a scatter-add, which serialises
-        # on TPU) only runs when the spill actually holds messages — the
-        # steady state skips it entirely.
-        dspill_pending = lax.cond(
-            st.dspill_count[0] > 0,
-            lambda _: counts_by_key(
-                jnp.minimum(jnp.maximum(st.dspill_tgt, 0), nl - 1),
-                (st.dspill_tgt >= 0).astype(jnp.int32), nl),
-            lambda _: jnp.zeros((nl,), jnp.int32), operand=None)
-        # One status word a row: everything the unmute pass asks of a
-        # muting RECEIVER is decided here, over the rows, and the pass
-        # reads it once by the mute refs (a gather is paid per index,
-        # whatever it fetches). Bit 0: live-congested — shows congestion
-        # evidence AND can run to drain it; bit 1: can-recover — alive
-        # and unmuted, i.e. not itself deadlocked; bit 2: recovered —
-        # drained to the unmute threshold, nothing parked for it in the
-        # device spill, no declared pressure: what releases a sender it
-        # muted; bit 3: declares pressure. The word is also the mesh's
-        # one all-gather for the pass, so it is built OUTSIDE the unmute
-        # cond (collectives must run collectively; jnp.any(st.muted) is
-        # shard-local).
-        can_recover = st.alive & ~st.muted
-        live_cong = (((occ0 > opts.unmute_occ) | (dspill_pending > 0))
-                     & can_recover)
-        recovered = ((occ0 <= opts.unmute_occ) & (dspill_pending == 0)
-                     & ~st.pressured)
-        muter_bits = (jnp.where(live_cong, LIVE_CONG, 0)
-                      | jnp.where(can_recover, CAN_RECOVER, 0)
-                      | jnp.where(recovered, RECOVERED, 0)
-                      | jnp.where(st.pressured, PRESSURED, 0)
-                      ).astype(jnp.int32)
-        # Gated like the pressured gather: the bits feed only the unmute
-        # pass, which has work only when someone (anywhere) is muted —
-        # exactly what world bit1 reports from the previous tick's vote.
-        if p > 1:
-            muter_bits_global = lax.cond(
-                world_muted,
-                lambda _: lax.all_gather(muter_bits, "actors",
-                                         tiled=True),
-                lambda _: jnp.zeros((p * nl,), jnp.int32),
-                operand=None)
-        else:
-            muter_bits_global = muter_bits
-
-        def unmute_pass(_):
-            # ≙ ponyint_sched_unmute_senders walking the mutemap
-            # receiver-set (scheduler.c:1552-1635): a sender releases only
-            # when EVERY tracked muting receiver has recovered.
-            refs = st.mute_refs                       # [K, nl]
-            has = refs >= 0
-            lref = refs - base
-            ref_local = (lref >= 0) & (lref < nl)
-            status = muter_bits_global
-            if p > 1:
-                # Each bit is believed from where it was believed before
-                # the word: live-congested and can-recover as gathered
-                # under world bit1, pressure from its own all-gather
-                # (world bit0), and `recovered` from this shard's rows
-                # alone — a remote ref's is never read.
-                status = ((status & (LIVE_CONG | CAN_RECOVER))
-                          | jnp.where(pressured_global, PRESSURED, 0)
-                          | lax.dynamic_update_slice(
-                              jnp.zeros((p * nl,), jnp.int32),
-                              muter_bits & RECOVERED, (base,)))
-            got = jnp.take(status, jnp.maximum(refs, 0), mode="clip")
-
-            def says(bit):       # [K, nl]: the ref's muter has `bit` set
-                return has & ((got & bit) > 0)
-            ref_pressured = says(PRESSURED)
-            local_ok = ref_local & says(RECOVERED)
-            # Remote muting ref: release once this shard's route-spill
-            # drained (the local evidence of congestion is gone;
-            # receiver-side pressure will re-mute via routing if it
-            # persists) — unless the remote receiver still DECLARES
-            # pressure (the all-gathered bits above), which holds the
-            # sender muted exactly as a local pressured ref would.
-            remote_ok = (has & ~ref_local & (st.rspill_count[0] == 0)
-                         & ~ref_pressured)
-            slot_ok = ~has | local_ok | remote_ok
-            all_ok = jnp.all(slot_ok, axis=0)
-            # Overflowed ref sets (more distinct muters than slots) defer
-            # to a shard-wide quiet condition — conservative, never early.
-            # Overflowed ref sets may have EVICTED a pressured ref
-            # (slot collision), so the conservative release condition
-            # consults the whole world's pressure bits, not just local.
-            shard_quiet = (jnp.max(occ0) <= opts.unmute_occ) \
-                & (st.dspill_count[0] == 0) & (st.rspill_count[0] == 0) \
-                & ~jnp.any(pressured_global)
-            # Aging deadlock-breaker: a sender muted for
-            # mute_age_limit consecutive ticks force-releases even if
-            # its muters look unrecovered. Mutual-mute cycles and
-            # chains (A muted-by B muted-by C...) can otherwise never
-            # drain — the known deadlock of the reference's pre-0.36
-            # backpressure, where every muter must RUN to recover and
-            # muted actors don't run. Bounded queues + spill make the
-            # periodic release safe: each release round dispatches real
-            # work, and overflow still fails loudly. Host-declared
-            # pressure is exempt (never aged away).
-            # Staggered by actor row (threshold in [limit, 2*limit)):
-            # a fan-in that muted thousands of senders on one tick would
-            # otherwise release them all on one tick too, and the
-            # synchronized wave into the still-full receiver could blow
-            # the bounded spill. Phasing spreads releases over `limit`
-            # ticks, so the per-tick wave is ~n_muted/limit.
-            if opts.mute_age_limit > 0:
-                lim = opts.mute_age_limit
-                threshold = lim + jnp.arange(nl, dtype=jnp.int32) % lim
-                aged = st.mute_age >= threshold
-                held_by_pressure = jnp.any(ref_pressured, axis=0)
-                # A tracked muter (on ANY shard — the word is the
-                # mesh's all-gather) that still shows LIVE congestion
-                # evidence (occ above the unmute threshold, or messages
-                # parked in its shard's device spill) and that can still
-                # run to drain it
-                # (alive, not itself muted) vetoes aging: releasing a
-                # sender into a receiver that is actively being worked
-                # just grows the bounded spill until overflow — the
-                # reference never releases while the muter is
-                # overloaded/pressured (scheduler.c:1552-1635). Aging
-                # therefore only breaks TRUE mute-cycle deadlocks, where
-                # every congested muter is itself muted or dead and can
-                # never run to recover. A non-empty local route spill
-                # additionally holds any sender with a remote muter that
-                # can still RECOVER (alive, unmuted): the backlog bound
-                # for that muter is still in flight here, so its
-                # congestion state is not yet observable. A remote muter
-                # that is itself muted/dead gives no such hold — its
-                # route-spill backlog can never drain (muted receivers
-                # don't run), and holding on it would re-create the
-                # cross-shard mute-cycle deadlock aging exists to break.
-                held_by_live = jnp.any(says(LIVE_CONG), axis=0)
-                if p > 1:
-                    remote_recover = jnp.any(
-                        ~ref_local & says(CAN_RECOVER), axis=0)
-                    held_by_live = held_by_live | (
-                        remote_recover & (st.rspill_count[0] > 0))
-                # Overflowed ref sets may have EVICTED a pressured ref, so
-                # aging defers while any pressure exists anywhere — the
-                # same conservative rule as the non-aged ovf path.
-                aged_ok = (aged & ~held_by_pressure & ~held_by_live
-                           & (~st.mute_ovf | ~jnp.any(pressured_global)))
-            else:
-                # mute_age_limit <= 0: aging deadlock-breaker disabled
-                # (reference mute semantics exactly — documented opt-out
-                # in config.py).
-                aged_ok = jnp.zeros((nl,), jnp.bool_)
-            release = st.muted & (
-                (all_ok & (~st.mute_ovf | shard_quiet))
-                | aged_ok)
-            return (st.muted & ~release,
-                    jnp.where(release[None, :], -1, refs),
-                    st.mute_ovf & ~release)
-
-        # Nobody muted (the common case) → skip the pass entirely.
-        muted, mute_refs, mute_ovf = lax.cond(
-            jnp.any(st.muted), unmute_pass,
-            lambda _: (st.muted, st.mute_refs, st.mute_ovf), operand=None)
-
-        phase("spawn")
-        # --- 1b. spawn reservations (≙ pony_create's slot allocation,
-        # actor.c:688-734, done ahead of dispatch): per spawn-target
-        # cohort, compact this shard's free rows (dead, drained, no stale
-        # spill) and hand each spawner cohort its statically-partitioned
-        # window, reshaped to per-(actor, batch-slot, site) refs.
-        free_rows: Dict[str, jnp.ndarray] = {}
-
-        def rspill_hits(rspill_tgt, any_rspill):
-            """[nl] bool: rows some shard's route-spill still addresses.
-            A message parked in *another shard's* route-spill may be
-            addressed to a locally dead row; reclaiming that row would
-            deliver the stale message to the newborn. One psum over the
-            mesh makes every shard's rspill targets globally visible —
-            the cross-shard twin of the dspill_pending guard. Gated on
-            world bit2: with every shard's route-spill empty (the
-            steady state) the psum is skipped and zeros are exact."""
-            if not (program.spawn_target_names and p > 1):
-                return jnp.zeros((nl,), jnp.bool_)
-
-            def _rhit(_):
-                rhit = jnp.zeros((p * nl,), jnp.int32).at[
-                    jnp.maximum(rspill_tgt, 0)].max(
-                    (rspill_tgt >= 0).astype(jnp.int32), mode="drop")
-                rhit = lax.psum(rhit, "actors")
-                return lax.dynamic_slice(rhit, (base,), (nl,)) > 0
-            return lax.cond(
-                any_rspill, _rhit,
-                lambda _: jnp.zeros((nl,), jnp.bool_), operand=None)
-
-        def free_mask(tc, alive_, occ_, pending_, rhit_):
-            """A target cohort's claimable rows: dead, drained, nothing
-            parked for the last tenant in any spill."""
-            s0, s1 = tc.local_start, tc.local_stop
-            return (~alive_[s0:s1] & (occ_[s0:s1] == 0)
-                    & (pending_[s0:s1] == 0) & ~rhit_[s0:s1])
-
-        rspill_hit = rspill_hits(st.rspill_tgt, world_rspill)
-        for tname in program.spawn_target_names:
-            tc = program.by_type_name(tname)
-            with phase_scope("spawn/free"):
-                perm, vfree, _ = compact_mask(
-                    free_mask(tc, st.alive, occ0, dspill_pending,
-                              rspill_hit), tc.local_capacity)
-                free_rows[tname] = jnp.where(
-                    vfree, tc.local_start + perm.astype(jnp.int32),
-                    jnp.int32(-1))
-
-        # --- 2. drain + dispatch per cohort (≙ actor run loop).
-        runnable = st.alive & ~muted
-
-        def cohort_resv(ch):
-            """Per-actor spawn reservations: the rows that can DISPATCH
-            this tick (runnable and holding a message — only a dispatch
-            can spawn) get disjoint spawn_dispatches × sites windows
-            into the target's free rows, ranked by a cumsum over that
-            mask. An idle row reserves nothing, whether it waits for a
-            reply or is garbage the collector has not reached yet — see
-            Program._resolve_spawns."""
-            resv = {}
-            if not ch.spawns:
-                return resv
-            s0, s1 = ch.local_start, ch.local_stop
-            with phase_scope("spawn/reserve"):
-                run_c = runnable[s0:s1] & (occ0[s0:s1] > 0)
-                rank = jnp.cumsum(run_c.astype(jnp.int32)) - 1
-                sd = ch.spawn_dispatches
-                for tname, sites in sorted(ch.spawns.items()):
-                    per = sd * sites
-                    off = ch.spawn_offsets[tname]
-                    widx = jnp.where(run_c, rank * per, 0)
-                    # Planar [sd, sites, rows]: the per-(dispatch, site)
-                    # offsets are the small major axes, actor lanes
-                    # minor.
-                    idx = (off + widx[None, None, :]
-                           + (jnp.arange(sd, dtype=jnp.int32)
-                              * sites)[:, None, None]
-                           + jnp.arange(sites,
-                                        dtype=jnp.int32)[None, :, None])
-                    rows = jnp.take(free_rows[tname], idx, mode="fill",
-                                    fill_value=-1)
-                    resv[tname] = jnp.where(
-                        (rows >= 0) & run_c[None, None, :],
-                        base + rows, jnp.int32(-1))
-            return resv
-
-        # --- 2a'. device blob pool reservations (the spawn-reservation
-        # pattern applied to the "actor heap": compact this shard's free
-        # pool slots, hand each allocating cohort its statically-
-        # partitioned window; ≙ pony_alloc on the owning actor's heap,
-        # done race-free ahead of the planar dispatch).
-        blob_en = opts.blob_slots > 0
-        if blob_en:
-            bsl = opts.blob_slots
-            bbase = shard * bsl
-            # Idle costs nothing (the fork's thesis, README.md:8-10):
-            # the free-slot compaction feeds only reservation windows,
-            # and no window is READ unless an allocating cohort
-            # dispatches — so skip the sort when none has queued work.
-            alloc_busy = jnp.bool_(False)
-            for _ch in dev_cohorts:
-                if _ch.blob_sites and _ch.blob_dispatches:
-                    _sl = slice(_ch.local_start, _ch.local_stop)
-                    alloc_busy = alloc_busy | jnp.any(
-                        runnable[_sl] & (occ0[_sl] > 0))
-
-            def _compact_free(_):
-                bperm, bvfree, _n = compact_mask(~st.blob_used, bsl)
-                return jnp.where(bvfree,
-                                 bbase + bperm.astype(jnp.int32),
-                                 jnp.int32(-1))
-            free_blob = lax.cond(
-                alloc_busy, _compact_free,
-                lambda _: jnp.full((bsl,), -1, jnp.int32), operand=None)
-        blob_cur = (st.blob_data, st.blob_used, st.blob_len, st.blob_gen)
-        blob_fail = st.blob_fail[0]
-        blob_budget = st.blob_budget_fail[0]
-        nb_alloc = jnp.int32(0)
-        nb_free = jnp.int32(0)
-        nb_remote = jnp.int32(0)
-
-        def cohort_blob_resv(ch):
-            """[bd, sites, rows] reserved global blob handles: each
-            runnable actor gets blob_dispatches×sites disjoint windows
-            into the compacted free list (idle actors reserve nothing);
-            a used-counter walk hands one window to each dispatch that
-            actually allocates (the spawn_dispatches pattern)."""
-            sites = ch.blob_sites
-            bd = ch.blob_dispatches
-            if not sites:
-                return jnp.zeros((bd, 0, ch.local_capacity), jnp.int32)
-            run_c = runnable[ch.local_start:ch.local_stop]
-            rank = jnp.cumsum(run_c.astype(jnp.int32)) - 1
-            per = bd * sites
-            widx = jnp.where(run_c, rank * per, 0)
-            idx = (ch.blob_offset + widx[None, None, :]
-                   + (jnp.arange(bd, dtype=jnp.int32)
-                      * sites)[:, None, None]
-                   + jnp.arange(sites, dtype=jnp.int32)[None, :, None])
-            handles = jnp.take(free_blob, idx, mode="fill", fill_value=-1)
-            return jnp.where(run_c[None, None, :], handles, jnp.int32(-1))
-        phase("dispatch")
-        new_type_state: Dict[str, Dict[str, Any]] = dict(st.type_state)
-        head_segments: List[jnp.ndarray] = []
-        out_entries: List[Entries] = []
-        claim_lists: Dict[str, List[jnp.ndarray]] = {
-            t: [] for t in program.spawn_target_names}
-        init_lists: Dict[str, List[Any]] = {
-            t: [] for t in program.spawn_target_names}
-        destroy_rows: List[Tuple[int, jnp.ndarray]] = []  # (s0, [rows] bool)
-        error_rows: List[Tuple[int, Any]] = []   # (s0, ([rows] bool, codes))
-        exit_f = st.exit_flag[0]
-        exit_c = st.exit_code[0]
-        spawn_fail = st.spawn_fail[0]
-        nproc_total = jnp.int32(0)
-        nbad_total = jnp.int32(0)
-        drain_facts = []   # (cohort, head before, head after) — feeds
-        #   the profiler lanes (profile_lanes) when analysis >= 1
-        for run_cohort, ch in dispatchers:
-            s0, s1 = ch.local_start, ch.local_stop
-            ids = base + s0 + jnp.arange(ch.local_capacity, dtype=jnp.int32)
-            with phase_scope("spawn"):
-                resv = cohort_resv(ch)
-                if blob_en and ch.uses_blobs:
-                    blobd = {"data": blob_cur[0], "used": blob_cur[1],
-                             "len": blob_cur[2], "gen": blob_cur[3],
-                             "base": bbase, "resv": cohort_blob_resv(ch)}
-                else:
-                    blobd = None
-            (stf, out, new_head_rows, ef, ec, nproc, nbad, claims, inits,
-             sfail, dstr, errs, blob_out) = run_cohort(
-                st.type_state[ch.atype.__name__],
-                st.buf[ch.atype.__name__], st.head[s0:s1], occ0[s0:s1],
-                runnable[s0:s1], ids, resv, blob=blobd)
-            if blob_out is not None:
-                blob_cur = blob_out[:4]
-                blob_fail = blob_fail | blob_out[4]
-                blob_budget = blob_budget | blob_out[5]
-                nb_alloc = nb_alloc + blob_out[6]
-                nb_free = nb_free + blob_out[7]
-                nb_remote = nb_remote + blob_out[8]
-            new_type_state[ch.atype.__name__] = stf
-            head_segments.append(new_head_rows)
-            if opts.analysis >= 1:
-                drain_facts.append((ch, st.head[s0:s1], new_head_rows))
-            out_entries.append(out)
-            for t, cl in claims.items():
-                claim_lists[t].append(cl)
-                init_lists[t].append(None if inits is None else inits[t])
-            if ch.spawns:
-                spawn_fail = spawn_fail | sfail
-            destroy_rows.append((s0, dstr))
-            error_rows.append((s0, errs))
-            exit_c = jnp.where(ef & ~exit_f, ec, exit_c)
-            exit_f = exit_f | ef
-            nproc_total = nproc_total + nproc
-            nbad_total = nbad_total + nbad
-        if fh < nl:  # host-cohort heads unchanged by device dispatch
-            head_segments.append(st.head[fh:nl])
-        new_head = (jnp.concatenate(head_segments) if head_segments
-                    else st.head)
-
-        phase("spawn")
-        # --- 2b. apply spawn claims (before delivery, so constructor
-        # messages and same-step sends to the newborn land): claimed rows
-        # become alive with a fresh empty mailbox and zeroed state fields
-        # (the constructor behaviour initialises them — Pony's `create` is
-        # itself the first message).
-        alive = st.alive
-        tail0 = st.tail
-        n_spawned = jnp.int32(0)
-        with phase_scope("spawn/claim"):
-            for tname, clist in claim_lists.items():
-                if not clist:
-                    continue
-                refs = jnp.concatenate(clist)
-                any_sync = any(e is not None for e in init_lists[tname])
-                # Every claimed row is claimed once (the windows are
-                # disjoint), so "which rows were claimed" is membership:
-                # one mask (ops.segment.marks_of — a sort and a merge,
-                # where a scatter of the claim list runs one update after
-                # another), then selects over the rows.
-                claimed = marks_of(jnp.where(refs >= 0, refs - base, -1), nl)
-                alive = alive | claimed
-                new_head = jnp.where(claimed, 0, new_head)
-                tail0 = jnp.where(claimed, 0, tail0)
-                n_spawned = n_spawned + jnp.sum(
-                    (refs >= 0).astype(jnp.int32))
-                tc = program.by_type_name(tname)
-                born = claimed[tc.local_start:tc.local_stop]
-                ts = dict(new_type_state[tname])
-                if any_sync:
-                    # Sync-constructed spawns (spawn_sync) land their
-                    # constructor's field values, claim by claim; cohorts
-                    # that never spawn_sync contribute constant-False
-                    # has-masks (the lanes cost only exists when some
-                    # behaviour of the program actually sync-constructs).
-                    cols = jnp.where(refs >= 0, refs - base - tc.local_start,
-                                     tc.local_capacity)
-                    has_init = jnp.concatenate(
-                        [e[0] if e is not None
-                         else jnp.zeros((cl.shape[0],), jnp.bool_)
-                         for e, cl in zip(init_lists[tname], clist)])
-                for fname in ts:
-                    # async spawns zero and let the constructor message
-                    # initialise
-                    default = pack.null_word(tc.atype.field_specs[fname])
-                    ts[fname] = jnp.where(born, default, ts[fname])
-                    if any_sync:
-                        vals = jnp.concatenate(
-                            [e[1][fname] if e is not None
-                             else jnp.zeros((cl.shape[0],), ts[fname].dtype)
-                             for e, cl in zip(init_lists[tname], clist)])
-                        ts[fname] = ts[fname].at[
-                            jnp.where(has_init, cols, tc.local_capacity)
-                        ].set(vals.astype(ts[fname].dtype), mode="drop")
-                new_type_state[tname] = ts
-
-        # --- 2c. causal-trace spans + context propagation (tracing on
-        # only; the Python-level gate keeps the jaxpr bit-identical to
-        # a tracer-free build otherwise — tests/test_tracing.py traps
-        # trace_span_lanes to prove it). Every cohort's outbox gains
-        # two trailing word rows carrying (trace_id, span_id) of the
-        # dispatch that emitted each entry; spills, routing and
-        # delivery move them with the payload from here on.
-        if tracing:
-            phase("analysis")
-            (span_data2, span_count2, span_dropped2, span_next2,
-             tr_rows) = trace_span_lanes(program, opts, st, drain_facts,
-                                         base, shard)
-            out_entries = [
-                o._replace(words=jnp.concatenate([o.words, t], axis=0))
-                for o, t in zip(out_entries, tr_rows)]
-
-        # --- 3. route (mesh) or pass through (single chip).
-        phase("route")
-        rspill_e = Entries(st.rspill_tgt, st.rspill_sender, st.rspill_words)
-        out_cat = Entries(
-            tgt=jnp.concatenate([rspill_e.tgt] +
-                                [o.tgt for o in out_entries]),
-            sender=jnp.concatenate([rspill_e.sender] +
-                                   [o.sender for o in out_entries]),
-            words=jnp.concatenate([rspill_e.words] +
-                                  [o.words for o in out_entries], axis=1),
-        )
-        route_muted = jnp.zeros((nl,), jnp.bool_)
-        route_refs, route_ovf = empty_mute_slots(nl, opts.mute_slots)
-        if p > 1:
-            rblob = None
-            if route_blobs:
-                rblob = {"data": blob_cur[0], "used": blob_cur[1],
-                         "len": blob_cur[2], "gen": blob_cur[3],
-                         "bbase": bbase, "bsl": bsl, "shard": shard,
-                         "mask": _blob_route_mask,
-                         "mask_iso": _blob_route_mask_iso}
-            (incoming, new_rspill, rsp_count, rsp_over, route_muted,
-             route_refs, route_ovf, route_blob_out, routed) = _route(
-                out_cat, shards=p, n_local=nl, bucket=bucket,
-                rspill_cap=s_cap, overload_occ=opts.overload_occ,
-                head=new_head, tail=tail0, shard_base=base,
-                mute_slots=opts.mute_slots,
-                pressured_global=pressured_global,
-                pressured_local=st.pressured, blob=rblob)
-            if route_blob_out is not None:
-                blob_cur, n_ship, n_recv, n_drop = route_blob_out
-                nb_free = nb_free + n_ship
-                nb_alloc = nb_alloc + n_recv
-                nb_moved = n_recv
-                nb_remote = nb_remote + n_drop
-            else:
-                nb_moved = jnp.int32(0)
-            if not short_list:
-                incoming = local_rows(incoming)
-        else:
-            incoming = local_rows(out_cat)
-            new_rspill = Entries(st.rspill_tgt, st.rspill_sender,
-                                 st.rspill_words)   # unused, stays empty
-            rsp_count = st.rspill_count[0]
-            rsp_over = jnp.bool_(False)
-            nb_moved = jnp.int32(0)
-
-        # --- 4. delivery list: receiver spill first (oldest), then host
-        # injections, then routed messages. Injections are replicated to
-        # all shards; each shard keeps only rows it owns.
-        def delivery_list(incoming):
-            """`incoming` (local rows) behind the receiver spill and the
-            injections, and every entry's level."""
-            inj_l = inject_tgt - base
-            inj_local = jnp.where((inj_l >= 0) & (inj_l < nl), inj_l, -1)
-            dspill_e = Entries(st.dspill_tgt, st.dspill_sender,
-                               st.dspill_words)
-            all_e = Entries(
-                tgt=jnp.concatenate([dspill_e.tgt, inj_local,
-                                     incoming.tgt]),
-                sender=jnp.concatenate([dspill_e.sender,
-                                        jnp.full_like(inj_local, -1),
-                                        incoming.sender]),
-                words=jnp.concatenate([dspill_e.words, inject_words,
-                                       incoming.words], axis=1),
-            )
-
-            # The level of an incoming entry is its sender's cohort's: a
-            # constant of the program when it has one priority, and on
-            # one chip a constant of each segment of `incoming` (the
-            # route spill, empty there, then one outbox a cohort). Only
-            # a mesh with several priorities has to ask each entry for
-            # its sender.
-            if len(pri_sorted) <= 1:
-                lvl_in = jnp.full_like(incoming.tgt, 2)
-            elif p == 1:
-                lvl_in = jnp.concatenate(
-                    [jnp.full_like(rspill_e.tgt, 2)]
-                    + [jnp.full_like(o.tgt, 2 + pri_rank[ch.priority])
-                       for ch, o in zip(dev_cohorts, out_entries)])
-            else:
-                prio_row = _np.zeros((nl,), _np.int32)
-                for ch in dev_cohorts:
-                    prio_row[ch.local_start:ch.local_stop] = \
-                        pri_rank[ch.priority]
-                snd_in = incoming.sender
-                srow = jnp.where(snd_in >= 0, snd_in, 0) % nl
-                lvl_in = jnp.where(snd_in >= 0,
-                                   2 + jnp.asarray(prio_row)[srow],
-                                   jnp.int32(2)).astype(jnp.int32)
-            lvl_all = jnp.concatenate([
-                jnp.zeros_like(dspill_e.tgt),
-                jnp.ones_like(inj_local),
-                lvl_in])
-            return all_e, lvl_all
-
-        def delivered(all_e, lvl_all, plan):
-            return deliver(st.buf, new_head, tail0, alive, all_e,
-                           n_local=nl, mailbox_cap=c, spill_cap=s_cap,
-                           overload_occ=opts.overload_occ, shard_base=base,
-                           cohort_layout=cohort_layout,
-                           mute_slots=opts.mute_slots,
-                           level=lvl_all, n_levels=n_levels, plan=plan,
-                           pressured=st.pressured,
-                           cosort=(opts.delivery == "cosort"),
-                           trace_buf=st.trace_buf if tracing else None)
-
-        plan = (st.plan_key, st.plan_perm, st.plan_bounds)
-        n_unpacked = jnp.int32(0)
-        if not short_list:
-            all_e, lvl_all = delivery_list(incoming)
-            phase("delivery")
-            res = delivered(all_e, lvl_all, plan)
-        else:
-            # A meshed shard delivers over what ARRIVED. The received
-            # buckets are `p * bucket` entries whatever came (at the
-            # default bucket four outboxes' worth for one outbox's worth
-            # of messages), and every list phase of delivery is paid by
-            # the entry. So the window holds delivery at two static
-            # lengths and the tick's arrivals choose: where they fit one
-            # shard's outbox (`l_in`: what a shard can emit is what a
-            # balanced world hands it back) the buckets are joined front
-            # to front (`_route_unpack`) and delivery runs over
-            # `e_short` entries; a tick that does not fit — a skewed
-            # one, a fan-in onto this shard — runs the list it always
-            # ran. Same mailboxes, tails, spill and mutes either way:
-            # delivery is stable in arrival order and sorts the invalid
-            # last. `deliver` holds no collective, so each shard takes
-            # its own branch.
-            with phase_scope("route/unpack"):
-                fill = jnp.sum(
-                    (incoming.tgt >= 0).reshape(p, bucket).astype(jnp.int32),
-                    axis=1)
-                fits = jnp.sum(fill) <= l_in
-            n_unpacked = fits.astype(jnp.int32)
-
-            def over(incoming, plan):
-                with phase_scope("route"):
-                    all_e, lvl_all = delivery_list(local_rows(incoming))
-                with phase_scope("delivery"):
-                    return delivered(all_e, lvl_all, plan)
-
-            def short(_):
-                joined = _route_unpack(incoming, fill, shards=p,
-                                       bucket=bucket, l_in=l_in)
-                with phase_scope("delivery/plan"):
-                    cached = _short_plan(plan, e_short)
-                res = over(joined, cached)
-                with phase_scope("delivery/plan"):
-                    key, perm = _store_short_plan(plan, res.plan_key,
-                                                  res.plan_perm)
-                return res._replace(plan_key=key, plan_perm=perm)
-
-            phase("delivery")
-            res = lax.cond(fits, short, lambda _: over(incoming, plan),
-                           operand=None)
-            if opts.analysis >= 1:
-                # phase_cost_lanes counts the list's valid entries, and
-                # the long list holds the same ones: built here for its
-                # targets alone, the rest of it is dead code.
-                all_e, _ = delivery_list(local_rows(incoming))
-
-        phase("gc_mark")
-        # --- 4b. apply destroys (≙ ponyint_actor_setpendingdestroy +
-        # ponyint_actor_destroy, actor.c:570-664): the slot dies at end of
-        # step; its remaining queue is discarded (head := tail), flags
-        # clear, and the row becomes reclaimable by a later spawn.
-        new_tail = res.tail
-        pinned = st.pinned
-        pressured = st.pressured
-        # Int-coded error residue (≙ pony_error_int/code, fork): latest
-        # nonzero code per actor + a counter; zero-cost for cohorts whose
-        # behaviours never call ctx.error_int (gated at trace).
-        last_error = st.last_error
-        last_error_loc = st.last_error_loc
-        n_errors = jnp.int32(0)
-        for s0, errs in error_rows:
-            if errs is None:
-                continue
-            errf, errc, errl = errs
-            rows = jnp.where(errf, s0 + jnp.arange(errf.shape[0],
-                                                   dtype=jnp.int32), nl)
-            last_error = last_error.at[rows].set(
-                jnp.where(errf, errc, 0), mode="drop")
-            last_error_loc = last_error_loc.at[rows].set(
-                jnp.where(errf, errl, 0), mode="drop")
-            n_errors = n_errors + jnp.sum(errf.astype(jnp.int32))
-        n_destroyed = jnp.int32(0)
-        for s0, dstr in destroy_rows:
-            if dstr is None:
-                continue
-            rows = jnp.where(dstr, s0 + jnp.arange(dstr.shape[0],
-                                                   dtype=jnp.int32), nl)
-            alive = alive.at[rows].set(False, mode="drop")
-            new_head = new_head.at[rows].set(
-                jnp.take(new_tail, jnp.minimum(rows, nl - 1)), mode="drop")
-            muted = muted.at[rows].set(False, mode="drop")
-            mute_refs = mute_refs.at[:, rows].set(-1, mode="drop")
-            mute_ovf = mute_ovf.at[rows].set(False, mode="drop")
-            pinned = pinned.at[rows].set(False, mode="drop")
-            pressured = pressured.at[rows].set(False, mode="drop")
-            n_destroyed = n_destroyed + jnp.sum(dstr.astype(jnp.int32))
-
-        phase("mute")
-        # --- 5. mute bookkeeping (≙ ponyint_mute_actor + mutemap insert,
-        # actor.c:1171-1207, mutemap.c): this tick's muting refs from
-        # delivery and routing MERGE into each sender's slot table (a
-        # re-muted sender keeps its older muters); a slot collision
-        # between distinct refs sets the sticky overflow bit.
-        def _merge_slots(a, b):
-            both = (a >= 0) & (b >= 0)
-            m = jnp.where(a < 0, b, jnp.where(b < 0, a, jnp.maximum(a, b)))
-            return m, jnp.any(both & (a != b), axis=0)
-
-        newly = (res.newly_muted | route_muted) & alive
-        became_muted = newly & ~muted
-        muted2 = muted | newly
-        # Consecutive-muted-tick counter (see the aging release above):
-        # +1 while muted, reset on release or fresh mute.
-        mute_age2 = jnp.where(muted2,
-                              jnp.where(became_muted, 0,
-                                        st.mute_age + 1),
-                              0)
-
-        def merge_mutes(_):
-            inc_refs, c1 = _merge_slots(res.new_mute_refs, route_refs)
-            merged_refs, c2 = _merge_slots(mute_refs, inc_refs)
-            return (jnp.where(newly[None, :], merged_refs, mute_refs),
-                    jnp.where(newly,
-                              mute_ovf | res.new_mute_ovf | route_ovf
-                              | c1 | c2,
-                              mute_ovf))
-
-        # The [K, N] slot-table merge only runs on ticks that actually
-        # muted someone (≙ mutemap inserts happening only on mute).
-        mute_refs2, mute_ovf2 = lax.cond(
-            jnp.any(newly), merge_mutes,
-            lambda _: (mute_refs, mute_ovf), operand=None)
-
-        # --- 5b. per-event trace ring (analysis level 3 only; ≙ the
-        # fork's per-event analysis rows, analysis.c:587-692): record the
-        # tick's TRANSITIONS (mute, unmute, overload-on, spawn, destroy,
-        # error) as (event, actor, step) triples compacted into a bounded
-        # ring the host drains at window boundaries. Traced only when
-        # enabled; and under a cond so event-free ticks skip the
-        # compaction sort.
-        occ_after = new_tail - new_head
-        ev_data, ev_count, ev_dropped = (st.ev_data, st.ev_count[0],
-                                         st.ev_dropped[0])
-        if opts.analysis >= 1:
-            phase("analysis")
-        if opts.analysis >= 3:
-            released_ev = st.muted & ~muted & alive
-            over_ev = (occ_after > opts.overload_occ) \
-                & ~(occ0 > opts.overload_occ)
-            spawn_ev = alive & ~st.alive
-            destroy_ev = st.alive & ~alive
-            err_ev = jnp.zeros((nl,), jnp.bool_)
-            for s0, errs in error_rows:
-                if errs is None:
-                    continue
-                errf = errs[0]
-                rows_ = s0 + jnp.arange(errf.shape[0], dtype=jnp.int32)
-                err_ev = err_ev.at[rows_].max(errf)
-            classes = [(1, became_muted), (2, released_ev), (3, over_ev),
-                       (4, spawn_ev), (5, destroy_ev), (6, err_ev)]
-            masks = jnp.concatenate([m for _, m in classes])
-            ev_cap = opts.analysis_events
-
-            # A tick can produce at most len(classes)*nl events.
-            k_ev = min(ev_cap, masks.shape[0])
-
-            def record(_):
-                codes = jnp.concatenate(
-                    [jnp.full((nl,), cde, jnp.int32) for cde, _ in classes])
-                actors = base + jnp.tile(
-                    jnp.arange(nl, dtype=jnp.int32), len(classes))
-                perm2, valid2, total2 = compact_mask(masks, k_ev)
-                pos = ev_count + jnp.arange(k_ev, dtype=jnp.int32)
-                ok = valid2 & (pos < ev_cap)
-                posc = jnp.where(ok, pos, ev_cap)
-                ev = ev_data
-                ev = ev.at[0, posc].set(
-                    jnp.where(ok, codes[perm2], 0), mode="drop")
-                ev = ev.at[1, posc].set(
-                    jnp.where(ok, actors[perm2], 0), mode="drop")
-                ev = ev.at[2, posc].set(
-                    jnp.full((k_ev,), st.step_no[0] + 1), mode="drop")
-                return (ev, jnp.minimum(ev_count + total2, ev_cap),
-                        ev_dropped + jnp.maximum(
-                            0, ev_count + total2 - ev_cap))
-
-            ev_data, ev_count, ev_dropped = lax.cond(
-                jnp.any(masks), record,
-                lambda _: (ev_data, ev_count, ev_dropped), operand=None)
-
-        # --- 5c. per-behaviour profiler lanes (analysis level >= 1 only;
-        # the gate is PYTHON-level, so level 0 traces none of this —
-        # tests trap profile_lanes to assert exactly that).
-        if opts.analysis >= 1:
-            (beh_runs2, beh_del2, beh_rej2, coh_mt2, qw_hist2,
-             qw_enq2) = profile_lanes(program, opts, st, tail0, res,
-                                      drain_facts, muted2)
-            phase_cost2 = phase_cost_lanes(
-                st, all_e, drain_facts, nproc_total, n_spawned,
-                n_destroyed, res.rebuild_slots)
-        else:
-            beh_runs2, beh_del2, beh_rej2 = (st.beh_runs,
-                                             st.beh_delivered,
-                                             st.beh_rejected)
-            coh_mt2, qw_hist2 = st.coh_mute_ticks, st.qwait_hist
-            qw_enq2 = dict(st.qwait_enq)
-            phase_cost2 = st.phase_cost
-
-        # --- 6. the vote: the tick's facts reduced to the aux the window's
-        # continue test (aux_go) and the host read.
-        phase("vote")
-        nrej_new = st.n_rejected[0] + res.n_rejected
-        nbad_new = st.n_badmsg[0] + nbad_total
-        ndl_new = st.n_deadletter[0] + res.n_deadletter
-        nmut_new = st.n_mutes[0] + jnp.sum(became_muted.astype(jnp.int32))
-        if opts.analysis >= 1:
-            occ_sum = jnp.sum(occ_after)
-            occ_max = jnp.max(occ_after)
-            n_muted_now = jnp.sum(muted2.astype(jnp.int32))
-            n_over_now = jnp.sum(
-                (occ_after > opts.overload_occ).astype(jnp.int32))
-            nrej_all, nbad_all, ndl_all, nmut_all = (
-                nrej_new, nbad_new, ndl_new, nmut_new)
-            # Worst-cohort queue-wait p99 of the cumulative histograms —
-            # in-trace twin of analysis.hist_percentile (bucket k holds
-            # waits in [2^k, 2^(k+1)); the reported value is the lower
-            # bound of the first bucket whose cumulative count reaches
-            # ceil(0.99 * total)). Rides the aux so the host's window
-            # controller sees queue-wait pressure with no extra fetch.
-            nd_prof = qw_hist2.shape[0] // QW_BUCKETS
-            if nd_prof > 0:
-                h2 = qw_hist2.reshape(nd_prof, QW_BUCKETS)
-                tot = jnp.sum(h2, axis=1)
-                need = jnp.maximum(1, (tot * 99 + 99) // 100)
-                first = jnp.argmax(
-                    jnp.cumsum(h2, axis=1) >= need[:, None],
-                    axis=1).astype(jnp.int32)
-                qw_p99 = jnp.max(jnp.where(
-                    tot > 0, jnp.left_shift(jnp.int32(1), first),
-                    jnp.int32(0)))
-            else:
-                qw_p99 = jnp.int32(0)
-        else:
-            occ_sum = occ_max = n_muted_now = n_over_now = jnp.int32(0)
-            nrej_all = nbad_all = ndl_all = nmut_all = jnp.int32(0)
-            qw_p99 = jnp.int32(0)
-        local_pending = (jnp.any(occ_after[:fh] > 0)
-                         | (res.spill_count > 0) | (rsp_count > 0))
-        any_muted_local = jnp.any(muted2)
-        host_pending = (jnp.any(occ_after[fh:] > 0) if fh < nl
-                        else jnp.bool_(False))
-        # Sticky: once any step overflowed, every later aux reports it, so
-        # the host catches it whatever its fetch cadence (quiesce_interval).
-        overflow = st.spill_overflow[0] | res.spill_overflow | rsp_over
-        # End-of-tick facts feeding the next tick's gather gates (exact,
-        # not conservative: `pressured`/`muted2` are post-destroy finals,
-        # `rsp_count` is the post-route spill count).
-        any_pressured_local = jnp.any(pressured)
-        any_rspill_local = rsp_count > 0
-        if p > 1:
-            # ONE packed psum + ONE packed pmax replace the former ~17
-            # separate collectives (≙ the CNF/ACK token protocol being a
-            # single token, not one message per fact, scheduler.c:303-480).
-            # Booleans ride as 0/1 counts ("any" = sum > 0); cumulative
-            # counters wrap mod 2^32 exactly as the per-shard counters do.
-            i32c = lambda x: jnp.asarray(x, jnp.int32)  # noqa: E731
-            summed = lax.psum(jnp.stack([
-                i32c(spawn_fail), i32c(local_pending),
-                i32c(any_muted_local), i32c(host_pending),
-                i32c(exit_f), i32c(overflow),
-                i32c(any_pressured_local), i32c(any_rspill_local),
-                st.n_processed[0] + nproc_total,
-                st.n_delivered[0] + res.n_delivered,
-                occ_sum, n_muted_now, n_over_now,
-                nrej_all, nbad_all, ndl_all, nmut_all,
-                i32c(blob_fail), i32c(blob_budget)]), "actors")
-            spawn_fail_any = summed[0] > 0
-            device_pending = summed[1] > 0
-            any_muted_all = summed[2] > 0
-            host_pending = summed[3] > 0
-            exit_any = summed[4] > 0
-            overflow_any = summed[5] > 0
-            any_pressured_all = summed[6] > 0
-            any_rspill_all = summed[7] > 0
-            nproc_all = summed[8]
-            ndel_all = summed[9]
-            blob_fail_any = summed[17] > 0
-            blob_budget_any = summed[18] > 0
-            if opts.analysis >= 1:
-                occ_sum, n_muted_now, n_over_now = (summed[10], summed[11],
-                                                    summed[12])
-                nrej_all, nbad_all, ndl_all, nmut_all = (
-                    summed[13], summed[14], summed[15], summed[16])
-            maxed = lax.pmax(jnp.stack([
-                jnp.where(exit_f, exit_c, jnp.int32(-2**31)), occ_max,
-                qw_p99]), "actors")
-            exit_code_all = jnp.where(exit_any, maxed[0], exit_c)
-            if opts.analysis >= 1:
-                occ_max = maxed[1]
-                qw_p99 = maxed[2]
-        else:
-            spawn_fail_any = spawn_fail
-            device_pending = local_pending
-            any_muted_all = any_muted_local
-            exit_any = exit_f
-            exit_code_all = exit_c
-            overflow_any = overflow
-            any_pressured_all = any_pressured_local
-            any_rspill_all = any_rspill_local
-            nproc_all = st.n_processed[0] + nproc_total
-            ndel_all = st.n_delivered[0] + res.n_delivered
-            blob_fail_any = blob_fail
-            blob_budget_any = blob_budget
-        # Row pressure (a program with device spawns only): what the
-        # NEXT tick's reservations will find, read off this tick's final
-        # state with the next tick's own predicates: the free rows by
-        # free_mask; the rows that will reserve, those that hold a
-        # message (muted or not: an unmute may release them first). A
-        # row can be refused only when it dispatches a behaviour that
-        # spawns, so a spawner cohort needs its window up to the LAST
-        # such row: spawn_offset + (that row's rank among the reserving
-        # rows + 1) × spawn_dispatches × sites.
-        spawn_aux = {}
-        if program.has_device_spawns:
-            with phase_scope("spawn/reserve"):
-                pending2 = lax.cond(
-                    res.spill_count > 0,
-                    lambda _: counts_by_key(
-                        jnp.minimum(jnp.maximum(res.spill.tgt, 0), nl - 1),
-                        (res.spill.tgt >= 0).astype(jnp.int32), nl),
-                    lambda _: jnp.zeros((nl,), jnp.int32), operand=None)
-                rhit2 = rspill_hits(new_rspill.tgt, any_rspill_all)
-                n_free = {
-                    t: jnp.sum(free_mask(
-                        program.by_type_name(t), alive, occ_after, pending2,
-                        rhit2).astype(jnp.int32))
-                    for t in program.spawn_target_names}
-                room = jnp.int32(2**31 - 1)
-                for ch in dev_cohorts:
-                    if not ch.spawns:
-                        continue
-                    s0, s1 = ch.local_start, ch.local_stop
-                    holds = alive[s0:s1] & (occ_after[s0:s1] > 0)
-                    may = spawning(ch)
-                    wants = holds
-                    if not may.all():
-                        # some behaviour never spawns: ask the messages
-                        # the next dispatch will take which they are
-                        gid0 = ch.behaviours[0].global_id
-                        gids = res.buf[ch.atype.__name__][:, :1, :]
-                        wants = jnp.zeros_like(holds)
-                        for k in range(ch.batch):
-                            beh = _ring_take(
-                                gids, (new_head[s0:s1] + k) % c)[0] - gid0
-                            wants = wants | (
-                                (k < occ_after[s0:s1])
-                                & (beh >= 0) & (beh < len(may))
-                                & jnp.asarray(may)[
-                                    jnp.clip(beh, 0, len(may) - 1)])
-                        wants = wants & holds
-                    last = jnp.max(jnp.where(
-                        wants, jnp.cumsum(holds.astype(jnp.int32)), 0))
-                    for tname, sites in ch.spawns.items():
-                        room = jnp.minimum(room, n_free[tname] - (
-                            ch.spawn_offsets[tname] * (last > 0)
-                            + last * ch.spawn_dispatches * sites))
-                born = st.n_spawned[0] + n_spawned
-                if p > 1:
-                    room = lax.pmin(room, "actors")
-                    born = lax.psum(born, "actors")
-            spawn_aux = {"room": room,
-                         "low": jnp.where(room >= 0, room,
-                                          jnp.int32(2**31 - 1)),
-                         "spawned": born}
-        wb_new =(any_pressured_all.astype(jnp.int32)
-                  | (any_muted_all.astype(jnp.int32) << 1)
-                  | (any_rspill_all.astype(jnp.int32) << 2))
-
-        def vec(x, dtype=None):   # per-shard "scalar" → [1]
-            return jnp.asarray(x, dtype).reshape(1)
-
-        st2 = RtState(
-            buf=res.buf, head=new_head, tail=new_tail,
-            alive=alive, muted=muted2, mute_refs=mute_refs2,
-            mute_age=mute_age2,
-            mute_ovf=mute_ovf2, pinned=pinned, pressured=pressured,
-            dspill_tgt=res.spill.tgt, dspill_sender=res.spill.sender,
-            dspill_words=res.spill.words,
-            dspill_count=vec(res.spill_count),
-            rspill_tgt=new_rspill.tgt, rspill_sender=new_rspill.sender,
-            rspill_words=new_rspill.words,
-            rspill_count=vec(rsp_count),
-            route_counts=({name: vec(st.route_counts[name][0] + n)
-                           for name, n in zip(ROUTE_COUNTERS,
-                                              (*routed, n_unpacked))}
-                          if p > 1 else st.route_counts),
-            spill_overflow=vec(overflow, jnp.bool_),
-            exit_flag=vec(exit_f, jnp.bool_), exit_code=vec(exit_c),
-            step_no=vec(st.step_no[0] + 1),
-            n_processed=vec(st.n_processed[0] + nproc_total),
-            n_delivered=vec(st.n_delivered[0] + res.n_delivered),
-            n_rejected=vec(nrej_new),
-            n_badmsg=vec(nbad_new),
-            n_deadletter=vec(ndl_new),
-            n_mutes=vec(nmut_new),
-            n_spawned=vec(st.n_spawned[0] + n_spawned),
-            n_destroyed=vec(st.n_destroyed[0] + n_destroyed),
-            spawn_fail=vec(spawn_fail, jnp.bool_),
-            n_collected=st.n_collected,
-            last_error=last_error, last_error_loc=last_error_loc,
-            n_errors=vec(st.n_errors[0] + n_errors),
-            ev_data=ev_data, ev_count=vec(ev_count),
-            ev_dropped=vec(ev_dropped),
-            beh_runs=beh_runs2, beh_delivered=beh_del2,
-            beh_rejected=beh_rej2, coh_mute_ticks=coh_mt2,
-            qwait_hist=qw_hist2, qwait_enq=qw_enq2,
-            phase_cost=phase_cost2,
-            trace_buf=res.trace_buf,
-            span_data=span_data2 if tracing else st.span_data,
-            span_count=(vec(span_count2) if tracing else st.span_count),
-            span_dropped=(vec(span_dropped2) if tracing
-                          else st.span_dropped),
-            span_next=(vec(span_next2) if tracing else st.span_next),
-            plan_key=res.plan_key, plan_perm=res.plan_perm,
-            plan_bounds=res.plan_bounds,
-            world_bits=vec(wb_new),
-            blob_data=blob_cur[0], blob_used=blob_cur[1],
-            blob_len=blob_cur[2], blob_gen=blob_cur[3],
-            blob_fail=vec(blob_fail, jnp.bool_),
-            blob_budget_fail=vec(blob_budget, jnp.bool_),
-            n_blob_alloc=vec(st.n_blob_alloc[0] + nb_alloc),
-            n_blob_free=vec(st.n_blob_free[0] + nb_free),
-            n_blob_remote=vec(st.n_blob_remote[0] + nb_remote),
-            n_blob_moved=vec(st.n_blob_moved[0] + nb_moved),
-            type_state=new_type_state,
-        )
-        aux = StepAux(
-            device_pending=device_pending,
-            host_pending=host_pending,
-            any_muted=any_muted_all,
-            exit_flag=exit_any, exit_code=exit_code_all,
-            spill_overflow=overflow_any,
-            spawn_fail=spawn_fail_any,
-            blob_fail=blob_fail_any,
-            blob_budget_fail=blob_budget_any,
-            n_processed=nproc_all,
-            n_delivered=ndel_all,
-            occ_sum=occ_sum, occ_max=occ_max,
-            n_muted_now=n_muted_now, n_overloaded_now=n_over_now,
-            n_rejected=nrej_all, n_badmsg=nbad_all,
-            n_deadletter=ndl_all, n_mutes=nmut_all,
-            qw_p99=qw_p99,
-            spawn=spawn_aux,
-        )
-        return st2, aux
+            return tick(k, st, inject_tgt, inject_words, phase)
 
     return local_step
 
@@ -2682,9 +1320,3 @@ def jit_step(program: Program, opts: RuntimeOptions, mesh=None):
     """Jit one tick (see _jit_over_mesh for the mesh wrapping)."""
     return _jit_over_mesh(build_step(program, opts), program, opts, mesh,
                           n_extra=0)
-
-
-def _state_structure(program, opts):
-    """A pytree with the same structure as RtState for building specs."""
-    from .state import init_state
-    return jax.eval_shape(lambda: init_state(program, opts))

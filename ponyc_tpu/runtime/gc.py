@@ -60,7 +60,7 @@ The same pass sweeps the device blob pool (≙ an actor's heap dying with
 the actor, mem/heap.c): a pool slot survives iff a surviving actor's
 Blob field holds its handle, a queued/spilled/injected message's Blob
 argument carries it, or the host owns it (blob_store not yet sent).
-Marking is shard-local by design — after migration (engine._route moves
+Marking is shard-local by design — after migration (route._route moves
 a blob WITH its routed message) every reachable handle is local to its
 pool's shard; the rare off-shard handle (host injection without
 near=, or a migration drop) is undereferenceable and is collected.
@@ -78,7 +78,7 @@ from jax import lax
 from ..config import RuntimeOptions
 from ..ops.segment import marks_of, segment_bounds
 from ..program import Program
-from .state import PhaseCursor, RtState, phase_scope
+from .state import PhaseCursor, RtState, phase_scope, ring_take
 
 def sorted_edges(src, src_ok, tgt, n: int):
     """The edges (row src[i] -> tgt[i]) with `src_ok`, sorted by target:
@@ -247,7 +247,7 @@ def build_gc(program: Program, opts: RuntimeOptions):
         # messages. A row that holds a message is a root, so these
         # are marked ONCE, here; and only the occupied slots are
         # read: rank k of every mailbox at a time (ring slot
-        # (head + k) % cap where k < occupancy; engine._ring_take, a
+        # (head + k) % cap where k < occupancy; state.ring_take, a
         # select chain over the planar [cap, w1_c, rows] table), as
         # deep as the fullest mailbox. ONE walk serves both masks
         # (ref args feed the actor trace, Blob args the blob sweep).
@@ -257,14 +257,13 @@ def build_gc(program: Program, opts: RuntimeOptions):
             if any_ref_args or walk_blobs else []
         mb_blobs = jnp.zeros((bsl if walk_blobs else 0,), jnp.bool_)
         if walked:
-            from .engine import _ring_take
             def rank_k(carry):
                 k, marks, bmarks = carry
                 refs = []
                 for cohort in walked:
                     cbuf = st.buf[cohort.atype.__name__]
                     s0, s1 = cohort.local_start, cohort.local_stop
-                    msg = _ring_take(cbuf, (st.head[s0:s1] + k) % cap)
+                    msg = ring_take(cbuf, (st.head[s0:s1] + k) % cap)
                     held = k < occ[s0:s1]
                     own = [b.global_id for b in cohort.behaviours]
                     for w in range(cbuf.shape[1] - 1):
@@ -346,7 +345,7 @@ def build_gc(program: Program, opts: RuntimeOptions):
         # holds it, a queued/spilled message's Blob ARG carries it, or
         # the host declared it a root (rt.blob_store handles not yet
         # sent). Marking is shard-LOCAL on purpose: migration
-        # (engine._route) re-homes a payload WITH its routed message,
+        # (route._route) re-homes a payload WITH its routed message,
         # so every resting reachable handle is local to its pool's
         # shard; the rare off-shard handle (host injection without
         # near=, migration drop) is undereferenceable and collects.

@@ -661,7 +661,7 @@ class Runtime:
 
         A slot is free only if it is dead, its queue is drained, AND no
         message addressed to it is parked in either spill tier — the same
-        free_ok condition the device spawn path enforces (engine.py step
+        free_ok condition the device spawn path enforces (spawn.free_mask, step
         1b). Reclaiming a row with a stale spilled message would deliver a
         previous life's message to the newborn."""
         st = self.state
@@ -719,7 +719,7 @@ class Runtime:
         self._set_flag_column("pressured", ids, True)
         # Raise the mesh-wide "any pressure" gate bit on every shard so
         # the next tick's (otherwise-skipped) pressured all_gather runs;
-        # the per-tick vote keeps it honest from then on (engine.py).
+        # the per-tick vote keeps it honest from then on (engine.vote).
         self.state = self._replace(world_bits=self.state.world_bits | 1)
 
     def release_backpressure(self, ids) -> None:
@@ -2226,7 +2226,7 @@ class Runtime:
     @_api_phase("read")
     def profile(self) -> Dict[str, Any]:
         """Structured per-behaviour/per-cohort telemetry report — the
-        host face of the on-device profiler matrix (engine.profile_lanes;
+        host face of the on-device profiler matrix (lanes.profile_lanes;
         ≙ reading back the fork's per-actor --ponyanalysis records).
         Requires opts.analysis >= 1 (at level 0 the lanes compile away
         and there is nothing to read). One small device fetch; call it

@@ -42,6 +42,7 @@ acceptable for now, and noted here deliberately.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Any, Dict
 
@@ -51,7 +52,7 @@ import jax.numpy as jnp
 from ..config import RuntimeOptions
 from ..program import Program
 
-# Queue-wait histogram geometry (the profiler, engine.profile_lanes):
+# Queue-wait histogram geometry (the profiler, lanes.profile_lanes):
 # bucket k counts dispatched messages that waited [2^k, 2^(k+1)) ticks
 # between delivery (enqueue stamp) and dispatch; the last bucket is
 # open-ended (>= 2^(QW_BUCKETS-1)). Power-of-two buckets keep the
@@ -61,7 +62,7 @@ QW_BUCKETS = 16
 
 # Per-phase window telemetry (the device-cost observatory, ISSUE 19):
 # one work-unit counter per scheduler-tick phase, accumulated on device
-# in engine.phase_cost_lanes. Work units are DETERMINISTIC per-phase
+# in lanes.phase_cost_lanes. Work units are DETERMINISTIC per-phase
 # tallies (delivery-list entries gathered, ring slots drained,
 # behaviours dispatched, GC bookkeeping rows touched, mailbox slots the
 # rebuild gathered) — not wall time — so every dispatch formulation
@@ -85,10 +86,10 @@ N_PHASES = len(PHASE_NAMES)
 SCOPE_PREFIX = "pony"
 STEP_SCOPES = ("unmute", "spawn", "drain", "dispatch", "dispatch/heap",
                "route",
-               # a mesh only (engine._route): the one sort by
+               # a mesh only (route._route): the one sort by
                # destination shard that carries the entries, a
                # contiguous masked slice of them a destination, the
-               # all_to_alls, overflow + link mutes; then (build_step)
+               # all_to_alls, overflow + link mutes; then (deliver_routed)
                # the received buckets' fills and their joining front to
                # front for the short delivery list
                "route/sort", "route/bucket", "route/exchange",
@@ -115,6 +116,40 @@ def pool_index(nslots: int, word, slot):
     pool of `nslots` slots (RtState.blob_data): word-major, slots on the
     lanes. Broadcasts; jnp or numpy."""
     return word * nslots + slot
+
+
+def ring_take(buf_rows, slot):
+    """Pull ring-slot `slot[r]` of every actor r: [cap, w1, R] × [R] →
+    [w1, R]. The per-lane index varies only over the small static `cap`
+    axis, so a static select chain keeps every op a full-width vector op
+    (a gather along a tiny major axis would defeat the lane layout —
+    see the layout note above)."""
+    cap = buf_rows.shape[0]
+    out = buf_rows[0]
+    for c in range(1, cap):
+        out = jnp.where((slot == c)[None, :], buf_rows[c], out)
+    return out
+
+
+# What a tick knows before it is traced, worked out once
+# (engine.tick_static) and handed to every phase. `p` shards of `nl` rows,
+# mailbox_cap `c`, first host row `fh`, spill_cap `s_cap`; `lists`:
+# route.ListSizes; `dispatchers`: (run_cohort, cohort) a device cohort.
+# `pri_rank`, `n_levels` — delivery priority levels (see
+# delivery.deliver): 0 = receiver spill, 1 = host inject, 2+k = sender
+# cohort with k-th highest PRIORITY (≙ the fork's actor priority hint
+# ordering contenders).
+# `cohort_layout` — per-cohort mailbox widths tiling the local row space
+# (ALL cohorts, device + host): delivery rebuilds each table at its own
+# width.
+# `blob_route` — blob migration over the mesh: (mask, iso mask) iff some
+# behaviour ROUTES a Blob argument (static mask) and the pool is live
+# (see route._route), else None. Iso-mode positions MOVE (source slot
+# freed); val-mode (frozen, shared) positions COPY — other readers keep
+# the source.
+TickStatic = collections.namedtuple(
+    "TickStatic", "program opts p nl c fh s_cap lists pri_rank n_levels "
+    "cohort_layout blob_route dispatchers")
 
 
 class PhaseCursor:
@@ -151,7 +186,7 @@ from ..tracing import SPAN_ROWS  # noqa: E402  (after QW_BUCKETS on purpose)
 
 
 def layout_sizes(program: Program, opts: RuntimeOptions):
-    """Static per-shard sizes shared by build_step and init_state:
+    """Static per-shard sizes shared by route.list_sizes and init_state:
     (e_out, bucket, n_delivery_entries).
 
     e_out — outbox entries one shard can emit per tick;
@@ -162,7 +197,7 @@ def layout_sizes(program: Program, opts: RuntimeOptions):
     the LONG list, the received buckets as they come (`shards * bucket`
     incoming), and the plan's length; where that is longer than one
     shard's outbox a tick whose arrivals fit delivers over the short
-    list, `s + inject + e_out + s` entries (engine.build_step), and
+    list, `s + inject + e_out + s` entries (route.list_sizes), and
     keeps its plan in the front of the same arrays."""
     e_out = sum(ch.local_capacity * ch.batch * ch.max_sends
                 for ch in program.device_cohorts)
@@ -253,7 +288,7 @@ class RtState:
     # ships: one parked in the route spill counts when its retry does);
     # "n_routed_remote" [P] int32 — those of them whose bucket went to
     # ANOTHER shard; "n_unpacked" [P] int32 — the ticks on which this
-    # shard delivered over the short list (engine._route_unpack: what
+    # shard delivered over the short list (route._route_unpack: what
     # arrived fitted one shard's outbox). Read through
     # Runtime.counter(), which sums them over the mesh like n_processed.
     route_counts: Dict[str, jnp.ndarray]
@@ -298,7 +333,7 @@ class RtState:
     # attribution). All cumulative int32, indexed by GLOBAL behaviour
     # id (which encodes the cohort: each type owns a contiguous gid
     # range) or by device-cohort index. Zero-length when analysis < 1
-    # so every lane compiles away (engine.profile_lanes is never even
+    # so every lane compiles away (lanes.profile_lanes is never even
     # traced at level 0 — the zero-cost-when-off discipline).
     beh_runs: jnp.ndarray       # [P*NB] int32 — dispatches per behaviour
     beh_delivered: jnp.ndarray  # [P*NB] int32 — mailbox acceptances per
@@ -329,7 +364,7 @@ class RtState:
     # Causal tracing (analysis >= 3 AND trace_sample > 0; PROFILE.md
     # §10; ≙ the fork's per-event rows following one message
     # send→dispatch, analysis.c:587-692). {} / zero-length when off —
-    # the whole subsystem compiles away (engine.trace_span_lanes is
+    # the whole subsystem compiles away (lanes.trace_span_lanes is
     # never traced; tests/test_tracing.py pins jaxpr identity).
     trace_buf: Dict[str, jnp.ndarray]  # {type: [cap, 2, capacity]}
     #                               per-ring-slot (trace_id,
@@ -397,7 +432,7 @@ class RtState:
     #   receiving shard's pool was full (loud data loss, never
     #   corruption)
     n_blob_moved: jnp.ndarray   # [P] int32 — blobs that MIGRATED in
-    #   with a routed message (engine._route: payload rides the
+    #   with a routed message (route._route: payload rides the
     #   all_to_all, fresh local slot + generation at the receiver)
 
     # Mesh-wide world facts from the previous tick's packed vote, stored

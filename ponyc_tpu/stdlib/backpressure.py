@@ -9,7 +9,7 @@ pony_apply_backpressure / pony_release_backpressure,
 src/libponyrt/actor/actor.c:1137-1162). Here the runtime side is the
 `pressured` actor column: senders to a pressured actor mute at delivery
 time and release after release() once occupancy also recovers
-(delivery.py mute triggers; engine.py unmute pass).
+(delivery.py mute triggers; mute.py unmute pass).
 
 Mirrors the reference's capability-security shape: calling apply/release
 requires an `ApplyReleaseBackpressureAuth` token derived from the

@@ -5,7 +5,7 @@ send to dispatch (analysis.c:587-692) and the DTrace scripts stitching
 USDT probes into causal timelines (SURVEY §5): here the device threads
 a sampled (trace_id, parent_span) context through mailbox ring side
 lanes (runtime/state.py), dispatch records one SPAN per traced message
-in a bounded device ring (engine.trace_span_lanes), and every send or
+in a bounded device ring (lanes.trace_span_lanes), and every send or
 spawn the behaviour performs inherits the context — so an injection's
 whole causal fan-out (inject → behaviour → fan-out → quiescence) is
 reconstructable after the fact, per message, not per aggregate.

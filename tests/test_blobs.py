@@ -15,7 +15,7 @@ capability checker. v1 scoped semantics under test here:
   - pool exhaustion raises BlobCapacityError host-side (sticky flag);
   - per-dispatch alloc budget = MAX_BLOBS (exceeding rejects at build);
   - on a mesh a blob MIGRATES with its routed message (fresh local
-    slot + generation at the receiver, engine._route; n_blob_moved);
+    slot + generation at the receiver, route._route; n_blob_moved);
     host injections bypass routing — allocate near the receiver;
   - the host side allocates/reads via Runtime.blob_store/blob_fetch.
 """

@@ -69,7 +69,7 @@ def test_plan_equals_argsort_and_searchsorted(shape, n_levels, invalid):
         sorted_key, n, n_levels)
     np.testing.assert_array_equal(perm, want_perm)
     np.testing.assert_array_equal(sorted_key, key[want_perm])
-    # a payload rides the same sort (the mesh route's, engine._route_pack)
+    # a payload rides the same sort (the mesh route's, route._route_pack)
     tag = key * 3 + 1
     np.testing.assert_array_equal(
         jax.jit(stable_sort_carrying)(key, tag, perm)[1:],

@@ -427,7 +427,7 @@ def run_blob_chain(seed, opts_kw, n=None, n_starts=6, vmax=10,
     for i, v, w in seeds:
         # Host injections don't route, so allocate on the seed's shard;
         # after that, chains cross shards freely — blobs MIGRATE with
-        # the routed messages (engine._route).
+        # the routed messages (route._route).
         h = rt.blob_store([w], near=int(ids[i]))
         rt.send(int(ids[i]), BlobWalker.step, v, h)
     assert rt.run(max_steps=100_000) == 0

@@ -3,7 +3,7 @@ the cell `fanin-zipf.steady`) against its plain references
 (`benchmarks/reference_fanin.py`), on the CPU at small sizes.
 
 The deployment lives in the backpressure chain: reject -> spill ->
-mute -> unmute (`delivery.py`'s pressure branch, `engine.py`'s unmute
+mute -> unmute (`delivery.py`'s pressure branch, `mute.py`'s unmute
 pass). One shard is held to the protocol tick by tick, on every actor;
 conservation (nothing lost, nothing duplicated) is checked on the way.
 A mesh mutes differently — a receiver's rejection mutes only the
@@ -21,7 +21,7 @@ import pytest
 from benchmarks import reference_fanin as ref
 from benchmarks.worlds import fanin
 from _rebuild import block_indices
-from test_profiler import _bare_hlo
+from _hlo import bare_hlo
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TICKS = 96
@@ -184,4 +184,4 @@ def test_pressure_scopes_are_named_and_are_metadata_only(monkeypatch):
                         lambda _name: contextlib.nullcontext())
     bare = _lowered_window().compile().as_text()
     assert "pony/" not in bare
-    assert _bare_hlo(scoped) == _bare_hlo(bare)
+    assert bare_hlo(scoped) == bare_hlo(bare)

@@ -149,7 +149,7 @@ CONFIGS = [
                                quiesce_interval=2)),
     # PR 42: the program's own bucket. Every shard of every tick
     # delivers over the SHORT list (the received buckets joined front
-    # to front, engine._route_unpack), with the receiver spill's retry
+    # to front, route._route_unpack), with the receiver spill's retry
     # and the mutes at work on it.
     ("mesh4-default-bucket", dict(mailbox_cap=2, batch=1, max_sends=3,
                                   spill_cap=4096, inject_slots=32,

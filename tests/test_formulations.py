@@ -237,7 +237,7 @@ def test_window_equals_plan_on_every_leaf(world, formulation):
 
 @pytest.mark.parametrize("formulation", list(FORMULATIONS))
 def test_profiler_lanes_equal_plans_at_analysis_1(formulation):
-    """The profiler's lanes (engine.profile_lanes, phase_cost_lanes) are
+    """The profiler's lanes (lanes.profile_lanes, phase_cost_lanes) are
     recomputed from facts every formulation produces: equal like every
     other leaf, and counting — the deep fan-in's rebuild lane among
     them."""

@@ -21,7 +21,7 @@ import pytest
 from benchmarks import reference_gups as ref
 from benchmarks.worlds import gups
 from ponyc_tpu import Runtime, RuntimeOptions
-from test_profiler import _bare_hlo
+from _hlo import bare_hlo
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ACTORS, SLICE = 128, 64
@@ -193,7 +193,7 @@ def test_heap_scope_is_named_and_is_metadata_only(delivery, monkeypatch):
                         lambda _name: contextlib.nullcontext())
     bare = _lowered_window(delivery).compile().as_text()
     assert "pony/" not in bare
-    assert _bare_hlo(scoped) == _bare_hlo(bare)
+    assert bare_hlo(scoped) == bare_hlo(bare)
 
 
 def _scatters(text):

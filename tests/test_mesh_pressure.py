@@ -56,7 +56,7 @@ def _run_pressure(opts, n_src=48, items=4):
 def test_route_bucket_overflow_spills_mutes_and_recovers():
     # Worst-case fan-in across the mesh: every shard's senders target one
     # shard; per-tick emissions exceed the all_to_all bucket, so messages
-    # park in route-spill and their senders mute (engine._route pressure
+    # park in route-spill and their senders mute (route._route pressure
     # branch). Everything must still arrive exactly once.
     opts = RuntimeOptions(mailbox_cap=4, batch=1, max_sends=2, msg_words=2,
                           mesh_shards=4, spill_cap=256, inject_slots=64,

@@ -1,4 +1,4 @@
-"""A meshed shard delivers over what arrived (engine.build_step, step 4):
+"""A meshed shard delivers over what arrived (route.deliver_routed, step 4):
 where a tick's arrivals fit one shard's outbox the received buckets are
 joined front to front and delivery runs over the SHORT list, else over
 the received buckets as they came, the LONG list it always ran. On the
@@ -6,7 +6,7 @@ suite's virtual CPU devices:
 
   - a uniform world takes the short list on every shard of every tick
     and is, leaf for leaf, the world that has the long list only (the
-    static guard `engine._unpack_fits` patched to refuse the short one);
+    static guard `route._unpack_fits` patched to refuse the short one);
   - a world whose stamped messages all land on shard 0, in pulses,
     takes the long list there on the ticks of a pulse and the short one
     between them, loses, doubles and reorders nothing, never overflows
@@ -22,7 +22,7 @@ import numpy as np
 import pytest
 
 from ponyc_tpu import I32, Ref, Runtime, RuntimeOptions, actor, behaviour
-from ponyc_tpu.runtime import delivery, engine
+from ponyc_tpu.runtime import delivery, route
 from ponyc_tpu.runtime.state import layout_sizes
 from chip_smoke import PLAN_CACHE_LEAVES
 from test_mesh_ubench import TICKS, _world
@@ -47,7 +47,7 @@ def _assert_same_world(a, b, tick):
 
 
 def _long_only(monkeypatch):
-    monkeypatch.setattr(engine, "_unpack_fits", lambda *_a: False)
+    monkeypatch.setattr(route, "_unpack_fits", lambda *_a: False)
 
 
 @pytest.mark.parametrize("shards,recipients", [(4, "random"), (2, "cycle")])
@@ -165,7 +165,7 @@ def test_a_world_skewed_onto_one_shard_takes_the_long_list_there(
     rt = _pulsed_world()
     l_in, bucket, e_long, e_short = _sizes(rt)
     assert (l_in, bucket) == (144, 144)
-    assert engine._unpack_fits(SHARDS, bucket, l_in)
+    assert route._unpack_fits(SHARDS, bucket, l_in)
     with monkeypatch.context() as patched:
         _long_only(patched)
         twin = _pulsed_world()
@@ -245,7 +245,7 @@ def test_a_plan_never_validates_the_other_lengths_list():
              jnp.zeros((e_long,), jnp.int32),
              jnp.zeros((rows + 1,), jnp.int32))
     # a fresh state matches nothing, on either list
-    key, perm, _ = engine._short_plan(fresh, e_short)
+    key, perm, _ = route._short_plan(fresh, e_short)
     assert key.shape == perm.shape == (e_short,) and (np.asarray(key) < 0).all()
 
     key_s = jnp.asarray(rng.integers(0, rows + 1, e_short), jnp.int32)
@@ -255,9 +255,9 @@ def test_a_plan_never_validates_the_other_lengths_list():
     perm_l = jnp.asarray(rng.permutation(e_long), jnp.int32)  # same front
 
     # short stores: a short tick finds its plan, a long one a mismatch
-    key, perm = engine._store_short_plan(fresh, key_s, perm_s)
+    key, perm = route._store_short_plan(fresh, key_s, perm_s)
     assert key.shape == perm.shape == (e_long,)
-    got = engine._short_plan((key, perm, fresh[2]), e_short)
+    got = route._short_plan((key, perm, fresh[2]), e_short)
     np.testing.assert_array_equal(got[0], key_s)
     np.testing.assert_array_equal(got[1], perm_s)
     assert not bool(jnp.all(key_l == key))        # the mark: no key is < 0
@@ -265,10 +265,10 @@ def test_a_plan_never_validates_the_other_lengths_list():
     # long stores (deliver's own arrays): a short tick whose key IS the
     # long key's front must still replan, not take the front of a
     # permutation of the long list
-    got = engine._short_plan((key_l, perm_l, fresh[2]), e_short)
+    got = route._short_plan((key_l, perm_l, fresh[2]), e_short)
     assert not bool(jnp.all(got[0] == key_s))
     # and a short store over a long plan leaves the rest as it was
-    key, perm = engine._store_short_plan((key_l, perm_l, fresh[2]),
+    key, perm = route._store_short_plan((key_l, perm_l, fresh[2]),
                                          key_s, perm_s)
     np.testing.assert_array_equal(key[e_short + 1:], key_l[e_short + 1:])
     np.testing.assert_array_equal(perm[e_short:], perm_l[e_short:])

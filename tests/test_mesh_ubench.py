@@ -25,7 +25,7 @@ from benchmarks import reference_mesh
 from benchmarks.worlds import ubench_mesh
 from ponyc_tpu.runtime import engine
 from ponyc_tpu.runtime.state import ROUTE_COUNTERS, SCOPE_PREFIX
-from test_profiler import _bare_hlo
+from _hlo import bare_hlo
 from test_run_loop import recording  # noqa: F401  (a fixture)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -181,7 +181,7 @@ def test_route_scopes_are_metadata_only(monkeypatch):
     bare = _window_text(world.rt, compiled=True)
     world.rt.stop()
     assert "pony/" not in bare
-    assert _bare_hlo(scoped) == _bare_hlo(bare)
+    assert bare_hlo(scoped) == bare_hlo(bare)
 
 
 def _route_gathers(text, scope):

@@ -254,7 +254,7 @@ def test_cross_shard_cycle_self_heals_without_aging():
     """Unlike the single-shard cycle (which freezes,
     test_mute_age_limit_zero_disables_aging), the CROSS-shard cycle
     self-heals even with aging disabled: the remote-ref release path
-    (engine.py remote_ok — release once the local route spill drains)
+    (mute.py remote_ok — release once the local route spill drains)
     periodically frees each side, so the pair grinds to completion.
     Pinning this down documents that aging is only load-bearing for
     same-shard cycles."""
@@ -381,7 +381,7 @@ def test_aged_release_waits_cross_shard():
                 # neither remote_ok (spill not drained) nor aging (the
                 # has_remote hold) may release. With the spill drained,
                 # remote_ok releases even into a still-congested remote
-                # receiver — the documented divergence (engine.py
+                # receiver — the documented divergence (mute.py
                 # remote_ok comment: routing re-mutes if it persists) —
                 # so that case is allowed.
                 if len(remote) and prev["rspill"][g // nl] > 0:

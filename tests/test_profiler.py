@@ -1,6 +1,6 @@
 """Per-behaviour profiler tests (≙ the fork's per-actor --ponyanalysis
 records, analysis.h:16-31): the on-device telemetry matrix
-(engine.profile_lanes), queue-wait latency histograms, GC window stats,
+(lanes.profile_lanes), queue-wait latency histograms, GC window stats,
 Runtime.profile(), the window CSV's dynamic columns, per-behaviour
 chrome-trace tracks, the `top` view, and the zero-cost-at-level-0
 guarantee."""
@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 import _child
+from _hlo import bare_hlo
 from _rebuild import block_indices
 from ponyc_tpu import (I32, Ref, Runtime, RuntimeOptions, actor,
                        analysis, behaviour)
@@ -206,7 +207,7 @@ def test_level0_lanes_compile_to_baseline(monkeypatch):
     import jax.numpy as jnp
 
     from ponyc_tpu.program import Program
-    from ponyc_tpu.runtime import engine
+    from ponyc_tpu.runtime import engine, lanes
     from ponyc_tpu.runtime.state import init_state
 
     def build(analysis):
@@ -226,7 +227,7 @@ def test_level0_lanes_compile_to_baseline(monkeypatch):
     def boom(*_a, **_k):
         raise AssertionError("profiler lanes traced at analysis=0")
 
-    monkeypatch.setattr(engine, "profile_lanes", boom)
+    monkeypatch.setattr(lanes, "profile_lanes", boom)
     assert build(0) == baseline     # trap unreached, jaxpr bit-identical
     with pytest.raises(AssertionError, match="lanes traced"):
         build(1)                    # and it IS the only lane source
@@ -238,7 +239,7 @@ def test_level0_lanes_compile_to_baseline(monkeypatch):
     def boom2(*_a, **_k):
         raise AssertionError("phase lanes traced at analysis=0")
 
-    monkeypatch.setattr(engine, "phase_cost_lanes", boom2)
+    monkeypatch.setattr(lanes, "phase_cost_lanes", boom2)
     assert build(0) == baseline
     with pytest.raises(AssertionError, match="phase lanes traced"):
         build(1)
@@ -611,24 +612,6 @@ def test_phase_scopes_name_the_lowered_window(delivery, cap):
     assert f"{SCOPE_PREFIX}/gc_mark/" in gc_text
 
 
-def _bare_hlo(text):
-    """Optimised HLO without what only describes it: per-instruction
-    metadata={...} and the module's file / function / location / stack
-    frame tables that the metadata indexes."""
-    import re
-    text = re.sub(r", metadata=\{[^}]*\}", "", text)
-    out, skipping = [], False
-    for line in text.splitlines():
-        if line in ("FileNames", "FunctionNames", "FileLocations",
-                    "StackFrames"):
-            skipping = True
-        elif skipping and not line.strip():
-            skipping = False
-        elif not skipping:
-            out.append(line)
-    return "\n".join(out)
-
-
 @pytest.mark.parametrize("delivery,cap", WINDOWS, ids=WINDOW_IDS)
 def test_phase_scopes_are_metadata_only(delivery, cap, monkeypatch):
     """The optimised HLO of the window is the same program with the
@@ -645,7 +628,7 @@ def test_phase_scopes_are_metadata_only(delivery, cap, monkeypatch):
     _rt, lowered = _lowered_window(delivery, cap)
     bare = lowered.compile().as_text()
     assert "pony/" not in bare
-    assert _bare_hlo(scoped) == _bare_hlo(bare)
+    assert bare_hlo(scoped) == bare_hlo(bare)
 
 
 def test_profiler_trace_holds_the_run_phases(tmp_path):

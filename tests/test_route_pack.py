@@ -1,4 +1,4 @@
-"""`engine._route_pack` — a shard's entries into its all-to-all buckets by
+"""`route._route_pack` — a shard's entries into its all-to-all buckets by
 one payload-carrying sort and a contiguous slice a destination — against
 the plain NumPy form of what it replaced: a stable argsort by
 destination shard, every array read back through the permutation, and a
@@ -9,15 +9,14 @@ did.
 """
 
 import functools
-import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import _child
-from ponyc_tpu.runtime import engine
+import _hlo
+from ponyc_tpu.runtime import route
 
 SHARDS, N_LOCAL = 4, 16
 
@@ -74,7 +73,7 @@ def _entries(e, w1, seed, dests=None, invalid=0.25):
 def _check(tgt, sender, words, bucket):
     """The pack's every output is the reference's."""
     got = jax.jit(functools.partial(
-        engine._route_pack, shards=SHARDS, n_local=N_LOCAL, bucket=bucket))(
+        route._route_pack, shards=SHARDS, n_local=N_LOCAL, bucket=bucket))(
         jnp.asarray(tgt), jnp.asarray(sender), jnp.asarray(words))
     want = reference_pack(tgt, sender, words, SHARDS, N_LOCAL, bucket)
     for g_part, w_part, part in zip(got, want,
@@ -127,7 +126,7 @@ def test_overflow_reaches_the_route_spill_unchanged(w1):
     assert int(np.max(np.asarray(cnt) - np.asarray(acc))) > 0
     n = SHARDS * N_LOCAL
     spill, count, over, muted, _refs, _ovf = jax.jit(functools.partial(
-        engine._route_spill, shards=SHARDS, n_local=N_LOCAL, bucket=bucket,
+        route._route_spill, shards=SHARDS, n_local=N_LOCAL, bucket=bucket,
         rspill_cap=cap, overload_occ=48, shard_base=jnp.int32(0),
         mute_slots=4))(
         ts, ss, ws, dt, seg_start, cnt - acc,
@@ -184,37 +183,15 @@ def test_random_geometries(seed):
 
 
 # The pack alone, compiled for a described v5e (no chip: libtpu's
-# compiler, in a child). Prints what the chip would run: how many
-# arrays as long as the padded entries it writes (the pad must fold
+# compiler, in a child: tests/_hlo.py). What the chip would run: how
+# many arrays as long as the padded entries it writes (the pad must fold
 # into each destination's slice) and its gathers, the bounds' binary
 # search aside.
 FOR_THE_CHIP = """
-import functools, json, re, sys
-sys.path.insert(0, {root!r})
-import jax, jax.numpy as jnp
-from jax.experimental import topologies
-from jax.sharding import SingleDeviceSharding
-from ponyc_tpu.runtime import engine
-try:
-    device = topologies.get_topology_desc("v5e:2x2", "tpu").devices[0]
-except Exception as e:
-    print(json.dumps({{"no_compiler": repr(e)}})); sys.exit(0)
+from ponyc_tpu.runtime import route
 e, w1 = {e}, {w1}
-arg = lambda *shape: jax.ShapeDtypeStruct(
-    shape, jnp.int32, sharding=SingleDeviceSharding(device))
-text = jax.jit(functools.partial(
-    engine._route_pack, shards=4, n_local=e // 8, bucket=e)).trace(
-    arg(e), arg(e), arg(w1, e)).lower(
-    lowering_platforms=("tpu",)).compile().as_text()
-padded, gathers, fused = 0, 0, False
-for line in text.splitlines():
-    if not line.startswith(" "):
-        fused = line.startswith("%fused_computation")
-        continue
-    head = line.split(" = ")[1].split("(")[0] if " = " in line else ""
-    padded += (not fused) and str(2 * e) in head
-    gathers += " gather(" in line and "jit(searchsorted)" not in line
-print(json.dumps({{"padded": padded, "gathers": gathers}}))
+fn = functools.partial(route._route_pack, shards=4, n_local=e // 8, bucket=e)
+args = (arg(e), arg(e), arg(w1, e))
 """
 
 
@@ -226,11 +203,5 @@ def test_for_the_chip_the_pad_folds_into_the_slices(w1):
     the parent's padding bytes over again. And it reads nothing by
     index."""
     e = 4096
-    out = _child.script(FOR_THE_CHIP.format(root=_child.ROOT, e=e, w1=w1),
-                        env={"ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
-                             "TPU_LOG_DIR": "disabled"})
-    assert out.returncode == 0, out.stderr[-2000:]
-    seen = json.loads(out.stdout.strip().splitlines()[-1])
-    if "no_compiler" in seen:
-        pytest.skip(f"no TPU compiler here: {seen['no_compiler']}")
-    assert seen == {"padded": 0, "gathers": 0}
+    seen = _hlo.v5e_counts(FOR_THE_CHIP.format(e=e, w1=w1), length=2 * e)
+    assert (seen["long"], seen["gathers"]) == (0, 0)

@@ -1,4 +1,4 @@
-"""`engine._route_unpack` — the received all-to-all buckets joined front to
+"""`route._route_unpack` — the received all-to-all buckets joined front to
 front by contiguous copies, `_route_pack` run backwards — against the
 plain NumPy form: keep the valid entries of the padded list in order,
 pad to the short list's length. Every shard count, message width and
@@ -8,15 +8,14 @@ in arrival order.
 """
 
 import functools
-import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import _child
-from ponyc_tpu.runtime import engine
+import _hlo
+from ponyc_tpu.runtime import route
 from ponyc_tpu.runtime.delivery import Entries
 
 L_IN = 48
@@ -58,7 +57,7 @@ def reference_unpack(tgt, sender, words, l_in):
 
 def unpack(tgt, sender, words, fill, bucket, l_in):
     got = jax.jit(functools.partial(
-        engine._route_unpack, shards=len(fill), bucket=bucket, l_in=l_in))(
+        route._route_unpack, shards=len(fill), bucket=bucket, l_in=l_in))(
         Entries(jnp.asarray(tgt), jnp.asarray(sender), jnp.asarray(words)),
         jnp.asarray(fill, jnp.int32))
     return tuple(np.asarray(x) for x in got)
@@ -136,7 +135,7 @@ def test_pack_then_unpack_is_the_outbox_by_destination(shards, w1):
     sender = np.arange(e, dtype=np.int32)          # arrival order
     words = np.stack([sender + 1000 * (i + 1) for i in range(w1)])
     _sorted, (_start, cnt, acc), (bt, bs, bw, _fill) = jax.jit(
-        functools.partial(engine._route_pack, shards=shards,
+        functools.partial(route._route_pack, shards=shards,
                           n_local=n_local, bucket=bucket))(
         jnp.asarray(tgt), jnp.asarray(sender), jnp.asarray(words))
     np.testing.assert_array_equal(np.asarray(acc), np.asarray(cnt))
@@ -157,52 +156,27 @@ def test_the_static_guard():
     """The short list exists only on a mesh whose received buckets are
     longer than it: one chip and a small explicit `route_bucket` keep
     the window they had."""
-    assert engine._unpack_fits(4, 48, 48)
-    assert engine._unpack_fits(2, 25, 48)
-    assert not engine._unpack_fits(1, 0, 48)
-    assert not engine._unpack_fits(4, 12, 48)      # 4 x 12 == l_in
-    assert not engine._unpack_fits(4, 8, 48)
+    assert route._unpack_fits(4, 48, 48)
+    assert route._unpack_fits(2, 25, 48)
+    assert not route._unpack_fits(1, 0, 48)
+    assert not route._unpack_fits(4, 12, 48)      # 4 x 12 == l_in
+    assert not route._unpack_fits(4, 8, 48)
 
 
 # The unpack alone, compiled for a described v5e (no chip: libtpu's
-# compiler, in a child). Prints what the chip would run under it:
-# reads by index, writes by index, sorts, and how many arrays as long as
-# the padded list (`shards * bucket`) it writes — the joined buffer is
+# compiler, in a child: tests/_hlo.py). What the chip would run under
+# it: reads by index, writes by index, sorts, and how many arrays as long
+# as the padded list (`shards * bucket`) it writes — the joined buffer is
 # `l_in + bucket`, a copy of what was received would be the parent's
 # padding over again.
 FOR_THE_CHIP = """
-import functools, json, re, sys
-sys.path.insert(0, {root!r})
-import jax, jax.numpy as jnp
-from jax.experimental import topologies
-from jax.sharding import SingleDeviceSharding
-from ponyc_tpu.runtime import engine
+from ponyc_tpu.runtime import route
 from ponyc_tpu.runtime.delivery import Entries
-try:
-    device = topologies.get_topology_desc("v5e:2x2", "tpu").devices[0]
-except Exception as e:
-    print(json.dumps({{"no_compiler": repr(e)}})); sys.exit(0)
 shards, bucket, w1 = 4, {bucket}, {w1}
-arg = lambda *shape: jax.ShapeDtypeStruct(
-    shape, jnp.int32, sharding=SingleDeviceSharding(device))
-text = jax.jit(functools.partial(
-    engine._route_unpack, shards=shards, bucket=bucket, l_in=bucket)).trace(
-    Entries(arg(shards * bucket), arg(shards * bucket),
-            arg(w1, shards * bucket)), arg(shards)).lower(
-    lowering_platforms=("tpu",)).compile().as_text()
-seen = dict(gathers=0, scatters=0, sorts=0, padded=0)
-fused = False
-for line in text.splitlines():
-    if not line.startswith(" "):
-        fused = line.startswith("%fused_computation")
-        continue
-    head = line.split(" = ")[1].split("(")[0] if " = " in line else ""
-    seen["gathers"] += " gather(" in line
-    seen["scatters"] += " scatter(" in line
-    seen["sorts"] += " sort(" in line
-    seen["padded"] += ((not fused) and " parameter(" not in line
-                       and str(shards * bucket) in head)
-print(json.dumps(seen))
+fn = functools.partial(route._route_unpack, shards=shards, bucket=bucket,
+                       l_in=bucket)
+args = (Entries(arg(shards * bucket), arg(shards * bucket),
+                arg(w1, shards * bucket)), arg(shards))
 """
 
 
@@ -213,11 +187,7 @@ def test_for_the_chip_the_unpack_is_contiguous_copies(w1):
     padded list. At the mesh cell's own size (a bucket of 8,392,704): a
     list small enough for the chip's fast memory is prefetched there
     whole, which is a copy of it, if a cheap one."""
-    out = _child.script(
-        FOR_THE_CHIP.format(root=_child.ROOT, bucket=8392704, w1=w1),
-        env={"ALLOW_MULTIPLE_LIBTPU_LOAD": "1", "TPU_LOG_DIR": "disabled"})
-    assert out.returncode == 0, out.stderr[-2000:]
-    seen = json.loads(out.stdout.strip().splitlines()[-1])
-    if "no_compiler" in seen:
-        pytest.skip(f"no TPU compiler here: {seen['no_compiler']}")
-    assert seen == {"gathers": 0, "scatters": 0, "sorts": 0, "padded": 0}
+    bucket = 8392704
+    seen = _hlo.v5e_counts(FOR_THE_CHIP.format(bucket=bucket, w1=w1),
+                           length=4 * bucket)
+    assert seen == {"gathers": 0, "scatters": 0, "sorts": 0, "long": 0}

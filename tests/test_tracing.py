@@ -233,7 +233,7 @@ def test_jaxpr_identity_when_off(monkeypatch):
     import jax.numpy as jnp
 
     from ponyc_tpu.program import Program
-    from ponyc_tpu.runtime import engine
+    from ponyc_tpu.runtime import engine, lanes
     from ponyc_tpu.runtime.state import init_state
 
     def build(analysis, sample):
@@ -258,7 +258,7 @@ def test_jaxpr_identity_when_off(monkeypatch):
     def boom(*_a, **_k):
         raise AssertionError("trace lanes traced while tracing off")
 
-    monkeypatch.setattr(engine, "trace_span_lanes", boom)
+    monkeypatch.setattr(lanes, "trace_span_lanes", boom)
     assert build(3, 0) == baseline3     # trap unreached, identical
     assert build(2, 64) == build(2, 0)
     with pytest.raises(AssertionError, match="lanes traced"):
