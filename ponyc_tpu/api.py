@@ -165,6 +165,12 @@ class ActorTypeMeta(type):
             b.actor_type = cls
         # Scheduling hints (≙ actor.c:398-423 lazy hint fns):
         cls.BATCH = ns.get("BATCH", None)        # msgs per step override
+        # Ring slots of this type's mailboxes (a power of two) where
+        # RuntimeOptions.mailbox_cap does not fit it: a coordinator that
+        # takes a whole window of replies among actors that see two or
+        # three messages (every slot of every row is HBM, and a drain
+        # or a rebuild pass reads them all).
+        cls.MAILBOX_CAP = ns.get("MAILBOX_CAP", None)
         cls.PRIORITY = ns.get("PRIORITY", 0)     # ≙ fork's priority hint
         cls.HOST = ns.get("HOST", False)         # ≙ use_main_thread: runs on host
         cls.TAG = ns.get("TAG", 0)               # ≙ fork's analysis tag
@@ -233,7 +239,8 @@ class ActorTypeMeta(type):
                      for a in args)
         name = f"{cls.__name__}[{', '.join(disp)}]"
         ns = {"__annotations__": {}, "__qualname__": name}
-        for attr in ("BATCH", "PRIORITY", "HOST", "TAG", "SPAWNS",
+        for attr in ("BATCH", "MAILBOX_CAP", "PRIORITY", "HOST", "TAG",
+                     "SPAWNS",
                      "SPAWN_DISPATCHES", "MAX_SENDS", "MAX_BLOBS",
                      "BLOB_DISPATCHES"):
             if attr in cls.__dict__:

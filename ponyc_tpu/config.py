@@ -33,7 +33,8 @@ class RuntimeOptions:
     """
 
     # --- mailbox / message geometry (≙ messageq.c + actor.c batch) ---
-    mailbox_cap: int = 64          # per-actor ring capacity (power of two)
+    mailbox_cap: int = 64          # per-actor ring capacity (power of two);
+    #   an actor class that states MAILBOX_CAP has its own (api.py)
     msg_words: int = 6             # payload words per message (int32 lanes)
     batch: int = 8                 # default msgs drained per actor per step
     #   (reference default batch is 100 msgs per *scheduler run*
@@ -351,13 +352,21 @@ class RuntimeOptions:
         spill tables and outbox entries keep the tracer-free width."""
         return 2 if self.tracing else 0
 
+    def overload_of(self, cap: int) -> int:
+        """The overload line of a ring of `cap` slots."""
+        return max(1, int(cap * self.overload_threshold))
+
+    def unmute_of(self, cap: int) -> int:
+        """The unmute line of a ring of `cap` slots."""
+        return max(0, int(cap * self.unmute_threshold))
+
     @property
     def overload_occ(self) -> int:
-        return max(1, int(self.mailbox_cap * self.overload_threshold))
+        return self.overload_of(self.mailbox_cap)
 
     @property
     def unmute_occ(self) -> int:
-        return max(0, int(self.mailbox_cap * self.unmute_threshold))
+        return self.unmute_of(self.mailbox_cap)
 
 
 _FLAG_TYPES = {f.name: f.type for f in dataclasses.fields(RuntimeOptions)}

@@ -44,6 +44,15 @@ class Cohort:
         self.local_capacity = self.capacity // shards
         self.local_start = 0        # per-shard row offset; set by finalize()
         self.batch = atype.BATCH or opts.batch
+        # The type's own ring depth where it states one (api.py,
+        # MAILBOX_CAP), RuntimeOptions.mailbox_cap otherwise; its
+        # overload and unmute lines are the options' fractions of it.
+        self.mailbox_cap = int(atype.MAILBOX_CAP or opts.mailbox_cap)
+        if self.mailbox_cap & (self.mailbox_cap - 1):
+            raise ValueError(f"{atype.__name__}.MAILBOX_CAP must be a "
+                             "power of two")
+        self.overload_occ = opts.overload_of(self.mailbox_cap)
+        self.unmute_occ = opts.unmute_of(self.mailbox_cap)
         self.priority = atype.PRIORITY
         self.host = bool(atype.HOST)
         # Static send budget: max ctx.send() calls across this type's

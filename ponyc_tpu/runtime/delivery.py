@@ -171,25 +171,34 @@ def empty_mute_slots(n: int, k: int):
 REBUILD_BLOCK = 8
 
 
-def rebuild_tables(tables, wds, tail, acc, seg_start, *, mailbox_cap: int):
+def rebuild_tables(tables, wds, tail, acc, seg_start):
     """Write this tick's accepted messages into the ring tables.
 
     `tables` = [(table [cap, rows, nn], s0, s1, r0, r1)]: the table of
     actors [s0, s1) takes word rows [r0, r1) of the sorted entries `wds`
-    — every cohort's mailbox at its own width and, with causal tracing
+    — every cohort's mailbox at its own width AND its own depth (`cap`
+    is the table's: program.Cohort.mailbox_cap) and, with causal tracing
     on, its trace side lanes through the SAME (mask, source) pairs, so
     context and message cannot land in different slots. Arrival rank r
     of actor i (r < acc[i]) is entry seg_start[i] + r and lands in ring
     slot (tail[i] + r) % cap. Returns (new tables, indices gathered: B
     ranks x rows a full-width block, B ranks x M a compacted one,
-    summed over the blocks each cohort ran; None for a ring of one
+    summed over the blocks each cohort ran; None for rings of one
     block, whose count the caller knows)."""
-    c = mailbox_cap
+    caps = {table.shape[0] for table, *_ in tables}
     e = wds.shape[1]
-    rels = (jnp.arange(c, dtype=jnp.int32)[:, None]
-            - tail[None, :]) % c                  # [cap, n] rank of a slot
 
-    if c <= REBUILD_BLOCK:
+    def rank_of_slot(cap, tails):           # [cap, rows] rank of a slot
+        return (jnp.arange(cap, dtype=jnp.int32)[:, None]
+                - tails[None, :]) % cap
+
+    # one depth for the world: the ranks once, every cohort a slice of
+    # them (the window every program had before a cohort could state
+    # its own); else a cohort's ranks at its own depth
+    c = caps.pop() if len(caps) == 1 else None
+    rels = None if c is None else rank_of_slot(c, tail)
+
+    if c is not None and c <= REBUILD_BLOCK:
         # One block covers the ring: plane c (ring slot c of every
         # actor) pulls sorted entry seg_start + (c - tail) % cap, all
         # planes' indices in ONE gather per table. No depth, no loop.
@@ -215,7 +224,9 @@ def rebuild_tables(tables, wds, tail, acc, seg_start, *, mailbox_cap: int):
     for (s0, s1), members in cohorts.items():
         tabs, gathered = _rebuild_cohort(
             [tables[i][0] for i in members],
-            [tables[i][3:] for i in members], wds, rels[:, s0:s1],
+            [tables[i][3:] for i in members], wds,
+            rels[:, s0:s1] if rels is not None else rank_of_slot(
+                tables[members[0]][0].shape[0], tail[s0:s1]),
             acc[s0:s1], seg_start[s0:s1])
         for i, tab in zip(members, tabs):
             out[i] = tab
@@ -309,15 +320,17 @@ def _rebuild_cohort(tabs, word_rows, wds, rels, acc, seg_start):
 
 
 def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
-            mailbox_cap: int, spill_cap: int, overload_occ: int,
+            mailbox_cap, spill_cap: int, overload_occ,
             shard_base, cohort_layout, mute_slots: int = 4, level=None,
             n_levels: int = 1, plan=None, pressured=None,
             cosort: bool = False, trace_buf=None) -> DeliveryResult:
-    """`buf` is the per-cohort mailbox dict {type: [cap, 1+W_c, rows_c]};
+    """`buf` is the per-cohort mailbox dict {type: [cap_c, 1+W_c, rows_c]};
     `cohort_layout` = [(type, s0, s1, w1_c)] tiles the local row space
     [0, n_local) in cohort order — bookkeeping (tails, segments, spill)
     stays global over rows, only the table rebuild is per cohort at its
-    own width (≙ per-type pony_msg_t sizes, genfun.c).
+    own width (≙ per-type pony_msg_t sizes, genfun.c) and depth.
+    `mailbox_cap` and `overload_occ` are the rows': an int where every
+    cohort has the same, else an [n_local] vector (state.rows_of).
 
     `level` ([E] int32, 0 = most urgent) folds the fork's actor
     *priorities* (actor.h priority hint; scheduler.c:1053-1078 priority
@@ -404,7 +417,8 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
                 lambda _: _compute_plan(key),
                 operand=None)
 
-    one_block = c <= REBUILD_BLOCK     # rebuild_tables' static guard
+    # rebuild_tables' static guard: one depth for the world, one block
+    one_block = isinstance(c, int) and c <= REBUILD_BLOCK
 
     def _empty_spill():
         refs, ovf = empty_mute_slots(n, mute_slots)
@@ -462,7 +476,7 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
                 tables += [(trace_buf[cname], s0, s1, w1f - 2, w1f)
                            for cname, s0, s1, _w1c in cohort_layout]
             rebuilt, slots = rebuild_tables(tables, wds, tail, acc,
-                                            seg_start, mailbox_cap=c)
+                                            seg_start)
             names = [cname for cname, *_ in cohort_layout]
             buf2 = dict(zip(names, rebuilt))
             tbuf2 = dict(zip(names, rebuilt[len(names):]))

@@ -48,7 +48,17 @@ from . import lanes, mute, route, spawn
 from .delivery import Entries
 from .gc import build_blob_arg_mask
 from .state import (ROUTE_COUNTERS, PhaseCursor, RtState, TickStatic,
-                    phase_scope, ring_take)
+                    cohort_scope, phase_scope, ring_take)
+
+
+# Selects a cohort's drain may unroll: `batch` ring_takes of `cap - 1`
+# selects each. Up to here the takes are one straight line the compiler
+# fuses (every cohort at RuntimeOptions' default geometry, 8 x 63); past
+# it one take is the body of a loop: a coordinator that drains the
+# reference's own batch of 100 from a ring of 256 would be 25,500
+# selects, which the v5e's compiler had not finished after a quarter of
+# an hour (PERF.md, PR 44), for rows so few that the loop costs nothing.
+DRAIN_UNROLL = 4096
 
 
 class StepAux(NamedTuple):
@@ -374,7 +384,7 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
     msg_words = opts.msg_words          # OUTBOX width (program-wide max)
     ms = cohort.max_sends
     batch = cohort.batch
-    cap = opts.mailbox_cap
+    cap = cohort.mailbox_cap
     rows = cohort.local_capacity
     w1 = 1 + msg_words
     # This cohort's own mailbox width (≙ per-type pony_msg_t, genfun.c):
@@ -640,9 +650,15 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
                         interpret=mk.interpret_mode())
             else:
                 with phase_scope("drain"):
-                    msgs = jnp.stack(
-                        [ring_take(buf_rows, (head_rows + k) % cap)
-                         for k in range(batch)])        # [batch, w1, rows]
+                    def take(k):
+                        return ring_take(buf_rows, (head_rows + k) % cap)
+
+                    if batch * (cap - 1) > DRAIN_UNROLL:
+                        msgs = lax.map(take, jnp.arange(batch,
+                                                        dtype=jnp.int32))
+                    else:
+                        msgs = jnp.stack([take(k) for k in range(batch)])
+                    # [batch, w1, rows]
                     valids = (jnp.arange(batch, dtype=jnp.int32)[:, None]
                               < n_run[None, :])         # [batch, rows]
             z = lambda d: jnp.zeros((rows,), d)         # noqa: E731
@@ -733,7 +749,7 @@ def tick_static(program: Program, opts: RuntimeOptions) -> TickStatic:
     pri_sorted = sorted({ch.priority for ch in dev_cohorts}, reverse=True)
     return TickStatic(
         program=program, opts=opts, p=program.shards, nl=program.n_local,
-        c=opts.mailbox_cap, fh=program.first_host_row,
+        fh=program.first_host_row,
         s_cap=opts.spill_cap, lists=route.list_sizes(program, opts),
         pri_rank={pv: i for i, pv in enumerate(pri_sorted)},
         n_levels=2 + max(1, len(pri_sorted)),
@@ -791,11 +807,12 @@ def dispatch(k: TickStatic, st: RtState, w, rs) -> Dispatched:
                          "resv": spawn.cohort_blob_resv(ch, rs)}
             else:
                 blobd = None
-        (stf, out, new_head_rows, ef, ec, nproc, nbad, claims, inits,
-         sfail, dstr, errs, blob_out) = run_cohort(
-            st.type_state[ch.atype.__name__],
-            st.buf[ch.atype.__name__], st.head[s0:s1], w.occ0[s0:s1],
-            rs.runnable[s0:s1], ids, resv, blob=blobd)
+        with phase_scope(cohort_scope(ch.atype.__name__)):
+            (stf, out, new_head_rows, ef, ec, nproc, nbad, claims, inits,
+             sfail, dstr, errs, blob_out) = run_cohort(
+                st.type_state[ch.atype.__name__],
+                st.buf[ch.atype.__name__], st.head[s0:s1], w.occ0[s0:s1],
+                rs.runnable[s0:s1], ids, resv, blob=blobd)
         if blob_out is not None:
             pool = pool._replace(
                 cur=blob_out[:4], fail=pool.fail | blob_out[4],

@@ -172,7 +172,6 @@ def build_gc(program: Program, opts: RuntimeOptions):
     nl = program.n_local
     ntot = p * nl
     fh = program.first_host_row
-    cap = opts.mailbox_cap
     ref_mask_np = build_ref_arg_mask(program, opts.msg_words)
     any_ref_args = bool(ref_mask_np.any())
     max_iters = opts.gc_max_iters
@@ -263,7 +262,8 @@ def build_gc(program: Program, opts: RuntimeOptions):
                 for cohort in walked:
                     cbuf = st.buf[cohort.atype.__name__]
                     s0, s1 = cohort.local_start, cohort.local_stop
-                    msg = ring_take(cbuf, (st.head[s0:s1] + k) % cap)
+                    msg = ring_take(cbuf, (st.head[s0:s1] + k)
+                                    % cohort.mailbox_cap)
                     held = k < occ[s0:s1]
                     own = [b.global_id for b in cohort.behaviours]
                     for w in range(cbuf.shape[1] - 1):

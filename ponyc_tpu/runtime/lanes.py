@@ -14,7 +14,7 @@ from jax import lax
 from ..config import RuntimeOptions
 from ..ops.segment import compact_mask
 from ..program import Program
-from .state import PHASE_NAMES, QW_BUCKETS, RtState, TickStatic
+from .state import PHASE_NAMES, QW_BUCKETS, RtState, TickStatic, rows_of
 
 
 def _qwait_bucket(delta):
@@ -67,7 +67,6 @@ def profile_lanes(program: Program, opts: RuntimeOptions, st: RtState,
 
     `drain_facts` = [(cohort, head_before, head_after)] in
     device-cohort order. Returns the six updated state fields."""
-    cap = opts.mailbox_cap
     s_now = st.step_no[0]
     beh_runs = st.beh_runs
     beh_del = st.beh_delivered
@@ -75,7 +74,9 @@ def profile_lanes(program: Program, opts: RuntimeOptions, st: RtState,
     coh_mt = st.coh_mute_ticks
     qw_hist = st.qwait_hist
     qw_enq = dict(st.qwait_enq)
-    ci = jnp.arange(cap, dtype=jnp.int32)[:, None]   # ring-slot planes
+    def _planes(ch):        # ring-slot planes of a cohort, and its depth
+        cap = ch.mailbox_cap
+        return jnp.arange(cap, dtype=jnp.int32)[:, None], cap
 
     def _count(mask):
         return jnp.sum(mask.astype(jnp.int32))
@@ -83,6 +84,7 @@ def profile_lanes(program: Program, opts: RuntimeOptions, st: RtState,
     # --- dispatch side: runs per behaviour + queue-wait histogram.
     for di, (ch, head0, head1) in enumerate(drain_facts):
         cname = ch.atype.__name__
+        ci, cap = _planes(ch)
         n_con = head1 - head0
         # Ring slot ci held a message drained this tick iff its
         # monotonic count fell in [head0, head0 + n_con).
@@ -102,6 +104,7 @@ def profile_lanes(program: Program, opts: RuntimeOptions, st: RtState,
     for ch in program.cohorts:
         cname = ch.atype.__name__
         s0, s1 = ch.local_start, ch.local_stop
+        ci, cap = _planes(ch)
         n_new = res.tail[s0:s1] - tail0[s0:s1]
         fresh = ((ci - tail0[None, s0:s1]) % cap) < n_new[None, :]
         gid = res.buf[cname][:, 0, :]
@@ -200,7 +203,6 @@ def trace_span_lanes(program: Program, opts: RuntimeOptions, st: RtState,
     `drain_facts` = [(cohort, head_before, head_after)] in
     device-cohort order. Returns (span_data, span_count, span_dropped,
     span_next, [per-cohort [2, e_c] propagation rows])."""
-    cap = opts.mailbox_cap
     p = program.shards
     ts_cap = opts.trace_slots
     s_now = st.step_no[0]
@@ -208,10 +210,11 @@ def trace_span_lanes(program: Program, opts: RuntimeOptions, st: RtState,
     span_count = st.span_count[0]
     span_dropped = st.span_dropped[0]
     span_next = st.span_next[0]
-    ci = jnp.arange(cap, dtype=jnp.int32)[:, None]
     tr_out = []
     for (ch, head0, head1) in drain_facts:
         cname = ch.atype.__name__
+        cap = ch.mailbox_cap
+        ci = jnp.arange(cap, dtype=jnp.int32)[:, None]
         rows = ch.local_capacity
         batch, ms = ch.batch, ch.max_sends
         n_con = head1 - head0
@@ -311,8 +314,8 @@ def event_ring(k: TickStatic, st: RtState, w, ring, error_rows, life,
     opts, nl, alive, muted = k.opts, k.nl, life.alive, life.muted
     ev_data, ev_count, ev_dropped = ring
     released_ev = st.muted & ~muted & alive
-    over_ev = (occ_after > opts.overload_occ) \
-        & ~(w.occ0 > opts.overload_occ)
+    hot = rows_of(k.program, "overload_occ")
+    over_ev = (occ_after > hot) & ~(w.occ0 > hot)
     spawn_ev = alive & ~st.alive
     destroy_ev = st.alive & ~alive
     err_ev = jnp.zeros((nl,), jnp.bool_)
@@ -366,7 +369,7 @@ def vote_lanes(k: TickStatic, occ_after, muted2, counts, qw_hist2):
     occ_max = jnp.max(occ_after)
     n_muted_now = jnp.sum(muted2.astype(jnp.int32))
     n_over_now = jnp.sum(
-        (occ_after > k.opts.overload_occ).astype(jnp.int32))
+        (occ_after > rows_of(k.program, "overload_occ")).astype(jnp.int32))
     # Worst-cohort queue-wait p99 of the cumulative histograms —
     # in-trace twin of analysis.hist_percentile (bucket k holds
     # waits in [2^k, 2^(k+1)); the reported value is the lower

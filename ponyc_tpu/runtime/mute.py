@@ -12,7 +12,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.segment import counts_by_key
-from .state import RtState, TickStatic
+from .state import RtState, TickStatic, rows_of
 
 # A row's status word for the unmute pass (`muter_bits`).
 LIVE_CONG, CAN_RECOVER, RECOVERED, PRESSURED = 1, 2, 4, 8
@@ -94,9 +94,10 @@ def unmute_pass(k: TickStatic, st: RtState, w: World) -> Unmuted:
     # cond (collectives must run collectively; jnp.any(st.muted) is
     # shard-local).
     can_recover = st.alive & ~st.muted
-    live_cong = (((occ0 > opts.unmute_occ) | (w.dspill_pending > 0))
+    calm = rows_of(k.program, "unmute_occ")     # a row's own unmute line
+    live_cong = (((occ0 > calm) | (w.dspill_pending > 0))
                  & can_recover)
-    recovered = ((occ0 <= opts.unmute_occ) & (w.dspill_pending == 0)
+    recovered = ((occ0 <= calm) & (w.dspill_pending == 0)
                  & ~st.pressured)
     muter_bits = (jnp.where(live_cong, LIVE_CONG, 0)
                   | jnp.where(can_recover, CAN_RECOVER, 0)
@@ -157,7 +158,8 @@ def unmute_pass(k: TickStatic, st: RtState, w: World) -> Unmuted:
         # Overflowed ref sets may have EVICTED a pressured ref
         # (slot collision), so the conservative release condition
         # consults the whole world's pressure bits, not just local.
-        shard_quiet = (jnp.max(occ0) <= opts.unmute_occ) \
+        shard_quiet = (jnp.max(occ0) <= calm if isinstance(calm, int)
+                       else jnp.all(occ0 <= calm)) \
             & (st.dspill_count[0] == 0) & (st.rspill_count[0] == 0) \
             & ~jnp.any(pressured_global)
         # Aging deadlock-breaker: a sender muted for

@@ -18,7 +18,7 @@ from ..ops import pack
 from ..ops.segment import compact_mask, stable_sort_carrying
 from .delivery import Entries, deliver, empty_mute_slots, mute_ref_slots
 from .state import (PhaseCursor, RtState, TickStatic, layout_sizes,
-                    phase_scope, pool_index)
+                    phase_scope, pool_index, rows_of)
 
 
 def _route_pack(tgt, sender, words, *, shards: int, n_local: int,
@@ -343,7 +343,9 @@ def _route_spill(ts, ss, ws, dt, seg_start, over, *, shards: int,
         lsnd = ss - shard_base
         s_ok = (rej | pr_t) & (lsnd >= 0) & (lsnd < n_local)
         sc = jnp.minimum(jnp.maximum(lsnd, 0), n_local - 1)
-        s_hot = (tail[sc] - head[sc]) > overload_occ
+        s_hot = (tail[sc] - head[sc]) > (
+            overload_occ if isinstance(overload_occ, int)
+            else overload_occ[sc])
         # ≙ the reference's !OVERLOADED/UNDER_PRESSURE sender exemption
         # (actor.c mute rules): a sender that is itself hot or has
         # itself declared pressure never mutes — prevents two
@@ -492,7 +494,8 @@ def deliver_routed(k: TickStatic, st: RtState, w, inject_tgt, inject_words,
         (incoming, new_rspill, rsp_count, rsp_over, route_muted,
          route_refs, route_ovf, route_blob_out, routed) = _route(
             out_cat, shards=p, n_local=nl, bucket=bucket,
-            rspill_cap=k.s_cap, overload_occ=opts.overload_occ,
+            rspill_cap=k.s_cap,
+            overload_occ=rows_of(k.program, "overload_occ"),
             head=head, tail=tail0, shard_base=base,
             mute_slots=opts.mute_slots,
             pressured_global=w.pressured_global,
@@ -514,8 +517,11 @@ def deliver_routed(k: TickStatic, st: RtState, w, inject_tgt, inject_words,
 
     def delivered(all_e, lvl_all, plan):
         return deliver(st.buf, head, tail0, alive, all_e,
-                       n_local=nl, mailbox_cap=k.c, spill_cap=k.s_cap,
-                       overload_occ=opts.overload_occ, shard_base=base,
+                       n_local=nl,
+                       mailbox_cap=rows_of(k.program, "mailbox_cap"),
+                       spill_cap=k.s_cap,
+                       overload_occ=rows_of(k.program, "overload_occ"),
+                       shard_base=base,
                        cohort_layout=k.cohort_layout,
                        mute_slots=opts.mute_slots,
                        level=lvl_all, n_levels=k.n_levels, plan=plan,

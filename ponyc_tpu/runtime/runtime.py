@@ -1054,23 +1054,23 @@ class Runtime:
             else:
                 words[:, off] = col.astype(np.int32)
                 off += 1
+        # Per-cohort mailbox tables (state.py): all targets live in ONE
+        # cohort (checked above); write its table at its own depth and
+        # width (the packed words beyond it are zeros by construction —
+        # this behaviour's args fit the cohort's width). Advanced
+        # indices (slot, col) pair up, the word axis rides.
+        cname = behaviour_def.actor_type.__name__
+        cohort = self.program.by_type_name(cname)
         tail = self.state.tail
         t_at = np.asarray(tail[targets])
         occ = t_at - np.asarray(self.state.head[targets])
-        if (occ >= self.opts.mailbox_cap).any():
-            full = targets[occ >= self.opts.mailbox_cap]
+        if (occ >= cohort.mailbox_cap).any():
+            full = targets[occ >= cohort.mailbox_cap]
             raise RuntimeError(
                 f"bulk_send would overflow {len(full)} full mailbox(es) "
                 f"(first target {int(full[0])}); drain with run() first or "
                 "raise mailbox_cap")
-        slot = t_at % self.opts.mailbox_cap
-        # Per-cohort mailbox tables (state.py): all targets live in ONE
-        # cohort (checked above); write its table at its own width (the
-        # packed words beyond it are zeros by construction — this
-        # behaviour's args fit the cohort's width). Advanced indices
-        # (slot, col) pair up, the word axis rides.
-        cname = behaviour_def.actor_type.__name__
-        cohort = self.program.by_type_name(cname)
+        slot = t_at % cohort.mailbox_cap
         cols = np.asarray(cohort.gid_to_col(targets))
         w1c = 1 + cohort.msg_words
         new_cbuf = self.state.buf[cname].at[slot, :, cols].set(
@@ -1251,7 +1251,6 @@ class Runtime:
         # (at its own width) and read messages via cohort-local columns.
         host_bufs: Dict[str, np.ndarray] = {}
         host_tbufs: Dict[str, np.ndarray] = {}   # trace side lanes
-        c = self.opts.mailbox_cap
         new_head = head.copy()
         for i in np.nonzero(pending)[0]:
             aid = int(rows[int(i)])
@@ -1267,7 +1266,7 @@ class Runtime:
             col = int(cohort.gid_to_col(aid))
             consumed = 0
             for k in range(int(pending[i])):
-                slot = (head[i] + k) % c
+                slot = (head[i] + k) % cohort.mailbox_cap
                 msg = cbuf[slot, :, col]
                 tctx = None
                 if self._tracer is not None:
@@ -1635,7 +1634,7 @@ class Runtime:
         a pass could at most find to free."""
         if self._row_bytes is None:
             self._row_bytes = min(
-                4 * (self.opts.mailbox_cap * (1 + tc.msg_words)
+                4 * (tc.mailbox_cap * (1 + tc.msg_words)
                      + len(tc.atype.field_specs))
                 for tc in map(self.program.by_type_name,
                               self.program.spawn_target_names))
@@ -2183,7 +2182,9 @@ class Runtime:
         Raises AssertionError with the first violated invariant."""
         st = jax.device_get(self.state)
         occ = st.tail - st.head
-        c = self.opts.mailbox_cap
+        c = np.tile(np.concatenate(
+            [np.full(ch.local_capacity, ch.mailbox_cap)
+             for ch in self.program.cohorts]), self.program.shards)
         assert (occ >= 0).all(), "mailbox occupancy negative (head>tail)"
         assert (occ <= c).all(), "mailbox occupancy exceeds capacity"
         alive = np.asarray(st.alive)

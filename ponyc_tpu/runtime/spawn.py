@@ -291,7 +291,8 @@ def row_pressure(k: TickStatic, st: RtState, w, r, any_rspill_all, alive,
                 wants = jnp.zeros_like(holds)
                 for j in range(ch.batch):
                     beh = ring_take(
-                        gids, (new_head[s0:s1] + j) % k.c)[0] - gid0
+                        gids, (new_head[s0:s1] + j)
+                        % ch.mailbox_cap)[0] - gid0
                     wants = wants | (
                         (j < occ_after[s0:s1])
                         & (beh >= 0) & (beh < len(may))

@@ -107,8 +107,32 @@ _named_scope = jax.named_scope      # the one seam the tests stub
 
 def phase_scope(path: str):
     """Context manager: the traced operations inside belong to phase
-    `path` (one of STEP_SCOPES, or `analysis` for the opt-in lanes)."""
+    `path` (one of STEP_SCOPES, a `cohort_scope`, or `analysis` for the
+    opt-in lanes)."""
     return _named_scope(f"{SCOPE_PREFIX}/{path}")
+
+
+def cohort_scope(type_name: str) -> str:
+    """The scope of ONE cohort's share of `dispatch`:
+    `dispatch/cohort/<actor type>`, below `dispatch`, so what a reader
+    sums under `pony/dispatch` is what it was. The drain inside it keeps
+    `pony/drain` and the pool's operations `pony/dispatch/heap`: scopes
+    are absolute, the innermost names the operation."""
+    return f"dispatch/cohort/{type_name}"
+
+
+def rows_of(program: Program, attr: str):
+    """A cohort's own `attr` (`mailbox_cap`, `overload_occ`,
+    `unmute_occ`: program.Cohort) for every local row. The plain int
+    where all cohorts agree — every program none of whose types states a
+    MAILBOX_CAP, so its window is the program it was — else an [n_local]
+    int32 vector, cohort by cohort, built where it is traced (broadcasts
+    and a concatenation, no literal of a million words)."""
+    values = [getattr(ch, attr) for ch in program.cohorts]
+    if len(set(values)) <= 1:
+        return values[0]
+    return jnp.concatenate([jnp.full((ch.local_capacity,), v, jnp.int32)
+                            for ch, v in zip(program.cohorts, values)])
 
 
 def pool_index(nslots: int, word, slot):
@@ -133,7 +157,8 @@ def ring_take(buf_rows, slot):
 
 # What a tick knows before it is traced, worked out once
 # (engine.tick_static) and handed to every phase. `p` shards of `nl` rows,
-# mailbox_cap `c`, first host row `fh`, spill_cap `s_cap`; `lists`:
+# first host row `fh`, spill_cap `s_cap` (a mailbox's capacity is its
+# cohort's: program.Cohort.mailbox_cap, `rows_of`); `lists`:
 # route.ListSizes; `dispatchers`: (run_cohort, cohort) a device cohort.
 # `pri_rank`, `n_levels` — delivery priority levels (see
 # delivery.deliver): 0 = receiver spill, 1 = host inject, 2+k = sender
@@ -148,7 +173,7 @@ def ring_take(buf_rows, slot):
 # freed); val-mode (frozen, shared) positions COPY — other readers keep
 # the source.
 TickStatic = collections.namedtuple(
-    "TickStatic", "program opts p nl c fh s_cap lists pri_rank n_levels "
+    "TickStatic", "program opts p nl fh s_cap lists pri_rank n_levels "
     "cohort_layout blob_route dispatchers")
 
 
@@ -468,7 +493,6 @@ def init_state(program: Program, opts: RuntimeOptions) -> RtState:
     # the (trace_id, parent_span) lanes when tracing is on — a parked
     # message must keep its causal context across the retry.
     w1 = 1 + opts.msg_words + opts.trace_lanes
-    c = opts.mailbox_cap
     s = opts.spill_cap * p
     _, _, n_entries = layout_sizes(program, opts)
     i32 = jnp.int32
@@ -490,7 +514,8 @@ def init_state(program: Program, opts: RuntimeOptions) -> RtState:
 
     return RtState(
         buf={cohort.atype.__name__:
-             jnp.zeros((c, 1 + cohort.msg_words, cohort.capacity), i32)
+             jnp.zeros((cohort.mailbox_cap, 1 + cohort.msg_words,
+                        cohort.capacity), i32)
              for cohort in program.cohorts},
         head=jnp.zeros((n,), i32),
         tail=jnp.zeros((n,), i32),
@@ -538,13 +563,14 @@ def init_state(program: Program, opts: RuntimeOptions) -> RtState:
         beh_rejected=jnp.zeros((p * nb,), i32),
         coh_mute_ticks=jnp.zeros((p * nd,), i32),
         qwait_hist=jnp.zeros((p * nd * QW_BUCKETS,), i32),
-        qwait_enq=({ch.atype.__name__: jnp.zeros((c, ch.capacity), i32)
+        qwait_enq=({ch.atype.__name__:
+                    jnp.zeros((ch.mailbox_cap, ch.capacity), i32)
                     for ch in program.device_cohorts}
                    if opts.analysis >= 1 else {}),
         phase_cost=jnp.zeros(
             (p * (N_PHASES if opts.analysis >= 1 else 0),), i32),
         trace_buf=({ch.atype.__name__:
-                    jnp.full((c, 2, ch.capacity), -1, i32)
+                    jnp.full((ch.mailbox_cap, 2, ch.capacity), -1, i32)
                     for ch in program.cohorts}
                    if opts.tracing else {}),
         span_data=jnp.zeros(
@@ -602,6 +628,10 @@ def geometry_descriptor(program: Program, opts: RuntimeOptions):
             "local_start": c.local_start,
             "host": bool(c.host),
             "msg_words": c.msg_words,
+            # its own ring depth, where it states one (a snapshot of a
+            # program that states none reads as it always did)
+            **({"mailbox_cap": c.mailbox_cap}
+               if c.mailbox_cap != opts.mailbox_cap else {}),
         } for c in program.cohorts],
     }
 

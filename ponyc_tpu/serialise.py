@@ -825,7 +825,6 @@ def _restore_relayout(rt, header, Z: Dict[str, np.ndarray]) -> None:
         st[name] = dst
 
     # ---- mailbox re-ring (head=0, tail=occ in the new ring) ----
-    c_old, c_new = old.mailbox_cap, opts.mailbox_cap
     head_n = np.zeros((n_new,), np.int64)
     tail_n = np.zeros((n_new,), np.int64)
     new_bufs: Dict[str, np.ndarray] = {}
@@ -834,6 +833,9 @@ def _restore_relayout(rt, header, Z: Dict[str, np.ndarray]) -> None:
     for c in prog.cohorts:
         name = c.atype.__name__
         co = old_cohorts[name]
+        # a cohort's own ring depth, where either side states one
+        c_old = int(co.get("mailbox_cap", old.mailbox_cap))
+        c_new = c.mailbox_cap
         slots, og, ng = kept_pairs[name]
         occ = tail_o[og] - head_o[og]
         if (occ > c_new).any():

@@ -119,6 +119,8 @@ def quiesce_key(program, opts: RuntimeOptions) -> Dict[str, Any]:
         {"type": ch.atype.__name__, "capacity": int(ch.capacity),
          "batch": int(ch.batch), "max_sends": int(ch.max_sends),
          "msg_words": int(ch.msg_words),
+         **({"mailbox_cap": int(ch.mailbox_cap)}
+            if ch.mailbox_cap != opts.mailbox_cap else {}),
          "behaviours": len(ch.behaviours),
          "host": bool(ch.host), "blobs": bool(ch.uses_blobs)}
         for ch in program.cohorts]
