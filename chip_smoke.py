@@ -57,9 +57,10 @@ UBENCH_GEOMETRY = dict(mailbox_cap=4, batch=PINGS, max_sends=1,
                        msg_words=1, spill_cap=1024, inject_slots=8)
 MESH_SHARDS = 4
 
-# The plan formulation's private cache (state.py): only delivery="plan"
-# writes it, so it is the one thing "cosort" may legitimately differ in.
-PLAN_CACHE_LEAVES = ("plan_key", "plan_perm", "plan_bounds")
+# The plan formulation's private cache (state.py) and its count of the
+# ticks delivered over the list's prefix: only delivery="plan" writes
+# them, so they are what "cosort" may legitimately differ in.
+PLAN_CACHE_LEAVES = ("plan_key", "plan_perm", "plan_bounds", "n_prefix")
 
 
 class SmokeFailure(AssertionError):

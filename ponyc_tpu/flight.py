@@ -157,6 +157,10 @@ class FlightRecorder:
             "occ_sum": int(aux.occ_sum), "occ_max": int(aux.occ_max),
             "qw_p99": int(aux.qw_p99),
             "muted_now": int(aux.n_muted_now),
+            # cumulative ticks delivered over the list's prefix, where
+            # the window holds that path (StepAux.lists)
+            **{name: int(n) & 0xFFFFFFFF
+               for name, n in aux.lists.items()},
             "flags": {
                 "device_pending": bool(aux.device_pending),
                 "host_pending": bool(aux.host_pending),

@@ -104,5 +104,7 @@ def compact_mask(mask: jnp.ndarray, cap: int):
     total = jnp.sum(mask.astype(jnp.int32))
     perm = jnp.argsort(~mask, stable=True)
     perm = perm[:cap]
+    if perm.shape[0] < cap:         # a mask shorter than cap: padding
+        perm = jnp.pad(perm, (0, cap - perm.shape[0]))
     valid = jnp.arange(cap, dtype=jnp.int32) < total
     return perm, valid, total

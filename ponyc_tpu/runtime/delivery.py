@@ -14,6 +14,28 @@ within one shard of the actor world:
      send order; SURVEY.md §7 hard part (c)) because a sender whose message
      was rejected is muted until its spill drains, so it can never emit a
      *newer* message that would overtake an older spilled one;
+  2b. the sort packs the live entries; what follows runs over them. An
+     entry that is not delivered (an empty slot, a target off this
+     shard) has the largest key there is, so the stable sort leaves
+     the tick's V live entries as the first V of the permutation, in
+     delivery order, and nothing in points 3-5 reads a position past V.
+     A delivery list is sized for what its world COULD send (every
+     actor, every batch slot, every send site) and is mostly room in a
+     world where few send or where a behaviour has more sites than
+     fire: `deliver` therefore holds points 3-5 at two static lengths,
+     the whole list and `prefix_len` of it (a quarter), and the tick's
+     own count chooses, `V <= prefix` — two conditionals in turn (the
+     whole list's tick, or the prefix's words, or nothing; then the
+     prefix's tick: one table-writing branch each, see there), and one
+     inside the plan's miss for the bounds' merge. The permutation
+     gather, the merge, the table the
+     rebuild's gathers read and every list read of the pressure branch
+     are then paid by a quarter of the slots. Same mailboxes, spill,
+     mutes and stored plan on either branch, bit for bit
+     (tests/test_delivery_prefix.py); `n_prefix` (RtState.route_counts)
+     counts the ticks that took the prefix. What makes the prefix is
+     not shortened: the key, its compare with the cached one, the sort.
+     Not built for rings of one block (4d) nor in cosort;
   3. per-target segment bounds come from merging the target boundaries
      into the sorted keys (ops/segment.py `segment_bounds`: sorts and a
      prefix sum, no indexed read); each target accepts min(count,
@@ -93,6 +115,7 @@ within one shard of the actor world:
 
 from __future__ import annotations
 
+from functools import partial
 from itertools import accumulate
 from typing import NamedTuple
 
@@ -101,7 +124,7 @@ from jax import lax
 
 from ..ops.segment import (compact_mask, segment_bounds,
                            stable_sort_with_keys)
-from .state import phase_scope
+from .state import REBUILD_BLOCK, phase_scope
 
 
 class Entries(NamedTuple):
@@ -143,6 +166,12 @@ class DeliveryResult(NamedTuple):
     #                               compacted one (rebuild_tables); cap x
     #                               N for a ring of one block; 0 with no
     #                               message
+    n_prefix: object = None    # [] int32, 1 where this tick ran over the
+    #                               list's PREFIX (deliver; 0 always in
+    #                               cosort and for a list of a few
+    #                               tiles, which have the one length);
+    #                               None for rings of one rebuild
+    #                               block: the state has no such counter
 
 
 def mute_ref_slots(trig, mute_row, refs, *, n: int, k: int):
@@ -165,10 +194,18 @@ def empty_mute_slots(n: int, k: int):
     return jnp.full((k, n), -1, jnp.int32), jnp.zeros((n,), jnp.bool_)
 
 
-# Arrival ranks one rebuild block gathers for every actor: a vreg's
-# sublanes, and RuntimeOptions' default `batch` — an actor that keeps
-# taking in more than it drains is under pressure, not in steady state.
-REBUILD_BLOCK = 8
+# The short length of a delivery list is one PREFIX_SHARE-th of it
+# (prefix_len): the tick's live entries, which the plan's sort packs to
+# the front, fit it in every world whose list is mostly room.
+PREFIX_SHARE = 4
+
+
+def prefix_len(e: int) -> int:
+    """The short one of the two static lengths delivery runs at after
+    the plan's sort (module docstring, 2b), for a list of `e` entries:
+    a quarter of it, in whole 128-lane tiles. Not below `e` for a list
+    of a few tiles, which then has the one length."""
+    return min(e, -(-e // (PREFIX_SHARE * 128)) * 128)
 
 
 def rebuild_tables(tables, wds, tail, acc, seg_start):
@@ -388,9 +425,26 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
         with phase_scope("delivery/plan/bounds"):
             return segment_bounds(sorted_key, n, n_levels)
 
+    # rebuild_tables' static guard: one depth for the world, one block
+    one_block = isinstance(c, int) and c <= REBUILD_BLOCK
+
+    # --- after the sort, the live entries are a prefix (module
+    # docstring, 2b): everything below the sort is held at two static
+    # lengths, the list's and `short`, and the tick's own count of live
+    # entries chooses. Not for rings of one block (their window is
+    # counted in operations) nor in cosort (no permutation to cut).
+    short = e if one_block or cosort else prefix_len(e)
+    n_live = jnp.sum(in_range.astype(jnp.int32)) if short < e else None
+
     def _compute_plan(k):
         sorted_key, p_ = stable_sort_with_keys(k)
-        return p_, _bounds(sorted_key)
+        if short == e:
+            return p_, _bounds(sorted_key)
+        # every key past the live ones equals the largest query: the
+        # same n + 1 numbers from a merge of n + 1 + short keys
+        return p_, lax.cond(n_live <= short,
+                            lambda _: _bounds(sorted_key[:short]),
+                            lambda _: _bounds(sorted_key), operand=None)
 
     w1 = words.shape[0]
     if cosort:
@@ -417,9 +471,6 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
                 lambda _: _compute_plan(key),
                 operand=None)
 
-    # rebuild_tables' static guard: one depth for the world, one block
-    one_block = isinstance(c, int) and c <= REBUILD_BLOCK
-
     def _empty_spill():
         refs, ovf = empty_mute_slots(n, mute_slots)
         return (Entries(tgt=jnp.full((spill_cap,), -1, jnp.int32),
@@ -431,7 +482,12 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
     # tick, so it all sits under one cond: an *idle* world's step touches
     # no mailbox memory at all (≙ the fork's idle-cost fix is the reason
     # it exists, README.md:8-10 — a waiting scheduler must cost ~nothing).
-    def with_msgs(_):
+    def with_msgs(buf, trace_buf, wds, _):
+        """The tick's delivery into the tables `buf` / `trace_buf`: over
+        the whole sorted list, or over its first `ln` entries where the
+        caller hands in their words, `wds` [w1, ln] sorted (the live
+        entries fit them)."""
+        ln = e if wds is None else wds.shape[1]
         if cosort:
             with phase_scope("delivery/plan"):
                 ops = lax.sort((key, tgt, sender) + tuple(words),
@@ -445,9 +501,12 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
         else:
             snd_s = None
             seg_bounds = bounds
+            head_perm = perm if ln == e else perm[:ln]
             with phase_scope("delivery/permute"):
-                kt = jnp.where(in_range, tgt, n).astype(jnp.int32)[perm]
-                wds = words[:, perm]                 # [w1, E] sorted
+                kt = jnp.where(in_range, tgt,
+                               n).astype(jnp.int32)[head_perm]
+                if wds is None:
+                    wds = words[:, head_perm]        # [w1, ln] sorted
         ktc = jnp.minimum(kt, n - 1)
         seg_start = seg_bounds[:-1]              # [n]
         cnt = seg_bounds[1:] - seg_start         # [n] sends per target
@@ -506,9 +565,9 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
                 if pressured is not None:
                     hot_t = hot_t | (pressured & alive)
                 ok = kt < n
-                rej = ok & (jnp.arange(e, dtype=jnp.int32) >= bound[ktc])
+                rej = ok & (jnp.arange(ln, dtype=jnp.int32) >= bound[ktc])
                 perm2, vspill, _ = compact_mask(rej, spill_cap)
-                snd = snd_s if cosort else sender[perm]
+                snd = snd_s if cosort else sender[head_perm]
                 spill = Entries(
                     tgt=jnp.where(vspill, kt[perm2], -1),
                     sender=jnp.where(vspill, snd[perm2], -1),
@@ -564,10 +623,46 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
 
     # A tick whose only sends go to dead rows has messages too: it
     # counts them in with_msgs and delivers nothing.
-    any_valid = jnp.any(in_range)
+    if short == e:
+        any_valid = jnp.any(in_range)
+        out = lax.cond(any_valid, partial(with_msgs, buf, trace_buf, None),
+                       no_msgs, operand=None)
+        took_prefix = None if one_block else jnp.int32(0)
+    else:
+        # Two conditionals in turn, each with ONE branch that rebuilds
+        # the tables, not one conditional of three: XLA orders a
+        # conditional's branches, and every branch but the last that
+        # writes an operand in place copies it first — the whole
+        # mailbox table once a rebuild block (8 ms at 1M rows x 64
+        # slots). And the list's words are read by the FIRST alone, so
+        # that they die where they did before the tick had two lengths:
+        # the compiler keeps them in fast memory for `words[:, perm]`
+        # only if nothing after it reads them (cycle's permute 43.6 ms,
+        # 72.1 without). So the first conditional runs the whole list's
+        # tick, or gathers the words of the prefix's, or idles; the
+        # second runs the prefix's tick over the first's tables and
+        # those words, or hands everything through.
+        any_valid = n_live > 0
+        fits = n_live <= short
+        took_prefix = (any_valid & fits).astype(jnp.int32)
+
+        def prefix_words(_):
+            with phase_scope("delivery/permute"):
+                return no_msgs(None), words[:, perm[:short]]
+
+        no_words = jnp.zeros((w1, short), jnp.int32)
+        out, wds_short = lax.switch(
+            took_prefix + 2 * (~fits).astype(jnp.int32),
+            (lambda _: (no_msgs(None), no_words), prefix_words,
+             lambda _: (with_msgs(buf, trace_buf, None, None), no_words)),
+            None)
+        out = lax.cond(
+            took_prefix > 0, lambda _: with_msgs(
+                out[0], None if trace_buf is None else out[1], wds_short,
+                None),
+            lambda _: out, operand=None)
     (buf_out, tbuf_out, new_tail, spill, newly_muted, new_refs, new_ovf,
-     n_delivered, nrej, n_deadletter, *slots) = lax.cond(
-         any_valid, with_msgs, no_msgs, operand=None)
+     n_delivered, nrej, n_deadletter, *slots) = out
     # A ring of one block carries no count out of the cond (its window
     # stays the program it was): it gathered its whole ring iff the
     # tick had a message.
@@ -584,5 +679,5 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
         n_rejected=nrej,
         n_deadletter=n_deadletter,
         plan_key=key, plan_perm=perm, plan_bounds=bounds,
-        rebuild_slots=rebuild_slots,
+        rebuild_slots=rebuild_slots, n_prefix=took_prefix,
     )

@@ -47,8 +47,8 @@ from ..program import Cohort, Program
 from . import lanes, mute, route, spawn
 from .delivery import Entries
 from .gc import build_blob_arg_mask
-from .state import (ROUTE_COUNTERS, PhaseCursor, RtState, TickStatic,
-                    cohort_scope, phase_scope, ring_take)
+from .state import (PhaseCursor, RtState, TickStatic, cohort_scope,
+                    counts_prefix, phase_scope, ring_take)
 
 
 # Selects a cohort's drain may unroll: `batch` ring_takes of `cap - 1`
@@ -115,6 +115,10 @@ class StepAux(NamedTuple):
     #   int32 — the least room >= 0 any tick of the run has left: how
     #   near the world came to a refused spawn; "spawned" int32 —
     #   *cumulative* device spawns (the state's n_spawned, mesh-wide).
+    lists: dict = {}             # which list delivery ran over, where it
+    #   may hold two (state.counts_prefix; {} elsewhere: no leaf).
+    #   "n_prefix" int32 — *cumulative* shard-ticks delivered over the
+    #   list's prefix (the state's route_counts["n_prefix"], mesh-wide).
 
 
 def _bcast_lanes(v, dtype, lanes: int):
@@ -906,6 +910,9 @@ def vote(k: TickStatic, st: RtState, w, d, n_spawned, r, life, m, qw_hist2,
     ndl_new = st.n_deadletter[0] + res.n_deadletter
     nmut_new = st.n_mutes[0] + jnp.sum(m.became.astype(jnp.int32))
     counts = (nrej_new, nbad_new, ndl_new, nmut_new)
+    # which list delivery ran over, where the state counts it
+    lists = {name: st.route_counts[name][0] + r.counts[name]
+             for name in ("n_prefix",) if name in r.counts}
     (occ_sum, occ_max, n_muted_now, n_over_now, nrej_all, nbad_all,
      ndl_all, nmut_all, qw_p99) = lanes.vote_lanes(
         k, occ_after, m.muted, counts, qw_hist2)
@@ -937,7 +944,8 @@ def vote(k: TickStatic, st: RtState, w, d, n_spawned, r, life, m, qw_hist2,
             st.n_delivered[0] + res.n_delivered,
             occ_sum, n_muted_now, n_over_now,
             nrej_all, nbad_all, ndl_all, nmut_all,
-            i32c(pool.fail), i32c(pool.budget)]), "actors")
+            i32c(pool.fail), i32c(pool.budget), *lists.values()]), "actors")
+        lists = dict(zip(lists, summed[19:]))
         facts = tuple(summed[i] > 0 for i in range(len(facts)))
         nproc_all, ndel_all = summed[8], summed[9]
         blob_fail_any, blob_budget_any = summed[17] > 0, summed[18] > 0
@@ -982,6 +990,7 @@ def vote(k: TickStatic, st: RtState, w, d, n_spawned, r, life, m, qw_hist2,
         n_deadletter=ndl_all, n_mutes=nmut_all,
         qw_p99=qw_p99,
         spawn=spawn_aux,
+        lists=lists,
     )
     return aux, counts, overflow, wb_new
 
@@ -1062,9 +1071,8 @@ def tick(k: TickStatic, st: RtState, inject_tgt, inject_words,
         rspill_tgt=r.rspill.tgt, rspill_sender=r.rspill.sender,
         rspill_words=r.rspill.words,
         rspill_count=_vec(r.rspill_count),
-        route_counts=({name: _vec(st.route_counts[name][0] + n)
-                       for name, n in zip(ROUTE_COUNTERS, r.counts)}
-                      if k.p > 1 else st.route_counts),
+        route_counts={name: _vec(st.route_counts[name][0] + n)
+                      for name, n in r.counts.items()},
         spill_overflow=_vec(overflow, jnp.bool_),
         exit_flag=_vec(d.exit_f, jnp.bool_), exit_code=_vec(d.exit_c),
         step_no=_vec(st.step_no[0] + 1),
@@ -1256,13 +1264,17 @@ def build_multi_step(program: Program, opts: RuntimeOptions):
 def zero_aux(program: Optional[Program] = None) -> StepAux:
     """The pre-first-tick aux template (device_pending=True so a window's
     while condition admits tick 0; everything else zero/false; for a
-    `program` with device spawns, all the room there is)."""
+    `program`, the leaves its window's aux has besides: with device
+    spawns all the room there is, `lists` where its state counts the
+    prefix's ticks)."""
     i32, b = jnp.int32, jnp.bool_
     most = i32(2**31 - 1)
     return StepAux(
         spawn=({"room": most, "low": most, "spawned": i32(0)}
                if program is not None and program.has_device_spawns
                else {}),
+        lists=({"n_prefix": i32(0)}
+               if program is not None and counts_prefix(program) else {}),
         device_pending=b(True), host_pending=b(False),
         any_muted=b(False),
         exit_flag=b(False), exit_code=i32(0),

@@ -17,8 +17,8 @@ from jax import lax
 from ..ops import pack
 from ..ops.segment import compact_mask, stable_sort_carrying
 from .delivery import Entries, deliver, empty_mute_slots, mute_ref_slots
-from .state import (PhaseCursor, RtState, TickStatic, layout_sizes,
-                    phase_scope, pool_index, rows_of)
+from .state import (ROUTE_COUNTERS, PhaseCursor, RtState, TickStatic,
+                    layout_sizes, phase_scope, pool_index, rows_of)
 
 
 def _route_pack(tgt, sender, words, *, shards: int, n_local: int,
@@ -380,8 +380,9 @@ ListSizes = namedtuple("ListSizes", "bucket l_in e_short short")
 
 # What steps 3 and 4 leave: delivery.deliver's result; the new route
 # spill, its count, whether it overflowed (fatal); the senders a link
-# muted, [nl], and their refs (nobody on one chip); (n_routed, n_remote,
-# n_unpacked), None on one chip; spawn.Pool after migration and the blobs
+# muted, [nl], and their refs (nobody on one chip); {counter: this
+# tick's count} for the leaves of RtState.route_counts
+# (state.list_counters); spawn.Pool after migration and the blobs
 # that arrived; the delivery list's targets, >= 0 where valid, for
 # lanes.phase_cost_lanes (read at analysis >= 1 only).
 Routed = namedtuple("Routed", "res rspill rspill_count rspill_over muted "
@@ -590,7 +591,9 @@ def deliver_routed(k: TickStatic, st: RtState, w, inject_tgt, inject_words,
             listed_tgt = jnp.concatenate([
                 st.dspill_tgt, _inject_local(k, base, inject_tgt),
                 incoming.tgt])
+    counts = {} if routed is None else dict(
+        zip(ROUTE_COUNTERS, (*routed, n_unpacked)))
+    if res.n_prefix is not None:
+        counts["n_prefix"] = res.n_prefix
     return Routed(res, new_rspill, rsp_count, rsp_over, route_muted,
-                  route_refs, route_ovf,
-                  None if routed is None else (*routed, n_unpacked),
-                  pool, nb_moved, listed_tgt)
+                  route_refs, route_ovf, counts, pool, nb_moved, listed_tgt)

@@ -40,7 +40,7 @@ from ..ops import pack
 from ..program import Program
 from . import engine
 from .controller import WindowController
-from .state import ROUTE_COUNTERS, RtState, init_state, pool_index
+from .state import LIST_COUNTERS, RtState, init_state, pool_index
 
 # Window-length histogram buckets (power-of-two, like state.QW_BUCKETS):
 # bucket k counts retired windows that ran [2^k, 2^(k+1)) ticks.
@@ -2217,9 +2217,10 @@ class Runtime:
         """Sum a per-shard runtime counter (n_processed, n_delivered,
         n_rejected, n_badmsg, n_deadletter, n_mutes; the route's
         n_routed, n_routed_remote, n_unpacked — 0 on one chip, where
-        nothing is routed and the state holds no such leaf) over the
-        mesh."""
-        if name in ROUTE_COUNTERS:
+        nothing is routed and the state holds no such leaf; n_prefix,
+        the ticks delivered over the list's prefix — 0 where the state
+        holds no such leaf, state.counts_prefix) over the mesh."""
+        if name in LIST_COUNTERS:
             leaf = self.state.route_counts.get(name)
             return 0 if leaf is None else int(self._fetch(leaf).sum())
         return int(self._fetch(getattr(self.state, name)).sum())

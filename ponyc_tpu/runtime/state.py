@@ -121,6 +121,13 @@ def cohort_scope(type_name: str) -> str:
     return f"dispatch/cohort/{type_name}"
 
 
+# Arrival ranks one rebuild block gathers for every actor
+# (delivery.rebuild_tables): a vreg's sublanes, and RuntimeOptions'
+# default `batch` — an actor that keeps taking in more than it drains
+# is under pressure, not in steady state.
+REBUILD_BLOCK = 8
+
+
 def rows_of(program: Program, attr: str):
     """A cohort's own `attr` (`mailbox_cap`, `overload_occ`,
     `unmute_occ`: program.Cohort) for every local row. The plain int
@@ -306,9 +313,14 @@ class RtState:
     rspill_sender: jnp.ndarray  # [P*S] int32 sender global id
     rspill_words: jnp.ndarray  # [1+W, P*S] int32
     rspill_count: jnp.ndarray  # [P] int32
-    # What the route moved, cumulative per shard, a mesh only ({} where
-    # P == 1: no leaf, the one-chip window's HLO unchanged — nothing is
-    # routed there). "n_routed" [P] int32 — entries this shard placed
+    # Which list delivery ran over and what the route moved, cumulative
+    # per shard (list_counters: a leaf only where its path is built).
+    # "n_prefix" [P] int32 — the ticks on which this shard delivered
+    # over the PREFIX of its list (delivery.deliver: the live entries
+    # fitted a quarter of it; 0 in cosort, which has the one length);
+    # no leaf where every ring is one rebuild block (counts_prefix).
+    # A mesh only, nothing being routed on one chip: "n_routed" [P]
+    # int32 — entries this shard placed
     # in an all_to_all bucket (a message counts once, in the tick it
     # ships: one parked in the route spill counts when its retry does);
     # "n_routed_remote" [P] int32 — those of them whose bucket went to
@@ -473,8 +485,27 @@ class RtState:
     type_state: Dict[str, Dict[str, jnp.ndarray]]
 
 
-# The route's counters (RtState.route_counts), a mesh only.
+# The route's counters (RtState.route_counts), a mesh only; and every
+# name that dict may hold (list_counters: which of them a program has).
 ROUTE_COUNTERS = ("n_routed", "n_routed_remote", "n_unpacked")
+LIST_COUNTERS = ROUTE_COUNTERS + ("n_prefix",)
+
+
+def counts_prefix(program: Program) -> bool:
+    """Whether the state counts the ticks delivered over the list's
+    prefix ("n_prefix": delivery.deliver, module docstring 2b). Not
+    where one rebuild block covers every ring: that window holds
+    delivery at the one length, is counted in operations (delivery.py,
+    4d) and stays the program it was."""
+    cap = rows_of(program, "mailbox_cap")
+    return not (isinstance(cap, int) and cap <= REBUILD_BLOCK)
+
+
+def list_counters(program: Program) -> tuple:
+    """The leaves of RtState.route_counts for this program."""
+    return ((ROUTE_COUNTERS if program.shards > 1 else ())
+            + (("n_prefix",) if counts_prefix(program) else ()))
+
 
 # The int32 word tables that serialise.save(packed=True) stores as an
 # int16 lane plane + an int32 escape plane: mailbox ring records, both
@@ -534,8 +565,8 @@ def init_state(program: Program, opts: RuntimeOptions) -> RtState:
         rspill_sender=jnp.full((s,), -1, i32),
         rspill_words=jnp.zeros((w1, s), i32),
         rspill_count=jnp.zeros((p,), i32),
-        route_counts=({name: jnp.zeros((p,), i32)
-                       for name in ROUTE_COUNTERS} if p > 1 else {}),
+        route_counts={name: jnp.zeros((p,), i32)
+                      for name in list_counters(program)},
         spill_overflow=jnp.zeros((p,), jnp.bool_),
         exit_flag=jnp.zeros((p,), jnp.bool_),
         exit_code=jnp.zeros((p,), i32),

@@ -251,7 +251,8 @@ def _unpack_snapshot_arrays(arrays: Dict[str, np.ndarray],
 # telemetry starts over) instead of rejecting the whole snapshot.
 _ZERO_IF_ABSENT = frozenset({"st.phase_cost", "st.route_counts.n_routed",
                              "st.route_counts.n_routed_remote",
-                             "st.route_counts.n_unpacked"})
+                             "st.route_counts.n_unpacked",
+                             "st.route_counts.n_prefix"})
 
 
 def _pad_phase_lanes(arr, n_shards: int) -> np.ndarray:

@@ -5,7 +5,8 @@
   `benchmarks/phase_trace.py` reads).
 - `v5e_counts(snippet, length)`: a function compiled for a DESCRIBED
   v5e (no chip: libtpu's compiler, in a child) and what the chip would
-  run under it, counted.
+  run under it, counted; `branch_ops(text, scope)`: the same a branch
+  of one conditional.
 - `python tests/_hlo.py DIR [--chip] [--only NAME ...]`: the jaxpr, the
   bare HLO and the op_names of the windows in WINDOWS, one file each,
   so that "the parent's program, line for line" is `diff -r` of two
@@ -55,6 +56,51 @@ def op_names(text: str) -> list:
                   for name in re.findall(r'op_name="([^"]*)"', text))
 
 
+def branch_ops(text: str, scope: str) -> list:
+    """For each conditional whose `op_name` ends in `scope`, for each
+    of its branches, the indexed reads, indexed writes and sorts it can
+    reach (through fusions, loops and conditionals inside it), each as
+    (opcode, the dimensions of its result): what a branch costs by the
+    entry."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None and line.startswith(" "):
+            comps[name].append(line)
+
+    def called(line):
+        return re.findall(
+            r"(?:calls|to_apply|body|condition|true_computation|"
+            r"false_computation)=%?([\w.\-]+)", line) + [
+            c.strip().lstrip("%") for group in re.findall(
+                r"branch_computations=\{([^}]*)\}", line)
+            for c in group.split(",")]
+
+    def reach(comp, seen):
+        if comp in seen or comp not in comps:
+            return []
+        seen.add(comp)
+        ops = []
+        for line in comps[comp]:
+            m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) "
+                         r"(gather|scatter|sort)\(", line)
+            if m:
+                ops.append((m.group(2), tuple(
+                    int(d) for d in re.findall(
+                        r"\[([\d,]+)\]", m.group(1))[0].split(","))))
+            for sub in called(line):
+                ops += reach(sub, seen)
+        return ops
+
+    return [[sorted(reach(c, set())) for c in called(line)]
+            for lines in comps.values() for line in lines
+            if " conditional(" in line and re.search(
+                rf'op_name="[^"]*{re.escape(scope)}"', line)]
+
+
 # ------------------------------------------------ for the described v5e
 
 # The child: `snippet` defines `fn` and `args` (`arg(*shape)` is an
@@ -78,6 +124,8 @@ arg = lambda *shape: jax.ShapeDtypeStruct(
 {snippet}
 text = jax.jit(fn).trace(*args).lower(
     lowering_platforms=("tpu",)).compile().as_text()
+if "report" in globals():       # the snippet's own reading of the text
+    print(json.dumps(report(text))); sys.exit(0)
 seen = dict(gathers=0, scatters=0, sorts=0, long=0)
 fused = False
 for line in text.splitlines():
@@ -94,11 +142,11 @@ print(json.dumps(seen))
 """
 
 
-def v5e_counts(snippet: str, length: int) -> dict:
+def v5e_counts(snippet: str, length: int = 0) -> dict:
     """`snippet`'s `fn(*args)` compiled for the described v5e in a
     child: {"gathers", "scatters", "sorts", "long"} of the compiled
-    program. Skips the calling test where this machine has no TPU
-    compiler."""
+    program, or what the snippet's own `report(text)` makes of it.
+    Skips the calling test where this machine has no TPU compiler."""
     import pytest
 
     import _child

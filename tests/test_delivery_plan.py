@@ -182,3 +182,93 @@ def test_plan_holds_sorts_and_one_pass(mode):
     assert sorts.count("delivery/plan") == 1
     assert sorts.count("delivery/plan/bounds") == 2
     assert "cumsum" in {p for s, p in eqns if s == "delivery/plan/bounds"}
+
+
+# `deliver` alone, compiled for a described v5e (no chip: libtpu's
+# compiler, in a child: tests/_hlo.py), a cached plan handed in: what
+# each branch of its choice of length can reach, the reads and writes
+# by index and the sorts, each with its result's dimensions. With
+# `parent` the prefix is not built (`prefix_len` the identity): the
+# program `deliver` was before it held two lengths.
+FOR_THE_CHIP = """
+sys.path.insert(0, {tests!r})
+import _hlo
+from ponyc_tpu.runtime import delivery
+from ponyc_tpu.runtime.delivery import Entries, deliver
+from ponyc_tpu.runtime.state import phase_scope
+if {parent}:
+    delivery.prefix_len = lambda e: e
+n, e, cap, w1 = {n}, {e}, 16, 2
+def fn(buf, head, tail, tgt, sender, words, key, perm, bounds):
+    with phase_scope("delivery"):
+        return deliver(
+            {{"A": buf}}, head, tail, head >= 0, Entries(tgt, sender, words),
+            n_local=n, mailbox_cap=cap, spill_cap=4096, overload_occ=12,
+            shard_base=jnp.int32(0), cohort_layout=[("A", 0, n, w1)],
+            plan=(key, perm, bounds))
+args = (arg(cap, w1, n), arg(n), arg(n), arg(e), arg(e), arg(w1, e),
+        arg(e), arg(e), arg(n + 1))
+report = lambda text: dict(
+    body=_hlo.branch_ops(text, "pony/delivery/cond"),
+    plan=_hlo.branch_ops(text, "pony/delivery/plan/cond"))
+"""
+CHIP_N = 1 << 12
+CHIP_E = 8 * CHIP_N + 2 * 4096 + 8          # ubench-like, 4,096 rows
+
+
+@functools.cache
+def _for_the_chip(parent):
+    """{"whole": what the tick over the whole list can reach, "prefix":
+    what the tick over the prefix can (its words' gather and its
+    delivery sit in two conditionals in turn), "plan": the plan's miss
+    branch}, each its operations as (opcode, dims); the branches that
+    idle, hand through or hit must reach nothing."""
+    import os
+
+    import _hlo
+    seen = _hlo.v5e_counts(FOR_THE_CHIP.format(
+        tests=os.path.dirname(os.path.abspath(__file__)), parent=parent,
+        n=CHIP_N, e=CHIP_E))
+    ops = lambda branch: [(op, tuple(dims)) for op, dims in branch]  # noqa: E731
+    branches = [ops(b) for cond in seen["body"] for b in cond]
+    assert branches.count([]) == (1 if parent else 2)
+    (whole,) = [b for b in branches if _of_the_list(b)]
+    ((miss, hit),) = seen["plan"]        # a conditional's false branch first
+    assert hit == []
+    return {"whole": whole, "plan": ops(miss), "prefix": sorted(
+        op for b in branches if b and b is not whole for op in b)}
+
+
+def _of_the_list(ops):
+    """Those of a branch's operations that run over the whole list, or
+    over the bounds' merge of it (N + 1 + E keys)."""
+    return [(op, dims) for op, dims in ops
+            if set(dims) & {CHIP_E, CHIP_N + 1 + CHIP_E}]
+
+
+def test_for_the_chip_the_prefix_branch_reads_nothing_by_the_list():
+    """What the chip runs on a tick over the prefix holds no gather,
+    sort or scatter of E or N + 1 + E elements: they are of the prefix,
+    the same operations at the other length. The one operation over the
+    list that such a tick pays is the plan's own sort, which makes the
+    prefix — in the plan's miss branch, where the merge for a prefix
+    tick is of N + 1 + prefix keys."""
+    from ponyc_tpu.runtime.delivery import prefix_len
+    short = prefix_len(CHIP_E)
+    seen = _for_the_chip(False)
+    assert seen["prefix"] and _of_the_list(seen["prefix"]) == []
+    assert seen["prefix"] == sorted(
+        (op, tuple(short if d == CHIP_E else d for d in dims))
+        for op, dims in seen["whole"])
+    assert sorted(seen["plan"]) == sorted(
+        [("sort", (CHIP_E,))] + [("sort", (CHIP_N + 1 + CHIP_E,))] * 2
+        + [("sort", (CHIP_N + 1 + short,))] * 2)
+
+
+def test_for_the_chip_the_long_branch_is_the_parents():
+    """The branch a tick takes when its live entries do not fit the
+    prefix holds the operations `deliver` held before it had two
+    lengths, one for one."""
+    parent = _for_the_chip(True)
+    assert _for_the_chip(False)["whole"] == parent["whole"]
+    assert parent["prefix"] == []

@@ -163,7 +163,7 @@ def _lowered_window():
     rt = world.rt
     lowered = jax.jit(engine.build_multi_step_gated(rt.program, rt.opts)) \
         .lower(rt.state, *rt._empty_inject, jnp.int32(4), jnp.bool_(True),
-               engine.zero_aux())
+               rt._zero_aux)
     rt.stop()
     return lowered
 

@@ -24,7 +24,8 @@ import pytest
 from benchmarks import reference_mesh
 from benchmarks.worlds import ubench_mesh
 from ponyc_tpu.runtime import engine
-from ponyc_tpu.runtime.state import ROUTE_COUNTERS, SCOPE_PREFIX
+from ponyc_tpu.runtime.state import (LIST_COUNTERS, ROUTE_COUNTERS,
+                                     SCOPE_PREFIX)
 from _hlo import bare_hlo
 from test_run_loop import recording  # noqa: F401  (a fixture)
 
@@ -147,12 +148,12 @@ def _window_text(rt, compiled=False):
 def test_route_scopes_and_counters_exist_on_a_mesh_only():
     """A mesh's window names the route's five parts and its state holds
     the three counters; a one-shard window has no operation under
-    `pony/route/*` and no such leaf: its inputs are the parent's."""
+    `pony/route/*` and no such leaf (`n_prefix` is delivery's: on both)."""
     world = _world(4, "random", actors=256)
     text = _window_text(world.rt)
     for scope in ROUTE_SCOPES:
         assert f"{SCOPE_PREFIX}/{scope}/" in text, scope
-    assert sorted(world.rt.state.route_counts) == sorted(ROUTE_COUNTERS)
+    assert sorted(world.rt.state.route_counts) == sorted(LIST_COUNTERS)
     world.rt.stop()
 
     world = _world(1, "random", actors=256)
@@ -160,7 +161,7 @@ def test_route_scopes_and_counters_exist_on_a_mesh_only():
     assert f"{SCOPE_PREFIX}/route/" in text
     for scope in ROUTE_SCOPES:
         assert f"{SCOPE_PREFIX}/{scope}" not in text, scope
-    assert world.rt.state.route_counts == {}
+    assert list(world.rt.state.route_counts) == ["n_prefix"]
     assert world.rt.counter("n_routed") == 0
     assert world.rt.counter("n_routed_remote") == 0
     assert world.rt.counter("n_unpacked") == 0

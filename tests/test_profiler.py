@@ -559,7 +559,7 @@ def _lowered_window(delivery, cap):
     gated = engine.build_multi_step_gated(rt.program, rt.opts)
     lowered = jax.jit(gated).lower(
         rt.state, *rt._empty_inject, jnp.int32(4), jnp.bool_(True),
-        engine.zero_aux())
+        rt._zero_aux)
     rt.stop()
     return rt, lowered
 

@@ -23,6 +23,9 @@ N, E = 24, 320
 LAYOUT = [("Narrow", 0, 16, 2), ("Wide", 16, 24, 4)]   # (type, s0, s1, 1+W)
 ONE = [("Wide", 0, N, 4)]                  # a world of one cohort
 W1 = 4 + 2                                 # widest payload + trace context
+# `deliver` holds its body at two lengths, the list and its prefix
+# (delivery.prefix_len); a one-block ring at the one.
+LENGTHS = 1 + (delivery.prefix_len(E) < E)
 
 
 def _forms(acc, layout=LAYOUT):
@@ -327,7 +330,8 @@ def test_deep_ring_has_two_loops_a_cohort(layout):
     full block is one gather a table; a compacted one a sort of the deep
     rows, one (short) gather a table and ONE scatter back to the table's
     lanes for the cohort, its trace side lanes inside the same loops."""
-    cohorts, tables = len(layout), 2 * len(layout)  # buf + trace_buf
+    # buf + trace_buf, in the body at each length
+    cohorts, tables = LENGTHS * len(layout), LENGTHS * 2 * len(layout)
     b = delivery.REBUILD_BLOCK
     deep = _rebuild_eqns(16, layout)
     assert deep.count("while") == 2 * cohorts
@@ -339,7 +343,7 @@ def test_deep_ring_has_two_loops_a_cohort(layout):
     # The selects of both loops and the deep rows' `where`; every
     # `_take` and the `%` of `rels` hold one each.
     assert deep.count("_where") == (2 * tables * b + cohorts
-                                    + 2 * tables + 1)
+                                    + 2 * tables + LENGTHS)
     # Every loop's body writes its scope itself (a body is a computation
     # of its own): its gathers are named without their loop's help, the
     # compacted blocks' one scope down.

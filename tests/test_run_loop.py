@@ -147,7 +147,7 @@ def test_gate_closes_on_stale_attention_aux():
     # Real first window: runs (force himself is not even needed — the
     # inject makes zero_aux's device_pending=True gate pass).
     st, aux, k = rt._multi_g(rt.state, *inj, jnp.int32(4),
-                             np.bool_(True), engine.zero_aux())
+                             np.bool_(True), rt._zero_aux)
     rt.state = st
     assert int(k) == 4
     # Forge a stale attention vote: same aux but host_pending=True.
@@ -166,7 +166,7 @@ def test_gate_closes_on_stale_quiet_aux_keeps_quiescence_exact():
     import jax.numpy as jnp
     rt, _ids = _ring(hops=2)
     rt.run(max_steps=100)                    # quiesce for real
-    quiet = engine.zero_aux()._replace(device_pending=np.bool_(False))
+    quiet = rt._zero_aux._replace(device_pending=np.bool_(False))
     st, aux, k = rt._multi_g(rt.state, *rt._empty_inject, jnp.int32(8),
                              np.bool_(False), quiet)
     rt.state = st
@@ -186,7 +186,7 @@ def test_gated_out_window_requeues_injections():
     inj_t, inj_w, consumed = rt._drain_inject_tracked()
     assert len(consumed) == 2 and not rt._inject_q
     import jax.numpy as jnp
-    quiet = engine.zero_aux()._replace(device_pending=np.bool_(False))
+    quiet = rt._zero_aux._replace(device_pending=np.bool_(False))
     st, aux, k = rt._multi_g(rt.state, inj_t, inj_w, jnp.int32(4),
                              np.bool_(False), quiet)
     rt.state = st
@@ -525,7 +525,7 @@ def test_window_constants_ride_optimization_barrier():
     gated = engine.build_multi_step_gated(rt.program, rt.opts)
     text = jax.jit(gated).lower(
         rt.state, *rt._empty_inject, jnp.int32(4), jnp.bool_(True),
-        engine.zero_aux()).as_text()
+        rt._zero_aux).as_text()
     assert "optimization_barrier" in text
 
 

@@ -144,7 +144,7 @@ def test_each_phase_traces_alone(analysis):
         with PhaseCursor() as phase:
             return route.deliver_routed(k, *a, phase)
     r = alone(routed, st, w, inj_t, inj_w, d.out_entries, cl, d.pool)
-    assert r.counts is None and r.listed_tgt.shape == (
+    assert r.counts == {} and r.listed_tgt.shape == (
         opts.spill_cap + opts.inject_slots + k.lists.l_in,)
     life = alone(lambda st, d, tail, cl, um: engine.lifecycle(
         k, st, d, tail, cl, um), st, d, r.res.tail, cl, um)
