@@ -184,6 +184,10 @@ def phase_ubench(n: int, steps: int, mesh_shards: int = 1) -> dict:
         unpacked = rt.counter("n_unpacked")
         check("every shard of every tick took the short delivery list",
               unpacked == mesh_shards * rt.steps_run, f"{unpacked}")
+        # Nobody declares pressure here: no shard of any tick looks its
+        # routed entries' targets up in the pressured bits.
+        looked_up = rt.counter("n_route_pressure")
+        check("no tick looked up pressure", looked_up == 0, f"{looked_up}")
     rt.stop()
     return {"setup_s": setup_s, "first_call_s": first_s, "rest_s": rest_s,
             "rest_steps": warm_steps}

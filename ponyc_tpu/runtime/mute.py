@@ -17,10 +17,12 @@ from .state import RtState, TickStatic, rows_of
 # A row's status word for the unmute pass (`muter_bits`).
 LIVE_CONG, CAN_RECOVER, RECOVERED, PRESSURED = 1, 2, 4, 8
 # This shard's index and the global id of its row 0; [nl] occupancy; world
-# bits 1 and 2 of the previous vote; [p * nl] declared pressure, mesh-wide;
-# [nl] messages parked for a row in the device spill.
-World = namedtuple("World", "shard base occ0 muted_anywhere rspill_anywhere "
-                   "pressured_global dspill_pending")
+# bits 0, 1 and 2 of the previous vote; [p * nl] declared pressure,
+# mesh-wide (zeros where bit 0 is clear); [nl] messages parked for a row
+# in the device spill.
+World = namedtuple("World", "shard base occ0 pressured_anywhere "
+                   "muted_anywhere rspill_anywhere pressured_global "
+                   "dspill_pending")
 Unmuted = namedtuple("Unmuted", "muted mute_refs mute_ovf")
 # `became`: [nl] muted this tick and not before.
 Muted = namedtuple("Muted", "became muted age refs ovf")
@@ -72,8 +74,8 @@ def world(k: TickStatic, st: RtState) -> World:
             jnp.minimum(jnp.maximum(st.dspill_tgt, 0), nl - 1),
             (st.dspill_tgt >= 0).astype(jnp.int32), nl),
         lambda _: jnp.zeros((nl,), jnp.int32), operand=None)
-    return World(shard, base, occ0, world_muted, world_rspill,
-                 pressured_global, dspill_pending)
+    return World(shard, base, occ0, world_pressured, world_muted,
+                 world_rspill, pressured_global, dspill_pending)
 
 
 def unmute_pass(k: TickStatic, st: RtState, w: World) -> Unmuted:

@@ -137,8 +137,8 @@ def _store_short_plan(plan, key_s, perm_s):
 
 def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
            rspill_cap: int, overload_occ, head, tail, shard_base,
-           mute_slots: int, pressured_global, pressured_local,
-           blob=None):
+           mute_slots: int, pressured_anywhere, pressured_global,
+           pressured_local, blob=None):
     """Mesh routing: pack entries into per-destination-shard buckets
     (`_route_pack`: one payload-carrying sort, then a contiguous slice a
     destination) and exchange them with three all_to_all over the actor
@@ -302,6 +302,7 @@ def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
             n_local=n_local, bucket=bucket, rspill_cap=rspill_cap,
             overload_occ=overload_occ, head=head, tail=tail,
             shard_base=shard_base, mute_slots=mute_slots,
+            pressured_anywhere=pressured_anywhere,
             pressured_global=pressured_global,
             pressured_local=pressured_local)
     received = Entries(tgt=rt, sender=rs, words=rw)
@@ -310,8 +311,8 @@ def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
 
 def _route_spill(ts, ss, ws, dt, seg_start, over, *, shards: int,
                  n_local: int, bucket: int, rspill_cap: int, overload_occ,
-                 head, tail, shard_base, mute_slots: int, pressured_global,
-                 pressured_local):
+                 head, tail, shard_base, mute_slots: int,
+                 pressured_anywhere, pressured_global, pressured_local):
     """What did not fit its bucket, and who mutes for it: the sorted
     entries (`ts`, `ss`, `ws` by destination `dt`), each destination's
     `seg_start` and overflow `over` → (new route-spill, spill count,
@@ -323,9 +324,20 @@ def _route_spill(ts, ss, ws, dt, seg_start, over, *, shards: int,
     # cross-shard face of pony_apply_backpressure — every shard sees the
     # all-gathered pressured bits, so senders mute at routing time, not
     # only on the receiver's shard (≙ the reference muting any scheduler
-    # that sends to an under-pressure actor).
-    pr_t = (ts >= 0) & jnp.take(
-        pressured_global, jnp.maximum(ts, 0), mode="clip")
+    # that sends to an under-pressure actor). A read by index is paid by
+    # the entry whatever it fetches, so it runs only on a tick whose
+    # world bit 0 (`pressured_anywhere`, mute.world) says the table can
+    # hold a set bit: where the bit is clear `pressured_global` IS zeros,
+    # and so is the answer.
+    def looked_up(_):
+        hit = (ts >= 0) & jnp.take(
+            pressured_global, jnp.maximum(ts, 0), mode="clip")
+        return hit, jnp.any(hit)
+
+    pr_t, any_pr = lax.cond(
+        pressured_anywhere, looked_up,
+        lambda _: (jnp.zeros((e,), jnp.bool_), jnp.bool_(False)),
+        operand=None)
 
     def pressure(_):
         # Bucket overflow → route spill (stays on this shard, ordered)
@@ -367,7 +379,7 @@ def _route_spill(ts, ss, ws, dt, seg_start, over, *, shards: int,
                 jnp.zeros((n_local,), jnp.bool_), refs, ovf)
 
     new_rspill, newly_muted, new_refs, new_ovf = lax.cond(
-        (nrej > 0) | jnp.any(pr_t), pressure, quiet, operand=None)
+        (nrej > 0) | any_pr, pressure, quiet, operand=None)
     return (new_rspill, jnp.minimum(nrej, rspill_cap), nrej > rspill_cap,
             newly_muted, new_refs, new_ovf)
 
@@ -499,6 +511,7 @@ def deliver_routed(k: TickStatic, st: RtState, w, inject_tgt, inject_words,
             overload_occ=rows_of(k.program, "overload_occ"),
             head=head, tail=tail0, shard_base=base,
             mute_slots=opts.mute_slots,
+            pressured_anywhere=w.pressured_anywhere,
             pressured_global=w.pressured_global,
             pressured_local=st.pressured, blob=rblob)
         if route_blob_out is not None:
@@ -592,7 +605,8 @@ def deliver_routed(k: TickStatic, st: RtState, w, inject_tgt, inject_words,
                 st.dspill_tgt, _inject_local(k, base, inject_tgt),
                 incoming.tgt])
     counts = {} if routed is None else dict(
-        zip(ROUTE_COUNTERS, (*routed, n_unpacked)))
+        zip(ROUTE_COUNTERS, (*routed, n_unpacked,
+                             w.pressured_anywhere.astype(jnp.int32))))
     if res.n_prefix is not None:
         counts["n_prefix"] = res.n_prefix
     return Routed(res, new_rspill, rsp_count, rsp_over, route_muted,

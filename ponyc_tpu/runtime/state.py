@@ -326,7 +326,10 @@ class RtState:
     # "n_routed_remote" [P] int32 — those of them whose bucket went to
     # ANOTHER shard; "n_unpacked" [P] int32 — the ticks on which this
     # shard delivered over the short list (route._route_unpack: what
-    # arrived fitted one shard's outbox). Read through
+    # arrived fitted one shard's outbox); "n_route_pressure" [P] int32 —
+    # the ticks on which this shard looked its sorted entries' targets
+    # up in the mesh-wide pressured bits (route._route_spill: world bit
+    # 0 was set; 0 on a mesh where nobody declares pressure). Read through
     # Runtime.counter(), which sums them over the mesh like n_processed.
     route_counts: Dict[str, jnp.ndarray]
 
@@ -487,7 +490,8 @@ class RtState:
 
 # The route's counters (RtState.route_counts), a mesh only; and every
 # name that dict may hold (list_counters: which of them a program has).
-ROUTE_COUNTERS = ("n_routed", "n_routed_remote", "n_unpacked")
+ROUTE_COUNTERS = ("n_routed", "n_routed_remote", "n_unpacked",
+                  "n_route_pressure")
 LIST_COUNTERS = ROUTE_COUNTERS + ("n_prefix",)
 
 

@@ -252,6 +252,7 @@ def _unpack_snapshot_arrays(arrays: Dict[str, np.ndarray],
 _ZERO_IF_ABSENT = frozenset({"st.phase_cost", "st.route_counts.n_routed",
                              "st.route_counts.n_routed_remote",
                              "st.route_counts.n_unpacked",
+                             "st.route_counts.n_route_pressure",
                              "st.route_counts.n_prefix"})
 
 

@@ -32,7 +32,9 @@ and tear-down of its fixtures included, and the one that does is named:
   of this run already is marked `skip` here.
 
 And a SIGINT nobody pressed fails the test it hit instead of ending
-the xdist session (`_stray_sigint_fails`).
+the xdist session (`_stray_sigint_fails`). And a process that has
+compiled its way past half of `vm.max_map_count` drops JAX's caches
+after the test (`_drop_jit_caches_near_the_map_limit`).
 """
 
 import faulthandler
@@ -127,6 +129,34 @@ def _disarm():
     faulthandler.cancel_dump_traceback_later()
 
 
+def _drop_jit_caches_near_the_map_limit():
+    """A compiled XLA:CPU executable holds memory mappings for as long
+    as a jit cache holds it, and Linux gives a process `vm.max_map_count`
+    of them (65,530): tests/test_rebuild.py alone leaves its worker
+    44,000, the files behind it add theirs (55,000 seen), and past the
+    limit the next compile's mmap fails inside LLVM: a segmentation
+    fault in whichever test compiles next, another one every run (PR
+    46: four of five whole runs of a tree whose new cases had moved
+    which files share that worker). A process past half the limit drops
+    JAX's caches; what a later test needs again is compiled again."""
+    held, limit = _maps_held_and_limit()
+    if held > limit // 2:
+        import jax
+        jax.clear_caches()
+
+
+def _maps_held_and_limit():
+    """(this process's memory mappings, the most it may have); (0, 0)
+    where the system has no such account."""
+    try:
+        with open("/proc/sys/vm/max_map_count") as f:
+            limit = int(f.read())
+        with open("/proc/self/maps", "rb") as f:
+            return sum(1 for _ in f), limit
+    except (OSError, ValueError):
+        return 0, 0
+
+
 @pytest.hookimpl(wrapper=True, tryfirst=True)
 def pytest_runtest_protocol(item, nextitem):
     _skip_if_it_ended_a_worker(item)
@@ -135,6 +165,7 @@ def pytest_runtest_protocol(item, nextitem):
         return (yield)
     finally:
         _disarm()
+        _drop_jit_caches_near_the_map_limit()
 
 
 def _stray_sigint_fails(item):

@@ -73,9 +73,15 @@ def test_fixture_corpus_is_pure_ast_no_import_no_jax():
     # can only raise — the analyzer must never try.
     with pytest.raises(ImportError):
         importlib.import_module("a_module_that_does_not_exist_anywhere")
-    t0 = time.perf_counter()
-    findings = check_path(BROKEN)
-    dt = time.perf_counter() - t0
+    # The least of three: the analysis is 6 ms of work, and under xdist
+    # this file follows test_rebuild.py on its worker, whose heap a full
+    # collection walks in 250–1,100 ms (a `gc.callbacks` log, PR 46);
+    # one landing in a single timing read 192–253 ms in three whole runs.
+    dt = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        findings = check_path(BROKEN)
+        dt = min(dt, time.perf_counter() - t0)
     assert findings, "corpus produced no findings"
     assert "broken_bodies" not in sys.modules
     assert dt < 0.1, f"pure-AST analysis took {dt * 1000:.1f} ms"

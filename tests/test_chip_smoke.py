@@ -49,9 +49,12 @@ def test_phase_mesh_spreads_state_over_four_devices():
 def test_phase_mesh_ubench_takes_the_short_delivery_list(capsys):
     """Phase (e)'s ubench: cycle traffic at the program's own bucket.
     The phase itself checks that every shard of every tick delivered
-    over the short list (`n_unpacked`)."""
+    over the short list (`n_unpacked`) and that none looked up pressure
+    (`n_route_pressure`)."""
     chip_smoke.phase_ubench(256, 8, mesh_shards=4)
-    assert "took the short delivery list: ok" in capsys.readouterr().out
+    out = capsys.readouterr().out
+    assert "took the short delivery list: ok" in out
+    assert "no tick looked up pressure: ok" in out
 
 
 def test_a_failed_check_raises_and_entry_point_refuses_cpu(capsys):

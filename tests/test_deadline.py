@@ -295,3 +295,23 @@ def test_force_cpu_switches_the_exported_compile_cache_off(tmp_path):
     assert "/jax/compilation_cache/tasks_using_cache" \
         not in got["cache_events"], got
     assert sorted(os.listdir(cache)) == filled
+
+
+@pytest.mark.parametrize("held,dropped", [(40000, True), (30000, False),
+                                          (0, False)])
+def test_a_process_past_half_the_map_limit_drops_jaxs_caches(
+        monkeypatch, held, dropped):
+    """conftest's guard after every test: compiled XLA:CPU executables
+    hold memory mappings while a jit cache holds them, and past
+    `vm.max_map_count` the next compile segfaults."""
+    import conftest
+    import jax
+    calls = []
+    monkeypatch.setattr(jax, "clear_caches", lambda: calls.append(1))
+    monkeypatch.setattr(conftest, "_maps_held_and_limit",
+                        lambda: (held, 65530 if held else 0))
+    conftest._drop_jit_caches_near_the_map_limit()
+    assert bool(calls) == dropped
+    monkeypatch.undo()
+    mine, limit = conftest._maps_held_and_limit()
+    assert 0 < mine < limit or (mine, limit) == (0, 0)

@@ -9,11 +9,15 @@ overflow spill across shards; and the spill-overflow abort.
 """
 
 import dataclasses
+import functools
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from ponyc_tpu import I32, Ref, Runtime, RuntimeOptions, actor, behaviour
+from ponyc_tpu.runtime import mute
 from ponyc_tpu.runtime.runtime import SpillOverflowError
 
 
@@ -41,14 +45,15 @@ class Sink:
         return {**st, "got": st["got"] + v}
 
 
-def _run_pressure(opts, n_src=48, items=4):
-    """n_src senders spread over all shards flood ONE sink on shard 0."""
+def _run_pressure(opts, n_src=48, items=4, go=True):
+    """n_src senders spread over all shards flood ONE sink on shard 0
+    (`go=False`: spawned, none started)."""
     rt = Runtime(opts)
     rt.declare(Burst, n_src).declare(Sink, 4)
     rt.start()
     sink = rt.spawn(Sink)
     srcs = rt.spawn_many(Burst, n_src, out=int(sink), left=items)
-    for s in srcs:
+    for s in srcs if go else ():
         rt.send(int(s), Burst.go, 0)
     return rt, sink, srcs
 
@@ -183,3 +188,141 @@ def test_programmatic_backpressure_on_mesh():
     assert rt.run(max_steps=4000) == 0
     assert rt.state_of(int(sink))["got"] == 16 * 40
     assert not np.asarray(rt.state.muted).any()
+
+
+# --- the pressure lookup runs only behind world bit 0 -------------------
+# `route._route_spill` looks its sorted entries' targets up in the
+# mesh-wide pressured bits only on a tick whose world bit 0
+# (`mute.world`'s `pressured_anywhere`) is set; on every other tick the
+# table is zeros and so is the answer. Held against the world in which
+# the bit always reads set: the lookup on every tick, as it was.
+
+SOURCES, ITEMS = 16, 12
+QUIET, PRESSURED, AFTER = 2, 3, 16      # ticks before / under / after
+PHASE = QUIET + PRESSURED + AFTER
+TARGETS = ("remote", "local")           # the phases of one world, in turn
+
+
+def _leaves(rt):
+    """Every state leaf by path (muted, mute_refs, the route spill, the
+    actors' own counts, ...) but the counter of the choice itself."""
+    flat, _ = jax.tree_util.tree_flatten_with_path(rt.state)
+    return {jax.tree_util.keystr(path): np.asarray(leaf)
+            for path, leaf in flat
+            if "n_route_pressure" not in jax.tree_util.keystr(path)}
+
+
+@functools.lru_cache(maxsize=None)
+def _declared_twice(shards, always_look_up):
+    """One meshed world ticked twice through quiet -> the sink declares
+    pressure -> its senders mute at routing time -> release -> unmute:
+    first with four senders none of which lives on the sink's shard
+    ("remote"), then with four that all do ("local"). Four senders of
+    one item a tick are what the sink drains (batch 4), so its mailbox
+    never nears the overload line. With `always_look_up`, `mute.world`'s
+    bit 0 always reads set: `_route_spill` gathers on every tick.
+    Returns per tick (world bit 0 as the tick found it, every state
+    leaf after it) and what the tests ask of the world."""
+    opts = RuntimeOptions(mailbox_cap=64, batch=4, max_sends=2,
+                          msg_words=2, mesh_shards=shards, spill_cap=512,
+                          inject_slots=64, quiesce_interval=1)
+    with pytest.MonkeyPatch.context() as patched:
+        if always_look_up:
+            real = mute.world
+            patched.setattr(mute, "world", lambda k, st: real(
+                k, st)._replace(pressured_anywhere=jnp.bool_(True)))
+        rt, sink, srcs = _run_pressure(opts, SOURCES, ITEMS, go=False)
+        sink, n_local = int(sink), rt.program.n_local
+        seen = []
+
+        def tick(inject=None):
+            bit = int(np.asarray(rt.state.world_bits)[0]) & 1
+            rt.state, _aux = rt._step(
+                rt.state, *(inject or rt._empty_inject))
+            seen.append((bit, _leaves(rt)))
+
+        for where in TARGETS:
+            for s in [s for s in srcs if (int(s) // n_local == sink // n_local)
+                      == (where == "local")][:4]:
+                rt.send(int(s), Burst.go, 0)
+            tick(rt._drain_inject())
+            for _ in range(QUIET - 1):
+                tick()
+            rt.apply_backpressure([sink])
+            for _ in range(PRESSURED):
+                tick()
+            rt.release_backpressure([sink])
+            for _ in range(AFTER):
+                tick()
+        world = dict(sink=sink, n_local=n_local,
+                     overload_occ=rt.opts.overload_occ,
+                     looked_up=rt.counter("n_route_pressure"))
+        rt.stop()
+    return seen, world
+
+
+@pytest.mark.parametrize("where", TARGETS)
+@pytest.mark.parametrize("shards", [2, 4])
+def test_the_gated_lookup_is_the_lookup_on_every_tick(shards, where):
+    seen, world = _declared_twice(shards, False)
+    want, forced = _declared_twice(shards, True)
+    assert len(seen) == len(want) == PHASE * len(TARGETS)
+    first = PHASE * TARGETS.index(where)
+    mine = seen[first:first + PHASE]
+    for t, ((bit, got), (_bit, ref)) in enumerate(
+            zip(mine, want[first:first + PHASE])):
+        assert got.keys() == ref.keys()
+        bad = [k for k in got if not np.array_equal(got[k], ref[k])]
+        assert not bad, (where, t, bit, bad[:6])
+    # the bit is set from the declaration to the tick after the release
+    # (whose vote clears it), and on no other tick
+    bits = [bit for bit, _ in mine]
+    assert bits == ([0] * QUIET + [1] * (PRESSURED + 1)
+                    + [0] * (AFTER - 1)), bits
+    # the script did what it says: the four senders muted under
+    # pressure, on the shards it says, while the sink's mailbox was far
+    # from its overload line; everyone was released and every item of
+    # this phase and the one before arrived once
+    under = mine[QUIET + PRESSURED - 1][1]
+    muted = np.flatnonzero(under[".muted"]) // world["n_local"]
+    on_sinks = muted == world["sink"] // world["n_local"]
+    assert muted.size == 4
+    assert on_sinks.all() if where == "local" else not on_sinks.any()
+    assert (int((under[".tail"] - under[".head"])[world["sink"]])
+            <= world["overload_occ"])
+    assert not mine[-1][1][".muted"].any()
+    assert (int(mine[-1][1][".type_state['Sink']['got']"].sum())
+            == 4 * ITEMS * (1 + TARGETS.index(where)))
+    # and the counter: a shard-tick for every tick whose bit was set
+    assert world["looked_up"] == shards * sum(bit for bit, _ in seen)
+    assert forced["looked_up"] == shards * len(want)
+
+
+@pytest.mark.parametrize("target", ["same-layout", "relayout"])
+def test_a_snapshot_from_before_the_counter_restores_with_it_at_zero(
+        tmp_path, target):
+    """`n_route_pressure` is a state leaf newer than snapshots in the
+    wild: one without it restores, the other route counters carried."""
+    from ponyc_tpu import serialise
+
+    opts = RuntimeOptions(mailbox_cap=8, batch=2, max_sends=2, msg_words=2,
+                          mesh_shards=2, spill_cap=64, inject_slots=64)
+    rt, sink, _srcs = _run_pressure(opts, n_src=8, items=4)
+    rt.apply_backpressure([int(sink)])
+    rt.run(max_steps=3)
+    assert rt.counter("n_route_pressure") > 0
+    header, arrays = serialise.capture(rt)
+    del arrays["st.route_counts.n_route_pressure"]
+    path = str(tmp_path / "older.npz")
+    serialise.write_snapshot(header, arrays, path)
+
+    if target == "relayout":
+        opts = dataclasses.replace(opts, mailbox_cap=16)
+    rt2 = Runtime(opts)
+    rt2.declare(Burst, 8).declare(Sink, 4)
+    rt2.start()
+    serialise.restore(rt2, path)
+    assert rt2.counter("n_route_pressure") == 0
+    assert rt2.counter("n_routed") == rt.counter("n_routed") > 0
+    rt.stop()
+    rt2.stop()

@@ -82,9 +82,11 @@ def test_every_actor_follows_the_reference_on_any_layout(shards, recipients):
         # the route's counters are the reference's sends and crossings
         # (nothing is routed, and nothing counted, on one shard)
         # and at the program's own bucket every shard of every tick
-        # delivers over what arrived, the short list (`n_unpacked`)
+        # delivers over what arrived, the short list (`n_unpacked`);
+        # nobody declares pressure, so no shard of any tick looks its
+        # entries' targets up (`n_route_pressure`)
         expect = (int(sent[:tick + 1].sum()), int(remote[:tick + 1].sum()),
-                  shards * (tick + 1)) if shards > 1 else (0, 0, 0)
+                  shards * (tick + 1), 0) if shards > 1 else (0, 0, 0, 0)
         assert routed == expect, (tick, routed, expect)
         assert spilled == 0, tick
     assert not any(errors.values()), errors
@@ -136,6 +138,18 @@ def test_a_small_bucket_parks_and_retries_without_loss():
     rt.stop()
 
 
+@pytest.mark.parametrize("recipients", ["cycle", "random"])
+@pytest.mark.parametrize("shards", [2, 4])
+def test_a_quiet_mesh_never_looks_up_pressure(shards, recipients):
+    """Nobody declares pressure in this world: world bit 0 is never set
+    and `route._route_spill`'s lookup of the sorted entries' targets in
+    the mesh-wide pressured bits runs on no shard of any tick."""
+    seen = _ticked(shards, recipients)[0]
+    names = dict(zip(ROUTE_COUNTERS, seen[-1][1]))
+    assert names["n_unpacked"] == shards * TICKS
+    assert names["n_route_pressure"] == 0
+
+
 def _window_text(rt, compiled=False):
     gated = engine.jit_multi_step_gated(rt.program, rt.opts, rt.mesh)
     lowered = gated.lower(rt.state, *rt._empty_inject, jax.numpy.int32(4),
@@ -147,7 +161,7 @@ def _window_text(rt, compiled=False):
 
 def test_route_scopes_and_counters_exist_on_a_mesh_only():
     """A mesh's window names the route's five parts and its state holds
-    the three counters; a one-shard window has no operation under
+    the four counters; a one-shard window has no operation under
     `pony/route/*` and no such leaf (`n_prefix` is delivery's: on both)."""
     world = _world(4, "random", actors=256)
     text = _window_text(world.rt)
@@ -165,6 +179,7 @@ def test_route_scopes_and_counters_exist_on_a_mesh_only():
     assert world.rt.counter("n_routed") == 0
     assert world.rt.counter("n_routed_remote") == 0
     assert world.rt.counter("n_unpacked") == 0
+    assert world.rt.counter("n_route_pressure") == 0
     world.rt.stop()
 
 

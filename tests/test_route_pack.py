@@ -132,6 +132,7 @@ def test_overflow_reaches_the_route_spill_unchanged(w1):
         ts, ss, ws, dt, seg_start, cnt - acc,
         head=jnp.zeros((N_LOCAL,), jnp.int32),
         tail=jnp.zeros((N_LOCAL,), jnp.int32),
+        pressured_anywhere=jnp.bool_(False),
         pressured_global=jnp.zeros((n,), jnp.bool_),
         pressured_local=jnp.zeros((N_LOCAL,), jnp.bool_))
     w_tgt, w_sender, w_words, w_count, w_over, rejected = reference_spill(
@@ -205,3 +206,63 @@ def test_for_the_chip_the_pad_folds_into_the_slices(w1):
     e = 4096
     seen = _hlo.v5e_counts(FOR_THE_CHIP.format(e=e, w1=w1), length=2 * e)
     assert (seen["long"], seen["gathers"]) == (0, 0)
+
+
+# `_route_spill` alone, compiled for the described v5e the same way: what
+# a quiet tick pays for it. The lookup of the sorted entries' targets in
+# the mesh-wide pressured bits sits behind world bit 0
+# (`pressured_anywhere`), the overflow and the mutes behind the
+# conditional they always had.
+SPILL_FOR_THE_CHIP = """
+import re
+sys.path.insert(0, {tests!r})
+import _hlo
+from ponyc_tpu.runtime import route
+from ponyc_tpu.runtime.state import phase_scope
+e, shards, n = {e}, 4, {n}
+flag = lambda *shape: jax.ShapeDtypeStruct(
+    shape, jnp.bool_, sharding=SingleDeviceSharding(device))
+def fn(ts, ss, ws, dt, seg_start, over, head, tail, anywhere, everyone, mine):
+    with phase_scope("route/spill"):
+        return route._route_spill(
+            ts, ss, ws, dt, seg_start, over, shards=shards, n_local=n,
+            bucket=e, rspill_cap=4096, overload_occ=48, head=head, tail=tail,
+            shard_base=jnp.int32(0), mute_slots=4,
+            pressured_anywhere=anywhere, pressured_global=everyone,
+            pressured_local=mine)
+args = (arg(e), arg(e), arg(2, e), arg(e), arg(shards), arg(shards), arg(n),
+        arg(n), flag(), flag(shards * n), flag(n))
+def report(text):
+    entry = text[text.index("\\nENTRY "):].split("\\n}}")[0].splitlines()[2:]
+    wide = []
+    for line in entry:
+        typed, opcode = re.match(
+            r"\\s*(?:ROOT )?%?[\\w.\\-]+ = (.*?) ([\\w\\-]+)\\(", line).groups()
+        if re.search(r"[\\[,]%d[\\],]" % e, typed):
+            wide.append(opcode)
+    return dict(conds=_hlo.branch_ops(text, "pony/route/spill/cond"),
+                gathers=text.count(" gather("), wide=sorted(set(wide)))
+"""
+
+
+def test_for_the_chip_a_quiet_ticks_spill_holds_no_gather():
+    """Outside its two conditionals the spill reads nothing by index and
+    writes nothing as long as the entries (the branch's zeros come out
+    of the conditional; the compiler may prefetch a branch's operand,
+    which is a copy); the lookup's branch holds the one gather of `e`
+    flags, and where nothing overflowed and nothing is pressured the
+    second conditional reaches nothing either."""
+    import os
+    e = 1 << 16
+    seen = _hlo.v5e_counts(SPILL_FOR_THE_CHIP.format(
+        tests=os.path.dirname(os.path.abspath(__file__)), e=e, n=e // 8))
+    lookup = [["gather", [e]]]
+    conds = sorted(seen["conds"], key=lambda branches: branches[1] != lookup)
+    assert len(conds) == 2
+    (quiet_zeros, looked_up), (quiet, pressure) = conds  # false branch first
+    assert (quiet_zeros, looked_up, quiet) == ([], lookup, [])
+    in_branches = sum(op == "gather" for op, _dims in looked_up + pressure)
+    assert seen["gathers"] == in_branches > 1
+    computes_nothing = {"parameter", "tuple", "get-tuple-element", "bitcast",
+                        "conditional", "copy-start", "copy-done"}
+    assert set(seen["wide"]) <= computes_nothing, seen["wide"]
