@@ -29,8 +29,12 @@ functions and runs them tiny on the CPU backend):
                   pallas / pallas_fused, every state leaf bit-for-bit
                   against plan (interpret=False on a TPU)
   (e) mesh        (a) and (b) at mesh_shards=4 when >= 4 devices are
-                  visible; with fewer it prints "mesh: not run (N
-                  device)" — the only permitted non-run
+                  visible, then a small fan-in under skew whose
+                  producers mostly live on another shard than their
+                  aggregator (200 ticks mid-pressure, then to
+                  quiescence: conserved, nobody left muted); with fewer
+                  devices it prints "mesh: not run (N device)" — the
+                  only permitted non-run
 
 The compile cache is jax's persistent one, at JAX_COMPILATION_CACHE_DIR
 where that is set and else at the checkout's .cache/ponyc_tpu/xla
@@ -184,10 +188,17 @@ def phase_ubench(n: int, steps: int, mesh_shards: int = 1) -> dict:
         unpacked = rt.counter("n_unpacked")
         check("every shard of every tick took the short delivery list",
               unpacked == mesh_shards * rt.steps_run, f"{unpacked}")
-        # Nobody declares pressure here: no shard of any tick looks its
-        # routed entries' targets up in the pressured bits.
+        # Every mailbox of this geometry sits over its overload line (4
+        # of 4 queued, the line at 3), so from the second tick on every
+        # shard looks its routed entries' targets up in the mesh-wide
+        # hot word — and mutes nobody: a sender that is itself over its
+        # line is exempt. (A mesh on which nobody is overloaded or
+        # declares pressure looks nothing up: tests/test_fanin_mesh.py.)
         looked_up = rt.counter("n_route_pressure")
-        check("no tick looked up pressure", looked_up == 0, f"{looked_up}")
+        check("every tick but the first looked the hot word up",
+              looked_up == mesh_shards * (rt.steps_run - 1), f"{looked_up}")
+        muted = rt.counter("n_mutes")
+        check("and nobody was muted", muted == 0, f"{muted}")
     rt.stop()
     return {"setup_s": setup_s, "first_call_s": first_s, "rest_s": rest_s,
             "rest_steps": warm_steps}
@@ -228,6 +239,80 @@ def phase_ring(n_nodes: int, hops: int, mesh_shards: int = 1) -> dict:
           f"steps_run {rt.steps_run}")
     if mesh_shards > 1:
         spread_check(rt, mesh_shards)
+    rt.stop()
+    return {"first_call_s": first_s, "rest_s": rest_s}
+
+
+# ---------------------------------------------------------------------------
+# (e) backpressure across shards
+
+
+def phase_fanin_mesh(producers: int, aggregators: int, items: int,
+                     mesh_shards: int) -> dict:
+    """A fan-in under skew on a mesh (models.fanin: slow aggregators,
+    BATCH 1): a receiver's overload has to mute senders on other shards
+    and release them when it has recovered. 200 ticks, then on to
+    quiescence."""
+    import numpy as np
+
+    from ponyc_tpu import Runtime, RuntimeOptions
+    from ponyc_tpu.models import fanin
+
+    # two items a producer outside a mailbox at the most (route._route_spill)
+    spill = 1 << (2 * producers - 1).bit_length()
+    rt = Runtime(RuntimeOptions(
+        mailbox_cap=8, batch=2, msg_words=1, spill_cap=spill,
+        mesh_shards=mesh_shards))
+    rt.declare(fanin.Producer, producers).declare(fanin.Aggregator,
+                                                  aggregators)
+    rt.start()
+    print(f"  formulation: {formulation(rt)}", flush=True)
+    aggs = rt.spawn_many(fanin.Aggregator, aggregators)
+    # log-uniform ranks: rank 0 draws producers / log2(aggregators)
+    rank = (aggregators ** np.random.default_rng(0).random(producers)
+            ).astype(np.int64) - 1
+    wired = np.bincount(rank, minlength=aggregators)
+    prods = rt.spawn_many(fanin.Producer, producers, out=aggs[rank])
+    crossing = prods // rt.program.n_local != aggs[rank] // rt.program.n_local
+    check("most edges cross a shard", crossing.mean() > 0.5,
+          f"{crossing.mean():.2f}")
+    rt.bulk_send(prods, fanin.Producer.produce, [items] * producers)
+
+    def accounted():
+        """Per aggregator: counted + queued + parked in a spill, and
+        what its producers have sent."""
+        st = rt.state
+        total = rt.cohort_state(fanin.Aggregator)["total"].astype(np.int64)
+        queued = (np.asarray(st.tail)[aggs].astype(np.int64)
+                  - np.asarray(st.head)[aggs])
+        tgt = np.asarray(st.dspill_tgt).astype(np.int64)
+        gid = tgt + (np.arange(len(tgt)) // (len(tgt) // mesh_shards)
+                     ) * rt.program.n_local
+        index_of = np.zeros(mesh_shards * rt.program.n_local, np.int64)
+        index_of[aggs] = np.arange(aggregators)
+        parked = np.bincount(index_of[gid[tgt >= 0]], minlength=aggregators)
+        sent = np.bincount(
+            rank, weights=rt.cohort_state(fanin.Producer)["sent"],
+            minlength=aggregators).astype(np.int64)
+        return total + queued + parked, sent
+
+    code, first_s = timed(lambda: rt.run(max_steps=200))
+    check("run() return code", code == 0, f"{code}")
+    have, sent = accounted()
+    check("mid-pressure: counted + queued + spilled == sent",
+          bool((have == sent).all()), f"off by {int(abs(have - sent).sum())}")
+    remote = rt.counter("n_remote_mutes")
+    check("senders on other shards were muted", remote > 0, f"{remote}")
+    code, rest_s = timed(rt.run)                 # to quiescence
+    check("run() returns 0 by itself", code == 0, f"{code}")
+    total = rt.cohort_state(fanin.Aggregator)["total"]
+    check("every item counted once", bool((total == items * wired).all()),
+          f"{int(total.sum())} of {items * producers}")
+    muted = int(np.asarray(rt.state.muted).sum())
+    check("nobody left muted", muted == 0, f"{muted}")
+    check("no dead letter, no bad message",
+          rt.counter("n_deadletter") == rt.counter("n_badmsg") == 0)
+    spread_check(rt, mesh_shards)
     rt.stop()
     return {"first_call_s": first_s, "rest_s": rest_s}
 
@@ -379,6 +464,9 @@ def main() -> int:
         run_phase(f"(e) mesh: ring 1024 nodes at mesh_shards="
                   f"{MESH_SHARDS}, every hop crossing a shard",
                   phase_ring, 1024, 2000, mesh_shards=MESH_SHARDS)
+        run_phase(f"(e) mesh: fan-in under skew, 8,192 producers on 256 "
+                  f"aggregators at mesh_shards={MESH_SHARDS}",
+                  phase_fanin_mesh, 8192, 256, 2, MESH_SHARDS)
     else:
         print(f"mesh: not run ({dev['count']} device)", flush=True)
 

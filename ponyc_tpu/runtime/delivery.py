@@ -87,7 +87,10 @@ within one shard of the actor world:
      operations;
   5. rejections compact into the next spill buffer and their locally
      resident senders mute (≙ ponyint_maybe_mute: mute on sending to an
-     overloaded/muted receiver, actor.c:898-921). Both are *pressure
+     overloaded/muted receiver, actor.c:898-921; on a mesh the senders
+     on OTHER shards mute a tick later, at routing, by the mesh-wide
+     hot word — route._route_spill — so such a sender has at most two
+     messages outside a mailbox where a local one has one). Both are *pressure
      paths*: they run under `lax.cond` and cost nothing in the steady
      state where nothing rejects and nobody is overloaded (≙ the
      reference only walking mute maps when senders actually muted,
@@ -581,7 +584,12 @@ def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,
             # declared pressure (the reference's !OVERLOADED /
             # UNDER_PRESSURE guard, which prevents mute deadlocks among
             # hot actors): one bit a row, read once by the sender index.
-            # Only senders resident on this shard can be muted here.
+            # Only senders resident on this shard can be muted here: on
+            # a mesh a sender on another shard mutes at routing, one
+            # tick later, by the hot word `mute.world` gathers from what
+            # this tick leaves (occupancy over the line, anything
+            # spilled: route._route_spill), and both are released by
+            # the same bit of the same tick (mute.unmute_pass).
             with phase_scope("delivery/pressure/mute"):
                 recv_hot = hot_t[ktc]
                 hot_s = occ_after > overload_occ

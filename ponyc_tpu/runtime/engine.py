@@ -48,7 +48,7 @@ from . import lanes, mute, route, spawn
 from .delivery import Entries
 from .gc import build_blob_arg_mask
 from .state import (PhaseCursor, RtState, TickStatic, cohort_scope,
-                    counts_prefix, phase_scope, ring_take)
+                    counts_prefix, phase_scope, ring_take, rows_of)
 
 
 # Selects a cohort's drain may unroll: `batch` ring_takes of `cap - 1`
@@ -932,6 +932,14 @@ def vote(k: TickStatic, st: RtState, w, d, n_spawned, r, life, m, qw_hist2,
     facts = (spawn_fail, local_pending, any_muted_local, host_pending,
              exit_f, overflow, any_pressured_local, any_rspill_local)
     if p > 1:
+        # A mesh's ninth fact, world bit 3: some row ends the tick
+        # overloaded — over its overload line, or with a message parked
+        # for it in a receiver spill — which is what `mute.world` will
+        # put in the next tick's hot word: the gate of its all-gather
+        # and of routing's lookup in it (route._route_spill).
+        facts += (jnp.any(occ_after > rows_of(k.program, "overload_occ"))
+                  | (res.spill_count > 0),)
+        nf = len(facts)
         # ONE packed psum + ONE packed pmax replace the former ~17
         # separate collectives (≙ the CNF/ACK token protocol being a
         # single token, not one message per fact, scheduler.c:303-480).
@@ -945,15 +953,14 @@ def vote(k: TickStatic, st: RtState, w, d, n_spawned, r, life, m, qw_hist2,
             occ_sum, n_muted_now, n_over_now,
             nrej_all, nbad_all, ndl_all, nmut_all,
             i32c(pool.fail), i32c(pool.budget), *lists.values()]), "actors")
-        lists = dict(zip(lists, summed[19:]))
-        facts = tuple(summed[i] > 0 for i in range(len(facts)))
-        nproc_all, ndel_all = summed[8], summed[9]
-        blob_fail_any, blob_budget_any = summed[17] > 0, summed[18] > 0
+        lists = dict(zip(lists, summed[nf + 11:]))
+        *facts, any_overloaded_all = (summed[i] > 0 for i in range(nf))
+        nproc_all, ndel_all = summed[nf], summed[nf + 1]
+        blob_fail_any, blob_budget_any = (summed[nf + 9] > 0,
+                                          summed[nf + 10] > 0)
         if k.opts.analysis >= 1:
-            occ_sum, n_muted_now, n_over_now = (summed[10], summed[11],
-                                                summed[12])
-            nrej_all, nbad_all, ndl_all, nmut_all = (
-                summed[13], summed[14], summed[15], summed[16])
+            occ_sum, n_muted_now, n_over_now = summed[nf + 2:nf + 5]
+            nrej_all, nbad_all, ndl_all, nmut_all = summed[nf + 5:nf + 9]
         maxed = lax.pmax(jnp.stack([
             jnp.where(exit_f, exit_c, jnp.int32(-2**31)), occ_max,
             qw_p99]), "actors")
@@ -973,6 +980,8 @@ def vote(k: TickStatic, st: RtState, w, d, n_spawned, r, life, m, qw_hist2,
     wb_new =(any_pressured_all.astype(jnp.int32)
               | (any_muted_all.astype(jnp.int32) << 1)
               | (any_rspill_all.astype(jnp.int32) << 2))
+    if p > 1:
+        wb_new = wb_new | (any_overloaded_all.astype(jnp.int32) << 3)
     aux = StepAux(
         device_pending=device_pending,
         host_pending=host_pending,

@@ -16,13 +16,21 @@ from .state import RtState, TickStatic, rows_of
 
 # A row's status word for the unmute pass (`muter_bits`).
 LIVE_CONG, CAN_RECOVER, RECOVERED, PRESSURED = 1, 2, 4, 8
+# A row's word for the mute at routing time (`World.hot_global`, a mesh
+# only): it declares pressure; it is overloaded — over its overload line
+# as the tick finds it, or with messages parked for it in its shard's
+# device spill.
+HOT_PRESSURED, HOT_OVERLOADED = 1, 2
 # This shard's index and the global id of its row 0; [nl] occupancy; world
-# bits 0, 1 and 2 of the previous vote; [p * nl] declared pressure,
-# mesh-wide (zeros where bit 0 is clear); [nl] messages parked for a row
-# in the device spill.
-World = namedtuple("World", "shard base occ0 pressured_anywhere "
-                   "muted_anywhere rspill_anywhere pressured_global "
-                   "dspill_pending")
+# bits 1 and 2 of the previous vote; `hot_anywhere`: bit 0 or bit 3
+# (someone declares pressure or is overloaded: the gate of `hot_global`'s
+# gather and of the lookup in it, route._route_spill); [p * nl] declared
+# pressure, mesh-wide (zeros where `hot_anywhere` is clear); [p * nl]
+# int8 the HOT_* word of every row of the mesh (None on one chip; zeros
+# where `hot_anywhere` is clear); [nl] messages parked for a row in the
+# device spill.
+World = namedtuple("World", "shard base occ0 muted_anywhere rspill_anywhere "
+                   "hot_anywhere pressured_global hot_global dspill_pending")
 Unmuted = namedtuple("Unmuted", "muted mute_refs mute_ovf")
 # `became`: [nl] muted this tick and not before.
 Muted = namedtuple("Muted", "became muted age refs ovf")
@@ -38,7 +46,8 @@ def world(k: TickStatic, st: RtState) -> World:
     occ0 = st.tail - st.head
     # World bits (previous tick's mesh-wide vote, stored replicated
     # per shard): bit0 = any actor pressured anywhere, bit1 = any
-    # muted anywhere, bit2 = any route-spill entries anywhere. They
+    # muted anywhere, bit2 = any route-spill entries anywhere, bit3 (a
+    # mesh only) = any row overloaded anywhere. They
     # are shard-uniform by construction (computed from the packed
     # psum vote below; host writes set every shard's entry), so they
     # can gate collectives — every shard takes the same cond branch,
@@ -50,20 +59,6 @@ def world(k: TickStatic, st: RtState) -> World:
     world_pressured = (wb0 & 1) > 0
     world_muted = (wb0 & 2) > 0
     world_rspill = (wb0 & 4) > 0
-    # Mesh-wide pressured bits (≙ pony_apply_backpressure being
-    # visible to every scheduler): one all_gather of the [nl] bool
-    # column — it lets BOTH the routing mute and the remote unmute
-    # guard see off-shard pressure. Gated: ticks on a mesh with no
-    # declared pressure anywhere skip the gather (zeros are exact).
-    if p > 1:
-        pressured_global = lax.cond(
-            world_pressured,
-            lambda _: lax.all_gather(st.pressured, "actors",
-                                     tiled=True),
-            lambda _: jnp.zeros((p * nl,), jnp.bool_),
-            operand=None)
-    else:
-        pressured_global = st.pressured
 
     # The per-row pending histogram (a scatter-add, which serialises
     # on TPU) only runs when the spill actually holds messages — the
@@ -74,13 +69,55 @@ def world(k: TickStatic, st: RtState) -> World:
             jnp.minimum(jnp.maximum(st.dspill_tgt, 0), nl - 1),
             (st.dspill_tgt >= 0).astype(jnp.int32), nl),
         lambda _: jnp.zeros((nl,), jnp.int32), operand=None)
-    return World(shard, base, occ0, world_pressured, world_muted,
-                 world_rspill, pressured_global, dspill_pending)
+
+    # Who must not be sent to, mesh-wide (≙ ponyint_maybe_mute reading
+    # the RECEIVER's flags on every send, whatever scheduler thread the
+    # sender runs on, actor.c:898-921; pony_apply_backpressure being
+    # visible to every scheduler): one all_gather of a word a row —
+    # it declares pressure; it is overloaded as this tick finds it (over
+    # its overload line, or messages parked for it in its shard's device
+    # spill: what its own shard's delivery muted its LOCAL senders for
+    # at the end of the tick before). Routing reads the word by the
+    # sorted entries' targets (route._route_spill) and mutes their
+    # senders, which are always local: a receiver's overload reaches a
+    # sender on another shard one tick after it reached the senders on
+    # its own. The remote unmute guard reads the pressured bit. Gated:
+    # a tick of a mesh on which nobody declared pressure (world bit 0)
+    # and nobody was overloaded (bit 3) when the last tick voted skips
+    # the gather — zeros are exact.
+    if p > 1:
+        world_hot = world_pressured | ((wb0 & 8) > 0)
+        overloaded = st.alive & (
+            (occ0 > rows_of(k.program, "overload_occ"))
+            | (dspill_pending > 0))
+        hot = (jnp.where(st.pressured, HOT_PRESSURED, 0)
+               | jnp.where(overloaded, HOT_OVERLOADED, 0)).astype(jnp.int8)
+        hot_global = lax.cond(
+            world_hot,
+            lambda _: lax.all_gather(hot, "actors", tiled=True),
+            lambda _: jnp.zeros((p * nl,), jnp.int8),
+            operand=None)
+        pressured_global = (hot_global & HOT_PRESSURED) > 0
+    else:
+        world_hot, hot_global = world_pressured, None
+        pressured_global = st.pressured
+    return World(shard, base, occ0, world_muted, world_rspill, world_hot,
+                 pressured_global, hot_global, dspill_pending)
 
 
 def unmute_pass(k: TickStatic, st: RtState, w: World) -> Unmuted:
-    # --- 1. unmute pass (≙ ponyint_sched_unmute_senders,
-    # scheduler.c:1552-1635: receiver recovered → senders released).
+    """--- 1. unmute pass (≙ ponyint_sched_unmute_senders,
+    scheduler.c:1552-1635: receiver recovered → senders released, on
+    every scheduler). A sender is released when EVERY receiver in its
+    ref table has recovered — at or under its unmute line, nothing
+    parked for it in its shard's device spill, no declared pressure —
+    as this tick finds it. On a mesh a ref on another shard is asked
+    the same bit of the same tick as a local one, from the one
+    all-gather of the status word (`muter_bits`, under world bit 1), so
+    a release is never stale; a remote ref additionally waits for this
+    shard's route spill to be empty. (The MUTE that crosses shards is
+    one tick late: `world`'s hot word, route._route_spill.) Link mutes,
+    declared pressure, the aging release and its vetoes are below."""
     p, nl, opts = k.p, k.nl, k.opts
     base, occ0, pressured_global = w.base, w.occ0, w.pressured_global
     # One status word a row: everything the unmute pass asks of a
@@ -129,30 +166,32 @@ def unmute_pass(k: TickStatic, st: RtState, w: World) -> Unmuted:
         ref_local = (lref >= 0) & (lref < nl)
         status = muter_bits_global
         if p > 1:
-            # Each bit is believed from where it was believed before
-            # the word: live-congested and can-recover as gathered
-            # under world bit1, pressure from its own all-gather
-            # (world bit0), and `recovered` from this shard's rows
-            # alone — a remote ref's is never read.
-            status = ((status & (LIVE_CONG | CAN_RECOVER))
-                      | jnp.where(pressured_global, PRESSURED, 0)
-                      | lax.dynamic_update_slice(
-                          jnp.zeros((p * nl,), jnp.int32),
-                          muter_bits & RECOVERED, (base,)))
+            # Live-congested, can-recover and recovered as gathered
+            # under world bit1 — set whenever this pass runs, so a
+            # remote ref's `recovered` is its muter's own, as fresh as
+            # a local ref's —, pressure from the hot word's all-gather
+            # (world bit0), where it was believed before the word.
+            status = ((status & (LIVE_CONG | CAN_RECOVER | RECOVERED))
+                      | jnp.where(pressured_global, PRESSURED, 0))
         got = jnp.take(status, jnp.maximum(refs, 0), mode="clip")
 
         def says(bit):       # [K, nl]: the ref's muter has `bit` set
             return has & ((got & bit) > 0)
         ref_pressured = says(PRESSURED)
-        local_ok = ref_local & says(RECOVERED)
-        # Remote muting ref: release once this shard's route-spill
-        # drained (the local evidence of congestion is gone;
-        # receiver-side pressure will re-mute via routing if it
-        # persists) — unless the remote receiver still DECLARES
-        # pressure (the all-gathered bits above), which holds the
-        # sender muted exactly as a local pressured ref would.
+        recovered_ref = says(RECOVERED)
+        local_ok = ref_local & recovered_ref
+        # Remote muting ref: released when — and not before — the
+        # receiver has recovered, by the same bit of the same tick as a
+        # local ref (the gathered word), AND this shard's route-spill
+        # has drained (a message parked here for that receiver is
+        # congestion it cannot see yet). A remote receiver that still
+        # DECLARES pressure holds its senders as a local one would
+        # (`recovered` says so too; the bit is asked of the hot word,
+        # which host-declared pressure reaches at once).
         remote_ok = (has & ~ref_local & (st.rspill_count[0] == 0)
                      & ~ref_pressured)
+        if p > 1:       # one chip has no remote ref: its window stays
+            remote_ok = remote_ok & recovered_ref      # what it was
         slot_ok = ~has | local_ok | remote_ok
         all_ok = jnp.all(slot_ok, axis=0)
         # Overflowed ref sets (more distinct muters than slots) defer

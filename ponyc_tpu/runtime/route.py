@@ -137,7 +137,7 @@ def _store_short_plan(plan, key_s, perm_s):
 
 def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
            rspill_cap: int, overload_occ, head, tail, shard_base,
-           mute_slots: int, pressured_anywhere, pressured_global,
+           mute_slots: int, hot_anywhere, hot_global,
            pressured_local, blob=None):
     """Mesh routing: pack entries into per-destination-shard buckets
     (`_route_pack`: one payload-carrying sort, then a contiguous slice a
@@ -145,15 +145,19 @@ def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
     axis (ICI): targets, senders, words.
 
     Returns (received Entries [shards*bucket], new route-spill, spill count,
-    overflow flag, newly muted [n_local], their refs, ref overflow, blob
-    results or None, (entries shipped, those of them off-shard)). Its
-    parts carry the scopes `pony/route/sort`, `/bucket`, `/exchange` and
-    `/spill` (state.STEP_SCOPES).
+    overflow flag, newly muted [n_local], their refs, ref overflow, the
+    senders muted behind another shard's receiver, blob results or None,
+    (entries shipped, those of them off-shard)). Its parts carry the
+    scopes `pony/route/sort`, `/bucket`, `/exchange` and `/spill` with
+    `/spill/lookup` and `/spill/mute` below it (state.STEP_SCOPES).
     Bucket overflow keeps messages on the source shard (route-spill,
-    retried first next step) and mutes the sender — backpressure across
-    the mesh without any receiver-side state (≙ the intent of
-    ponyint_maybe_mute; the occupancy signal here is "the link to that
-    shard is saturated").
+    retried first next step) and mutes the sender — the occupancy signal
+    there is "the link to that shard is saturated"; a sender whose
+    message goes to a receiver that is overloaded or declares pressure,
+    on whatever shard, mutes by the mesh-wide hot word (`_route_spill`:
+    ≙ ponyint_maybe_mute reading the receiver's flags at the send).
+    `head` / `tail` are the rows' at the tick's START, for the senders'
+    exemption.
 
     Blob MIGRATION (`blob` = dict(data, used, len, gen, bbase, bsl,
     shard, mask) when the program routes Blob args on a mesh): a blob
@@ -294,7 +298,7 @@ def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
     with phase_scope("route/spill"):
         # The spill reads the sorted entries only behind this barrier:
         # without it the compiler fuses `maximum(ts, 0)` into the
-        # bucket's slices and `pressured_global[ts]` loses its fast
+        # bucket's slices and the lookup `hot_global[ts]` loses its fast
         # memory, 114 ms for 68 at 8.4M entries (PERF.md §6, PR 41).
         ts, ss, ws, dt = lax.optimization_barrier((ts, ss, ws, dt))
         spilled = _route_spill(
@@ -302,8 +306,7 @@ def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
             n_local=n_local, bucket=bucket, rspill_cap=rspill_cap,
             overload_occ=overload_occ, head=head, tail=tail,
             shard_base=shard_base, mute_slots=mute_slots,
-            pressured_anywhere=pressured_anywhere,
-            pressured_global=pressured_global,
+            hot_anywhere=hot_anywhere, hot_global=hot_global,
             pressured_local=pressured_local)
     received = Entries(tgt=rt, sender=rs, words=rw)
     return (received, *spilled, blob_out, (n_routed, n_remote))
@@ -312,76 +315,115 @@ def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
 def _route_spill(ts, ss, ws, dt, seg_start, over, *, shards: int,
                  n_local: int, bucket: int, rspill_cap: int, overload_occ,
                  head, tail, shard_base, mute_slots: int,
-                 pressured_anywhere, pressured_global, pressured_local):
-    """What did not fit its bucket, and who mutes for it: the sorted
-    entries (`ts`, `ss`, `ws` by destination `dt`), each destination's
-    `seg_start` and overflow `over` → (new route-spill, spill count,
-    overflow flag, newly muted [n_local], their refs, ref overflow)."""
+                 hot_anywhere, hot_global, pressured_local):
+    """What did not fit its bucket, and who mutes for what it sent: the
+    sorted entries (`ts`, `ss`, `ws` by destination `dt`), each
+    destination's `seg_start` and overflow `over` → (new route-spill,
+    spill count, overflow flag, newly muted [n_local], their refs, ref
+    overflow, the senders muted behind a receiver on another shard).
+
+    Backpressure across the mesh (≙ ponyint_maybe_mute, actor.c:898-921:
+    the SENDER reads the receiver's flags on every send, whatever
+    scheduler thread either runs on). A receiver's own shard mutes only
+    the senders that live on it (delivery.deliver, step 5, at the end of
+    the tick that rejected them or left the receiver over its overload
+    line). Every other sender learns it here, from `hot_global`
+    (mute.world): the word of every row of the mesh as THIS tick found
+    it — declares pressure; overloaded: over its overload line, or
+    messages parked for it in its shard's device spill — so a sender on
+    another shard mutes in the first tick in which it sends to a
+    receiver that the tick BEFORE left overloaded, one tick after the
+    receiver's local senders, with the receiver as its muting ref. Its
+    message of this tick still ships. It is released by `mute.
+    unmute_pass` when that receiver has recovered. The bound that
+    follows: a sender that sends one message a dispatch has at most TWO
+    outside a mailbox (one chip: one) — the one the receiver rejected,
+    and the one it sent in the tick that muted it; it then does not run
+    until nothing is parked for that receiver, its own among them.
+    Senders that are themselves overloaded or declare pressure never
+    mute (the reference's exemption)."""
     e = ts.shape[0]
     nrej = jnp.sum(over)
     w1 = ws.shape[0]
-    # Sends whose (possibly remote) target DECLARED pressure: the
-    # cross-shard face of pony_apply_backpressure — every shard sees the
-    # all-gathered pressured bits, so senders mute at routing time, not
-    # only on the receiver's shard (≙ the reference muting any scheduler
-    # that sends to an under-pressure actor). A read by index is paid by
-    # the entry whatever it fetches, so it runs only on a tick whose
-    # world bit 0 (`pressured_anywhere`, mute.world) says the table can
-    # hold a set bit: where the bit is clear `pressured_global` IS zeros,
-    # and so is the answer.
+    # A read by index is paid by the entry whatever it fetches, so the
+    # lookup runs only on a tick whose world bit 0 or 3 (`hot_anywhere`,
+    # mute.world) says the word can hold a set bit: where both are clear
+    # `hot_global` IS zeros, and so is the answer.
     def looked_up(_):
-        hit = (ts >= 0) & jnp.take(
-            pressured_global, jnp.maximum(ts, 0), mode="clip")
-        return hit, jnp.any(hit)
+        with phase_scope("route/spill/lookup"):
+            hit = (ts >= 0) & (jnp.take(
+                hot_global, jnp.maximum(ts, 0), mode="clip") != 0)
+            return hit, jnp.any(hit)
 
     pr_t, any_pr = lax.cond(
-        pressured_anywhere, looked_up,
+        hot_anywhere, looked_up,
         lambda _: (jnp.zeros((e,), jnp.bool_), jnp.bool_(False)),
         operand=None)
 
-    def pressure(_):
-        # Bucket overflow → route spill (stays on this shard, ordered)
-        # + mute the (always local) senders of parked or
-        # pressured-targeted messages.
-        rank = jnp.arange(e, dtype=jnp.int32) - seg_start[
-            jnp.minimum(dt, shards - 1)]
-        rej = (dt < shards) & (rank >= bucket)
+    def empty_spill():
+        return Entries(tgt=jnp.full((rspill_cap,), -1, jnp.int32),
+                       sender=jnp.full((rspill_cap,), -1, jnp.int32),
+                       words=jnp.zeros((w1, rspill_cap), jnp.int32))
+
+    def parked(_):
+        # Bucket overflow → route spill (stays on this shard, ordered).
+        # `dt` is sorted and `shards` is small: a select a destination
+        # finds an entry's segment start, no read by index.
+        start = jnp.zeros((e,), jnp.int32)
+        for d in range(shards):
+            start = jnp.where(dt == d, seg_start[d], start)
+        rej = (dt < shards) & (jnp.arange(e, dtype=jnp.int32) - start
+                               >= bucket)
         perm2, vsp, _ = compact_mask(rej, rspill_cap)
-        spill = Entries(
+        return rej, Entries(
             tgt=jnp.where(vsp, ts[perm2], -1),
             sender=jnp.where(vsp, ss[perm2], -1),
             words=jnp.where(vsp[None, :], ws[:, perm2], 0),
         )
-        lsnd = ss - shard_base
-        s_ok = (rej | pr_t) & (lsnd >= 0) & (lsnd < n_local)
-        sc = jnp.minimum(jnp.maximum(lsnd, 0), n_local - 1)
-        s_hot = (tail[sc] - head[sc]) > (
-            overload_occ if isinstance(overload_occ, int)
-            else overload_occ[sc])
-        # ≙ the reference's !OVERLOADED/UNDER_PRESSURE sender exemption
-        # (actor.c mute rules): a sender that is itself hot or has
-        # itself declared pressure never mutes — prevents two
-        # host-pressured actors that message each other from
-        # mutually muting into a stall.
-        trig = s_ok & ~s_hot & ~pressured_local[sc]
-        mute_row = jnp.where(trig, sc, n_local)
-        newly_muted = jnp.zeros((n_local,), jnp.bool_).at[mute_row].max(
-            trig, mode="drop")
-        refs, ovf = mute_ref_slots(trig, mute_row, ts, n=n_local,
-                                   k=mute_slots)
-        return spill, newly_muted, refs, ovf
+
+    def pressure(_):
+        # Mute the (always local) senders of parked messages and of
+        # messages to a hot receiver. The compaction runs only where a
+        # link overflowed: a tick that is here for a hot receiver alone
+        # — every tick of a skewed world — parks nothing.
+        rej, spill = lax.cond(
+            nrej > 0, parked,
+            lambda _: (jnp.zeros((e,), jnp.bool_), empty_spill()),
+            operand=None)
+        with phase_scope("route/spill/mute"):
+            lsnd = ss - shard_base
+            s_ok = (rej | pr_t) & (lsnd >= 0) & (lsnd < n_local)
+            sc = jnp.minimum(jnp.maximum(lsnd, 0), n_local - 1)
+            # ≙ the reference's !OVERLOADED/UNDER_PRESSURE sender
+            # exemption (actor.c mute rules): a sender that is itself
+            # hot or has itself declared pressure never mutes — prevents
+            # two host-pressured actors that message each other from
+            # mutually muting into a stall. One bit a row, decided over
+            # the rows and read once by the sender index; `head` and
+            # `tail` are the tick's START, as the hot word is: what the
+            # tick before left, which is what its delivery exempted by.
+            exempt = ((tail - head) > overload_occ) | pressured_local
+            trig = s_ok & ~exempt[sc]
+            mute_row = jnp.where(trig, sc, n_local)
+            refs, ovf = mute_ref_slots(trig, mute_row, ts, n=n_local,
+                                       k=mute_slots)
+            # Every trigger wrote its ref (>= 0) into its sender's
+            # column: the table says who was muted, and behind whom.
+            newly_muted = jnp.any(refs >= 0, axis=0)
+            elsewhere = (refs >= 0) & (refs // n_local
+                                       != shard_base // n_local)
+            n_remote = jnp.sum(jnp.any(elsewhere, axis=0).astype(jnp.int32))
+        return spill, newly_muted, refs, ovf, n_remote
 
     def quiet(_):
         refs, ovf = empty_mute_slots(n_local, mute_slots)
-        return (Entries(tgt=jnp.full((rspill_cap,), -1, jnp.int32),
-                        sender=jnp.full((rspill_cap,), -1, jnp.int32),
-                        words=jnp.zeros((w1, rspill_cap), jnp.int32)),
-                jnp.zeros((n_local,), jnp.bool_), refs, ovf)
+        return (empty_spill(), jnp.zeros((n_local,), jnp.bool_), refs, ovf,
+                jnp.int32(0))
 
-    new_rspill, newly_muted, new_refs, new_ovf = lax.cond(
+    new_rspill, newly_muted, new_refs, new_ovf, n_remote = lax.cond(
         (nrej > 0) | any_pr, pressure, quiet, operand=None)
     return (new_rspill, jnp.minimum(nrej, rspill_cap), nrej > rspill_cap,
-            newly_muted, new_refs, new_ovf)
+            newly_muted, new_refs, new_ovf, n_remote)
 
 
 # The static lengths of a shard's lists: the per-destination all_to_all
@@ -392,8 +434,8 @@ ListSizes = namedtuple("ListSizes", "bucket l_in e_short short")
 
 # What steps 3 and 4 leave: delivery.deliver's result; the new route
 # spill, its count, whether it overflowed (fatal); the senders a link
-# muted, [nl], and their refs (nobody on one chip); {counter: this
-# tick's count} for the leaves of RtState.route_counts
+# or a hot receiver muted, [nl], and their refs (nobody on one chip);
+# {counter: this tick's count} for the leaves of RtState.route_counts
 # (state.list_counters); spawn.Pool after migration and the blobs
 # that arrived; the delivery list's targets, >= 0 where valid, for
 # lanes.phase_cost_lanes (read at analysis >= 1 only).
@@ -505,14 +547,14 @@ def deliver_routed(k: TickStatic, st: RtState, w, inject_tgt, inject_words,
                      "shard": w.shard, "mask": k.blob_route[0],
                      "mask_iso": k.blob_route[1]}
         (incoming, new_rspill, rsp_count, rsp_over, route_muted,
-         route_refs, route_ovf, route_blob_out, routed) = _route(
+         route_refs, route_ovf, n_remote_mutes, route_blob_out,
+         routed) = _route(
             out_cat, shards=p, n_local=nl, bucket=bucket,
             rspill_cap=k.s_cap,
             overload_occ=rows_of(k.program, "overload_occ"),
-            head=head, tail=tail0, shard_base=base,
+            head=st.head, tail=st.tail, shard_base=base,
             mute_slots=opts.mute_slots,
-            pressured_anywhere=w.pressured_anywhere,
-            pressured_global=w.pressured_global,
+            hot_anywhere=w.hot_anywhere, hot_global=w.hot_global,
             pressured_local=st.pressured, blob=rblob)
         if route_blob_out is not None:
             cur, n_ship, n_recv, n_drop = route_blob_out
@@ -606,7 +648,8 @@ def deliver_routed(k: TickStatic, st: RtState, w, inject_tgt, inject_words,
                 incoming.tgt])
     counts = {} if routed is None else dict(
         zip(ROUTE_COUNTERS, (*routed, n_unpacked,
-                             w.pressured_anywhere.astype(jnp.int32))))
+                             w.hot_anywhere.astype(jnp.int32),
+                             n_remote_mutes)))
     if res.n_prefix is not None:
         counts["n_prefix"] = res.n_prefix
     return Routed(res, new_rspill, rsp_count, rsp_over, route_muted,

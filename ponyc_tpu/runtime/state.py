@@ -89,11 +89,14 @@ STEP_SCOPES = ("unmute", "spawn", "drain", "dispatch", "dispatch/heap",
                # a mesh only (route._route): the one sort by
                # destination shard that carries the entries, a
                # contiguous masked slice of them a destination, the
-               # all_to_alls, overflow + link mutes; then (deliver_routed)
-               # the received buckets' fills and their joining front to
-               # front for the short delivery list
+               # all_to_alls, overflow + link mutes — below them the
+               # lookup of the sorted targets in the mesh-wide hot word
+               # and the mutes it and a full link trigger —; then
+               # (deliver_routed) the received buckets' fills and their
+               # joining front to front for the short delivery list
                "route/sort", "route/bucket", "route/exchange",
-               "route/spill", "route/unpack",
+               "route/spill", "route/spill/lookup", "route/spill/mute",
+               "route/unpack",
                "delivery", "delivery/plan", "delivery/plan/bounds",
                "delivery/permute", "delivery/rebuild",
                "delivery/rebuild/compact", "delivery/pressure",
@@ -328,8 +331,11 @@ class RtState:
     # shard delivered over the short list (route._route_unpack: what
     # arrived fitted one shard's outbox); "n_route_pressure" [P] int32 —
     # the ticks on which this shard looked its sorted entries' targets
-    # up in the mesh-wide pressured bits (route._route_spill: world bit
-    # 0 was set; 0 on a mesh where nobody declares pressure). Read through
+    # up in the mesh-wide hot word (route._route_spill: world bit 0 or 3
+    # was set; 0 on a mesh where nobody declares pressure and nobody is
+    # overloaded); "n_remote_mutes" [P] int32 — the senders routing
+    # muted behind a receiver on ANOTHER shard (overloaded, under
+    # declared pressure, or at the end of a full link). Read through
     # Runtime.counter(), which sums them over the mesh like n_processed.
     route_counts: Dict[str, jnp.ndarray]
 
@@ -477,7 +483,9 @@ class RtState:
 
     # Mesh-wide world facts from the previous tick's packed vote, stored
     # shard-uniform: bit0 = any pressured, bit1 = any muted, bit2 = any
-    # route-spill entries. They gate the per-tick all_gathers/psums the
+    # route-spill entries, bit3 (a mesh only) = any row overloaded (over
+    # its overload line, or anything parked in a receiver spill). They
+    # gate the per-tick all_gathers/psums the
     # backpressure machinery needs only when those states exist — a quiet
     # mesh tick runs collective-free except routing + one vote
     # (≙ idle costing ~nothing, the fork's README.md:8-10 thesis).
@@ -491,7 +499,7 @@ class RtState:
 # The route's counters (RtState.route_counts), a mesh only; and every
 # name that dict may hold (list_counters: which of them a program has).
 ROUTE_COUNTERS = ("n_routed", "n_routed_remote", "n_unpacked",
-                  "n_route_pressure")
+                  "n_route_pressure", "n_remote_mutes")
 LIST_COUNTERS = ROUTE_COUNTERS + ("n_prefix",)
 
 

@@ -253,6 +253,7 @@ _ZERO_IF_ABSENT = frozenset({"st.phase_cost", "st.route_counts.n_routed",
                              "st.route_counts.n_routed_remote",
                              "st.route_counts.n_unpacked",
                              "st.route_counts.n_route_pressure",
+                             "st.route_counts.n_remote_mutes",
                              "st.route_counts.n_prefix"})
 
 
@@ -955,9 +956,14 @@ def _restore_relayout(rt, header, Z: Dict[str, np.ndarray]) -> None:
             st[name] = dst
 
     # world facts for the first restored tick: recompute from the
-    # restored columns (route spill is empty by construction).
+    # restored columns (both spills are empty by construction: their
+    # entries ride the inject queue). Bit 3, a mesh's "someone is
+    # overloaded", errs towards set: the first tick's vote has it exact.
+    line = min(c.overload_occ for c in prog.cohorts)
     bits = (1 * bool(st["pressured"].any())
-            | 2 * bool(st["muted"].any()))
+            | 2 * bool(st["muted"].any())
+            | 8 * (prog.shards > 1
+                   and bool(((st["tail"] - st["head"]) > line).any())))
     st["world_bits"] = np.full_like(st["world_bits"], bits)
 
     # ---- type_state scatter (+ ref/blob field value remap) ----

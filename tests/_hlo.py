@@ -162,15 +162,16 @@ def v5e_counts(snippet: str, length: int = 0) -> dict:
 
 # ------------------------------------------------------------ the dump
 
-def _bench_rt(config: str, traffic: str, actors: int, **over):
+def _bench_rt(config: str, traffic: str, actors: int, cfg_over=None,
+              **over):
     """The Runtime of a benchmark world (`benchmarks/worlds/*.py`) at
     `actors`; `over` replaces runtime options, `count` the spreader's
-    tree depth."""
+    tree depth, `cfg_over` other keys of the configuration."""
     def load(*parts):
         with open(os.path.join(ROOT, "benchmarks", *parts)) as f:
             return json.load(f)
     cfg = load("configs", config + ".json")
-    cfg["actors"] = actors
+    cfg.update(cfg_over or {}, actors=actors)
     if "count" in over:
         cfg["count"] = over.pop("count")
     cfg["runtime_options"] = {**cfg["runtime_options"], **over}
@@ -198,7 +199,7 @@ def _blobs_over_a_mesh():
         blob_slots=64, blob_words=records.W, mesh_shards=2))[0]
 
 
-# name -> () -> Runtime: the six configurations' worlds, small, and the
+# name -> () -> Runtime: the configurations' worlds, small, and the
 # formulations no cell runs.
 WINDOWS = {
     "ubench": lambda: _bench_rt("ubench-1m", "cycle", 2048),
@@ -206,7 +207,11 @@ WINDOWS = {
     "fanin": lambda: _bench_rt("fanin-zipf", "steady", 2048),
     "gups": lambda: _bench_rt("gups-hpcc", "stream", 512),
     "spreader": lambda: _bench_rt("spreader-forest", "churn", 4096, count=6),
+    "bank": lambda: _bench_rt(
+        "savina-bank", "transfers", 2002,
+        cfg_over={"teller_batch": 12, "teller_mailbox_cap": 32}),
     "mesh4": lambda: _bench_rt("ubench-4m-mesh4", "remote", 2048),
+    "fanin-mesh4": lambda: _bench_rt("fanin-zipf-mesh4", "crossing", 2048),
     "ubench-analysis1": lambda: _bench_rt("ubench-1m", "cycle", 2048,
                                           analysis=1),
     "ubench-analysis3-tracing": lambda: _bench_rt(

@@ -49,12 +49,24 @@ def test_phase_mesh_spreads_state_over_four_devices():
 def test_phase_mesh_ubench_takes_the_short_delivery_list(capsys):
     """Phase (e)'s ubench: cycle traffic at the program's own bucket.
     The phase itself checks that every shard of every tick delivered
-    over the short list (`n_unpacked`) and that none looked up pressure
-    (`n_route_pressure`)."""
+    over the short list (`n_unpacked`) and that the lookups in the hot
+    word (`n_route_pressure`: this geometry keeps every mailbox over its
+    overload line) muted nobody."""
     chip_smoke.phase_ubench(256, 8, mesh_shards=4)
     out = capsys.readouterr().out
     assert "took the short delivery list: ok" in out
-    assert "no tick looked up pressure: ok" in out
+    assert "looked the hot word up: ok" in out
+    assert "and nobody was muted: ok" in out
+
+
+def test_phase_mesh_fanin_mutes_across_shards_and_ends_clean(capsys):
+    """Phase (e)'s fan-in under skew: 200 ticks mid-pressure with
+    senders muted behind aggregators of other shards, conserved, then
+    quiescent with every item counted and nobody muted."""
+    chip_smoke.phase_fanin_mesh(64, 8, 3, mesh_shards=4)
+    out = capsys.readouterr().out
+    assert "senders on other shards were muted: ok" in out
+    assert "nobody left muted: ok" in out
 
 
 def test_a_failed_check_raises_and_entry_point_refuses_cpu(capsys):

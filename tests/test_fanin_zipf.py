@@ -6,9 +6,11 @@ The deployment lives in the backpressure chain: reject -> spill ->
 mute -> unmute (`delivery.py`'s pressure branch, `mute.py`'s unmute
 pass). One shard is held to the protocol tick by tick, on every actor;
 conservation (nothing lost, nothing duplicated) is checked on the way.
-A mesh mutes differently — a receiver's rejection mutes only the
-senders resident on its shard — so it is held to conservation and to
-exactly-once at quiescence, not to the one-shard protocol.
+A mesh mutes differently — a receiver's rejection mutes the senders
+resident on its shard at once and the others a tick later, at routing
+— so here it is held to conservation and to exactly-once at quiescence;
+`tests/test_fanin_mesh.py` holds it to the protocol with the layout in
+it (`benchmarks/reference_fanin_mesh.py`), tick by tick.
 """
 
 import contextlib
@@ -130,8 +132,9 @@ def test_one_aggregator_under_every_producer(delivery):
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_mesh_conserves_and_counts_every_item_once(seed):
-    """Finite producers (`hops` items each: remote senders are not muted
-    by a receiver, so an endless fan-in would outgrow any spill)."""
+    """Finite producers (`hops` items each) on `worlds/fanin.py` as it
+    is: its spill is sized for one item a producer, a mesh needs two
+    (`worlds/fanin_mesh.py` sizes an endless one)."""
     hops = 3
     world = _world(2048, seed, mesh_shards=4, traffic={"hops": hops})
     rt = world.rt

@@ -192,9 +192,10 @@ def test_programmatic_backpressure_on_mesh():
 
 # --- the pressure lookup runs only behind world bit 0 -------------------
 # `route._route_spill` looks its sorted entries' targets up in the
-# mesh-wide pressured bits only on a tick whose world bit 0
-# (`mute.world`'s `pressured_anywhere`) is set; on every other tick the
-# table is zeros and so is the answer. Held against the world in which
+# mesh-wide hot word only on a tick whose world bit 0 or 3
+# (`mute.world`'s `hot_anywhere`: someone declares pressure or is
+# overloaded; nobody is overloaded in this world) is set; on every other
+# tick the table is zeros and so is the answer. Held against the world in which
 # the bit always reads set: the lookup on every tick, as it was.
 
 SOURCES, ITEMS = 16, 12
@@ -220,7 +221,7 @@ def _declared_twice(shards, always_look_up):
     ("remote"), then with four that all do ("local"). Four senders of
     one item a tick are what the sink drains (batch 4), so its mailbox
     never nears the overload line. With `always_look_up`, `mute.world`'s
-    bit 0 always reads set: `_route_spill` gathers on every tick.
+    `hot_anywhere` always reads set: `_route_spill` gathers on every tick.
     Returns per tick (world bit 0 as the tick found it, every state
     leaf after it) and what the tests ask of the world."""
     opts = RuntimeOptions(mailbox_cap=64, batch=4, max_sends=2,
@@ -230,7 +231,7 @@ def _declared_twice(shards, always_look_up):
         if always_look_up:
             real = mute.world
             patched.setattr(mute, "world", lambda k, st: real(
-                k, st)._replace(pressured_anywhere=jnp.bool_(True)))
+                k, st)._replace(hot_anywhere=jnp.bool_(True)))
         rt, sink, srcs = _run_pressure(opts, SOURCES, ITEMS, go=False)
         sink, n_local = int(sink), rt.program.n_local
         seen = []
@@ -298,11 +299,13 @@ def test_the_gated_lookup_is_the_lookup_on_every_tick(shards, where):
     assert forced["looked_up"] == shards * len(want)
 
 
+@pytest.mark.parametrize("leaf", ["n_route_pressure", "n_remote_mutes"])
 @pytest.mark.parametrize("target", ["same-layout", "relayout"])
 def test_a_snapshot_from_before_the_counter_restores_with_it_at_zero(
-        tmp_path, target):
-    """`n_route_pressure` is a state leaf newer than snapshots in the
-    wild: one without it restores, the other route counters carried."""
+        tmp_path, target, leaf):
+    """`n_route_pressure` (PR 46) and `n_remote_mutes` (PR 47) are state
+    leaves newer than snapshots in the wild: one without the leaf
+    restores, the other route counters carried."""
     from ponyc_tpu import serialise
 
     opts = RuntimeOptions(mailbox_cap=8, batch=2, max_sends=2, msg_words=2,
@@ -310,9 +313,9 @@ def test_a_snapshot_from_before_the_counter_restores_with_it_at_zero(
     rt, sink, _srcs = _run_pressure(opts, n_src=8, items=4)
     rt.apply_backpressure([int(sink)])
     rt.run(max_steps=3)
-    assert rt.counter("n_route_pressure") > 0
+    assert rt.counter(leaf) > 0
     header, arrays = serialise.capture(rt)
-    del arrays["st.route_counts.n_route_pressure"]
+    del arrays[f"st.route_counts.{leaf}"]
     path = str(tmp_path / "older.npz")
     serialise.write_snapshot(header, arrays, path)
 
@@ -322,7 +325,40 @@ def test_a_snapshot_from_before_the_counter_restores_with_it_at_zero(
     rt2.declare(Burst, 8).declare(Sink, 4)
     rt2.start()
     serialise.restore(rt2, path)
-    assert rt2.counter("n_route_pressure") == 0
+    assert rt2.counter(leaf) == 0
     assert rt2.counter("n_routed") == rt.counter("n_routed") > 0
     rt.stop()
     rt2.stop()
+
+
+def test_an_overloaded_receiver_mutes_senders_on_other_shards():
+    """No declared pressure, no full link: the sink's mailbox (cap 4)
+    overflows, its own shard mutes the senders it holds in that tick,
+    and the senders on the other shards are muted at routing in the
+    next tick they send, by the hot word, with the sink as their ref —
+    and everyone is released once the sink has drained."""
+    opts = RuntimeOptions(mailbox_cap=4, batch=1, max_sends=2, msg_words=2,
+                          mesh_shards=4, spill_cap=2048, inject_slots=64,
+                          quiesce_interval=1)
+    rt, sink, srcs = _run_pressure(opts, n_src=32, items=6)
+    sink, nl = int(sink), rt.program.n_local
+    far = np.asarray([int(s) for s in srcs if int(s) // nl != sink // nl])
+    saw = 0
+    for _ in range(12):
+        rt.run(max_steps=1)
+        muted = np.asarray(rt.state.muted)[far]
+        refs = np.asarray(rt.state.mute_refs)[:, far]
+        assert ((refs == sink).any(axis=0) == muted).all()
+        saw = max(saw, int(muted.sum()))
+    assert saw > len(far) // 2 and rt.counter("n_remote_mutes") >= saw
+    assert rt.counter("rspill_count") == 0          # no link was full
+    # (some ticks before the sink's overload there is nothing to look up)
+    assert 0 < rt.counter("n_route_pressure") <= 4 * rt.steps_run
+    assert rt.run(max_steps=2000) == 0
+    assert rt.state_of(sink)["got"] == 32 * 6
+    assert not np.asarray(rt.state.muted).any()
+    # the tick after nobody is overloaded any more looks nothing up
+    looked = rt.counter("n_route_pressure")
+    rt.run(max_steps=4)
+    assert rt.counter("n_route_pressure") == looked
+    rt.stop()

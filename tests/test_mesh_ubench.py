@@ -32,7 +32,8 @@ from test_run_loop import recording  # noqa: F401  (a fixture)
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ACTORS, TICKS = 1024, 12
 ROUTE_SCOPES = ("route/sort", "route/bucket", "route/exchange",
-                "route/spill", "route/unpack")
+                "route/spill", "route/spill/lookup", "route/spill/mute",
+                "route/unpack")
 
 
 def _world(shards, recipients, seed=7, actors=ACTORS, **options):
@@ -83,10 +84,11 @@ def test_every_actor_follows_the_reference_on_any_layout(shards, recipients):
         # (nothing is routed, and nothing counted, on one shard)
         # and at the program's own bucket every shard of every tick
         # delivers over what arrived, the short list (`n_unpacked`);
-        # nobody declares pressure, so no shard of any tick looks its
-        # entries' targets up (`n_route_pressure`)
+        # nobody declares pressure and nobody is overloaded, so no
+        # shard of any tick looks its entries' targets up
+        # (`n_route_pressure`) and nobody mutes (`n_remote_mutes`)
         expect = (int(sent[:tick + 1].sum()), int(remote[:tick + 1].sum()),
-                  shards * (tick + 1), 0) if shards > 1 else (0, 0, 0, 0)
+                  shards * (tick + 1), 0, 0) if shards > 1 else (0,) * 5
         assert routed == expect, (tick, routed, expect)
         assert spilled == 0, tick
     assert not any(errors.values()), errors

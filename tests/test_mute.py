@@ -250,19 +250,25 @@ def test_aging_breaks_cross_shard_mute_cycle():
     assert got == 2 * (2 ** 9 - 1), got
 
 
-def test_cross_shard_cycle_self_heals_without_aging():
-    """Unlike the single-shard cycle (which freezes,
-    test_mute_age_limit_zero_disables_aging), the CROSS-shard cycle
-    self-heals even with aging disabled: the remote-ref release path
-    (mute.py remote_ok — release once the local route spill drains)
-    periodically frees each side, so the pair grinds to completion.
-    Pinning this down documents that aging is only load-bearing for
-    same-shard cycles."""
+def test_cross_shard_cycle_freezes_without_aging_as_on_one_shard():
+    """Like the single-shard cycle
+    (test_mute_age_limit_zero_disables_aging), the CROSS-shard cycle
+    freezes with aging disabled: a remote muting ref releases when — and
+    not before — its receiver has recovered (mute.py remote_ok reads the
+    gathered `recovered` bit), and each side's receiver is the other,
+    full and muted. Until the mute crossed shards properly the remote
+    ref was released whenever the local route spill had drained, and
+    the pair ground on: that leak is what let a remote producer keep
+    sending into an overloaded receiver (the meshed fan-in's spill
+    overflow). Aging is what breaks the cycle, on one shard and across
+    (test_aging_breaks_cross_shard_mute_cycle)."""
     rt, a, b = _flood_pair_mesh(mute_age_limit=0)
-    rt.run(max_steps=20_000)
+    got0 = int(np.asarray(rt.state.type_state["Flooder"]["got"]).sum())
+    rt.run(max_steps=400)
+    assert np.asarray(rt.state.muted).all(), \
+        "cross-shard pair released with aging disabled"
     got = int(np.asarray(rt.state.type_state["Flooder"]["got"]).sum())
-    assert got == 2 * (2 ** 9 - 1), got
-    assert not np.asarray(rt.state.muted).any()
+    assert got == got0 < 2 * (2 ** 9 - 1), (got0, got)
 
 
 def test_aged_release_waits_for_live_congested_muter():
@@ -379,11 +385,9 @@ def test_aged_release_waits_cross_shard():
                     f"sender {g} released past live local muter(s)")
                 # With a remote ref and a non-empty local route spill,
                 # neither remote_ok (spill not drained) nor aging (the
-                # has_remote hold) may release. With the spill drained,
-                # remote_ok releases even into a still-congested remote
-                # receiver — the documented divergence (mute.py
-                # remote_ok comment: routing re-mutes if it persists) —
-                # so that case is allowed.
+                # has_remote hold) may release. (With the spill drained,
+                # remote_ok asks the remote receiver's own `recovered`
+                # bit, as a local ref would: tests/test_fanin_mesh.py.)
                 if len(remote) and prev["rspill"][g // nl] > 0:
                     raise AssertionError(
                         f"sender {g} released while its shard's route "
@@ -546,11 +550,14 @@ def test_unmute_pass_against_its_predicates_one_by_one(muter, shards,
     has = refs >= 0
     at = np.maximum(refs, 0)
     ref_local = shard[at] == shard[None, :]
-    local_ok = (has & ref_local & (occ[at] <= opts.unmute_occ)
-                & (parked[at] == 0) & ~pressured[at])
+    recovered = ((occ[at] <= opts.unmute_occ) & (parked[at] == 0)
+                 & ~pressured[at])
+    local_ok = has & ref_local & recovered
     remote_pressured = has & ~ref_local & pressured[at]
-    remote_ok = (has & ~ref_local & (in_route[shard] == 0)[None, :]
-                 & ~remote_pressured)
+    # a remote ref is asked the same bit, and waits for the local route
+    # spill besides
+    remote_ok = (has & ~ref_local & recovered
+                 & (in_route[shard] == 0)[None, :] & ~remote_pressured)
     can_recover = alive & ~muted
     live_congested = ((occ > opts.unmute_occ) | (parked > 0)) & can_recover
     held_by_pressure = (has & pressured[at]).any(axis=0)
@@ -565,14 +572,14 @@ def test_unmute_pass_against_its_predicates_one_by_one(muter, shards,
     aged_ok = aged & ~held_by_pressure & ~held_by_live \
         & (~ovf | (not pressured.any()))
     release = muted & ((all_ok & (~ovf | shard_quiet)) | aged_ok)
-    # the case is the one its name says: the plain sender waits for a
-    # muter of its shard while any of the three holds it, for a remote
-    # one only while that declares pressure — or behind a backlog
+    # the case is the one its name says: the plain sender waits for its
+    # muter while any of the three holds it, on the muter's shard and on
+    # another alike — and a remote one behind a backlog besides
     if sender == "local-ref":
-        assert release[SENDER] == (not (muter["over"] or muter["parked"]
-                                        or muter["declared"]))
+        waits = muter["over"] or muter["parked"] or muter["declared"]
+        assert release[SENDER] == (not waits)
         if shards > 1:
-            assert release[FAR_SENDER] == (not muter["declared"])
+            assert release[FAR_SENDER] == (not waits)
     if sender == "backlog":
         assert not release[FAR_SENDER]
 

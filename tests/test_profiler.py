@@ -587,7 +587,8 @@ def test_phase_scopes_name_the_lowered_window(delivery, cap):
     elsewhere = ("gc_mark", "dispatch/heap", "spawn/free", "spawn/reserve",
                  "spawn/claim", "gc_mark/roots", "gc_mark/hop",
                  "gc_mark/sweep", "route/sort", "route/bucket",
-                 "route/exchange", "route/spill", "route/unpack") + (
+                 "route/exchange", "route/spill", "route/spill/lookup",
+                 "route/spill/mute", "route/unpack") + (
                      () if cap > 8 else ("delivery/rebuild/compact",))
     missing = [s for s in STEP_SCOPES if s not in elsewhere
                and f"{SCOPE_PREFIX}/{s}/" not in text]

@@ -125,15 +125,15 @@ def test_overflow_reaches_the_route_spill_unchanged(w1):
     (dt, ts, ss, ws), (seg_start, cnt, acc), _ = got
     assert int(np.max(np.asarray(cnt) - np.asarray(acc))) > 0
     n = SHARDS * N_LOCAL
-    spill, count, over, muted, _refs, _ovf = jax.jit(functools.partial(
+    spill, count, over, muted, _refs, _ovf, _remote = jax.jit(functools.partial(
         route._route_spill, shards=SHARDS, n_local=N_LOCAL, bucket=bucket,
         rspill_cap=cap, overload_occ=48, shard_base=jnp.int32(0),
         mute_slots=4))(
         ts, ss, ws, dt, seg_start, cnt - acc,
         head=jnp.zeros((N_LOCAL,), jnp.int32),
         tail=jnp.zeros((N_LOCAL,), jnp.int32),
-        pressured_anywhere=jnp.bool_(False),
-        pressured_global=jnp.zeros((n,), jnp.bool_),
+        hot_anywhere=jnp.bool_(False),
+        hot_global=jnp.zeros((n,), jnp.int8),
         pressured_local=jnp.zeros((N_LOCAL,), jnp.bool_))
     w_tgt, w_sender, w_words, w_count, w_over, rejected = reference_spill(
         want, SHARDS, bucket, cap)
@@ -210,8 +210,8 @@ def test_for_the_chip_the_pad_folds_into_the_slices(w1):
 
 # `_route_spill` alone, compiled for the described v5e the same way: what
 # a quiet tick pays for it. The lookup of the sorted entries' targets in
-# the mesh-wide pressured bits sits behind world bit 0
-# (`pressured_anywhere`), the overflow and the mutes behind the
+# the mesh-wide hot word sits behind world bits 0 and 3
+# (`hot_anywhere`), the overflow and the mutes behind the
 # conditional they always had.
 SPILL_FOR_THE_CHIP = """
 import re
@@ -220,18 +220,18 @@ import _hlo
 from ponyc_tpu.runtime import route
 from ponyc_tpu.runtime.state import phase_scope
 e, shards, n = {e}, 4, {n}
-flag = lambda *shape: jax.ShapeDtypeStruct(
-    shape, jnp.bool_, sharding=SingleDeviceSharding(device))
+flag = lambda *shape, dtype=jnp.bool_: jax.ShapeDtypeStruct(
+    shape, dtype, sharding=SingleDeviceSharding(device))
 def fn(ts, ss, ws, dt, seg_start, over, head, tail, anywhere, everyone, mine):
     with phase_scope("route/spill"):
         return route._route_spill(
             ts, ss, ws, dt, seg_start, over, shards=shards, n_local=n,
             bucket=e, rspill_cap=4096, overload_occ=48, head=head, tail=tail,
             shard_base=jnp.int32(0), mute_slots=4,
-            pressured_anywhere=anywhere, pressured_global=everyone,
+            hot_anywhere=anywhere, hot_global=everyone,
             pressured_local=mine)
 args = (arg(e), arg(e), arg(2, e), arg(e), arg(shards), arg(shards), arg(n),
-        arg(n), flag(), flag(shards * n), flag(n))
+        arg(n), flag(), flag(shards * n, dtype=jnp.int8), flag(n))
 def report(text):
     entry = text[text.index("\\nENTRY "):].split("\\n}}")[0].splitlines()[2:]
     wide = []
