@@ -453,7 +453,9 @@ class Analysis:
                 if cinf is not None:
                     extra = (f" qw_p50={cinf['queue_wait_p50']}"
                              f" qw_p99={cinf['queue_wait_p99']}"
-                             f" mute_ticks={cinf['mute_ticks']}")
+                             f" mute_ticks={cinf['mute_ticks']}"
+                             f" pinned_handles="
+                             f"{','.join(cinf['pinned_handles']) or '-'}")
                 lines.append(
                     f"  cohort {cohort.atype.__name__}: "
                     f"cap={cohort.capacity} queued={int(co.sum())} "

@@ -326,16 +326,31 @@ class BlobPoolView:
     exact — no cross-branch selects, and reads observe this dispatch's
     own earlier writes (read-your-writes).
 
+    A handle is checked — `local`: its slot and its generation against
+    the slot's; `live`: the slot's used flag — where an op is given it,
+    unless the engine checked it ONCE for the whole cohort dispatch
+    (`resolved`; engine._cohort_dispatch, `pinned`): a Blob state field
+    that every behaviour of the cohort hands back as the very tracer it
+    came in as, in a cohort none of whose behaviours calls blob_alloc or
+    blob_free. That is exact: the field holds one value in every batch
+    slot (returned by identity), `gen` and `used` are written by alloc
+    and free alone (cohorts run one after another, so nothing else
+    writes them while this cohort's scan runs), and an iso has one owner
+    — the same `h`, `gen`, `used` give the same answer in slot 7 as in
+    slot 0, for stale, null, remote and forged handles too. A handle
+    that is any other object (a message argument, a computed value, a
+    field some behaviour overwrites) is checked where it is used.
+
     ≙ the reference's actor heap + pony_alloc_msg payloads
     (pony.h:332-360): alloc on the owning actor, move by message."""
 
     __slots__ = ("data", "used", "len_", "gen", "base", "nslots", "words",
                  "take",
-                 "resv", "claims", "fail", "budget_fail", "n_alloc",
-                 "n_free", "n_remote", "alloced", "budget_over")
+                 "resv", "claims", "frees", "fail", "budget_fail", "n_alloc",
+                 "n_free", "n_remote", "alloced", "budget_over", "resolved")
 
     def __init__(self, data, used, len_, gen, base, take, resv,
-                 budget_over=None):
+                 budget_over=None, resolved=None):
         self.data = data            # [W*B] i32, word-major (working copy)
         self.used = used            # [B] bool
         self.len_ = len_            # [B] i32
@@ -346,6 +361,9 @@ class BlobPoolView:
         self.take = take            # [lanes] bool
         self.resv = resv            # [sites, lanes] i32 handles, or None
         self.claims = 0             # trace-time alloc-site counter
+        self.frees = 0              # trace-time free-site counter
+        self.resolved = resolved    # pack.RefTypes or None: handle tracer
+        #   -> (slot, ok, used) checked once, before the batch scan
         self.fail = jnp.bool_(False)     # sticky: wanted a slot, pool empty
         self.budget_fail = jnp.bool_(False)  # sticky: wanted a slot but
         #   the dispatch was past its BLOB_DISPATCHES reservation budget
@@ -368,12 +386,31 @@ class BlobPoolView:
         mode="drop"/"fill", so -1 would silently address the last slot;
         an out-of-range-high index is what those modes actually
         drop/fill."""
+        got = self._checked(h)
+        if got:
+            return got[0], got[1]
+        h = jnp.asarray(h, jnp.int32)
         hl = pack.blob_slot(h) - self.base
         ok = (h >= 0) & (hl >= 0) & (hl < self.nslots)
         hs = jnp.where(ok, hl, self.nslots)
         ok = ok & (jnp.take(self.gen, hs, mode="fill", fill_value=-1)
                    == pack.blob_gen_of(h))
         return jnp.where(ok, hl, self.nslots), ok
+
+    def live(self, h, hl):
+        """Is slot `hl` (of handle `h`, from `local`) allocated: a read
+        of a freed, stale or forged slot yields 0 and a write to it is
+        dropped, never another blob's words."""
+        got = self._checked(h)
+        if got:
+            return got[2]
+        return jnp.take(self.used, hl, mode="fill", fill_value=False)
+
+    def _checked(self, h):
+        """(slot, ok, used) of a handle checked before the scan, found
+        by identity (before jnp.asarray can hand back another object);
+        None for any other."""
+        return self.resolved and self.resolved.lookup(h)
 
     def at(self, word, slot, ok):
         """Flat index of (word, local slot) where `ok`, else one past
@@ -433,7 +470,8 @@ class Context:
                  "spawn_claims", "destroy_called", "error_flag",
                  "error_code", "error_loc", "error_called", "ref_types",
                  "_spawn_meta", "sync_inits", "_effected", "cap_moves",
-                 "cap_types", "exit_called", "yield_called", "_blob")
+                 "cap_types", "exit_called", "yield_called", "_blob",
+                 "kept")
 
     def __init__(self, actor_id, msg_words: int, spawn_resv=None,
                  spawn_meta=None, blob=None):
@@ -473,6 +511,9 @@ class Context:
         self._effected = False    # trace-time: any exit()/yield_() call
         # Device blob pool view (None = pool disabled or host dispatch).
         self._blob: Optional[BlobPoolView] = blob
+        # Trace-time: the state fields the behaviour handed back as the
+        # very objects it was given (engine.eval_behaviour fills it).
+        self.kept: frozenset = frozenset()
 
     # -- messaging (≙ pony_sendv, actor.c:773-834) --
     def _send_checks(self, target, behaviour_def: BehaviourDef, args):
@@ -836,11 +877,8 @@ class Context:
         ``ctx.blob_get(h, i).view(jnp.float32)``."""
         b = self._require_blob("blob_get")
         self._blob_guard(h, "blob_get")
-        h = jnp.asarray(h, jnp.int32)
         hl, ok = b.local(h)
-        # Reads of unallocated (freed/stale/forged) slots yield 0, not
-        # another blob's leftover words — the same used-gate writes have.
-        ok = ok & jnp.take(b.used, hl, mode="fill", fill_value=False)
+        ok = ok & b.live(h, hl)
         i = jnp.asarray(i, jnp.int32)
         ok = ok & (i >= 0) & (i < b.words)
         return jnp.take(b.data, b.at(i, hl, ok), mode="fill", fill_value=0)
@@ -851,7 +889,6 @@ class Context:
         null/remote handles)."""
         b = self._require_blob("blob_length")
         self._blob_guard(h, "blob_length")
-        h = jnp.asarray(h, jnp.int32)
         hl, _ok = b.local(h)
         return jnp.take(b.len_, hl, mode="fill", fill_value=0)
 
@@ -890,12 +927,10 @@ class Context:
                 "capability: blob_set on a frozen (val) blob — "
                 "shared-immutable payloads cannot be written "
                 "(≙ val's deny-write, type/cap.c)")
-        h = jnp.asarray(h, jnp.int32)
         hl, okh = b.local(h)
         i = jnp.asarray(i, jnp.int32)
         ok = (jnp.asarray(when, jnp.bool_) & b.take & okh
-              & (i >= 0) & (i < b.words)
-              & jnp.take(b.used, hl, mode="fill", fill_value=False))
+              & (i >= 0) & (i < b.words) & b.live(h, hl))
         v = jnp.broadcast_to(jnp.asarray(v, jnp.int32), ok.shape)
         key, v = b.ordered(i, hl, ok, v)
         b.data = lax.scatter(
@@ -918,9 +953,9 @@ class Context:
                 "mark pass reclaims unreferenced val blobs")
         h = jnp.asarray(h, jnp.int32)
         hl, okh = b.local(h)
-        ok = (jnp.asarray(when, jnp.bool_) & b.take & okh
-              & jnp.take(b.used, hl, mode="fill", fill_value=False))
+        ok = jnp.asarray(when, jnp.bool_) & b.take & okh & b.live(h, hl)
         idx = jnp.where(ok, hl, b.nslots)           # OOB-high → dropped
+        b.frees += 1
         b.used = b.used.at[idx].set(False, mode="drop")
         b.len_ = b.len_.at[idx].set(0, mode="drop")
         b.n_free = b.n_free + jnp.sum(ok.astype(jnp.int32))

@@ -33,6 +33,7 @@ jit) and meshed execution (shard_map over an 'actors' axis); per-shard
 
 from __future__ import annotations
 
+import functools
 from collections import namedtuple
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
@@ -40,7 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..api import Context
+from ..api import BlobPoolView, Context
 from ..config import RuntimeOptions
 from ..ops import pack
 from ..program import Cohort, Program
@@ -185,6 +186,7 @@ def eval_behaviour(bdef, st, payload, ids_vec, *, msg_words: int,
         raise TypeError(
             f"behaviour {bdef} changed the state fields: "
             f"{sorted(st2)} vs {sorted(st)}")
+    ctx.kept = frozenset(k for k, v in st2.items() if v is st[k])
     for k, v in st2.items():
         want = pack.ref_target(field_specs[k])
         got = ctx.ref_types.lookup(v)
@@ -298,23 +300,31 @@ def _make_branch(bdef, msg_words: int, max_sends: int, field_dtypes,
     spawn_sites: ordered (target_name, n_sites) static budget — every
     branch emits claims in this exact layout. effects: trace-time mutable
     record of which effects any behaviour of the cohort used (lets the
-    engine skip dead scatters)."""
+    engine skip dead scatters; for a pool-using cohort also which state
+    fields every behaviour handed back untouched, `kept`, and whether
+    any allocates or frees, `alloc_free` — what `pinned` is decided
+    from, _cohort_dispatch). pinned: {Blob field: (slot, ok, used)}
+    checked before the batch scan; the view finds them by the field's
+    tracer."""
     w1 = 1 + msg_words
 
-    def branch(st, payload, ids_vec, resv_k, blob_in=None, take=None):
+    def branch(st, payload, ids_vec, resv_k, blob_in=None, take=None,
+               pinned=None):
         bv = None
         if blob_in is not None:
             # (pool arrays threaded sequentially through the branches —
             # see api.BlobPoolView for why no cross-branch select is
             # needed; resv row may be zero-sites for receive-only types.)
-            from ..api import BlobPoolView
             bdata, bused, blen, bgen, bbase, bresv, bover = blob_in
+            resolved = pack.RefTypes() if pinned else None
+            for f, checked in (pinned or {}).items():
+                resolved.tag(st[f], checked)
             bv = BlobPoolView(bdata, bused, blen, bgen, bbase,
                               (take if take is not None
                                else jnp.ones((lanes,), jnp.bool_)),
                               bresv if (bresv is not None
                                         and bresv.shape[0]) else None,
-                              budget_over=bover)
+                              budget_over=bover, resolved=resolved)
         ctx, st2, tgts, words = eval_behaviour(
             bdef, st, payload, ids_vec, msg_words=msg_words,
             field_specs=field_specs, field_dtypes=field_dtypes,
@@ -324,6 +334,15 @@ def _make_branch(bdef, msg_words: int, max_sends: int, field_dtypes,
         effects["error"] = effects["error"] or ctx.error_called
         effects["sync_init"] = (effects["sync_init"]
                                 or bool(ctx.sync_inits))
+        if bv is not None:
+            alloc_free = bool(bv.claims or bv.frees)
+            if pinned and (alloc_free or not set(pinned) <= ctx.kept):
+                raise RuntimeError(
+                    f"behaviour {bdef} allocates, frees or overwrites a "
+                    f"handle its cohort checks once ({sorted(pinned)}): "
+                    "the probe and the trace disagree (engine wiring)")
+            effects["alloc_free"] = effects["alloc_free"] or alloc_free
+            effects["kept"] &= ctx.kept
         claims = []
         inits = []
         for tname, n in spawn_sites:
@@ -383,7 +402,8 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
     dispatch each, honour yield (fork: actor.c:675-679), count
     consumption — for every actor of the cohort at once, as [rows]-wide
     vector ops (actors on the 128 TPU lanes, batch slots iterated by a
-    lax.scan whose carries are all lane-shaped).
+    lax.scan whose carries are lane-shaped, all but a pool-using
+    cohort's: the pool's [W*B] words ride the carry too).
     """
     msg_words = opts.msg_words          # OUTBOX width (program-wide max)
     ms = cohort.max_sends
@@ -404,7 +424,9 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
     # construction (Context.spawn_sync).
     spawn_meta = {t: program.by_type_name(t).atype.field_specs
                   for t, _ in spawn_sites}
-    effects = {"destroy": False, "error": False, "sync_init": False}
+    effects = {"destroy": False, "error": False, "sync_init": False,
+               "alloc_free": False, "pinned": None,
+               "kept": frozenset(cohort.atype.field_specs)}
     # Device blob pool (≙ actor-heap message payloads; see ops.pack.Blob):
     # a cohort that allocates (MAX_BLOBS) or receives/holds Blob handles
     # threads the pool arrays through its dispatch; everything else keeps
@@ -428,6 +450,44 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
     base = cohort.behaviours[0].global_id if nb else 0
     sd = cohort.spawn_dispatches
     fused = None
+    sds = jax.ShapeDtypeStruct
+
+    def probe(*pool):
+        """One abstract trace of every branch: `effects` is known
+        before anything is built from it."""
+        for br in branches:
+            jax.eval_shape(
+                br,
+                {f: sds((rows,), field_dtypes[f])
+                 for f in cohort.atype.field_specs},
+                sds((cohort.msg_words, rows), jnp.int32),
+                sds((rows,), jnp.int32),
+                {t: sds((n, rows), jnp.int32) for t, n in spawn_sites},
+                *pool)
+
+    def pinned_fields():
+        """Which handles are checked once a dispatch, not once a message
+        (api.BlobPoolView): a Blob field every behaviour hands back as
+        the tracer it was given, in a cohort that neither allocates nor
+        frees — the field, `gen` and `used` are then the scan's
+        invariants. Decided from the behaviours' own trace over a
+        stand-in pool of one slot (no fact read depends on a size), the
+        first time the cohort is traced (a behaviour that cannot be
+        traced fails where it always did: at the first run)."""
+        if effects["pinned"] is None:
+            effects["pinned"] = ()
+            if use_blob and nb:
+                one = sds((1,), jnp.int32)
+                probe((sds((opts.blob_words,), jnp.int32),
+                       sds((1,), jnp.bool_), one, one, sds((), jnp.int32),
+                       sds((cohort.blob_sites, rows), jnp.int32),
+                       sds((rows,), jnp.bool_)), sds((rows,), jnp.bool_))
+                if not effects["alloc_free"]:
+                    effects["pinned"] = tuple(
+                        f for f, spec in cohort.atype.field_specs.items()
+                        if pack.is_blob(spec) and f in effects["kept"])
+        return effects["pinned"]
+
     if opts.pallas_fused and nb >= 1:
         from ..ops import fused_dispatch as fd
         from ..ops import mailbox_kernel as mk
@@ -435,16 +495,7 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
         # the kernel is built (it hosts destroy/error/spawn claims as
         # lane planes but cannot host sync-construction packaging).
         if not use_blob:
-            for br in branches:
-                jax.eval_shape(
-                    br,
-                    {f: jax.ShapeDtypeStruct((rows,), field_dtypes[f])
-                     for f in cohort.atype.field_specs},
-                    jax.ShapeDtypeStruct((cohort.msg_words, rows),
-                                         jnp.int32),
-                    jax.ShapeDtypeStruct((rows,), jnp.int32),
-                    {t: jax.ShapeDtypeStruct((n, rows), jnp.int32)
-                     for t, n in spawn_sites})
+            probe()
         fnames = tuple(cohort.atype.field_specs.keys())
         fused = (fd.build_fused_dispatch(
             cohort.behaviours, base_gid=base,
@@ -469,7 +520,7 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
                 f"cohort {cohort.atype.__name__} uses the blob pool but "
                 "run_cohort got blob=None (engine wiring)")
 
-        def scan_body(carry, x):
+        def scan_body(pinned, carry, x):
             (st, stopped, ef, ec, sfail, dstr, errf, errc, errl, used,
              nproc, nbad, blb, bused_c) = carry
             msg, valid = x                    # msg [w1, rows], valid [rows]
@@ -545,7 +596,7 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
                                blob["base"], rblob, rblob_over)
                 (st2, (btgt, bwrd), (bef, bec), byf, bclm, bini, bsf,
                  bds, (berf, berc, berl), bl_o) = br(
-                    st, msg[1:], ids, resv_k, blob_in, take)
+                    st, msg[1:], ids, resv_k, blob_in, take, pinned)
                 if blb_a is not None:
                     blb_o = (bl_o[0], bl_o[1], bl_o[2], bl_o[3],
                              blb_a[4] | bl_o[4], blb_a[5] | bl_o[5],
@@ -672,6 +723,20 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
                         jnp.int32(0), jnp.int32(0), jnp.int32(0))
             else:
                 blb0 = None
+            # A pinned handle (pinned_fields, above) is checked here,
+            # once: its slot, its generation, the slot's used flag — the
+            # two gathers every batch slot made. Of what the scan
+            # carries, the pool's [W*B] words are NOT lane-shaped; what
+            # it closes over here is: three lane vectors a field.
+            pinned, fields = {}, pinned_fields()
+            if fields:
+                with phase_scope("dispatch/heap"):
+                    view = BlobPoolView(*blb0[:4], blob["base"],
+                                        z(jnp.bool_), None)
+                    for f in fields:
+                        h = type_state_rows[f]
+                        hl, ok = view.local(h)
+                        pinned[f] = (hl, ok, view.live(h, hl))
             carry0 = (type_state_rows, z(jnp.bool_), z(jnp.bool_),
                       z(jnp.int32), z(jnp.bool_), z(jnp.bool_),
                       z(jnp.bool_), z(jnp.int32), z(jnp.int32),
@@ -680,7 +745,8 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
             ((stf, _, ef, ec, sfail, dstr, errf, errc, errl, _used, nproc,
               nbad, blbf, _bused),
              (stgt, swrd, consumed, claims, inits)) = lax.scan(
-                scan_body, carry0, (msgs, valids))
+                functools.partial(scan_body, pinned), carry0,
+                (msgs, valids))
             # stgt [batch, ms, rows] → flat [e] with rows minor;
             # swrd [batch, ms, w1, rows] → [w1, e] planar.
             n_consumed = jnp.sum(consumed.astype(jnp.int32), axis=0)
@@ -738,7 +804,19 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
                 (errf, errc, errl) if effects["error"] else None,
                 blob_out)
 
+    run_cohort.pinned_fields = pinned_fields
     return run_cohort
+
+
+def pinned_handles(program: Program, opts: RuntimeOptions
+                   ) -> Dict[str, List[str]]:
+    """{actor type: the Blob state fields its dispatch checks once}, a
+    device cohort a row ([] for a cohort that checks where it uses):
+    the analysis dump's `pinned_handles`."""
+    return {ch.atype.__name__: list(_cohort_dispatch(
+                ch, opts, opts.noyield, program).pinned_fields()
+            if opts.blob_slots > 0 and ch.uses_blobs else ())
+            for ch in program.device_cohorts}
 
 
 def tick_static(program: Program, opts: RuntimeOptions) -> TickStatic:

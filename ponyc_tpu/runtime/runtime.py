@@ -2225,6 +2225,12 @@ class Runtime:
             return 0 if leaf is None else int(self._fetch(leaf).sum())
         return int(self._fetch(getattr(self.state, name)).sum())
 
+    @functools.cached_property
+    def _pinned_handles(self) -> Dict[str, List[str]]:
+        """A fact of the compiled program, worked out once a runtime
+        (a program is frozen by then: profile() needs start())."""
+        return engine.pinned_handles(self.program, self.opts)
+
     @_api_phase("read")
     def profile(self) -> Dict[str, Any]:
         """Structured per-behaviour/per-cohort telemetry report — the
@@ -2242,7 +2248,11 @@ class Runtime:
              "cohorts": {"Type": {"queue_wait_hist": [QW_BUCKETS ints],
                                   "queue_wait_p50": int,   # ticks (2^k
                                   "queue_wait_p99": int,   #  bucket lo)
-                                  "mute_ticks": int}},
+                                  "mute_ticks": int,
+                                  "pinned_handles": [field names]}},
+                         # Blob fields whose handle the dispatch checks
+                         # once, not once a message (a fact of the
+                         # compiled program: engine.pinned_handles)
              "phases": {"delivery": int, "drain": int, "dispatch": int,
                         "gc_mark": int,       # cumulative work units
                         "rebuild": int},      # indices the rebuild read
@@ -2283,6 +2293,7 @@ class Runtime:
                 "rejected": int(rej[g]),
             }
         cohorts = {}
+        pinned = self._pinned_handles
         for di, ch in enumerate(self.program.device_cohorts):
             h = [int(x) for x in hist[di]]
             cohorts[ch.atype.__name__] = {
@@ -2290,6 +2301,7 @@ class Runtime:
                 "queue_wait_p50": hist_percentile(h, 0.50),
                 "queue_wait_p99": hist_percentile(h, 0.99),
                 "mute_ticks": int(mt[di]),
+                "pinned_handles": pinned[ch.atype.__name__],
             }
         ph = self._fetch(self.state.phase_cost).reshape(
             p, N_PHASES).sum(0)
