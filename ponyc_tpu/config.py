@@ -187,7 +187,7 @@ class RuntimeOptions:
     # runtime-analysis posture). All three are HOST-side: none feeds the
     # traced step, so with metrics_port=None and analysis=0 the step
     # jaxpr is bit-identical to a build without them (tests assert). ---
-    flight_windows: int = 64       # flight-recorder ring: how many
+    flight_windows: int = 256      # flight-recorder ring: how many
     #   retired-window records (control scalars the run loop already
     #   fetched, controller decisions, GC stats, recent host mail) the
     #   always-on black box retains for the crash/SIGQUIT/watchdog

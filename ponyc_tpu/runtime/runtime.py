@@ -398,6 +398,12 @@ class Runtime:
         #   checkpoint_every_s is set (durable worlds, PROFILE.md §12)
         self._costs = None            # costs.capture memo — measured
         #   cost/memory analysis of the compiled executables (ISSUE 19)
+        # costs.window_symbols: each launched program's first launch as
+        # shapes + shardings (taken at the cold launch), its `Compiled`
+        # and its symbol table, by costs.LAUNCHED's names
+        self._launch_specs: Dict[str, Any] = {}
+        self._compiled: Dict[str, Any] = {}
+        self._symbols: Dict[str, list] = {}
         self._last_run_crashed = False  # run() exited exceptionally:
         #   stop() must NOT overwrite the ring's newest snapshot with
         #   the post-crash world (the supervisor restores the last
@@ -773,8 +779,11 @@ class Runtime:
                     slot = pack.blob_slot(v)
                     if v >= 0 and 0 <= slot < n_blob_total:
                         blob_roots[slot] = True
-        self.state, facts = self._gc_fn(
-            self.state, jnp.asarray(extra), jnp.asarray(blob_roots))
+        args = (self.state, jnp.asarray(extra), jnp.asarray(blob_roots))
+        if "gc" not in self._launch_specs:
+            from .. import costs as _costs
+            self._launch_specs["gc"] = _costs.launch_specs(*args)
+        self.state, facts = self._gc_fn(*args)
         # the pass's answer, waited for here: its seconds are this
         # phase's (`gc`), not the next counter read's
         n, converged, iters, n_swept, free_before, born = map(
@@ -1500,10 +1509,13 @@ class Runtime:
         with _PhaseSpan(self, "dispatching", meta):
             inj_t, inj_w, consumed = self._drain_inject_tracked()
             mask = self._defer_signals()
-            try:
-                st2, aux, kdev = self._multi_g(
-                    self.state, inj_t, inj_w, jnp.int32(max(1, budget)),
+            args = (self.state, inj_t, inj_w, jnp.int32(max(1, budget)),
                     np.bool_(force), prev_aux)
+            if cold:
+                from .. import costs as _costs
+                self._launch_specs["window"] = _costs.launch_specs(*args)
+            try:
+                st2, aux, kdev = self._multi_g(*args)
                 self.state = st2
                 epoch = self._state_epoch
             finally:
@@ -2341,6 +2353,23 @@ class Runtime:
         backend doesn't report degrade to None."""
         from .. import costs as _costs
         return _costs.capture(self, force=force)
+
+    def window_symbols(self) -> Dict[str, list]:
+        """What a profiler trace's device operations are, by the
+        program's own names (costs.window_symbols): for each program
+        the run loop launches — `"window"` and, once a collection pass
+        has run, `"gc"` — a row for every instruction of the COMPILED
+        text that can be a device event: `name` (`fusion.238`),
+        `opcode`, `shape`, `kind` (gather / scatter / sort / collective
+        / other), the phase `scope` (state.STEP_SCOPES) with `how` it
+        was found (own / inside / around / none), and for a gather or a
+        scatter whether its table or output was dealt `S(1)` (`s1`; the
+        table alone: `table_s1`; `table_bytes`, `index_count`). Join a trace's event to a row by
+        instruction name and output shape (PROFILE.md §16). Lazy and
+        memoized; after a run the executable is found again, not
+        compiled, and the world does not advance."""
+        from .. import costs as _costs
+        return _costs.window_symbols(self)
 
     def traces(self) -> Dict[int, Dict[str, Any]]:
         """Reassembled causal traces (PROFILE.md §10): drains the

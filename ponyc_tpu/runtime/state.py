@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import re
 from typing import Any, Dict
 
 import jax
@@ -106,6 +107,34 @@ STEP_SCOPES = ("unmute", "spawn", "drain", "dispatch", "dispatch/heap",
                "spawn/free", "spawn/reserve", "spawn/claim",
                "gc_mark/roots", "gc_mark/hop", "gc_mark/sweep")
 _named_scope = jax.named_scope      # the one seam the tests stub
+# Segments of an op_name that JAX writes itself: a scope's path ends at
+# the first one, so no scope may be named like one.
+_JAX_SEGMENT = re.compile(
+    r"^(cond|while|body|scan|branch_\d+_fun|cond_fun|body_fun|pjit|"
+    r"closed_call|core_call|checkpoint|remat|shard_map|custom_jvp_call|"
+    r"custom_vjp_call|.*\(.*)$")
+
+
+def scope_of(op_name):
+    """The phase an HLO `op_name` belongs to: what follows its LAST
+    `pony` segment, up to the first segment JAX wrote itself and without
+    the final segment, which names the primitive — `jit(multi)/while/
+    body/pony/delivery/cond/branch_1_fun/pony/delivery/rebuild/gather`
+    is `delivery/rebuild`. None for an op_name under no scope. The rule
+    the scopes are written for (absolute, above); every reader of a
+    trace or of a compiled text applies this one."""
+    if not op_name:
+        return None
+    segments = op_name.split("/")
+    if SCOPE_PREFIX not in segments:
+        return None
+    last = len(segments) - 1 - segments[::-1].index(SCOPE_PREFIX)
+    path = []
+    for seg in segments[last + 1:-1]:
+        if _JAX_SEGMENT.match(seg):
+            break
+        path.append(seg)
+    return "/".join(path) or None
 
 
 def phase_scope(path: str):
