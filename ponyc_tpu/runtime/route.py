@@ -16,7 +16,8 @@ from jax import lax
 
 from ..ops import pack
 from ..ops.segment import compact_mask, stable_sort_carrying
-from .delivery import Entries, deliver, empty_mute_slots, mute_ref_slots
+from .delivery import (Entries, deliver, empty_mute_slots, mute_ref_slots,
+                       prefix_len)
 from .state import (ROUTE_COUNTERS, PhaseCursor, RtState, TickStatic,
                     layout_sizes, phase_scope, pool_index, rows_of)
 
@@ -146,10 +147,14 @@ def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
 
     Returns (received Entries [shards*bucket], new route-spill, spill count,
     overflow flag, newly muted [n_local], their refs, ref overflow, the
-    senders muted behind another shard's receiver, blob results or None,
-    (entries shipped, those of them off-shard)). Its parts carry the
-    scopes `pony/route/sort`, `/bucket`, `/exchange` and `/spill` with
-    `/spill/lookup` and `/spill/mute` below it (state.STEP_SCOPES).
+    senders muted behind another shard's receiver, 1 where the spill read
+    the sorted entries' prefix alone, blob results or None, (entries
+    shipped, those of them off-shard)). Its parts carry the scopes
+    `pony/route/sort`, `/bucket`, `/exchange` and `/spill` with
+    `/spill/lookup` and `/spill/mute` below it (state.STEP_SCOPES), at
+    either of the spill's two lengths (`_route_spill`: the sorted
+    entries, or the quarter of them the valid ones fit, by the tick's
+    own count).
     Bucket overflow keeps messages on the source shard (route-spill,
     retried first next step) and mutes the sender — the occupancy signal
     there is "the link to that shard is saturated"; a sender whose
@@ -300,9 +305,15 @@ def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
         # without it the compiler fuses `maximum(ts, 0)` into the
         # bucket's slices and the lookup `hot_global[ts]` loses its fast
         # memory, 114 ms for 68 at 8.4M entries (PERF.md §6, PR 41).
+        # `_route_spill` cuts its prefix from what comes out of the
+        # barrier, never from the sort's own outputs, for the same
+        # reason.
         ts, ss, ws, dt = lax.optimization_barrier((ts, ss, ws, dt))
+        # The invalid tail is keyed `shards` and sorts last: the valid
+        # entries are the first `n_live` of the sorted ones.
+        n_live = seg_start[-1] + cnt[-1]
         spilled = _route_spill(
-            ts, ss, ws, dt, seg_start, cnt - acc, shards=shards,
+            ts, ss, ws, dt, seg_start, cnt - acc, n_live, shards=shards,
             n_local=n_local, bucket=bucket, rspill_cap=rspill_cap,
             overload_occ=overload_occ, head=head, tail=tail,
             shard_base=shard_base, mute_slots=mute_slots,
@@ -312,15 +323,17 @@ def _route(entries: Entries, *, shards: int, n_local: int, bucket: int,
     return (received, *spilled, blob_out, (n_routed, n_remote))
 
 
-def _route_spill(ts, ss, ws, dt, seg_start, over, *, shards: int,
+def _route_spill(ts, ss, ws, dt, seg_start, over, n_live, *, shards: int,
                  n_local: int, bucket: int, rspill_cap: int, overload_occ,
                  head, tail, shard_base, mute_slots: int,
                  hot_anywhere, hot_global, pressured_local):
     """What did not fit its bucket, and who mutes for what it sent: the
     sorted entries (`ts`, `ss`, `ws` by destination `dt`), each
-    destination's `seg_start` and overflow `over` → (new route-spill,
-    spill count, overflow flag, newly muted [n_local], their refs, ref
-    overflow, the senders muted behind a receiver on another shard).
+    destination's `seg_start` and overflow `over`, and how many of the
+    entries are valid (`n_live`) → (new route-spill, spill count,
+    overflow flag, newly muted [n_local], their refs, ref overflow, the
+    senders muted behind a receiver on another shard, 1 where the tick
+    read the entries' prefix alone).
 
     Backpressure across the mesh (≙ ponyint_maybe_mute, actor.c:898-921:
     the SENDER reads the receiver's flags on every send, whatever
@@ -341,89 +354,141 @@ def _route_spill(ts, ss, ws, dt, seg_start, over, *, shards: int,
     and the one it sent in the tick that muted it; it then does not run
     until nothing is parked for that receiver, its own among them.
     Senders that are themselves overloaded or declare pressure never
-    mute (the reference's exemption)."""
+    mute (the reference's exemption).
+
+    The two lengths (PR 50; `delivery.deliver`'s since PR 45, here).
+    Every read and scatter by index below is paid by the SLOT of the
+    sorted entries, valid or not, and the slots are a shard's whole
+    route spill and outbox for the fraction of them a tick routes. The
+    sort keys the invalid tail `shards`, so the valid entries are the
+    first `n_live` sorted ones and nothing below asks about the rest: a
+    hot target, a parked entry and a muting sender are all valid
+    entries. So the window holds the spill at two static lengths, the
+    entries' `e` and `prefix_len(e)` (delivery's own quarter; static,
+    and this module's name for it is the seam the tests patch to get
+    the whole length alone), and `n_live` chooses: where the valid
+    entries fit the prefix, the lookup, the overflow's compaction (an
+    entry's rank in its segment is its position in the sorted entries,
+    which a cut behind it does not move), the exemption and the ref
+    table's scatters run over `ts[:L]`, `ss[:L]`, `ws[:, :L]`,
+    `dt[:L]`; else over all of them.
+    One algorithm, the same seven results bit for bit either way
+    (tests/test_route_spill_prefix.py); `pr_t` and `rej` never leave
+    the branch. The caller hands the entries over from BEHIND its
+    `optimization_barrier` and the cut is made here, inside the branch:
+    cut from the sort's own outputs it would fuse into the bucket's
+    slices as `maximum(ts, 0)` did (PERF.md §6, PR 41). The eighth
+    result feeds `n_route_prefix` (RtState.route_counts): 1 where the
+    lookup or the pressure branch ran at the short length — a quiet
+    tick runs neither at either length and counts 0."""
     e = ts.shape[0]
     nrej = jnp.sum(over)
     w1 = ws.shape[0]
-    # A read by index is paid by the entry whatever it fetches, so the
-    # lookup runs only on a tick whose world bit 0 or 3 (`hot_anywhere`,
-    # mute.world) says the word can hold a set bit: where both are clear
-    # `hot_global` IS zeros, and so is the answer.
-    def looked_up(_):
-        with phase_scope("route/spill/lookup"):
-            hit = (ts >= 0) & (jnp.take(
-                hot_global, jnp.maximum(ts, 0), mode="clip") != 0)
-            return hit, jnp.any(hit)
-
-    pr_t, any_pr = lax.cond(
-        hot_anywhere, looked_up,
-        lambda _: (jnp.zeros((e,), jnp.bool_), jnp.bool_(False)),
-        operand=None)
 
     def empty_spill():
         return Entries(tgt=jnp.full((rspill_cap,), -1, jnp.int32),
                        sender=jnp.full((rspill_cap,), -1, jnp.int32),
                        words=jnp.zeros((w1, rspill_cap), jnp.int32))
 
-    def parked(_):
-        # Bucket overflow → route spill (stays on this shard, ordered).
-        # `dt` is sorted and `shards` is small: a select a destination
-        # finds an entry's segment start, no read by index.
-        start = jnp.zeros((e,), jnp.int32)
-        for d in range(shards):
-            start = jnp.where(dt == d, seg_start[d], start)
-        rej = (dt < shards) & (jnp.arange(e, dtype=jnp.int32) - start
-                               >= bucket)
-        perm2, vsp, _ = compact_mask(rej, rspill_cap)
-        return rej, Entries(
-            tgt=jnp.where(vsp, ts[perm2], -1),
-            sender=jnp.where(vsp, ss[perm2], -1),
-            words=jnp.where(vsp[None, :], ws[:, perm2], 0),
-        )
-
-    def pressure(_):
-        # Mute the (always local) senders of parked messages and of
-        # messages to a hot receiver. The compaction runs only where a
-        # link overflowed: a tick that is here for a hot receiver alone
-        # — every tick of a skewed world — parks nothing.
-        rej, spill = lax.cond(
-            nrej > 0, parked,
-            lambda _: (jnp.zeros((e,), jnp.bool_), empty_spill()),
-            operand=None)
-        with phase_scope("route/spill/mute"):
-            lsnd = ss - shard_base
-            s_ok = (rej | pr_t) & (lsnd >= 0) & (lsnd < n_local)
-            sc = jnp.minimum(jnp.maximum(lsnd, 0), n_local - 1)
-            # ≙ the reference's !OVERLOADED/UNDER_PRESSURE sender
-            # exemption (actor.c mute rules): a sender that is itself
-            # hot or has itself declared pressure never mutes — prevents
-            # two host-pressured actors that message each other from
-            # mutually muting into a stall. One bit a row, decided over
-            # the rows and read once by the sender index; `head` and
-            # `tail` are the tick's START, as the hot word is: what the
-            # tick before left, which is what its delivery exempted by.
-            exempt = ((tail - head) > overload_occ) | pressured_local
-            trig = s_ok & ~exempt[sc]
-            mute_row = jnp.where(trig, sc, n_local)
-            refs, ovf = mute_ref_slots(trig, mute_row, ts, n=n_local,
-                                       k=mute_slots)
-            # Every trigger wrote its ref (>= 0) into its sender's
-            # column: the table says who was muted, and behind whom.
-            newly_muted = jnp.any(refs >= 0, axis=0)
-            elsewhere = (refs >= 0) & (refs // n_local
-                                       != shard_base // n_local)
-            n_remote = jnp.sum(jnp.any(elsewhere, axis=0).astype(jnp.int32))
-        return spill, newly_muted, refs, ovf, n_remote
-
     def quiet(_):
         refs, ovf = empty_mute_slots(n_local, mute_slots)
         return (empty_spill(), jnp.zeros((n_local,), jnp.bool_), refs, ovf,
                 jnp.int32(0))
 
-    new_rspill, newly_muted, new_refs, new_ovf, n_remote = lax.cond(
-        (nrej > 0) | any_pr, pressure, quiet, operand=None)
+    def over_the_first(length: int):
+        """`spilled` over the first `length` sorted entries, as a branch."""
+        def run(_):
+            # the scope again: it names the conditionals inside a branch
+            with phase_scope("route/spill"):
+                if length == e:
+                    return spilled(ts, ss, ws, dt)
+                return spilled(*(lax.slice_in_dim(x, 0, length, axis=-1)
+                                 for x in (ts, ss, ws, dt)))
+        return run
+
+    def spilled(ts, ss, ws, dt):
+        e = ts.shape[0]
+        # A read by index is paid by the entry whatever it fetches, so the
+        # lookup runs only on a tick whose world bit 0 or 3 (`hot_anywhere`,
+        # mute.world) says the word can hold a set bit: where both are clear
+        # `hot_global` IS zeros, and so is the answer.
+        def looked_up(_):
+            with phase_scope("route/spill/lookup"):
+                hit = (ts >= 0) & (jnp.take(
+                    hot_global, jnp.maximum(ts, 0), mode="clip") != 0)
+                return hit, jnp.any(hit)
+
+        pr_t, any_pr = lax.cond(
+            hot_anywhere, looked_up,
+            lambda _: (jnp.zeros((e,), jnp.bool_), jnp.bool_(False)),
+            operand=None)
+
+        def parked(_):
+            # Bucket overflow → route spill (stays on this shard, ordered).
+            # `dt` is sorted and `shards` is small: a select a destination
+            # finds an entry's segment start, no read by index.
+            start = jnp.zeros((e,), jnp.int32)
+            for d in range(shards):
+                start = jnp.where(dt == d, seg_start[d], start)
+            rej = (dt < shards) & (jnp.arange(e, dtype=jnp.int32) - start
+                                   >= bucket)
+            perm2, vsp, _ = compact_mask(rej, rspill_cap)
+            return rej, Entries(
+                tgt=jnp.where(vsp, ts[perm2], -1),
+                sender=jnp.where(vsp, ss[perm2], -1),
+                words=jnp.where(vsp[None, :], ws[:, perm2], 0),
+            )
+
+        def pressure(_):
+            # Mute the (always local) senders of parked messages and of
+            # messages to a hot receiver. The compaction runs only where a
+            # link overflowed: a tick that is here for a hot receiver alone
+            # — every tick of a skewed world — parks nothing.
+            rej, spill = lax.cond(
+                nrej > 0, parked,
+                lambda _: (jnp.zeros((e,), jnp.bool_), empty_spill()),
+                operand=None)
+            with phase_scope("route/spill/mute"):
+                lsnd = ss - shard_base
+                s_ok = (rej | pr_t) & (lsnd >= 0) & (lsnd < n_local)
+                sc = jnp.minimum(jnp.maximum(lsnd, 0), n_local - 1)
+                # ≙ the reference's !OVERLOADED/UNDER_PRESSURE sender
+                # exemption (actor.c mute rules): a sender that is itself
+                # hot or has itself declared pressure never mutes — prevents
+                # two host-pressured actors that message each other from
+                # mutually muting into a stall. One bit a row, decided over
+                # the rows and read once by the sender index; `head` and
+                # `tail` are the tick's START, as the hot word is: what the
+                # tick before left, which is what its delivery exempted by.
+                exempt = ((tail - head) > overload_occ) | pressured_local
+                trig = s_ok & ~exempt[sc]
+                mute_row = jnp.where(trig, sc, n_local)
+                refs, ovf = mute_ref_slots(trig, mute_row, ts, n=n_local,
+                                           k=mute_slots)
+                # Every trigger wrote its ref (>= 0) into its sender's
+                # column: the table says who was muted, and behind whom.
+                newly_muted = jnp.any(refs >= 0, axis=0)
+                elsewhere = (refs >= 0) & (refs // n_local
+                                           != shard_base // n_local)
+                n_remote = jnp.sum(
+                    jnp.any(elsewhere, axis=0).astype(jnp.int32))
+            return spill, newly_muted, refs, ovf, n_remote
+
+        return lax.cond((nrej > 0) | any_pr, pressure, quiet, operand=None)
+
+    short = prefix_len(e)
+    if short < e:
+        fits = n_live <= short
+        new_rspill, newly_muted, new_refs, new_ovf, n_remote = lax.cond(
+            fits, over_the_first(short), over_the_first(e), operand=None)
+        took_prefix = fits & (hot_anywhere | (nrej > 0))
+    else:       # a few tiles of entries: the one length
+        new_rspill, newly_muted, new_refs, new_ovf, n_remote = \
+            over_the_first(e)(None)
+        took_prefix = jnp.bool_(False)
     return (new_rspill, jnp.minimum(nrej, rspill_cap), nrej > rspill_cap,
-            newly_muted, new_refs, new_ovf, n_remote)
+            newly_muted, new_refs, new_ovf, n_remote,
+            took_prefix.astype(jnp.int32))
 
 
 # The static lengths of a shard's lists: the per-destination all_to_all
@@ -547,8 +612,8 @@ def deliver_routed(k: TickStatic, st: RtState, w, inject_tgt, inject_words,
                      "shard": w.shard, "mask": k.blob_route[0],
                      "mask_iso": k.blob_route[1]}
         (incoming, new_rspill, rsp_count, rsp_over, route_muted,
-         route_refs, route_ovf, n_remote_mutes, route_blob_out,
-         routed) = _route(
+         route_refs, route_ovf, n_remote_mutes, n_route_prefix,
+         route_blob_out, routed) = _route(
             out_cat, shards=p, n_local=nl, bucket=bucket,
             rspill_cap=k.s_cap,
             overload_occ=rows_of(k.program, "overload_occ"),
@@ -649,7 +714,7 @@ def deliver_routed(k: TickStatic, st: RtState, w, inject_tgt, inject_words,
     counts = {} if routed is None else dict(
         zip(ROUTE_COUNTERS, (*routed, n_unpacked,
                              w.hot_anywhere.astype(jnp.int32),
-                             n_remote_mutes)))
+                             n_route_prefix, n_remote_mutes)))
     if res.n_prefix is not None:
         counts["n_prefix"] = res.n_prefix
     return Routed(res, new_rspill, rsp_count, rsp_over, route_muted,

@@ -2229,9 +2229,11 @@ class Runtime:
         """Sum a per-shard runtime counter (n_processed, n_delivered,
         n_rejected, n_badmsg, n_deadletter, n_mutes; the route's
         n_routed, n_routed_remote, n_unpacked, n_route_pressure,
-        n_remote_mutes — 0 on one chip, where nothing is routed and the state holds no such
-        leaf; n_prefix, the ticks delivered over the list's prefix — 0
-        where there is no such leaf, state.counts_prefix) over the mesh."""
+        n_route_prefix (the shard-ticks whose route spill read the
+        sorted entries' prefix alone), n_remote_mutes — 0 on one chip,
+        where nothing is routed and the state holds no such leaf;
+        n_prefix, the ticks delivered over the list's prefix — 0 where
+        there is no such leaf, state.counts_prefix) over the mesh."""
         if name in LIST_COUNTERS:
             leaf = self.state.route_counts.get(name)
             return 0 if leaf is None else int(self._fetch(leaf).sum())

@@ -362,7 +362,13 @@ class RtState:
     # the ticks on which this shard looked its sorted entries' targets
     # up in the mesh-wide hot word (route._route_spill: world bit 0 or 3
     # was set; 0 on a mesh where nobody declares pressure and nobody is
-    # overloaded); "n_remote_mutes" [P] int32 — the senders routing
+    # overloaded); "n_route_prefix" [P] int32 — those ticks, and the
+    # ticks of a link overflow, on which this shard's valid entries
+    # fitted the PREFIX of the sorted ones and `_route_spill` read no
+    # further (a quarter: delivery.prefix_len; = "n_route_pressure" in
+    # a world whose entries always fit and whose links never overflow;
+    # a quiet tick reads neither length and counts 0; state only, not a
+    # StepAux leaf); "n_remote_mutes" [P] int32 — the senders routing
     # muted behind a receiver on ANOTHER shard (overloaded, under
     # declared pressure, or at the end of a full link). Read through
     # Runtime.counter(), which sums them over the mesh like n_processed.
@@ -528,7 +534,7 @@ class RtState:
 # The route's counters (RtState.route_counts), a mesh only; and every
 # name that dict may hold (list_counters: which of them a program has).
 ROUTE_COUNTERS = ("n_routed", "n_routed_remote", "n_unpacked",
-                  "n_route_pressure", "n_remote_mutes")
+                  "n_route_pressure", "n_route_prefix", "n_remote_mutes")
 LIST_COUNTERS = ROUTE_COUNTERS + ("n_prefix",)
 
 

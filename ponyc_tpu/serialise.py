@@ -253,6 +253,7 @@ _ZERO_IF_ABSENT = frozenset({"st.phase_cost", "st.route_counts.n_routed",
                              "st.route_counts.n_routed_remote",
                              "st.route_counts.n_unpacked",
                              "st.route_counts.n_route_pressure",
+                             "st.route_counts.n_route_prefix",
                              "st.route_counts.n_remote_mutes",
                              "st.route_counts.n_prefix"})
 

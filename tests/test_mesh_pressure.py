@@ -204,13 +204,15 @@ PHASE = QUIET + PRESSURED + AFTER
 TARGETS = ("remote", "local")           # the phases of one world, in turn
 
 
-def _leaves(rt):
+def _leaves(rt, but=("n_route_pressure", "n_route_prefix")):
     """Every state leaf by path (muted, mute_refs, the route spill, the
-    actors' own counts, ...) but the counter of the choice itself."""
+    actors' own counts, ...) `but` the counter of the choice itself and
+    the one that counts a part of the ticks it counts (PR 50: those
+    whose spill read the entries' prefix alone)."""
     flat, _ = jax.tree_util.tree_flatten_with_path(rt.state)
     return {jax.tree_util.keystr(path): np.asarray(leaf)
             for path, leaf in flat
-            if "n_route_pressure" not in jax.tree_util.keystr(path)}
+            if not any(name in jax.tree_util.keystr(path) for name in but)}
 
 
 @functools.lru_cache(maxsize=None)
@@ -299,17 +301,20 @@ def test_the_gated_lookup_is_the_lookup_on_every_tick(shards, where):
     assert forced["looked_up"] == shards * len(want)
 
 
-@pytest.mark.parametrize("leaf", ["n_route_pressure", "n_remote_mutes"])
+@pytest.mark.parametrize("leaf", ["n_route_pressure", "n_remote_mutes",
+                                  "n_route_prefix"])
 @pytest.mark.parametrize("target", ["same-layout", "relayout"])
 def test_a_snapshot_from_before_the_counter_restores_with_it_at_zero(
         tmp_path, target, leaf):
-    """`n_route_pressure` (PR 46) and `n_remote_mutes` (PR 47) are state
-    leaves newer than snapshots in the wild: one without the leaf
-    restores, the other route counters carried."""
+    """`n_route_pressure` (PR 46), `n_remote_mutes` (PR 47) and
+    `n_route_prefix` (PR 50) are state leaves newer than snapshots in
+    the wild: one without the leaf restores, the other route counters
+    carried. (A route spill of 1,024: sorted entries enough for the
+    spill's second length, a quarter of them.)"""
     from ponyc_tpu import serialise
 
     opts = RuntimeOptions(mailbox_cap=8, batch=2, max_sends=2, msg_words=2,
-                          mesh_shards=2, spill_cap=64, inject_slots=64)
+                          mesh_shards=2, spill_cap=1024, inject_slots=64)
     rt, sink, _srcs = _run_pressure(opts, n_src=8, items=4)
     rt.apply_backpressure([int(sink)])
     rt.run(max_steps=3)

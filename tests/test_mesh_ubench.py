@@ -86,9 +86,10 @@ def test_every_actor_follows_the_reference_on_any_layout(shards, recipients):
         # delivers over what arrived, the short list (`n_unpacked`);
         # nobody declares pressure and nobody is overloaded, so no
         # shard of any tick looks its entries' targets up
-        # (`n_route_pressure`) and nobody mutes (`n_remote_mutes`)
+        # (`n_route_pressure`), at either length (`n_route_prefix`),
+        # and nobody mutes (`n_remote_mutes`)
         expect = (int(sent[:tick + 1].sum()), int(remote[:tick + 1].sum()),
-                  shards * (tick + 1), 0, 0) if shards > 1 else (0,) * 5
+                  shards * (tick + 1), 0, 0, 0) if shards > 1 else (0,) * 6
         assert routed == expect, (tick, routed, expect)
         assert spilled == 0, tick
     assert not any(errors.values()), errors
@@ -149,7 +150,7 @@ def test_a_quiet_mesh_never_looks_up_pressure(shards, recipients):
     seen = _ticked(shards, recipients)[0]
     names = dict(zip(ROUTE_COUNTERS, seen[-1][1]))
     assert names["n_unpacked"] == shards * TICKS
-    assert names["n_route_pressure"] == 0
+    assert names["n_route_pressure"] == names["n_route_prefix"] == 0
 
 
 def _window_text(rt, compiled=False):
@@ -182,6 +183,7 @@ def test_route_scopes_and_counters_exist_on_a_mesh_only():
     assert world.rt.counter("n_routed_remote") == 0
     assert world.rt.counter("n_unpacked") == 0
     assert world.rt.counter("n_route_pressure") == 0
+    assert world.rt.counter("n_route_prefix") == 0
     world.rt.stop()
 
 

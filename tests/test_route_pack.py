@@ -17,6 +17,7 @@ import pytest
 
 import _hlo
 from ponyc_tpu.runtime import route
+from ponyc_tpu.runtime.delivery import prefix_len
 
 SHARDS, N_LOCAL = 4, 16
 
@@ -125,11 +126,12 @@ def test_overflow_reaches_the_route_spill_unchanged(w1):
     (dt, ts, ss, ws), (seg_start, cnt, acc), _ = got
     assert int(np.max(np.asarray(cnt) - np.asarray(acc))) > 0
     n = SHARDS * N_LOCAL
-    spill, count, over, muted, _refs, _ovf, _remote = jax.jit(functools.partial(
+    (spill, count, over, muted, _refs, _ovf, _remote,
+     _prefix) = jax.jit(functools.partial(
         route._route_spill, shards=SHARDS, n_local=N_LOCAL, bucket=bucket,
         rspill_cap=cap, overload_occ=48, shard_base=jnp.int32(0),
         mute_slots=4))(
-        ts, ss, ws, dt, seg_start, cnt - acc,
+        ts, ss, ws, dt, seg_start, cnt - acc, seg_start[-1] + cnt[-1],
         head=jnp.zeros((N_LOCAL,), jnp.int32),
         tail=jnp.zeros((N_LOCAL,), jnp.int32),
         hot_anywhere=jnp.bool_(False),
@@ -212,7 +214,9 @@ def test_for_the_chip_the_pad_folds_into_the_slices(w1):
 # a quiet tick pays for it. The lookup of the sorted entries' targets in
 # the mesh-wide hot word sits behind world bits 0 and 3
 # (`hot_anywhere`), the overflow and the mutes behind the
-# conditional they always had.
+# conditional they always had; both at the entries' length and at a
+# quarter of it (PR 50), behind the conditional that asks whether the
+# valid entries fit the quarter.
 SPILL_FOR_THE_CHIP = """
 import re
 sys.path.insert(0, {tests!r})
@@ -222,16 +226,17 @@ from ponyc_tpu.runtime.state import phase_scope
 e, shards, n = {e}, 4, {n}
 flag = lambda *shape, dtype=jnp.bool_: jax.ShapeDtypeStruct(
     shape, dtype, sharding=SingleDeviceSharding(device))
-def fn(ts, ss, ws, dt, seg_start, over, head, tail, anywhere, everyone, mine):
+def fn(ts, ss, ws, dt, seg_start, over, n_live, head, tail, anywhere,
+       everyone, mine):
     with phase_scope("route/spill"):
         return route._route_spill(
-            ts, ss, ws, dt, seg_start, over, shards=shards, n_local=n,
+            ts, ss, ws, dt, seg_start, over, n_live, shards=shards, n_local=n,
             bucket=e, rspill_cap=4096, overload_occ=48, head=head, tail=tail,
             shard_base=jnp.int32(0), mute_slots=4,
             hot_anywhere=anywhere, hot_global=everyone,
             pressured_local=mine)
-args = (arg(e), arg(e), arg(2, e), arg(e), arg(shards), arg(shards), arg(n),
-        arg(n), flag(), flag(shards * n, dtype=jnp.int8), flag(n))
+args = (arg(e), arg(e), arg(2, e), arg(e), arg(shards), arg(shards), arg(),
+        arg(n), arg(n), flag(), flag(shards * n, dtype=jnp.int8), flag(n))
 def report(text):
     entry = text[text.index("\\nENTRY "):].split("\\n}}")[0].splitlines()[2:]
     wide = []
@@ -246,23 +251,34 @@ def report(text):
 
 
 def test_for_the_chip_a_quiet_ticks_spill_holds_no_gather():
-    """Outside its two conditionals the spill reads nothing by index and
+    """Outside its conditionals the spill reads nothing by index and
     writes nothing as long as the entries (the branch's zeros come out
     of the conditional; the compiler may prefetch a branch's operand,
-    which is a copy); the lookup's branch holds the one gather of `e`
-    flags, and where nothing overflowed and nothing is pressured the
-    second conditional reaches nothing either."""
+    which is a copy). The first conditional chooses a length, the
+    entries' or a quarter of them; at either, the lookup's branch holds
+    the one gather of that many flags, where nothing overflowed and
+    nothing is pressured the second conditional reaches nothing, and
+    nothing at the quarter reads or writes at the entries' length."""
     import os
     e = 1 << 16
+    short = prefix_len(e)
     seen = _hlo.v5e_counts(SPILL_FOR_THE_CHIP.format(
         tests=os.path.dirname(os.path.abspath(__file__)), e=e, n=e // 8))
-    lookup = [["gather", [e]]]
-    conds = sorted(seen["conds"], key=lambda branches: branches[1] != lookup)
-    assert len(conds) == 2
-    (quiet_zeros, looked_up), (quiet, pressure) = conds  # false branch first
-    assert (quiet_zeros, looked_up, quiet) == ([], lookup, [])
-    in_branches = sum(op == "gather" for op, _dims in looked_up + pressure)
-    assert seen["gathers"] == in_branches > 1
+    conds = seen["conds"]
+    assert len(conds) == 5
+    outer = max(conds, key=lambda branches: sum(map(len, branches)))
+    inner = [c for c in conds if c is not outer]
+    in_branches = 0
+    for length, reached in zip((e, short), outer):   # false branch first
+        lookup = [["gather", [length]]]
+        (quiet_zeros, looked_up), = [c for c in inner if c[1] == lookup]
+        (quiet, pressure), = [c for c in inner if c[1] != lookup
+                              and lookup[0] in c[1]]
+        assert (quiet_zeros, looked_up, quiet) == ([], lookup, [])
+        assert sorted(looked_up + pressure) == reached
+        in_branches += sum(op == "gather" for op, _dims in reached)
+    assert not [op for op in outer[1] if e in op[1]]
+    assert seen["gathers"] == in_branches > 2
     computes_nothing = {"parameter", "tuple", "get-tuple-element", "bitcast",
                         "conditional", "copy-start", "copy-done"}
     assert set(seen["wide"]) <= computes_nothing, seen["wide"]
