@@ -1,5 +1,16 @@
-"""n-body — port of the reference benchmark `examples/n-body/` (gravity
-between bodies; the compute-heavy-float-behaviour workload).
+"""n-body — the library's TOY twin of the reference benchmark
+`examples/n-body/` (gravity between bodies; the compute-heavy-float-
+behaviour workload), kept for the library's own tests
+(`tests/test_models.py`, `tests/test_vec_payloads.py`).
+
+What it is: two dimensions, a softened force (`SOFTEN`), an arbitrary
+constant `G`, random bodies, ONE interaction round (accelerations only,
+no integrator). What it is not: the source's system. The source's own
+form — the Sun and the four Jovian planets in three dimensions, the
+force `dt / (d2 sqrt(d2))` without softening, the symplectic-Euler
+`advance`, the constants and the two printed energies — is
+`benchmarks/worlds/nbody.py` (configuration `nbody-jovian`, cell
+`nbody-jovian.orbit`), held to `benchmarks/reference_nbody.py`.
 
 TPU shape: a *systolic ring* of body actors. Each body launches a token
 carrying its (position, mass); tokens hop the ring, and every body a token
