@@ -52,18 +52,24 @@ within one shard of the actor world:
      its rows; none where it received nothing), and a block pulls only
      for the rows that have a message in it, those with acc > k*B.
      While more than M = ceil(rows / B) of the cohort's rows are that
-     deep the block runs FULL WIDTH: one gather of B*rows indices, every
-     row's B ranks. From the first block whose rows fit in M on — their
-     count only falls with k, so a cohort is two `lax.while_loop`s in
-     turn and no `cond` — a block runs COMPACTED: the deep rows'
-     indices ascending, each with its segment's start (one sort of the
-     rows), B*M indices into the list, then one scatter of the M
-     windows back to their rows' lanes, sorted and unique and told so:
-     B*M = rows indices for B*rows. The gather is the expensive part —
+     deep the block runs FULL WIDTH: every row's rank, one gather of
+     `rows` indices a rank — for the min(B, max(acc) - k*B) ranks some
+     row of the cohort holds, in a loop of its own, and no more: a
+     cohort whose every actor sends itself one message a tick (a
+     producer, a streamer, a timer, a body of a ring) pulls one rank
+     for its first block, not eight, and what the loop pulls is placed
+     in one pass as before. From the first block whose rows fit in M on
+     — their count only falls with k, so a cohort is two
+     `lax.while_loop`s in turn and no `cond` — a block runs COMPACTED:
+     the deep rows' indices ascending, each with its segment's start
+     (one sort of the rows), B*M indices into the list, then one
+     scatter of the M windows back to their rows' lanes, sorted and
+     unique and told so: B*M = rows indices for what a full block of B
+     ranks reads in B*rows. The gather is the expensive part —
      ~6 ns an index on a v5e whatever it fetches; a scatter told sorted
      and unique ~40 ns a window, untold one update after another (note
-     a) — so a steady world pays B*rows a tick instead of cap*N, a
-     million shallow senders do not pay for the one deep receiver they
+     a) — so a steady world pays max(acc)*rows a tick instead of cap*N,
+     a million shallow senders do not pay for the one deep receiver they
      share a world with, the few rows past rank 8 of a Poisson tail do
      not make every row gather another block, and a world few of whose
      rows receive anything compacts its first block too (k = 0 is no
@@ -164,7 +170,9 @@ class DeliveryResult(NamedTuple):
     rebuild_slots: jnp.ndarray  # [] int32 indices the rebuild's gathers
     #                               read this tick: over the cohorts and
     #                               the rank blocks ITS fullest mailbox
-    #                               made it run, B ranks x its rows a
+    #                               made it run, the ranks that mailbox
+    #                               holds of the block (B, or what is
+    #                               left of max(acc)) x its rows a
     #                               full-width block, B ranks x M a
     #                               compacted one (rebuild_tables); cap x
     #                               N for a ring of one block; 0 with no
@@ -221,10 +229,10 @@ def rebuild_tables(tables, wds, tail, acc, seg_start):
     on, its trace side lanes through the SAME (mask, source) pairs, so
     context and message cannot land in different slots. Arrival rank r
     of actor i (r < acc[i]) is entry seg_start[i] + r and lands in ring
-    slot (tail[i] + r) % cap. Returns (new tables, indices gathered: B
-    ranks x rows a full-width block, B ranks x M a compacted one,
-    summed over the blocks each cohort ran; None for rings of one
-    block, whose count the caller knows)."""
+    slot (tail[i] + r) % cap. Returns (new tables, indices gathered:
+    the block's ranks that some row holds x rows a full-width block, B
+    ranks x M a compacted one, summed over the blocks each cohort ran;
+    None for rings of one block, whose count the caller knows)."""
     caps = {table.shape[0] for table, *_ in tables}
     e = wds.shape[1]
 
@@ -281,9 +289,10 @@ def _rebuild_cohort(tabs, word_rows, wds, rels, acc, seg_start):
     REBUILD_BLOCK) blocks — none for a cohort that received nothing.
     Block k has work only for the rows with acc > k*B; their count falls
     with k, so the blocks come as two loops in turn: full-width ones
-    while more than M = ceil(rows / B) rows are that deep, then
-    compacted ones, which pull for those rows alone. Returns (new
-    tables, indices gathered)."""
+    while more than M = ceil(rows / B) rows are that deep — each a loop
+    over the ranks the fullest row holds of it, one gather of the rows
+    a rank —, then compacted ones, which pull for those rows alone.
+    Returns (new tables, indices gathered)."""
     b = REBUILD_BLOCK
     e = wds.shape[1]
     nn = acc.shape[0]
@@ -305,12 +314,40 @@ def _rebuild_cohort(tabs, word_rows, wds, rels, acc, seg_start):
         # Absolute, like every scope (state.py): a loop's body and its
         # test are computations of their own and would carry no phase.
         with phase_scope("delivery/rebuild"):
-            ranks = k * b + jnp.arange(b, dtype=jnp.int32)        # [B]
-            srcs = jnp.minimum(seg_start[None, :] + ranks[:, None],
-                               e - 1).reshape(b * nn)
-            pulled = [jnp.take(wds[r0:r1], srcs,
-                               axis=1).reshape(r1 - r0, b, nn)
-                      for r0, r1 in word_rows]
+            # The ranks this block has at all: a rank no row holds is
+            # not fetched (a gather is paid by the index).
+            r = jnp.minimum(depth - k * b, b)
+            # Each table's word rows of the list, cut once a block (a
+            # cohort narrower than the list's records would copy its
+            # rows of the whole list a rank otherwise); a single row as
+            # the vector the gather reads it as.
+            parts = [wds[r0] if r1 - r0 == 1 else wds[r0:r1]
+                     for r0, r1 in word_rows]
+
+            def rank(carry):
+                j, pulled = carry
+                with phase_scope("delivery/rebuild"):
+                    # Rank k*B + j of every row: ONE gather of `nn`
+                    # indices a table, written as plane j. The indices
+                    # are in [0, e) as they stand, and the gather is
+                    # told so: no mask of the out-of-range to compute,
+                    # copy and select by, a rank.
+                    srcs = jnp.minimum(seg_start + (k * b + j), e - 1)
+                    return j + 1, tuple(
+                        lax.dynamic_update_slice(
+                            pull, jnp.take(part, srcs, axis=part.ndim - 1,
+                                           mode="clip").reshape(-1, 1, nn),
+                            (0, j, 0))
+                        for pull, part in zip(pulled, parts))
+
+            def more_ranks(carry):
+                with phase_scope("delivery/rebuild"):
+                    return carry[0] < r
+
+            _, pulled = lax.while_loop(
+                more_ranks, rank,
+                (jnp.int32(0), tuple(jnp.zeros((r1 - r0, b, nn), jnp.int32)
+                                     for r0, r1 in word_rows)))
             return k + 1, place(k, tabs, pulled)
 
     def compact(carry):
@@ -356,7 +393,9 @@ def _rebuild_cohort(tabs, word_rows, wds, rels, acc, seg_start):
                                   (jnp.int32(0), tuple(tabs)))
     blocks, tabs = lax.while_loop(lambda carry: more(carry[0]), compact,
                                   (n_full, tabs))
-    return tabs, (n_full * nn + (blocks - n_full) * m) * b
+    # Full blocks are B ranks wide but the last, which ends at `depth`.
+    return tabs, (jnp.minimum(depth, n_full * b) * nn
+                  + (blocks - n_full) * m * b)
 
 
 def deliver(buf, head, tail, alive, entries: Entries, *, n_local: int,

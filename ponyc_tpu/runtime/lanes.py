@@ -150,7 +150,9 @@ def phase_cost_lanes(st: RtState, listed_tgt, drain_facts, nproc_total,
                     work the GC pass marks from);
       - rebuild  += indices the delivery rebuild's gathers read: over
                     the cohorts and the rank blocks each ran (as deep as
-                    its own fullest mailbox of the tick), 8 ranks x the
+                    its own fullest mailbox of the tick), the ranks
+                    that mailbox holds of the block (8, or what is left
+                    of its depth: 1 for a cohort of self-senders) x the
                     cohort's rows a full-width block; 8 ranks x M a
                     compacted one, M = ceil(rows / 8), which a block is
                     from the first whose rows with a message in it fit

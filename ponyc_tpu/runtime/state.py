@@ -153,8 +153,9 @@ def cohort_scope(type_name: str) -> str:
     return f"dispatch/cohort/{type_name}"
 
 
-# Arrival ranks one rebuild block gathers for every actor
-# (delivery.rebuild_tables): a vreg's sublanes, and RuntimeOptions'
+# Arrival ranks one rebuild block gathers for every actor AT MOST (a
+# full block pulls as many as its cohort's fullest mailbox holds of it:
+# delivery.rebuild_tables): a vreg's sublanes, and RuntimeOptions'
 # default `batch` — an actor that keeps taking in more than it drains
 # is under pressure, not in steady state.
 REBUILD_BLOCK = 8

@@ -114,9 +114,10 @@ def test_one_aggregator_under_every_producer(delivery):
         bursts += seen["queued"][0] - (before - drained) >= cap - 16
         # a producer that ran sent itself its next `produce`: the one
         # block Producer's rows ever need, whatever the aggregator took,
-        # as wide as the producers that ran (tests/_rebuild.py)
+        # as wide as the producers that ran and, full width, the ONE
+        # rank deep that any of them holds (tests/_rebuild.py)
         ran = int((seen["sent"] > sent).sum())
-        producer_slots += block_indices(world.p, ran) if ran else 0
+        producer_slots += block_indices(world.p, ran, 1) if ran else 0
         if tick % 16 == 0:
             _conserved(world)
     assert bursts >= 2
@@ -124,7 +125,8 @@ def test_one_aggregator_under_every_producer(delivery):
     # COHORT's rows (ISSUE 36, 39): one aggregator row ever receives, so
     # every block of Aggregator's is a compacted one
     slots = world.rt.profile()["phases"]["rebuild"]
-    blocks, rest = divmod(slots - producer_slots, block_indices(world.a, 1))
+    blocks, rest = divmod(slots - producer_slots,
+                          block_indices(world.a, 1, 8))
     assert rest == 0
     assert blocks >= 6 * bursts               # 48 accepted: 6 blocks of 8
     world.rt.stop()

@@ -249,9 +249,10 @@ def test_profiler_lanes_equal_plans_at_analysis_1(formulation):
     # A block reads over a COHORT's rows, as wide as those with a message
     # in it (tests/_rebuild.py): the first delivery alone took three
     # blocks of the aggregator's one row and one full-width block of the
-    # producers' 20 — and the whole run less than that delivery cost
-    # when all 21 rows went as deep as the aggregator (ISSUE 36)
-    assert (3 * block_indices(1, 1) + block_indices(20, 20)
+    # producers' 20, one rank deep (ISSUE 52) — and the whole run less
+    # than that delivery cost when all 21 rows went as deep as the
+    # aggregator (ISSUE 36)
+    assert (3 * block_indices(1, 1, 8) + block_indices(20, 20, 1)
             <= cost["rebuild"] < 8 * 3 * 21)
 
 

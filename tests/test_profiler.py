@@ -14,7 +14,7 @@ import pytest
 
 import _child
 from _hlo import bare_hlo
-from _rebuild import block_indices
+from _rebuild import block_indices, tick_indices
 from ponyc_tpu import (I32, Ref, Runtime, RuntimeOptions, actor,
                        analysis, behaviour)
 from ponyc_tpu.models import ring
@@ -279,8 +279,9 @@ def test_rebuild_lane_counts_slots_gathered(shards):
     three blocks for the one row of Worker's on the hub's shard, none
     over Leaf's and none elsewhere; later 5 leaves are poked through the
     delivery list (one block of Leaf's rows on each shard that holds one
-    of them, as wide as the leaves it holds there) and send (one block
-    of Worker's); ticks that deliver nothing gather nothing."""
+    of them, as wide as the leaves it holds there and, full width, ONE
+    rank deep: a leaf holds its one poke) and send (one block of
+    Worker's); ticks that deliver nothing gather nothing."""
     rt = Runtime(_opts(mailbox_cap=32, batch=2, analysis=1,
                        inject_slots=32, mesh_shards=shards))
     rt.declare(Worker, 1).declare(Leaf, 20).start()
@@ -292,19 +293,20 @@ def test_rebuild_lane_counts_slots_gathered(shards):
     assert sum(rows.values()) == rt.program.n_local
     rt.bulk_send(leaves, Leaf.poke, np.ones(20, np.int32))
     assert rt.run() == 0
-    hub_blocks = 3
-    assert rt.profile()["phases"]["rebuild"] == (
-        hub_blocks * block_indices(rows["Worker"], 1))
+    hub_slots = tick_indices(rows["Worker"], [20])  # blocks of 8, 8, 4
+    assert rt.profile()["phases"]["rebuild"] == hub_slots == (
+        3 * block_indices(rows["Worker"], 1, 8))
     for leaf in leaves[:5]:
         rt.send(int(leaf), Leaf.poke, 1)
     assert rt.run() == 0
-    hub_blocks += 1
+    hub_slots += tick_indices(rows["Worker"], [5])
     poked = np.bincount([int(leaf) // rt.program.n_local
                          for leaf in leaves[:5]], minlength=shards)
     assert (poked > 0).sum() == min(shards, 5)
-    assert rt.profile()["phases"]["rebuild"] == (
-        hub_blocks * block_indices(rows["Worker"], 1)
-        + sum(block_indices(rows["Leaf"], int(k)) for k in poked if k))
+    assert rt.profile()["phases"]["rebuild"] == hub_slots + sum(
+        block_indices(rows["Leaf"], int(k), 1) for k in poked if k)
+    if shards == 1:         # 5 of 20 rows past M = 3: full width, 1 rank
+        assert block_indices(rows["Leaf"], 5, 1) == 20
     assert rt.state_of(hub)["done"] == 25
     rt.stop()
 
@@ -312,9 +314,9 @@ def test_rebuild_lane_counts_slots_gathered(shards):
 def test_rebuild_lane_falls_where_few_rows_are_deep():
     """A later block reads for the rows that have a message in it: 64
     workers all take a message in one tick and one of them takes 20, so
-    the first block is full width (8 x 64) and the two later ones, with
-    one deep row against M = 8, are compacted: 8 x 8 each, where a full
-    block reads 8 x 64."""
+    the first block is full width (the fullest row's 8 ranks of it x
+    64) and the two later ones, with one deep row against M = 8, are
+    compacted: 8 x 8 each, where a full block of 8 ranks reads 8 x 64."""
     rt = Runtime(_opts(mailbox_cap=32, batch=2, analysis=1))
     rt.declare(Worker, 64).declare(Leaf, 83).start()
     workers = rt.spawn_many(Worker, 64)
@@ -323,7 +325,9 @@ def test_rebuild_lane_falls_where_few_rows_are_deep():
     rt.bulk_send(leaves, Leaf.poke, np.ones(83, np.int32))
     assert rt.run() == 0
     got = rt.profile()["phases"]["rebuild"]
-    assert got == block_indices(64, 64) + 2 * block_indices(64, 1) == 512 + 2 * 64
+    assert got == tick_indices(64, [20] + 63 * [1]) == (
+        block_indices(64, 64, 8) + block_indices(64, 1, 8)
+        + block_indices(64, 1, 4)) == 512 + 2 * 64
     assert got < 3 * 8 * 64
     assert rt.state_of(int(workers[0]))["done"] == 20
     rt.stop()

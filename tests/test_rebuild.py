@@ -1,8 +1,9 @@
 """The mailbox rebuild (delivery.rebuild_tables): arrival ranks gathered
 in blocks, a cohort's tables as deep as that cohort's fullest mailbox of
 the tick, a block as wide as the rows that have a message in it: full
-width while more than an eighth of the cohort's rows are that deep,
-compacted to those rows from then on.
+width while more than an eighth of the cohort's rows are that deep —
+one gather of the cohort's rows a rank that some row holds, not always
+eight —, compacted to those rows from then on.
 
 Most of tier-1 runs rings of 2-8 slots, which take the one-block form;
 these tests drive `deliver()` itself at `mailbox_cap` 16 and 64 against
@@ -17,7 +18,7 @@ import pytest
 from ponyc_tpu.runtime import delivery
 from ponyc_tpu.runtime.delivery import Entries, deliver
 
-from _rebuild import block_indices
+from _rebuild import block_indices, tick_indices
 
 N, E = 24, 320
 LAYOUT = [("Narrow", 0, 16, 2), ("Wide", 16, 24, 4)]   # (type, s0, s1, 1+W)
@@ -46,13 +47,11 @@ def _forms(acc, layout=LAYOUT):
 
 def _slots(acc, layout=LAYOUT):
     """What `rebuild_slots` must read, the indices the rebuild's gathers
-    read: over the blocks each cohort runs, `block_indices` of its rows
-    and of those with a message in the block."""
-    b = delivery.REBUILD_BLOCK
-    return sum(
-        block_indices(s1 - s0, int((acc[s0:s1] > k * b).sum()))
-        for _n, s0, s1, _w in layout
-        for k in range(-(-int(acc[s0:s1].max()) // b)))
+    read: over the blocks each cohort runs, `block_indices` of its rows,
+    of those with a message in the block and of the ranks its fullest
+    row holds there (`tick_indices`)."""
+    return sum(tick_indices(s1 - s0, acc[s0:s1])
+               for _n, s0, s1, _w in layout)
 
 
 def _world(cap, seed, cnt=None, layout=LAYOUT):
@@ -114,6 +113,19 @@ def _deliver(cap, world, *, cosort, tracing, layout=LAYOUT):
         trace_buf={k: i32(v) for k, v in tbuf.items()} if tracing else None)
 
 
+def _same_tables(res, want_buf, want_tbuf, want_tail, tracing):
+    """Every table `deliver` returned is the oracle's, bit for bit."""
+    np.testing.assert_array_equal(res.tail, want_tail)
+    for name in want_buf:
+        np.testing.assert_array_equal(res.buf[name], want_buf[name])
+    if tracing:
+        for name in want_tbuf:
+            np.testing.assert_array_equal(res.trace_buf[name],
+                                          want_tbuf[name])
+    else:
+        assert res.trace_buf == {}
+
+
 @pytest.mark.parametrize("tracing", [False, True], ids=["plain", "traced"])
 @pytest.mark.parametrize("mode", ["plan", "cosort"])
 @pytest.mark.parametrize("cap", [16, 64])
@@ -125,15 +137,7 @@ def test_rebuild_equals_one_by_one_pushes(cap, mode, tracing):
         assert acc[1] == 3                              # space-limited
         res = _deliver(cap, world, cosort=(mode == "cosort"),
                        tracing=tracing)
-        np.testing.assert_array_equal(res.tail, want_tail)
-        for name in want_buf:
-            np.testing.assert_array_equal(res.buf[name], want_buf[name])
-        if tracing:
-            for name in want_tbuf:
-                np.testing.assert_array_equal(res.trace_buf[name],
-                                              want_tbuf[name])
-        else:
-            assert res.trace_buf == {}
+        _same_tables(res, want_buf, want_tbuf, want_tail, tracing)
         assert int(res.n_delivered) == acc.sum()
         # Narrow (actor 0) ran every block, Wide what its own took.
         assert int(res.rebuild_slots) == _slots(acc)
@@ -162,12 +166,7 @@ def test_rebuild_depth_is_the_cohorts(deep, mode, tracing):
     want_buf, want_tbuf, want_tail, acc = _oracle(cap, *world)
     assert (acc[:16].max(), acc[16:].max()) == DEPTHS[deep]
     res = _deliver(cap, world, cosort=(mode == "cosort"), tracing=tracing)
-    np.testing.assert_array_equal(res.tail, want_tail)
-    for name in want_buf:
-        np.testing.assert_array_equal(res.buf[name], want_buf[name])
-        if tracing:
-            np.testing.assert_array_equal(res.trace_buf[name],
-                                          want_tbuf[name])
+    _same_tables(res, want_buf, want_tbuf, want_tail, tracing)
     assert int(res.rebuild_slots) == _slots(acc)
 
 
@@ -253,14 +252,40 @@ def test_rebuild_block_is_as_wide_as_its_rows(form, cap, mode, tracing):
     assert _forms(acc, layout) == want
     res = _deliver(cap, world, cosort=(mode == "cosort"), tracing=tracing,
                    layout=layout)
-    np.testing.assert_array_equal(res.tail, want_tail)
-    for name in want_buf:
-        np.testing.assert_array_equal(res.buf[name], want_buf[name])
-        if tracing:
-            np.testing.assert_array_equal(res.trace_buf[name],
-                                          want_tbuf[name])
+    _same_tables(res, want_buf, want_tbuf, want_tail, tracing)
     assert int(res.n_delivered) == acc.sum()
     assert int(res.rebuild_slots) == _slots(acc, layout)
+
+
+# The cohort's fullest mailbox of the tick: a first full block at every
+# width, and (9, 13) a second full block narrower than the first.
+FULLEST = [1, 2, 5, 8, 9, 13]
+
+
+@pytest.mark.parametrize("tracing", [False, True], ids=["plain", "traced"])
+@pytest.mark.parametrize("cap", [16, 64])
+@pytest.mark.parametrize("most", FULLEST)
+def test_full_block_pulls_the_ranks_its_fullest_mailbox_holds(most, cap,
+                                                              tracing):
+    """Every block of both cohorts runs FULL WIDTH (more rows than M
+    are as deep as the fullest) and pulls min(8, most - 8k) ranks, one
+    gather of the cohort's rows each: the tables are the one-by-one
+    oracle's bit for bit, and `rebuild_slots` is `most` x the rows (the
+    dead row 5 among them) — a rank no row holds is not fetched."""
+    cnt = np.zeros(N, int)
+    cnt[:16] = 1 + np.arange(16) % most
+    cnt[[2, 9, 14]] = most              # Narrow: 3 rows that deep, M = 2
+    cnt[16:] = 1
+    cnt[[17, 22]] = most                # Wide: 2 rows, M = 1
+    buf, tbuf, head, _tail, alive, tgt, words = _world(cap, 4, cnt=cnt)
+    world = (buf, tbuf, head, head.copy(), alive, tgt, words)
+    want_buf, want_tbuf, want_tail, acc = _oracle(cap, *world)
+    assert (acc[:16].max(), acc[16:].max()) == (most, most)
+    assert _forms(acc) == ["F" * -(-most // 8)] * 2
+    res = _deliver(cap, world, cosort=False, tracing=tracing)
+    _same_tables(res, want_buf, want_tbuf, want_tail, tracing)
+    assert int(res.n_delivered) == acc.sum()
+    assert int(res.rebuild_slots) == _slots(acc) == most * N
 
 
 def test_rebuild_runs_as_many_blocks_as_the_fullest_mailbox():
@@ -283,16 +308,21 @@ def test_rebuild_runs_as_many_blocks_as_the_fullest_mailbox():
         assert int(res.n_delivered) == sent + (sent > 0)
 
 
+def _deliver_jaxpr(cap, layout):
+    """`deliver`'s jaxpr over a world of `layout`, tracing on."""
+    world = _world(cap, 0, layout=layout)
+    return jax.make_jaxpr(
+        lambda: _deliver(cap, world, cosort=False, tracing=True,
+                         layout=layout))()
+
+
 def _rebuild_eqns(cap, layout=LAYOUT, inherit=True,
                   scope="pony/delivery/rebuild"):
     """Primitive names (a jitted helper's own name for `jit`) of every
     equation under `scope` in `deliver`'s jaxpr, sub-jaxprs included;
     with `inherit` off, only of those whose OWN name stack holds the
     scope."""
-    world = _world(cap, 0, layout=layout)
-    jaxpr = jax.make_jaxpr(
-        lambda: _deliver(cap, world, cosort=False, tracing=True,
-                         layout=layout))()
+    jaxpr = _deliver_jaxpr(cap, layout)
     names = []
 
     def walk(jp, inherited):
@@ -327,23 +357,27 @@ def test_deep_ring_has_two_loops_a_cohort(layout):
     """A ring deeper than a block: one depth (`max(acc)` over the
     cohort's rows) and two loops in turn a COHORT, the full-width
     blocks' and the compacted blocks' — no `cond` round the tables. A
-    full block is one gather a table; a compacted one a sort of the deep
+    full block holds the one loop more, over the ranks its fullest row
+    holds: one gather a table a rank; a compacted one a sort of the deep
     rows, one (short) gather a table and ONE scatter back to the table's
     lanes for the cohort, its trace side lanes inside the same loops."""
     # buf + trace_buf, in the body at each length
     cohorts, tables = LENGTHS * len(layout), LENGTHS * 2 * len(layout)
     b = delivery.REBUILD_BLOCK
     deep = _rebuild_eqns(16, layout)
-    assert deep.count("while") == 2 * cohorts
+    assert deep.count("while") == 3 * cohorts
     assert "cond" not in deep
     assert deep.count("reduce_max") == cohorts
     # The full loop's test counts the rows that deep; nothing else sums.
     assert deep.count("reduce_sum") == cohorts
     assert deep.count("_take") == 2 * tables
-    # The selects of both loops and the deep rows' `where`; every
-    # `_take` and the `%` of `rels` hold one each.
+    # A rank's plane into the block's pull, nowhere else.
+    assert deep.count("dynamic_update_slice") == tables
+    # The selects of both loops and the deep rows' `where`; the
+    # compacted blocks' `_take`s and the `%` of `rels` hold one each
+    # (a rank's `_take` is told its indices are in range: no fill).
     assert deep.count("_where") == (2 * tables * b + cohorts
-                                    + 2 * tables + LENGTHS)
+                                    + tables + LENGTHS)
     # Every loop's body writes its scope itself (a body is a computation
     # of its own): its gathers are named without their loop's help, the
     # compacted blocks' one scope down.
@@ -354,3 +388,140 @@ def test_deep_ring_has_two_loops_a_cohort(layout):
     assert compact.count("_take") == tables
     assert compact.count("sort") == compact.count("scatter") == cohorts
     assert "while" not in compact
+
+
+def _rank_loops(layout):
+    """The `while` equations of `deliver`'s jaxpr that sit inside
+    another `while` of the rebuild: the full block's loop over its
+    ranks, one a cohort a length."""
+    jaxpr = _deliver_jaxpr(16, layout)
+    found = []
+
+    def walk(jp, in_block):
+        for eqn in jp.eqns:
+            block = (eqn.primitive.name == "while" and "pony/delivery/rebuild"
+                     in str(eqn.source_info.name_stack))
+            if block and in_block:
+                found.append(eqn)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, in_block or block)
+    walk(jaxpr.jaxpr, False)
+    return found
+
+
+@pytest.mark.parametrize("layout", [ONE, LAYOUT], ids=["one", "two"])
+def test_full_block_gathers_one_rank_of_its_rows_at_a_time(layout):
+    """The loop inside a full block: its body one gather a table, of the
+    cohort's ROWS indices (not 8 x rows), written as one plane of the
+    block's pull, with no select by an out-of-range mask (the indices
+    are in range as they stand and the gather is told so: the mask's
+    compare, copy and select a rank cost the one-chip ubench window its
+    compacted pull's `S(1)` table, PERF.md §6, PR 52); and every
+    equation of the body and of the test names `pony/delivery/rebuild`
+    ITSELF — a loop's computations carry no scope of their own, and
+    `mailbox_rebuild_ms` reads by that name."""
+    loops = _rank_loops(layout)
+    assert len(loops) == LENGTHS * len(layout)
+    rows = sorted(LENGTHS * [s1 - s0 for _n, s0, s1, _w in layout])
+    seen = []
+    for loop in loops:
+        test, body = (loop.params["cond_jaxpr"].jaxpr,
+                      loop.params["body_jaxpr"].jaxpr)
+        for eqn in (*test.eqns, *body.eqns):
+            stack = str(eqn.source_info.name_stack)
+            assert stack.endswith("pony/delivery/rebuild") or (
+                "pony/delivery/rebuild/jit(" in stack), (eqn, stack)
+        assert [e.primitive.name for e in test.eqns] == ["lt"]
+        takes = [e for e in body.eqns if e.primitive.name == "jit"
+                 and e.params["name"] == "_take"]
+        writes = [e for e in body.eqns
+                  if e.primitive.name == "dynamic_update_slice"]
+        assert len(takes) == len(writes) == 2      # mailbox + side lanes
+        for take in takes:      # told in range: a gather, no fill's select
+            inner = [e.primitive.name for sub in jax.core.jaxprs_in_params(
+                take.params) for e in sub.eqns]
+            assert "gather" in inner and "select_n" not in inner, inner
+        (nn,) = {e.outvars[0].aval.shape[1] for e in takes}
+        assert {e.outvars[0].aval.shape[1:] for e in writes} == {
+            (delivery.REBUILD_BLOCK, nn)}
+        # ... and nothing else by the list: a table's word rows are cut
+        # from it once a block, outside (a cohort narrower than the
+        # records copied its rows of the whole list a rank: the bank's
+        # tellers, 64 ranks a tick, 14 ms).
+        assert not any(e.primitive.name in ("while", "cond", "sort",
+                                            "scatter", "slice", "squeeze")
+                       for e in body.eqns)
+        seen.append(nn)
+    assert sorted(seen) == rows
+
+
+# `deliver` alone, compiled for a described v5e (no chip: libtpu's
+# compiler, in a child: tests/_hlo.py) at a ring of two blocks: what the
+# chip would run for the rebuild, read off the program's own symbol
+# table (costs.hlo_symbols).
+FOR_THE_CHIP = """
+sys.path.insert(0, {tests!r})
+import re
+import _hlo
+from ponyc_tpu import costs
+from ponyc_tpu.runtime.delivery import Entries, deliver
+from ponyc_tpu.runtime.state import phase_scope
+n, e, cap, w1 = {n}, {e}, 16, 2
+def fn(buf, head, tail, tgt, sender, words, key, perm, bounds):
+    with phase_scope("delivery"):
+        return deliver(
+            {{"A": buf}}, head, tail, head >= 0, Entries(tgt, sender, words),
+            n_local=n, mailbox_cap=cap, spill_cap=4096, overload_occ=12,
+            shard_base=jnp.int32(0), cohort_layout=[("A", 0, n, w1)],
+            plan=(key, perm, bounds))
+args = (arg(cap, w1, n), arg(n), arg(n), arg(e), arg(e), arg(w1, e),
+        arg(e), arg(e), arg(n + 1))
+def report(text):
+    rows = costs.hlo_symbols(text)
+    conds = [_hlo.branch_ops(text, scope) for scope in (
+        "pony/delivery/cond", "pony/delivery/rebuild/cond",
+        "pony/delivery/rebuild/while/body/cond")]
+    # a table-shaped copy in a branch or a loop's body (the entry's own
+    # is of the argument, which this `jit` does not donate)
+    copies, entry = 0, False
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            entry = line.startswith("ENTRY")
+        copies += (not entry) and bool(re.search(
+            r"= s32\\[%d,%d,%d\\][^ ]* copy\\(" % (cap, w1, n), line))
+    return dict(
+        gathers=[(r["scope"], r["how"], r["index_count"]) for r in rows
+                 if r["kind"] == "gather"
+                 and (r["scope"] or "").startswith("delivery/rebuild")],
+        unnamed_loops=[r["name"] for r in rows if r["opcode"] == "while"
+                       and r["scope"] is None],
+        rebuild_conds=sum(len(c) for c in conds[1:]),
+        table_writers=[sum(any(op == "scatter" for op, _d in branch)
+                           for branch in cond) for cond in conds[0]],
+        table_copies=copies)
+"""
+CHIP_N = 1 << 12
+CHIP_E = 8 * CHIP_N + 2 * 4096 + 8          # ubench-like, 4,096 rows
+
+
+def test_for_the_chip_a_full_block_gathers_its_rows_a_rank():
+    """What the chip runs: the full block's gather has `rows` indices at
+    either length (the parent's had 8 x rows), the compacted block's
+    8 x M = rows, each named by its own `op_name`; no loop without a
+    phase; no conditional inside the rebuild, and of the two round it
+    (`deliver`'s choice of length) one branch each that rebuilds (told
+    by the compacted block's scatter), so none copies the mailbox table
+    to write it."""
+    import os
+
+    import _hlo
+    seen = _hlo.v5e_counts(FOR_THE_CHIP.format(
+        tests=os.path.dirname(os.path.abspath(__file__)),
+        n=CHIP_N, e=CHIP_E))
+    assert sorted(map(tuple, seen["gathers"])) == sorted(
+        LENGTHS * [("delivery/rebuild", "own", CHIP_N),
+                   ("delivery/rebuild/compact", "own", CHIP_N)])
+    assert seen["unnamed_loops"] == []
+    assert seen["rebuild_conds"] == 0
+    assert seen["table_writers"] == [1] * LENGTHS
+    assert seen["table_copies"] == 0
