@@ -293,17 +293,22 @@ def actor(cls):
     return ActorTypeMeta(cls.__name__, (Actor,), ns)
 
 
-def _heap_scoped(method):
+def _heap_scoped(kind: str):
     """One blob op of a behaviour: its handle checks, gathers and
-    scatters on the pool carry the device scope `pony/dispatch/heap`
-    (runtime.state.STEP_SCOPES; metadata only), so a trace names the
-    heap's share of the dispatch."""
-    @functools.wraps(method)
-    def scoped(self, *args, **kwargs):
-        from .runtime.state import phase_scope
-        with phase_scope("dispatch/heap"):
-            return method(self, *args, **kwargs)
-    return scoped
+    scatters on the pool carry the device scope `pony/dispatch/heap/
+    <kind>` — `get` (a word's or the length's read), `set`, `alloc`
+    (with its zeroing) or `free` (runtime.state.STEP_SCOPES; metadata
+    only) —, so a trace names the heap's share of the dispatch, which
+    is what lies at and below `dispatch/heap`, and splits it by what
+    the behaviour asked for."""
+    def scope(method):
+        @functools.wraps(method)
+        def scoped(self, *args, **kwargs):
+            from .runtime.state import phase_scope
+            with phase_scope(f"dispatch/heap/{kind}"):
+                return method(self, *args, **kwargs)
+        return scoped
+    return scope
 
 
 # One word an index into the flat pool (lax.scatter, not `.at[]`, which
@@ -815,7 +820,7 @@ class Context:
                 f"capability: use-after-move — blob handle already moved "
                 f"by {prev} is passed to {what}")
 
-    @_heap_scoped
+    @_heap_scoped("alloc")
     def blob_alloc(self, length=None, when=True):
         """Claim a fresh device blob; returns its handle ([lanes] i32,
         -1 where `when` is false or the pool had no free slot — the
@@ -870,7 +875,7 @@ class Context:
         self.cap_types.tag(h2, "iso")
         return h2
 
-    @_heap_scoped
+    @_heap_scoped("get")
     def blob_get(self, h, i):
         """Read word `i` of blob `h` ([lanes] i32; 0 for null/-1 handles,
         out-of-range words, or handles owned by another shard). Floats:
@@ -883,7 +888,7 @@ class Context:
         ok = ok & (i >= 0) & (i < b.words)
         return jnp.take(b.data, b.at(i, hl, ok), mode="fill", fill_value=0)
 
-    @_heap_scoped
+    @_heap_scoped("get")
     def blob_length(self, h):
         """Logical word count recorded at blob_alloc ([lanes] i32; 0 for
         null/remote handles)."""
@@ -908,7 +913,7 @@ class Context:
         self.cap_types.tag(h, "val")
         return h
 
-    @_heap_scoped
+    @_heap_scoped("set")
     def blob_set(self, h, i, v, when=True):
         """Write word `i` of blob `h` (i32; masked by `when`). Only the
         owner holds the handle (iso), so lanes never collide; writes are
@@ -937,7 +942,7 @@ class Context:
             b.data, key[:, None], v, _WORD_SCATTER, indices_are_sorted=True,
             unique_indices=True, mode=lax.GatherScatterMode.FILL_OR_DROP)
 
-    @_heap_scoped
+    @_heap_scoped("free")
     def blob_free(self, h, when=True):
         """Release blob `h` back to the pool. Explicit free is the fast
         path; blobs whose owner died (or whose handle moved off-shard)

@@ -49,7 +49,8 @@ from . import lanes, mute, route, spawn
 from .delivery import Entries
 from .gc import build_blob_arg_mask
 from .state import (PhaseCursor, RtState, TickStatic, cohort_scope,
-                    counts_prefix, phase_scope, ring_take, rows_of)
+                    counts_pool, counts_prefix, phase_scope, ring_take,
+                    rows_of)
 
 
 # Selects a cohort's drain may unroll: `batch` ring_takes of `cap - 1`
@@ -120,6 +121,13 @@ class StepAux(NamedTuple):
     #   may hold two (state.counts_prefix; {} elsewhere: no leaf).
     #   "n_prefix" int32 — *cumulative* shard-ticks delivered over the
     #   list's prefix (the state's route_counts["n_prefix"], mesh-wide).
+    pool: dict = {}              # the blob pool's books, where a cohort
+    #   can allocate or free in the window (state.counts_pool; {}
+    #   elsewhere: no leaf). "alloc", "free" int32 — *cumulative* slots
+    #   claimed and released (the state's n_blob_alloc / n_blob_free,
+    #   mesh-wide; the host's stores and frees between windows count,
+    #   and a payload moved over the mesh is one of each): their
+    #   difference is the slots in use at the window's end.
 
 
 def _bcast_lanes(v, dtype, lanes: int):
@@ -533,14 +541,17 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
             rblob_over = None
             if blb is not None:
                 rt_b = blob["resv"]
-                rblob = jnp.full(rt_b.shape[1:], -1, jnp.int32)
-                for d in range(rt_b.shape[0]):
-                    rblob = jnp.where((bused_c == d)[None, :], rt_b[d],
-                                      rblob)
-                # Lanes whose window was withheld for BUDGET (allocating
-                # dispatch count past BLOB_DISPATCHES) — an alloc failure
-                # there blames the budget knob, not the pool size.
-                rblob_over = bused_c >= rt_b.shape[0]
+                with phase_scope("dispatch/heap/reserve",
+                                 when=cohort.blob_sites > 0):
+                    rblob = jnp.full(rt_b.shape[1:], -1, jnp.int32)
+                    for d in range(rt_b.shape[0]):
+                        rblob = jnp.where((bused_c == d)[None, :], rt_b[d],
+                                          rblob)
+                    # Lanes whose window was withheld for BUDGET
+                    # (allocating dispatch count past BLOB_DISPATCHES) —
+                    # an alloc failure there blames the budget knob, not
+                    # the pool size.
+                    rblob_over = bused_c >= rt_b.shape[0]
             # Hand one dispatch-worth of spawn reservations to this batch
             # slot: a `used` counter walks the SPAWN_DISPATCHES axis;
             # exhausted budget yields -1 refs (→ sticky spawn_fail,
@@ -991,6 +1002,10 @@ def vote(k: TickStatic, st: RtState, w, d, n_spawned, r, life, m, qw_hist2,
     # which list delivery ran over, where the state counts it
     lists = {name: st.route_counts[name][0] + r.counts[name]
              for name in ("n_prefix",) if name in r.counts}
+    # the pool's books, where the window can move them
+    books = ({"alloc": st.n_blob_alloc[0] + pool.n_alloc,
+              "free": st.n_blob_free[0] + pool.n_free}
+             if counts_pool(k.program) else {})
     (occ_sum, occ_max, n_muted_now, n_over_now, nrej_all, nbad_all,
      ndl_all, nmut_all, qw_p99) = lanes.vote_lanes(
         k, occ_after, m.muted, counts, qw_hist2)
@@ -1030,8 +1045,11 @@ def vote(k: TickStatic, st: RtState, w, d, n_spawned, r, life, m, qw_hist2,
             st.n_delivered[0] + res.n_delivered,
             occ_sum, n_muted_now, n_over_now,
             nrej_all, nbad_all, ndl_all, nmut_all,
-            i32c(pool.fail), i32c(pool.budget), *lists.values()]), "actors")
+            i32c(pool.fail), i32c(pool.budget), *lists.values(),
+            *books.values()]), "actors")
         lists = dict(zip(lists, summed[nf + 11:]))
+        if books:
+            books = dict(zip(books, summed[nf + 11 + len(lists):]))
         *facts, any_overloaded_all = (summed[i] > 0 for i in range(nf))
         nproc_all, ndel_all = summed[nf], summed[nf + 1]
         blob_fail_any, blob_budget_any = (summed[nf + 9] > 0,
@@ -1078,6 +1096,7 @@ def vote(k: TickStatic, st: RtState, w, d, n_spawned, r, life, m, qw_hist2,
         qw_p99=qw_p99,
         spawn=spawn_aux,
         lists=lists,
+        pool=books,
     )
     return aux, counts, overflow, wb_new
 
@@ -1362,6 +1381,8 @@ def zero_aux(program: Optional[Program] = None) -> StepAux:
                else {}),
         lists=({"n_prefix": i32(0)}
                if program is not None and counts_prefix(program) else {}),
+        pool=({"alloc": i32(0), "free": i32(0)}
+              if program is not None and counts_pool(program) else {}),
         device_pending=b(True), host_pending=b(False),
         any_muted=b(False),
         exit_flag=b(False), exit_code=i32(0),

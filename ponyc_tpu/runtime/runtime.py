@@ -338,6 +338,11 @@ class Runtime:
         # asked for (one pass an aux: a gated-out window hands the same
         # aux back).
         self._free_rows_low = 2**31 - 1
+        # The pool's books as the windows' aux brought them (StepAux.
+        # pool): slots claimed and released up to the last retired
+        # window, Python integers summed from mod-2^32 differences (the
+        # device's own count stands at the first window).
+        self._pool_books: Dict[str, int] = {}
         self._row_gc_step = -1
         self._spawned_at_gc = 0     # device spawns when the last pass ran
         self._row_bytes = None      # see _spawned_bytes
@@ -1590,6 +1595,9 @@ class Runtime:
             last = self._last_counters.get(key, 0)
             self.totals[key] += (cur - last) & 0xFFFFFFFF
             self._last_counters[key] = cur
+        for key, leaf in a.pool.items():    # cumulative, as above
+            have = self._pool_books.get(key, 0)
+            self._pool_books[key] = have + ((int(leaf) - have) & 0xFFFFFFFF)
         self._rl_windows += 1
         self._rl_gap_ns += win["gap_ns"]
         # Where this window's wall clock went. The records of
@@ -2037,8 +2045,13 @@ class Runtime:
         actors are the device's `n_spawned` / `n_collected`, counter()),
         `free_rows_low` (a program with device spawns: the least free
         rows, net of the next tick's reservations, any tick has left;
-        None before the first window), the window-length histogram (power-of-two buckets) and the
-        controller snapshot."""
+        None before the first window), `pool` (a program whose window
+        can allocate or free blobs, state.counts_pool: the slots claimed
+        and released up to the last retired window, `allocs` / `frees`,
+        and their difference `blobs_in_use` at its end — read with the
+        window's aux, no fetch of their own; None elsewhere and before
+        the first window), the window-length histogram (power-of-two
+        buckets) and the controller snapshot."""
         n = max(1, self._rl_windows)
         return {
             "windows": self._rl_windows,
@@ -2056,6 +2069,11 @@ class Runtime:
             "gc_iters": self.totals["gc_iters"],
             "free_rows_low": (self._free_rows_low
                               if self._free_rows_low < 2**31 - 1 else None),
+            "pool": ({"allocs": self._pool_books["alloc"],
+                      "frees": self._pool_books["free"],
+                      "blobs_in_use": (self._pool_books["alloc"]
+                                       - self._pool_books["free"])}
+                     if self._pool_books else None),
             "window_hist": [int(x) for x in self._win_hist],
             "controller": (self._controller.snapshot()
                            if self._controller is not None else None),

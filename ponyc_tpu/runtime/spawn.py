@@ -108,17 +108,20 @@ def reserve(k: TickStatic, st: RtState, w, muted) -> Reserved:
         # and no window is READ unless an allocating cohort
         # dispatches — so skip the sort when none has queued work.
         alloc_busy = jnp.bool_(False)
+        allocating = False
         for _ch in program.device_cohorts:
             if _ch.blob_sites and _ch.blob_dispatches:
+                allocating = True
                 _sl = slice(_ch.local_start, _ch.local_stop)
                 alloc_busy = alloc_busy | jnp.any(
                     runnable[_sl] & (w.occ0[_sl] > 0))
 
         def _compact_free(_):
-            bperm, bvfree, _n = compact_mask(~st.blob_used, bsl)
-            return jnp.where(bvfree,
-                             bbase + bperm.astype(jnp.int32),
-                             jnp.int32(-1))
+            with phase_scope("dispatch/heap/reserve", when=allocating):
+                bperm, bvfree, _n = compact_mask(~st.blob_used, bsl)
+                return jnp.where(bvfree,
+                                 bbase + bperm.astype(jnp.int32),
+                                 jnp.int32(-1))
         free_blob = lax.cond(
             alloc_busy, _compact_free,
             lambda _: jnp.full((bsl,), -1, jnp.int32), operand=None)
@@ -166,12 +169,13 @@ def cohort_blob_resv(ch, rs: Reserved):
     bd = ch.blob_dispatches
     if not sites:
         return jnp.zeros((bd, 0, ch.local_capacity), jnp.int32)
-    run_c = rs.runnable[ch.local_start:ch.local_stop]
-    rank = jnp.cumsum(run_c.astype(jnp.int32)) - 1
-    handles = jnp.take(
-        rs.pool.free, _windows(ch.blob_offset, run_c, rank, bd, sites),
-        mode="fill", fill_value=-1)
-    return jnp.where(run_c[None, None, :], handles, jnp.int32(-1))
+    with phase_scope("dispatch/heap/reserve"):
+        run_c = rs.runnable[ch.local_start:ch.local_stop]
+        rank = jnp.cumsum(run_c.astype(jnp.int32)) - 1
+        handles = jnp.take(
+            rs.pool.free, _windows(ch.blob_offset, run_c, rank, bd, sites),
+            mode="fill", fill_value=-1)
+        return jnp.where(run_c[None, None, :], handles, jnp.int32(-1))
 
 
 def claim(k: TickStatic, st: RtState, w, d) -> Claimed:

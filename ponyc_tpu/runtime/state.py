@@ -43,6 +43,7 @@ acceptable for now, and noted here deliberately.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import re
 from typing import Any, Dict
@@ -51,6 +52,7 @@ import jax
 import jax.numpy as jnp
 
 from ..config import RuntimeOptions
+from ..ops import pack
 from ..program import Program
 
 # Queue-wait histogram geometry (the profiler, lanes.profile_lanes):
@@ -86,6 +88,14 @@ N_PHASES = len(PHASE_NAMES)
 # is the same program (tests/test_profiler.py).
 SCOPE_PREFIX = "pony"
 STEP_SCOPES = ("unmute", "spawn", "drain", "dispatch", "dispatch/heap",
+               # below `dispatch/heap` (a handle checked once a dispatch
+               # keeps the bare scope: engine, `pinned`): what a
+               # behaviour asked of the pool (api._heap_scoped), and the
+               # engine's share — the free list's compaction and the
+               # windows handed to the allocating dispatches
+               "dispatch/heap/get", "dispatch/heap/set",
+               "dispatch/heap/alloc", "dispatch/heap/free",
+               "dispatch/heap/reserve",
                "route",
                # a mesh only (route._route): the one sort by
                # destination shard that carries the entries, a
@@ -137,10 +147,15 @@ def scope_of(op_name):
     return "/".join(path) or None
 
 
-def phase_scope(path: str):
+def phase_scope(path: str, when: bool = True):
     """Context manager: the traced operations inside belong to phase
     `path` (one of STEP_SCOPES, a `cohort_scope`, or `analysis` for the
-    opt-in lanes)."""
+    opt-in lanes). Not `when` a static fact says the operations inside
+    are dead in this program (the pool's reservations in a world that
+    allocates nothing): a name no compiled operation carries would only
+    show in the lowered text."""
+    if not when:
+        return contextlib.nullcontext()
     return _named_scope(f"{SCOPE_PREFIX}/{path}")
 
 
@@ -547,6 +562,19 @@ def counts_prefix(program: Program) -> bool:
     4d) and stays the program it was."""
     cap = rows_of(program, "mailbox_cap")
     return not (isinstance(cap, int) and cap <= REBUILD_BLOCK)
+
+
+def counts_pool(program: Program) -> bool:
+    """Whether the window's aux carries the pool's books (engine.
+    StepAux.pool): a pool some device cohort can allocate in or free a
+    message's payload from — it declares MAX_BLOBS, or a behaviour of
+    it takes a Blob argument. A pool whose handles are state fields set
+    at build time alone (GUPS's table) has no such cohort, and its
+    window stays the program it was."""
+    return program.opts.blob_slots > 0 and any(
+        ch.blob_sites or any(pack.is_blob(s) for b in ch.behaviours
+                             for s in b.arg_specs)
+        for ch in program.device_cohorts)
 
 
 def list_counters(program: Program) -> tuple:

@@ -184,7 +184,7 @@ def test_heap_scope_is_named_and_is_metadata_only(delivery, monkeypatch):
     assert "dispatch/heap" in state.STEP_SCOPES
     lowered = _lowered_window(delivery)
     text = lowered.as_text(debug_info=True)
-    for op in ("jit(_take", "scatter"):           # blob_get, blob_set
+    for op in ("get/jit(_take", "set/scatter"):   # blob_get, blob_set
         assert f"pony/dispatch/heap/{op}" in text, op
     scoped = lowered.compile().as_text()
     assert re.search(r'op_name="[^"]*/pony/dispatch/[^"]*/pony/dispatch/heap/',
@@ -227,6 +227,6 @@ def test_the_heaps_write_is_a_sorted_unique_scatter(world):
         assert "indices_are_sorted = true" in attrs \
             and "unique_indices = true" in attrs, attrs
         assert types == f"{pool}, tensor<64x1xui32>, tensor<64xi32>"
-    assert "pony/dispatch/heap/sort" in text
-    assert "pony/dispatch/heap/cond" in text      # the breach of iso
+    assert "pony/dispatch/heap/set/sort" in text
+    assert "pony/dispatch/heap/set/cond" in text  # the breach of iso
 

@@ -584,11 +584,15 @@ def test_phase_scopes_name_the_lowered_window(delivery, cap):
     rt, lowered = _lowered_window(delivery, cap)
     text = lowered.as_text(debug_info=True)
     # `dispatch/heap` names the blob pool's operations: a blob-free
-    # world has none (tests/test_gups.py holds the world that has);
+    # world has none (tests/test_gups.py holds the world that has, and
+    # tests/test_taskbench_payload.py the five scopes below it);
     # `spawn/*` a world whose behaviours create actors, `gc_mark/*` the
     # collector's own program (tests/test_spreader.py holds both),
     # `route/*` a mesh's window (tests/test_mesh_ubench.py holds it)
-    elsewhere = ("gc_mark", "dispatch/heap", "spawn/free", "spawn/reserve",
+    elsewhere = ("gc_mark", "dispatch/heap", "dispatch/heap/get",
+                 "dispatch/heap/set", "dispatch/heap/alloc",
+                 "dispatch/heap/free", "dispatch/heap/reserve",
+                 "spawn/free", "spawn/reserve",
                  "spawn/claim", "gc_mark/roots", "gc_mark/hop",
                  "gc_mark/sweep", "route/sort", "route/bucket",
                  "route/exchange", "route/spill", "route/spill/lookup",

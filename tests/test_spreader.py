@@ -287,8 +287,9 @@ def test_spawn_scopes_and_the_aux_leaves_exist_only_where_actors_spawn():
         mailbox_cap=4, batch=2, max_sends=1, msg_words=1, spill_cap=64,
         inject_slots=8, **QUIET), pings=2)
     plain = engine.zero_aux(rt.program)
-    assert plain.spawn == plain.lists == {} and plain == engine.zero_aux()
-    assert len(jax.tree.leaves(plain)) == len(engine.StepAux._fields) - 2
+    assert plain.spawn == plain.lists == plain.pool == {}
+    assert plain == engine.zero_aux()
+    assert len(jax.tree.leaves(plain)) == len(engine.StepAux._fields) - 3
     text = _lowered(rt).as_text(debug_info=True)
     for scope in ("spawn/free", "spawn/reserve", "spawn/claim"):
         assert f"{SCOPE_PREFIX}/{scope}/" not in text
