@@ -455,7 +455,11 @@ class Analysis:
                              f" qw_p99={cinf['queue_wait_p99']}"
                              f" mute_ticks={cinf['mute_ticks']}"
                              f" pinned_handles="
-                             f"{','.join(cinf['pinned_handles']) or '-'}")
+                             f"{','.join(cinf['pinned_handles']) or '-'}"
+                             " born_full=" + "/".join(
+                                 str(cinf["born_full"][k]) for k in (
+                                     "allocs", "sets_folded",
+                                     "sets_alone")))
                 lines.append(
                     f"  cohort {cohort.atype.__name__}: "
                     f"cap={cohort.capacity} queued={int(co.sum())} "

@@ -40,6 +40,7 @@ import inspect
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from .ops import pack
@@ -296,9 +297,11 @@ def actor(cls):
 def _heap_scoped(kind: str):
     """One blob op of a behaviour: its handle checks, gathers and
     scatters on the pool carry the device scope `pony/dispatch/heap/
-    <kind>` — `get` (a word's or the length's read), `set`, `alloc`
-    (with its zeroing) or `free` (runtime.state.STEP_SCOPES; metadata
-    only) —, so a trace names the heap's share of the dispatch, which
+    <kind>` — `get` (a word's or the length's read), `set` (with a
+    fresh payload's column, whichever op flushes it:
+    BlobPoolView.flush), `alloc` (the slot's books) or `free`
+    (runtime.state.STEP_SCOPES; metadata only) —, so a trace names the
+    heap's share of the dispatch, which
     is what lies at and below `dispatch/heap`, and splits it by what
     the behaviour asked for."""
     def scope(method):
@@ -318,6 +321,51 @@ _WORD_SCATTER = lax.ScatterDimensionNumbers(
     scatter_dims_to_operand_dims=(0,))
 
 
+# One column (every word of a slot) an index into the pool seen as
+# [W, nslots]: the updates are [W, lanes], window on dimension 0.
+_COLUMN_SCATTER = lax.ScatterDimensionNumbers(
+    update_window_dims=(0,), inserted_window_dims=(1,),
+    scatter_dims_to_operand_dims=(1,))
+
+
+def _ascending(key, carried, end, past):
+    """(key, carried) sorted by key, STRICTLY ascending — what a scatter
+    flagged sorted and unique may be handed. Keys below `end` write and
+    are distinct in an honest program; the others are dropped and were
+    keyed from `past` (each its own, `end` or more). A breach shows
+    after the sort as two equal neighbours: the first keeps its key,
+    the others take `past`'s and the vector is sorted once more —
+    behind a cond, so an honest program pays one compare."""
+    key, carried = lax.sort((key, carried), num_keys=1, is_stable=True)
+    dup = jnp.concatenate(
+        [jnp.zeros((1,), jnp.bool_), key[1:] == key[:-1]])
+
+    def once_more():
+        return tuple(lax.sort(
+            (jnp.where(dup | (key >= end), past, key), carried),
+            num_keys=1, is_stable=True))
+
+    return lax.cond(jnp.any(dup), once_more, lambda: (key, carried))
+
+
+class _Column:
+    """A payload between its blob_alloc and its flush (BlobPoolView):
+    the handle object the alloc returned, the alloc's own local slot
+    `idx` (nslots where it did not allocate) and mask `ok`, and the W
+    words of every lane as they stand, `rows` — zeros until a blob_set
+    of a static word lands. `checked`: the handle's generation and used
+    flag as blob_set reads them, gathered at the first set (while the
+    column is open nothing can change either: an alloc writes other
+    slots, a free flushes first)."""
+
+    __slots__ = ("handle", "idx", "ok", "rows", "checked")
+
+    def __init__(self, handle, idx, ok, words):
+        self.handle, self.idx, self.ok = handle, idx, ok
+        self.rows = [jnp.zeros(ok.shape, jnp.int32)] * words
+        self.checked = None
+
+
 class BlobPoolView:
     """Trace-time working view of the device blob pool for ONE behaviour
     evaluation (see ops.pack.Blob; pool arrays live in runtime.state).
@@ -330,6 +378,31 @@ class BlobPoolView:
     behaviours are disjoint, sequential application across branches is
     exact — no cross-branch selects, and reads observe this dispatch's
     own earlier writes (read-your-writes).
+
+    One kind of write waits: **a payload is born full.** blob_alloc
+    writes the slot's books (`gen`, `used`, `len_`, the counters) at
+    once but not its words; it opens a *column* (`_Column`) for the
+    handle object it returns — the W words of every lane, zeros. A
+    blob_set that is given that very object (found by identity, as a
+    pinned handle is) and a static word index lands in the column's row
+    under the mask it would have written the pool with, later sets over
+    earlier ones, and emits no pool operation. `flush` writes every
+    open column, in the order they were opened, each as ONE scatter of
+    [W, lanes] into the pool seen as [W, nslots] (word-major: a payload
+    is a column of it), and runs before anything that could observe or
+    disturb a column: the end of the behaviour's evaluation
+    (engine._make_branch), any blob_get, a blob_set whose index is
+    traced or whose handle is not an open column's, blob_free.
+    blob_length reads `len_`, which is eager, and flushes nothing. That
+    is exact: an iso has one owner, so only this lane of this behaviour
+    can name the slot; columns open at once hold disjoint slots (the
+    reservation's windows: spawn.cohort_blob_resv); and every read of
+    the pool's words flushes first — so "the slot's words are zeroed"
+    means "by the flush", which writes 0 to every word no set covered.
+    A forged handle (an untyped int that names the fresh slot) is not
+    the column's object: the op it is given to flushes and then goes
+    the eager way, after the column's words as in program order, so no
+    program can see the column before it is written.
 
     A handle is checked — `local`: its slot and its generation against
     the slot's; `live`: the slot's used flag — where an op is given it,
@@ -352,7 +425,8 @@ class BlobPoolView:
     __slots__ = ("data", "used", "len_", "gen", "base", "nslots", "words",
                  "take",
                  "resv", "claims", "frees", "fail", "budget_fail", "n_alloc",
-                 "n_free", "n_remote", "alloced", "budget_over", "resolved")
+                 "n_free", "n_remote", "alloced", "budget_over", "resolved",
+                 "columns", "sets_folded", "sets_alone")
 
     def __init__(self, data, used, len_, gen, base, take, resv,
                  budget_over=None, resolved=None):
@@ -367,6 +441,9 @@ class BlobPoolView:
         self.resv = resv            # [sites, lanes] i32 handles, or None
         self.claims = 0             # trace-time alloc-site counter
         self.frees = 0              # trace-time free-site counter
+        self.columns = []           # open _Columns, in the order opened
+        self.sets_folded = 0        # trace-time: blob_sets a column took
+        self.sets_alone = 0         # trace-time: blob_sets that scattered
         self.resolved = resolved    # pack.RefTypes or None: handle tracer
         #   -> (slot, ok, used) checked once, before the batch scan
         self.fail = jnp.bool_(False)     # sticky: wanted a slot, pool empty
@@ -447,19 +524,55 @@ class BlobPoolView:
                 "fit an unsigned 32-bit key")
         past = jnp.uint32(size) + lax.iota(jnp.uint32, n)
         flat = pool_index(self.nslots, word, slot).astype(jnp.uint32)
-        key, value = lax.sort(
-            (jnp.where(ok, flat, past.reshape(ok.shape)).reshape(-1),
-             value.reshape(-1)), num_keys=1, is_stable=True)
-        dup = jnp.concatenate(
-            [jnp.zeros((1,), jnp.bool_), key[1:] == key[:-1]])
+        return _ascending(
+            jnp.where(ok, flat, past.reshape(ok.shape)).reshape(-1),
+            value.reshape(-1), size, past)
 
-        def one_write_a_word():
-            return tuple(lax.sort(
-                (jnp.where(dup | (key >= size), past, key), value),
-                num_keys=1, is_stable=True))
+    def column_of(self, h, word):
+        """The open column a blob_set of `word` to `h` folds into: `h`
+        is the object its blob_alloc returned and `word` a Python or
+        NumPy integer that names one of its rows; None for any other."""
+        if (not isinstance(word, (int, np.integer))
+                or isinstance(word, bool) or not 0 <= word < self.words):
+            return None
+        for col in self.columns:
+            if col.handle is h:
+                return col
+        return None
 
-        return lax.cond(jnp.any(dup), one_write_a_word,
-                        lambda: (key, value))
+    def flush(self):
+        """Write every open column into the pool, in the order they were
+        opened, and close them: one column scatter each, under `dispatch/
+        heap/set` whoever asks (the scope is absolute).
+
+        The lanes are sorted by slot once a column — a lane that did not
+        allocate keyed `nslots + lane`, its own and past the end, so it
+        sorts behind every slot and FILL_OR_DROP drops it — carrying the
+        lane, and the column's rows follow by one gather; the scatter is
+        told sorted and unique, which `_ascending` makes true here as it
+        does for `ordered`: two lanes handed one slot (a free list that
+        names it twice) show as equal neighbours, the lowest lane keeps
+        the slot whole and the others' columns are dropped."""
+        if not self.columns:
+            return
+        from .runtime.state import phase_scope
+        cols, self.columns = self.columns, []
+        lanes = cols[0].ok.size
+        past = jnp.int32(self.nslots) + lax.iota(jnp.int32, lanes)
+        with phase_scope("dispatch/heap/set"):
+            for col in cols:
+                key, lane = _ascending(
+                    jnp.where(col.ok, col.idx, past).reshape(-1),
+                    lax.iota(jnp.int32, lanes), self.nslots, past)
+                rows = jnp.take(
+                    jnp.stack([jnp.broadcast_to(r, (lanes,))
+                               for r in col.rows]), lane,
+                    axis=1, mode="clip")
+                self.data = lax.scatter(
+                    self.data.reshape(self.words, self.nslots),
+                    key[:, None], rows, _COLUMN_SCATTER,
+                    indices_are_sorted=True, unique_indices=True,
+                    mode=lax.GatherScatterMode.FILL_OR_DROP).reshape(-1)
 
 
 class Context:
@@ -825,9 +938,20 @@ class Context:
         """Claim a fresh device blob; returns its handle ([lanes] i32,
         -1 where `when` is false or the pool had no free slot — the
         sticky blob-fail flag then raises host-side, like spawn_fail).
-        The slot's words are zeroed; `length` (default: the pool width)
-        records the logical word count read back by blob_length().
-        The class must declare ``MAX_BLOBS = n`` (allocs per dispatch).
+        The slot's words read 0 until set; `length` (default: the pool
+        width) records the logical word count read back by
+        blob_length(). The class must declare ``MAX_BLOBS = n`` (allocs
+        per dispatch).
+
+        The slot's books are written here; its words are not. The
+        handle returned opens a column on the view (BlobPoolView): the
+        blob_sets that follow to THIS object at static word indices
+        fill it, and the payload reaches the pool whole — zeros where
+        nothing was set — in one scatter, before any read of the pool,
+        any other write, any free, and at the latest when the behaviour
+        returns. Fill the handle you were given: a copy made by
+        arithmetic (`jnp.where(c, h, -1)`) is another object, and each
+        set to it flushes and scatters one word a lane.
         ≙ pony_alloc / pony_alloc_msg on the owning actor's heap."""
         b = self._require_blob("blob_alloc")
         if b.resv is None:
@@ -866,13 +990,11 @@ class Context:
               else jnp.clip(jnp.asarray(length, jnp.int32), 0, wpool))
         b.len_ = b.len_.at[idx].set(
             jnp.broadcast_to(ln, idx.shape), mode="drop")
-        b.data = b.data.at[b.at(
-            jnp.arange(wpool, dtype=jnp.int32)[:, None], idx[None],
-            ok[None])].set(0, mode="drop")
         b.n_alloc = b.n_alloc + jnp.sum(ok.astype(jnp.int32))
         b.alloced = b.alloced | ok
         h2 = jnp.where(ok, h, jnp.int32(-1))
         self.cap_types.tag(h2, "iso")
+        b.columns.append(_Column(h2, idx, ok, wpool))
         return h2
 
     @_heap_scoped("get")
@@ -882,6 +1004,7 @@ class Context:
         ``ctx.blob_get(h, i).view(jnp.float32)``."""
         b = self._require_blob("blob_get")
         self._blob_guard(h, "blob_get")
+        b.flush()
         hl, ok = b.local(h)
         ok = ok & b.live(h, hl)
         i = jnp.asarray(i, jnp.int32)
@@ -921,10 +1044,15 @@ class Context:
         handle's next owner after a send. Floats: pass
         ``value.view(jnp.int32)``.
 
-        The lanes are scattered in the order of their flat pool index
-        and XLA is told so: its TPU scatter of single words is one
-        update after another unless the indices are declared sorted AND
-        unique (BlobPoolView.ordered makes both true)."""
+        A set to the handle a blob_alloc of this behaviour returned (the
+        same object), at a Python or NumPy integer `i`, while that
+        payload's column is open, lands in the column under the very
+        mask below and emits no pool operation (BlobPoolView). Any other
+        set flushes the open columns and then writes the pool: the lanes
+        are scattered in the order of their flat pool index and XLA is
+        told so: its TPU scatter of single words is one update after
+        another unless the indices are declared sorted AND unique
+        (BlobPoolView.ordered makes both true)."""
         b = self._require_blob("blob_set")
         self._blob_guard(h, "blob_set")
         if self.cap_types.lookup(h) == "val":
@@ -932,6 +1060,19 @@ class Context:
                 "capability: blob_set on a frozen (val) blob — "
                 "shared-immutable payloads cannot be written "
                 "(≙ val's deny-write, type/cap.c)")
+        col = b.column_of(h, i)
+        if col is not None:
+            if col.checked is None:
+                hl, okh = b.local(h)
+                col.checked = okh & b.live(h, hl)
+            ok = jnp.asarray(when, jnp.bool_) & b.take & col.checked
+            col.rows[i] = jnp.where(
+                ok, jnp.broadcast_to(jnp.asarray(v, jnp.int32), ok.shape),
+                col.rows[i])
+            b.sets_folded += 1
+            return
+        b.flush()
+        b.sets_alone += 1
         hl, okh = b.local(h)
         i = jnp.asarray(i, jnp.int32)
         ok = (jnp.asarray(when, jnp.bool_) & b.take & okh
@@ -956,6 +1097,7 @@ class Context:
                 "capability: blob_free on a frozen (val) blob — shared "
                 "payloads have no single owner to free them; the GC "
                 "mark pass reclaims unreferenced val blobs")
+        b.flush()
         h = jnp.asarray(h, jnp.int32)
         hl, okh = b.local(h)
         ok = jnp.asarray(when, jnp.bool_) & b.take & okh & b.live(h, hl)

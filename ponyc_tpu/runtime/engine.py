@@ -311,7 +311,8 @@ def _make_branch(bdef, msg_words: int, max_sends: int, field_dtypes,
     engine skip dead scatters; for a pool-using cohort also which state
     fields every behaviour handed back untouched, `kept`, and whether
     any allocates or frees, `alloc_free` — what `pinned` is decided
-    from, _cohort_dispatch). pinned: {Blob field: (slot, ok, used)}
+    from, _cohort_dispatch — and a behaviour's allocs and how its
+    blob_sets went, `born_full`). pinned: {Blob field: (slot, ok, used)}
     checked before the batch scan; the view finds them by the field's
     tracer."""
     w1 = 1 + msg_words
@@ -343,6 +344,12 @@ def _make_branch(bdef, msg_words: int, max_sends: int, field_dtypes,
         effects["sync_init"] = (effects["sync_init"]
                                 or bool(ctx.sync_inits))
         if bv is not None:
+            # The payloads this behaviour allocated and filled reach the
+            # pool here at the latest, one column scatter each.
+            bv.flush()
+            effects["born_full"][bdef.name] = {
+                "allocs": bv.claims, "sets_folded": bv.sets_folded,
+                "sets_alone": bv.sets_alone}
             alloc_free = bool(bv.claims or bv.frees)
             if pinned and (alloc_free or not set(pinned) <= ctx.kept):
                 raise RuntimeError(
@@ -433,7 +440,7 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
     spawn_meta = {t: program.by_type_name(t).atype.field_specs
                   for t, _ in spawn_sites}
     effects = {"destroy": False, "error": False, "sync_init": False,
-               "alloc_free": False, "pinned": None,
+               "alloc_free": False, "pinned": None, "born_full": {},
                "kept": frozenset(cohort.atype.field_specs)}
     # Device blob pool (≙ actor-heap message payloads; see ops.pack.Blob):
     # a cohort that allocates (MAX_BLOBS) or receives/holds Blob handles
@@ -815,8 +822,29 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
                 (errf, errc, errl) if effects["error"] else None,
                 blob_out)
 
+    def born_full():
+        """How often a payload is born full (api.BlobPoolView): the
+        cohort's blob_allocs, the blob_sets an open column took and the
+        ones that scattered a word a lane, summed over its behaviours —
+        read off the same abstract trace as `pinned_fields`."""
+        pinned_fields()
+        return {k: sum(b[k] for b in effects["born_full"].values())
+                for k in ("allocs", "sets_folded", "sets_alone")}
+
     run_cohort.pinned_fields = pinned_fields
+    run_cohort.born_full = born_full
     return run_cohort
+
+
+def _pool_facts(program: Program, opts: RuntimeOptions, fact: str,
+                none) -> Dict[str, Any]:
+    """{actor type: what its dispatch's own trace says of the pool}
+    (`run_cohort.<fact>()`), a device cohort a row; `none` for a cohort
+    without the pool."""
+    return {ch.atype.__name__: (getattr(_cohort_dispatch(
+                ch, opts, opts.noyield, program), fact)()
+            if opts.blob_slots > 0 and ch.uses_blobs else none)
+            for ch in program.device_cohorts}
 
 
 def pinned_handles(program: Program, opts: RuntimeOptions
@@ -824,10 +852,20 @@ def pinned_handles(program: Program, opts: RuntimeOptions
     """{actor type: the Blob state fields its dispatch checks once}, a
     device cohort a row ([] for a cohort that checks where it uses):
     the analysis dump's `pinned_handles`."""
-    return {ch.atype.__name__: list(_cohort_dispatch(
-                ch, opts, opts.noyield, program).pinned_fields()
-            if opts.blob_slots > 0 and ch.uses_blobs else ())
-            for ch in program.device_cohorts}
+    return {t: list(f) for t, f in _pool_facts(
+        program, opts, "pinned_fields", ()).items()}
+
+
+def born_full(program: Program, opts: RuntimeOptions
+              ) -> Dict[str, Dict[str, int]]:
+    """{actor type: {"allocs": a, "sets_folded": f, "sets_alone": s}}, a
+    device cohort a row: its behaviours' blob_alloc sites, the blob_sets
+    that landed in a fresh payload's column and the ones that wrote the
+    pool a word a lane (api.BlobPoolView; all 0 for a cohort without the
+    pool) — the analysis dump's `born_full`."""
+    return {t: dict(n) for t, n in _pool_facts(
+        program, opts, "born_full", dict.fromkeys(
+            ("allocs", "sets_folded", "sets_alone"), 0)).items()}
 
 
 def tick_static(program: Program, opts: RuntimeOptions) -> TickStatic:

@@ -2263,6 +2263,11 @@ class Runtime:
         (a program is frozen by then: profile() needs start())."""
         return engine.pinned_handles(self.program, self.opts)
 
+    @functools.cached_property
+    def _born_full(self) -> Dict[str, Dict[str, int]]:
+        """Another: engine.born_full."""
+        return engine.born_full(self.program, self.opts)
+
     @_api_phase("read")
     def profile(self) -> Dict[str, Any]:
         """Structured per-behaviour/per-cohort telemetry report — the
@@ -2281,10 +2286,15 @@ class Runtime:
                                   "queue_wait_p50": int,   # ticks (2^k
                                   "queue_wait_p99": int,   #  bucket lo)
                                   "mute_ticks": int,
-                                  "pinned_handles": [field names]}},
+                                  "pinned_handles": [field names],
+                                  "born_full": {"allocs", "sets_folded",
+                                                "sets_alone"}}},
                          # Blob fields whose handle the dispatch checks
                          # once, not once a message (a fact of the
-                         # compiled program: engine.pinned_handles)
+                         # compiled program: engine.pinned_handles);
+                         # blob_alloc sites, the blob_sets a fresh
+                         # payload's column took and the ones that
+                         # wrote the pool (another: engine.born_full)
              "phases": {"delivery": int, "drain": int, "dispatch": int,
                         "gc_mark": int,       # cumulative work units
                         "rebuild": int},      # indices the rebuild read
@@ -2325,7 +2335,7 @@ class Runtime:
                 "rejected": int(rej[g]),
             }
         cohorts = {}
-        pinned = self._pinned_handles
+        pinned, born = self._pinned_handles, self._born_full
         for di, ch in enumerate(self.program.device_cohorts):
             h = [int(x) for x in hist[di]]
             cohorts[ch.atype.__name__] = {
@@ -2334,6 +2344,7 @@ class Runtime:
                 "queue_wait_p99": hist_percentile(h, 0.99),
                 "mute_ticks": int(mt[di]),
                 "pinned_handles": pinned[ch.atype.__name__],
+                "born_full": born[ch.atype.__name__],
             }
         ph = self._fetch(self.state.phase_cost).reshape(
             p, N_PHASES).sum(0)
