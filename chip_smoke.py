@@ -54,8 +54,8 @@ import time
 ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, ROOT)
 
-# bench.py's headline geometry (bench_ubench): 4 pings in flight per
-# pinger, the drain batch and mailbox sized to match, one payload word.
+# The smoke's ubench geometry: 4 pings in flight per pinger, the drain
+# batch and mailbox sized to match, one payload word.
 PINGS = 4
 UBENCH_GEOMETRY = dict(mailbox_cap=4, batch=PINGS, max_sends=1,
                        msg_words=1, spill_cap=1024, inject_slots=8)

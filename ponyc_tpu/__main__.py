@@ -12,7 +12,6 @@ Commands:
                               (config.strip_runtime_flags), exec the
                               script with the remaining argv on the
                               backend JAX resolves (JAX_PLATFORMS).
-  bench [args...]             the headline benchmark (bench.py).
   test [pytest args...]       the test suite (≙ ponytest aggregate).
   doc <module[:ATTR]> [-o D]  generate docs for actor types reachable
                               from a module (≙ docgen pass, docgen.c).
@@ -56,17 +55,6 @@ Commands:
                               one-line verdict + diagnosis. Exit:
                               0 ok/snapshot, 1 stalled/crashed/
                               degraded, 2 usage or unreadable.
-  perf [--check]              standing perf-regression scoreboard
-       [--tolerance F]        (ISSUE 19): the headline trajectory from
-       [--root DIR] [--json]  BENCH_HISTORY.jsonl (appended by every
-       [--history FILE]       bench.py run) + committed BENCH_r*.json
-                              rounds, vs per-group best-so-far and the
-                              10x north star. --check exits 1 when a
-                              comparable group's newest run sits more
-                              than --tolerance (0.2) below its best,
-                              or measured costs diverged from the
-                              model — runnable as a CI gate. Exit:
-                              0 ok, 1 regression, 2 no history/usage.
   supervise [--retries N]     run a workload script under restart-from-
             [--backoff S]     checkpoint supervision (supervise.py): on
             --prefix P        any nonzero/killed exit the child is
@@ -113,7 +101,6 @@ from __future__ import annotations
 import json
 import os
 import runpy
-import subprocess
 import sys
 import time
 
@@ -168,19 +155,6 @@ def cmd_run(argv) -> int:
     sys.path.insert(0, os.path.dirname(os.path.abspath(script)) or ".")
     runpy.run_path(script, run_name="__main__")
     return 0
-
-
-def cmd_bench(argv) -> int:
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    bench = os.path.join(root, "bench.py")
-    if not os.path.exists(bench):
-        print("ponyc_tpu bench: bench.py not found (installed package "
-              "without the repo harness)", file=sys.stderr)
-        return 2
-    # One process per chip: this parent has imported the package but
-    # never initialised a JAX backend (importing ponyc_tpu touches no
-    # device), so the child is the only process that claims the chip.
-    return subprocess.call([sys.executable, bench] + list(argv))
 
 
 def cmd_test(argv) -> int:
@@ -711,66 +685,6 @@ def cmd_serve(argv) -> int:
     return serve_main(argv)
 
 
-def cmd_perf(argv) -> int:
-    """Standing perf-regression scoreboard (costs.py, ISSUE 19):
-
-        ponyc_tpu perf [--check] [--tolerance F] [--root DIR]
-                       [--history FILE] [--json]
-
-    Ingests BENCH_HISTORY.jsonl (appended by every bench.py run) plus
-    the committed BENCH_r*.json round records, renders the headline
-    trajectory against per-group best-so-far and the 10x north star,
-    and with --check gates on regression: newest row of each
-    comparable (metric, unit, platform, actors) group more than
-    --tolerance (default 0.2) below that group's best, or any row
-    whose measured costs diverged from the model. Exit: 0 ok,
-    1 regression/divergence (--check), 2 usage or no history."""
-    from . import costs
-    root, history, tol = ".", None, costs.PERF_TOLERANCE
-    check = json_out = False
-    i = 0
-    while i < len(argv):
-        a = argv[i]
-        if a == "--check":
-            check = True
-        elif a == "--json":
-            json_out = True
-        elif a in ("--tolerance", "--root", "--history"):
-            if i + 1 >= len(argv):
-                print(f"ponyc_tpu perf: {a} needs a value",
-                      file=sys.stderr)
-                return 2
-            i += 1
-            if a == "--tolerance":
-                try:
-                    tol = float(argv[i])
-                except ValueError:
-                    print(f"ponyc_tpu perf: bad --tolerance "
-                          f"{argv[i]!r}", file=sys.stderr)
-                    return 2
-            elif a == "--root":
-                root = argv[i]
-            else:
-                history = argv[i]
-        else:
-            print(f"ponyc_tpu perf: unknown argument {a!r}",
-                  file=sys.stderr)
-            return 2
-        i += 1
-    rows = costs.load_history(root, history_path=history)
-    verdict = costs.perf_check(rows, tolerance=tol) if check else None
-    if json_out:
-        import json as _json
-        print(_json.dumps({"rows": rows, "check": verdict}))
-    else:
-        print(costs.render_perf(rows, verdict))
-    if not rows:
-        return 2
-    if check and not verdict["ok"]:
-        return 1
-    return 0
-
-
 def cmd_version(_argv) -> int:
     # Prints strings only: no jax.devices(), so asking for the version
     # never claims the chip from a process that is using it.
@@ -783,12 +697,12 @@ def cmd_version(_argv) -> int:
     return 0
 
 
-COMMANDS = {"run": cmd_run, "bench": cmd_bench, "test": cmd_test,
-            "doc": cmd_doc, "verify": cmd_verify, "lint": cmd_lint,
-            "trace": cmd_trace, "top": cmd_top, "doctor": cmd_doctor,
+COMMANDS = {"run": cmd_run, "test": cmd_test, "doc": cmd_doc,
+            "verify": cmd_verify, "lint": cmd_lint, "trace": cmd_trace,
+            "top": cmd_top, "doctor": cmd_doctor,
             "supervise": cmd_supervise, "snapshot": cmd_snapshot,
             "restore": cmd_restore, "serve": cmd_serve,
-            "perf": cmd_perf, "version": cmd_version}
+            "version": cmd_version}
 
 
 def main(argv=None) -> int:

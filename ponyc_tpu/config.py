@@ -71,7 +71,7 @@ class RuntimeOptions:
 
     # --- lifecycle / quiescence (≙ scheduler.c:303-480 CNF/ACK) ---
     quiesce_interval: Union[int, str] = "auto"  # max ticks fused into
-    #   one device dispatch (engine.build_multi_step); the window
+    #   one device dispatch (engine.build_multi_step_gated); the window
     #   self-terminates on host work / exit / fatal flags, so this
     #   bounds only how long the device may run *uninterrupted* — raise
     #   to amortise dispatch overhead, lower to tighten max_steps
@@ -207,11 +207,11 @@ class RuntimeOptions:
     #   Scrapes never touch the device: they render the snapshot the
     #   run loop last pushed at a window boundary (the same
     #   non-blocking posture as the analysis writer)
-    cost_capture: bool = False     # measured device-cost capture
-    #   (costs.py, ISSUE 19): at start(), AOT-compile the runtime's
+    cost_capture: bool = False     # the compiler's cost record
+    #   (costs.capture): at start(), AOT-compile the runtime's
     #   real step/window executables and record their
     #   cost_analysis()/memory_analysis() (bytes accessed, flops, peak
-    #   HBM) next to the modelled bytes/msg — one extra compile per
+    #   HBM) for /metrics and the postmortem — one extra compile per
     #   executable at start (the XLA disk cache absorbs the repeat).
     #   HOST-side: the traced step never sees it, so the step jaxpr is
     #   bit-identical with capture on or off. Off, the same capture is

@@ -234,11 +234,9 @@ class FlightRecorder:
                         if getattr(rt, "_serve", None) is not None
                         else None),
             "watchdog": (None if wd is None else wd.snapshot()),
-            # Measured device costs (ISSUE 19): the costs.capture memo
-            # when the observatory ran — a host attribute, present so a
-            # crash dump states what the executables actually cost,
-            # not just what the model claimed. None pre-capture (and on
-            # every pre-PR-19 postmortem: readers must .get()).
+            # The compiler's cost record: the costs.capture memo where
+            # a capture ran — a host attribute. None before one
+            # (readers must .get()).
             "measured": getattr(rt, "_costs", None),
             "options": dataclasses.asdict(rt.opts)
             if getattr(rt, "opts", None) is not None else {},
@@ -455,9 +453,9 @@ def render_postmortem(pm: Dict[str, Any]) -> str:
     if mail:
         lines.append("recent host mail: " + ", ".join(
             f"a{m['actor']}.{m['behaviour']}" for m in mail[-6:]))
-    # Measured device costs (ISSUE 19) — absent on pre-capture runs and
-    # every pre-PR-19 postmortem: .get() everything, render nothing
-    # rather than crash the crash report.
+    # The compiler's cost record (costs.capture) — absent unless the
+    # run captured it: .get() everything, render nothing rather than
+    # crash the crash report.
     meas = pm.get("measured") or {}
     for exe, rec in sorted((meas.get("executables") or {}).items()):
         if not isinstance(rec, dict) or rec.get("error"):
@@ -473,14 +471,6 @@ def render_postmortem(pm: Dict[str, Any]) -> str:
             lines.append(f"measured [{exe}] "
                          f"({meas.get('backend', '?')}): "
                          + " ".join(bits))
-    div = meas.get("model_divergence") or {}
-    if div.get("ratio") is not None:
-        verdict = ("DIVERGED" if div.get("diverged") else "ok")
-        lines.append(
-            f"model vs measured: {div.get('modelled_bytes')} vs "
-            f"{div.get('measured_bytes')} B/msg "
-            f"(ratio {div['ratio']}, tol {div.get('tolerance')}) "
-            f"-> {verdict}")
     env = pm.get("env") or {}
     if env:
         lines.append(f"env: libtpu_importable="

@@ -19,8 +19,8 @@ from a thread, a subprocess, or another machine. Two jobs:
   building egress backpressure), malformed frames (`malform_every`),
   and mid-request kill (`kill_after` closes the socket with requests
   outstanding). Every knob is client-side misbehaviour the front door
-  must absorb without wedging the world (tests/test_serve.py and the
-  soak half of `bench.py --serve-smoke` drive them).
+  must absorb without wedging the world (tests/test_serve.py drives
+  them).
 
 CLI: ``python -m ponyc_tpu.loadgen HOST PORT [--conns N] [--depth D]
 [--requests K] [--deadline-ms MS] [--duration S] [...chaos flags]`` —

@@ -104,9 +104,9 @@ def snapshot(rt) -> Dict[str, Any]:
     srv = getattr(rt, "_serve", None)
     if srv is not None:
         snap["serving"] = srv.stats()
-    # Measured device costs (ISSUE 19): captured once at start()
-    # (opts.cost_capture) or via Runtime.measured_costs() — a host
-    # attribute read here, never a compile.
+    # The compiler's cost record (costs.capture): captured once at
+    # start() (opts.cost_capture) or via Runtime.measured_costs() — a
+    # host attribute read here, never a compile.
     costs = getattr(rt, "_costs", None)
     if costs is not None:
         snap["measured"] = costs
@@ -272,15 +272,6 @@ def prometheus_text(snap: Dict[str, Any],
                 "Device working set per compiled executable "
                 "(memory_analysis: args+outputs+temps+code-aliased)",
                 rows_p)
-        div = measured.get("model_divergence") or {}
-        if div.get("ratio") is not None:
-            fam("pony_tpu_model_divergence_ratio", "gauge",
-                "Measured/modelled bytes-per-message ratio "
-                "(1.0 = the model holds)", [(None, div["ratio"])])
-            fam("pony_tpu_model_divergence", "gauge",
-                "1 when measured bytes/msg disagrees with the model "
-                "past tolerance", [(None, 1 if div.get("diverged")
-                                    else 0)])
     g = snap.get("gc", {})
     if g:
         fam("pony_tpu_gc_passes_total", "counter", "GC passes run",

@@ -34,8 +34,7 @@ class Pinger:
 
 def cap_for_pings(pings: int, floor: int = 4) -> int:
     """Smallest power-of-two mailbox_cap that holds `pings` in-flight
-    messages (shared by build() and bench.py so the sizing rule lives
-    once)."""
+    messages."""
     return max(floor, 1 << max(0, pings - 1).bit_length())
 
 

@@ -47,8 +47,8 @@ def force_cpu(n_devices: int | None = None) -> None:
     re-test hook forces it.
 
     For tests (tests/conftest.py: 8 virtual devices) and
-    ``__graft_entry__.dryrun_multichip``; bench.py's ``--platform cpu``
-    uses it too. Call before the first ``jax.devices()`` / trace: the
+    ``__graft_entry__.dryrun_multichip``. Call before the first
+    ``jax.devices()`` / trace: the
     ``xla_force_host_platform_device_count`` flag is read when the CPU
     client is created, and the platform cannot change once a backend
     is up."""

@@ -1392,19 +1392,6 @@ def check_kernels(program: Program, opts: RuntimeOptions) -> None:
             honour("pallas_fused=True", fd.refusal(ch, opts, sync_init))
 
 
-def build_multi_step(program: Program, opts: RuntimeOptions):
-    """The ungated window: `build_multi_step_gated` with tick 0 forced
-    (the pre-pipelining signature — bench.py drives it directly;
-    zero_aux as prev keeps the carry well-typed)."""
-    gated = build_multi_step_gated(program, opts)
-
-    def multi(st: RtState, inject_tgt, inject_words, limit):
-        return gated(st, inject_tgt, inject_words, limit,
-                     jnp.bool_(True), zero_aux(program))
-
-    return multi
-
-
 def zero_aux(program: Optional[Program] = None) -> StepAux:
     """The pre-first-tick aux template (device_pending=True so a window's
     while condition admits tick 0; everything else zero/false; for a
@@ -1469,14 +1456,6 @@ def _jit_over_mesh(fn, program: Program, opts: RuntimeOptions, mesh,
         out_specs=(state_spec, aux_spec) + (repl,) * n_extra,
         check_vma=False)
     return jax.jit(mapped, donate_argnums=(0,))
-
-
-def jit_multi_step(program: Program, opts: RuntimeOptions, mesh=None):
-    """Jit the fused window (extra replicated input: tick limit; extra
-    replicated output: ticks run — so the while condition and the host's
-    step accounting are shard-uniform)."""
-    return _jit_over_mesh(build_multi_step(program, opts), program, opts,
-                          mesh, n_extra=1)
 
 
 def jit_multi_step_gated(program: Program, opts: RuntimeOptions,

@@ -126,8 +126,8 @@ def profile_lanes(program: Program, opts: RuntimeOptions, st: RtState,
 
 def phase_cost_lanes(st: RtState, listed_tgt, drain_facts, nproc_total,
                      n_spawned, n_destroyed, rebuild_slots):
-    """Per-phase window telemetry (the device-cost observatory, ISSUE
-    19): accumulate one deterministic work-unit tally per scheduler-tick
+    """Per-phase window telemetry (ISSUE 19): accumulate one
+    deterministic work-unit tally per scheduler-tick
     phase into st.phase_cost (state.PHASE_NAMES order). ONLY traced when
     opts.analysis >= 1 — the caller gates the call itself, so at level 0
     none of this exists in the jaxpr (the zero-cost test traps this

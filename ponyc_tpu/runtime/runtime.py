@@ -9,9 +9,9 @@ program's code) plus the host side of actor creation
 non-actor context).
 
 The host loop is deliberately thin: it issues ONE fused device dispatch
-per iteration (engine.build_multi_step — a lax.while_loop advancing up to
-`quiesce_interval` ticks that self-terminates the moment host attention
-is needed), then reads back a handful of scalars to decide termination —
+per iteration (engine.build_multi_step_gated — a lax.while_loop advancing
+up to `quiesce_interval` ticks that self-terminates the moment host
+attention is needed), then reads back a few scalars to decide termination —
 the TPU analog of the CNF/ACK quiescence vote (scheduler.c:303-480).
 Host-resident actors (HOST=True types — the main-thread/ASIO-side actors
 of the reference, scheduler.c:179-190, asio/asio.c) are drained at those
@@ -41,10 +41,6 @@ from ..program import Program
 from . import engine
 from .controller import WindowController
 from .state import LIST_COUNTERS, RtState, init_state, pool_index
-
-# Window-length histogram buckets (power-of-two, like state.QW_BUCKETS):
-# bucket k counts retired windows that ran [2^k, 2^(k+1)) ticks.
-WIN_BUCKETS = 16
 
 
 # The run loop's phases (ISSUE 24). One mechanism does three things at a
@@ -386,14 +382,12 @@ class Runtime:
         self._cold_n = 0              #   and how many there were
         # Run-loop telemetry (run_loop_stats()): windows retired, how
         #   many dispatches rode behind an in-flight window, cumulative
-        #   host-imposed device-idle gap, re-queued gated-out injects,
-        #   window-length histogram.
+        #   host-imposed device-idle gap, re-queued gated-out injects.
         self._rl_windows = 0
         self._rl_pipelined = 0
         self._rl_synced = 0
         self._rl_gap_ns = 0
         self._rl_requeued = 0
-        self._win_hist = np.zeros((WIN_BUCKETS,), np.int64)
         # ---- operational observability (PROFILE.md §11) ----
         self._flight = None           # flight.FlightRecorder (start())
         self._watchdog = None         # flight.Watchdog when watchdog_s
@@ -428,8 +422,8 @@ class Runtime:
         self._serve = None            # serve.Server when a front door is
         #   attached (metrics/flight surface the serving block)
 
-    # Any state assignment — including a driver pushing rt._step results
-    # back, as bench.py does — conservatively invalidates the cached
+    # Any state assignment — including a caller handing back what
+    # rt._step returned — conservatively invalidates the cached
     # freelists; internal writers that provably keep them consistent
     # restore _freelist_key after assigning.
     @property
@@ -479,15 +473,12 @@ class Runtime:
                 raise stall from None
             raise
         if self.opts.cost_capture:
-            # Device-cost observatory (ISSUE 19): record XLA's own
-            # cost/memory analysis of the just-built executables so
-            # every BENCH json / postmortem / metrics scrape carries
-            # measured numbers next to the modelled ones. Opt-in: it
-            # AOT-compiles step+window once more (lower() only — the
-            # world does not advance).
+            # Record XLA's own cost/memory analysis of the just-built
+            # executables so the postmortem and every metrics scrape
+            # carry it. Opt-in: it AOT-compiles step+window once more
+            # (lower() only — the world does not advance).
             from .. import costs as _costs
             _costs.capture(self, force=True)
-            _costs.measured_block(self)
         if self.opts.metrics_port is not None:
             from .. import metrics as _metrics
             self._metrics = _metrics.MetricsServer(
@@ -562,12 +553,8 @@ class Runtime:
         self.opts = _dc.replace(self.opts, quiesce_interval=qi)
         self.program.opts = self.opts
         self._step = engine.jit_step(self.program, self.opts, self.mesh)
-        self._multi = engine.jit_multi_step(self.program, self.opts,
-                                            self.mesh)
-        # The PIPELINED window (tick 0 gated on-device by the previous
-        # window's aux) — only the executable the run loop actually
-        # calls gets compiled (jit is lazy), so drivers that use
-        # self._multi directly (bench.py) pay nothing here.
+        # The window (tick 0 gated on-device by the previous window's
+        # aux): the one program the run loop launches.
         self._multi_g = engine.jit_multi_step_gated(
             self.program, self.opts, self.mesh)
         self._cold_window = True
@@ -1620,8 +1607,6 @@ class Runtime:
         self._rl_tile_t = now
         self._rl_outside = {}
         self._rl_wall_ns += int(wall * 1e9)
-        self._win_hist[min(WIN_BUCKETS - 1,
-                           max(0, k.bit_length() - 1))] += 1
         # Controller: a full-budget exit with no host attention grows
         # the window; a host-attention cut (or queue-wait pressure via
         # the qw_p99 aux lane) shrinks it; early quiescence holds.
@@ -2050,15 +2035,12 @@ class Runtime:
         and released up to the last retired window, `allocs` / `frees`,
         and their difference `blobs_in_use` at its end — read with the
         window's aux, no fetch of their own; None elsewhere and before
-        the first window), the window-length histogram (power-of-two
-        buckets) and the controller snapshot."""
-        n = max(1, self._rl_windows)
+        the first window) and the controller snapshot."""
         return {
             "windows": self._rl_windows,
             "pipelined_dispatches": self._rl_pipelined,
             "sync_dispatches": self._rl_synced,
             "host_gap_us_total": self._rl_gap_ns / 1e3,
-            "host_gap_us_mean": self._rl_gap_ns / 1e3 / n,
             "windows_wall_s": self._rl_wall_ns / 1e9,
             "phase_s": dict(self._phase_s),
             "phase_n": dict(self._phase_n),
@@ -2074,7 +2056,6 @@ class Runtime:
                       "blobs_in_use": (self._pool_books["alloc"]
                                        - self._pool_books["free"])}
                      if self._pool_books else None),
-            "window_hist": [int(x) for x in self._win_hist],
             "controller": (self._controller.snapshot()
                            if self._controller is not None else None),
         }

@@ -63,7 +63,7 @@ from ..program import Program
 # quantize() aggregations over the fork's USDT probes).
 QW_BUCKETS = 16
 
-# Per-phase window telemetry (the device-cost observatory, ISSUE 19):
+# Per-phase window telemetry (ISSUE 19):
 # one work-unit counter per scheduler-tick phase, accumulated on device
 # in lanes.phase_cost_lanes. Work units are DETERMINISTIC per-phase
 # tallies (delivery-list entries gathered, ring slots drained,

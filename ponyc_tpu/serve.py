@@ -69,9 +69,7 @@ connection.
 
 `python -m ponyc_tpu serve` runs the default compute service
 (`ServeWorker.handle(tag, x) → 2*x+1`); `ponyc_tpu/loadgen.py` is the
-matching load generator + chaos/soak harness, and `bench.py
---serve-smoke` records the standing `serving` BENCH block (p50/p99
-end-to-end latency, shed rate, goodput under 2× overload).
+matching load generator + chaos/soak harness.
 """
 
 from __future__ import annotations
@@ -666,8 +664,8 @@ class Server:
                 "n": len(lat)}
 
     def stats(self) -> Dict[str, Any]:
-        """The `serving` block (metrics snapshot, flight postmortems,
-        bench.py --serve-smoke)."""
+        """The `serving` block (metrics snapshot, flight
+        postmortems)."""
         c = self.c
         shed = (c["shed_busy"] + c["shed_deadline"] + c["shed_drain"]
                 + c["shed_choked"])

@@ -2,7 +2,7 @@
 `.cache/ponyc_tpu/`), and nothing that times anything.
 
 - **The compile cache** (`enable_compile_cache`): jax's persistent
-  compilation cache, wired for Runtime / bench.py / chip_smoke.py.
+  compilation cache, wired for Runtime / chip_smoke.py.
 - **The window length's memory** (`resolve_quiesce_interval`,
   `store_quiesce_interval`): `quiesce_interval="auto"` starts the
   adaptive controller (runtime/controller.py) from the window a

@@ -4,10 +4,10 @@ give every test a deadline that names it.
 ≙ the reference's fake-stdlib/PassTest fixture strategy (test/libponyc/
 util.h:32-82): tests run against a controllable substrate rather than the
 real target. Multi-chip sharding tests use these 8 virtual devices; the
-real TPU is exercised by chip_smoke.py (and bench.py), never by this
-suite. The pinning (env var + config knob + the virtual-device XLA
-flag + the persistent compile cache off, all before the first device
-touch) lives in ponyc_tpu.platforms.
+real TPU is exercised by chip_smoke.py, never by this suite. The
+pinning (env var + config knob + the virtual-device XLA flag + the
+persistent compile cache off, all before the first device touch) lives
+in ponyc_tpu.platforms.
 
 The deadline (ISSUE 31). A run that is cut by the driver's clock names
 nothing; so no test may stand still for longer than DEADLINE_S, set-up

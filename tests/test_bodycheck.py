@@ -510,7 +510,7 @@ def test_shipped_trees_lint_clean_pure_ast():
          # types (Egress/FrontDoor/ServeWorker) and the load generator
          os.path.join(ROOT, "ponyc_tpu", "serve.py"),
          os.path.join(ROOT, "ponyc_tpu", "loadgen.py"),
-         # device-cost observatory + perf scoreboard (ISSUE 19)
+         # the compiler's cost record + the window's symbol table
          os.path.join(ROOT, "ponyc_tpu", "costs.py")])
     dt = time.perf_counter() - t0
     assert findings == [], "\n".join(str(f) for f in findings)

@@ -395,16 +395,15 @@ def test_doctor_cli_postmortem(tmp_path, capsys):
     assert capsys.readouterr().out.startswith("STALLED")
 
 
-def test_doctor_cli_refuses_bench_json(tmp_path, capsys):
-    """`doctor --postmortem` reads flight-recorder postmortems only: a
-    bench result json (which no longer embeds one — bench.py exits
-    non-zero without a chip instead of recording a fallback) is a
-    usage error, not a diagnosis."""
+def test_doctor_cli_refuses_a_json_that_is_no_postmortem(tmp_path, capsys):
+    """`doctor --postmortem` reads flight-recorder postmortems only: any
+    other json (a benchmark's result line, say) is a usage error, not a
+    diagnosis."""
     from ponyc_tpu.__main__ import main as cli_main
-    bench_json = {"metric": "x", "value": 1,
+    other_json = {"metric": "x", "value": 1,
                   "postmortem": {"reason": "tpu_init_failed"}}
-    path = str(tmp_path / "bench.json")
-    json.dump(bench_json, open(path, "w"))
+    path = str(tmp_path / "result.json")
+    json.dump(other_json, open(path, "w"))
     assert cli_main(["doctor", "--postmortem", path]) == 2
     assert "not a ponyc_tpu postmortem" in capsys.readouterr().err
 

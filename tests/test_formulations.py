@@ -392,6 +392,5 @@ def test_start_records_only_the_window():
     # the run loop called one executable, the gated window, and start()
     # had traced none
     assert compiles[0] - before == 1
-    assert (rt._step._cache_size(), rt._multi._cache_size(),
-            rt._multi_g._cache_size()) == (0, 0, 1)
+    assert (rt._step._cache_size(), rt._multi_g._cache_size()) == (0, 1)
     rt.stop()

@@ -343,7 +343,6 @@ def test_run_loop_stats_host_gap_accounting():
     assert rl["pipelined_dispatches"] == 0       # sync mode never rides
     assert rl["windows"] > 1
     assert rl["host_gap_us_total"] >= 0
-    assert sum(rl["window_hist"]) == rl["windows"]
     assert rl["controller"]["window"] == 8       # fixed mode holds
 
 
@@ -377,8 +376,9 @@ def test_qw_p99_aux_lane():
     import jax.numpy as jnp
     for level, expect_pos in ((1, True), (0, False)):
         rt, ids = _ring(hops=20, analysis=level, mailbox_cap=8)
-        st, aux, _k = rt._multi(rt.state, *rt._drain_inject(),
-                                jnp.int32(8))
+        st, aux, _k = rt._multi_g(rt.state, *rt._drain_inject(),
+                                  jnp.int32(8), jnp.bool_(True),
+                                  rt._zero_aux)
         rt.state = st
         a = jax.device_get(aux)
         if expect_pos:

@@ -240,6 +240,40 @@ def test_admission_shed_under_synthetic_qw_pressure():
     rt.stop()
 
 
+def test_real_overload_at_twice_capacity_sheds_at_the_edge():
+    """No synthetic vote: a gentle closed loop measures what the
+    service answers, then conns x depth far past the worker pool offer
+    at least twice that for a second. The front door sheds BUSY at the
+    edge, admitted requests complete with p50 < p99, every frame is
+    answered, and no ring ever reaches a sticky failure."""
+    workers = 16
+    rt, server, port = _build(workers)
+
+    def client():
+        calib = loadgen.run_load("127.0.0.1", port, conns=2, depth=2,
+                                 requests=30, timeout_s=60.0)
+        load = loadgen.run_load("127.0.0.1", port, conns=4,
+                                depth=4 * workers, requests=1 << 30,
+                                duration_s=1.0, busy_backoff_s=0.005)
+        return calib, load
+
+    code, (calib, load) = _run_with_client(rt, server, client)
+    assert code == 0
+    assert calib["unanswered"] == 0 and calib["goodput_rps"] > 0
+    assert load["offered_rps"] >= 2.0 * calib["goodput_rps"]
+    assert load["busy"] > 0, "overload was not shed"
+    assert load["ok"] > 0 and load["goodput_rps"] > 0
+    assert load["unanswered"] == 0 and load["bad_value"] == 0
+    assert load["p99_us"] > load["p50_us"] > 0
+    st = server.stats()
+    assert st["drained"] and st["shed"]["busy"] > 0
+    assert st["admission"]["limit"] >= 1
+    assert st["batches"] >= 1 and st["submitted"] >= load["ok"]
+    assert not [key for key in rt._error_counts if key[0] in (
+        "SpillOverflowError", "SpawnCapacityError", "BlobCapacityError")]
+    rt.stop()
+
+
 def test_deadline_shed_and_expiry():
     """A deadline the measured service rate cannot meet sheds at the
     edge; a queued request whose deadline lapses is answered DEADLINE
