@@ -457,9 +457,7 @@ class Analysis:
                              f" pinned_handles="
                              f"{','.join(cinf['pinned_handles']) or '-'}"
                              " born_full=" + "/".join(
-                                 str(cinf["born_full"][k]) for k in (
-                                     "allocs", "sets_folded",
-                                     "sets_alone")))
+                                 map(str, cinf["born_full"].values())))
                 lines.append(
                     f"  cohort {cohort.atype.__name__}: "
                     f"cap={cohort.capacity} queued={int(co.sum())} "

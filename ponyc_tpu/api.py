@@ -366,6 +366,46 @@ class _Column:
         self.checked = None
 
 
+# One window (the first rows of a slot) an index into those rows of the
+# pool seen as [rows, nslots]: _COLUMN_SCATTER the other way, the
+# result [rows, lanes].
+_WINDOW_GATHER = lax.GatherDimensionNumbers(
+    offset_dims=(0,), collapsed_slice_dims=(1,), start_index_map=(1,))
+
+# What a read window costs, in single-word gets of the same lanes (a
+# ratio, so the lanes cancel; measured on a v5e, docs/DESIGN.md §7b):
+# the gather's fixed part, and a part for every pool word the window
+# spans a lane (rows x nslots / lanes: seeing them as [rows, nslots]
+# re-tiles them, which is what a large window pays for).
+WINDOW_FIXED = 0.8
+WINDOW_A_WORD = 0.0105
+
+
+def window_rows(words, slots_a_lane):
+    """How many rows the ONE window holds that stands for static gets of
+    `words` of a handle — rows 0 .. max(words): a window starts at the
+    pool's first row, where its rows are a prefix of the flat pool — or
+    None where the single gets are cheaper: fewer than two of them, or
+    fewer than the window's price in gets (over a pool of `slots_a_lane`
+    slots a lane of the cohort)."""
+    rows = max(words) + 1
+    price = WINDOW_FIXED + rows * slots_a_lane * WINDOW_A_WORD
+    return rows if len(words) >= max(2, price) else None
+
+
+class _Window:
+    """The static reads of one handle object while nothing writes the
+    pool (BlobPoolView): `words`, the word of every such blob_get in
+    program order, and — where the view follows a plan that opened a
+    window for them — `rows`, the [rows, lanes] words one gather
+    read."""
+
+    __slots__ = ("handle", "words", "rows")
+
+    def __init__(self, handle):
+        self.handle, self.words, self.rows = handle, [], None
+
+
 class BlobPoolView:
     """Trace-time working view of the device blob pool for ONE behaviour
     evaluation (see ops.pack.Blob; pool arrays live in runtime.state).
@@ -404,6 +444,43 @@ class BlobPoolView:
     the eager way, after the column's words as in program order, so no
     program can see the column before it is written.
 
+    One kind of read is made whole: **a payload is read whole.** The
+    first blob_get of a handle object at a static word index (a Python
+    or NumPy integer in [0, W), the test a column makes of a set) checks
+    the handle as every get does and then, where the behaviour's own
+    probe says it pays (`reads`, below), gathers the handle's rows up
+    to the last one read ONCE — one gather of [rows, lanes] from those
+    rows of the pool (word-major: a prefix of it) seen as [rows,
+    nslots], indexed by slot, 0 in a lane whose handle is null, stale,
+    forged,
+    freed or another shard's, the lanes where a single get fills 0 —
+    and keeps them as a *read window* (`_Window`) under that object.
+    Every later blob_get of the same object at a static word returns a
+    row of the window and emits no pool operation. Whatever can change
+    the pool's words or what a check said closes every window first: a
+    flush that writes a column, a blob_set that scatters, blob_alloc,
+    blob_free — of ANY handle, since a forged one may alias the
+    window's slot. blob_length and a blob_set folded into a column
+    close nothing (neither writes the pool). So a window holds exactly
+    what a single get would read where it is used: nothing wrote the
+    pool, `gen` or `used` since the gather, and get - set - get of one
+    handle sees the set. A get at a traced index, of a handle copied by
+    arithmetic (another object), or where no window was opened, goes
+    the single-word way.
+
+    Whether a window opens is decided from the behaviour's own reads,
+    not by an option: the first get cannot see the gets behind it, so
+    the cohort's probe (engine._cohort_dispatch: an abstract trace of
+    every behaviour before the real one) runs on a view that only
+    RECORDS (given no `reads`: per handle object, in the order first
+    read, the static words read until something closed the record:
+    `read_log`), and `read_plan()` prices each record (`window_rows`:
+    the gets it stands for against the pool words its rows span a
+    lane). The real trace's
+    view is handed that plan and opens its k-th record as the plan's
+    k-th entry says; engine._make_branch holds the trace's own records
+    to the plan and raises where they differ.
+
     A handle is checked — `local`: its slot and its generation against
     the slot's; `live`: the slot's used flag — where an op is given it,
     unless the engine checked it ONCE for the whole cohort dispatch
@@ -426,10 +503,11 @@ class BlobPoolView:
                  "take",
                  "resv", "claims", "frees", "fail", "budget_fail", "n_alloc",
                  "n_free", "n_remote", "alloced", "budget_over", "resolved",
-                 "columns", "sets_folded", "sets_alone")
+                 "columns", "sets_folded", "sets_alone", "reads",
+                 "windows", "read_log", "gets")
 
     def __init__(self, data, used, len_, gen, base, take, resv,
-                 budget_over=None, resolved=None):
+                 budget_over=None, resolved=None, reads=()):
         self.data = data            # [W*B] i32, word-major (working copy)
         self.used = used            # [B] bool
         self.len_ = len_            # [B] i32
@@ -444,6 +522,11 @@ class BlobPoolView:
         self.columns = []           # open _Columns, in the order opened
         self.sets_folded = 0        # trace-time: blob_sets a column took
         self.sets_alone = 0         # trace-time: blob_sets that scattered
+        self.reads = reads          # the probe's read_plan(); given
+        #   none, this view records and opens no window
+        self.windows = []           # the _Windows nothing has closed yet
+        self.read_log = []          # every _Window, in the order opened
+        self.gets = 0               # trace-time: blob_gets
         self.resolved = resolved    # pack.RefTypes or None: handle tracer
         #   -> (slot, ok, used) checked once, before the batch scan
         self.fail = jnp.bool_(False)     # sticky: wanted a slot, pool empty
@@ -528,12 +611,17 @@ class BlobPoolView:
             jnp.where(ok, flat, past.reshape(ok.shape)).reshape(-1),
             value.reshape(-1), size, past)
 
+    def names_a_row(self, word):
+        """Is `word` a Python or NumPy integer in [0, W): an index the
+        trace can read, so a column or a read window can hold its row."""
+        return (isinstance(word, (int, np.integer))
+                and not isinstance(word, bool) and 0 <= word < self.words)
+
     def column_of(self, h, word):
         """The open column a blob_set of `word` to `h` folds into: `h`
         is the object its blob_alloc returned and `word` a Python or
         NumPy integer that names one of its rows; None for any other."""
-        if (not isinstance(word, (int, np.integer))
-                or isinstance(word, bool) or not 0 <= word < self.words):
+        if not self.names_a_row(word):
             return None
         for col in self.columns:
             if col.handle is h:
@@ -542,8 +630,9 @@ class BlobPoolView:
 
     def flush(self):
         """Write every open column into the pool, in the order they were
-        opened, and close them: one column scatter each, under `dispatch/
-        heap/set` whoever asks (the scope is absolute).
+        opened, and close them (and, having written, every read
+        window): one column scatter each, under `dispatch/heap/set`
+        whoever asks (the scope is absolute).
 
         The lanes are sorted by slot once a column — a lane that did not
         allocate keyed `nslots + lane`, its own and past the end, so it
@@ -557,6 +646,7 @@ class BlobPoolView:
             return
         from .runtime.state import phase_scope
         cols, self.columns = self.columns, []
+        self.close()
         lanes = cols[0].ok.size
         past = jnp.int32(self.nslots) + lax.iota(jnp.int32, lanes)
         with phase_scope("dispatch/heap/set"):
@@ -573,6 +663,62 @@ class BlobPoolView:
                     key[:, None], rows, _COLUMN_SCATTER,
                     indices_are_sorted=True, unique_indices=True,
                     mode=lax.GatherScatterMode.FILL_OR_DROP).reshape(-1)
+
+    def close(self):
+        """Close every read window: the pool's words, `gen` or `used`
+        are about to change."""
+        self.windows = []
+
+    def windowed(self, h, word):
+        """Word `word` of `h` out of its read window ([lanes] i32), or
+        None where this blob_get goes the single-word way: a traced or
+        out-of-range `word`, or a handle whose reads the plan gave no
+        window. The first static get of a handle object opens its
+        record, and — where the plan says so — checks the handle and
+        gathers its rows; the later ones cost a slice of the result."""
+        self.gets += 1
+        if not self.names_a_row(word):
+            return None
+        win = next((w for w in self.windows if w.handle is h), None)
+        if win is None:
+            win = _Window(h)
+            self.windows.append(win)
+            self.read_log.append(win)
+            k = len(self.read_log) - 1
+            rows = self.reads[k] if k < len(self.reads) else None
+            if rows is not None:
+                hl, ok = self.local(h)
+                ok = ok & self.live(h, hl)
+                # word-major: rows 0 .. rows-1 are a prefix of the pool
+                win.rows = lax.gather(
+                    self.data[:rows * self.nslots].reshape(rows, self.nslots),
+                    jnp.where(ok, hl, self.nslots)[..., None],
+                    _WINDOW_GATHER, (rows, 1),
+                    mode=lax.GatherScatterMode.FILL_OR_DROP, fill_value=0)
+        win.words.append(int(word))
+        # (a word past the rows the plan gave is a trace that reads
+        # otherwise than its probe: it goes alone here and
+        # engine._make_branch, which compares the two, raises)
+        if win.rows is None or word >= win.rows.shape[0]:
+            return None
+        return win.rows[word]
+
+    def read_plan(self):
+        """What this evaluation's static reads are worth, a record an
+        entry in the order opened: the rows of its window, or None where
+        its gets go alone (window_rows, at this pool's slots a lane)."""
+        slots_a_lane = self.nslots / jnp.size(self.take)
+        return tuple(window_rows(w.words, slots_a_lane)
+                     for w in self.read_log)
+
+    def read_facts(self):
+        """How this evaluation's blob_gets go under its own plan: the
+        windows it opens, the gets they stand for, the gets that gather
+        a word a lane."""
+        paid = [len(w.words) for w, rows in zip(self.read_log,
+                                                self.read_plan()) if rows]
+        return {"windows": len(paid), "gets_windowed": sum(paid),
+                "gets_alone": self.gets - sum(paid)}
 
 
 class Context:
@@ -962,6 +1108,7 @@ class Context:
             raise RuntimeError(
                 f"more than MAX_BLOBS={b.resv.shape[0]} blob_alloc calls "
                 "in one behaviour dispatch; raise the declared budget")
+        b.close()
         slot = b.resv[b.claims]                # reserved global SLOT ids
         b.claims += 1
         w = jnp.asarray(when, jnp.bool_)
@@ -1001,10 +1148,23 @@ class Context:
     def blob_get(self, h, i):
         """Read word `i` of blob `h` ([lanes] i32; 0 for null/-1 handles,
         out-of-range words, or handles owned by another shard). Floats:
-        ``ctx.blob_get(h, i).view(jnp.float32)``."""
+        ``ctx.blob_get(h, i).view(jnp.float32)``.
+
+        A payload is read whole: where a behaviour reads several words
+        of the handle it was GIVEN (the same object — a copy made by
+        arithmetic is another) at Python or NumPy integer indices, with
+        no blob_set that scatters, blob_alloc or blob_free between
+        them, the first get gathers the rows they span in one operation
+        and the others read the result (BlobPoolView: the read window;
+        `for i in range(W): self.blob_get(h, i)` is one gather). A
+        traced `i` gathers one word a lane, as does a lone get or a
+        pair too far apart to be worth the rows between them."""
         b = self._require_blob("blob_get")
         self._blob_guard(h, "blob_get")
         b.flush()
+        row = b.windowed(h, i)
+        if row is not None:
+            return row
         hl, ok = b.local(h)
         ok = ok & b.live(h, hl)
         i = jnp.asarray(i, jnp.int32)
@@ -1048,7 +1208,9 @@ class Context:
         same object), at a Python or NumPy integer `i`, while that
         payload's column is open, lands in the column under the very
         mask below and emits no pool operation (BlobPoolView). Any other
-        set flushes the open columns and then writes the pool: the lanes
+        set flushes the open columns, closes the read windows (the get
+        after it reads the pool again, and sees this set) and then
+        writes the pool: the lanes
         are scattered in the order of their flat pool index and XLA is
         told so: its TPU scatter of single words is one update after
         another unless the indices are declared sorted AND unique
@@ -1072,6 +1234,7 @@ class Context:
             b.sets_folded += 1
             return
         b.flush()
+        b.close()
         b.sets_alone += 1
         hl, okh = b.local(h)
         i = jnp.asarray(i, jnp.int32)
@@ -1089,7 +1252,10 @@ class Context:
         path; blobs whose owner died (or whose handle moved off-shard)
         are swept by the next Runtime.gc() mark pass (≙ the owner's
         heap dying with the actor, gc.c/heap.c). Freeing is a MOVE:
-        later use of the handle in this dispatch is rejected at trace."""
+        later use of the handle in this dispatch is rejected at trace.
+        A free closes every read window (BlobPoolView): a forged alias
+        of the freed slot reads 0 afterwards, as it would word by
+        word."""
         b = self._require_blob("blob_free")
         self._blob_guard(h, "blob_free")
         if self.cap_types.lookup(h) == "val":
@@ -1098,6 +1264,7 @@ class Context:
                 "payloads have no single owner to free them; the GC "
                 "mark pass reclaims unreferenced val blobs")
         b.flush()
+        b.close()
         h = jnp.asarray(h, jnp.int32)
         hl, okh = b.local(h)
         ok = jnp.asarray(when, jnp.bool_) & b.take & okh & b.live(h, hl)

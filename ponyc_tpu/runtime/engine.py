@@ -311,10 +311,11 @@ def _make_branch(bdef, msg_words: int, max_sends: int, field_dtypes,
     engine skip dead scatters; for a pool-using cohort also which state
     fields every behaviour handed back untouched, `kept`, and whether
     any allocates or frees, `alloc_free` — what `pinned` is decided
-    from, _cohort_dispatch — and a behaviour's allocs and how its
-    blob_sets went, `born_full`). pinned: {Blob field: (slot, ok, used)}
-    checked before the batch scan; the view finds them by the field's
-    tracer."""
+    from, _cohort_dispatch —, a behaviour's allocs and how its
+    blob_sets and blob_gets went, `born_full`, and the read windows its
+    probe priced, `reads`: api.BlobPoolView.read_plan). pinned: {Blob
+    field: (slot, ok, used)} checked before the batch scan; the view
+    finds them by the field's tracer."""
     w1 = 1 + msg_words
 
     def branch(st, payload, ids_vec, resv_k, blob_in=None, take=None,
@@ -328,12 +329,17 @@ def _make_branch(bdef, msg_words: int, max_sends: int, field_dtypes,
             resolved = pack.RefTypes() if pinned else None
             for f, checked in (pinned or {}).items():
                 resolved.tag(st[f], checked)
+            # The first trace of a behaviour is the cohort's probe: its
+            # view records the static reads, and every later trace
+            # opens the read windows that record priced.
+            plan = effects["reads"].get(bdef.name)
             bv = BlobPoolView(bdata, bused, blen, bgen, bbase,
                               (take if take is not None
                                else jnp.ones((lanes,), jnp.bool_)),
                               bresv if (bresv is not None
                                         and bresv.shape[0]) else None,
-                              budget_over=bover, resolved=resolved)
+                              budget_over=bover, resolved=resolved,
+                              reads=plan or ())
         ctx, st2, tgts, words = eval_behaviour(
             bdef, st, payload, ids_vec, msg_words=msg_words,
             field_specs=field_specs, field_dtypes=field_dtypes,
@@ -347,9 +353,17 @@ def _make_branch(bdef, msg_words: int, max_sends: int, field_dtypes,
             # The payloads this behaviour allocated and filled reach the
             # pool here at the latest, one column scatter each.
             bv.flush()
+            reads = bv.read_plan()
+            if plan is None:
+                effects["reads"][bdef.name] = reads
+            elif reads != plan:
+                raise RuntimeError(
+                    f"behaviour {bdef} reads its payloads' words as "
+                    f"{reads} where its probe saw {plan}: "
+                    "the probe and the trace disagree (engine wiring)")
             effects["born_full"][bdef.name] = {
                 "allocs": bv.claims, "sets_folded": bv.sets_folded,
-                "sets_alone": bv.sets_alone}
+                "sets_alone": bv.sets_alone, **bv.read_facts()}
             alloc_free = bool(bv.claims or bv.frees)
             if pinned and (alloc_free or not set(pinned) <= ctx.kept):
                 raise RuntimeError(
@@ -441,6 +455,7 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
                   for t, _ in spawn_sites}
     effects = {"destroy": False, "error": False, "sync_init": False,
                "alloc_free": False, "pinned": None, "born_full": {},
+               "reads": {},
                "kept": frozenset(cohort.atype.field_specs)}
     # Device blob pool (≙ actor-heap message payloads; see ops.pack.Blob):
     # a cohort that allocates (MAX_BLOBS) or receives/holds Blob handles
@@ -485,16 +500,18 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
         (api.BlobPoolView): a Blob field every behaviour hands back as
         the tracer it was given, in a cohort that neither allocates nor
         frees — the field, `gen` and `used` are then the scan's
-        invariants. Decided from the behaviours' own trace over a
-        stand-in pool of one slot (no fact read depends on a size), the
-        first time the cohort is traced (a behaviour that cannot be
-        traced fails where it always did: at the first run)."""
+        invariants. Decided from the behaviours' own trace over the
+        pool's shapes (a read window is priced by the slots a lane:
+        api.window_rows), the first time the cohort is traced (a
+        behaviour that cannot be traced fails where it always did: at
+        the first run)."""
         if effects["pinned"] is None:
             effects["pinned"] = ()
             if use_blob and nb:
-                one = sds((1,), jnp.int32)
-                probe((sds((opts.blob_words,), jnp.int32),
-                       sds((1,), jnp.bool_), one, one, sds((), jnp.int32),
+                slots = sds((opts.blob_slots,), jnp.int32)
+                probe((sds((opts.blob_words * opts.blob_slots,), jnp.int32),
+                       sds((opts.blob_slots,), jnp.bool_), slots, slots,
+                       sds((), jnp.int32),
                        sds((cohort.blob_sites, rows), jnp.int32),
                        sds((rows,), jnp.bool_)), sds((rows,), jnp.bool_))
                 if not effects["alloc_free"]:
@@ -823,13 +840,16 @@ def _cohort_dispatch(cohort: Cohort, opts: RuntimeOptions, noyield: bool,
                 blob_out)
 
     def born_full():
-        """How often a payload is born full (api.BlobPoolView): the
-        cohort's blob_allocs, the blob_sets an open column took and the
-        ones that scattered a word a lane, summed over its behaviours —
-        read off the same abstract trace as `pinned_fields`."""
+        """How often a payload is born full and read whole
+        (api.BlobPoolView): the cohort's blob_allocs, the blob_sets an
+        open column took and the ones that scattered a word a lane, the
+        read windows its behaviours open, the blob_gets those stand for
+        and the ones that gather a word a lane, summed over its
+        behaviours — read off the same abstract trace as
+        `pinned_fields`."""
         pinned_fields()
         return {k: sum(b[k] for b in effects["born_full"].values())
-                for k in ("allocs", "sets_folded", "sets_alone")}
+                for k in POOL_FACTS}
 
     run_cohort.pinned_fields = pinned_fields
     run_cohort.born_full = born_full
@@ -856,16 +876,21 @@ def pinned_handles(program: Program, opts: RuntimeOptions
         program, opts, "pinned_fields", ()).items()}
 
 
+# What `born_full` counts of a cohort's blob ops, in the dump's order.
+POOL_FACTS = ("allocs", "sets_folded", "sets_alone", "windows",
+              "gets_windowed", "gets_alone")
+
+
 def born_full(program: Program, opts: RuntimeOptions
               ) -> Dict[str, Dict[str, int]]:
-    """{actor type: {"allocs": a, "sets_folded": f, "sets_alone": s}}, a
-    device cohort a row: its behaviours' blob_alloc sites, the blob_sets
-    that landed in a fresh payload's column and the ones that wrote the
-    pool a word a lane (api.BlobPoolView; all 0 for a cohort without the
-    pool) — the analysis dump's `born_full`."""
+    """{actor type: {fact: count}} over POOL_FACTS, a device cohort a
+    row: its behaviours' blob_alloc sites, the blob_sets that landed in
+    a fresh payload's column and the ones that wrote the pool a word a
+    lane; the read windows they open, the blob_gets a window answered
+    and the ones that gathered a word a lane (api.BlobPoolView; all 0
+    for a cohort without the pool) — the analysis dump's `born_full`."""
     return {t: dict(n) for t, n in _pool_facts(
-        program, opts, "born_full", dict.fromkeys(
-            ("allocs", "sets_folded", "sets_alone"), 0)).items()}
+        program, opts, "born_full", dict.fromkeys(POOL_FACTS, 0)).items()}
 
 
 def tick_static(program: Program, opts: RuntimeOptions) -> TickStatic:

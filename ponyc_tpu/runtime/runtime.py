@@ -2269,13 +2269,18 @@ class Runtime:
                                   "mute_ticks": int,
                                   "pinned_handles": [field names],
                                   "born_full": {"allocs", "sets_folded",
-                                                "sets_alone"}}},
+                                                "sets_alone", "windows",
+                                                "gets_windowed",
+                                                "gets_alone"}}},
                          # Blob fields whose handle the dispatch checks
                          # once, not once a message (a fact of the
                          # compiled program: engine.pinned_handles);
                          # blob_alloc sites, the blob_sets a fresh
                          # payload's column took and the ones that
-                         # wrote the pool (another: engine.born_full)
+                         # wrote the pool; the read windows opened, the
+                         # blob_gets they answered and the ones that
+                         # gathered a word a lane (another:
+                         # engine.born_full)
              "phases": {"delivery": int, "drain": int, "dispatch": int,
                         "gc_mark": int,       # cumulative work units
                         "rebuild": int},      # indices the rebuild read

@@ -455,8 +455,10 @@ def test_unset_words_read_zero_at_the_receiver(shards):
     assert rt.counter("n_blob_moved") == (2 if shards == 2 else 0)
     assert rt.counter("n_blob_remote") == 0
     assert engine.born_full(rt.program, rt.opts) == {
-        "Maker": {"allocs": 2, "sets_folded": 6, "sets_alone": 0},
-        "Reader": {"allocs": 0, "sets_folded": 0, "sets_alone": 0}}
+        "Maker": {"allocs": 2, "sets_folded": 6, "sets_alone": 0,
+                  "windows": 0, "gets_windowed": 0, "gets_alone": 0},
+        "Reader": {"allocs": 0, "sets_folded": 0, "sets_alone": 0,
+                   "windows": 1, "gets_windowed": 4, "gets_alone": 0}}
     rt.stop()
 
 
@@ -484,7 +486,8 @@ def test_the_stencils_point_lowers_to_three_column_scatters_a_slot():
     rt = _hlo._bench_rt("taskbench-stencil", "payload", width)
     words, slots = rt.opts.blob_words, rt.opts.blob_slots
     assert engine.born_full(rt.program, rt.opts) == {
-        "Point": {"allocs": 3, "sets_folded": 96, "sets_alone": 0}}
+        "Point": {"allocs": 3, "sets_folded": 96, "sets_alone": 0,
+                  "windows": 1, "gets_windowed": 32, "gets_alone": 0}}
     jaxpr, hlo = _hlo.window_texts(rt)
     rt.stop()
     # the traced program: three scatters on the pool seen as [W, slots]
@@ -513,8 +516,9 @@ def test_the_gups_window_opens_no_column():
     rt = _hlo._bench_rt("gups-hpcc", "stream", 2048)
     words, slots = rt.opts.blob_words, rt.opts.blob_slots
     assert engine.born_full(rt.program, rt.opts) == {
-        "Updater": {"allocs": 0, "sets_folded": 0, "sets_alone": 1},
-        "Streamer": {"allocs": 0, "sets_folded": 0, "sets_alone": 0}}
+        "Updater": {"allocs": 0, "sets_folded": 0, "sets_alone": 1,
+                    "windows": 0, "gets_windowed": 0, "gets_alone": 1},
+        "Streamer": dict.fromkeys(engine.POOL_FACTS, 0)}
     jaxpr, hlo = _hlo.window_texts(rt)
     rt.stop()
     assert f"[{words},{slots}]" not in jaxpr and f"[{words},{slots}]" not in hlo
@@ -527,8 +531,9 @@ def test_the_dump_says_how_payloads_are_born():
     from ponyc_tpu import analysis
     rt = _hlo._bench_rt("taskbench-stencil", "payload", 8, analysis=1)
     assert rt.profile()["cohorts"]["Point"]["born_full"] == {
-        "allocs": 3, "sets_folded": 96, "sets_alone": 0}
+        "allocs": 3, "sets_folded": 96, "sets_alone": 0,
+        "windows": 1, "gets_windowed": 32, "gets_alone": 0}
     text = analysis.attach(rt).dump(out=io.StringIO())
     rt.stop()
-    assert re.search(r"cohort Point: .* pinned_handles=- born_full=3/96/0",
+    assert re.search(r"cohort Point: .* pinned_handles=- born_full=3/96/0/1/32/0",
                      text)

@@ -344,10 +344,11 @@ def test_a_trace_that_disagrees_with_the_probe_fails_the_build():
     """The probe reads what the behaviours do; the window is built from
     the same functions. One that overwrites its handle the second time
     it is traced would be given a stale answer: the build raises.
-    (Told apart here by the probe's stand-in pool of one slot.)"""
+    (Told apart here by the checked handles, `resolved`, that only the
+    real trace's view is handed.)"""
     def fickle(self, st, i: I32, v: I32):
         self.blob_set(st["table"], i, v)
-        if self._blob.nslots == 1:
+        if self._blob.resolved is None:
             return st
         return {**st, "table": st["table"] + 1}
 
